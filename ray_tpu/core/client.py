@@ -113,6 +113,8 @@ class CoreClient:
         self.direct_port: Optional[int] = None
         self.node_info: dict = {}
         self.current_actor_id: Optional[ActorID] = None  # set when hosting an actor
+        # chip ids the scheduler granted this worker process (worker_main)
+        self.tpu_chips: Optional[list] = None
         # in-flight actor calls: return ObjectID -> concurrent Future of reply
         self._pending_calls: Dict[ObjectID, Any] = {}
         self._pending_lock = threading.Lock()
@@ -695,7 +697,9 @@ class CoreClient:
                     # a restarted head parks reconnecting workers until
                     # their node daemon's reconciliation handshake claims
                     # or disowns them (double-grant fence)
-                    reconnect=True)
+                    reconnect=True,
+                    # chips this worker was granted stay its own
+                    tpu_chips=self.tpu_chips)
             except Exception:
                 try:
                     await conn.close()
@@ -1806,11 +1810,12 @@ class CoreClient:
         """Direct pushes cover the common shapes (label selectors
         included — grants are selector-checked by the granting scheduler);
         anything needing the head's placement machinery (PGs, streaming,
-        runtime envs) takes the scheduled path."""
+        runtime envs, chip grants) takes the scheduled path."""
         return (num_returns == 1
                 and options.get("num_returns") != "streaming"
                 and not options.get("placement_group")
                 and not options.get("runtime_env")
+                and not (options.get("resources") or {}).get("TPU")
                 and options.get("scheduling_strategy", "hybrid") == "hybrid")
 
     def _pick_lease_node(self, options: dict) -> Optional[dict]:

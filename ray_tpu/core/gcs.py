@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 from ray_tpu.core import config as _config
 from ray_tpu.core import object_directory as objdir
 from ray_tpu.core import protocol
+from ray_tpu.core import resources as _resources
 from ray_tpu.core.ids import ActorID, NodeID, ObjectID, PlacementGroupID, TaskID, WorkerID
 from ray_tpu.core.store import ObjectMeta, SharedMemoryStore
 
@@ -42,6 +43,11 @@ class NodeInfo:
         self.node_id = node_id
         self.resources = dict(resources)
         self.available = dict(resources)
+        # chip ids no live worker process holds (core/resources.py: a
+        # granted worker keeps its chips until the process is gone, which
+        # can lag the "TPU" count it released at task end)
+        self.free_chips: List[int] = list(
+            range(int(resources.get("TPU", 0))))
         self.labels = dict(labels)
         self.conn = conn              # None for the head-local node
         self.max_workers = max_workers
@@ -85,8 +91,15 @@ class NodeInfo:
         self.starting_workers = 0
 
     def fits(self, resources: Dict[str, float]) -> bool:
-        return all(self.available.get(r, 0) >= amt - 1e-9
-                   for r, amt in resources.items())
+        return (all(self.available.get(r, 0) >= amt - 1e-9
+                    for r, amt in resources.items())
+                and self.chips_for(resources) is not None)
+
+    def chips_for(self, resources: Dict[str, float]) -> Optional[List[int]]:
+        """Free chip ids for this request ([] when it asks for none);
+        None while too few are free."""
+        return _resources.take_chips(self.free_chips,
+                                     _resources.chips_needed(resources))
 
     def could_ever_fit(self, resources: Dict[str, float]) -> bool:
         return all(self.resources.get(r, 0) >= amt - 1e-9
@@ -118,6 +131,9 @@ class WorkerInfo:
         self.acquired: Dict[str, float] = {}
         self.acquired_pg: Optional[PlacementGroupID] = None
         self.acquired_bundle: Optional[int] = None
+        # chip ids this process was granted; back on the node's free list
+        # only when the process is gone
+        self.tpu_chips: List[int] = []
         self.proc: Optional[subprocess.Popen] = None
         # pip-isolated workers run a venv interpreter; tasks whose
         # runtime_env carries the same pip_key route here exclusively
@@ -487,7 +503,7 @@ class Head:
 
         async def register_worker(worker_id, pid, port, is_driver, node_id=None,
                                   log_tag=None, venv_key=None,
-                                  reconnect=False):
+                                  reconnect=False, tpu_chips=None):
             nid = NodeID(node_id) if node_id else self.node_id
             node = self.nodes.get(nid) or self.head_node
             w = WorkerInfo(WorkerID(worker_id), conn_state["conn"], pid, port,
@@ -501,10 +517,20 @@ class Head:
             # daemon has not re-registered yet — pool_reconcile uses it to
             # find fallback-parked workers
             w.declared_node = nid
+            if tpu_chips:
+                # a reconnecting process that still holds granted chips:
+                # they stay its own (and off the free list) until it exits
+                w.tpu_chips = list(tpu_chips)
+                w.retiring = True
+                superseded = self.workers.get(w.worker_id)
+                if superseded is not None:
+                    superseded.tpu_chips = []
+                node.free_chips = [c for c in node.free_chips
+                                   if c not in w.tpu_chips]
             self.workers[w.worker_id] = w
             conn_state["worker"] = w
             node.workers.add(w.worker_id)
-            if not is_driver:
+            if not is_driver and not w.retiring:
                 node.starting_workers = max(0, node.starting_workers - 1)
                 item = (node.pending_pool.pop(w.worker_id, None)
                         if node.conn is not None else None)
@@ -732,8 +758,9 @@ class Head:
             cluster epoch and a carve-out generation (grant_seq) the
             daemon must echo on release."""
             node = conn_state.get("node")
-            if node is None or not node.could_ever_fit(resources):
-                return None
+            if (node is None or not node.could_ever_fit(resources)
+                    or _resources.chips_needed(resources)):
+                return None  # chip grants are per dispatch, never pooled
             if epoch is not None and epoch != self.cluster_epoch:
                 self._stale_epoch("pool_acquire", node)
                 return None
@@ -1534,9 +1561,9 @@ class Head:
             client could re-ask, starving leases exactly when they matter
             most (the r4 multi-client throughput inversion)."""
             w = conn_state.get("worker")
-            if w is None:
-                return None
             resources = options.get("resources", {"CPU": 1})
+            if w is None or _resources.chips_needed(resources):
+                return None  # chip grants are per dispatch, never leased
             node = self._select_node(resources, options.get("label_selector"),
                                      options.get("scheduling_strategy",
                                                  "hybrid"))
@@ -2162,6 +2189,30 @@ class Head:
         for r, amt in resources.items():
             ledger[r] = ledger.get(r, 0) - amt
         w.acquired = dict(resources)
+        if _resources.chips_needed(resources):
+            # callers checked node.chips_for(resources) (NodeInfo.fits)
+            node = self.nodes[w.node_id]
+            w.tpu_chips = node.chips_for(resources)
+            node.free_chips = [c for c in node.free_chips
+                               if c not in w.tpu_chips]
+            # the chips are this process's until it exits: never pooled
+            # again (same exit path as max_calls)
+            w.retiring = True
+
+    def _free_chips(self, w: WorkerInfo) -> None:
+        node = self.nodes.get(w.node_id)
+        if node is not None and w.tpu_chips:
+            node.free_chips = sorted(set(node.free_chips) | set(w.tpu_chips))
+        w.tpu_chips = []
+
+    def _with_chips(self, spec: dict, w: WorkerInfo) -> dict:
+        """The spec as pushed to a granted worker: carries its chip ids
+        (and how many the host has) so it binds to them before user code
+        (resources.claim_chips)."""
+        if not w.tpu_chips:
+            return spec
+        host_chips = int(self.nodes[w.node_id].resources.get("TPU", 0))
+        return dict(spec, tpu_chips=w.tpu_chips, tpu_host_chips=host_chips)
 
     def _release(self, w: WorkerInfo, cpu_only: bool = False) -> None:
         ledger = None
@@ -2200,7 +2251,8 @@ class Head:
             if bundle is None:
                 return "resources"
             node = self.nodes.get(bundle.node_id)
-            if node is None or not node.alive:
+            if (node is None or not node.alive
+                    or node.chips_for(resources) is None):
                 return "resources"
             w = self._idle_worker_on(node, venv_key)
             if w is None:
@@ -2238,7 +2290,7 @@ class Head:
             if dm:
                 spec = dict(spec)
                 spec["dep_metas"] = dm
-        w.conn.push("exec_task", spec=spec)
+        w.conn.push("exec_task", spec=self._with_chips(spec, w))
         return None
 
     def _kick(self) -> None:
@@ -2284,7 +2336,8 @@ class Head:
             if bundle is None:
                 return
             node = self.nodes.get(bundle.node_id)
-            if node is None or not node.alive:
+            if (node is None or not node.alive
+                    or node.chips_for(resources) is None):
                 return
             w = self._idle_worker_on(node, venv_key)
             if w is None:
@@ -2303,7 +2356,7 @@ class Head:
             self._acquire(w, resources)
         w.actor_id = info.actor_id
         info.worker = w
-        w.conn.push("start_actor", spec=info.spec)
+        w.conn.push("start_actor", spec=self._with_chips(info.spec, w))
 
     # -------------------------------------------------------------- workers
     def _request_worker(self, node: NodeInfo, pip=None,
@@ -2484,6 +2537,7 @@ class Head:
                 node.idle.remove(w)
             node.unadopted.discard(w)
         self._release(w)
+        self._free_chips(w)
         rec = getattr(w, "current_record", None)
         if rec is not None and w.running_task is not None:
             if rec.cancelled:
@@ -2525,6 +2579,7 @@ class Head:
                 node.idle.remove(w)
             node.unadopted.discard(w)
         self._release(w)
+        self._free_chips(w)
         rec = getattr(w, "current_record", None)
         if rec is not None and w.running_task is not None:
             if rec.cancelled:

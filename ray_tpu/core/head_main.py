@@ -11,6 +11,7 @@ import argparse
 import asyncio
 import json
 import os
+import signal
 import sys
 
 from ray_tpu.core import config as _config
@@ -99,8 +100,14 @@ async def amain(args) -> None:
         with open(tmp, "w") as f:
             json.dump(ports, f)
         os.replace(tmp, args.port_file)
+    # SIGTERM is how `ray_tpu.shutdown()` and the CLI stop the head: leave
+    # through head.stop(), which ends the workers (a worker granted chips
+    # holds them until its process exits) and unlinks the shm arena
+    stopping = asyncio.Event()
+    asyncio.get_running_loop().add_signal_handler(signal.SIGTERM,
+                                                  stopping.set)
     try:
-        await asyncio.Event().wait()
+        await stopping.wait()
     finally:
         await head.stop()
 

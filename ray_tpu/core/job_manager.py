@@ -55,10 +55,15 @@ class JobManager:
         info = JobInfo(job_id, entrypoint, metadata)
         info.log_path = os.path.join(self.log_dir, f"job-{job_id}.log")
         self.jobs[job_id] = info
-        child_env = dict(os.environ)
         from ray_tpu.core.resources import strip_device_env
 
-        child_env = strip_device_env(child_env)
+        # a job's driver orchestrates: it is held to the CPU like every
+        # control-plane process, because the scheduler cannot see a chip a
+        # driver opens on its own. Chip work goes through tasks and actors
+        # granted `num_tpu_chips` (JaxTrainer with use_tpu=True, Serve
+        # replicas); a job that must train in its own process says so with
+        # env={"JAX_PLATFORMS": "tpu"} and owns the host's chips unscheduled.
+        child_env = strip_device_env(dict(os.environ))
         child_env["RAY_TPU_ADDRESS"] = f"127.0.0.1:{self.head_port}"
         child_env["RAY_TPU_JOB_ID"] = job_id
         child_env.update(env or {})

@@ -12,6 +12,9 @@ actor calls on its own port. Also implements:
 - cancellation: `cancel_task` async-raises TaskCancelledError into the task
   thread (the CPython equivalent of the reference's interrupt path);
 - `max_calls`: worker retires after N executions of a task's function;
+- chip grants: a spec carrying `tpu_chips` binds this process to those
+  chips before user code runs; the worker then exits with that task or
+  actor, because a chip stays with its process (core/resources.py);
 - async actors: `async def` methods run on the event loop under a
   per-concurrency-group semaphore; sync methods run on per-group thread
   pools (reference fiber/concurrency-group semantics,
@@ -126,6 +129,16 @@ class WorkerRuntime:
             self._adopt_sys_path(self.client.kv_get("cluster", b"driver_sys_path"))
         except Exception:
             pass
+
+    def _claim_chips(self, spec) -> None:
+        """Bind this process to the chips the head granted with `spec`
+        (core/resources.py) before any of its user code runs."""
+        chips = spec.get("tpu_chips")
+        if chips:
+            from ray_tpu.core.resources import claim_chips
+
+            claim_chips(chips, spec["tpu_host_chips"])
+            self.client.tpu_chips = list(chips)
 
     def _adopt_dep_metas(self, spec) -> None:
         """Dep metas shipped with a task spec (lease push or head
@@ -265,6 +278,7 @@ class WorkerRuntime:
         streaming = opts.get("num_returns") == "streaming"
         applied = None
         try:
+            self._claim_chips(spec)
             if opts.get("runtime_env"):
                 from ray_tpu.core.runtime_env import AppliedEnv
 
@@ -309,12 +323,13 @@ class WorkerRuntime:
             if applied is not None:
                 applied.restore()
             self._task_threads.pop(task_key, None)
-            retire = False
+            # a worker that was granted chips holds them until it exits
+            retire = bool(spec.get("tpu_chips"))
             max_calls = opts.get("max_calls")
             if max_calls:
                 fn_key = spec["fn_key"]
                 self._fn_calls[fn_key] = self._fn_calls.get(fn_key, 0) + 1
-                retire = self._fn_calls[fn_key] >= max_calls
+                retire = retire or self._fn_calls[fn_key] >= max_calls
             try:
                 if retire:
                     self.client.head_push("worker_retiring")
@@ -367,6 +382,7 @@ class WorkerRuntime:
         self.client.current_actor_id = self.actor_id
 
         def _init():
+            self._claim_chips(spec)
             if opts.get("runtime_env"):
                 from ray_tpu.core.runtime_env import AppliedEnv
 
@@ -466,7 +482,9 @@ class WorkerRuntime:
 
 def main():
     from ray_tpu.core import config as _config
+    from ray_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()  # before any user code can import JAX
     head_host = _config.get("head_host")
     head_port = int(os.environ["RAY_TPU_HEAD_PORT"])
     session = os.environ["RAY_TPU_SESSION"]

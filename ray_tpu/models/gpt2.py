@@ -194,9 +194,9 @@ def _attention(x, p, cfg: GPT2Config):
 
     impl = _resolve_attn_impl(cfg, T)
     if impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
+        from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 
-        out = flash_attention(q, k, v, True)
+        out = flash_attention_on_mesh(q, k, v, True)
     elif impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 

@@ -12,9 +12,13 @@ Design notes (TPU-first, see /opt/skills/guides/pallas_guide.md):
   logsumexp: a dq kernel gridded over q-blocks and a dk/dv kernel gridded
   over k-blocks.
 
-On non-TPU backends the same kernels run under `interpret=True`, which is
-what the CI virtual-CPU mesh uses; numerics are validated against
-`mha_reference` in tests/test_flash_attention.py.
+On the `tpu` backend the kernels are compiled by Mosaic; on the `cpu`
+backend — the tests' virtual mesh, and nothing else — the same kernels run
+under `interpret=True`, with numerics validated against `mha_reference` in
+tests/test_flash_attention.py. Any other backend is an error, not a
+fallback. Because K/V stay whole in VMEM, the compiled kernel has a
+sequence-length ceiling (`_check_resident_kv`), raised as a clear error at
+trace time; tests/test_tpu_compile.py pins where it sits.
 
 The reference framework has no comparable op (attention lives in user
 frameworks); this is the TPU-native capability SURVEY.md §5.7 calls out.
@@ -35,8 +39,31 @@ DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 
 
+# Mosaic's default scoped-VMEM limit on a v5e core
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"flash_attention compiles for the tpu backend and interprets "
+            f"on the cpu test backend; {backend!r} is neither")
+    return backend == "cpu"
+
+
+def _check_resident_kv(seq_k: int, head_dim: int, dtype) -> None:
+    """The kernels map a head's whole [Tk, Dh] K and V into VMEM: two
+    arrays, double-buffered, the minor dim padded to 128 lanes. Refuse here
+    what the TPU compiler would refuse later as a scoped-vmem overflow."""
+    resident = 4 * seq_k * max(head_dim, 128) * jnp.dtype(dtype).itemsize
+    if resident >= _SCOPED_VMEM_BYTES:
+        raise ValueError(
+            f"flash_attention: sequence length {seq_k} is too long for "
+            f"this kernel, which keeps a head's whole K and V "
+            f"([{seq_k}, {head_dim}] {jnp.dtype(dtype).name}) in VMEM: "
+            f"{resident / 2 ** 20:.0f} MiB resident against a "
+            f"{_SCOPED_VMEM_BYTES // 2 ** 20} MiB scoped limit")
 
 
 def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):
@@ -110,6 +137,9 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
     if Tq % block_q or Tk % block_k:
         raise ValueError(f"seq lens ({Tq},{Tk}) must divide blocks "
                          f"({block_q},{block_k}); pad the sequence")
+    interpret = _interpret()
+    if not interpret:
+        _check_resident_kv(Tk, Dh, k.dtype)
     grid = (B, H, Tq // block_q)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, seq_k=Tk)
@@ -129,7 +159,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v)
     return out, lse
 
@@ -290,3 +320,22 @@ def _vjp_bwd(causal, scale, block_q, block_k, residuals, g):
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def flash_attention_on_mesh(q, k, v, causal: bool = True, mesh=None):
+    """`flash_attention` inside a sharded program. The TPU compiler cannot
+    partition a Mosaic kernel on its own ("Mosaic kernels cannot be
+    automatically partitioned"), so under a multi-device mesh each device
+    runs the kernel on its own (batch, heads) shard through `shard_map`.
+    The sequence stays whole: a mesh that shards it takes ring attention."""
+    from jax import shard_map
+
+    from ray_tpu.parallel.mesh import current_mesh, logical_to_spec
+
+    mesh = mesh or current_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal)
+    spec = logical_to_spec("batch", "heads", None, None)
+    return shard_map(lambda q, k, v: flash_attention(q, k, v, causal),
+                     mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                     check_vma=False)(q, k, v)

@@ -26,13 +26,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.mesh import current_mesh, logical_to_spec
 from ray_tpu.util.collective.hierarchy import (account_collective,
                                                ring_perm)
-from ray_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 NEG_INF = -1e30
 
@@ -154,8 +153,8 @@ def _wrap_shard_map(local_fn, q, k, v, mesh, axis, causal, scale):
         account_collective(op, nbytes, str(getattr(k, "dtype", "unknown")),
                            hop="intra")
     fn = functools.partial(local_fn, axis_name=axis, causal=causal, scale=scale)
-    return _compat_shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
 
 
 def ring_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,

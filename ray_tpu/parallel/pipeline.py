@@ -29,14 +29,12 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.mesh import current_mesh
 from ray_tpu.util.collective.hierarchy import (account_collective,
                                                ring_perm)
-from ray_tpu.utils.jax_compat import axis_index_operand
-from ray_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 
 def pipeline_apply(
@@ -70,34 +68,17 @@ def pipeline_apply(
     if M < F:
         raise ValueError(f"n_microbatches {M} < pipeline depth {F}: "
                          "bubble would dominate; use M >= pp")
-    # On CPU only, the shard_map boundary runs in f32: XLA's CPU backend (the
-    # dryrun/test platform) miscompiles sub-group bf16 psum in partial-manual
-    # regions ("Invalid binary instruction opcode copy" CHECK), and the f32
-    # boundary also covers the backward-pass psum of the replicated input's
-    # cotangent. On TPU the bug doesn't exist and bf16 boundaries halve the
-    # buffer + ICI psum bytes. Compute inside the stages stays in x.dtype.
+    # On the CPU backend (tests, dry runs) the shard_map boundary runs in
+    # f32: XLA:CPU still miscompiles a sub-group bf16 psum in a
+    # partial-manual region under jax 0.9.0 ("Invalid binary instruction
+    # opcode copy" CHECK), and the f32 boundary also covers the backward
+    # psum of the replicated input's cotangent. The TPU compiler takes the
+    # bf16 boundary (tests/test_tpu_compile.py), which halves the buffer
+    # and the ICI psum bytes. Compute inside the stages stays in x.dtype.
     compute_dtype = x.dtype
-    on_cpu = jax.default_backend() == "cpu"
-    boundary_dtype = jnp.float32 if on_cpu else compute_dtype
+    boundary_dtype = (jnp.float32 if jax.default_backend() == "cpu"
+                      else compute_dtype)
     xs = x.reshape(M, B // M, *x.shape[1:]).astype(boundary_dtype)
-    # Lowering mode. TPU: partial-manual (only `pp` manual) so stage_fn
-    # keeps its auto dp/tp shardings. CPU (the dryrun/test platform):
-    # jax 0.4.x's SPMD partitioner CHECK-crashes on sub-group ppermute in
-    # a partial-manual region ("target.IsManualSubgroup() ==
-    # sharding().IsManualSubgroup()"), so the region goes FULL-manual over
-    # every mesh axis — numerically identical (params replicated over the
-    # data axes transpose to a psum'd gradient, verified by the pipeline
-    # train test), with the microbatch batch dim explicitly split over
-    # the first divisible data axis to keep dp compute parallel.
-    manual_axes = set(mesh.axis_names) if on_cpu else {axis}
-    batch_axis = None
-    if on_cpu:
-        for cand in ("dp", "fsdp", "data"):
-            if (cand != axis and cand in mesh.shape
-                    and (B // M) % mesh.shape[cand] == 0):
-                batch_axis = cand
-                break
-    xs_spec = P(None, batch_axis) if batch_axis else P()
     if not isinstance(x, jax.core.Tracer):
         # eager entry: account the pipeline's stage hand-off wire bytes
         # ((M+F-1) ticks, each stage forwards one microbatch activation).
@@ -107,13 +88,10 @@ def pipeline_apply(
         account_collective("pipeline.ppermute", (M + F - 1) * F * mb_bytes,
                            str(compute_dtype), hop="intra")
 
-    def spmd_fn(stage_p, xs, stage_ids):
+    def spmd_fn(stage_p, xs):
         xs = xs.astype(compute_dtype)
         stage_p = jax.tree.map(lambda a: a[0], stage_p)   # this stage's slice
-        # operand-derived stage index: lax.axis_index in a partial-manual
-        # region lowers to a PartitionId instruction jax 0.4.x's SPMD
-        # partitioner rejects (see utils/jax_compat.axis_index_operand)
-        stage = stage_ids[0]
+        stage = lax.axis_index(axis)
         state = jnp.zeros_like(xs[0])
         outs = jnp.zeros_like(xs)
 
@@ -140,27 +118,19 @@ def pipeline_apply(
         # replicate the last stage's outputs to every stage (psum in the
         # boundary dtype — see dtype note above)
         outs = outs.astype(boundary_dtype)
-        outs = lax.psum(
+        return lax.psum(
             jnp.where(stage == F - 1, outs, jnp.zeros_like(outs)), axis)
-        return outs
 
-    import contextlib
-
-    from ray_tpu.parallel import mesh as mesh_mod
-
-    # full-manual regions reject sharding constraints over manual axes;
-    # auto-sharding-style stage functions still call mesh.constrain
-    cm = (mesh_mod.suppress_constraints() if manual_axes != {axis}
-          else contextlib.nullcontext())
-    with cm:
-        out = _compat_shard_map(
-            spmd_fn,
-            mesh=mesh,
-            in_specs=(P(axis), xs_spec, P(axis)),
-            out_specs=xs_spec,
-            axis_names=manual_axes,
-            check_vma=False,
-        )(stage_params, xs, axis_index_operand(F))
+    # partial-manual: only `axis` is manual, so stage_fn keeps its auto
+    # dp/tp shardings and constraints
+    out = shard_map(
+        spmd_fn,
+        mesh=mesh,
+        in_specs=(P(axis), P()),
+        out_specs=P(),
+        axis_names={axis},
+        check_vma=False,
+    )(stage_params, xs)
     return out.astype(compute_dtype).reshape(B, *x.shape[1:])
 
 

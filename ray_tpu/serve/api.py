@@ -30,7 +30,6 @@ class Deployment:
     autoscaling_config: Optional[AutoscalingConfig] = None
     init_args: tuple = ()
     init_kwargs: Optional[dict] = None
-    visible_chips: Optional[list] = None
     # admission policy (serve/live_signals.SLOConfig or dict): the proxies
     # shed (429 / RESOURCE_EXHAUSTED + Retry-After) when the route's
     # EWMA-projected wait exceeds slo_s or every replica queue is at
@@ -67,7 +66,6 @@ class Deployment:
             "autoscaling_config": auto,
             "init_args": self.init_args,
             "init_kwargs": self.init_kwargs,
-            "visible_chips": self.visible_chips,
             "slo_config": slo.to_dict() if slo is not None else None,
             "compiled": bool(self.compiled),
             "chain_config": self.chain_config,

@@ -224,8 +224,7 @@ class ServeController:
         opts["max_concurrency"] = cfg.get("max_ongoing_requests", 8)
         handle = ReplicaActor.options(**opts).remote(
             info.name, tag, cfg["callable"], cfg.get("init_args"),
-            cfg.get("init_kwargs"), cfg.get("user_config"),
-            visible_chips=cfg.get("visible_chips"))
+            cfg.get("init_kwargs"), cfg.get("user_config"))
         info.replicas[tag] = handle
         info.replica_meta[tag] = {"healthy": True, "started": time.time()}
         info.version += 1

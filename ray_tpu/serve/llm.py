@@ -784,7 +784,11 @@ class LLMEngine:
             ttft_avg = (self.ttft_sum / self.ttft_count
                         if self.ttft_count else 0.0)
             last_ttft = self.last_ttft_s
+        from ray_tpu.utils.platform import device_report
+
         return {"scheduler": self.scheduler,
+                # what this engine's process runs JAX on
+                "devices": device_report(),
                 "max_batch": self.max_batch,
                 "prefill_chunk_size": self.prefill_chunk_size,
                 "max_num_batched_tokens": self.max_num_batched_tokens,
@@ -1145,6 +1149,7 @@ class OpenAIServer(LLMServer):
             "id": f"cmpl-{int(time.time() * 1e3)}",
             "object": "text_completion", "model": self.model_id,
             "choices": [{"index": 0, "text": out["text"],
+                         "token_ids": out["token_ids"],
                          "finish_reason": finish}],
             "usage": {"prompt_tokens": out["prompt_tokens"],
                       "completion_tokens": out["completion_tokens"],

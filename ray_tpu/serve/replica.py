@@ -3,9 +3,9 @@
 Parity with `python/ray/serve/_private/replica.py`: runs user __init__ once,
 serves requests with an ongoing-request gauge, health checks, reconfigure
 with user_config, graceful drain. TPU twist: a replica scheduled with
-`num_tpu_chips=k` pins itself to k chips via TPU_VISIBLE_CHIPS before any
-jax import, so multiple replicas subdivide a host (reference
-`tpu.py:283-323` set_current_process_visible_accelerator_ids).
+`ray_actor_options={"num_tpu_chips": k}` runs in a worker the scheduler
+bound to k of its node's free chips (core/resources.py), so the replicas
+of one deployment subdivide a host, each on chips of its own.
 """
 
 from __future__ import annotations
@@ -13,7 +13,6 @@ from __future__ import annotations
 import threading
 import time
 import traceback
-from typing import Any, Optional
 
 import ray_tpu
 
@@ -21,12 +20,7 @@ import ray_tpu
 @ray_tpu.remote
 class ReplicaActor:
     def __init__(self, deployment_name: str, replica_tag: str,
-                 cls_or_fn, init_args, init_kwargs, user_config,
-                 visible_chips: Optional[list] = None):
-        if visible_chips:
-            from ray_tpu.core.resources import set_visible_chips
-
-            set_visible_chips(visible_chips)
+                 cls_or_fn, init_args, init_kwargs, user_config):
         self.deployment_name = deployment_name
         self.replica_tag = replica_tag
         self._ongoing = 0

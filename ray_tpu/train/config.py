@@ -45,6 +45,20 @@ class ElasticConfig:
     max_fenced_restarts: int = 5
 
 
+def _chips_per_host() -> int:
+    """All chips of one host, as this cluster's nodes advertise them: what
+    a TPU worker asks for unless `chips_per_worker` says otherwise (1 on a
+    one-chip machine, 4 on a v5e 2x2 host)."""
+    import ray_tpu
+
+    chips = int(max((n["resources"].get("TPU", 0) for n in ray_tpu.nodes()
+                     if n["alive"]), default=0))
+    if not chips:
+        raise RuntimeError("ScalingConfig(use_tpu=True): no node of this "
+                           "cluster advertises TPU chips")
+    return chips
+
+
 @dataclasses.dataclass
 class ScalingConfig:
     """How many workers and what each one holds.
@@ -80,7 +94,7 @@ class ScalingConfig:
     def worker_resources(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker or {})
         if self.use_tpu and "TPU" not in res:
-            res["TPU"] = float(self.chips_per_worker or 4)
+            res["TPU"] = float(self.chips_per_worker or _chips_per_host())
         if not self.use_tpu and not res:
             res = {"CPU": 1.0}
         return res

@@ -154,7 +154,7 @@ def _fused_hier_sync(loss_fn, mesh: Mesh, topo, params_spec, batch_spec,
     from jax.flatten_util import ravel_pytree
 
     from ray_tpu.util.collective.hierarchy import hier_grad_sync_program
-    from ray_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     inter_ax, intra_ax = topo.inter_axis, topo.intra_axis
     world = topo.world
@@ -174,9 +174,8 @@ def _fused_hier_sync(loss_fn, mesh: Mesh, topo, params_spec, batch_spec,
         vec = flat.astype(jnp.float32)
         if n_pad > vec.shape[0]:
             vec = jnp.pad(vec, (0, n_pad - vec.shape[0]))
-        # rank arrives as a sharded iota operand: lax.axis_index inside
-        # (partially) manual regions lowers to partition-id, which the
-        # SPMD partitioner rejects on this jax line (jax_compat note)
+        # rank arrives as a sharded iota operand: each shard reads its own
+        # (inter, intra) index as ids_l[0, 0]
         key = (jax.random.fold_in(jax.random.PRNGKey(step_l), ids_l[0, 0])
                if sr else None)
         if ef:
@@ -267,7 +266,7 @@ def _timed_hier_step(loss_fn, mesh: Mesh, topo, params_spec, batch_spec,
     from jax.flatten_util import ravel_pytree
 
     from ray_tpu.util.collective.hierarchy import hier_phase_programs
-    from ray_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     inter_ax, intra_ax = topo.inter_axis, topo.intra_axis
     world = topo.world

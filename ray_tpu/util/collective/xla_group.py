@@ -21,7 +21,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ray_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.util.collective.types import ReduceOp

@@ -31,9 +31,9 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax import shard_map
 
 from ray_tpu.util.collective.types import ReduceOp
-from ray_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 _COORD_NS = "collective_xmh"
 _MEMBER_NS = "collective_xmh_members"
@@ -288,8 +288,8 @@ class XlaMultihostGroup:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        return _compat_shard_map(fn, mesh=self.mesh, in_specs=P("p"),
-                             out_specs=P("p"))(g)
+        return shard_map(fn, mesh=self.mesh, in_specs=P("p"),
+                         out_specs=P("p"))(g)
 
     def _local_of(self, garr) -> np.ndarray:
         """This process's shard of a [world, ...] global array."""
@@ -432,7 +432,7 @@ class XlaMultihostGroup:
                         out = out / world
                     return out[None], nr[None]
 
-                fn = _compat_shard_map(
+                fn = shard_map(
                     body, mesh=self._hier_mesh, in_specs=(spec, spec),
                     out_specs=(spec, spec), check_vma=False)
             else:
@@ -442,9 +442,9 @@ class XlaMultihostGroup:
                         out = out / world
                     return out[None]
 
-                fn = _compat_shard_map(body, mesh=self._hier_mesh,
-                                       in_specs=spec, out_specs=spec,
-                                       check_vma=False)
+                fn = shard_map(body, mesh=self._hier_mesh,
+                               in_specs=spec, out_specs=spec,
+                               check_vma=False)
         else:
             def body(a):
                 out = red(a[0], inter)
@@ -452,9 +452,9 @@ class XlaMultihostGroup:
                     out = out / world
                 return out[None]
 
-            fn = _compat_shard_map(body, mesh=self._hier_mesh,
-                                   in_specs=spec, out_specs=spec,
-                                   check_vma=False)
+            fn = shard_map(body, mesh=self._hier_mesh,
+                           in_specs=spec, out_specs=spec,
+                           check_vma=False)
         prog = jax.jit(fn)
         self._hier_progs[key] = prog
         return prog
@@ -646,7 +646,7 @@ class XlaMultihostGroup:
         # exactly the addressable shards of THIS process (one of the two)
         g = jax.make_array_from_single_device_arrays(
             (2,) + shape, sharding, [local])
-        out = _compat_shard_map(
+        out = shard_map(
             lambda a: lax.ppermute(a, "pp", [(0, 1)]),
             mesh=mesh, in_specs=P("pp"), out_specs=P("pp"))(g)
         return out.addressable_shards[0].data  # [1, ...] on local device

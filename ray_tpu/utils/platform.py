@@ -1,12 +1,15 @@
-"""Platform helpers: force a virtual multi-device CPU backend for tests/dryruns.
+"""Platform helpers: where compiled programs are cached, what the devices
+report, and the virtual multi-device CPU backend the tests run on.
 
 The reference tests distributed logic on one machine with fake resources
-(SURVEY.md §4.2); our analog is an N-device virtual CPU mesh. Environments may
-pre-register/initialize a TPU PJRT plugin before our code runs, so env vars
-alone are not enough — we reset jax's backend state when needed.
+(SURVEY.md §4.2); our analog is an N-device virtual CPU mesh.
 """
 
 from __future__ import annotations
+
+import os
+import sys
+from typing import List
 
 
 def ensure_virtual_cpu(n_devices: int) -> None:
@@ -14,40 +17,62 @@ def ensure_virtual_cpu(n_devices: int) -> None:
     already-initialized backend if necessary. Call before creating any arrays
     (live buffers on a cleared backend become invalid)."""
     import jax
+    import jax.extend.backend
+    from jax._src import xla_bridge
 
-    try:
-        from jax._src import xla_bridge
-    except ImportError:  # pragma: no cover - jax internals moved
-        xla_bridge = None
-
-    if xla_bridge is not None and xla_bridge.backends_are_initialized():
+    if xla_bridge.backends_are_initialized():
         if jax.devices()[0].platform == "cpu" and len(jax.devices()) >= n_devices:
             return
-        xla_bridge._clear_backends()
-        xla_bridge.get_backend.cache_clear()
-
+        jax.extend.backend.clear_backends()
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", max(n_devices, 1))
-    except AttributeError:
-        # older jax has no jax_num_cpu_devices option: the host-platform
-        # device count binds from XLA_FLAGS at backend init — backends are
-        # uninitialized (or were cleared above), so setting it now works
-        import os
-
-        if "xla_force_host_platform_device_count" not in \
-                os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count="
-                f"{max(n_devices, 1)}").strip()
-    except RuntimeError:
-        pass  # backend got initialized under us; XLA_FLAGS may still apply
+    jax.config.update("jax_num_cpu_devices", max(n_devices, 1))
     got = len(jax.devices())
     if got < n_devices:
         raise RuntimeError(
-            f"could not create {n_devices} virtual CPU devices (got {got}); "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=N before jax init")
+            f"could not create {n_devices} virtual CPU devices (got {got})")
+
+
+def compile_cache_dir() -> str:
+    """Where this installation keeps JAX's persistent compilation cache:
+    `JAX_COMPILATION_CACHE_DIR` verbatim when the environment sets it, else
+    `.jax_cache` beside the package (the checkout's root). The path is part
+    of the cache key, so it is never derived from a temp name, pid, session
+    or time: every process of every run agrees on it."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Point this process — and, through the environment, every child it
+    starts — at `compile_cache_dir()`. Called at start-up by every process
+    that compiles (workers before user code, bench.py, chip_smoke.py's
+    phases); imports no JAX itself, since JAX reads the variable when it is
+    imported."""
+    path = os.environ["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_report() -> List[dict]:
+    """What `jax.devices()` is in this process, for results that must name
+    the device they ran on: id, platform, kind, the memory counters the
+    backend keeps (peak and limit in bytes; absent on the CPU), and the chip
+    ids libtpu was narrowed to when the scheduler granted this process part
+    of a host (`process_chips`; None for a whole host)."""
+    import jax
+
+    out = []
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        out.append({"id": d.id, "platform": d.platform,
+                    "kind": d.device_kind,
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                    "bytes_limit": stats.get("bytes_limit"),
+                    "process_chips": os.environ.get("TPU_VISIBLE_CHIPS")})
+    return out
 
 
 # Root for all on-disk runtime state (job logs, runtime_env extractions,

@@ -9,11 +9,11 @@ XLA group on the virtual 8-device CPU mesh.
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 import ray_tpu
 from ray_tpu.util.collective import ReduceOp, XlaCollectiveGroup
 from ray_tpu.util.collective.types import Backend
-from ray_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 
 @pytest.fixture(scope="module")
@@ -210,8 +210,8 @@ def test_multihost_reducescatter_lowering_and_numerics(devices8):
     mesh = Mesh(np.array(devices8), ("p",))
     x = np.arange(world * world * 4, dtype=np.float32).reshape(world, world, 4)
     g = jax.device_put(x, NamedSharding(mesh, P("p")))
-    f = jax.jit(_compat_shard_map(_rs_program(ReduceOp.SUM), mesh=mesh,
-                              in_specs=P("p"), out_specs=P("p")))
+    f = jax.jit(shard_map(_rs_program(ReduceOp.SUM), mesh=mesh,
+                          in_specs=P("p"), out_specs=P("p")))
     out = np.asarray(f(g))
     np.testing.assert_allclose(out, np.stack(
         [x.sum(axis=0)[i] for i in range(world)]))
@@ -219,8 +219,8 @@ def test_multihost_reducescatter_lowering_and_numerics(devices8):
     assert "reduce-scatter" in hlo, "SUM path must lower to reduce-scatter"
     assert "all-reduce" not in hlo, "SUM path must NOT be a full allreduce"
     # non-sum ops: no scatter primitive exists; numerics still must hold
-    fmax = jax.jit(_compat_shard_map(_rs_program(ReduceOp.MAX), mesh=mesh,
-                                 in_specs=P("p"), out_specs=P("p")))
+    fmax = jax.jit(shard_map(_rs_program(ReduceOp.MAX), mesh=mesh,
+                             in_specs=P("p"), out_specs=P("p")))
     np.testing.assert_allclose(np.asarray(fmax(g)), np.stack(
         [x.max(axis=0)[i] for i in range(world)]))
 
@@ -260,8 +260,8 @@ def test_hier_allreduce_lowering_and_numerics(devices8):
     from ray_tpu.util.collective.hierarchy import hier_allreduce_program
 
     topo, mesh, spec, x, g = _hier_setup(devices8)
-    f = jax.jit(_compat_shard_map(hier_allreduce_program(topo), mesh=mesh,
-                                  in_specs=spec, out_specs=spec))
+    f = jax.jit(shard_map(hier_allreduce_program(topo), mesh=mesh,
+                          in_specs=spec, out_specs=spec))
     np.testing.assert_allclose(np.asarray(f(g)),
                                np.tile(x.sum(0), (4, 1)), rtol=1e-5)
     hlo = f.lower(g).compile().as_text()
@@ -288,7 +288,7 @@ def test_hier_quantized_wire_dtype_int8_and_fp8(devices8):
     topo, mesh, spec, x, g = _hier_setup(devices8)
     for dtype, marker in (("int8", "s8["), ("float8_e4m3fn", "f8e4m3")):
         q = QuantizedAllreduce(dtype=dtype, chunk=16, error_feedback=False)
-        f = jax.jit(_compat_shard_map(
+        f = jax.jit(shard_map(
             hier_allreduce_program(topo, quantize=q), mesh=mesh,
             in_specs=spec, out_specs=spec))
         hlo = f.lower(g).compile().as_text()
@@ -314,9 +314,9 @@ def test_hier_reduce_scatter_allgather_roundtrip(devices8):
         hier_all_gather_program, hier_reduce_scatter_program)
 
     topo, mesh, spec, x, g = _hier_setup(devices8)
-    frs = jax.jit(_compat_shard_map(hier_reduce_scatter_program(topo),
-                                    mesh=mesh, in_specs=spec,
-                                    out_specs=spec))
+    frs = jax.jit(shard_map(hier_reduce_scatter_program(topo),
+                            mesh=mesh, in_specs=spec,
+                            out_specs=spec))
     rs = frs(g)
     per = 64 // 4
     want = np.stack([x.sum(0)[topo.shard_index(d // 2, d % 2) * per:][:per]
@@ -324,9 +324,9 @@ def test_hier_reduce_scatter_allgather_roundtrip(devices8):
     np.testing.assert_allclose(np.asarray(rs), want, rtol=1e-5)
     hlo = frs.lower(g).compile().as_text()
     assert hlo.count("reduce-scatter(") >= 2 and "all-reduce(" not in hlo
-    fag = jax.jit(_compat_shard_map(hier_all_gather_program(topo),
-                                    mesh=mesh, in_specs=spec,
-                                    out_specs=spec))
+    fag = jax.jit(shard_map(hier_all_gather_program(topo),
+                            mesh=mesh, in_specs=spec,
+                            out_specs=spec))
     np.testing.assert_allclose(np.asarray(fag(rs)),
                                np.tile(x.sum(0), (4, 1)), rtol=1e-5)
 
@@ -371,7 +371,7 @@ def test_error_feedback_reduces_accumulated_bias(devices8):
 
     topo, mesh, spec, x, g = _hier_setup(devices8)
     q = QuantizedAllreduce(dtype="int8", chunk=16, error_feedback=True)
-    f = jax.jit(_compat_shard_map(
+    f = jax.jit(shard_map(
         hier_allreduce_ef_program(topo, q), mesh=mesh,
         in_specs=(spec, spec), out_specs=(spec, spec)))
     r = jax.device_put(np.zeros((4, 32), np.float32),
@@ -413,7 +413,7 @@ def test_product_allreduce_chunked_world4(devices8):
     x = np.full((4, 64), 2.0, np.float32)
     x[1] = 0.5
     g = jax.device_put(x, NamedSharding(mesh, P("p")))
-    f = jax.jit(_compat_shard_map(
+    f = jax.jit(shard_map(
         lambda a: gathered_reduce(a[0], "p", lambda t: t.prod(axis=0),
                                   cap_bytes=256)[None],
         mesh=mesh, in_specs=P("p"), out_specs=P("p")))
@@ -421,7 +421,7 @@ def test_product_allreduce_chunked_world4(devices8):
     hlo = f.lower(g).compile().as_text()
     assert hlo.count("all-gather(") > 1, "cap did not chunk the gather"
     # the multihost reduce-op body routes PRODUCT through the same helper
-    fm = jax.jit(_compat_shard_map(
+    fm = jax.jit(shard_map(
         lambda a: _reduce_op(ReduceOp.PRODUCT)(a[0], "p")[None],
         mesh=mesh, in_specs=P("p"), out_specs=P("p")))
     np.testing.assert_allclose(np.asarray(fm(g)), np.tile(x.prod(0), (4, 1)))
@@ -753,8 +753,8 @@ def test_fused_sync_bitwise_matches_staged(devices8):
     hdevs = np.asarray(ct.mesh.devices).reshape(topo.inter, topo.intra)
     hmesh = Mesh(hdevs, (topo.inter_axis, topo.intra_axis))
     spec = P((topo.inter_axis, topo.intra_axis))
-    f = jax.jit(_compat_shard_map(hier_allreduce_program(topo), mesh=hmesh,
-                                  in_specs=spec, out_specs=spec))
+    f = jax.jit(shard_map(hier_allreduce_program(topo), mesh=hmesh,
+                          in_specs=spec, out_specs=spec))
     staged = np.asarray(f(jax.device_put(
         x, NamedSharding(hmesh, spec))))[0] / topo.world
 

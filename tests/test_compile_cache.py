@@ -1,0 +1,90 @@
+"""Placement of JAX's persistent compilation cache
+(`utils.platform.enable_compile_cache`): `JAX_COMPILATION_CACHE_DIR`
+verbatim when the environment sets it, else one fixed path inside the
+checkout that every process agrees on — never a temp name, pid or time.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+import ray_tpu
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(ray_tpu.__file__)))
+IN_CHECKOUT = os.path.join(REPO, ".jax_cache")
+
+_PROBE = """
+import sys
+{pre}
+from ray_tpu.utils.platform import enable_compile_cache
+path = enable_compile_cache()
+import jax
+assert jax.config.jax_compilation_cache_dir == path, (
+    jax.config.jax_compilation_cache_dir, path)
+print(path)
+"""
+
+
+def _driver_path(env_value, cwd, jax_first=False):
+    """The path a fresh driver process settles on (helper called before or
+    after its `import jax`)."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = REPO
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    out = subprocess.run(
+        [sys.executable, "-c",
+         _PROBE.format(pre="import jax" if jax_first else "")],
+        env=env, cwd=cwd, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    return out.stdout.strip().splitlines()[-1]
+
+
+@ray_tpu.remote
+def _worker_path():
+    import jax
+
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+            jax.config.jax_compilation_cache_dir)
+
+
+def _worker_paths(monkeypatch, env_value):
+    """(env, jax config) as a worker of a cluster started here sees them:
+    the worker calls the helper itself at start-up, before user code."""
+    if env_value is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_value)
+    ray_tpu.init(num_cpus=1, num_tpu_chips=0, max_workers=2)
+    try:
+        return ray_tpu.get(_worker_path.remote(), timeout=120)
+    finally:
+        ray_tpu.shutdown()
+
+
+@pytest.mark.parametrize("jax_first", [False, True])
+def test_driver_uses_env_path_verbatim(tmp_path, jax_first):
+    given = str(tmp_path / "placed from outside") + "/"   # kept as given
+    assert _driver_path(given, str(tmp_path), jax_first) == given
+
+
+def test_worker_uses_env_path_verbatim(tmp_path, monkeypatch):
+    given = str(tmp_path / "placed-from-outside")
+    assert _worker_paths(monkeypatch, given) == (given, given)
+
+
+def test_unset_processes_agree_on_in_checkout_path(tmp_path, monkeypatch):
+    other = tmp_path / "elsewhere"
+    other.mkdir()
+    paths = {_driver_path(None, str(tmp_path)),
+             _driver_path(None, str(other), jax_first=True),
+             *_worker_paths(monkeypatch, None)}
+    assert paths == {IN_CHECKOUT}
+
+
+def test_in_checkout_cache_is_not_committed():
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -1,0 +1,209 @@
+"""The main path's kernels and step programs, compiled by the TPU's own
+compiler for a chip that is described and not attached (`v5e:2x2`).
+
+Nothing runs here, so nothing is said about results or times: a pass means
+the chip's compiler accepts the program (tiling, VMEM, partitioning,
+per-device memory), which interpret mode and the CPU backend cannot tell.
+Code that asks `jax.default_backend()` sees the CPU in this process, so the
+tests steer it onto its TPU branch themselves. conftest.py keeps the
+persistent compilation cache off: an executable compiled for a described
+chip cannot be read back without one.
+"""
+
+import importlib
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import gpt2
+from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+from ray_tpu.train.spmd import (compile_gpt2_train, compile_pipeline_train,
+                                default_optimizer)
+
+flash = importlib.import_module("ray_tpu.ops.flash_attention")
+
+HBM_BYTES = 16909336064        # a v5e chip's memory_stats()["bytes_limit"]
+
+
+@pytest.fixture(scope="module")
+def chips():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Take the branches the program takes on the chip."""
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _per_device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _flash_program(grad: bool):
+    def fwd(q, k, v):
+        return flash.flash_attention(q, k, v, True)
+
+    if not grad:
+        return jax.jit(fwd)
+    return jax.jit(jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+
+
+# ------------------------------------------------------------------ kernels
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("shape", [(4, 12, 2048, 64), (2, 25, 2048, 64)],
+                         ids=["125m-heads", "1.5b-heads"])
+def test_flash_attention_compiles(chips, as_on_tpu, shape, grad):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(chips[0]))
+    compiled = _flash_program(grad).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_refuses_16k_by_name(chips, as_on_tpu, grad):
+    """K/V stay whole in VMEM (ROADMAP S2): at 16k the kernel says so
+    itself. When S2 lifts the ceiling this test flips to a compile."""
+    x = jax.ShapeDtypeStruct((1, 12, 16384, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(chips[0]))
+    with pytest.raises(ValueError,
+                       match=r"flash_attention: sequence length 16384"):
+        _flash_program(grad).lower(x, x, x)
+
+
+@pytest.mark.parametrize("seq,fits", [(8192, True), (16384, False)])
+def test_flash_ceiling_is_where_the_compiler_puts_it(chips, as_on_tpu,
+                                                     monkeypatch, seq, fits):
+    """`_check_resident_kv` predicts the compiler's scoped-vmem refusal:
+    with the check out of the way the compiler itself decides."""
+    monkeypatch.setattr(flash, "_check_resident_kv", lambda *a: None)
+    x = jax.ShapeDtypeStruct((1, 12, seq, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(chips[0]))
+    lowered = _flash_program(False).lower(x, x, x)
+    if fits:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+
+
+# ------------------------------------------------------------ serving steps
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_serving_step_compiles_at_125m_widths(chips, program):
+    B, T, C = 8, 1024, 16
+    one = SingleDeviceSharding(chips[0])
+    cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=T)
+    params = _on(one, jax.eval_shape(
+        lambda: gpt2.init_params(jax.random.key(0), cfg)))
+    cache = _on(one, jax.eval_shape(lambda: gpt2.init_cache(cfg, B, T)))
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    # as serve/llm.LLMEngine jits them: the cache is donated
+    if program == "decode_step":
+        fn = jax.jit(lambda p, c, t, pos, a:
+                     gpt2.decode_step(p, c, t, pos, a, cfg),
+                     donate_argnums=(1,))
+        args = (params, cache, arr((B,), jnp.int32), arr((B,), jnp.int32),
+                arr((B,), jnp.bool_))
+    else:
+        fn = jax.jit(lambda p, c, t, pos0, n, a:
+                     gpt2.prefill_chunk(p, c, t, pos0, n, a, cfg),
+                     donate_argnums=(1,))
+        args = (params, cache, arr((B, C), jnp.int32), arr((B,), jnp.int32),
+                arr((B,), jnp.int32), arr((B,), jnp.bool_))
+    compiled = fn.lower(*args).compile()
+    assert _per_device_bytes(compiled) < HBM_BYTES
+
+
+# --------------------------------------------------------------- train step
+
+def _compile_train_step(train, batch, seq):
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(train.init_fn, jax.random.key(0)),
+        train.state_sharding)
+    data = {"tokens": jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32,
+                                           sharding=train.batch_sharding)}
+    return train.step_fn.lower(state, data).compile()
+
+
+@pytest.mark.parametrize("axes,n_chips", [({}, 1), ({"dp": 2, "tp": 2}, 4)],
+                         ids=["1chip", "dp2tp2"])
+def test_125m_train_step_compiles(chips, as_on_tpu, axes, n_chips):
+    """The smoke's step: T=1024 resolves to dense attention (no Mosaic
+    call), at a global batch that fits either layout."""
+    batch, seq = 16, 1024
+    mesh = build_mesh(MeshConfig(**axes), devices=chips[:n_chips])
+    cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=seq, remat=True,
+                                 remat_policy="dots")
+    train = compile_gpt2_train(cfg, mesh,
+                               optimizer=default_optimizer(total_steps=100))
+    compiled = _compile_train_step(train, batch, seq)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert _per_device_bytes(compiled) < HBM_BYTES
+    assert "nvoluntary full rematerialization" not in text
+    if n_chips > 1:
+        assert re.search(r"\ball-reduce(-start)?\(", text)
+
+
+def test_flash_attention_compiles_under_a_mesh(chips, as_on_tpu):
+    """The compiler refuses to partition a Mosaic kernel on its own, so
+    under dp2·tp2 the models call it per (batch, heads) shard through
+    shard_map; at T=2048 `auto` resolves to it."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.parallel.mesh import logical_to_spec, use_mesh
+
+    mesh = build_mesh(MeshConfig(dp=2, tp=2), devices=chips)
+    assert gpt2._resolve_attn_impl(gpt2.GPT2Config(), 2048) == "flash"
+    with use_mesh(mesh):
+        spec = logical_to_spec("batch", "heads", None, None)
+        x = jax.ShapeDtypeStruct((4, 12, 2048, 64), jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, spec))
+        step = jax.jit(jax.grad(
+            lambda q, k, v: flash.flash_attention_on_mesh(q, k, v)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+        assert "tpu_custom_call" in step.lower(x, x, x).compile().as_text()
+
+
+def test_pipeline_step_compiles_with_the_tpu_lowering(chips, as_on_tpu):
+    """pp2·dp2 over four chips through `pipeline_apply`'s TPU side — the
+    bf16 shard_map boundary the CPU backend cannot take."""
+    mesh = build_mesh(MeshConfig(pp=2, dp=2), devices=chips)
+    cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=256, remat=True,
+                                 n_layer=4, attn_impl="dense")
+    train = compile_pipeline_train(
+        gpt2, cfg, mesh, n_microbatches=4,
+        optimizer=default_optimizer(total_steps=100))
+    text = _compile_train_step(train, 8, 256).as_text()
+    assert re.search(r"\bcollective-permute(-start)?\(", text)
+    # the stage outputs are psum'd across pp in the compute dtype
+    assert re.search(r"bf16\[[^\]]*\]\S* all-reduce(-start)?\(", text)
