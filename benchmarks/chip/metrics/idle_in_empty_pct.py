@@ -1,0 +1,9 @@
+"""Share of the traced window in which the idlest device is idle while the
+engine's thread is inside `engine.empty`: no slot is live, there is
+nothing to run, and the host is not at fault (`_phases`)."""
+
+from . import _phases
+
+
+def read(record):
+    return _phases.idle_pct(record, phases=("empty",))

@@ -1,0 +1,8 @@
+"""Share of the traced window's device self time under the program's
+`ln` scope (`_scopes`)."""
+
+from . import _scopes
+
+
+def read(record):
+    return _scopes.share(record, "ln")

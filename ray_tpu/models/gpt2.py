@@ -11,7 +11,12 @@ as a torch port:
 - every activation is annotated with logical axes (`batch`/`seq`/`embed`/...)
   so the same code runs dp/fsdp/tp/sp sharded under any mesh from
   `ray_tpu.parallel.mesh.build_mesh` — XLA inserts the ICI collectives;
-- `jax.checkpoint` (remat) around each block trades FLOPs for HBM.
+- `jax.checkpoint` (remat) around each block trades FLOPs for HBM;
+- every part of a step program sits in a `jax.named_scope` (`embed`, `ln`,
+  `attn`, `mlp`, `unembed_loss`, `layers`, `kv_update`, `weights_cast`):
+  metadata only, it names each compiled operation's origin (`tf_op` in a
+  device trace), which `benchmarks/chip/metrics/_scopes.py` sums device
+  time by.
 
 No dropout in round 1 (the reference benchmark config trains without it).
 """
@@ -167,11 +172,20 @@ def param_specs(cfg: GPT2Config, rules=None) -> Params:
 # ---------------------------------------------------------------------------
 
 def _layer_norm(x, p, eps=1e-5):
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
-    y = (x32 - mu) * lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("ln"):
+        x32 = x.astype(jnp.float32)
+        mu = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.var(x32, axis=-1, keepdims=True)
+        y = (x32 - mu) * lax.rsqrt(var + eps)
+        return (y * p["scale"].astype(jnp.float32)
+                + p["bias"].astype(jnp.float32)).astype(x.dtype)
+
+
+def _w(p, cfg: "GPT2Config"):
+    """A weight in the compute dtype. Every conversion goes through here,
+    so that the innermost scope of its operation names it."""
+    with jax.named_scope("weights_cast"):
+        return p.astype(cfg.dtype)
 
 
 def _resolve_attn_impl(cfg: GPT2Config, seq_len: int) -> str:
@@ -183,7 +197,7 @@ def _resolve_attn_impl(cfg: GPT2Config, seq_len: int) -> str:
 def _attention(x, p, cfg: GPT2Config):
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
-    qkv = x @ p["wqkv"].astype(cfg.dtype) + p["bqkv"].astype(cfg.dtype)
+    qkv = x @ _w(p["wqkv"], cfg) + _w(p["bqkv"], cfg)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
@@ -214,22 +228,25 @@ def _attention(x, p, cfg: GPT2Config):
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
-    out = out @ p["wo"].astype(cfg.dtype) + p["bo"].astype(cfg.dtype)
+    out = out @ _w(p["wo"], cfg) + _w(p["bo"], cfg)
     return out
 
 
 def _mlp(x, p, cfg: GPT2Config):
-    h = x @ p["wi"].astype(cfg.dtype) + p["bi"].astype(cfg.dtype)
+    h = x @ _w(p["wi"], cfg) + _w(p["bi"], cfg)
     h = constrain(h, "batch", "seq", "mlp")
     h = jax.nn.gelu(h, approximate=True)
-    return h @ p["wo"].astype(cfg.dtype) + p["bo"].astype(cfg.dtype)
+    return h @ _w(p["wo"], cfg) + _w(p["bo"], cfg)
 
 
 def _block(x, bp, cfg: GPT2Config):
-    x = x + _attention(_layer_norm(x, bp["ln1"]), bp["attn"], cfg)
-    x = constrain(x, "batch", "seq", "embed")
-    x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
-    x = constrain(x, "batch", "seq", "embed")
+    # each residual add belongs to the scope of what it adds
+    with jax.named_scope("attn"):
+        x = x + _attention(_layer_norm(x, bp["ln1"]), bp["attn"], cfg)
+        x = constrain(x, "batch", "seq", "embed")
+    with jax.named_scope("mlp"):
+        x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
+        x = constrain(x, "batch", "seq", "embed")
     return x
 
 
@@ -242,16 +259,18 @@ def embed(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array:
     # rematerialization when re-sharding to the batch layout; an upfront
     # all-gather of the table (the ZeRO-3 prefetch pattern) is the cheap
     # and intended collective
-    wte = constrain(params["wte"], None, None)
-    x = wte[tokens] + params["wpe"][:T][None]
-    return constrain(x.astype(cfg.dtype), "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        wte = constrain(params["wte"], None, None)
+        x = wte[tokens] + params["wpe"][:T][None]
+        return constrain(x.astype(cfg.dtype), "batch", "seq", "embed")
 
 
 def unembed(params: Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
     """final hidden [B,T,D] -> logits [B,T,vocab] (tied embeddings)."""
-    x = _layer_norm(x, params["ln_f"])
-    logits = x @ params["wte"].T.astype(cfg.dtype)
-    return constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope("unembed_loss"):
+        x = _layer_norm(x, params["ln_f"])
+        logits = x @ _w(params["wte"].T, cfg)
+        return constrain(logits, "batch", "seq", "vocab")
 
 
 def hidden_states(params: Params, tokens: jax.Array,
@@ -272,7 +291,8 @@ def hidden_states(params: Params, tokens: jax.Array,
     def scan_body(carry, bp):
         return block_fn(carry, bp), None
 
-    x, _ = lax.scan(scan_body, x, params["blocks"])
+    with jax.named_scope("layers"):     # the scan's own slices and stacks
+        x, _ = lax.scan(scan_body, x, params["blocks"])
     return x
 
 
@@ -287,27 +307,29 @@ def chunked_ce(params: Params, x: jax.Array, targets: jax.Array,
     drops from [B, T, V] to [B, ce_chunk, V] (fwd AND bwd — the chunk
     body is rematerialized), freeing HBM for larger per-chip batches.
     Numerically identical to unembed+cross_entropy (f32 reductions)."""
-    x = _layer_norm(x, params["ln_f"])
-    W = params["wte"].T.astype(cfg.dtype)                  # [D, V]
     B, T, D = x.shape
     C = cfg.ce_chunk
     if T % C:
         raise ValueError(f"seq len {T} not divisible by ce_chunk={C}")
     K = T // C
-    xc = x.reshape(B, K, C, D).swapaxes(0, 1)              # [K, B, C, D]
-    tc = targets.reshape(B, K, C).swapaxes(0, 1)           # [K, B, C]
+    with jax.named_scope("unembed_loss"):
+        x = _layer_norm(x, params["ln_f"])
+        W = _w(params["wte"].T, cfg)                       # [D, V]
+        xc = x.reshape(B, K, C, D).swapaxes(0, 1)          # [K, B, C, D]
+        tc = targets.reshape(B, K, C).swapaxes(0, 1)       # [K, B, C]
 
-    def body(acc, xt):
-        xcb, tcb = xt
-        logits = constrain(xcb @ W, "batch", "seq", "vocab")
-        logits = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tcb[..., None],
-                                   axis=-1)[..., 0]
-        return acc + jnp.sum(logz - gold), None
+        def body(acc, xt):
+            xcb, tcb = xt
+            logits = constrain(xcb @ W, "batch", "seq", "vocab")
+            logits = logits.astype(jnp.float32)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, tcb[..., None],
+                                       axis=-1)[..., 0]
+            return acc + jnp.sum(logz - gold), None
 
-    total, _ = lax.scan(jax.checkpoint(body), jnp.float32(0.0), (xc, tc))
-    return total / (B * T)
+        total, _ = lax.scan(jax.checkpoint(body), jnp.float32(0.0),
+                            (xc, tc))
+        return total / (B * T)
 
 
 def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
@@ -346,8 +368,10 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     H, Dh = cfg.n_head, cfg.head_dim
     T = cache["k"].shape[3]
     wte = params["wte"]
-    x = wte[tokens] + params["wpe"][jnp.clip(pos, 0, cfg.max_seq_len - 1)]
-    x = x.astype(cfg.dtype)                                   # [B, D]
+    with jax.named_scope("embed"):
+        x = wte[tokens] + params["wpe"][
+            jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+        x = x.astype(cfg.dtype)                               # [B, D]
 
     def upd_one(c_b, val_b, p_b):
         # c_b [H, T, Dh], val_b [H, Dh] -> write at position p_b
@@ -356,35 +380,43 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
 
     def layer(x, scanned):
         bp, ck, cv = scanned                                  # ck/cv [B,H,T,Dh]
-        h = _layer_norm(x, bp["ln1"])
-        qkv = h @ bp["attn"]["wqkv"].astype(cfg.dtype) + \
-            bp["attn"]["bqkv"].astype(cfg.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, H, Dh)
-        k = k.reshape(B, H, Dh)
-        v = v.reshape(B, H, Dh)
-        ck_new = jax.vmap(upd_one)(ck, k, pos)
-        cv_new = jax.vmap(upd_one)(cv, v, pos)
-        ck = jnp.where(active[:, None, None, None], ck_new, ck)
-        cv = jnp.where(active[:, None, None, None], cv_new, cv)
-        scores = jnp.einsum("bhd,bhtd->bht", q, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(Dh)
-        t_idx = jnp.arange(T)[None, None, :]
-        scores = jnp.where(t_idx <= pos[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bht,bhtd->bhd", probs, cv)
-        attn = attn.reshape(B, H * Dh)
-        attn = attn @ bp["attn"]["wo"].astype(cfg.dtype) + \
-            bp["attn"]["bo"].astype(cfg.dtype)
-        x = x + attn
-        x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
+        with jax.named_scope("attn"):
+            h = _layer_norm(x, bp["ln1"])
+            qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
+                _w(bp["attn"]["bqkv"], cfg)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, H, Dh)
+            k = k.reshape(B, H, Dh)
+            v = v.reshape(B, H, Dh)
+            with jax.named_scope("kv_update"):
+                ck_new = jax.vmap(upd_one)(ck, k, pos)
+                cv_new = jax.vmap(upd_one)(cv, v, pos)
+                ck = jnp.where(active[:, None, None, None], ck_new, ck)
+                cv = jnp.where(active[:, None, None, None], cv_new, cv)
+            scores = jnp.einsum("bhd,bhtd->bht", q, ck,
+                                preferred_element_type=jnp.float32)
+            scores = scores / math.sqrt(Dh)
+            t_idx = jnp.arange(T)[None, None, :]
+            scores = jnp.where(t_idx <= pos[:, None, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bht,bhtd->bhd", probs, cv)
+            attn = attn.reshape(B, H * Dh)
+            attn = attn @ _w(bp["attn"]["wo"], cfg) + \
+                _w(bp["attn"]["bo"], cfg)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = lax.scan(layer, x,
-                                 (params["blocks"], cache["k"], cache["v"]))
-    x = _layer_norm(x, params["ln_f"])
-    logits = (x @ wte.T.astype(cfg.dtype)).astype(jnp.float32)
+    # what the scan does itself (a layer's weights and cache sliced in,
+    # the new caches stacked out, the carries) is `layers`; the layer's
+    # own operations keep their inner scopes
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = lax.scan(
+            layer, x, (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("unembed_loss"):
+        x = _layer_norm(x, params["ln_f"])
+        logits = (x @ _w(wte.T, cfg)).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -412,8 +444,10 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
     lane = jnp.arange(C)
     pos = pos0[:, None] + lane[None, :]                           # [B, C]
     valid = lane[None, :] < length[:, None]                       # [B, C]
-    x = wte[tokens] + params["wpe"][jnp.clip(pos, 0, cfg.max_seq_len - 1)]
-    x = x.astype(cfg.dtype)                                       # [B, C, D]
+    with jax.named_scope("embed"):
+        x = wte[tokens] + params["wpe"][
+            jnp.clip(pos, 0, cfg.max_seq_len - 1)]
+        x = x.astype(cfg.dtype)                                   # [B, C, D]
 
     def upd_chunk(c_b, val_b, p0_b, valid_b):
         # c_b [H, T, Dh], val_b [H, C, Dh]: write val lane i at position
@@ -431,40 +465,47 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
 
     def layer(x, scanned):
         bp, ck, cv = scanned                                # ck/cv [B,H,T,Dh]
-        h = _layer_norm(x, bp["ln1"])
-        qkv = h @ bp["attn"]["wqkv"].astype(cfg.dtype) + \
-            bp["attn"]["bqkv"].astype(cfg.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)    # [B, H, C, Dh]
-        k = k.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
-        v = v.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
-        ck_new = jax.vmap(upd_chunk)(ck, k, pos0, valid)
-        cv_new = jax.vmap(upd_chunk)(cv, v, pos0, valid)
-        ck = jnp.where(active[:, None, None, None], ck_new, ck)
-        cv = jnp.where(active[:, None, None, None], cv_new, cv)
-        # chunk lanes attend to everything written up to their own
-        # position (the chunk's k/v are already in the cache, so this is
-        # causal intra-chunk attention + full attention to the prefix)
-        scores = jnp.einsum("bhcd,bhtd->bhct", q, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(Dh)
-        t_idx = jnp.arange(T)[None, None, None, :]
-        scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhct,bhtd->bhcd", probs, cv)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, C, H * Dh)
-        attn = attn @ bp["attn"]["wo"].astype(cfg.dtype) + \
-            bp["attn"]["bo"].astype(cfg.dtype)
-        x = x + attn
-        x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
+        with jax.named_scope("attn"):
+            h = _layer_norm(x, bp["ln1"])
+            qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
+                _w(bp["attn"]["bqkv"], cfg)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)  # [B,H,C,Dh]
+            k = k.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
+            v = v.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
+            with jax.named_scope("kv_update"):
+                ck_new = jax.vmap(upd_chunk)(ck, k, pos0, valid)
+                cv_new = jax.vmap(upd_chunk)(cv, v, pos0, valid)
+                ck = jnp.where(active[:, None, None, None], ck_new, ck)
+                cv = jnp.where(active[:, None, None, None], cv_new, cv)
+            # chunk lanes attend to everything written up to their own
+            # position (the chunk's k/v are already in the cache, so this
+            # is causal intra-chunk attention + full attention to the
+            # prefix)
+            scores = jnp.einsum("bhcd,bhtd->bhct", q, ck,
+                                preferred_element_type=jnp.float32)
+            scores = scores / math.sqrt(Dh)
+            t_idx = jnp.arange(T)[None, None, None, :]
+            scores = jnp.where(t_idx <= pos[:, None, :, None], scores,
+                               -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhct,bhtd->bhcd", probs, cv)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, C, H * Dh)
+            attn = attn @ _w(bp["attn"]["wo"], cfg) + \
+                _w(bp["attn"]["bo"], cfg)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = lax.scan(layer, x,
-                                 (params["blocks"], cache["k"], cache["v"]))
-    last = jnp.clip(length - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    x_last = _layer_norm(x_last, params["ln_f"])
-    logits = (x_last @ wte.T.astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("layers"):         # as in decode_step
+        x, (new_k, new_v) = lax.scan(
+            layer, x, (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("unembed_loss"):
+        last = jnp.clip(length - 1, 0, C - 1)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        x_last = _layer_norm(x_last, params["ln_f"])
+        logits = (x_last @ _w(wte.T, cfg)).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

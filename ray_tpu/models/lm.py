@@ -15,10 +15,12 @@ def split_lm_batch(batch: dict):
 
 def cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean next-token cross-entropy; logits upcast to f32 for the softmax."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    with jax.named_scope("unembed_loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1)[..., 0]
+        return jnp.mean(logz - gold)
 
 
 def resolve_attn_impl(attn_impl: str, seq_len: int) -> str:

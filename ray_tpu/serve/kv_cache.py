@@ -74,23 +74,24 @@ class PagedKVCache:
         self._hash_of_block: Dict[int, bytes] = {}
         # counters (tests + /stats)
         self.hits = 0
-        self.misses = 0
         self.tokens_reused = 0
         self.blocks_evicted = 0
 
         L, N, H, Bs, Dh = shape
 
         def _copy_out(pool, cache, slot, t0, blk):
-            data = jax.lax.dynamic_slice(
-                cache, (0, slot, 0, t0, 0), (L, 1, H, Bs, Dh))
-            return jax.lax.dynamic_update_slice(
-                pool, data.reshape(L, 1, H, Bs, Dh), (0, blk, 0, 0, 0))
+            with jax.named_scope("prefix_pool"):
+                data = jax.lax.dynamic_slice(
+                    cache, (0, slot, 0, t0, 0), (L, 1, H, Bs, Dh))
+                return jax.lax.dynamic_update_slice(
+                    pool, data.reshape(L, 1, H, Bs, Dh), (0, blk, 0, 0, 0))
 
         def _copy_in(cache, pool, slot, t0, blk):
-            data = jax.lax.dynamic_slice(
-                pool, (0, blk, 0, 0, 0), (L, 1, H, Bs, Dh))
-            return jax.lax.dynamic_update_slice(
-                cache, data, (0, slot, 0, t0, 0))
+            with jax.named_scope("prefix_pool"):
+                data = jax.lax.dynamic_slice(
+                    pool, (0, blk, 0, 0, 0), (L, 1, H, Bs, Dh))
+                return jax.lax.dynamic_update_slice(
+                    cache, data, (0, slot, 0, t0, 0))
 
         self._copy_out = jax.jit(_copy_out, donate_argnums=(0,))
         self._copy_in = jax.jit(_copy_in, donate_argnums=(0,))
@@ -134,8 +135,6 @@ class PagedKVCache:
         if blocks:
             self.hits += 1
             self.tokens_reused += n
-        else:
-            self.misses += 1
         return n, blocks
 
     # ----------------------------------------------------------- eviction
@@ -190,10 +189,8 @@ class PagedKVCache:
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
-        return {"blocks_total": self.num_blocks,
-                "blocks_used": self.num_blocks - len(self._free),
-                "block_size": self.block_size,
-                "prefix_hits": self.hits, "prefix_misses": self.misses,
+        return {"blocks_used": self.num_blocks - len(self._free),
+                "prefix_hits": self.hits,
                 "tokens_reused": self.tokens_reused,
                 "blocks_evicted": self.blocks_evicted}
 

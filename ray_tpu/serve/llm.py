@@ -19,8 +19,61 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import uuid
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional
+
+from ray_tpu.util import tracing
+
+# What the engine's thread does, in the order of one pass of its loop.
+# Each is an `engine.<phase>` span on the profiler's clock and a
+# cumulative timer in `engine_stats()["phase_s"]`.
+ENGINE_PHASES = ("calls", "admit", "plan", "dispatch", "fetch", "sample",
+                 "publish", "notify", "empty")
+# seconds; shared by the two request-lifecycle histograms
+_LIFECYCLE_BOUNDARIES = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                         0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
+_lifecycle_metrics = None
+
+
+def _get_lifecycle_metrics() -> dict:
+    """The process's two request-lifecycle histograms, for `/metrics`. An
+    engine keeps its own count and sum beside them (`engine_stats()`)."""
+    global _lifecycle_metrics
+    if _lifecycle_metrics is None:
+        from ray_tpu.util import metrics as m
+
+        _lifecycle_metrics = {
+            "queue_wait_s": m.Histogram(
+                "serve_engine_queue_wait_seconds",
+                "Request handed to the engine -> placed in a decode slot",
+                boundaries=_LIFECYCLE_BOUNDARIES),
+            "ttft_s": m.Histogram(
+                "serve_engine_ttft_seconds",
+                "Request handed to the engine -> its first generated token",
+                boundaries=_LIFECYCLE_BOUNDARIES),
+        }
+    return _lifecycle_metrics
+
+
+class _Phase:
+    """One engine phase as a context: an `engine.<name>` annotation around
+    a cumulative `perf_counter` timer. Phases never nest and only the
+    engine's thread enters them, so an engine reuses one object a phase."""
+
+    __slots__ = ("_acc", "_name", "_label", "_ann", "_t0")
+
+    def __init__(self, acc: Dict[str, float], name: str):
+        self._acc, self._name, self._label = acc, name, f"engine.{name}"
+
+    def __enter__(self):
+        self._ann = tracing.annotate(self._label)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._acc[self._name] += time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
 
 
 class ByteTokenizer:
@@ -83,8 +136,17 @@ class _Request:
         # streaming consumers: wakes on every appended token batch
         self.progress = threading.Condition()
         self._sent_text = ""  # cumulative text already shipped to the consumer
+        # lifecycle, `time.time()`: handed to the engine, placed in a
+        # slot, first generated token (TTFT), last one
         self.t_enqueue = time.time()
-        self.t_first: Optional[float] = None   # first generated token (TTFT)
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.t_done: Optional[float] = None
+        self.reused_tokens = 0       # prompt tokens found in the prefix pool
+        # the caller's span context when it records (the replica's
+        # `serve.replica` span): the engine's thread is not the
+        # request's, so its spans are written at completion under this
+        self.trace_carrier = tracing.inject_context()
 
 
 def plan_chunk_budget(pending_lens: List[int], decoding: List[bool],
@@ -221,8 +283,6 @@ class LLMEngine:
         if weights_id is not None:
             self.weights_id = weights_id
         elif params_override is not None:
-            import uuid
-
             self.weights_id = f"override-{uuid.uuid4().hex[:12]}"
         else:
             self.weights_id = checkpoint or f"{preset}@seed{seed}"
@@ -314,7 +374,6 @@ class LLMEngine:
         self._deferred: List[_Request] = []
         self._ready: List[_Request] = []
         self._stop = threading.Event()
-        self._stats_lock = threading.Lock()
         self.total_generated = 0
         self.engine_steps = 0          # jitted step calls (either kind)
         self.chunk_steps = 0           # steps that ran the chunked program
@@ -322,9 +381,15 @@ class LLMEngine:
         self.prefix_imports = 0        # deferred blobs installed
         self.prefix_blocks_imported = 0
         self.prefix_wait_timeouts = 0  # deadline hit: local prefill
-        self.ttft_sum = 0.0            # submit -> first generated token
-        self.ttft_count = 0
-        self.last_ttft_s = 0.0
+        self.last_ttft_s = 0.0         # submit -> first generated token
+        # cumulative, so a reader takes deltas over its own window
+        self.phase_s: Dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
+        self._phase = {n: _Phase(self.phase_s, n) for n in ENGINE_PHASES}
+        self.loop_busy_s = 0.0         # loop passes that ran a step
+        # request lifecycle: observations and their sum, in seconds
+        self._stats_lock = threading.Lock()
+        self.lifecycle = {"queue_wait_s": {"count": 0, "sum": 0.0},
+                          "ttft_s": {"count": 0, "sum": 0.0}}
         # callables other threads need run ON the engine thread (the KV
         # pool is engine-owned, unlocked state: exports must not race
         # _alloc's block eviction/reuse)
@@ -389,8 +454,6 @@ class LLMEngine:
                      prefix_wait_s: float = 30.0) -> str:
         """Admit a request for incremental consumption via stream_next
         (the engine path behind OpenAI `stream: true`)."""
-        import uuid
-
         req = self._make_request(prompt, prompt_ids, max_tokens,
                                  temperature, top_k, top_p,
                                  prefix_future=prefix_future,
@@ -587,6 +650,8 @@ class LLMEngine:
                 pass        # bad blob: local prefill is always correct
 
     def _place(self, i: int, req: _Request) -> None:
+        req.t_admit = time.time()
+        self._observe("queue_wait_s", req.t_admit - req.t_enqueue)
         self._slots[i] = req
         self._slot_pos[i] = 0
         self._slot_prefill[i] = list(req.prompt_ids)
@@ -601,6 +666,7 @@ class LLMEngine:
                 self._slot_pos[i] = n_hit
                 self._slot_prefill[i] = list(
                     req.prompt_ids[n_hit:])
+                req.reused_tokens = n_hit
 
     def _sweep_streams(self) -> None:
         """Expire abandoned stream entries (client vanished): the sweep
@@ -615,51 +681,63 @@ class LLMEngine:
 
         rng = np.random.default_rng(0)
         last_sweep = time.time()
+        phase = self._phase
+        t_pass = time.perf_counter()
         while not self._stop.is_set():
             if time.time() - last_sweep > 60:
                 last_sweep = time.time()
                 self._sweep_streams()
             # marshalled work (KV exports) runs between steps: the pool
             # can't mutate under an export that shares this thread
-            for _ in range(8):
-                try:
-                    fn = self._engine_calls.get_nowait()
-                except queue.Empty:
-                    break
-                try:
-                    fn()
-                except Exception:
-                    pass
-            self._admit()
+            with phase["calls"]:
+                for _ in range(8):
+                    try:
+                        fn = self._engine_calls.get_nowait()
+                    except queue.Empty:
+                        break
+                    try:
+                        fn()
+                    except Exception:
+                        pass
+            with phase["admit"]:
+                self._admit()
             live = [i for i, r in enumerate(self._slots) if r is not None]
             if not live:
-                time.sleep(0.005)
-                continue
-            prefilling = any(self._slot_prefill[i] for i in live)
-            if prefilling and self._chunk_step is not None:
-                self._run_chunk_step(live, rng, np)
+                with phase["empty"]:
+                    time.sleep(0.005)
+                stepped = False
+            elif (self._chunk_step is not None
+                    and any(self._slot_prefill[i] for i in live)):
+                stepped = self._run_chunk_step(live, rng, np)
             else:
-                self._run_decode_step(live, rng, np)
+                stepped = self._run_decode_step(live, rng, np)
+            now = time.perf_counter()
+            if stepped:
+                self.loop_busy_s += now - t_pass
+            t_pass = now
 
     def _run_decode_step(self, live, rng, np):
         """One single-token step for every live slot (the pure-decode fast
         path; also the only step the fixed scheduler ever runs)."""
-        jnp = self.jnp
-        tokens = np.zeros((self.max_batch,), np.int32)
-        pos = np.asarray(self._slot_pos, np.int32)
-        active = np.zeros((self.max_batch,), bool)
-        for i in live:
-            active[i] = True
-            if self._slot_prefill[i]:
-                tokens[i] = self._slot_prefill[i][0]
-            else:
-                tokens[i] = (self._slots[i].generated[-1]
-                             if self._slots[i].generated
-                             else self._slots[i].prompt_ids[-1])
-        logits, self.cache = self._step(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(pos), jnp.asarray(active))
-        logits = np.asarray(logits)
+        jnp, phase = self.jnp, self._phase
+        with phase["plan"]:
+            tokens = np.zeros((self.max_batch,), np.int32)
+            pos = np.asarray(self._slot_pos, np.int32)
+            active = np.zeros((self.max_batch,), bool)
+            for i in live:
+                active[i] = True
+                if self._slot_prefill[i]:
+                    tokens[i] = self._slot_prefill[i][0]
+                else:
+                    tokens[i] = (self._slots[i].generated[-1]
+                                 if self._slots[i].generated
+                                 else self._slots[i].prompt_ids[-1])
+        with phase["dispatch"]:
+            logits, self.cache = self._step(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(pos), jnp.asarray(active))
+        with phase["fetch"]:
+            logits = np.asarray(logits)
         self.engine_steps += 1
         for i in live:
             req = self._slots[i]
@@ -673,48 +751,57 @@ class LLMEngine:
                     # prompt fully resident in this slot's cache:
                     # publish its full blocks for future prefix hits
                     # (dedup'd: shared prefixes stored once)
-                    self.kv.store_prefix(req.prompt_ids, self.cache, i)
+                    with phase["publish"]:
+                        self.kv.store_prefix(req.prompt_ids,
+                                             self.cache, i)
             self._finish_token(i, req, logits[i], rng, np)
+        return True
 
     def _run_chunk_step(self, live, rng, np):
         """One token-budget step: decode slots advance one token each
         (reserved first), prefilling slots consume up to a chunk of their
         remaining prompt — all in ONE fused prefill_chunk call."""
-        jnp = self.jnp
+        jnp, phase = self.jnp, self._phase
         B, C = self.max_batch, self.prefill_chunk_size
-        pending = [len(self._slot_prefill[i]) if self._slots[i] is not None
-                   else 0 for i in range(B)]
-        decoding = [self._slots[i] is not None and not self._slot_prefill[i]
-                    for i in range(B)]
-        takes = plan_chunk_budget(pending, decoding, C,
-                                  self.max_num_batched_tokens)
-        tokens = np.zeros((B, C), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        for i in live:
-            take = takes[i]
-            if take <= 0:
-                continue
-            # never step past the serving window (prefill_chunk requires
-            # pos0 + length <= T; _make_request already bounds prompts)
-            take = min(take, self.max_seq_len - self._slot_pos[i])
-            if take <= 0:
-                continue
-            lengths[i] = take
-            if self._slot_prefill[i]:
-                tokens[i, :take] = self._slot_prefill[i][:take]
-            else:
-                req = self._slots[i]
-                tokens[i, 0] = (req.generated[-1] if req.generated
-                                else req.prompt_ids[-1])
-        active = lengths > 0
+        with phase["plan"]:
+            pending = [len(self._slot_prefill[i])
+                       if self._slots[i] is not None else 0
+                       for i in range(B)]
+            decoding = [self._slots[i] is not None
+                        and not self._slot_prefill[i] for i in range(B)]
+            takes = plan_chunk_budget(pending, decoding, C,
+                                      self.max_num_batched_tokens)
+            tokens = np.zeros((B, C), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            for i in live:
+                take = takes[i]
+                if take <= 0:
+                    continue
+                # never step past the serving window (prefill_chunk
+                # requires pos0 + length <= T; _make_request already
+                # bounds prompts)
+                take = min(take, self.max_seq_len - self._slot_pos[i])
+                if take <= 0:
+                    continue
+                lengths[i] = take
+                if self._slot_prefill[i]:
+                    tokens[i, :take] = self._slot_prefill[i][:take]
+                else:
+                    req = self._slots[i]
+                    tokens[i, 0] = (req.generated[-1] if req.generated
+                                    else req.prompt_ids[-1])
+            active = lengths > 0
         if not active.any():
-            time.sleep(0.001)
-            return
-        logits, self.cache = self._chunk_step(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(np.asarray(self._slot_pos, np.int32)),
-            jnp.asarray(lengths), jnp.asarray(active))
-        logits = np.asarray(logits)
+            with phase["empty"]:
+                time.sleep(0.001)
+            return False
+        with phase["dispatch"]:
+            logits, self.cache = self._chunk_step(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(np.asarray(self._slot_pos, np.int32)),
+                jnp.asarray(lengths), jnp.asarray(active))
+        with phase["fetch"]:
+            logits = np.asarray(logits)
         self.engine_steps += 1
         self.chunk_steps += 1
         for i in live:
@@ -729,69 +816,97 @@ class LLMEngine:
                 if self._slot_prefill[i]:
                     continue  # chunk didn't cover the prompt yet
                 if self.kv is not None:
-                    self.kv.store_prefix(req.prompt_ids, self.cache, i)
+                    with phase["publish"]:
+                        self.kv.store_prefix(req.prompt_ids,
+                                             self.cache, i)
             # the chunk ended at the prompt's final token (or a decode
             # lane): its last-position logits seed/continue generation
             self._finish_token(i, req, logits[i], rng, np)
+        return True
 
     def _finish_token(self, i, req, logit_row, rng, np):
         """Sample one token from `logit_row`, append it, and evict the
         slot the moment the request finishes (its KV slot frees for the
         next admit — same tick)."""
-        if req.temperature > 0:
-            lg = logit_row / req.temperature
-            if req.top_k and req.top_k < len(lg):
-                kth = np.partition(lg, -req.top_k)[-req.top_k]
-                lg = np.where(lg < kth, -np.inf, lg)
-            p = np.exp(lg - lg.max())
-            p /= p.sum()
-            if req.top_p < 1.0:
-                order = np.argsort(p)[::-1]
-                # standard nucleus: smallest set whose mass reaches
-                # top_p — keep a token if the mass BEFORE it is
-                # still short of the threshold (inclusive of the
-                # one that crosses it)
-                csum = np.cumsum(p[order])
-                keep = (csum - p[order]) < req.top_p
-                mask = np.zeros_like(p, bool)
-                mask[order[keep]] = True
-                p = np.where(mask, p, 0.0)
-                p /= p.sum()
-            nxt = int(rng.choice(len(p), p=p))
-        else:
-            nxt = int(np.argmax(logit_row))
-        if req.t_first is None:
-            req.t_first = time.time()
-            with self._stats_lock:
+        with self._phase["sample"]:
+            nxt = self._sample(req, logit_row, rng, np)
+        with self._phase["notify"]:
+            if req.t_first is None:
+                req.t_first = time.time()
                 self.last_ttft_s = req.t_first - req.t_enqueue
-                self.ttft_sum += self.last_ttft_s
-                self.ttft_count += 1
-        req.generated.append(nxt)
-        self.total_generated += 1
-        finished = (len(req.generated) >= req.max_tokens
-                    or nxt == self.tokenizer.eos_id
-                    or self._slot_pos[i] >= self.max_seq_len - 1)
-        if finished:
-            req.finish_reason = ("stop" if nxt == self.tokenizer.eos_id
-                                 else "length")
-            self._slots[i] = None
-            req.done.set()
-        with req.progress:
-            req.progress.notify_all()
+                self._observe("ttft_s", self.last_ttft_s)
+            req.generated.append(nxt)
+            self.total_generated += 1
+            finished = (len(req.generated) >= req.max_tokens
+                        or nxt == self.tokenizer.eos_id
+                        or self._slot_pos[i] >= self.max_seq_len - 1)
+            if finished:
+                req.finish_reason = ("stop" if nxt == self.tokenizer.eos_id
+                                     else "length")
+                self._slots[i] = None
+                req.t_done = time.time()
+                if req.trace_carrier is not None:
+                    self._record_request_spans(req)
+                req.done.set()
+            with req.progress:
+                req.progress.notify_all()
+
+    @staticmethod
+    def _sample(req, logit_row, rng, np) -> int:
+        if req.temperature <= 0:
+            return int(np.argmax(logit_row))
+        lg = logit_row / req.temperature
+        if req.top_k and req.top_k < len(lg):
+            kth = np.partition(lg, -req.top_k)[-req.top_k]
+            lg = np.where(lg < kth, -np.inf, lg)
+        p = np.exp(lg - lg.max())
+        p /= p.sum()
+        if req.top_p < 1.0:
+            order = np.argsort(p)[::-1]
+            # standard nucleus: smallest set whose mass reaches top_p —
+            # keep a token if the mass BEFORE it is still short of the
+            # threshold (inclusive of the one that crosses it)
+            csum = np.cumsum(p[order])
+            keep = (csum - p[order]) < req.top_p
+            mask = np.zeros_like(p, bool)
+            mask[order[keep]] = True
+            p = np.where(mask, p, 0.0)
+            p /= p.sum()
+        return int(rng.choice(len(p), p=p))
+
+    @staticmethod
+    def _record_request_spans(req: _Request) -> None:
+        """A finished request's three stretches as children of the span
+        its caller was in: a client's `traceparent` follows the request
+        to its last token."""
+        attrs = {"prompt_tokens": len(req.prompt_ids),
+                 "reused_tokens": req.reused_tokens,
+                 "generated": len(req.generated)}
+        for name, t0, t1 in (
+                ("engine.queue_wait", req.t_enqueue, req.t_admit),
+                ("engine.prefill", req.t_admit, req.t_first),
+                ("engine.decode", req.t_first, req.t_done)):
+            tracing.record_span(name, t0, t1, carrier=req.trace_carrier,
+                                attributes=attrs)
+
+    def _observe(self, name: str, seconds: float) -> None:
+        """One request's `queue_wait_s` or `ttft_s`: into the engine's own
+        count and sum, and into the process's histogram for `/metrics`."""
+        with self._stats_lock:
+            own = self.lifecycle[name]
+            own["count"] += 1
+            own["sum"] += seconds
+        _get_lifecycle_metrics()[name].observe(seconds)
 
     def engine_stats(self) -> dict:
-        with self._stats_lock:
-            ttft_avg = (self.ttft_sum / self.ttft_count
-                        if self.ttft_count else 0.0)
-            last_ttft = self.last_ttft_s
         from ray_tpu.utils.platform import device_report
 
-        return {"scheduler": self.scheduler,
-                # what this engine's process runs JAX on
+        with self._stats_lock:
+            queue_wait = dict(self.lifecycle["queue_wait_s"])
+            ttft = dict(self.lifecycle["ttft_s"])
+        ttft_avg = ttft["sum"] / ttft["count"] if ttft["count"] else 0.0
+        return {# what this engine's process runs JAX on
                 "devices": device_report(),
-                "max_batch": self.max_batch,
-                "prefill_chunk_size": self.prefill_chunk_size,
-                "max_num_batched_tokens": self.max_num_batched_tokens,
                 "total_generated": self.total_generated,
                 "engine_steps": self.engine_steps,
                 "chunk_steps": self.chunk_steps,
@@ -799,11 +914,14 @@ class LLMEngine:
                 "prefix_imports": self.prefix_imports,
                 "prefix_blocks_imported": self.prefix_blocks_imported,
                 "prefix_wait_timeouts": self.prefix_wait_timeouts,
-                "queued": self._queue.qsize(),
                 "deferred": len(self._deferred),
-                "slots_busy": sum(r is not None for r in self._slots),
                 "ttft_avg_s": round(ttft_avg, 6),
-                "last_ttft_s": round(last_ttft, 6)}
+                "last_ttft_s": round(self.last_ttft_s, 6),
+                # cumulative since the engine started: read deltas
+                "phase_s": dict(self.phase_s),
+                "loop_busy_s": self.loop_busy_s,
+                "queue_wait_s": queue_wait,
+                "ttft_s": ttft}
 
 
 class LLMServer:
@@ -829,11 +947,6 @@ class LLMServer:
         self._prefix_pool = None
         self._prefix_pool_lock = threading.Lock()
         self._chain_pool = None
-        import uuid
-
-        # distinguishes replicas when a caller aggregates stats() rows
-        # sampled through a load-balanced handle
-        self.server_id = uuid.uuid4().hex[:12]
 
     # ------------------------------------------------- cluster prefix tier
     def _prefix_submit(self, fn, *args):
@@ -942,7 +1055,6 @@ class LLMServer:
 
     def stats(self) -> dict:
         out = self.engine.engine_stats()
-        out["server_id"] = self.server_id
         if self.engine.kv is not None:
             out["kv_cache"] = self.engine.kv.stats()
         if self.prefix_store is not None:

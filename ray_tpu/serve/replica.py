@@ -55,7 +55,7 @@ class ReplicaActor:
         # publish on ADMIT as well as completion: live-signal routing and
         # admission control read the gossiped queue depth, which must
         # rise while a burst is still executing, not after it drains
-        self._publish_load(self._ewma_latency_s)
+        self._publish_load()
         t0 = time.perf_counter()
         try:
             from ray_tpu.serve import multiplex
@@ -97,9 +97,9 @@ class ReplicaActor:
                 self._ewma_latency_s = (
                     dur if self._latency_samples == 1
                     else 0.9 * self._ewma_latency_s + 0.1 * dur)
-            self._publish_load(dur)
+            self._publish_load()
 
-    def _publish_load(self, last_latency_s: float) -> None:
+    def _publish_load(self) -> None:
         """Queue depth / in-flight / EWMA latency, published two ways on
         the SAME existing telemetry channel (the per-process metrics
         push — zero new RPCs): gauges for `/metrics` and a workload row
@@ -129,7 +129,6 @@ class ReplicaActor:
                 "queue_depth": self._ongoing,
                 "inflight": self._executing,
                 "ewma_latency_s": round(self._ewma_latency_s, 6),
-                "last_latency_s": round(last_latency_s, 6),
                 "total": self._total,
             }
             # deployment-specific routing hints (e.g. a prefill replica's
@@ -229,18 +228,16 @@ class ReplicaActor:
                         # client's failover check must see them directly
                         if c is None or is_chain_error(out[i]):
                             continue
-                        with tracing.start_span(
-                                f"chain.stage.{self.deployment_name}",
-                                carrier=c,
-                                attributes={"ray_tpu.op": "chain_stage",
-                                            "replica": self.replica_tag,
-                                            "batch": n}) as sp:
-                            if sp is not None:
-                                # backdate to cover the whole stage exec
-                                sp.start_ts = wall_end - stage_dur
-                                out[i] = TracedValue(
-                                    {"traceparent": sp.traceparent()},
-                                    out[i])
+                        # written after the fact: the whole stage exec
+                        sp = tracing.record_span(
+                            f"chain.stage.{self.deployment_name}",
+                            wall_end - stage_dur, wall_end, carrier=c,
+                            attributes={"ray_tpu.op": "chain_stage",
+                                        "replica": self.replica_tag,
+                                        "batch": n})
+                        if sp is not None:
+                            out[i] = TracedValue(
+                                {"traceparent": sp.traceparent()}, out[i])
                 except Exception:
                     pass
             return out
@@ -260,7 +257,7 @@ class ReplicaActor:
             now = time.monotonic()
             if now - getattr(self, "_chain_pub_ts", 0.0) > 1.0:
                 self._chain_pub_ts = now
-                self._publish_load(dur)
+                self._publish_load()
 
     def _report_models(self, model_ids):
         """Push the loaded-model set so routers prefer warm replicas."""

@@ -14,6 +14,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 _step_metrics = None
 
@@ -122,18 +123,13 @@ def _record_step(ctx: TrainContext, step_s: float, now: float) -> None:
     """Step telemetry is best-effort — it must never fail a run."""
     try:
         from ray_tpu.util import metrics as m
-        from ray_tpu.util import tracing
 
         ctx._ewma_step_s = (0.8 * ctx._ewma_step_s + 0.2 * step_s
                             if ctx._ewma_step_s > 0 else step_s)
-        if tracing.is_recording():
-            with tracing.start_span(
-                    "train.step",
-                    attributes={"ray_tpu.op": "train_step",
-                                "run": ctx.run_name, "rank": ctx.rank,
-                                "step": ctx._step_idx}) as sp:
-                if sp is not None:
-                    sp.start_ts = now - step_s
+        tracing.record_span(
+            "train.step", now - step_s, now,
+            attributes={"ray_tpu.op": "train_step", "run": ctx.run_name,
+                        "rank": ctx.rank, "step": ctx._step_idx})
         _get_step_metrics().observe(
             step_s, tags={"run": ctx.run_name, "rank": str(ctx.rank)})
         m.publish_workload(

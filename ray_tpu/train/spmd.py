@@ -52,6 +52,16 @@ def default_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
     )
 
 
+def _apply_optimizer(optimizer, state: "TrainState", grads):
+    """(new params, new optimizer state, gradient norm), under the
+    `optimizer` scope of the step program's operation names."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, state.opt_state,
+                                              state.params)
+        return (optax.apply_updates(state.params, updates), opt_state,
+                optax.global_norm(grads))
+
+
 def state_shardings(state_shape: Any, params_spec: Any, mesh: Mesh) -> Any:
     """Shard params by spec; shard opt-state subtrees that mirror the param
     tree (adam mu/nu etc., matched by tree STRUCTURE, not leaf shape — two
@@ -324,11 +334,10 @@ def _timed_hier_step(loss_fn, mesh: Mesh, topo, params_spec, batch_spec,
             grads = jax.tree.map(
                 lambda t, g: g.astype(t.dtype), state.params,
                 unravel(synced[:n_grads] / world))
-            updates, opt_state = optimizer.update(
-                grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            params, opt_state, grad_norm = _apply_optimizer(
+                optimizer, state, grads)
             return (TrainState(state.step + 1, params, opt_state),
-                    optax.global_norm(grads))
+                    grad_norm)
 
     rep = NamedSharding(mesh, P())
     apply_prog = jax.jit(
@@ -462,12 +471,11 @@ def compile_train(
                 else:
                     loss, grads = fused_sync(state.params, batch,
                                              state.step)
-                updates, opt_state = optimizer.update(
-                    grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
+                params, opt_state, grad_norm = _apply_optimizer(
+                    optimizer, state, grads)
                 metrics = {
                     "loss": loss,
-                    "grad_norm": optax.global_norm(grads),
+                    "grad_norm": grad_norm,
                     "step": state.step + 1,
                 }
                 out = TrainState(state.step + 1, params, opt_state)
@@ -508,11 +516,11 @@ def compile_train(
         def _step(state: TrainState, batch):
             with mesh_lib.use_mesh(mesh, rules):
                 loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-                updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
+                params, opt_state, grad_norm = _apply_optimizer(
+                    optimizer, state, grads)
                 metrics = {
                     "loss": loss,
-                    "grad_norm": optax.global_norm(grads),
+                    "grad_norm": grad_norm,
                     "step": state.step + 1,
                 }
                 return TrainState(state.step + 1, params, opt_state), metrics
@@ -536,9 +544,7 @@ def compile_train(
 
     def _apply(state: TrainState, grads):
         with mesh_lib.use_mesh(mesh, rules):
-            updates, opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-            params = optax.apply_updates(state.params, updates)
+            params, opt_state, _ = _apply_optimizer(optimizer, state, grads)
             return TrainState(state.step + 1, params, opt_state)
 
     apply_fn = jax.jit(

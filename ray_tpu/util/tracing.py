@@ -6,12 +6,18 @@ submission span and injects a W3C `traceparent` into the task spec; the
 executing worker extracts it and opens a child execution span, so one trace
 follows a task across processes.
 
-This image ships only `opentelemetry-api` (no SDK), so the tracer here is
-self-contained: 128-bit trace ids, 64-bit span ids, W3C traceparent
-inject/extract, finished spans buffered in-process (drain with
-`get_finished_spans()` or hand them to any exporter object with an
-`export(spans)` method). When the OpenTelemetry SDK *is* installed, spans
-are mirrored through it automatically.
+The tracer is self-contained and imports nothing of OpenTelemetry:
+128-bit trace ids, 64-bit span ids, W3C traceparent inject/extract,
+finished spans buffered in-process (drain with `get_finished_spans()`) and
+queued for the metrics push to the head, which merges every process's
+spans into `timeline()`. An exporter object with an `export(spans)`
+method, handed to `enable_tracing`, is called at each span end; that is
+the one way out of the process besides the push, and nothing is mirrored
+to an OpenTelemetry SDK.
+
+Two clocks. `Span`s carry `time.time()`. The serving engine's loop also
+opens `annotate(...)` spans on the JAX profiler's clock, which a device
+trace shares. The vocabulary is in the README's observability section.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ import dataclasses
 import os
 from ray_tpu.core import config as _config
 import secrets
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -113,57 +120,101 @@ def start_span(name: str, *, carrier: Optional[Dict[str, str]] = None,
     request's children record in every process it crosses without
     flipping any process-wide switch — per-request tracing stays
     per-request."""
-    parent_trace = parent_span = None
-    carrier_sampled = False
-    if carrier and "traceparent" in carrier:
-        # strict parse: a malformed header (LBs and APM agents inject
-        # these freely) must NOT force recording, and neither must a
-        # valid one whose W3C sampled flag is 00
-        try:
-            _, t, s, flags = carrier["traceparent"].split("-")
-        except ValueError:
-            t = s = flags = None
-        if t and len(t) == 32 and s and len(s) == 16:
-            parent_trace, parent_span = t, s
-            carrier_sampled = flags != "00"
-    if not (is_enabled() or _current.get() is not None or carrier_sampled):
+    span = _new_span(name, carrier, attributes, time.time())
+    if span is None:
         yield None
         return
-    if parent_trace is None:
-        cur = _current.get()
-        if cur is not None:
-            parent_trace, parent_span = cur.trace_id, cur.span_id
-    span = Span(name=name,
-                trace_id=parent_trace or secrets.token_hex(16),
-                span_id=secrets.token_hex(8),
-                parent_id=parent_span,
-                attributes=dict(attributes or {}),
-                start_ts=time.time())
     token = _current.set(span)
     try:
         yield span
     finally:
         _current.reset(token)
         span.end_ts = time.time()
-        cap = max(int(_config.get("tracing_buffer_spans")), 2)
-        dropped = 0
-        with _lock:
-            _finished.append(span)
-            if len(_finished) > cap:
-                # drop the oldest half: amortized O(1) per span, and the
-                # newest spans are the ones a live debugging session needs
-                del _finished[:cap // 2]
-            _push_queue.append(span.to_dict())
-            if len(_push_queue) > cap:
-                dropped = cap // 2
-                del _push_queue[:dropped]
-        if dropped:
-            _count_dropped(dropped)
-        if _exporter is not None:
-            try:
-                _exporter.export([span])
-            except Exception:
-                pass
+        _finish(span)
+
+
+def _parse_carrier(carrier: Optional[Dict[str, str]]):
+    """(trace id, parent span id, sampled) of a W3C carrier; Nones and
+    False for none or a malformed one. Strict: a malformed header (LBs
+    and APM agents inject these freely) must NOT force recording, and
+    neither must a valid one whose W3C sampled flag is 00."""
+    if not carrier or "traceparent" not in carrier:
+        return None, None, False
+    try:
+        _, t, s, flags = carrier["traceparent"].split("-")
+    except ValueError:
+        return None, None, False
+    if len(t) != 32 or len(s) != 16:
+        return None, None, False
+    return t, s, flags != "00"
+
+
+def _new_span(name: str, carrier: Optional[Dict[str, str]],
+              attributes: Optional[dict], start_ts: float) -> Optional[Span]:
+    """A span parented to `carrier` if it names one, else to the current
+    span; None when nothing records (see `start_span`)."""
+    parent_trace, parent_span, sampled = _parse_carrier(carrier)
+    cur = _current.get()
+    if not (is_enabled() or cur is not None or sampled):
+        return None
+    if parent_trace is None and cur is not None:
+        parent_trace, parent_span = cur.trace_id, cur.span_id
+    return Span(name=name,
+                trace_id=parent_trace or secrets.token_hex(16),
+                span_id=secrets.token_hex(8), parent_id=parent_span,
+                attributes=dict(attributes or {}), start_ts=start_ts)
+
+
+def _finish(span: Span) -> None:
+    """A finished span into the in-process buffer, the push queue and the
+    exporter."""
+    cap = max(int(_config.get("tracing_buffer_spans")), 2)
+    dropped = 0
+    with _lock:
+        _finished.append(span)
+        if len(_finished) > cap:
+            # drop the oldest half: amortized O(1) per span, and the
+            # newest spans are the ones a live debugging session needs
+            del _finished[:cap // 2]
+        _push_queue.append(span.to_dict())
+        if len(_push_queue) > cap:
+            dropped = cap // 2
+            del _push_queue[:dropped]
+    if dropped:
+        _count_dropped(dropped)
+    if _exporter is not None:
+        try:
+            _exporter.export([span])
+        except Exception:
+            pass
+
+
+def record_span(name: str, start_ts: float, end_ts: float,
+                carrier: Optional[Dict[str, str]] = None,
+                attributes: Optional[dict] = None) -> Optional[Span]:
+    """A finished span with explicit times, for work whose thread is not
+    the request's (the serving engine writes a request's spans when it
+    completes) or whose start is known only at its end (a train step is
+    the window between two reports). Parents and records as `start_span`
+    does: to `carrier` if given, else to the current span; None when
+    nothing records. Never becomes current."""
+    span = _new_span(name, carrier, attributes, start_ts)
+    if span is not None:
+        span.end_ts = end_ts
+        _finish(span)
+    return span
+
+
+def annotate(name: str):
+    """A span on the JAX profiler's clock, the one a device trace is on:
+    the only way hot-path code opens one. Written to a running profile
+    and to nothing else; with no profile running it costs one flag check.
+    A null context in a process that has not imported JAX (the proxy and
+    the head never do, and must not for this)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def _count_dropped(n: int) -> None:

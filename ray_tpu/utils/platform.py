@@ -47,12 +47,23 @@ def enable_compile_cache() -> str:
     """Point this process — and, through the environment, every child it
     starts — at `compile_cache_dir()`. Called at start-up by every process
     that compiles (workers before user code, bench.py, chip_smoke.py's
-    phases); imports no JAX itself, since JAX reads the variable when it is
-    imported."""
+    phases); imports no JAX itself, since JAX reads the variables when it
+    is imported.
+
+    The cache's key takes in each program's metadata (operation names with
+    their `jax.named_scope`s, source lines). JAX leaves it out by default,
+    and an executable found in the cache then carries the names of whichever
+    version of the code compiled it first: a device trace of this version
+    would show another's scopes, or none (PR 23: a parent commit that ran
+    first on a shared cache left the serving programs unnamed). The price is
+    a compile after an edit that moves the model's lines."""
     path = os.environ["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
+    os.environ["JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"] = "true"
     jax = sys.modules.get("jax")
     if jax is not None:
         jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     return path
 
 

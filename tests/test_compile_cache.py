@@ -23,6 +23,8 @@ path = enable_compile_cache()
 import jax
 assert jax.config.jax_compilation_cache_dir == path, (
     jax.config.jax_compilation_cache_dir, path)
+# a cached executable must carry this version's operation names
+assert jax.config.jax_compilation_cache_include_metadata_in_key
 print(path)
 """
 
