@@ -349,10 +349,74 @@ def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
-    """Per-layer KV cache: {"k","v"}: [n_layer, B, H, T, Dh] (compute dtype)."""
+    """KV cache of all layers: {"k","v"}: [n_layer, B, H, T, Dh] (compute
+    dtype). `decode_step` and `prefill_chunk` carry it whole through their
+    loop over the layers and write their new rows into it; the update is in
+    place only where the caller donates the cache to the jitted step
+    (`donate_argnums`), otherwise the program copies it once on entry."""
     T = max_len or cfg.max_seq_len
     shape = (cfg.n_layer, batch, cfg.n_head, T, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+# the narrowest stretch of positions a cache write touches. On the TPU the
+# cache [.., T, Dh] with Dh = 64 lies with T along the 128 lanes of a tile; a
+# narrower update prefers another layout, and the compiler then re-lays the
+# whole cache around the loop to suit it (tests/test_tpu_compile.py)
+_WRITE_WINDOW = 128
+
+
+def _cache_write(c, l, val, pos0, ok):
+    """Layer l of the carried cache c [L,B,H,T,Dh] takes val [B,H,C,Dh]:
+    lane i of slot b goes to position pos0[b] + i where ok[b, i]; nothing
+    else changes. Per slot one window of W >= C positions is read, blended
+    and written back in place: dynamic_update_slice clamps its start near
+    the end of the sequence, so an unmasked block write would smear garbage
+    lanes over valid earlier positions."""
+    _, B, H, T, Dh = c.shape
+    C = val.shape[2]
+    W = min(T, max(C, _WRITE_WINDOW))
+    # a lone row's window starts on a multiple of W, the edge of a tile
+    # there: such a window is written in 7.7 us against 12.5 (PERF.md, PR 24)
+    start = jnp.clip(pos0 // W * W if C == 1 else pos0, 0, T - W)
+    # window lane w (at position start + w) takes val lane w - (pos0 - start)
+    src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [B, W]
+    hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [B, W, C]
+    # the lanes are moved by a 0/1 matrix: exact (one product of 1 a lane),
+    # and a product's result takes the layout its consumer has, where a
+    # gather or a reshape would hand val's own layout on to the whole cache
+    moved = jnp.einsum("bwc,bhcd->bhwd", hit.astype(val.dtype), val,
+                       precision=lax.Precision.HIGHEST)
+    take = hit.any(axis=-1)                                           # [B, W]
+    for b in range(B):
+        at = (l, b, 0, start[b], 0)
+        old = lax.dynamic_slice(c, at, (1, 1, H, W, Dh))
+        new = jnp.where(take[b][:, None], moved[b], old)
+        c = lax.dynamic_update_slice(c, new, at)
+    return c
+
+
+def _cached_layers(layer, x, params: Params, cache):
+    """x through `layer(x, ck, cv, bp, l) -> (x, ck, cv)` for each block
+    bp = params["blocks"][l], ck/cv the whole caches. Returns (x, cache)."""
+    def body(carry, scanned):
+        l, bp = scanned
+        return layer(*carry, bp, l), None
+
+    # the loop CARRIES the hidden state and the whole k and v caches
+    # [L,B,H,T,Dh] and scans the layer's index and weights. A carry is one
+    # buffer from layer to layer, so a layer writes its rows into it and
+    # reads its own slice of it, and with the cache donated by the caller
+    # the step copies and rewrites nothing else of it: scanned in and
+    # stacked out, the caches would be two buffers and every layer's slice
+    # rewritten whole. What the loop does itself (a layer's weights sliced
+    # in, the carries) is `layers`; the layer's own operations keep their
+    # inner scopes
+    with jax.named_scope("layers"):
+        (x, ck, cv), _ = lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (jnp.arange(cache["k"].shape[0]), params["blocks"]))
+    return x, {"k": ck, "v": cv}
 
 
 def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
@@ -363,6 +427,11 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     position), active [B] bool (slots whose cache should advance). Returns
     (logits [B, vocab] f32, new_cache). Inactive slots' caches are untouched
     and their logits are garbage — the engine masks them.
+
+    The cache is the carry of the loop over the layers: a layer writes the
+    [H, Dh] row at pos[b] of each active slot into it (`_cache_write`) and
+    reads its own [B,H,T,Dh] slice once, for the scores and the weighted
+    values. The caller must donate `cache` for that to happen in place.
     """
     B = tokens.shape[0]
     H, Dh = cfg.n_head, cfg.head_dim
@@ -372,52 +441,40 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
         x = wte[tokens] + params["wpe"][
             jnp.clip(pos, 0, cfg.max_seq_len - 1)]
         x = x.astype(cfg.dtype)                               # [B, D]
+    ok = active[:, None]
 
-    def upd_one(c_b, val_b, p_b):
-        # c_b [H, T, Dh], val_b [H, Dh] -> write at position p_b
-        return jax.lax.dynamic_update_slice(
-            c_b, val_b[:, None, :], (0, p_b, 0))
-
-    def layer(x, scanned):
-        bp, ck, cv = scanned                                  # ck/cv [B,H,T,Dh]
+    def layer(x, ck, cv, bp, l):                          # ck/cv [L,B,H,T,Dh]
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1"])
             qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
                 _w(bp["attn"]["bqkv"], cfg)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(B, H, Dh)
-            k = k.reshape(B, H, Dh)
-            v = v.reshape(B, H, Dh)
+            k = k.reshape(B, H, 1, Dh)
+            v = v.reshape(B, H, 1, Dh)
             with jax.named_scope("kv_update"):
-                ck_new = jax.vmap(upd_one)(ck, k, pos)
-                cv_new = jax.vmap(upd_one)(cv, v, pos)
-                ck = jnp.where(active[:, None, None, None], ck_new, ck)
-                cv = jnp.where(active[:, None, None, None], cv_new, cv)
-            scores = jnp.einsum("bhd,bhtd->bht", q, ck,
+                ck = _cache_write(ck, l, k, pos, ok)
+                cv = _cache_write(cv, l, v, pos, ok)
+            scores = jnp.einsum("bhd,bhtd->bht", q, ck[l],
                                 preferred_element_type=jnp.float32)
             scores = scores / math.sqrt(Dh)
             t_idx = jnp.arange(T)[None, None, :]
             scores = jnp.where(t_idx <= pos[:, None, None], scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bht,bhtd->bhd", probs, cv)
+            attn = jnp.einsum("bht,bhtd->bhd", probs, cv[l])
             attn = attn.reshape(B, H * Dh)
             attn = attn @ _w(bp["attn"]["wo"], cfg) + \
                 _w(bp["attn"]["bo"], cfg)
             x = x + attn
         with jax.named_scope("mlp"):
             x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
-        return x, (ck, cv)
+        return x, ck, cv
 
-    # what the scan does itself (a layer's weights and cache sliced in,
-    # the new caches stacked out, the carries) is `layers`; the layer's
-    # own operations keep their inner scopes
-    with jax.named_scope("layers"):
-        x, (new_k, new_v) = lax.scan(
-            layer, x, (params["blocks"], cache["k"], cache["v"]))
+    x, cache = _cached_layers(layer, x, params, cache)
     with jax.named_scope("unembed_loss"):
         x = _layer_norm(x, params["ln_f"])
         logits = (x @ _w(wte.T, cfg)).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
@@ -431,11 +488,13 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
     chunk's first cache position), length [B] int32 (valid tokens in the
     chunk, 0..C), active [B] bool. Returns (logits [B, vocab] taken at
     each slot's LAST valid chunk token, new_cache). Inactive/zero-length
-    slots' caches are untouched and their logits are garbage. Cache
-    writes are lane-masked read-modify-writes: dynamic_update_slice
-    clamps its start near the sequence end, so an unmasked block write
-    would smear garbage lanes over valid earlier positions. Callers
+    slots' caches are untouched and their logits are garbage. Callers
     guarantee pos0 + length <= T and C <= T.
+
+    As in `decode_step` the cache is the carry of the loop over the layers:
+    a layer writes the valid lanes of each active slot's [H, C, Dh] chunk
+    into it (`_cache_write`) and reads its own slice once. The caller must
+    donate `cache` for that to happen in place.
     """
     B, C = tokens.shape
     H, Dh = cfg.n_head, cfg.head_dim
@@ -443,28 +502,13 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
     wte = params["wte"]
     lane = jnp.arange(C)
     pos = pos0[:, None] + lane[None, :]                           # [B, C]
-    valid = lane[None, :] < length[:, None]                       # [B, C]
+    ok = (lane[None, :] < length[:, None]) & active[:, None]      # [B, C]
     with jax.named_scope("embed"):
         x = wte[tokens] + params["wpe"][
             jnp.clip(pos, 0, cfg.max_seq_len - 1)]
         x = x.astype(cfg.dtype)                                   # [B, C, D]
 
-    def upd_chunk(c_b, val_b, p0_b, valid_b):
-        # c_b [H, T, Dh], val_b [H, C, Dh]: write val lane i at position
-        # p0_b + i for VALID lanes only. Window lane w (at absolute
-        # position start + w) takes val lane w - off, where off is the
-        # clamp shift; everything else keeps the old cache content.
-        start = jnp.clip(p0_b, 0, T - C)
-        off = p0_b - start
-        old = jax.lax.dynamic_slice(c_b, (0, start, 0), (H, C, Dh))
-        src = lane - off
-        srcc = jnp.clip(src, 0, C - 1)
-        take = (src >= 0) & (src < C) & valid_b[srcc]
-        blended = jnp.where(take[None, :, None], val_b[:, srcc, :], old)
-        return jax.lax.dynamic_update_slice(c_b, blended, (0, start, 0))
-
-    def layer(x, scanned):
-        bp, ck, cv = scanned                                # ck/cv [B,H,T,Dh]
+    def layer(x, ck, cv, bp, l):                          # ck/cv [L,B,H,T,Dh]
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1"])
             qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
@@ -474,39 +518,35 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
             k = k.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
             v = v.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
             with jax.named_scope("kv_update"):
-                ck_new = jax.vmap(upd_chunk)(ck, k, pos0, valid)
-                cv_new = jax.vmap(upd_chunk)(cv, v, pos0, valid)
-                ck = jnp.where(active[:, None, None, None], ck_new, ck)
-                cv = jnp.where(active[:, None, None, None], cv_new, cv)
+                ck = _cache_write(ck, l, k, pos0, ok)
+                cv = _cache_write(cv, l, v, pos0, ok)
             # chunk lanes attend to everything written up to their own
             # position (the chunk's k/v are already in the cache, so this
             # is causal intra-chunk attention + full attention to the
             # prefix)
-            scores = jnp.einsum("bhcd,bhtd->bhct", q, ck,
+            scores = jnp.einsum("bhcd,bhtd->bhct", q, ck[l],
                                 preferred_element_type=jnp.float32)
             scores = scores / math.sqrt(Dh)
             t_idx = jnp.arange(T)[None, None, None, :]
             scores = jnp.where(t_idx <= pos[:, None, :, None], scores,
                                -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bhct,bhtd->bhcd", probs, cv)
+            attn = jnp.einsum("bhct,bhtd->bhcd", probs, cv[l])
             attn = attn.transpose(0, 2, 1, 3).reshape(B, C, H * Dh)
             attn = attn @ _w(bp["attn"]["wo"], cfg) + \
                 _w(bp["attn"]["bo"], cfg)
             x = x + attn
         with jax.named_scope("mlp"):
             x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
-        return x, (ck, cv)
+        return x, ck, cv
 
-    with jax.named_scope("layers"):         # as in decode_step
-        x, (new_k, new_v) = lax.scan(
-            layer, x, (params["blocks"], cache["k"], cache["v"]))
+    x, cache = _cached_layers(layer, x, params, cache)
     with jax.named_scope("unembed_loss"):
         last = jnp.clip(length - 1, 0, C - 1)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         x_last = _layer_norm(x_last, params["ln_f"])
         logits = (x_last @ _w(wte.T, cfg)).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def num_params(cfg: GPT2Config) -> int:

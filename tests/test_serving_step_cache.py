@@ -1,0 +1,218 @@
+"""What `gpt2.decode_step` and `gpt2.prefill_chunk` do to the KV cache they
+carry through their loop over the layers: which entries change, what the
+written rows hold, and that a prompt fed through both gives the logits of
+`gpt2.forward`. CPU, `gpt2-tiny` in float32 (so a tolerance can be tight),
+no cluster. The cache's entries outside the written rows are compared bit
+for bit: nothing may round-trip through arithmetic on its way through."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import gpt2
+
+# T above the 128 positions a write touches at the least, so that a window
+# starts past 0 and is clamped near the end of the sequence
+B, T, C = 4, 160, 8
+CFG = gpt2.GPT2Config.preset("gpt2-tiny", dtype=jnp.float32, max_seq_len=T,
+                             attn_impl="dense")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return gpt2.init_params(jax.random.key(3), CFG)
+
+
+@pytest.fixture(scope="module")
+def steps(params):
+    """The two programs as serve/llm.LLMEngine jits them: cache donated."""
+    step = jax.jit(lambda c, t, pos, a: gpt2.decode_step(
+        params, c, t, pos, a, CFG), donate_argnums=(0,))
+    chunk = jax.jit(lambda c, t, p0, n, a: gpt2.prefill_chunk(
+        params, c, t, p0, n, a, CFG), donate_argnums=(0,))
+    return step, chunk
+
+
+def _random_cache(seed: int) -> dict:
+    rng = np.random.default_rng(seed)
+    shape = (CFG.n_layer, B, CFG.n_head, T, CFG.head_dim)
+    return {n: rng.standard_normal(shape).astype(np.float32) for n in "kv"}
+
+
+def _reference(params, cache, tokens, pos0, length, active):
+    """One step, written plainly: a Python loop over layers, slots and
+    lanes, each valid lane's k/v row stored at its position before the
+    layer's attention reads the slot's cache. tokens [B, n]. Returns
+    (logits at each slot's last valid lane, cache)."""
+    n = tokens.shape[1]
+    k_all = np.array(cache["k"])
+    v_all = np.array(cache["v"])
+    H, Dh = CFG.n_head, CFG.head_dim
+    logits = np.zeros((B, CFG.vocab_size), np.float32)
+    for b in range(B):
+        pos = pos0[b] + np.arange(n)
+        x = params["wte"][tokens[b]] + params["wpe"][np.clip(pos, 0, T - 1)]
+        rows = [i for i in range(n) if active[b] and i < length[b]]
+        for l in range(CFG.n_layer):
+            bp = jax.tree.map(lambda w: w[l], params["blocks"])
+            h = gpt2._layer_norm(x, bp["ln1"])
+            qkv = h @ bp["attn"]["wqkv"] + bp["attn"]["bqkv"]
+            q, k, v = (a.reshape(n, H, Dh) for a in jnp.split(qkv, 3, -1))
+            for i in rows:
+                k_all[l, b, :, pos[i], :] = k[i]
+                v_all[l, b, :, pos[i], :] = v[i]
+            scores = jnp.einsum("chd,htd->hct", q, k_all[l, b]) / math.sqrt(Dh)
+            seen = np.arange(T)[None, None, :] <= pos[None, :, None]
+            probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+            attn = jnp.einsum("hct,htd->chd", probs, v_all[l, b])
+            x = x + attn.reshape(n, H * Dh) @ bp["attn"]["wo"] + \
+                bp["attn"]["bo"]
+            x = x + gpt2._mlp(gpt2._layer_norm(x, bp["ln2"]), bp["mlp"], CFG)
+        last = int(np.clip(length[b] - 1, 0, n - 1))
+        logits[b] = gpt2._layer_norm(x[last], params["ln_f"]) @ \
+            params["wte"].T
+    return logits, {"k": k_all, "v": v_all}
+
+
+def _written(pos0, length, active) -> np.ndarray:
+    """[B, T] bool: the positions a step may write."""
+    t = np.arange(T)[None, :]
+    return (active[:, None] & (t >= pos0[:, None])
+            & (t < (pos0 + length)[:, None]))
+
+
+def _check(new_cache, logits, cache, ref_logits, ref_cache, written):
+    counted = written.any(axis=1)
+    for name in "kv":
+        got = np.asarray(new_cache[name])
+        # (a) outside the written rows of active slots: the input's bits
+        keep = np.broadcast_to(~written[None, :, None, :, None], got.shape)
+        assert np.array_equal(got[keep].view(np.uint32),
+                              cache[name][keep].view(np.uint32))
+        # (b) the written rows: the layer's own k/v
+        np.testing.assert_allclose(got[~keep], ref_cache[name][~keep],
+                                   rtol=2e-5, atol=2e-5)
+        if written.any():
+            assert not np.array_equal(got[~keep], cache[name][~keep])
+    np.testing.assert_allclose(np.asarray(logits)[counted],
+                               ref_logits[counted], rtol=2e-4, atol=2e-4)
+
+
+DECODE_CASES = {
+    "all-active": ([5, 9, 17, 30], [1, 1, 1, 1]),
+    "some-inactive": ([5, 9, 17, 30], [1, 0, 1, 0]),
+    "none-active": ([5, 9, 17, 30], [0, 0, 0, 0]),
+    "first-and-last-position": ([0, T - 1, 0, T - 1], [1, 1, 0, 0]),
+    "same-position": ([7, 7, 7, 7], [0, 1, 1, 1]),
+    "around-the-windows-edges": ([127, 128, T - 129, T - 128], [1, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_decode_step_writes_one_row_per_active_slot(params, steps, case):
+    pos, active = (np.asarray(a) for a in DECODE_CASES[case])
+    active = active.astype(bool)
+    rng = np.random.default_rng(len(case))
+    tokens = rng.integers(0, CFG.vocab_size, B)
+    cache = _random_cache(11)
+    logits, new_cache = steps[0](
+        jax.tree.map(jnp.asarray, cache), jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+    ones = np.ones(B, np.int64)
+    ref_logits, ref_cache = _reference(params, cache, tokens[:, None], pos,
+                                       ones, active)
+    _check(new_cache, logits, cache, ref_logits, ref_cache,
+           _written(pos, ones, active))
+
+
+CHUNK_CASES = {
+    # pos0, length, active
+    "lengths-0-1-C": ([3, 10, 0, 20], [0, 1, C, 4], [1, 1, 1, 1]),
+    "some-inactive": ([3, 10, 0, 20], [5, 1, C, 4], [0, 1, 0, 1]),
+    "none-active": ([3, 10, 0, 20], [5, 1, C, 4], [0, 0, 0, 0]),
+    # pos0 > T - C: dynamic_update_slice would clamp the window's start
+    "clamped-window": ([T - 3, T - 1, T - C, T - C + 1], [3, 1, C, C - 1],
+                       [1, 1, 1, 1]),
+    "clamped-and-inactive": ([T - 3, T - 1, T - 5, T - 2], [3, 1, 0, 2],
+                             [1, 0, 1, 1]),
+    "whole-window-at-zero": ([0, 0, 0, 0], [C, C, 1, 0], [1, 0, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_prefill_chunk_writes_the_valid_lanes_of_active_slots(params, steps,
+                                                              case):
+    pos0, length, active = (np.asarray(a) for a in CHUNK_CASES[case])
+    active = active.astype(bool)
+    rng = np.random.default_rng(len(case))
+    tokens = rng.integers(0, CFG.vocab_size, (B, C))
+    cache = _random_cache(12)
+    logits, new_cache = steps[1](
+        jax.tree.map(jnp.asarray, cache), jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(pos0, jnp.int32), jnp.asarray(length, jnp.int32),
+        jnp.asarray(active))
+    ref_logits, ref_cache = _reference(params, cache, tokens, pos0, length,
+                                       active)
+    _check(new_cache, logits, cache, ref_logits, ref_cache,
+           _written(pos0, length, active))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                       (jnp.bfloat16, 1e-1)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("prompt_len", [1, C, 2 * C + 3, T - 1],
+                         ids=lambda n: f"prompt{n}")
+def test_chunks_then_decode_match_forward(dtype, tol, prompt_len):
+    """A prompt fed C tokens a chunk (the last chunk's window clamped when
+    it reaches past T - C), then decoded to the end of the sequence, one
+    slot idle throughout: every step's logits are `forward`'s."""
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny", dtype=dtype, max_seq_len=T,
+                                 attn_impl="dense")
+    params = gpt2.init_params(jax.random.key(5), cfg)
+    rng = np.random.default_rng(prompt_len)
+    toks = rng.integers(0, cfg.vocab_size, (B, T))
+    full = np.asarray(gpt2.forward(params, jnp.asarray(toks, jnp.int32),
+                                   cfg).astype(jnp.float32))
+    step = jax.jit(lambda c, t, pos, a: gpt2.decode_step(
+        params, c, t, pos, a, cfg), donate_argnums=(0,))
+    chunk = jax.jit(lambda c, t, p0, n, a: gpt2.prefill_chunk(
+        params, c, t, p0, n, a, cfg), donate_argnums=(0,))
+    active = np.array([True, True, False, True])
+    cache = gpt2.init_cache(cfg, B, T)
+    for p0 in range(0, prompt_len, C):
+        n = min(C, prompt_len - p0)
+        lanes = np.zeros((B, C), np.int64)
+        lanes[:, :n] = toks[:, p0:p0 + n]
+        logits, cache = chunk(
+            cache, jnp.asarray(lanes, jnp.int32),
+            jnp.full((B,), p0, jnp.int32), jnp.full((B,), n, jnp.int32),
+            jnp.asarray(active))
+        np.testing.assert_allclose(np.asarray(logits)[active],
+                                   full[active, p0 + n - 1], rtol=tol,
+                                   atol=tol)
+    for pos in range(prompt_len, T):
+        logits, cache = step(cache, jnp.asarray(toks[:, pos], jnp.int32),
+                             jnp.full((B,), pos, jnp.int32),
+                             jnp.asarray(active))
+        np.testing.assert_allclose(np.asarray(logits)[active],
+                                   full[active, pos], rtol=tol, atol=tol)
+    # the idle slot's cache was never written
+    assert not np.asarray(cache["k"][:, 2]).any()
+    assert not np.asarray(cache["v"][:, 2]).any()
+
+
+def test_an_undonated_cache_is_left_as_it_was(params):
+    """Donation makes the update in place; without it the caller's cache
+    is not touched."""
+    cache = jax.tree.map(jnp.asarray, _random_cache(13))
+    before = jax.tree.map(np.array, cache)
+    _, new_cache = jax.jit(lambda c, t, pos, a: gpt2.decode_step(
+        params, c, t, pos, a, CFG))(
+            cache, jnp.zeros(B, jnp.int32), jnp.arange(B, dtype=jnp.int32),
+            jnp.ones(B, jnp.bool_))
+    for name in "kv":
+        assert np.array_equal(np.asarray(cache[name]), before[name])
+        assert not np.array_equal(np.asarray(new_cache[name]), before[name])

@@ -11,6 +11,7 @@ chip cannot be read back without one.
 """
 
 import importlib
+import math
 import os
 import re
 
@@ -113,9 +114,10 @@ def test_flash_ceiling_is_where_the_compiler_puts_it(chips, as_on_tpu,
 
 # ------------------------------------------------------------ serving steps
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
-def test_serving_step_compiles_at_125m_widths(chips, program):
-    B, T, C = 8, 1024, 16
+def _serving_step(chips, program, C=16):
+    """The donated step program of `serve/llm.LLMEngine` at 125M widths,
+    lowered for one described chip. Returns (lowered, cfg, (B, T))."""
+    B, T = 8, 1024
     one = SingleDeviceSharding(chips[0])
     cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=T)
     params = _on(one, jax.eval_shape(
@@ -138,8 +140,66 @@ def test_serving_step_compiles_at_125m_widths(chips, program):
                      donate_argnums=(1,))
         args = (params, cache, arr((B, C), jnp.int32), arr((B,), jnp.int32),
                 arr((B,), jnp.int32), arr((B,), jnp.bool_))
-    compiled = fn.lower(*args).compile()
+    return fn.lower(*args), cfg, (B, T)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_serving_step_compiles_at_125m_widths(chips, program):
+    compiled = _serving_step(chips, program)[0].compile()
     assert _per_device_bytes(compiled) < HBM_BYTES
+
+
+def _written_arrays(hlo: str, dims: str) -> list:
+    """(operation, type) of every instruction outside a fused computation
+    whose result holds a `bf16[<dims>]`: what the program materialises.
+    Inside a fusion a slice or a broadcast of that shape is only read."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    found, skip = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            skip = head.group(1) in fused
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if m and not skip and f"bf16[{dims}]" in m.group(1):
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+# the parent of PR 24 scanned the cache in and out of the loop over the
+# layers: its programs rewrote every layer's cache whole and copied the
+# whole cache besides. At these shapes its compiled programs held 2 `copy`,
+# 2 `fusion` and 2 AllocateBuffer `custom-call` of the whole
+# bf16[12,8,12,1024,64] and, in the loop, 6 `copy`, 3 `fusion` and a
+# `copy-start`/`copy-done` of a layer's bf16[1,8,12,1024,64] or
+# bf16[8,12,1024,64] (the decode program; the chunk program's alike), and
+# cost_analysis() counted 2.42 (decode), 2.82 (chunk of 16) and 2.97 GB
+# (chunk of 128) accessed against 0.80 GB of weights and cache; now 0.75,
+# 0.81 and 1.43 GB (a chunk of 128 reads and writes [8,12,128,1024] scores)
+@pytest.mark.parametrize("program,C,most", [
+    ("decode_step", 0, 1.25), ("prefill_chunk", 16, 1.25),
+    ("prefill_chunk", 128, 2.25)],
+    ids=["decode_step", "prefill_chunk-16", "prefill_chunk-128"])
+def test_serving_step_updates_the_cache_in_place(chips, program, C, most):
+    lowered, cfg, (B, T) = _serving_step(chips, program, C)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    layer = f"{B},{cfg.n_head},{T},{cfg.head_dim}"
+    whole = _written_arrays(hlo, f"{cfg.n_layer},{layer}")
+    in_place = {"parameter", "get-tuple-element", "tuple", "while",
+                "dynamic-update-slice", "bitcast"}
+    assert {op for op, _ in whole} <= in_place, whole
+    # one layout from the argument to the result: nothing re-lays the cache
+    assert len({re.search(r"\[%s\](\{[^}]*\})" % f"{cfg.n_layer},{layer}",
+                          t).group(1) for _, t in whole}) == 1, whole
+    # a window per slot and cache is written into the carry, and no copy
+    # of one layer's cache is made on the way to the scores
+    assert sum(op == "dynamic-update-slice" for op, _ in whole) == 2 * B
+    assert not _written_arrays(hlo, layer)
+    assert not _written_arrays(hlo, "1," + layer)
+    accessed = compiled.cost_analysis()["bytes accessed"]
+    held = sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(lowered.args_info))
+    assert accessed < most * held, (accessed, held)
 
 
 # --------------------------------------------------------------- train step
