@@ -1,0 +1,8 @@
+"""Share of the traced window's device self time under the program's
+`moe_experts` scope (`_moe_scopes`)."""
+
+from . import _moe_scopes
+
+
+def read(record):
+    return _moe_scopes.share(record, "moe_experts")

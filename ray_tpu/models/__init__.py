@@ -1,5 +1,6 @@
 """Model families (pure JAX, TPU-first): gpt2, llama (GQA/RoPE/SwiGLU),
-moe (Mixtral-style sparse MoE with expert parallelism)."""
+moe (OLMoE / Mixtral sparse MoE: dropless sort-and-grouped-matmul routing,
+one-hot dispatch under expert parallelism)."""
 
 from ray_tpu.models import gpt2
 

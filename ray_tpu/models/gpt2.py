@@ -307,29 +307,12 @@ def chunked_ce(params: Params, x: jax.Array, targets: jax.Array,
     drops from [B, T, V] to [B, ce_chunk, V] (fwd AND bwd — the chunk
     body is rematerialized), freeing HBM for larger per-chip batches.
     Numerically identical to unembed+cross_entropy (f32 reductions)."""
-    B, T, D = x.shape
-    C = cfg.ce_chunk
-    if T % C:
-        raise ValueError(f"seq len {T} not divisible by ce_chunk={C}")
-    K = T // C
+    from ray_tpu.models.lm import chunked_cross_entropy
+
     with jax.named_scope("unembed_loss"):
         x = _layer_norm(x, params["ln_f"])
         W = _w(params["wte"].T, cfg)                       # [D, V]
-        xc = x.reshape(B, K, C, D).swapaxes(0, 1)          # [K, B, C, D]
-        tc = targets.reshape(B, K, C).swapaxes(0, 1)       # [K, B, C]
-
-        def body(acc, xt):
-            xcb, tcb = xt
-            logits = constrain(xcb @ W, "batch", "seq", "vocab")
-            logits = logits.astype(jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, tcb[..., None],
-                                       axis=-1)[..., 0]
-            return acc + jnp.sum(logz - gold), None
-
-        total, _ = lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                            (xc, tc))
-        return total / (B * T)
+    return chunked_cross_entropy(x, W, targets, cfg.ce_chunk)
 
 
 def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:

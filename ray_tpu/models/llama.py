@@ -12,7 +12,10 @@ rotary embeddings, grouped-query attention, SwiGLU — all written the XLA way:
   sharded under any mesh from `ray_tpu.parallel.mesh.build_mesh`;
 - GQA: `n_kv_head <= n_head` with K/V broadcast done via reshape (free under
   XLA) rather than materialized repeats;
-- `jax.checkpoint` remat per block.
+- `jax.checkpoint` remat per block;
+- every part of a step program sits in a `jax.named_scope` (`embed`, `ln`,
+  `attn`, `mlp`, `unembed_loss`, `layers`, `weights_cast`: the names
+  `models/gpt2.py` uses), so a device trace names its time.
 """
 
 from __future__ import annotations
@@ -159,9 +162,17 @@ def param_specs(cfg: LlamaConfig, rules=None) -> Params:
 # ---------------------------------------------------------------------------
 
 def rms_norm(x, p, eps: float):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("ln"):
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+        return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+
+
+def _w(p, cfg):
+    """A weight in the compute dtype. Every conversion goes through here,
+    so that the innermost scope of its operation names it."""
+    with jax.named_scope("weights_cast"):
+        return p.astype(cfg.dtype)
 
 
 def rope_freqs(positions: jax.Array, head_dim: int, theta: float):
@@ -178,20 +189,22 @@ def apply_rope(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def _resolve_attn_impl(cfg, seq_len: int) -> str:
-    impl = cfg.attn_impl
+def attention(x, p, cfg) -> jax.Array:
+    """Causal GQA with RoPE. x [B,T,D]; p has wq/wk/wv/wo and, for a
+    model with QK-norm (OLMoE), `q_norm`/`k_norm`: an RMSNorm over the
+    whole projection, before it is split into heads and before RoPE."""
     from ray_tpu.models.lm import resolve_attn_impl
 
-    return resolve_attn_impl(impl, seq_len)
-
-
-def attention(x, p, cfg) -> jax.Array:
-    """Causal GQA with RoPE. x [B,T,D]; p has wq/wk/wv/wo."""
     B, T, D = x.shape
     H, KV, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    q = (x @ p["wq"].astype(cfg.dtype)).reshape(B, T, H, Dh)
-    k = (x @ p["wk"].astype(cfg.dtype)).reshape(B, T, KV, Dh)
-    v = (x @ p["wv"].astype(cfg.dtype)).reshape(B, T, KV, Dh)
+    q = x @ _w(p["wq"], cfg)
+    k = x @ _w(p["wk"], cfg)
+    if "q_norm" in p:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q = q.reshape(B, T, H, Dh)
+    k = k.reshape(B, T, KV, Dh)
+    v = (x @ _w(p["wv"], cfg)).reshape(B, T, KV, Dh)
 
     cos, sin = rope_freqs(jnp.arange(T), Dh, cfg.rope_theta)
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -213,7 +226,7 @@ def attention(x, p, cfg) -> jax.Array:
     k = constrain(k, "batch", "heads", "seq", None)
     v = constrain(v, "batch", "heads", "seq", None)
 
-    impl = _resolve_attn_impl(cfg, T)
+    impl = resolve_attn_impl(cfg.attn_impl, T)
     if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 
@@ -235,22 +248,32 @@ def attention(x, p, cfg) -> jax.Array:
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
-    return out @ p["wo"].astype(cfg.dtype)
+    return out @ _w(p["wo"], cfg)
 
 
 def swiglu(x, p, cfg) -> jax.Array:
-    g = x @ p["wg"].astype(cfg.dtype)
-    u = x @ p["wu"].astype(cfg.dtype)
+    g = x @ _w(p["wg"], cfg)
+    u = x @ _w(p["wu"], cfg)
     h = jax.nn.silu(g) * u
     h = constrain(h, "batch", "seq", "mlp")
-    return h @ p["wd"].astype(cfg.dtype)
+    return h @ _w(p["wd"], cfg)
+
+
+def attention_residual(x, bp, cfg) -> jax.Array:
+    """x + attention(norm(x)): the first half of a block, under the `attn`
+    scope (each residual add belongs to the scope of what it adds)."""
+    with jax.named_scope("attn"):
+        x = x + attention(rms_norm(x, bp["attn_norm"], cfg.norm_eps),
+                          bp["attn"], cfg)
+        return constrain(x, "batch", "seq", "embed")
 
 
 def _block(x, bp, cfg):
-    x = x + attention(rms_norm(x, bp["attn_norm"], cfg.norm_eps), bp["attn"], cfg)
-    x = constrain(x, "batch", "seq", "embed")
-    x = x + swiglu(rms_norm(x, bp["mlp_norm"], cfg.norm_eps), bp["mlp"], cfg)
-    x = constrain(x, "batch", "seq", "embed")
+    x = attention_residual(x, bp, cfg)
+    with jax.named_scope("mlp"):
+        x = x + swiglu(rms_norm(x, bp["mlp_norm"], cfg.norm_eps),
+                       bp["mlp"], cfg)
+        x = constrain(x, "batch", "seq", "embed")
     return x
 
 
@@ -258,16 +281,25 @@ def _block(x, bp, cfg):
 # Forward / loss
 # ---------------------------------------------------------------------------
 
-def embed(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
-    x = params["wte"][tokens].astype(cfg.dtype)
-    return constrain(x, "batch", "seq", "embed")
+def embed(params: Params, tokens: jax.Array, cfg) -> jax.Array:
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(cfg.dtype)
+        return constrain(x, "batch", "seq", "embed")
 
 
-def unembed(params: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(cfg.dtype)
-    return constrain(logits, "batch", "seq", "vocab")
+def final_hidden(params: Params, x: jax.Array, cfg) -> tuple:
+    """(the final norm of x, the unembedding matrix [D, V] in the compute
+    dtype): what `unembed` multiplies and a chunked loss takes apart."""
+    with jax.named_scope("unembed_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
+        return x, _w(head, cfg)
+
+
+def unembed(params: Params, x: jax.Array, cfg) -> jax.Array:
+    x, head = final_hidden(params, x, cfg)
+    with jax.named_scope("unembed_loss"):
+        return constrain(x @ head, "batch", "seq", "vocab")
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
@@ -278,7 +310,9 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
 
-    x, _ = lax.scan(lambda c, bp: (block_fn(c, bp), None), x, params["blocks"])
+    with jax.named_scope("layers"):     # the scan's own slices and stacks
+        x, _ = lax.scan(lambda c, bp: (block_fn(c, bp), None), x,
+                        params["blocks"])
     return unembed(params, x, cfg)
 
 

@@ -13,14 +13,49 @@ def split_lm_batch(batch: dict):
     return batch["inputs"], batch["targets"]
 
 
+def token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """logits [B,T,V], targets [B,T] -> each token's negative log
+    likelihood [B,T] float32; logits upcast to f32 for the softmax."""
+    logits = logits.astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return logz - gold
+
+
 def cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
-    """Mean next-token cross-entropy; logits upcast to f32 for the softmax."""
+    """Mean next-token cross-entropy."""
     with jax.named_scope("unembed_loss"):
-        logits = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None],
-                                   axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
+        return jnp.mean(token_nll(logits, targets))
+
+
+def chunked_cross_entropy(x: jax.Array, head: jax.Array, targets: jax.Array,
+                          chunk: int) -> jax.Array:
+    """Fused unembedding + mean cross-entropy over sequence chunks: x
+    [B,T,D] the final hidden state (already normed), head [D,V], both in
+    the compute dtype. Peak logits memory drops from [B,T,V] to
+    [B,chunk,V], forward AND backward (the chunk body is rematerialized).
+    Numerically identical to `cross_entropy(x @ head, targets)` (float32
+    reductions). The one copy every model family shares."""
+    from jax import lax
+
+    from ray_tpu.parallel.mesh import constrain
+
+    B, T, D = x.shape
+    if T % chunk:
+        raise ValueError(f"seq len {T} not divisible by ce_chunk={chunk}")
+    K = T // chunk
+    with jax.named_scope("unembed_loss"):
+        xc = x.reshape(B, K, chunk, D).swapaxes(0, 1)      # [K, B, C, D]
+        tc = targets.reshape(B, K, chunk).swapaxes(0, 1)   # [K, B, C]
+
+        def body(acc, xt):
+            xcb, tcb = xt
+            logits = constrain(xcb @ head, "batch", "seq", "vocab")
+            return acc + jnp.sum(token_nll(logits, tcb)), None
+
+        total, _ = lax.scan(jax.checkpoint(body), jnp.float32(0.0),
+                            (xc, tc))
+        return total / (B * T)
 
 
 def resolve_attn_impl(attn_impl: str, seq_len: int) -> str:

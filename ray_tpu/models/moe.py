@@ -1,20 +1,29 @@
-"""Mixture-of-Experts transformer (Mixtral-style) with expert parallelism.
+"""Sparse Mixture-of-Experts transformer: OLMoE and Mixtral from one block.
 
 Capability parity: the reference exposes expert parallelism only as vLLM
 engine flags plus placement groups (SURVEY.md §2.13, `python/ray/llm/_internal/
 serve/deployments/llm/vllm/vllm_models.py`) — it ships no MoE math. Here the
 framework owns a TPU-first sparse-MoE layer:
 
-- experts are STACKED (`[n_experts, ...]` leading dim) and sharded over the
-  `ep` mesh axis (logical axis "expert");
-- routing uses the dense one-hot dispatch/combine formulation (einsums, not
-  gather/scatter): top-k gating -> capacity-bounded position assignment ->
-  `dispatch [N,E,C]` / `combine [N,E,C]` masks -> three einsums that XLA maps
-  onto the MXU and turns into an all-to-all over `ep` when experts are
-  sharded. Static shapes throughout (capacity factor bounds expert load), so
-  the whole thing jits once;
-- standard Switch-style load-balance auxiliary loss + router z-loss;
-- attention/norm blocks are reused from `ray_tpu.models.llama`.
+- experts are STACKED (`[n_experts, ...]` leading dim; logical axis
+  "expert": on a mesh with an `ep` axis the experts' weights, gradients and
+  optimizer state are sharded over it, and the partitioner brings a
+  layer's weights together for its grouped matmuls);
+- the router runs in float32 (logits, softmax, top-k); the top-k gates are
+  kept as they are or renormalised, as the published `norm_topk_prob` says
+  (OLMoE: false; Mixtral: true);
+- routing is DROPLESS, on every mesh: the (token, slot) pairs are sorted
+  by expert, their rows gathered, one grouped matmul
+  (`ops/grouped_matmul.py`: JAX's megablox Pallas kernel `gmm` on the tpu
+  backend, forward and both backward products; `jax.lax.ragged_dot` on the
+  cpu test backend) runs over the ragged groups for each of `wg`, `wu`,
+  `wd`, and the outputs go back to token order, are scaled by their gates
+  and summed per token. No `[., T, E, C]` tensor, no capacity, no token
+  ever dropped, one path;
+- load-balancing auxiliary loss + router z-loss, returned with the loss
+  as `aux` so that a train step's metrics carry them;
+- attention (with OLMoE's QK-norm where the block has the scales), norms
+  and RoPE are reused from `ray_tpu.models.llama`.
 """
 
 from __future__ import annotations
@@ -22,14 +31,15 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import llama as _llama
-from ray_tpu.parallel.mesh import constrain, logical_to_spec
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.parallel.mesh import constrain, current_mesh, logical_to_spec
 
 Params = Any
 
@@ -44,8 +54,9 @@ class MoEConfig:
     d_ff: int = 14336                # per-expert SwiGLU hidden size
     n_experts: int = 8
     experts_per_token: int = 2       # top-k routing
-    capacity_factor: float = 1.25    # C = ceil(k*T/E * factor), padded tokens drop
-    aux_loss_weight: float = 0.01    # Switch load-balance loss
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum to 1
+    qk_norm: bool = False            # RMSNorm on the q and k projections
+    aux_loss_weight: float = 0.01    # load-balancing loss
     z_loss_weight: float = 1e-3      # router logit z-loss
     max_seq_len: int = 4096
     rope_theta: float = 10000.0
@@ -70,7 +81,17 @@ class MoEConfig:
         presets = {
             "mixtral-8x7b": dict(n_layer=32, n_head=32, n_kv_head=8,
                                  d_model=4096, d_ff=14336, n_experts=8,
-                                 experts_per_token=2, vocab_size=32000),
+                                 experts_per_token=2, vocab_size=32000,
+                                 norm_topk_prob=True),
+            # allenai/OLMoE-1B-7B-0125-Instruct config.json; QK-norm and
+            # the two loss weights from the paper (arXiv:2409.02060)
+            "olmoe-1b-7b": dict(n_layer=16, n_head=16, n_kv_head=16,
+                                d_model=2048, d_ff=1024, n_experts=64,
+                                experts_per_token=8, vocab_size=50304,
+                                max_seq_len=4096, rope_theta=10000.0,
+                                norm_eps=1e-5, norm_topk_prob=False,
+                                qk_norm=True, tie_embeddings=False,
+                                aux_loss_weight=0.01, z_loss_weight=1e-3),
             "moe-tiny": dict(n_layer=2, n_head=4, n_kv_head=2, d_model=128,
                              d_ff=256, n_experts=4, experts_per_token=2,
                              vocab_size=512, max_seq_len=128),
@@ -95,14 +116,18 @@ def init_params(key: jax.Array, cfg: MoEConfig) -> Params:
 
     def init_block(k):
         ks = jax.random.split(k, 8)
+        attn = {
+            "wq": norm(ks[0], (D, D)),
+            "wk": norm(ks[1], (D, kv_dim)),
+            "wv": norm(ks[2], (D, kv_dim)),
+            "wo": norm(ks[3], (D, D), resid_std),
+        }
+        if cfg.qk_norm:
+            attn["q_norm"] = {"scale": jnp.ones((D,), pd)}
+            attn["k_norm"] = {"scale": jnp.ones((kv_dim,), pd)}
         return {
             "attn_norm": {"scale": jnp.ones((D,), pd)},
-            "attn": {
-                "wq": norm(ks[0], (D, D)),
-                "wk": norm(ks[1], (D, kv_dim)),
-                "wv": norm(ks[2], (D, kv_dim)),
-                "wo": norm(ks[3], (D, D), resid_std),
-            },
+            "attn": attn,
             "mlp_norm": {"scale": jnp.ones((D,), pd)},
             "moe": {
                 "router": norm(ks[4], (D, E)),
@@ -124,14 +149,18 @@ def init_params(key: jax.Array, cfg: MoEConfig) -> Params:
 
 
 def param_logical_axes(cfg: MoEConfig) -> Params:
+    attn = {
+        "wq": ("embed", "heads"),
+        "wk": ("embed", "kv"),
+        "wv": ("embed", "kv"),
+        "wo": ("heads", "embed"),
+    }
+    if cfg.qk_norm:
+        attn["q_norm"] = {"scale": ("heads",)}
+        attn["k_norm"] = {"scale": ("kv",)}
     block = {
         "attn_norm": {"scale": ("embed",)},
-        "attn": {
-            "wq": ("embed", "heads"),
-            "wk": ("embed", "kv"),
-            "wv": ("embed", "kv"),
-            "wo": ("heads", "embed"),
-        },
+        "attn": attn,
         "mlp_norm": {"scale": ("embed",)},
         "moe": {
             "router": ("embed", None),       # tiny; replicated
@@ -161,138 +190,253 @@ def param_specs(cfg: MoEConfig, rules=None) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# Sparse MoE layer (dense dispatch/combine einsum formulation)
+# Sparse MoE layer
 # ---------------------------------------------------------------------------
 
-def expert_capacity(cfg: MoEConfig, n_tokens: int) -> int:
-    c = math.ceil(cfg.experts_per_token * n_tokens * cfg.capacity_factor
-                  / cfg.n_experts)
-    return max(int(c), 4)
+def _route(x2, router, cfg: MoEConfig):
+    """x2 [N, D] -> (logits [N, E], probs [N, E], gates [N, K], experts
+    [N, K]), all of it in float32: `Precision.HIGHEST`, because a float32
+    product runs in bfloat16 passes on the TPU unless it is asked for."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = lax.top_k(probs, cfg.experts_per_token)
+        if cfg.norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        return logits, probs, gates, experts
 
 
-def moe_layer(x, p, cfg: MoEConfig):
-    """Sparse SwiGLU MoE. x [B,T,D] -> (out [B,T,D], aux_metrics dict).
+def _router_losses(logits, probs, load, cfg: MoEConfig):
+    """(load-balancing loss, z-loss). `load` [E]: the share of the N·K
+    (token, slot) assignments each expert received. E · sum_e load_e ·
+    mean_prob_e is 1 under uniform routing (megablocks' normalisation,
+    which OLMoE trained with; Switch's form at K=1)."""
+    with jax.named_scope("moe_router"):
+        E = cfg.n_experts
+        mean_prob = jnp.mean(probs.reshape(-1, E), axis=0)
+        aux_loss = E * jnp.sum(load * mean_prob)
+        z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        return aux_loss, z_loss
 
-    Dense one-hot dispatch: every token gets top-k expert choices; a cumsum
-    over the token axis assigns per-expert positions; tokens past capacity C
-    are dropped (contribute zero — the residual stream carries them).
-    """
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """x[perm] for a permutation `perm` of x's rows whose inverse is
+    `inverse`: the backward pass is the gather `g[inverse]`, never the
+    scatter-add that the transpose of a general gather would be."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
+             first_expert=None):
+    """The experts' part of the layer for the tokens x [B,T,D] (`gates`,
+    `experts` [B,T,K], the ids counted over all E): sort the (token, slot)
+    pairs by expert, one grouped matmul over the ragged groups for each
+    weight, and back to token order, scaled by the gates and summed per
+    token. Rows move by permutation gathers in both directions of both
+    passes. `wg`, `wu` [E',D,F'], `wd` [E',F',D] may be a shard: experts
+    `first_expert`..+E' of the E and F' of an expert's columns. The
+    grouped matmul then leaves the other experts' rows unwritten, so they
+    are zeroed on the way in (for the backward pass) and out, and the
+    result is this shard's part of the sum."""
     B, T, D = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     N = B * T
-    C = expert_capacity(cfg, T)  # capacity per expert per batch row
 
-    xt = x.reshape(B, T, D)
-    # Router in f32 for numerics.
-    logits = (xt.astype(jnp.float32)
-              @ p["router"].astype(jnp.float32))          # [B,T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("moe_dispatch"):
+        flat = experts.reshape(N * K)                  # pair (n, k) at n*K+k
+        order = jnp.argsort(flat)                      # stable: by expert
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * K, dtype=order.dtype))
+        group_sizes = jnp.sum(jax.nn.one_hot(flat, E, dtype=jnp.int32),
+                              axis=0)
+        pairs = jnp.broadcast_to(x.reshape(N, 1, D), (N, K, D)).reshape(
+            N * K, D)
+        xs = _permute_rows(pairs, order, inverse)      # rows by expert
+        if first_expert is not None:
+            local = flat[order] - first_expert
+            mine = ((local >= 0) & (local < wg.shape[0]))[:, None]
+            xs = jnp.where(mine, xs, 0)
 
-    gate_vals, gate_idx = lax.top_k(probs, K)             # [B,T,K]
-    # Mixtral-style: renormalize the top-k gates to sum to 1.
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    with jax.named_scope("moe_experts"):
+        g = grouped_matmul(xs, wg, group_sizes, first_expert)
+        u = grouped_matmul(xs, wu, group_sizes, first_expert)
+        h = jax.nn.silu(g) * u
+        ys = grouped_matmul(h, wd, group_sizes, first_expert)
 
-    # One-hot expert assignment per routing slot: [B,T,K,E]
-    assign = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
-    # Position of each (token, slot) within its expert queue: cumulative count
-    # over (slot-major, then token) order so slot 0 choices win capacity ties.
-    flat = assign.transpose(0, 2, 1, 3).reshape(B, K * T, E)   # slot-major
-    pos_in_expert = jnp.cumsum(flat, axis=1) - flat            # [B,K*T,E]
-    pos_in_expert = pos_in_expert.reshape(B, K, T, E).transpose(0, 2, 1, 3)
-    within_cap = pos_in_expert < C                             # [B,T,K,E]
-    keep = assign * within_cap                                 # [B,T,K,E]
+    with jax.named_scope("moe_dispatch"):
+        if first_expert is not None:
+            ys = jnp.where(mine, ys, 0)
+        back = _permute_rows(ys, inverse, order).reshape(N, K, D)
+        out = jnp.einsum("nkd,nk->nd", back,
+                         gates.reshape(N, K).astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+        return out.reshape(B, T, D)
 
-    # Dispatch/combine tensors: [B,T,E,C]
-    slot_pos = jnp.sum(pos_in_expert * assign, axis=-1).astype(jnp.int32)
-    pos_oh = jax.nn.one_hot(slot_pos, C, dtype=jnp.float32)    # [B,T,K,C]
-    dispatch = jnp.einsum("btke,btkc->btec", keep, pos_oh)
-    combine = jnp.einsum("btke,btkc,btk->btec", keep, pos_oh, gate_vals)
 
-    # Expert inputs: [E, B, C, D] — the einsum over the token axis is the
-    # all-to-all when experts are ep-sharded and tokens dp-sharded.
-    expert_in = jnp.einsum("btec,btd->ebcd", dispatch.astype(cfg.dtype), xt)
-    expert_in = constrain(expert_in, "expert", "batch", None, "embed")
+def _experts_on_mesh(x, gates, experts, wg, wu, wd, cfg: MoEConfig, mesh):
+    """`_experts` inside a sharded program. The TPU compiler cannot
+    partition a Mosaic kernel on its own, so each device runs the sort and
+    the grouped matmuls on its own tokens (whole over `ep` and `tp`, as the
+    mesh rules lay activations out) against its own shard of the experts:
+    E/ep of them, F/tp of an expert's columns, the `fsdp` shards brought
+    together at the boundary. The parts are summed over `ep` and `tp`."""
+    from jax import shard_map
 
-    def one_expert(inp, wg, wu, wd):
-        g = inp @ wg.astype(cfg.dtype)
-        u = inp @ wu.astype(cfg.dtype)
-        return (jax.nn.silu(g) * u) @ wd.astype(cfg.dtype)
+    def mesh_axes(logical):
+        return tuple(a for part in logical_to_spec(logical)
+                     for a in ((part,) if isinstance(part, str) else part))
 
-    expert_out = jax.vmap(one_expert)(expert_in, p["wg"], p["wu"], p["wd"])
-    expert_out = constrain(expert_out, "expert", "batch", None, "embed")
+    tokens = logical_to_spec("batch", "seq", None)
+    up = logical_to_spec("expert", None, "mlp")
+    down = logical_to_spec("expert", "mlp", None)
+    over_experts = mesh_axes("expert")
+    summed = over_experts + mesh_axes("mlp")
 
-    out = jnp.einsum("btec,ebcd->btd", combine.astype(cfg.dtype), expert_out)
-    out = constrain(out, "batch", "seq", "embed")
+    def body(x, gates, experts, wg, wu, wd):
+        first = None
+        if wg.shape[0] < cfg.n_experts:
+            first = lax.axis_index(over_experts) * wg.shape[0]
+        out = _experts(x, gates, experts, wg, wu, wd, cfg, first)
+        return lax.psum(out, summed) if summed else out
 
-    # Switch load-balance loss: E * sum_e f_e * p_e  (f = fraction of tokens
-    # routed, p = mean router prob); plus z-loss on logits.
-    frac = jnp.mean(jnp.sum(keep, axis=2), axis=(0, 1)) * (E / K)  # [E]
-    mean_prob = jnp.mean(probs, axis=(0, 1)) * E                   # [E]
-    aux_loss = jnp.mean(frac * mean_prob)
-    z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    dropped = 1.0 - jnp.sum(keep) / (N * K)
+    return shard_map(body, mesh=mesh,
+                     in_specs=(tokens, tokens, tokens, up, up, down),
+                     out_specs=tokens, check_vma=False)(
+        x, gates, experts, wg, wu, wd)
+
+
+def moe_layer(x, p, cfg: MoEConfig):
+    """Sparse SwiGLU MoE, x [B,T,D] -> (out [B,T,D], aux: the `AUX_KEYS`
+    scalars and `routing`, what the router saw and gave). Dropless, on
+    every mesh: float32 router, then `_experts`."""
+    B, T, D = x.shape
+    E, K = cfg.n_experts, cfg.experts_per_token
+    N = B * T
+    logits, probs, gates, experts = _route(x.reshape(N, D), p["router"], cfg)
+    gates, experts = gates.reshape(B, T, K), experts.reshape(B, T, K)
+    weights = [_llama._w(p[k], cfg) for k in ("wg", "wu", "wd")]
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        out = _experts(x, gates, experts, *weights, cfg)
+    else:
+        out = _experts_on_mesh(x, gates, experts, *weights, cfg, mesh)
+    out = out.astype(cfg.dtype)
+
+    with jax.named_scope("moe_dispatch"):
+        sizes = jnp.sum(jax.nn.one_hot(experts.reshape(N * K), E,
+                                       dtype=jnp.int32),
+                        axis=0).astype(jnp.float32)
+    aux_loss, z_loss = _router_losses(logits, probs, sizes / (N * K), cfg)
     return out, {"aux_loss": aux_loss, "z_loss": z_loss,
-                 "dropped_frac": dropped}
+                 "dropped_frac": (N * K - jnp.sum(sizes)) / (N * K),
+                 "load_max_over_mean": jnp.max(sizes) * E / (N * K),
+                 "routing": {"inputs": x,
+                             "logits": logits.reshape(B, T, E),
+                             "gates": gates, "experts": experts}}
+
+
+AUX_KEYS = ("aux_loss", "z_loss", "dropped_frac", "load_max_over_mean")
 
 
 def _block(carry, bp, cfg: MoEConfig):
     x, aux_acc = carry
-    x = x + _llama.attention(
-        _llama.rms_norm(x, bp["attn_norm"], cfg.norm_eps), bp["attn"], cfg)
-    x = constrain(x, "batch", "seq", "embed")
-    moe_out, aux = moe_layer(
-        _llama.rms_norm(x, bp["mlp_norm"], cfg.norm_eps), bp["moe"], cfg)
-    x = x + moe_out
-    x = constrain(x, "batch", "seq", "embed")
-    aux_acc = {
-        "aux_loss": aux_acc["aux_loss"] + aux["aux_loss"],
-        "z_loss": aux_acc["z_loss"] + aux["z_loss"],
-        "dropped_frac": aux_acc["dropped_frac"] + aux["dropped_frac"],
-    }
-    return (x, aux_acc)
+    x = _llama.attention_residual(x, bp, cfg)
+    with jax.named_scope("mlp"):
+        moe_out, aux = moe_layer(
+            _llama.rms_norm(x, bp["mlp_norm"], cfg.norm_eps), bp["moe"], cfg)
+        x = x + moe_out
+        x = constrain(x, "batch", "seq", "embed")
+    return (x, {k: aux_acc[k] + aux[k] for k in AUX_KEYS}), aux["routing"]
 
 
 # ---------------------------------------------------------------------------
 # Forward / loss
 # ---------------------------------------------------------------------------
 
-def forward(params: Params, tokens: jax.Array, cfg: MoEConfig,
-            return_aux: bool = False):
-    x = params["wte"][tokens].astype(cfg.dtype)
-    x = constrain(x, "batch", "seq", "embed")
-    aux0 = {"aux_loss": jnp.zeros((), jnp.float32),
-            "z_loss": jnp.zeros((), jnp.float32),
-            "dropped_frac": jnp.zeros((), jnp.float32)}
+def hidden_states(params: Params, tokens: jax.Array, cfg: MoEConfig):
+    """tokens [B,T] -> (final hidden [B,T,D] before the last norm, the
+    layers' mean aux dict, every layer's `routing` stacked on a leading
+    layer axis)."""
+    x = _llama.embed(params, tokens, cfg)
+    aux0 = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
 
     block_fn = partial(_block, cfg=cfg)
     if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
 
-    (x, aux), _ = lax.scan(lambda c, bp: (block_fn(c, bp), None),
-                           (x, aux0), params["blocks"])
-    x = _llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(cfg.dtype)
-    logits = constrain(logits, "batch", "seq", "vocab")
-    aux = {k: v / cfg.n_layer for k, v in aux.items()}
+    with jax.named_scope("layers"):     # the scan's own slices and stacks
+        (x, aux), routing = lax.scan(block_fn, (x, aux0), params["blocks"])
+    return x, {k: v / cfg.n_layer for k, v in aux.items()}, routing
+
+
+def forward(params: Params, tokens: jax.Array, cfg: MoEConfig,
+            return_aux: bool = False):
+    x, aux, _ = hidden_states(params, tokens, cfg)
+    logits = _llama.unembed(params, x, cfg)
     return (logits, aux) if return_aux else logits
 
 
-def loss_fn(params: Params, batch: dict, cfg: MoEConfig) -> jax.Array:
-    from ray_tpu.models.lm import cross_entropy, split_lm_batch
+def routing(params: Params, tokens: jax.Array, cfg: MoEConfig) -> dict:
+    """tokens [B,T] -> what every layer's router saw and gave: `inputs`
+    [L,B,T,D] (the normed residual stream, in the compute dtype), `logits`
+    [L,B,T,E] and `gates` [L,B,T,K] float32, `experts` [L,B,T,K] int32.
+    What a reference's routing is compared with, and what shows that the
+    router ran in float32 (recompute `logits` from `inputs`)."""
+    return hidden_states(params, tokens, cfg)[2]
+
+
+# tokens of the sequence whose float32 logits exist at once in the loss
+CE_CHUNK = 1024
+
+
+def loss_fn(params: Params, batch: dict, cfg: MoEConfig):
+    """(cross-entropy + aux_loss_weight · load-balancing loss +
+    z_loss_weight · router z-loss, aux): `aux` holds what a MoE job
+    watches and `train/spmd.compile_train` adds to the step's metrics."""
+    from ray_tpu.models.lm import (chunked_cross_entropy, cross_entropy,
+                                   split_lm_batch)
 
     inputs, targets = split_lm_batch(batch)
-    logits, aux = forward(params, inputs, cfg, return_aux=True)
-    ce = cross_entropy(logits, targets)
-    return (ce + cfg.aux_loss_weight * aux["aux_loss"]
+    x, aux, _ = hidden_states(params, inputs, cfg)
+    T = inputs.shape[1]
+    if T > CE_CHUNK and T % CE_CHUNK == 0:
+        ce = chunked_cross_entropy(*_llama.final_hidden(params, x, cfg),
+                                   targets, CE_CHUNK)
+    else:
+        ce = cross_entropy(_llama.unembed(params, x, cfg), targets)
+    loss = (ce + cfg.aux_loss_weight * aux["aux_loss"]
             + cfg.z_loss_weight * aux["z_loss"])
+    return loss, {"router_aux_loss": aux["aux_loss"],
+                  "router_z_loss": aux["z_loss"],
+                  "moe_dropped_frac": aux["dropped_frac"],
+                  "moe_load_max_over_mean": aux["load_max_over_mean"]}
+
+
+def _attn_params(cfg: MoEConfig) -> int:
+    D = cfg.d_model
+    kv_dim = cfg.n_kv_head * cfg.head_dim
+    qk_norm = D + kv_dim if cfg.qk_norm else 0
+    return D * D * 2 + D * kv_dim * 2 + qk_norm + 2 * D   # + the two norms
 
 
 def num_params(cfg: MoEConfig) -> int:
     D, F, L, V, E = (cfg.d_model, cfg.d_ff, cfg.n_layer, cfg.vocab_size,
                      cfg.n_experts)
-    kv_dim = cfg.n_kv_head * cfg.head_dim
-    per_block = (D * D * 2 + D * kv_dim * 2 + D * E + E * 3 * D * F + 2 * D)
+    per_block = _attn_params(cfg) + D * E + E * 3 * D * F
     total = V * D + L * per_block + D
     if not cfg.tie_embeddings:
         total += D * V
@@ -302,10 +446,8 @@ def num_params(cfg: MoEConfig) -> int:
 def active_params(cfg: MoEConfig) -> int:
     """Params touched per token (experts_per_token of n_experts)."""
     D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layer
-    kv_dim = cfg.n_kv_head * cfg.head_dim
     K = cfg.experts_per_token
-    per_block = (D * D * 2 + D * kv_dim * 2 + D * cfg.n_experts
-                 + K * 3 * D * F + 2 * D)
+    per_block = _attn_params(cfg) + D * cfg.n_experts + K * 3 * D * F
     total = cfg.vocab_size * D + L * per_block + D
     if not cfg.tie_embeddings:
         total += D * cfg.vocab_size

@@ -62,6 +62,21 @@ def _apply_optimizer(optimizer, state: "TrainState", grads):
                 optax.global_norm(grads))
 
 
+def _with_aux(loss_fn):
+    """`loss_fn(params, batch)` may return the loss alone or `(loss, aux)`,
+    `aux` a dict of scalars the job watches (a MoE's router losses and
+    load). Either way: `(loss, aux)`, for `value_and_grad(has_aux=True)`."""
+    def wrapped(params, batch):
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+    return wrapped
+
+
+def _loss_only(loss_fn):
+    with_aux = _with_aux(loss_fn)
+    return lambda params, batch: with_aux(params, batch)[0]
+
+
 def state_shardings(state_shape: Any, params_spec: Any, mesh: Mesh) -> Any:
     """Shard params by spec; shard opt-state subtrees that mirror the param
     tree (adam mu/nu etc., matched by tree STRUCTURE, not leaf shape — two
@@ -386,7 +401,10 @@ def compile_train(
 ) -> CompiledTrain:
     """Build sharded init + train-step functions for an arbitrary model.
 
-    loss_fn(params, batch) -> scalar; init_params_fn(key) -> params pytree;
+    loss_fn(params, batch) -> scalar, or (scalar, aux) with `aux` a dict of
+    scalars that joins the fused step's `metrics` beside `loss`,
+    `grad_norm` and `step` (the hierarchical-mesh and split grad/apply
+    programs take the loss alone); init_params_fn(key) -> params pytree;
     params_spec: PartitionSpec pytree matching params.
 
     On a hierarchical mesh (`mesh_lib.build_hierarchical_mesh`, dp split
@@ -405,6 +423,7 @@ def compile_train(
     the fused `step_fn`.
     """
     optimizer = optimizer or default_optimizer()
+    loss_aux_fn, loss_fn = _with_aux(loss_fn), _loss_only(loss_fn)
     hier = mesh_lib.is_hierarchical_mesh(mesh)
     if batch_spec is None:
         batch_spec = (P((*mesh_lib.DP_SUB_AXES, "fsdp")) if hier
@@ -515,13 +534,15 @@ def compile_train(
     else:
         def _step(state: TrainState, batch):
             with mesh_lib.use_mesh(mesh, rules):
-                loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+                (loss, aux), grads = jax.value_and_grad(
+                    loss_aux_fn, has_aux=True)(state.params, batch)
                 params, opt_state, grad_norm = _apply_optimizer(
                     optimizer, state, grads)
                 metrics = {
                     "loss": loss,
                     "grad_norm": grad_norm,
                     "step": state.step + 1,
+                    **aux,
                 }
                 return TrainState(state.step + 1, params, opt_state), metrics
 

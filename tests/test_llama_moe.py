@@ -1,6 +1,7 @@
 """LLaMA + MoE model families: shapes, causality, GQA decode parity,
 expert-parallel sharding consistency, loss decrease."""
 
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -118,27 +119,83 @@ def test_moe_causality():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_moe_capacity_bounds_tokens():
-    # with huge capacity nothing is dropped
-    cfg = moe.MoEConfig.preset("moe-tiny", remat=False, dtype=jnp.float32,
-                               capacity_factor=8.0)
+EP_MESHES = [dict(dp=2, ep=4), dict(ep=4, tp=2), dict(dp=4, ep=2)]
+EP_IDS = ["dp2ep4", "ep4tp2", "dp4ep2"]
+
+
+@pytest.mark.parametrize("axes", EP_MESHES, ids=EP_IDS)
+def test_moe_drops_nothing_under_skew_on_an_ep_mesh(devices8, axes):
+    """One token id filling the batch sends every token of a position to
+    the same experts. There is no capacity to exceed, on any mesh: nothing
+    is dropped and the output is the single device's."""
+    cfg = moe.MoEConfig.preset("moe-tiny", remat=False, dtype=jnp.float32)
     params = moe.init_params(jax.random.key(0), cfg)
-    _, aux = moe.forward(params, jnp.zeros((2, 32), jnp.int32), cfg,
-                         return_aux=True)
-    assert float(aux["dropped_frac"]) == pytest.approx(0.0, abs=1e-6)
+    toks = jnp.zeros((4, 32), jnp.int32)
+    ref, ref_aux = moe.forward(params, toks, cfg, return_aux=True)
+    assert float(ref_aux["dropped_frac"]) == 0.0
+    mesh = build_mesh(MeshConfig(**axes), devices=devices8)
+    with use_mesh(mesh):
+        out, aux = jax.jit(lambda p, t: moe.forward(
+            p, t, cfg, return_aux=True))(params, toks)
+    assert float(aux["dropped_frac"]) == 0.0
+    assert float(aux["load_max_over_mean"]) == pytest.approx(
+        float(ref_aux["load_max_over_mean"]))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_moe_expert_parallel_matches_single(devices8):
+    """The one routing path on a dp2·ep4 mesh (experts' weights sharded
+    over `ep`) against one device: the forward pass and its aux, and a
+    whole train step's loss, gradient norm and aux."""
     params = moe.init_params(jax.random.key(0), MCFG)
     rng = np.random.default_rng(1)
-    toks = _tokens(rng, MCFG.vocab_size, 4, 16)
-    ref = np.asarray(moe.forward(params, toks, MCFG).astype(jnp.float32))
+    toks = _tokens(rng, MCFG.vocab_size, 4, 17)
+    ref, ref_aux = moe.forward(params, toks[:, :-1], MCFG, return_aux=True)
 
     mesh = build_mesh(MeshConfig(dp=2, ep=4), devices=devices8)
     with use_mesh(mesh):
-        fwd = jax.jit(lambda p, t: moe.forward(p, t, MCFG))
-        out = np.asarray(fwd(params, toks).astype(jnp.float32))
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+        fwd = jax.jit(lambda p, t: moe.forward(p, t, MCFG, return_aux=True))
+        out, aux = fwd(params, toks[:, :-1])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+    for k in moe.AUX_KEYS:
+        assert float(aux[k]) == pytest.approx(float(ref_aux[k]), abs=1e-5)
+
+    steps = []
+    for m in (build_mesh(MeshConfig(), devices=devices8[:1]), mesh):
+        train = compile_model_train(moe, MCFG, m,
+                                    optimizer=default_optimizer(total_steps=10))
+        state = train.init_fn(jax.random.key(0))
+        if m is mesh:       # the experts' state really is split over ep
+            wg = state.params["blocks"]["moe"]["wg"]
+            assert wg.sharding.shard_shape(wg.shape)[1] \
+                == MCFG.n_experts // 4
+        steps.append(train.step_fn(state, {"tokens": toks})[1])
+    for k in steps[0]:
+        assert float(steps[1][k]) == pytest.approx(float(steps[0][k]),
+                                                   rel=1e-4, abs=1e-5), k
+
+
+@pytest.mark.parametrize("axes", [dict(dp=2, fsdp=2, tp=2), dict(fsdp=8),
+                                  *EP_MESHES[1:]],
+                         ids=["dp2fsdp2tp2", "fsdp8", *EP_IDS[1:]])
+def test_moe_sharded_matches_single(devices8, axes):
+    """The partitioner splits the sort and the grouped matmuls on every
+    mesh, and the result is the single device's."""
+    params = moe.init_params(jax.random.key(0), MCFG)
+    rng = np.random.default_rng(1)
+    toks = _tokens(rng, MCFG.vocab_size, 8, 16)
+    ref, ref_aux = moe.forward(params, toks, MCFG, return_aux=True)
+    mesh = build_mesh(MeshConfig(**axes), devices=devices8)
+    with use_mesh(mesh):
+        out, aux = jax.jit(lambda p, t: moe.forward(
+            p, t, MCFG, return_aux=True))(params, toks)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+    assert float(aux["dropped_frac"]) == 0.0
+    assert float(aux["load_max_over_mean"]) == pytest.approx(
+        float(ref_aux["load_max_over_mean"]))
 
 
 def test_moe_loss_decreases():

@@ -234,6 +234,64 @@ def test_125m_train_step_compiles(chips, as_on_tpu, axes, n_chips):
         assert re.search(r"\ball-reduce(-start)?\(", text)
 
 
+def test_olmoe_train_step_compiles_at_the_published_widths(chips, as_on_tpu):
+    """The cell `train-olmoe-4k`'s step, as its configuration file has it
+    (OLMoE-1B-7B's widths, depth 1, 8 sequences of 4,096): it fits the
+    chip and fills four fifths of it; attention is the three Pallas flash
+    kernels (T=4096 resolves to them) and the experts are nine grouped
+    matmul kernels (three products forward, d-lhs and d-rhs of each
+    backward); no `[., 4096, 64, .]` one-hot dispatch tensor and no dense
+    product of an expert's width exists."""
+    import json
+    import sys
+
+    chip_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    sys.path[:0] = [p for p in (chip_dir, os.path.join(chip_dir, "rehearse"))
+                    if p not in sys.path]
+    from compile_olmoe_for_v5e import CONFIG, compile_step, made_of
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    compiled = compile_step(config, chips)
+    total = _per_device_bytes(compiled)
+    assert total == config["memory"]["step_program_bytes_compiled_for_v5e"]
+    assert 0.8 * HBM_BYTES <= total < HBM_BYTES
+    assert made_of(compiled.as_text(), config["model"],
+                   config["job"]["seq_len"]) == {
+        "flash_kernels": 3, "grouped_matmul_kernels": 9,
+        "one_hot_dispatch_tensors": 0, "dense_expert_products": 0}
+
+
+@pytest.mark.parametrize("axes", [dict(ep=4), dict(dp=2, ep=2),
+                                  dict(fsdp=2, tp=2)],
+                         ids=["ep4", "dp2ep2", "fsdp2tp2"])
+def test_moe_train_step_compiles_under_a_mesh(chips, as_on_tpu, axes):
+    """The compiler refuses to partition the grouped-matmul kernel on its
+    own, so under a mesh `moe._experts_on_mesh` runs it per device through
+    shard_map against that device's shard of the experts: all nine kernels
+    (three products forward, d-lhs and d-rhs of each) are in the step."""
+    from ray_tpu.models import moe
+    from ray_tpu.train.spmd import compile_model_train
+
+    cfg = moe.MoEConfig.preset(
+        "olmoe-1b-7b", n_layer=1, d_model=512, n_head=4, n_kv_head=4,
+        d_ff=256, n_experts=8, experts_per_token=2, vocab_size=1024,
+        max_seq_len=512, remat=False)
+    mesh = build_mesh(MeshConfig(**axes), devices=chips)
+    train = compile_model_train(moe, cfg, mesh,
+                                optimizer=default_optimizer(total_steps=10))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(train.init_fn, jax.random.key(0)),
+        train.state_sharding)
+    data = {"tokens": jax.ShapeDtypeStruct((8, 513), jnp.int32,
+                                           sharding=train.batch_sharding)}
+    text = train.step_fn.lower(state, data).compile().as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 9
+
+
 def test_flash_attention_compiles_under_a_mesh(chips, as_on_tpu):
     """The compiler refuses to partition a Mosaic kernel on its own, so
     under dp2·tp2 the models call it per (batch, heads) shard through
