@@ -167,6 +167,14 @@ def param_specs(cfg: GPT2Config, rules=None) -> Params:
     )
 
 
+def resident_specs(cfg: GPT2Config, rules=None) -> Params:
+    """`param_specs` for the tree `resident_params` makes: the unembedding
+    is the table with its axes reversed."""
+    table = param_logical_axes(cfg)["wte"]
+    return {**param_specs(cfg, rules),
+            "unembed": logical_to_spec(*reversed(table), rules=rules)}
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -331,6 +339,53 @@ def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 
+def resident_params(params: Params, cfg: GPT2Config) -> Params:
+    """The tree a serving replica keeps on the device: what `decode_step`
+    and `prefill_chunk` read, with the conversions they would make in every
+    step made once.
+
+    The blocks' matrices and biases (`attn.{wqkv,bqkv,wo,bo}`,
+    `mlp.{wi,bi,wo,bo}`) are in `cfg.dtype`, and a new leaf `unembed` holds
+    the table transposed for the logits, [D, V] in `cfg.dtype`. `wte`, `wpe`
+    and the norms' `scale`/`bias` stay as they are: the embedding is a
+    float32 sum of two gathered rows rounded once and the norms compute in
+    float32, so a converted table would round twice and give another
+    result. Rounding is deterministic, so the programs give on this tree,
+    to the bit, what they give on `params`.
+
+    `params` may be a tree of `init_params`' kind or a resident one. One
+    jitted program converts the leaves that are not in `cfg.dtype` yet and
+    always makes `unembed` again from `wte` (a merge into the table reaches
+    the logits that way); every other leaf is handed on as the same array,
+    not a copy, so a tree that shares leaves with another keeps sharing
+    them."""
+    dt = jnp.dtype(cfg.dtype)
+    blocks = params["blocks"]
+    stale = {part: {k: v for k, v in blocks[part].items() if v.dtype != dt}
+             for part in ("attn", "mlp")}
+
+    @jax.jit
+    def convert(wte, stale):
+        return _w(wte.T, cfg), jax.tree.map(lambda v: _w(v, cfg), stale)
+
+    unembed, fresh = convert(params["wte"], stale)
+    return {**params, "unembed": unembed,
+            "blocks": {**blocks,
+                       "attn": {**blocks["attn"], **fresh["attn"]},
+                       "mlp": {**blocks["mlp"], **fresh["mlp"]}}}
+
+
+def _unembedding(params: Params, cfg: GPT2Config) -> jax.Array:
+    """[D, V] in the compute dtype: a resident tree's own leaf, else the
+    table transposed and converted here, in the step. The barrier keeps
+    that a value of its own, as the leaf is: XLA's CPU backend otherwise
+    folds the transpose into the product and sums in another order, and
+    the two kinds of tree would differ in the logits' last bit."""
+    if "unembed" in params:
+        return _w(params["unembed"], cfg)
+    return lax.optimization_barrier(_w(params["wte"].T, cfg))
+
+
 def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
     """KV cache of all layers: {"k","v"}: [n_layer, B, H, T, Dh] (compute
     dtype). `decode_step` and `prefill_chunk` carry it whole through their
@@ -456,7 +511,7 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     x, cache = _cached_layers(layer, x, params, cache)
     with jax.named_scope("unembed_loss"):
         x = _layer_norm(x, params["ln_f"])
-        logits = (x @ _w(wte.T, cfg)).astype(jnp.float32)
+        logits = (x @ _unembedding(params, cfg)).astype(jnp.float32)
     return logits, cache
 
 
@@ -528,7 +583,7 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
         last = jnp.clip(length - 1, 0, C - 1)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         x_last = _layer_norm(x_last, params["ln_f"])
-        logits = (x_last @ _w(wte.T, cfg)).astype(jnp.float32)
+        logits = (x_last @ _unembedding(params, cfg)).astype(jnp.float32)
     return logits, cache
 
 
@@ -619,7 +674,16 @@ def apply_lora(params: Params, adapter: dict) -> Params:
     scaling. Stacked scanned-layer params ([L, D, K]) take stacked
     A/B ([L, D, r], [L, r, K]) via batched matmul. Serving keeps the
     BASE params shared; each adapter costs only its merged copies of the
-    targeted leaves (reference: multi-LoRA serving behind serve.llm)."""
+    targeted leaves (reference: multi-LoRA serving behind serve.llm).
+
+    The delta is computed in float32 whatever the leaf's dtype, and the
+    sum is rounded once, to the leaf's dtype. On float32 leaves that is
+    `leaf + delta`. On a tree of `resident_params`, where a serving
+    replica keeps no float32 master, the merged weight is the rounding of
+    (the base already rounded to the compute dtype + the float32 delta):
+    one rounding more than a merge into float32 masters rounded
+    afterwards. A merge into `wte` reaches the logits when the merged tree
+    goes through `resident_params` again, which `LLMEngine` does."""
     out = jax.tree.map(lambda x: x, params)  # shallow structural copy
     for path, spec in adapter.items():
         keys = path.split(".")
@@ -628,8 +692,8 @@ def apply_lora(params: Params, adapter: dict) -> Params:
             node[k] = dict(node[k]) if isinstance(node[k], dict) else node[k]
             node = node[k]
         leaf = node[keys[-1]]
-        A = jnp.asarray(spec["A"], leaf.dtype)
-        B = jnp.asarray(spec["B"], leaf.dtype)
+        A = jnp.asarray(spec["A"], jnp.float32)
+        B = jnp.asarray(spec["B"], jnp.float32)
         r = A.shape[-1]
         alpha = float(spec.get("alpha", r))
         delta = (alpha / r) * (A @ B)
@@ -637,7 +701,7 @@ def apply_lora(params: Params, adapter: dict) -> Params:
             raise ValueError(
                 f"LoRA delta shape {delta.shape} != param {leaf.shape} "
                 f"at {path!r}")
-        node[keys[-1]] = leaf + delta
+        node[keys[-1]] = (leaf.astype(jnp.float32) + delta).astype(leaf.dtype)
     return out
 
 

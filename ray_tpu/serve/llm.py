@@ -227,7 +227,7 @@ class LLMEngine:
             # when the checkpoint's architecture differs — ADVICE r5).
             self.cfg = (cfg_override if cfg_override is not None
                         else gpt2.GPT2Config.preset(preset, **overrides))
-            self.params = params_override
+            source = params_override
             self.checkpoint = checkpoint
         elif checkpoint:
             # REAL weights: architecture from the checkpoint sidecar,
@@ -242,7 +242,7 @@ class LLMEngine:
             import time as _time
 
             base = gpt2.GPT2Config.preset(preset, **overrides)
-            self.params = None
+            source = None
             t0 = _time.perf_counter()
             if weight_store:
                 try:
@@ -252,28 +252,34 @@ class LLMEngine:
                     loaded = (store.load_params(checkpoint, base_cfg=base)
                               if store is not None else None)
                     if loaded is not None:
-                        self.params, self.cfg = loaded
+                        source, self.cfg = loaded
                         _ws.observe_cold_start(
                             _time.perf_counter() - t0, "p2p")
                 except Exception:
-                    self.params = None   # never fail init on the store
-            if self.params is None:
-                self.params, self.cfg = gpt2.load_params(checkpoint,
-                                                         cfg=base)
+                    source = None   # never fail init on the store
+            if source is None:
+                source, self.cfg = gpt2.load_params(checkpoint, cfg=base)
                 if weight_store:
                     from ray_tpu.serve import weight_store as _ws
 
                     _ws.observe_cold_start(
                         _time.perf_counter() - t0, "checkpoint")
                     _ws.maybe_publish_params_async(
-                        self.params, checkpoint,
+                        source, checkpoint,
                         arch={k: getattr(self.cfg, k)
                               for k in gpt2._CFG_FIELDS})
             self.checkpoint = checkpoint
         else:
             self.cfg = gpt2.GPT2Config.preset(preset, **overrides)
-            self.params = gpt2.init_params(jax.random.key(seed), self.cfg)
+            source = gpt2.init_params(jax.random.key(seed), self.cfg)
             self.checkpoint = None
+        # the replica's one copy of the weights on the device, and what
+        # `_step` and `_chunk_step` take: converted once, here, to what the
+        # step programs read (gpt2.resident_params). What was made, loaded
+        # or handed over goes with this frame; the weight store published
+        # the loader's tree, so a puller converts after loading as well
+        self.params = gpt2.resident_params(source, self.cfg)
+        del source
         # weight identity for the cluster prefix store: engines whose KV
         # is interchangeable must agree on it. Checkpoint path or
         # preset+seed derive it; params_override callers (LoRA adapters)
@@ -335,7 +341,7 @@ class LLMEngine:
                 devices=jax.devices()[:tensor_parallel_size])
             self.mesh = mesh
             with use_mesh(mesh):
-                pspecs = gpt2.param_specs(cfg)
+                pspecs = gpt2.resident_specs(cfg)
             param_sh = jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs)
             self.params = jax.tree.map(jax.device_put, self.params,

@@ -216,3 +216,103 @@ def test_an_undonated_cache_is_left_as_it_was(params):
     for name in "kv":
         assert np.array_equal(np.asarray(cache[name]), before[name])
         assert not np.array_equal(np.asarray(new_cache[name]), before[name])
+
+
+# ------------------------------------------------- the resident tree (PR 26)
+
+_DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+_CONVERTED = [("attn", n) for n in ("wqkv", "bqkv", "wo", "bo")] + \
+    [("mlp", n) for n in ("wi", "bi", "wo", "bo")]
+
+
+def _bits(a) -> np.ndarray:
+    return np.asarray(a).view(np.uint8)
+
+
+def _same_bits(a, b) -> bool:
+    return a.dtype == b.dtype and np.array_equal(_bits(a), _bits(b))
+
+
+@pytest.fixture(scope="module", params=list(_DTYPES))
+def trees(request):
+    """(cfg, a tree of `init_params`, its resident tree) for a compute
+    dtype."""
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny", dtype=_DTYPES[request.param],
+                                 max_seq_len=T, attn_impl="dense")
+    source = gpt2.init_params(jax.random.key(7), cfg)
+    return cfg, source, gpt2.resident_params(source, cfg)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_a_resident_tree_gives_the_source_trees_bits(trees, program):
+    """The weights converted once are the values the step converted them
+    to: logits and both caches equal to the bit, in either compute dtype."""
+    cfg, source, resident = trees
+    rng = np.random.default_rng(21)
+    shape = (cfg.n_layer, B, cfg.n_head, T, cfg.head_dim)
+    cache = {n: jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+             for n in "kv"}
+    active = jnp.asarray([True, True, False, True])
+    pos0 = jnp.asarray([5, 127, 17, T - C], jnp.int32)
+    if program == "decode_step":
+        fn = jax.jit(lambda p, c, t, pos, a: gpt2.decode_step(
+            p, c, t, pos, a, cfg))
+        args = (jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32),
+                pos0, active)
+    else:
+        fn = jax.jit(lambda p, c, t, p0, n, a: gpt2.prefill_chunk(
+            p, c, t, p0, n, a, cfg))
+        args = (jnp.asarray(rng.integers(0, cfg.vocab_size, (B, C)),
+                            jnp.int32),
+                pos0, jnp.asarray([1, C, 3, C], jnp.int32), active)
+    logits, new = fn(source, cache, *args)
+    logits_r, new_r = fn(resident, cache, *args)
+    assert logits.dtype == jnp.float32 and np.asarray(logits).any()
+    assert _same_bits(logits, logits_r)
+    for n in "kv":
+        assert not _same_bits(new[n], cache[n])       # the step wrote
+        assert _same_bits(new[n], new_r[n])
+
+
+def test_resident_tree_leaf_dtypes(trees):
+    """The eight leaves of a block that the programs convert, and the
+    unembedding, are in the compute dtype; the table, the positions and
+    every norm stay float32, and are the source's own arrays."""
+    cfg, source, resident = trees
+    blocks = resident["blocks"]
+    for part, name in _CONVERTED:
+        leaf = blocks[part][name]
+        assert leaf.dtype == cfg.dtype, (part, name)
+        assert leaf.shape == source["blocks"][part][name].shape
+        assert _same_bits(leaf, source["blocks"][part][name].astype(cfg.dtype))
+    assert resident["unembed"].dtype == cfg.dtype
+    assert resident["unembed"].shape == (cfg.d_model, cfg.vocab_size)
+    assert _same_bits(resident["unembed"],
+                      source["wte"].T.astype(cfg.dtype))
+    kept = [("wte",), ("wpe",), ("ln_f", "scale"), ("ln_f", "bias")] + \
+        [("blocks", ln, n) for ln in ("ln1", "ln2")
+         for n in ("scale", "bias")]
+    for path in kept:
+        a, b = resident, source
+        for k in path:
+            a, b = a[k], b[k]
+        assert a.dtype == jnp.float32, path
+        assert a is b, path             # handed on, not copied
+    # nothing else: the source's leaves and the unembedding
+    assert len(jax.tree.leaves(resident)) == len(jax.tree.leaves(source)) + 1
+
+
+def test_resident_params_is_idempotent(trees):
+    """A resident tree goes in, the same values come out, and the leaves
+    that needed nothing are the same arrays; the unembedding is made again
+    from the table, so a table that changed reaches the logits."""
+    cfg, _, resident = trees
+    again = gpt2.resident_params(resident, cfg)
+    assert jax.tree.structure(again) == jax.tree.structure(resident)
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(resident)):
+        assert _same_bits(a, b)
+    for part, name in _CONVERTED:
+        assert again["blocks"][part][name] is resident["blocks"][part][name]
+    moved = {**resident, "wte": resident["wte"] * 2}
+    assert _same_bits(gpt2.resident_params(moved, cfg)["unembed"],
+                      moved["wte"].T.astype(cfg.dtype))

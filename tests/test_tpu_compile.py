@@ -114,14 +114,17 @@ def test_flash_ceiling_is_where_the_compiler_puts_it(chips, as_on_tpu,
 
 # ------------------------------------------------------------ serving steps
 
-def _serving_step(chips, program, C=16):
-    """The donated step program of `serve/llm.LLMEngine` at 125M widths,
-    lowered for one described chip. Returns (lowered, cfg, (B, T))."""
+def _serving_step(chips, program, C=16, preset="gpt2-125m", **widths):
+    """The donated step program of `serve/llm.LLMEngine` at a preset's
+    widths (125M unless told), lowered for one described chip on the tree
+    the engine holds (`gpt2.resident_params`). Returns (lowered, cfg,
+    (B, T))."""
     B, T = 8, 1024
     one = SingleDeviceSharding(chips[0])
-    cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=T)
+    cfg = gpt2.GPT2Config.preset(preset, max_seq_len=T, **widths)
     params = _on(one, jax.eval_shape(
-        lambda: gpt2.init_params(jax.random.key(0), cfg)))
+        lambda: gpt2.resident_params(
+            gpt2.init_params(jax.random.key(0), cfg), cfg)))
     cache = _on(one, jax.eval_shape(lambda: gpt2.init_cache(cfg, B, T)))
 
     def arr(shape, dtype):
@@ -149,18 +152,20 @@ def test_serving_step_compiles_at_125m_widths(chips, program):
     assert _per_device_bytes(compiled) < HBM_BYTES
 
 
-def _written_arrays(hlo: str, dims: str) -> list:
+def _written_arrays(hlo: str, dims: str, dtype: str = "bf16") -> list:
     """(operation, type) of every instruction outside a fused computation
-    whose result holds a `bf16[<dims>]`: what the program materialises.
-    Inside a fusion a slice or a broadcast of that shape is only read."""
+    whose result holds a `<dtype>[<dims>]` (`dims` a regular expression):
+    what the program materialises. Inside a fusion a slice or a broadcast
+    of that shape is only read."""
     fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    holds = re.compile(r"%s\[(?:%s)\]" % (dtype, dims))
     found, skip = [], False
     for line in hlo.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
         if head:
             skip = head.group(1) in fused
         m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
-        if m and not skip and f"bf16[{dims}]" in m.group(1):
+        if m and not skip and holds.search(m.group(1)):
             found.append((m.group(2), m.group(1)))
     return found
 
@@ -173,11 +178,16 @@ def _written_arrays(hlo: str, dims: str) -> list:
 # `copy-start`/`copy-done` of a layer's bf16[1,8,12,1024,64] or
 # bf16[8,12,1024,64] (the decode program; the chunk program's alike), and
 # cost_analysis() counted 2.42 (decode), 2.82 (chunk of 16) and 2.97 GB
-# (chunk of 128) accessed against 0.80 GB of weights and cache; now 0.75,
-# 0.81 and 1.43 GB (a chunk of 128 reads and writes [8,12,128,1024] scores)
+# (chunk of 128) accessed against 0.80 GB of weights and cache; PR 24: 0.75,
+# 0.81 and 1.43 GB (a chunk of 128 reads and writes [8,12,128,1024] scores).
+# Until PR 26 the programs took float32 weights and converted all of them
+# in every step; on the resident tree (the matrices bf16 once, the float32
+# table only gathered from) they hold 0.71 GB and access 0.45, 0.51 and
+# 0.68 GB: 0.64, 0.72 and 0.97 of what they hold, where PR 24's were 0.94,
+# 1.01 and 1.79
 @pytest.mark.parametrize("program,C,most", [
-    ("decode_step", 0, 1.25), ("prefill_chunk", 16, 1.25),
-    ("prefill_chunk", 128, 2.25)],
+    ("decode_step", 0, 0.8), ("prefill_chunk", 16, 0.9),
+    ("prefill_chunk", 128, 1.2)],
     ids=["decode_step", "prefill_chunk-16", "prefill_chunk-128"])
 def test_serving_step_updates_the_cache_in_place(chips, program, C, most):
     lowered, cfg, (B, T) = _serving_step(chips, program, C)
@@ -200,6 +210,43 @@ def test_serving_step_updates_the_cache_in_place(chips, program, C, most):
     held = sum(math.prod(a.shape) * a.dtype.itemsize
                for a in jax.tree.leaves(lowered.args_info))
     assert accessed < most * held, (accessed, held)
+
+
+# what an instruction may do with a stack of the layers' weights without
+# writing one: hand the argument into the loop
+_HANDED_ON = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+@pytest.mark.parametrize("program,C", [
+    ("decode_step", 0), ("prefill_chunk", 128)],
+    ids=["decode_step", "prefill_chunk-128"])
+@pytest.mark.parametrize("widths", [
+    dict(preset="gpt2-125m"),
+    dict(preset="gpt2-1.5b", vocab_size=50304)],     # the benchmark's
+    ids=["125m", "xl"])
+def test_serving_step_converts_no_weights(chips, program, C, widths):
+    """On the tree the engine holds, a step makes no bf16 copy of any
+    [n_layer, ...] stack of weights (until PR 26 eight `convert`s a step,
+    hoisted out of the loop: 13.6 ms of a 31.6 ms decode step at XL) and
+    neither transposes, converts nor copies the table for the logits: the
+    unembedding is read where the caller put it."""
+    lowered, cfg, _ = _serving_step(chips, program, C, **widths)
+    hlo = lowered.compile().as_text()
+    stacks = _written_arrays(hlo, r"%d,\d+(,\d+)?" % cfg.n_layer)
+    assert stacks and {op for op, _ in stacks} <= _HANDED_ON, stacks
+    V, D = cfg.vocab_size, cfg.d_model
+    # (at 125M the compiler prefetches it into fast memory while the loop
+    # runs, `copy-start`/`copy-done` in the layout it has: that is a read)
+    unembed = _written_arrays(hlo, f"{D},{V}|{V},{D}")
+    assert {op for op, _ in unembed} <= {"parameter", "copy-start",
+                                         "copy-done"}, unembed
+    # the float32 table is only gathered from. At 1600 wide the chip's
+    # own layout of [V, 1600] has the vocabulary along the lanes (1600 is
+    # no multiple of 128), and the gather has it copied row-major first:
+    # 322 MB a step, `copy.4` in the traces since PR 22 (PERF.md, PR 26)
+    table = [op for op, _ in _written_arrays(hlo, f"{V},{D}", "f32")]
+    relaid = ["copy"] if D % 128 else []
+    assert sorted(table) == sorted(["parameter"] + relaid), table
 
 
 # --------------------------------------------------------------- train step
