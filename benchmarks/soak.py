@@ -61,14 +61,12 @@ FIXED seed, so a failure replays identically:
   holds and ZERO non-shed failures surface on either route.
 
   phase 4 — elastic-train drill: a 2-worker GPT-2-DDP run
-  (microbenchmark._elastic_train_loop); once the gang makes progress, a
+  (`_elastic_train_loop`); once the gang makes progress, a
   `kill:*:n=1` plan is injected into one daemon over the chaos control
   plane (`set_node_chaos`), so the daemon SIGKILLs itself on its next
   outbound call — a chaos-injected daemon kill, not a test harness kill.
   The controller must shrink to the surviving worker, restore the
-  resharded checkpoint, and FINISH; the kill→first-post-restore-step time
-  is reported (same definition as the `elastic_train_recovery_s` gate
-  row).
+  resharded checkpoint, and FINISH.
 
 Run: `python benchmarks/soak.py [--seed 7] [--out soak.json]`
 """
@@ -81,7 +79,6 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -745,33 +742,256 @@ def proxy_compiled_soak(seed: int, duration_s: float = 10.0,
 
 
 def shuffle_kill_soak(seed: int, P: int = 4) -> dict:
-    """Kill-a-shuffle-node phase (ISSUE 15): a distributed hash shuffle
-    lands its map sub-blocks on one isolated node; that node is
-    SIGKILLed before the reduce stage consumes them. Lineage
-    reconstruction re-runs exactly the lost map tasks on a replacement
-    node and the reduce output must be byte-identical to the in-process
-    reference. One drill body, shared with the `shuffle_recovery_s`
-    bench row (the `run_elastic_drill` pattern)."""
-    from microbenchmark import run_shuffle_kill_drill
+    """Kill-a-shuffle-node phase (ISSUE 15): an isolation-mode cluster
+    lands every map sub-block of a distributed hash shuffle on one node,
+    that node is SIGKILLed before the reduce stage consumes them, and the
+    shuffle must complete byte-identical to the in-process reference
+    through lineage reconstruction of exactly the lost map tasks on a
+    replacement node."""
+    import numpy as np
+    import ray_tpu
+    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.data import shuffle as shf
 
-    return run_shuffle_kill_drill(seed=seed, P=P)
+    n_blocks = 4
+    saved = os.environ.get("RAY_TPU_STORE_ISOLATION")
+    os.environ["RAY_TPU_STORE_ISOLATION"] = "1"
+    cluster = Cluster(num_cpus=0)
+    node_a = cluster.add_node(num_cpus=2, resources={"nodeA": 4})
+    cluster.add_node(num_cpus=2, resources={"nodeB": 4})
+    try:
+        cluster.connect()
+        cluster.wait_for_nodes(3)
+        rng = np.random.default_rng(seed)
+        blocks = [{"k": np.arange(1600, dtype=np.int64) + 1600 * i,
+                   "x": rng.random((1600, 64))} for i in range(n_blocks)]
+        parts = [shf._map_partition(b, [], P, "hash", "k", None, None)
+                 for b in blocks]
+        expected = [shf._reduce_concat(*[pp[p] for pp in parts])
+                    for p in range(P)]
+        map_task = ray_tpu.remote(shf._map_partition).options(
+            num_returns=P, name="data_shuffle_map", data_stage=True,
+            resources={"nodeA": 1})
+        reducer = ray_tpu.remote(shf._reduce_concat).options(
+            name="data_shuffle_reduce", lineage=True, data_stage=True,
+            resources={"nodeB": 1})
+        refs = [map_task.remote(b, [], P, "hash", "k", None, None)
+                for b in blocks]
+        flat = [r for rs in refs for r in rs]
+        ready, _ = ray_tpu.wait(flat, num_returns=len(flat), timeout=120)
+        assert len(ready) == len(flat), "map stage never completed"
+        cluster.kill_node(node_a)
+        t0 = time.perf_counter()
+        cluster.add_node(num_cpus=2, resources={"nodeA": 4})
+        out = [reducer.remote(*[refs[m][p] for m in range(n_blocks)])
+               for p in range(P)]
+        got = ray_tpu.get(out, timeout=240)
+        recovery_s = time.perf_counter() - t0
+        for g, e in zip(got, expected):
+            for col in e:
+                assert np.array_equal(np.asarray(g[col]),
+                                      np.asarray(e[col])), \
+                    f"column {col} diverged after reconstruction"
+        from ray_tpu.util import state
+
+        recon = 0
+        deadline = time.time() + 20
+        while time.time() < deadline:
+            recon = next((row.get("data_reconstructs", 0)
+                          for row in state.list_scheduler_stats()
+                          if row.get("is_head")), 0)
+            if recon >= n_blocks * P:
+                break
+            time.sleep(0.2)
+        assert recon > 0, "no lineage reconstruction recorded"
+        return {"partitions": P, "sub_blocks_lost": n_blocks * P,
+                "sub_blocks_reconstructed": recon,
+                "recovery_s": round(recovery_s, 2)}
+    finally:
+        try:
+            ray_tpu.shutdown()
+        except Exception:
+            pass
+        cluster.shutdown()
+        if saved is None:
+            os.environ.pop("RAY_TPU_STORE_ISOLATION", None)
+        else:
+            os.environ["RAY_TPU_STORE_ISOLATION"] = saved
+
+
+def _elastic_train_loop(config):
+    """Tiny GPT-2 DDP loop for the elastic-recovery soak: per-worker
+    2-device mesh, cross-worker kv-collective grad sync, sharded
+    checkpoint every step (the restore path reshards it to whatever world
+    size survives)."""
+    import json
+    import os as _os
+    import tempfile
+    import time as _t
+
+    from ray_tpu.utils.platform import ensure_virtual_cpu
+
+    ensure_virtual_cpu(2)
+    import jax
+    import numpy as _np
+
+    from ray_tpu import train
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.train import Checkpoint
+    from ray_tpu.train.spmd import (compile_gpt2_train,
+                                    cross_worker_grad_sync,
+                                    default_optimizer, restore_state_sharded,
+                                    save_state_sharded)
+    from ray_tpu.util import collective
+
+    ctx = train.get_context()
+    world, rank, gen = (ctx.get_world_size(), ctx.get_world_rank(),
+                        ctx.get_generation())
+    mesh = build_mesh(MeshConfig(dp=2), devices=jax.devices()[:2])
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny", vocab_size=128, max_seq_len=16,
+                                 n_layer=1, n_head=2, d_model=32, d_ff=64)
+    prog = compile_gpt2_train(
+        cfg, mesh, optimizer=default_optimizer(lr=1e-2, warmup=1,
+                                               total_steps=config["steps"]))
+    ck = ctx.get_checkpoint()
+    if ck is not None:
+        state = restore_state_sharded(ck.as_directory(), prog)
+        start = int(state.step)
+    else:
+        state = prog.init_fn(jax.random.key(0))
+        start = 0
+    group = None
+    if world > 1:
+        group = f"ddp:{config['run']}:g{gen}"
+        collective.rebuild_collective_group(world, rank, backend="kv",
+                                            group_name=group)
+    rng = _np.random.default_rng(rank)
+    tokens = jax.device_put(
+        rng.integers(0, cfg.vocab_size, (4, 17), dtype=_np.int32),
+        prog.batch_sharding)
+    for step in range(start, config["steps"]):
+        loss, grads = prog.grad_fn(state, {"tokens": tokens})
+        if world > 1:
+            grads = cross_worker_grad_sync(grads, group, world)
+        state = prog.apply_fn(state, grads)
+        ckpt = None
+        if rank == 0:
+            d = tempfile.mkdtemp(prefix="bench_ckpt_")
+            save_state_sharded(state, d, world_size=world)
+            ckpt = Checkpoint(d)
+            with open(config["history"], "a") as f:
+                f.write(json.dumps({"gen": gen, "step": step,
+                                    "world": world, "loss": float(loss),
+                                    "ts": _t.time()}) + "\n")
+        train.report({"loss": float(loss), "step": step, "world": world},
+                     checkpoint=ckpt)
+        _t.sleep(config.get("step_s", 0.0))
+
+
+def read_jsonl_history(path: str) -> list:
+    """History lines appended by another process: tolerate a torn
+    trailing line mid-append instead of crashing the caller."""
+    if not os.path.exists(path):
+        return []
+    out = []
+    with open(path) as f:
+        for line in f:
+            try:
+                out.append(json.loads(line))
+            except ValueError:
+                pass
+    return out
 
 
 def elastic_train_drill(seed: int, steps: int = 30) -> dict:
-    """The tentpole acceptance drill as a soak phase: the shared harness
-    (`microbenchmark.run_elastic_drill`), with the kill delivered by the
-    chaos plane — `set_node_chaos` arms a seeded `kill:*:n=1` plan, so
-    the victim daemon SIGKILLs ITSELF on its next outbound control-plane
-    call (a chaos-injected kill, not a harness kill)."""
-    from microbenchmark import run_elastic_drill
+    """The tentpole acceptance drill as a soak phase: a 2-worker
+    GPT-2-DDP run on a head + 2 one-CPU nodes; once the gang makes
+    progress the chaos plane delivers the kill — `set_node_chaos` arms a
+    seeded `kill:*:n=1` plan, so the victim daemon SIGKILLs ITSELF on its
+    next outbound control-plane call (a chaos-injected kill, not a
+    harness kill). The drill asserts the controller shrinks to world
+    size 1, restores the resharded checkpoint, and FINISHES covering
+    every step. Returns {recovery_s, restarts, final_world_size, steps}."""
+    import tempfile
+    import threading
 
-    def chaos_kill(cluster, nids, client):
+    import ray_tpu
+    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.train import (ElasticConfig, FailureConfig, RunConfig,
+                               ScalingConfig)
+    from ray_tpu.train.controller import TrainControllerLogic
+
+    run_name = f"soak{seed}"
+    storage = tempfile.mkdtemp(prefix=f"{run_name}_")
+    history = os.path.join(storage, "history.jsonl")
+    cluster = Cluster(num_cpus=0)
+    nids = [cluster.add_node(num_cpus=1), cluster.add_node(num_cpus=1)]
+    try:
+        cluster.connect()
+        cluster.wait_for_nodes(3)
+        client = ray_tpu.core.api._global_client()
+        logic = TrainControllerLogic(
+            _elastic_train_loop,
+            {"steps": steps, "run": run_name, "history": history,
+             "step_s": 0.1},
+            ScalingConfig(num_workers=2, min_workers=1,
+                          resources_per_worker={"CPU": 1},
+                          elastic=ElasticConfig(regrow=False,
+                                                schedule_wait_s=30.0)),
+            RunConfig(name=run_name, storage_path=storage,
+                      failure_config=FailureConfig(max_failures=2)))
+        box = {}
+
+        def _run():
+            try:
+                box["result"] = logic.run()
+            except BaseException as e:
+                box["error"] = e
+
+        t = threading.Thread(target=_run, daemon=True)
+        t.start()
+        deadline = time.time() + 180
+        while time.time() < deadline:
+            if any(e["world"] == 2 and e["step"] >= 3
+                   for e in read_jsonl_history(history)):
+                break
+            time.sleep(0.1)
+        else:
+            raise AssertionError("2-worker run never made progress")
+        t_kill = time.time()
         assert client.head_request(
             "set_node_chaos", node_id=bytes.fromhex(nids[1]),
             spec=f"seed={seed},kill:*:n=1") is True
-
-    return run_elastic_drill(chaos_kill, steps=steps,
-                             run_name=f"soak{seed}")
+        deadline = time.time() + 180
+        first_post = None
+        while time.time() < deadline:
+            post = [e for e in read_jsonl_history(history)
+                    if e["gen"] >= 1]
+            if post:
+                first_post = post[0]
+                break
+            time.sleep(0.05)
+        assert first_post is not None, "never recovered after daemon kill"
+        t.join(timeout=240)
+        assert not t.is_alive(), "controller never finished"
+        if "error" in box:
+            raise box["error"]
+        result = box["result"]
+        assert result["state"] == "FINISHED", result["error"]
+        assert result["final_world_size"] == 1, result
+        entries = read_jsonl_history(history)
+        assert {e["step"] for e in entries} == set(range(steps))
+        return {"recovery_s": round(first_post["ts"] - t_kill, 2),
+                "restarts": result["restarts"],
+                "final_world_size": result["final_world_size"],
+                "steps": steps}
+    finally:
+        try:
+            ray_tpu.shutdown()
+        except Exception:
+            pass
+        cluster.shutdown()
 
 
 def main(seed: int = 7, out: str | None = None, rounds: int = 6,

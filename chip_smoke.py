@@ -45,8 +45,9 @@ FOUR_CHIP_PHASES = (("build", 120), ("train_mesh", 600),
 RUN_LIMIT_S = 1150
 SEED = 0    # weights, batches and prompts are all made from it
 
-# train: bench.py's cell. The per-chip batch is the first of these whose
-# compiled step fits the device (decided by memory_analysis, printed).
+# train: the model of the benchmark's `train-small-1k`. The per-chip batch
+# is the first of these whose compiled step fits the device (decided by
+# memory_analysis, printed).
 TRAIN_PRESET, TRAIN_SEQ = "gpt2-125m", 1024
 TRAIN_PER_CHIP_BATCHES = (24, 20, 16, 12, 8, 4)
 TRAIN_STEPS = 10

@@ -226,6 +226,23 @@ def carve_pool(client, sched_addr, n, timeout: float = 90,
     assert got == n, f"carved {got}/{n} at {sched_addr}"
 
 
+def warm_daemon_lease(client, submit_and_get, timeout=90, idle_wait=1.5):
+    """Drive `submit_and_get()` until the driver holds a DAEMON-granted
+    lease (two-level warm path). The head may win the cold-grant race;
+    when it does, wait `idle_wait` so the head lease idles out, then
+    retry — the daemon's node has warm pool workers by then and grants
+    instantly. Shared by the chaos/head-FT drills so the known-flaky
+    warmup dance has one implementation."""
+    deadline = time.time() + timeout
+    while (time.time() < deadline
+           and client.lease_stats["daemon_grants"] == 0):
+        submit_and_get()
+        if client.lease_stats["daemon_grants"]:
+            break
+        time.sleep(idle_wait if client._leases else 0.05)
+    assert client.lease_stats["daemon_grants"] >= 1, client.lease_stats
+
+
 class VirtualNodes:
     """N fake node registrations over real sockets on a private loop —
     the reference cluster_utils strategy scaled past process counts: all

@@ -196,13 +196,9 @@ def _w(p, cfg: "GPT2Config"):
         return p.astype(cfg.dtype)
 
 
-def _resolve_attn_impl(cfg: GPT2Config, seq_len: int) -> str:
+def _attention(x, p, cfg: GPT2Config):
     from ray_tpu.models.lm import resolve_attn_impl
 
-    return resolve_attn_impl(cfg.attn_impl, seq_len)
-
-
-def _attention(x, p, cfg: GPT2Config):
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
     qkv = x @ _w(p["wqkv"], cfg) + _w(p["bqkv"], cfg)
@@ -214,7 +210,7 @@ def _attention(x, p, cfg: GPT2Config):
     k = constrain(k, "batch", "heads", "seq", None)
     v = constrain(v, "batch", "heads", "seq", None)
 
-    impl = _resolve_attn_impl(cfg, T)
+    impl = resolve_attn_impl(cfg.attn_impl, T)
     if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 
@@ -591,13 +587,6 @@ def num_params(cfg: GPT2Config) -> int:
     d, f, L, V, S = cfg.d_model, cfg.d_ff, cfg.n_layer, cfg.vocab_size, cfg.max_seq_len
     per_block = (3 * d * d + 3 * d) + (d * d + d) + (2 * d * f + f + d) + 4 * d
     return V * d + S * d + L * per_block + 2 * d
-
-
-def flops_per_token(cfg: GPT2Config, seq_len: int) -> float:
-    """Approx training FLOPs/token (fwd+bwd ≈ 6*N + attention term)."""
-    n = num_params(cfg) - cfg.vocab_size * cfg.d_model  # non-embedding
-    attn = 12 * cfg.n_layer * cfg.d_model * seq_len
-    return 6 * (n + cfg.vocab_size * cfg.d_model) + attn
 
 
 # ---------------------------------------------------------------------------

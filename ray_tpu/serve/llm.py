@@ -185,15 +185,13 @@ def plan_chunk_budget(pending_lens: List[int], decoding: List[bool],
 class LLMEngine:
     """Continuous-batching decode engine over a fixed slot batch.
 
-    `scheduler="continuous"` (default) is per-step join/evict with a
-    token-budget step plan: new requests enter the running batch at the
-    next decode step, finished sequences free their KV slot immediately,
-    and long prompts prefill in `prefill_chunk_size`-token chunks
-    (gpt2.prefill_chunk) under `max_num_batched_tokens` per step, with
-    decode lanes reserved first so prefill can't starve decode.
-    `scheduler="fixed"` is the admit-then-run loop kept for the serve
-    bench comparison: a batch is admitted only when every slot is free
-    and runs token-by-token to completion before the next admit.
+    Per-step join/evict with a token-budget step plan: new requests
+    enter the running batch at the next decode step, finished sequences
+    free their KV slot immediately, and long prompts prefill in
+    `prefill_chunk_size`-token chunks (gpt2.prefill_chunk) under
+    `max_num_batched_tokens` per step, with decode lanes reserved first
+    so prefill can't starve decode. `scheduler` names that one loop:
+    deployment configs carry the key, and any other value is refused.
     """
 
     def __init__(self, preset: str = "gpt2-tiny", max_batch: int = 4,
@@ -210,6 +208,9 @@ class LLMEngine:
                  params_override=None, cfg_override=None,
                  weights_id: Optional[str] = None,
                  weight_store: bool = True):
+        if scheduler != "continuous":
+            raise ValueError(
+                f'scheduler must be "continuous", got {scheduler!r}')
         import jax
         import jax.numpy as jnp
 
@@ -310,7 +311,6 @@ class LLMEngine:
                                    block_size=kv_block_size,
                                    dtype=cfg.dtype)
 
-        self.scheduler = scheduler
         # chunk must fit the serving window (prefill_chunk requires C <= T)
         self.prefill_chunk_size = max(1, min(prefill_chunk_size,
                                              self.max_seq_len - 1))
@@ -365,8 +365,6 @@ class LLMEngine:
             self.mesh = None
             self._step = jax.jit(_step, donate_argnums=(1,))
             self._chunk_step = jax.jit(_chunk, donate_argnums=(1,))
-        if self.scheduler == "fixed":
-            self._chunk_step = None   # legacy admit-then-run, 1 token/step
         self.tokenizer = tokenizer if tokenizer is not None else ByteTokenizer()
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -582,12 +580,6 @@ class LLMEngine:
     # ------------------------------------------------------------- engine
     def _admit(self):
         self._admit_deferred()
-        if self.scheduler == "fixed":
-            # admit-then-run: a new batch forms only once EVERY slot is
-            # free (the seed loop the continuous scheduler replaces; kept
-            # for the serve bench A/B)
-            if any(r is not None for r in self._slots):
-                return
         for i in range(self.max_batch):
             if self._slots[i] is None:
                 req = self._next_ready()
@@ -712,8 +704,7 @@ class LLMEngine:
                 with phase["empty"]:
                     time.sleep(0.005)
                 stepped = False
-            elif (self._chunk_step is not None
-                    and any(self._slot_prefill[i] for i in live)):
+            elif any(self._slot_prefill[i] for i in live):
                 stepped = self._run_chunk_step(live, rng, np)
             else:
                 stepped = self._run_decode_step(live, rng, np)
@@ -723,8 +714,8 @@ class LLMEngine:
             t_pass = now
 
     def _run_decode_step(self, live, rng, np):
-        """One single-token step for every live slot (the pure-decode fast
-        path; also the only step the fixed scheduler ever runs)."""
+        """One single-token step for every live slot: the pure-decode
+        fast path, reached only when no live slot is prefilling."""
         jnp, phase = self.jnp, self._phase
         with phase["plan"]:
             tokens = np.zeros((self.max_batch,), np.int32)
@@ -732,12 +723,9 @@ class LLMEngine:
             active = np.zeros((self.max_batch,), bool)
             for i in live:
                 active[i] = True
-                if self._slot_prefill[i]:
-                    tokens[i] = self._slot_prefill[i][0]
-                else:
-                    tokens[i] = (self._slots[i].generated[-1]
-                                 if self._slots[i].generated
-                                 else self._slots[i].prompt_ids[-1])
+                tokens[i] = (self._slots[i].generated[-1]
+                             if self._slots[i].generated
+                             else self._slots[i].prompt_ids[-1])
         with phase["dispatch"]:
             logits, self.cache = self._step(
                 self.params, self.cache, jnp.asarray(tokens),
@@ -748,18 +736,6 @@ class LLMEngine:
         for i in live:
             req = self._slots[i]
             self._slot_pos[i] += 1
-            if self._slot_prefill[i]:
-                self._slot_prefill[i].pop(0)
-                self.tokens_prefilled += 1
-                if self._slot_prefill[i]:
-                    continue  # still prefilling; ignore logits
-                if self.kv is not None:
-                    # prompt fully resident in this slot's cache:
-                    # publish its full blocks for future prefix hits
-                    # (dedup'd: shared prefixes stored once)
-                    with phase["publish"]:
-                        self.kv.store_prefix(req.prompt_ids,
-                                             self.cache, i)
             self._finish_token(i, req, logits[i], rng, np)
         return True
 

@@ -46,9 +46,9 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Point this process — and, through the environment, every child it
     starts — at `compile_cache_dir()`. Called at start-up by every process
-    that compiles (workers before user code, bench.py, chip_smoke.py's
-    phases); imports no JAX itself, since JAX reads the variables when it
-    is imported.
+    that compiles (workers before user code, chip_smoke.py's phases);
+    imports no JAX itself, since JAX reads the variables when it is
+    imported.
 
     The cache's key takes in each program's metadata (operation names with
     their `jax.named_scope`s, source lines). JAX leaves it out by default,

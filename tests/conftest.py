@@ -25,22 +25,3 @@ def devices8():
     if len(devs) < 8:
         pytest.skip("needs 8 virtual devices")
     return devs[:8]
-
-
-def warm_daemon_lease(client, submit_and_get, timeout=90, idle_wait=1.5):
-    """Drive `submit_and_get()` until the driver holds a DAEMON-granted
-    lease (two-level warm path). The head may win the cold-grant race;
-    when it does, wait `idle_wait` so the head lease idles out, then
-    retry — the daemon's node has warm pool workers by then and grants
-    instantly. Shared by the chaos/head-FT drills so the known-flaky
-    warmup dance has one implementation."""
-    import time as _time
-
-    deadline = _time.time() + timeout
-    while (_time.time() < deadline
-           and client.lease_stats["daemon_grants"] == 0):
-        submit_and_get()
-        if client.lease_stats["daemon_grants"]:
-            break
-        _time.sleep(idle_wait if client._leases else 0.05)
-    assert client.lease_stats["daemon_grants"] >= 1, client.lease_stats

@@ -492,7 +492,7 @@ def test_daemon_partition_warm_path_continues_and_gossip_drains():
     node goes stale; after heal the daemon's queued flight-recorder
     events drain (delivery acks requeue un-acked batches) and its
     counters catch up at the head."""
-    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.cluster_utils import Cluster, warm_daemon_lease
     from ray_tpu.util import state
 
     overrides = {"RAY_TPU_POOL_IDLE_S": "60",
@@ -519,8 +519,6 @@ def test_daemon_partition_warm_path_continues_and_gossip_drains():
 
         assert ray_tpu.get([square.remote(i) for i in range(8)],
                            timeout=120) == [i * i for i in range(8)]
-        from conftest import warm_daemon_lease
-
         warm_daemon_lease(client,
                           lambda: ray_tpu.get(square.remote(2), timeout=60))
 
@@ -579,3 +577,22 @@ def test_daemon_partition_warm_path_continues_and_gossip_drains():
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
+
+
+def test_no_test_imports_from_a_module_called_conftest():
+    """Two modules are called `conftest` (this directory's and
+    `chip_bench/`'s, with no package between them), so in a worker that
+    collected a `chip_bench` file first a name imported from `conftest`
+    comes from the wrong one: the two drills that did so failed for five
+    PRs. Shared helpers live in `ray_tpu.cluster_utils`."""
+    tests_dir = os.path.dirname(os.path.abspath(__file__))
+    needle = "from conftest " + "import"
+    found = []
+    for root, dirs, files in os.walk(tests_dir):
+        dirs[:] = [d for d in dirs if d != "chip_bench"]
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name)) as f:
+                    if needle in f.read():
+                        found.append(os.path.join(root, name))
+    assert found == []

@@ -149,7 +149,7 @@ def test_head_restart_reconciles_daemon_pools_no_double_grant():
     leaked carve-out; (2) the cluster epoch advanced and stale-epoch RPCs
     are rejected-and-counted rather than applied; (3) retryable tasks
     submitted before, during, and after the outage all complete."""
-    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.cluster_utils import Cluster, warm_daemon_lease
     from ray_tpu.util import state
 
     overrides = {
@@ -180,8 +180,6 @@ def test_head_restart_reconciles_daemon_pools_no_double_grant():
 
         assert ray_tpu.get([square.remote(i) for i in range(8)],
                            timeout=120) == [i * i for i in range(8)]
-        from conftest import warm_daemon_lease
-
         warm_daemon_lease(client,
                           lambda: ray_tpu.get(square.remote(2), timeout=60),
                           idle_wait=1.0)

@@ -73,29 +73,52 @@ def test_chunk_budget_plan_reserves_decode_first():
     assert plan_chunk_budget([20, 3], [False, False], 8, 32) == [8, 3]
 
 
-def test_chunked_prefill_matches_fixed_loop_and_uses_fewer_steps():
-    """The continuous scheduler's chunked prefill is byte-identical to
-    the legacy one-token-per-step loop, with far fewer engine steps."""
+def test_chunked_prefill_matches_plain_greedy_and_uses_fewer_steps():
+    """The engine's greedy tokens are those of a plain greedy loop over
+    `gpt2.forward` on the same seed's weights (nothing of the engine, its
+    step programs or its cache in the reference), and chunked prefill
+    takes far fewer engine steps than one token a step would."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import gpt2
     from ray_tpu.serve.llm import LLMEngine
     from ray_tpu.utils.platform import ensure_virtual_cpu
 
     ensure_virtual_cpu(1)
-    kw = dict(max_batch=2, enable_prefix_caching=False, **TINY)
-    fixed = LLMEngine(scheduler="fixed", **kw)
-    cont = LLMEngine(scheduler="continuous", prefill_chunk_size=8, **kw)
+    eng = LLMEngine(scheduler="continuous", prefill_chunk_size=8,
+                    max_batch=2, enable_prefix_caching=False, **TINY)
     try:
         prompt = "the quick brown fox jumps over the lazy dog " * 2
-        want = fixed.generate(prompt, max_tokens=8)["token_ids"]
-        got = cont.generate(prompt, max_tokens=8)["token_ids"]
-        assert got == want, "chunked prefill diverged from per-token loop"
-        fs = fixed.engine_stats()
-        cs = cont.engine_stats()
-        assert cs["chunk_steps"] >= 1
-        assert cs["engine_steps"] < fs["engine_steps"] / 2, (cs, fs)
-        assert cs["ttft_avg_s"] > 0
+        out = eng.generate(prompt, max_tokens=8)
+        got, n_prompt = out["token_ids"], out["prompt_tokens"]
+        cfg = gpt2.GPT2Config.preset(
+            TINY["preset"], max_seq_len=TINY["max_seq_len"],
+            **TINY["model_overrides"])
+        params = gpt2.init_params(jax.random.key(TINY["seed"]), cfg)
+        ids = eng.tokenizer.encode(prompt)
+        assert len(ids) == n_prompt and len(got) >= 4
+        want = []
+        for _ in got:
+            logits = gpt2.forward(params, jnp.asarray([ids + want]), cfg)
+            want.append(int(np.argmax(np.asarray(logits[0, -1]))))
+        assert got == want, "chunked prefill diverged from plain greedy"
+        stats = eng.engine_stats()
+        assert stats["chunk_steps"] >= 1
+        assert stats["engine_steps"] < (n_prompt + 8) / 2, stats
+        assert stats["ttft_avg_s"] > 0
     finally:
-        fixed.shutdown()
-        cont.shutdown()
+        eng.shutdown()
+
+
+def test_scheduler_other_than_continuous_is_refused():
+    """The keyword names the engine's one loop (deployment configs carry
+    it): any other value is refused before anything is built."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    with pytest.raises(ValueError, match="continuous"):
+        LLMEngine(scheduler="fixed", **TINY)
 
 
 def test_request_joins_running_batch_mid_flight():

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import gpt2
+from ray_tpu.models import gpt2, lm
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.train.spmd import (compile_gpt2_train, compile_pipeline_train,
                                 default_optimizer)
@@ -348,7 +348,7 @@ def test_flash_attention_compiles_under_a_mesh(chips, as_on_tpu):
     from ray_tpu.parallel.mesh import logical_to_spec, use_mesh
 
     mesh = build_mesh(MeshConfig(dp=2, tp=2), devices=chips)
-    assert gpt2._resolve_attn_impl(gpt2.GPT2Config(), 2048) == "flash"
+    assert lm.resolve_attn_impl(gpt2.GPT2Config().attn_impl, 2048) == "flash"
     with use_mesh(mesh):
         spec = logical_to_spec("batch", "heads", None, None)
         x = jax.ShapeDtypeStruct((4, 12, 2048, 64), jnp.bfloat16,
