@@ -23,11 +23,16 @@ import uuid
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional
 
+import numpy as np
+
 from ray_tpu.util import tracing
 
-# What the engine's thread does, in the order of one pass of its loop.
-# Each is an `engine.<phase>` span on the profiler's clock and a
-# cumulative timer in `engine_stats()["phase_s"]`.
+# What the engine's thread does in one pass of its loop. Each is an
+# `engine.<phase>` span on the profiler's clock and a cumulative timer in
+# `engine_stats()["phase_s"]`. The loop runs one step ahead of what it has
+# read: `dispatch` launches a step program and `sample` the selection of
+# its tokens on the device; `fetch` is the wait for the ids of the step
+# dispatched a pass earlier, `notify` and `publish` what follows from them.
 ENGINE_PHASES = ("calls", "admit", "plan", "dispatch", "fetch", "sample",
                  "publish", "notify", "empty")
 # seconds; shared by the two request-lifecycle histograms
@@ -130,6 +135,9 @@ class _Request:
         self.prefix_future = prefix_future
         self.prefix_deadline = time.time() + prefix_wait_s
         self.generated: List[int] = []
+        # tokens whose steps are dispatched: `generated` catches up when
+        # the engine reads a step's ids, one step later
+        self.scheduled = 0
         self.done = threading.Event()
         self.error: Optional[str] = None
         self.finish_reason: str = "stop"
@@ -192,6 +200,13 @@ class LLMEngine:
     `max_num_batched_tokens` per step, with decode lanes reserved first
     so prefill can't starve decode. `scheduler` names that one loop:
     deployment configs carry the key, and any other value is refused.
+
+    The next token of every slot is chosen on the device
+    (`serve/sampling.select_tokens`) and stays there as the next step's
+    input, so the loop dispatches step n+1 before it reads step n's ids:
+    the device never waits for the host to learn a token. A reply that
+    ends by length gives up its slot before its last step has run; an EOS
+    is learnt one step late, and the lane-step it cost is dropped.
     """
 
     def __init__(self, preset: str = "gpt2-tiny", max_batch: int = 4,
@@ -318,12 +333,26 @@ class LLMEngine:
             max_num_batched_tokens if max_num_batched_tokens
             else max(2 * max_batch, max_batch + self.prefill_chunk_size))
 
+        from ray_tpu.serve.sampling import select_tokens
+
         def _step(params, cache, tokens, pos, active):
             return gpt2.decode_step(params, cache, tokens, pos, active, cfg)
 
         def _chunk(params, cache, tokens, pos0, length, active):
             return gpt2.prefill_chunk(params, cache, tokens, pos0, length,
                                       active, cfg)
+
+        def _select(logits, prev, produce, sampling, step):
+            temperature, top_k, top_p = sampling
+            key = jax.random.fold_in(jax.random.key(seed), step)
+            return select_tokens(logits, prev, produce, temperature,
+                                 top_k.astype(jnp.int32), top_p, key)
+
+        def _merge(tokens, ids, decoding):
+            # a chunk step's decode lanes read their token where the last
+            # selection left it; prefilling lanes keep the host's prompt
+            return tokens.at[:, 0].set(
+                jnp.where(decoding, ids, tokens[:, 0]))
 
         if tensor_parallel_size > 1:
             # TP-sharded engine (reference: vLLM TP workers in a
@@ -361,10 +390,21 @@ class LLMEngine:
                 in_shardings=(param_sh, {"k": cache_sh, "v": cache_sh},
                               rep, rep, rep, rep),
                 out_shardings=(rep, {"k": cache_sh, "v": cache_sh}))
+            # the ids are replicated, as the logits they are chosen from
+            self._select = jax.jit(_select, out_shardings=rep)
+            self._merge = jax.jit(_merge, out_shardings=rep)
         else:
             self.mesh = None
+            rep = None
             self._step = jax.jit(_step, donate_argnums=(1,))
             self._chunk_step = jax.jit(_chunk, donate_argnums=(1,))
+            self._select = jax.jit(_select)
+            self._merge = jax.jit(_merge)
+        # each slot's newest token, where the selection left it: the next
+        # step's decode lanes read it there, the host reads it a step late
+        self._ids = jax.device_put(np.zeros((max_batch,), np.int32), rep)
+        # rows temperature, top_k, top_p of each slot's request
+        self._sampling = np.zeros((3, max_batch), np.float32)
         self.tokenizer = tokenizer if tokenizer is not None else ByteTokenizer()
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -380,6 +420,10 @@ class LLMEngine:
         self._stop = threading.Event()
         self.total_generated = 0
         self.engine_steps = 0          # jitted step calls (either kind)
+        # steps dispatched while the step before them was still unread
+        self.steps_dispatched_ahead = 0
+        # lane-steps run for a request its EOS had already ended
+        self.overrun_lane_steps = 0
         self.chunk_steps = 0           # steps that ran the chunked program
         self.tokens_prefilled = 0      # prompt tokens processed
         self.prefix_imports = 0        # deferred blobs installed
@@ -653,6 +697,10 @@ class LLMEngine:
         self._slots[i] = req
         self._slot_pos[i] = 0
         self._slot_prefill[i] = list(req.prompt_ids)
+        # a new array, not a write into the old one: the transfer of a
+        # dispatched step's arguments may still be reading that
+        self._sampling = self._sampling.copy()
+        self._sampling[:, i] = req.temperature, req.top_k, req.top_p
         if self.kv is not None and len(req.prompt_ids) > 1:
             # the last prompt token is always re-run (its logits
             # seed generation), so match against ids[:-1]
@@ -675,11 +723,14 @@ class LLMEngine:
                 self._streams.pop(sid, None)
 
     def _engine_loop(self):
-        import numpy as np
-
-        rng = np.random.default_rng(0)
+        """Runs one step ahead of what it has read: a pass admits, plans
+        and dispatches step n+1 from what the host already knows (which
+        slots are live, their positions, which end by length), and only
+        then reads step n's ids. The tokens themselves stay on the device
+        (`self._ids`), so nothing the next step needs waits for the host."""
         last_sweep = time.time()
         phase = self._phase
+        unread = None         # the step dispatched and not read yet
         t_pass = time.perf_counter()
         while not self._stop.is_set():
             if time.time() - last_sweep > 60:
@@ -699,162 +750,158 @@ class LLMEngine:
                         pass
             with phase["admit"]:
                 self._admit()
-            live = [i for i, r in enumerate(self._slots) if r is not None]
-            if not live:
+            step = self._dispatch_step()
+            if unread is not None:
+                if step is not None:
+                    self.steps_dispatched_ahead += 1
+                self._read_step(*unread)
+            elif step is None:
                 with phase["empty"]:
                     time.sleep(0.005)
-                stepped = False
-            elif any(self._slot_prefill[i] for i in live):
-                stepped = self._run_chunk_step(live, rng, np)
-            else:
-                stepped = self._run_decode_step(live, rng, np)
             now = time.perf_counter()
-            if stepped:
+            if step is not None or unread is not None:
                 self.loop_busy_s += now - t_pass
             t_pass = now
+            unread = step
 
-    def _run_decode_step(self, live, rng, np):
-        """One single-token step for every live slot: the pure-decode
-        fast path, reached only when no live slot is prefilling."""
-        jnp, phase = self.jnp, self._phase
-        with phase["plan"]:
-            tokens = np.zeros((self.max_batch,), np.int32)
-            pos = np.asarray(self._slot_pos, np.int32)
-            active = np.zeros((self.max_batch,), bool)
-            for i in live:
-                active[i] = True
-                tokens[i] = (self._slots[i].generated[-1]
-                             if self._slots[i].generated
-                             else self._slots[i].prompt_ids[-1])
-        with phase["dispatch"]:
-            logits, self.cache = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(active))
-        with phase["fetch"]:
-            logits = np.asarray(logits)
-        self.engine_steps += 1
-        for i in live:
-            req = self._slots[i]
-            self._slot_pos[i] += 1
-            self._finish_token(i, req, logits[i], rng, np)
-        return True
+    def _dispatch_step(self):
+        """Plan and dispatch one step for every live slot, then the
+        selection of its tokens; nothing here waits for the device.
+        Returns (ids, lanes, prompts) for `_read_step`, or None when no
+        slot is live. `lanes` are the (slot, request, ends by length)
+        whose logits are a token's. A request that ends by length leaves
+        its slot here, before the step has run: the next pass admits into
+        it. `prompts` are the (prompt ids, slot) this step finishes
+        prefilling and whose blocks `_read_step` pools.
 
-    def _run_chunk_step(self, live, rng, np):
-        """One token-budget step: decode slots advance one token each
-        (reserved first), prefilling slots consume up to a chunk of their
-        remaining prompt — all in ONE fused prefill_chunk call."""
+        With a prompt left in any live slot this is a token-budget step
+        of the chunked program (decode lanes reserved first, one token
+        each; prefilling lanes up to a chunk of their prompt), else one
+        single-token step of the decode program."""
         jnp, phase = self.jnp, self._phase
         B, C = self.max_batch, self.prefill_chunk_size
         with phase["plan"]:
-            pending = [len(self._slot_prefill[i])
-                       if self._slots[i] is not None else 0
-                       for i in range(B)]
-            decoding = [self._slots[i] is not None
-                        and not self._slot_prefill[i] for i in range(B)]
-            takes = plan_chunk_budget(pending, decoding, C,
-                                      self.max_num_batched_tokens)
-            tokens = np.zeros((B, C), np.int32)
-            lengths = np.zeros((B,), np.int32)
+            live = [i for i, r in enumerate(self._slots) if r is not None]
+            if not live:
+                return None
+            pos = np.asarray(self._slot_pos, np.int32)
+            decoding = np.zeros((B,), bool)
             for i in live:
-                take = takes[i]
+                decoding[i] = not self._slot_prefill[i]
+            chunked = not decoding[live].all()
+            if chunked:
+                pending = [len(self._slot_prefill[i])
+                           if self._slots[i] is not None else 0
+                           for i in range(B)]
+                takes = plan_chunk_budget(pending, list(decoding), C,
+                                          self.max_num_batched_tokens)
+                tokens = np.zeros((B, C), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                for i in live:
+                    # never step past the serving window (prefill_chunk
+                    # requires pos0 + length <= T; _make_request already
+                    # bounds prompts)
+                    take = min(takes[i], self.max_seq_len - self._slot_pos[i])
+                    if take <= 0:
+                        continue
+                    lengths[i] = take
+                    if not decoding[i]:
+                        tokens[i, :take] = self._slot_prefill[i][:take]
+                active = lengths > 0
+            else:
+                lengths = active = decoding
+            lanes, prompts, last_prompts = [], [], []
+            produce = np.zeros((B,), bool)
+            for i in live:
+                take = int(lengths[i])
                 if take <= 0:
                     continue
-                # never step past the serving window (prefill_chunk
-                # requires pos0 + length <= T; _make_request already
-                # bounds prompts)
-                take = min(take, self.max_seq_len - self._slot_pos[i])
-                if take <= 0:
-                    continue
-                lengths[i] = take
-                if self._slot_prefill[i]:
-                    tokens[i, :take] = self._slot_prefill[i][:take]
-                else:
-                    req = self._slots[i]
-                    tokens[i, 0] = (req.generated[-1] if req.generated
-                                    else req.prompt_ids[-1])
-            active = lengths > 0
-        if not active.any():
-            with phase["empty"]:
-                time.sleep(0.001)
-            return False
-        with phase["dispatch"]:
-            logits, self.cache = self._chunk_step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(np.asarray(self._slot_pos, np.int32)),
-                jnp.asarray(lengths), jnp.asarray(active))
-        with phase["fetch"]:
-            logits = np.asarray(logits)
-        self.engine_steps += 1
-        self.chunk_steps += 1
-        for i in live:
-            take = int(lengths[i])
-            if take <= 0:
-                continue
-            req = self._slots[i]
-            self._slot_pos[i] += take
-            if self._slot_prefill[i]:
-                del self._slot_prefill[i][:take]
-                self.tokens_prefilled += take
-                if self._slot_prefill[i]:
-                    continue  # chunk didn't cover the prompt yet
-                if self.kv is not None:
-                    with phase["publish"]:
-                        self.kv.store_prefix(req.prompt_ids,
-                                             self.cache, i)
-            # the chunk ended at the prompt's final token (or a decode
-            # lane): its last-position logits seed/continue generation
-            self._finish_token(i, req, logits[i], rng, np)
-        return True
-
-    def _finish_token(self, i, req, logit_row, rng, np):
-        """Sample one token from `logit_row`, append it, and evict the
-        slot the moment the request finishes (its KV slot frees for the
-        next admit — same tick)."""
-        with self._phase["sample"]:
-            nxt = self._sample(req, logit_row, rng, np)
-        with self._phase["notify"]:
-            if req.t_first is None:
-                req.t_first = time.time()
-                self.last_ttft_s = req.t_first - req.t_enqueue
-                self._observe("ttft_s", self.last_ttft_s)
-            req.generated.append(nxt)
-            self.total_generated += 1
-            finished = (len(req.generated) >= req.max_tokens
-                        or nxt == self.tokenizer.eos_id
+                req = self._slots[i]
+                self._slot_pos[i] += take
+                if not decoding[i]:
+                    del self._slot_prefill[i][:take]
+                    self.tokens_prefilled += take
+                    if self._slot_prefill[i]:
+                        continue  # chunk didn't cover the prompt yet
+                # the chunk ends at the prompt's final token (or this is a
+                # decode lane): its last-position logits are a token's
+                produce[i] = True
+                req.scheduled += 1
+                ends = (req.scheduled >= req.max_tokens
                         or self._slot_pos[i] >= self.max_seq_len - 1)
-            if finished:
-                req.finish_reason = ("stop" if nxt == self.tokenizer.eos_id
-                                     else "length")
-                self._slots[i] = None
-                req.t_done = time.time()
-                if req.trace_carrier is not None:
-                    self._record_request_spans(req)
-                req.done.set()
-            with req.progress:
-                req.progress.notify_all()
+                if ends:
+                    self._slots[i] = None
+                if not decoding[i]:
+                    # pooled while the request holds the slot: when its
+                    # first token is read, or here if this step is its last
+                    (last_prompts if ends else prompts).append(
+                        (req.prompt_ids, i))
+                lanes.append((i, req, ends))
+        with phase["dispatch"]:
+            if chunked:
+                logits, self.cache = self._chunk_step(
+                    self.params, self.cache,
+                    self._merge(tokens, self._ids, decoding),
+                    jnp.asarray(pos), jnp.asarray(lengths),
+                    jnp.asarray(active))
+                self.chunk_steps += 1
+            else:
+                logits, self.cache = self._step(
+                    self.params, self.cache, self._ids, jnp.asarray(pos),
+                    jnp.asarray(active))
+        with phase["sample"]:
+            self._ids = self._select(
+                logits, self._ids, produce, self._sampling,
+                np.uint32(self.engine_steps))
+        self.engine_steps += 1
+        self._pool_prompts(last_prompts)
+        return self._ids, lanes, prompts
 
-    @staticmethod
-    def _sample(req, logit_row, rng, np) -> int:
-        if req.temperature <= 0:
-            return int(np.argmax(logit_row))
-        lg = logit_row / req.temperature
-        if req.top_k and req.top_k < len(lg):
-            kth = np.partition(lg, -req.top_k)[-req.top_k]
-            lg = np.where(lg < kth, -np.inf, lg)
-        p = np.exp(lg - lg.max())
-        p /= p.sum()
-        if req.top_p < 1.0:
-            order = np.argsort(p)[::-1]
-            # standard nucleus: smallest set whose mass reaches top_p —
-            # keep a token if the mass BEFORE it is still short of the
-            # threshold (inclusive of the one that crosses it)
-            csum = np.cumsum(p[order])
-            keep = (csum - p[order]) < req.top_p
-            mask = np.zeros_like(p, bool)
-            mask[order[keep]] = True
-            p = np.where(mask, p, 0.0)
-            p /= p.sum()
-        return int(rng.choice(len(p), p=p))
+    def _pool_prompts(self, prompts):
+        """Copy each prefilled prompt's blocks from its slot into the
+        prefix pool: dispatched behind the step that wrote the rows and
+        ahead of any that rewrites them; the device keeps that order."""
+        if prompts and self.kv is not None:
+            with self._phase["publish"]:
+                for prompt_ids, i in prompts:
+                    self.kv.store_prefix(prompt_ids, self.cache, i)
+
+    def _read_step(self, ids, lanes, prompts):
+        """Read a dispatched step's ids (the wait for the device is here),
+        append each lane's token and retire what ended. An EOS is learnt
+        only now, with the next step already dispatched: that step's lane
+        for the request is an overrun, and its token is dropped here.
+
+        Only then are the step's finished prompts pooled. A prompt's
+        copies into the pool are tens of programs: dispatched with the
+        step they would hold the host, and the device, between the first
+        token and its reader. Each request still holds its slot here."""
+        with self._phase["fetch"]:
+            ids = np.asarray(ids)
+        with self._phase["notify"]:
+            for i, req, ends in lanes:
+                if req.done.is_set():
+                    self.overrun_lane_steps += 1
+                    continue
+                nxt = int(ids[i])
+                if req.t_first is None:
+                    req.t_first = time.time()
+                    self.last_ttft_s = req.t_first - req.t_enqueue
+                    self._observe("ttft_s", self.last_ttft_s)
+                req.generated.append(nxt)
+                self.total_generated += 1
+                stop = nxt == self.tokenizer.eos_id
+                if stop or ends:
+                    req.finish_reason = "stop" if stop else "length"
+                    if self._slots[i] is req:
+                        self._slots[i] = None
+                    req.t_done = time.time()
+                    if req.trace_carrier is not None:
+                        self._record_request_spans(req)
+                    req.done.set()
+                with req.progress:
+                    req.progress.notify_all()
+        self._pool_prompts(prompts)
 
     @staticmethod
     def _record_request_spans(req: _Request) -> None:
@@ -891,6 +938,8 @@ class LLMEngine:
                 "devices": device_report(),
                 "total_generated": self.total_generated,
                 "engine_steps": self.engine_steps,
+                "steps_dispatched_ahead": self.steps_dispatched_ahead,
+                "overrun_lane_steps": self.overrun_lane_steps,
                 "chunk_steps": self.chunk_steps,
                 "tokens_prefilled": self.tokens_prefilled,
                 "prefix_imports": self.prefix_imports,

@@ -103,6 +103,7 @@ def _generate(eng, n: int, **kw) -> None:
 def test_engine_phase_timers_account_for_the_loops_busy_time(engine):
     from ray_tpu.serve.llm import ENGINE_PHASES
 
+    _generate(engine, 1)        # the first request prepares the programs
     before = engine.engine_stats()
     _generate(engine, 6, temperature=0.7, top_p=0.9)   # more than slots
     after = engine.engine_stats()
@@ -110,7 +111,12 @@ def test_engine_phase_timers_account_for_the_loops_busy_time(engine):
     d = {k: after["phase_s"][k] - before["phase_s"][k]
          for k in ENGINE_PHASES}
     assert all(v >= 0 for v in d.values())
-    assert d["dispatch"] > 0 and d["fetch"] > 0 and d["sample"] > 0
+    # `dispatch` is the step program's launch and `fetch` the wait for its
+    # [B] ids, a step late; `sample` is no longer the host choosing tokens
+    # in numpy but what it costs to launch the selection on the device,
+    # which is waited for in `fetch` with the step itself
+    assert d["dispatch"] > 0 and d["fetch"] > 0
+    assert 0 < d["sample"] < d["fetch"]
     busy = after["loop_busy_s"] - before["loop_busy_s"]
     phased = sum(v for k, v in d.items() if k != "empty")
     assert busy > 0 and abs(phased - busy) <= 0.05 * busy, (phased, busy)
@@ -142,6 +148,22 @@ def test_engine_counters_only_grow(engine):
     for a, b in zip(readings, readings[1:]):
         assert b["loop_busy_s"] > a["loop_busy_s"]
         assert all(b["phase_s"][k] >= a["phase_s"][k] for k in a["phase_s"])
+        # a step dispatched with the one before it unread, and a lane run
+        # for a request its EOS had ended: cumulative, read as deltas
+        assert b["engine_steps"] > a["engine_steps"]
+        assert b["steps_dispatched_ahead"] > a["steps_dispatched_ahead"]
+        assert b["overrun_lane_steps"] >= a["overrun_lane_steps"]
+        assert (b["steps_dispatched_ahead"] - a["steps_dispatched_ahead"]
+                < b["engine_steps"] - a["engine_steps"])
+
+
+@pytest.mark.parametrize("name", ["steps_dispatched_ahead",
+                                  "overrun_lane_steps"])
+def test_the_run_ahead_counters_are_whole_numbers_in_engine_stats(engine,
+                                                                  name):
+    stats = engine.engine_stats()
+    assert isinstance(stats[name], int) and stats[name] >= 0
+    assert stats[name] <= stats["engine_steps"] * engine.max_batch
 
 
 def test_engine_histograms_reach_the_metrics_registry(engine):

@@ -249,6 +249,35 @@ def test_serving_step_converts_no_weights(chips, program, C, widths):
     assert sorted(table) == sorted(["parameter"] + relaid), table
 
 
+def test_token_selection_compiles_at_xl_vocabulary(chips):
+    """`serve/sampling.select_tokens` over the serving cells' [8, 50304]
+    logits: one program whose sort is in a branch of a conditional, and
+    which needs no more of the chip than a few copies of the logits."""
+    from ray_tpu.serve.sampling import select_tokens
+
+    B, V = 8, 50304
+    one = SingleDeviceSharding(chips[0])
+
+    def program(logits, prev, produce, temperature, top_k, top_p, step):
+        key = jax.random.fold_in(jax.random.key(0), step)
+        return select_tokens(logits, prev, produce, temperature, top_k,
+                             top_p, key)
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(program).lower(
+        on((B, V), jnp.float32), on((B,), jnp.int32), on((B,), bool),
+        on((B,), jnp.float32), on((B,), jnp.int32), on((B,), jnp.float32),
+        on((), jnp.uint32)).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"\bconditional\(", hlo)
+    entry = hlo[hlo.index("ENTRY"):]
+    assert not re.search(r"\bsort\(", entry.split("\n}")[0])
+    assert re.search(r"\bsort\(", hlo)
+    assert _per_device_bytes(compiled) < 32 * B * V * 4
+
+
 # --------------------------------------------------------------- train step
 
 def _compile_train_step(train, batch, seq):
