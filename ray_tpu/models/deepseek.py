@@ -1,0 +1,496 @@
+"""The DeepSeek-V3 layer for serving: latent attention (MLA) over a latent
+cache, one leading dense layer, then expert layers with shared experts.
+
+What is served is `kakaocorp/kanana-2-30b-a3b-instruct-2601`
+(`model_type: deepseek_v3`; preset `kanana-2-30b-a3b`). As published, with
+d the hidden size, H heads, `qk_nope_head_dim` n, `qk_rope_head_dim` p,
+`v_head_dim` v, `kv_lora_rank` r, `q_lora_rank` null, no biases:
+
+    h = RMSNorm(x)
+    q = h W_q -> [H, n + p], split q_nope [H, n], q_rope [H, p]
+    [c, k_r] = h W_kva -> r + p;  c = RMSNorm_kv(c)
+    RoPE(position) on q_rope (each head) and on k_r (one key for all heads)
+
+  plain form (the definition; `benchmarks/chip/families/kanana.py`):
+    [k_nope, val] = c W_kvb -> [H, n + v];  k = [k_nope ; k_r]
+    scores q . k / sqrt(n + p), causal softmax in float32
+    o = softmax . val -> [H, v];  x += concat(o) W_o
+
+  absorbed form (what runs here, decode and chunk alike), with W_kvb split
+  by head into W_uk [r, H, n] and W_uv [r, H, v]:
+    q' = q_nope W_uk^T -> [H, r]
+    scores (q' . c_t + q_rope . k_r,t) / sqrt(n + p) against the cache
+    o = (softmax . c) W_uv
+  The cache holds c after its norm and k_r after RoPE: r + p values a token
+  a layer and nothing by head. Both forms are one function
+  (tests/test_deepseek_serving.py holds them to each other).
+
+    layer 0 .. first_k_dense_replace - 1:  x += SwiGLU_dense(RMSNorm(x))
+    the others, with h = RMSNorm(x):
+      s = sigmoid(h W_g) in float32 over the E experts; the K largest of
+      s + b are chosen (`e_score_correction_bias`), their gates are s
+      without b, divided by (their sum + 1e-20) (`norm_topk_prob`) and
+      times `routed_scaling_factor`
+      x += sum_k g_k SwiGLU^(e_k)(h) + SwiGLU_shared(h)
+    no capacity, nothing dropped; the `n_shared_experts` shared experts are
+    one MLP of n_shared x the experts' width. `n_group: 1, topk_group: 1`
+    make the published group limit a no-op, which is not built.
+    Final RMSNorm, untied head, logits float32.
+
+Departures and choices (the configuration file lists them under `assumed`):
+RoPE pairs lane i with lane i + p/2 (`llama.apply_rope`; `rope_interleave`
+is a convention of the checkpoint's layout, and a score is the same under
+any pairing that q and k share); `b` is drawn from the seed.
+
+The weights exist only in the dtype the replica holds them
+(`cfg.param_dtype`), a layer at a time: `init_layer(key, l, cfg)` makes
+layer l from `fold_in(key, l)` and nothing else, so the engine, a
+reference and a test make the same layer alone. The router, its bias and
+the norms' scales are float32 (they are used in float32), and so is the
+residual stream inside the step programs (`_layers`). Router and
+experts are `models/moe.py`'s: `_route` and the one sorted grouped-matmul
+path `_experts`, at any number of rows.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import moe as _moe
+from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs
+
+Params = Any
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekConfig:
+    vocab_size: int = 128256
+    n_layer: int = 48                # dense and expert layers together
+    n_dense_layer: int = 1           # first_k_dense_replace
+    n_head: int = 32
+    d_model: int = 2048
+    d_ff: int = 6144                 # the dense layers' SwiGLU
+    d_ff_expert: int = 768           # one routed expert's
+    n_experts: int = 128
+    experts_per_token: int = 6
+    n_shared_experts: int = 2
+    norm_topk_prob: bool = True
+    router_scoring: str = "sigmoid"
+    routed_scaling_factor: float = 2.448
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    max_seq_len: int = 32768
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16        # compute
+    param_dtype: Any = jnp.bfloat16  # what the replica holds
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Values a token leaves in the cache, a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @classmethod
+    def preset(cls, name: str, **overrides) -> "DeepseekConfig":
+        return cls(**{**PRESETS[name], **overrides})
+
+
+PRESETS = {
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json: the defaults
+    "kanana-2-30b-a3b": dict(),
+    "deepseek-tiny": dict(
+        vocab_size=512, n_layer=3, n_dense_layer=1, n_head=4, d_model=64,
+        d_ff=128, d_ff_expert=32, n_experts=8, experts_per_token=3,
+        n_shared_experts=2, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, max_seq_len=128),
+}
+
+# the cache's leaves that hold a value a token, and the axis that counts
+# the tokens: what a prefix pool keeps a block of (`serve/kv_cache.py`)
+CACHE_TOKEN_AXIS = {"latent": 2, "k_rope": 2}
+
+
+# ---------------------------------------------------------------------------
+# Weights, a layer at a time
+# ---------------------------------------------------------------------------
+
+# The seeded weights' spreads, N(0, std). Every matrix 0.02 and every down
+# projection 0.02 / sqrt(2 n_layer), as a fresh Hugging Face model, but: the
+# token table 0.3 and W_o 0.02, so that a layer's attention and its experts
+# each add about a third of the residual stream's size (with the table at
+# 0.02 the stream is a fifth of what the first layers add to it, any
+# rounding becomes another expert for some token within three layers, and a
+# bf16 program and a float8 one land equally far from a float32 reference:
+# PERF.md, PR 29); and the selection bias b 0.02, small against the scores
+# it corrects and not zero, so that selection by s + b and weighting by s
+# can be told apart.
+EMBED_STD, ATTN_OUT_STD, ROUTER_BIAS_STD = 0.3, 0.02, 0.02
+
+
+def _normal(key, shape, std, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _attn_params(key, cfg: DeepseekConfig) -> Params:
+    ks = jax.random.split(key, 4)
+    pd, D, H = cfg.param_dtype, cfg.d_model, cfg.n_head
+    return {
+        "wq": _normal(ks[0], (D, H, cfg.qk_head_dim), 0.02, pd),
+        "wkva": _normal(ks[1], (D, cfg.cache_width), 0.02, pd),
+        "kv_norm": {"scale": jnp.ones((cfg.kv_lora_rank,), jnp.float32)},
+        "wkvb": _normal(ks[2], (cfg.kv_lora_rank, H,
+                                cfg.qk_nope_head_dim + cfg.v_head_dim),
+                        0.02, pd),
+        "wo": _normal(ks[3], (H * cfg.v_head_dim, D), ATTN_OUT_STD, pd),
+    }
+
+
+def _swiglu_params(key, cfg: DeepseekConfig, width: int,
+                   resid_std: float) -> Params:
+    ks = jax.random.split(key, 3)
+    pd, D = cfg.param_dtype, cfg.d_model
+    return {"wg": _normal(ks[0], (D, width), 0.02, pd),
+            "wu": _normal(ks[1], (D, width), 0.02, pd),
+            "wd": _normal(ks[2], (width, D), resid_std, pd)}
+
+
+def _init_layer(key: jax.Array, l, cfg: DeepseekConfig,
+                dense: bool) -> Params:
+    ks = jax.random.split(jax.random.fold_in(key, l), 6)
+    pd, D, E, F = cfg.param_dtype, cfg.d_model, cfg.n_experts, cfg.d_ff_expert
+    resid_std = 0.02 / math.sqrt(2 * cfg.n_layer)
+    ones = {"scale": jnp.ones((D,), jnp.float32)}
+    layer = {"attn_norm": ones, "attn": _attn_params(ks[0], cfg),
+             "mlp_norm": ones}
+    if dense:
+        layer["mlp"] = _swiglu_params(ks[1], cfg, cfg.d_ff, resid_std)
+        return layer
+    layer["moe"] = {
+        "router": _normal(ks[1], (D, E), 0.02, jnp.float32),
+        "bias": _normal(ks[2], (E,), ROUTER_BIAS_STD, jnp.float32),
+        "wg": _normal(ks[3], (E, D, F), 0.02, pd),
+        "wu": _normal(ks[4], (E, D, F), 0.02, pd),
+        "wd": _normal(jax.random.fold_in(ks[4], 1), (E, F, D), resid_std, pd),
+    }
+    layer["shared"] = _swiglu_params(
+        ks[5], cfg, cfg.n_shared_experts * F, resid_std)
+    return layer
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_program(cfg: DeepseekConfig, dense: bool):
+    return jax.jit(lambda key, l: _init_layer(key, l, cfg, dense))
+
+
+def init_layer(key: jax.Array, l: int, cfg: DeepseekConfig) -> Params:
+    """Layer l's weights from `fold_in(key, l)` and nothing else: a dense
+    layer for l < cfg.n_dense_layer, else an expert layer. One compiled
+    program a kind of layer makes them wherever they are made (a sum fused
+    another way may round another way), so a layer made alone is, to the
+    bit, the layer in `init_params`' tree."""
+    return _layer_program(cfg, l < cfg.n_dense_layer)(key, jnp.int32(l))
+
+
+def init_ends(key: jax.Array, cfg: DeepseekConfig) -> Params:
+    """What is not a layer: the table, the final norm and the untied head,
+    from `fold_in(key, cfg.n_layer)`."""
+    k_emb, k_head = jax.random.split(jax.random.fold_in(key, cfg.n_layer))
+    pd, D, V = cfg.param_dtype, cfg.d_model, cfg.vocab_size
+    return {"wte": _normal(k_emb, (V, D), EMBED_STD, pd),
+            "final_norm": {"scale": jnp.ones((D,), jnp.float32)},
+            "lm_head": _normal(k_head, (D, V), 0.02, pd)}
+
+
+def init_params(key: jax.Array, cfg: DeepseekConfig) -> Params:
+    """The whole tree, every leaf made in the dtype it is held in: `dense`
+    [n_dense_layer, ...] and `blocks` [n_layer - n_dense_layer, ...]
+    stacked on a leading layer axis. A stack is allocated once and each
+    layer's program writes its layer into it (donated), so the most that
+    exists beside the tree is one layer: no float32 copy of the tree, and
+    no second copy of a stack."""
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def put(stack, layer, i):
+        return jax.tree.map(
+            lambda s, a: lax.dynamic_update_index_in_dim(s, a, i, 0),
+            stack, layer)
+
+    def stack(first: int, n: int):
+        shapes = jax.eval_shape(lambda: init_layer(key, first, cfg))
+        out = jax.jit(lambda: jax.tree.map(
+            lambda s: jnp.zeros((n,) + s.shape, s.dtype), shapes))()
+        for i in range(n):
+            out = put(out, init_layer(key, first + i, cfg), jnp.int32(i))
+        return out
+
+    k = cfg.n_dense_layer
+    return {**jax.jit(init_ends, static_argnums=(1,))(key, cfg),
+            "dense": stack(0, k), "blocks": stack(k, cfg.n_layer - k)}
+
+
+def resident_params(params: Params, cfg: DeepseekConfig) -> Params:
+    """`init_params` makes the tree a replica holds: nothing to convert."""
+    del cfg
+    return params
+
+
+def resident_specs(cfg: DeepseekConfig, rules=None) -> Params:
+    raise NotImplementedError(
+        "the deepseek family is served on one chip: its weights have no "
+        "partition specs yet (tensor_parallel_size > 1 is GPT-2's)")
+
+
+def num_params(cfg: DeepseekConfig) -> int:
+    D, H, r = cfg.d_model, cfg.n_head, cfg.kv_lora_rank
+    attn = (D * H * cfg.qk_head_dim + D * cfg.cache_width + r
+            + r * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + H * cfg.v_head_dim * D + 2 * D)
+    F = cfg.d_ff_expert
+    expert = attn + D * cfg.n_experts + cfg.n_experts \
+        + (cfg.n_experts + cfg.n_shared_experts) * 3 * D * F
+    dense = attn + 3 * D * cfg.d_ff
+    return (cfg.n_dense_layer * dense
+            + (cfg.n_layer - cfg.n_dense_layer) * expert
+            + 2 * cfg.vocab_size * D + D)
+
+
+# ---------------------------------------------------------------------------
+# The cache
+# ---------------------------------------------------------------------------
+
+# the columns of the cache's `counts` leaf, each a sum over a program's
+# executions: over the expert layers, the (lane, expert) rows the experts
+# were given for valid lanes, the experts that got at least one, the most
+# that one expert got, and 1; and once a step the positions the valid
+# lanes attend to (position + 1 each)
+COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
+          "expert_layer_steps", "attended_positions")
+
+
+def init_cache(cfg: DeepseekConfig, batch: int,
+               max_len: Optional[int] = None):
+    """{"latent" [n_layer, B, T, r], "k_rope" [n_layer, B, T, p]} in the
+    compute dtype: c after its norm and the shared rotary key after RoPE,
+    two leaves because they are two operands (the scores contract both, the
+    weighted sum only the latent) and a [.., T, r + p] leaf would be sliced
+    inside every layer; and `counts` uint32 [2, 5], not a token's: what
+    the step programs count themselves, row 0 `decode_step`'s, row 1
+    `prefill_chunk`'s; `COUNTS` names the columns
+    (`serve/llm.py` reads them for `stats()`; they wrap, so a reader takes
+    differences modulo 2**32)."""
+    T = max_len or cfg.max_seq_len
+    L = cfg.n_layer
+    return {"latent": jnp.zeros((L, batch, T, cfg.kv_lora_rank), cfg.dtype),
+            "k_rope": jnp.zeros((L, batch, T, cfg.qk_rope_head_dim),
+                                cfg.dtype),
+            "counts": jnp.zeros((2, len(COUNTS)), jnp.uint32)}
+
+
+# as `gpt2._WRITE_WINDOW`: the narrowest stretch of positions a write touches
+_WRITE_WINDOW = 128
+
+
+def _cache_write(c, l, val, pos0, ok):
+    """Layer l of the carried leaf c [L,B,T,F] takes val [B,C,F]: lane i of
+    slot b goes to position pos0[b] + i where ok[b, i]; nothing else
+    changes. `gpt2._cache_write` without the heads: per slot one window of
+    W >= C positions is read, blended and written back in place."""
+    _, B, T, F = c.shape
+    C = val.shape[1]
+    W = min(T, max(C, _WRITE_WINDOW))
+    start = jnp.clip(pos0 // W * W if C == 1 else pos0, 0, T - W)
+    src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [B, W]
+    hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [B, W, C]
+    moved = jnp.einsum("bwc,bcf->bwf", hit.astype(val.dtype), val,
+                       precision=lax.Precision.HIGHEST)
+    take = hit.any(axis=-1)                                           # [B, W]
+    for b in range(B):
+        at = (l, b, start[b], 0)
+        old = lax.dynamic_slice(c, at, (1, 1, W, F))
+        new = jnp.where(take[b][:, None], moved[b], old)
+        c = lax.dynamic_update_slice(c, new, at)
+    return c
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
+
+def _w(p, cfg: DeepseekConfig):
+    with jax.named_scope("weights_cast"):
+        return p.astype(cfg.dtype)
+
+
+def _swiglu(h, p, cfg: DeepseekConfig):
+    g = h @ _w(p["wg"], cfg)
+    u = h @ _w(p["wu"], cfg)
+    return (jax.nn.silu(g) * u) @ _w(p["wd"], cfg)
+
+
+def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok):
+    """x [B,C,D] float32 += absorbed attention of its C lanes (positions
+    `pos` [B,C], written where `ok`) against layer l of the carried
+    caches."""
+    B, C, _ = x.shape
+    H, r = cfg.n_head, cfg.kv_lora_rank
+    n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
+    T = lat.shape[2]
+    p = bp["attn"]
+    with jax.named_scope("attn"):
+        h = rms_norm(x, bp["attn_norm"], cfg.norm_eps).astype(cfg.dtype)
+        with jax.named_scope("mla_project"):
+            q = jnp.einsum("bcd,dhk->bchk", h, _w(p["wq"], cfg))
+            ckr = h @ _w(p["wkva"], cfg)                          # [B,C,r+p]
+            c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
+            cos, sin = rope_freqs(pos, cfg.qk_rope_head_dim, cfg.rope_theta)
+            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+            q_rope = apply_rope(q[..., n:], cos, sin)             # [B,C,H,p]
+            k_r = apply_rope(ckr[..., None, r:], cos, sin)[:, :, 0]
+            wkvb = _w(p["wkvb"], cfg)
+            q_abs = jnp.einsum("bchn,rhn->bchr", q[..., :n], wkvb[..., :n])
+        with jax.named_scope("kv_update"):
+            lat = _cache_write(lat, l, c, pos0, ok)
+            kr = _cache_write(kr, l, k_r, pos0, ok)
+        with jax.named_scope("mla_attend"):
+            scores = (jnp.einsum("bchr,btr->bhct", q_abs, lat[l],
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bchp,btp->bhct", q_rope, kr[l],
+                                   preferred_element_type=jnp.float32))
+            scores = scores / math.sqrt(cfg.qk_head_dim)
+            t_idx = jnp.arange(T)[None, None, None, :]
+            scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            mixed = jnp.einsum("bhct,btr->bchr", probs, lat[l])   # [B,C,H,r]
+        with jax.named_scope("mla_project"):
+            o = jnp.einsum("bchr,rhv->bchv", mixed, wkvb[..., n:])
+            x = x + jnp.dot(o.reshape(B, C, H * v), _w(p["wo"], cfg),
+                            preferred_element_type=x.dtype)
+    return x, lat, kr
+
+
+def _expert_mlp(x, bp, cfg: DeepseekConfig, counts, ok):
+    """x [B,C,D] += routed experts + shared experts; `counts` [4] += the
+    first four of `COUNTS`, over the lanes that are `ok`."""
+    B, C, D = x.shape
+    E, K = cfg.n_experts, cfg.experts_per_token
+    with jax.named_scope("mlp"):
+        h32 = rms_norm(x, bp["mlp_norm"], cfg.norm_eps)
+        h = h32.astype(cfg.dtype)
+        m = bp["moe"]
+        # the router reads the norm's float32 output, not its rounding
+        _, _, gates, experts = _moe._route(h32.reshape(B * C, D),
+                                           m["router"], cfg, m["bias"])
+        with jax.named_scope("moe_router"):
+            given = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
+                jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
+            counts = counts + jnp.stack(
+                [jnp.sum(given), jnp.sum(given > 0), jnp.max(given),
+                 jnp.ones((), jnp.int32)]).astype(counts.dtype)
+        routed = _moe._experts(
+            h, gates.reshape(B, C, K), experts.reshape(B, C, K),
+            _w(m["wg"], cfg), _w(m["wu"], cfg), _w(m["wd"], cfg), cfg)
+        with jax.named_scope("moe_shared"):
+            shared = _swiglu(h, bp["shared"], cfg)
+        x = x + routed.astype(x.dtype) + shared.astype(x.dtype)
+    return x, counts
+
+
+def _dense_mlp(x, bp, cfg: DeepseekConfig):
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, bp["mlp_norm"], cfg.norm_eps).astype(cfg.dtype)
+        return x + _swiglu(h, bp["mlp"], cfg).astype(x.dtype)
+
+
+def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
+            program: int):
+    """x [B,C,D] float32 through every layer, the caches carried: the residual
+    stream stays float32 from the table to the last norm (a bf16 stream
+    rounds every layer's sum to 8 bits, which at these widths is most of
+    what separates the program from the reference: PERF.md, PR 29), what a
+    product reads of it is the norm's output in the compute dtype, and the
+    router reads that output before it is rounded. The dense layers
+    stand before the loop, the expert layers are one scan over their
+    stacked weights (which has each layer's three expert matrices copied
+    out of the stack for the kernels: ROADMAP S12). `program` is the row of
+    the cache's `counts` that this program's counts go to. Returns
+    (x, cache)."""
+    lat, kr = cache["latent"], cache["k_rope"]
+    counts = jnp.zeros((4,), jnp.uint32)
+    n_dense = cfg.n_dense_layer
+    for l in range(n_dense):
+        bp = jax.tree.map(lambda a, l=l: a[l], params["dense"])
+        x, lat, kr = _attention(x, bp, cfg, lat, kr, l, pos0, pos, ok)
+        x = _dense_mlp(x, bp, cfg)
+
+    def body(carry, layer):
+        x, lat, kr, counts = carry
+        l, bp = layer
+        x, lat, kr = _attention(x, bp, cfg, lat, kr, l, pos0, pos, ok)
+        x, counts = _expert_mlp(x, bp, cfg, counts, ok)
+        return (x, lat, kr, counts), None
+
+    # as `gpt2._cached_layers`: the caches are carries, one buffer from
+    # layer to layer, written in place where the caller donates them
+    with jax.named_scope("layers"):
+        (x, lat, kr, counts), _ = lax.scan(
+            body, (x, lat, kr, counts),
+            (jnp.arange(n_dense, cfg.n_layer), params["blocks"]))
+    with jax.named_scope("moe_router"):
+        attended = jnp.sum(jnp.where(ok, pos + 1, 0)).astype(jnp.uint32)
+        counts = cache["counts"].at[program].add(
+            jnp.concatenate([counts, attended[None]]))
+    return x, {"latent": lat, "k_rope": kr, "counts": counts}
+
+
+def _logits(params: Params, x, cfg: DeepseekConfig):
+    with jax.named_scope("unembed_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
+        return jnp.dot(x, _w(params["lm_head"], cfg),
+                       preferred_element_type=jnp.float32)
+
+
+def _chunk(params: Params, cache, tokens, pos0, length, active,
+           cfg: DeepseekConfig, program: int):
+    """`prefill_chunk`, its counts to row `program` of the cache's."""
+    B, C = tokens.shape
+    lane = jnp.arange(C)
+    pos = pos0[:, None] + lane[None, :]                               # [B, C]
+    ok = (lane[None, :] < length[:, None]) & active[:, None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
+    x, cache = _layers(x, params, cache, cfg, pos0, pos, ok, program)
+    last = jnp.clip(length - 1, 0, C - 1)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return _logits(params, x_last, cfg), cache
+
+
+def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
+                  length: jax.Array, active: jax.Array, cfg: DeepseekConfig):
+    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
+    slot), pos0 [B] (the chunk's first cache position), length [B] (valid
+    tokens, 0..C), active [B] -> (logits [B, vocab] float32 at each slot's
+    last valid lane, the cache). Inactive and zero-length slots leave the
+    cache as it was and their logits are garbage; pos0 + length <= T and
+    C <= T are the caller's to keep. Donate `cache`."""
+    return _chunk(params, cache, tokens, pos0, length, active, cfg, 1)
+
+
+def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
+                active: jax.Array, cfg: DeepseekConfig):
+    """`gpt2.decode_step`'s contract: tokens [B], pos [B], active [B] ->
+    (logits [B, vocab] float32, the cache). The chunk program at one lane
+    a slot."""
+    return _chunk(params, cache, tokens[:, None], pos,
+                  active.astype(jnp.int32), active, cfg, 0)

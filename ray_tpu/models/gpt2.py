@@ -382,6 +382,11 @@ def _unembedding(params: Params, cfg: GPT2Config) -> jax.Array:
     return lax.optimization_barrier(_w(params["wte"].T, cfg))
 
 
+# the cache's leaves that hold a value a token, and the axis that counts
+# the tokens: what a prefix pool keeps a block of (`serve/kv_cache.py`)
+CACHE_TOKEN_AXIS = {"k": 3, "v": 3}
+
+
 def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
     """KV cache of all layers: {"k","v"}: [n_layer, B, H, T, Dh] (compute
     dtype). `decode_step` and `prefill_chunk` carry it whole through their

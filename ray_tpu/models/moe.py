@@ -9,9 +9,10 @@ framework owns a TPU-first sparse-MoE layer:
   "expert": on a mesh with an `ep` axis the experts' weights, gradients and
   optimizer state are sharded over it, and the partitioner brings a
   layer's weights together for its grouped matmuls);
-- the router runs in float32 (logits, softmax, top-k); the top-k gates are
-  kept as they are or renormalised, as the published `norm_topk_prob` says
-  (OLMoE: false; Mixtral: true);
+- the router runs in float32 (logits, softmax or sigmoid, top-k); the top-k
+  gates are kept as they are or renormalised, as the published
+  `norm_topk_prob` says (OLMoE: false; Mixtral: true), chosen with a bias
+  and scaled where the model has them (`models/deepseek.py`);
 - routing is DROPLESS, on every mesh: the (token, slot) pairs are sorted
   by expert, their rows gathered, one grouped matmul
   (`ops/grouped_matmul.py`: JAX's megablox Pallas kernel `gmm` on the tpu
@@ -55,6 +56,8 @@ class MoEConfig:
     n_experts: int = 8
     experts_per_token: int = 2       # top-k routing
     norm_topk_prob: bool = True      # renormalise the top-k gates to sum to 1
+    router_scoring: str = "softmax"  # or "sigmoid" (DeepSeek-V3's scoring_func)
+    routed_scaling_factor: float = 1.0   # the gates' factor after that
     qk_norm: bool = False            # RMSNorm on the q and k projections
     aux_loss_weight: float = 0.01    # load-balancing loss
     z_loss_weight: float = 1e-3      # router logit z-loss
@@ -193,17 +196,39 @@ def param_specs(cfg: MoEConfig, rules=None) -> Params:
 # Sparse MoE layer
 # ---------------------------------------------------------------------------
 
-def _route(x2, router, cfg: MoEConfig):
-    """x2 [N, D] -> (logits [N, E], probs [N, E], gates [N, K], experts
+def _route(x2, router, cfg, bias=None):
+    """x2 [N, D] -> (logits [N, E], scores [N, E], gates [N, K], experts
     [N, K]), all of it in float32: `Precision.HIGHEST`, because a float32
-    product runs in bfloat16 passes on the TPU unless it is asked for."""
+    product runs in bfloat16 passes on the TPU unless it is asked for.
+
+    `cfg.router_scoring` is `softmax` over the experts (OLMoE, Mixtral) or
+    an independent `sigmoid` an expert (DeepSeek-V3). With a `bias` [E]
+    (DeepSeek-V3's `e_score_correction_bias`, `topk_method: noaux_tc`) the
+    K experts are chosen by score + bias and weighted by the score alone.
+    The chosen scores are renormalised where `cfg.norm_topk_prob` says so
+    and scaled by `cfg.routed_scaling_factor`. `n_group: 1, topk_group: 1`
+    make DeepSeek-V3's group limit a no-op; it is not built."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = lax.top_k(probs, cfg.experts_per_token)
+        sigmoid = cfg.router_scoring == "sigmoid"
+        if not sigmoid and cfg.router_scoring != "softmax":
+            raise ValueError(f"router_scoring {cfg.router_scoring!r}")
+        probs = (jax.nn.sigmoid(logits) if sigmoid
+                 else jax.nn.softmax(logits, axis=-1))
+        if bias is None:
+            gates, experts = lax.top_k(probs, cfg.experts_per_token)
+        else:
+            _, experts = lax.top_k(probs + bias.astype(jnp.float32),
+                                   cfg.experts_per_token)
+            gates = jnp.take_along_axis(probs, experts, axis=-1)
         if cfg.norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            total = jnp.sum(gates, axis=-1, keepdims=True)
+            # the published sigmoid router guards its sum, softmax's cannot
+            # be zero
+            gates = gates / (total + 1e-20 if sigmoid else total)
+        if cfg.routed_scaling_factor != 1.0:
+            gates = gates * cfg.routed_scaling_factor
         return logits, probs, gates, experts
 
 

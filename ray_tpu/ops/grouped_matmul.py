@@ -17,7 +17,23 @@ products, the f32 -> bf16 casts of the weights included): tiles (512,
 lowers to a grouped-matmul kernel of its own) 42.3 ms, and it names its
 operations `ragged-dot-*`, outside every `jax.named_scope`, so a trace
 could not lay its time to the layer that called it. (1024, 1024, 1024) and
-(512, 2048, 1024) overflow the 16 MiB of scoped VMEM. On the `cpu` backend
+(512, 2048, 1024) overflow the 16 MiB of scoped VMEM.
+
+Serving meets the same kernel at few rows a group (PR 29, a v5e,
+`benchmarks/chip/rehearse/gmm_few_rows.py`: the three products of a
+DeepSeek-V3 expert layer, 2048 -> 768 and back over 128 groups, bf16). A
+decode step's 192 rows touch 106 groups, whose weights alone take 1.22 ms at
+819 GB/s: the training tiles cut to the rows (64, 1024, 768) 1.42 ms; the
+whole contraction in one tile (64, 2048, 768) / (64, 768, 2048) 1.38 ms
+(88% of the bandwidth); 32, 16 or 8 rows a tile 1.39-1.45; `ragged_dot`
+2.10; gathering each row's matrices for an `einsum` 14.9 ms. A chunk step's
+24,576 rows (192 a group): (512, 1024, 768) 4.89 ms, (256, 1024, 768) 3.98,
+(128, 1024, 768) 4.53, (256, 2048, 768) 3.44, (128, 2048, 768) 3.30,
+`ragged_dot` 6.96; at 12,288 rows 4.16, 2.90, 3.10, -, 2.51 and 5.72 (and
+(64, 2048, 768) 2.62). A row tile far above a group's rows multiplies
+mostly masked rows, and a contraction cut in two reads each row tile
+twice: so below `TILE_M` rows a group the tiles are 128 rows by the whole
+contraction. On the `cpu` backend
 — the tests' virtual mesh, and nothing else — the same arithmetic is
 `jax.lax.ragged_dot`; `interpret=True` runs the kernel itself there
 (tests/test_olmoe.py).
@@ -37,15 +53,22 @@ from jax import lax
 # rows, contraction, columns: the largest tiles whose double buffers and
 # float32 accumulator fit the v5e's scoped VMEM (measured, see above)
 TILE_M, TILE_K, TILE_N = 512, 1024, 1024
+# below TILE_M rows a group: fewer rows a tile, the whole contraction in
+# one, and as many columns as leave the weights' tile at 4 MiB
+FEW_ROWS_TILE_M, FEW_ROWS_TILE_K, FEW_ROWS_WEIGHTS = 128, 2048, 2048 * 1024
 
 
-def _tiling(m: int, k: int, n: int) -> tuple:
+def _tiling(m: int, k: int, n: int, groups: int) -> tuple:
     """The measured tiles, cut to the problem: the kernel wants M a whole
     number of row tiles (K and N may end in a partial tile)."""
-    tm = TILE_M
+    if m >= groups * TILE_M:
+        tm, tk, tn = TILE_M, min(TILE_K, k), min(TILE_N, n)
+    else:
+        tm, tk = FEW_ROWS_TILE_M, min(FEW_ROWS_TILE_K, k)
+        tn = min(n, max(128, FEW_ROWS_WEIGHTS // tk // 128 * 128))
     while m % tm:
         tm //= 2
-    return tm, min(TILE_K, k), min(TILE_N, n)
+    return tm, tk, tn
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
@@ -73,6 +96,6 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             f"ragged_dot on the cpu test backend; {backend!r} is neither")
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2])
+    tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.shape[0])
     return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, first_group,
                interpret=interpret)

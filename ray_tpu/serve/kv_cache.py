@@ -8,8 +8,10 @@ a rolling content hash of the prompt prefix, so requests sharing a
 prefix skip prefill for the cached span and shared prefixes are stored
 ONCE.
 
-TPU-first shape choice: the pool is a dense jax array
-`[n_layer, n_blocks, n_head, block_size, head_dim]` and reuse happens by
+TPU-first shape choice: the pool is a dense jax array a cache leaf
+(GPT-2: `[n_layer, n_blocks, n_head, block_size, head_dim]` for keys and for
+values; a latent cache: `[n_layer, n_blocks, block_size, width]` for each of
+its leaves) and reuse happens by
 block-granular device-to-device copies into the decode engine's dense
 per-slot cache (XLA-friendly static shapes; dynamic_update_slice on
 block boundaries). In-kernel gather-paging is a Pallas follow-up; the
@@ -53,7 +55,13 @@ class PagedKVCache:
     store_prefix(...)  -> copy a finished prompt's full blocks from a
                           slot's dense cache into the pool (dedup'd).
     copy_into_slot(...)-> materialize matched blocks into a slot cache.
-    """
+
+    The pool holds a block of whatever a token leaves in the model's
+    cache: one array a leaf, the leaf's shape with the slots' axis (1)
+    counting blocks and the tokens' axis a block long. `for_cache` builds
+    it from a cache and the model's word on which axis counts tokens;
+    the plain constructor is GPT-2's keys and values by head,
+    {"k", "v"}: [n_layer, slots, n_head, T, head_dim]."""
 
     def __init__(self, n_layer: int, n_head: int, head_dim: int,
                  num_blocks: int = 64, block_size: int = 16,
@@ -61,13 +69,47 @@ class PagedKVCache:
         import jax
         import jax.numpy as jnp
 
+        leaf = jax.ShapeDtypeStruct((n_layer, 1, n_head, block_size,
+                                     head_dim), dtype or jnp.float32)
+        self._build({"k": leaf, "v": leaf}, {"k": 3, "v": 3},
+                    num_blocks, block_size)
+
+    @classmethod
+    def for_cache(cls, cache: dict, token_axis: Dict[str, int],
+                  num_blocks: int = 64,
+                  block_size: int = 16) -> "PagedKVCache":
+        """A pool for the leaves of `cache` that `token_axis` names (leaf
+        -> the axis that counts tokens; axis 0 the layers, 1 the slots).
+        Leaves it does not name are not a token's and are not pooled."""
+        self = cls.__new__(cls)
+        self._build({name: cache[name] for name in token_axis}, token_axis,
+                    num_blocks, block_size)
+        return self
+
+    def _build(self, leaves: dict, token_axis: Dict[str, int],
+               num_blocks: int, block_size: int) -> None:
+        import jax
+        import jax.numpy as jnp
+
         self.jax, self.jnp = jax, jnp
         self.block_size = block_size
         self.num_blocks = num_blocks
-        shape = (n_layer, num_blocks, n_head, block_size, head_dim)
-        dtype = dtype or jnp.float32
-        self.pool_k = jnp.zeros(shape, dtype)
-        self.pool_v = jnp.zeros(shape, dtype)
+        self.pools: Dict[str, "jax.Array"] = {}
+        self._copiers: Dict[str, tuple] = {}
+        by_geometry: dict = {}
+        for name, leaf in leaves.items():
+            axis = token_axis[name]
+            block = list(leaf.shape)
+            block[1], block[axis] = 1, block_size
+            geometry = (tuple(block), axis, jnp.dtype(leaf.dtype).name)
+            if geometry not in by_geometry:
+                by_geometry[geometry] = self._copy_programs(tuple(block),
+                                                            axis)
+            self._copiers[name] = by_geometry[geometry]
+            block[1] = num_blocks
+            self.pools[name] = jnp.zeros(tuple(block), leaf.dtype)
+        # the first leaf's two programs, under the names they have had
+        self._copy_out, self._copy_in = next(iter(self._copiers.values()))
         self._free: List[int] = list(range(num_blocks))
         # chain hash -> block id, LRU order (least recent first)
         self._table: "OrderedDict[bytes, int]" = OrderedDict()
@@ -77,24 +119,45 @@ class PagedKVCache:
         self.tokens_reused = 0
         self.blocks_evicted = 0
 
-        L, N, H, Bs, Dh = shape
+    def _copy_programs(self, block: tuple, axis: int) -> tuple:
+        """(copy_out, copy_in) for leaves whose one block of one slot is
+        `block` ([L, 1, ..., block_size at `axis`, ...])."""
+        jax = self.jax
+
+        def at(second, t0):
+            return tuple(second if i == 1 else t0 if i == axis else 0
+                         for i in range(len(block)))
 
         def _copy_out(pool, cache, slot, t0, blk):
             with jax.named_scope("prefix_pool"):
-                data = jax.lax.dynamic_slice(
-                    cache, (0, slot, 0, t0, 0), (L, 1, H, Bs, Dh))
-                return jax.lax.dynamic_update_slice(
-                    pool, data.reshape(L, 1, H, Bs, Dh), (0, blk, 0, 0, 0))
+                data = jax.lax.dynamic_slice(cache, at(slot, t0), block)
+                return jax.lax.dynamic_update_slice(pool, data, at(blk, 0))
 
         def _copy_in(cache, pool, slot, t0, blk):
             with jax.named_scope("prefix_pool"):
-                data = jax.lax.dynamic_slice(
-                    pool, (0, blk, 0, 0, 0), (L, 1, H, Bs, Dh))
-                return jax.lax.dynamic_update_slice(
-                    cache, data, (0, slot, 0, t0, 0))
+                data = jax.lax.dynamic_slice(pool, at(blk, 0), block)
+                return jax.lax.dynamic_update_slice(cache, data,
+                                                    at(slot, t0))
 
-        self._copy_out = jax.jit(_copy_out, donate_argnums=(0,))
-        self._copy_in = jax.jit(_copy_in, donate_argnums=(0,))
+        return (jax.jit(_copy_out, donate_argnums=(0,)),
+                jax.jit(_copy_in, donate_argnums=(0,)))
+
+    # GPT-2's two pools by name: the transfer blobs below are theirs
+    @property
+    def pool_k(self):
+        return self.pools["k"]
+
+    @pool_k.setter
+    def pool_k(self, value):
+        self.pools["k"] = value
+
+    @property
+    def pool_v(self):
+        return self.pools["v"]
+
+    @pool_v.setter
+    def pool_v(self, value):
+        self.pools["v"] = value
 
     # ------------------------------------------------------------ hashing
     def _chains(self, ids: List[int]):
@@ -155,7 +218,7 @@ class PagedKVCache:
     def store_prefix(self, ids: List[int], cache, slot: int) -> int:
         """Copy every full block of `ids` from `cache`'s dense slot lane
         into the pool (skipping chains already present). Returns the
-        number of NEW blocks stored. `cache` is the engine's {"k","v"}."""
+        number of NEW blocks stored. `cache` is the engine's dict of leaves."""
         stored = 0
         t0 = 0
         for h, _blk in self._chains(ids):
@@ -163,10 +226,9 @@ class PagedKVCache:
                 blk = self._alloc()
                 if blk is None:
                     break
-                self.pool_k = self._copy_out(self.pool_k, cache["k"],
-                                             slot, t0, blk)
-                self.pool_v = self._copy_out(self.pool_v, cache["v"],
-                                             slot, t0, blk)
+                for name, (copy_out, _) in self._copiers.items():
+                    self.pools[name] = copy_out(self.pools[name],
+                                                cache[name], slot, t0, blk)
                 self._table[h] = blk
                 self._hash_of_block[blk] = h
                 stored += 1
@@ -179,13 +241,14 @@ class PagedKVCache:
     def copy_into_slot(self, cache, slot: int, blocks: List[int]):
         """Materialize matched pool blocks into cache slot lane starting
         at position 0; returns the updated cache dict."""
-        k, v = cache["k"], cache["v"]
+        cache = dict(cache)
         t0 = 0
         for blk in blocks:
-            k = self._copy_in(k, self.pool_k, slot, t0, blk)
-            v = self._copy_in(v, self.pool_v, slot, t0, blk)
+            for name, (_, copy_in) in self._copiers.items():
+                cache[name] = copy_in(cache[name], self.pools[name], slot,
+                                      t0, blk)
             t0 += self.block_size
-        return {"k": k, "v": v}
+        return cache
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:

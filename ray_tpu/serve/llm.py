@@ -2,10 +2,11 @@
 
 Capability counterpart of the reference's serve.llm stack
 (`python/ray/llm/_internal/serve/` — vLLM engine behind deployments). The
-TPU-native engine is ours: a jitted GPT-2 KV-cache decode step over a fixed
-slot batch (ray_tpu/models/gpt2.py decode_step); requests are admitted into
-free slots as others finish (continuous batching), so decode throughput
-stays at the full batch width under load.
+TPU-native engine is ours: a jitted cached decode step over a fixed slot
+batch (a model module's `decode_step`; the preset's name picks the module,
+`ray_tpu.models.serving_family`); requests are admitted into free slots as
+others finish (continuous batching), so decode throughput stays at the
+full batch width under load.
 
 Real weights: `checkpoint=` loads a `gpt2.save_params` directory (what
 the trainer writes), so replicas serve trained parameters, not random
@@ -119,6 +120,14 @@ class HFTokenizer:
         return self._tok.decode(ids)
 
 
+def _require_gpt2(family: str, what: str) -> None:
+    """`what` is one of the paths built for GPT-2's tree and cache alone."""
+    if family != "gpt2":
+        raise NotImplementedError(
+            f"{what} is built for the gpt2 family only; the {family} "
+            f"family serves seeded or handed-over weights on one chip")
+
+
 class _Request:
     def __init__(self, prompt_ids: List[int], max_tokens: int,
                  temperature: float, top_k: int = 0, top_p: float = 1.0,
@@ -196,7 +205,7 @@ class LLMEngine:
     Per-step join/evict with a token-budget step plan: new requests
     enter the running batch at the next decode step, finished sequences
     free their KV slot immediately, and long prompts prefill in
-    `prefill_chunk_size`-token chunks (gpt2.prefill_chunk) under
+    `prefill_chunk_size`-token chunks (the model's `prefill_chunk`) under
     `max_num_batched_tokens` per step, with decode lanes reserved first
     so prefill can't starve decode. `scheduler` names that one loop:
     deployment configs carry the key, and any other value is refused.
@@ -229,9 +238,14 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import gpt2
+        from ray_tpu.models import serving_family
 
-        self.jax, self.jnp, self.gpt2 = jax, jnp, gpt2
+        self.family, model, config_cls = serving_family(preset)
+        self.jax, self.jnp, self.model = jax, jnp, model
+        if checkpoint:
+            _require_gpt2(self.family, "checkpoint=")
+        if tensor_parallel_size > 1:
+            _require_gpt2(self.family, "tensor_parallel_size > 1")
         self.tensor_parallel_size = tensor_parallel_size
         overrides = dict(model_overrides or {})
         overrides.setdefault("max_seq_len", max_seq_len)
@@ -242,7 +256,7 @@ class LLMEngine:
             # resolved cfg (re-deriving from the preset would mismatch
             # when the checkpoint's architecture differs — ADVICE r5).
             self.cfg = (cfg_override if cfg_override is not None
-                        else gpt2.GPT2Config.preset(preset, **overrides))
+                        else config_cls.preset(preset, **overrides))
             source = params_override
             self.checkpoint = checkpoint
         elif checkpoint:
@@ -257,7 +271,7 @@ class LLMEngine:
             # replica of this model pulls from peers.
             import time as _time
 
-            base = gpt2.GPT2Config.preset(preset, **overrides)
+            base = config_cls.preset(preset, **overrides)
             source = None
             t0 = _time.perf_counter()
             if weight_store:
@@ -274,7 +288,7 @@ class LLMEngine:
                 except Exception:
                     source = None   # never fail init on the store
             if source is None:
-                source, self.cfg = gpt2.load_params(checkpoint, cfg=base)
+                source, self.cfg = model.load_params(checkpoint, cfg=base)
                 if weight_store:
                     from ray_tpu.serve import weight_store as _ws
 
@@ -283,18 +297,19 @@ class LLMEngine:
                     _ws.maybe_publish_params_async(
                         source, checkpoint,
                         arch={k: getattr(self.cfg, k)
-                              for k in gpt2._CFG_FIELDS})
+                              for k in model._CFG_FIELDS})
             self.checkpoint = checkpoint
         else:
-            self.cfg = gpt2.GPT2Config.preset(preset, **overrides)
-            source = gpt2.init_params(jax.random.key(seed), self.cfg)
+            self.cfg = config_cls.preset(preset, **overrides)
+            source = model.init_params(jax.random.key(seed), self.cfg)
             self.checkpoint = None
         # the replica's one copy of the weights on the device, and what
         # `_step` and `_chunk_step` take: converted once, here, to what the
-        # step programs read (gpt2.resident_params). What was made, loaded
-        # or handed over goes with this frame; the weight store published
-        # the loader's tree, so a puller converts after loading as well
-        self.params = gpt2.resident_params(source, self.cfg)
+        # step programs read (the model's `resident_params`). What was made,
+        # loaded or handed over goes with this frame; the weight store
+        # published the loader's tree, so a puller converts after loading
+        # as well
+        self.params = model.resident_params(source, self.cfg)
         del source
         # weight identity for the cluster prefix store: engines whose KV
         # is interchangeable must agree on it. Checkpoint path or
@@ -313,18 +328,21 @@ class LLMEngine:
         # when a checkpoint's architecture allows a longer context (the
         # sidecar must win for PARAM shapes, never for cache sizing)
         self.max_seq_len = min(max_seq_len, self.cfg.max_seq_len)
-        self.cache = gpt2.init_cache(self.cfg, max_batch, self.max_seq_len)
+        self.cache = model.init_cache(self.cfg, max_batch, self.max_seq_len)
         cfg = self.cfg
+        # what a token leaves in the cache, all layers: a gauge
+        self.kv_bytes_per_token = sum(
+            self.cache[name].nbytes for name in model.CACHE_TOKEN_AXIS
+        ) // (max_batch * self.max_seq_len)
         # paged prefix cache: shared-prompt requests skip prefill for the
         # cached span (reference: vLLM prefix caching behind serve.llm)
         self.kv = None
         if enable_prefix_caching:
             from ray_tpu.serve.kv_cache import PagedKVCache
 
-            self.kv = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
-                                   num_blocks=kv_blocks,
-                                   block_size=kv_block_size,
-                                   dtype=cfg.dtype)
+            self.kv = PagedKVCache.for_cache(
+                self.cache, model.CACHE_TOKEN_AXIS, num_blocks=kv_blocks,
+                block_size=kv_block_size)
 
         # chunk must fit the serving window (prefill_chunk requires C <= T)
         self.prefill_chunk_size = max(1, min(prefill_chunk_size,
@@ -336,11 +354,11 @@ class LLMEngine:
         from ray_tpu.serve.sampling import select_tokens
 
         def _step(params, cache, tokens, pos, active):
-            return gpt2.decode_step(params, cache, tokens, pos, active, cfg)
+            return model.decode_step(params, cache, tokens, pos, active, cfg)
 
         def _chunk(params, cache, tokens, pos0, length, active):
-            return gpt2.prefill_chunk(params, cache, tokens, pos0, length,
-                                      active, cfg)
+            return model.prefill_chunk(params, cache, tokens, pos0, length,
+                                       active, cfg)
 
         def _select(logits, prev, produce, sampling, step):
             temperature, top_k, top_p = sampling
@@ -370,7 +388,7 @@ class LLMEngine:
                 devices=jax.devices()[:tensor_parallel_size])
             self.mesh = mesh
             with use_mesh(mesh):
-                pspecs = gpt2.resident_specs(cfg)
+                pspecs = model.resident_specs(cfg)
             param_sh = jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs)
             self.params = jax.tree.map(jax.device_put, self.params,
@@ -453,6 +471,7 @@ class LLMEngine:
         (same weights + cache geometry) agree; anything else differs."""
         if self.kv is None:
             return None
+        _require_gpt2(self.family, "the cluster prefix store")
         from ray_tpu.serve.prefix_store import model_cache_key
 
         cfg = self.cfg
@@ -568,6 +587,7 @@ class LLMEngine:
         connectors: nixl/lmcache behind serve.llm)."""
         if self.kv is None:
             raise RuntimeError("prefix caching disabled: no KV to export")
+        _require_gpt2(self.family, "disaggregated export")
         ids = prompt_ids if prompt_ids is not None else \
             self.tokenizer.encode(prompt)
         ids = ids[-(self.max_seq_len - 2):]
@@ -587,33 +607,43 @@ class LLMEngine:
         prompt's content hash, so off-thread callers marshal through the
         engine-call queue. Falls back to a direct (pre-PR-13-semantics)
         export if the engine thread is wedged past `timeout`."""
+        from concurrent.futures import TimeoutError as _FutTimeout
+
         from ray_tpu.serve.kv_cache import export_prefix as _export
 
+        try:
+            return self._on_engine_thread(
+                lambda: _export(self.kv, list(ids)), timeout)
+        except _FutTimeout:
+            return _export(self.kv, list(ids))
+
+    def _on_engine_thread(self, fn, timeout: float):
+        """`fn()` run on the engine's thread between two passes (what it
+        owns unlocked, the pool and the donated cache, cannot change under
+        it there); here and now when this is that thread or it has ended.
+        Raises `concurrent.futures.TimeoutError` when the loop is wedged."""
         if (threading.current_thread() is self._thread
                 or not self._thread.is_alive()):
-            return _export(self.kv, ids)
+            return fn()
         from concurrent.futures import Future
-        from concurrent.futures import TimeoutError as _FutTimeout
 
         fut: Future = Future()
 
         def _do():
             try:
-                fut.set_result(_export(self.kv, list(ids)))
+                fut.set_result(fn())
             except BaseException as e:   # engine thread must survive
                 fut.set_exception(e)
 
         self._engine_calls.put(_do)
-        try:
-            return fut.result(timeout=timeout)
-        except _FutTimeout:
-            return _export(self.kv, list(ids))
+        return fut.result(timeout=timeout)
 
     def import_prefix(self, blob) -> int:
         """Decode side: install a prefill replica's exported KV blocks;
         subsequent matching prompts skip prefill for the covered span."""
         if self.kv is None:
             raise RuntimeError("prefix caching disabled: no KV to import")
+        _require_gpt2(self.family, "disaggregated import")
         from ray_tpu.serve.kv_cache import import_prefix as _import
 
         return _import(self.kv, blob)
@@ -927,6 +957,31 @@ class LLMEngine:
             own["sum"] += seconds
         _get_lifecycle_metrics()[name].observe(seconds)
 
+    def _device_counters(self) -> dict:
+        """What the step programs count themselves, in a leaf of the cache
+        that is no token's (`deepseek.init_cache`'s `counts`): read here
+        and only here, on the engine's thread between two passes (the leaf
+        is donated to every step), at the cost of waiting for the step in
+        flight. `moe_expert_rows` and `moe_experts_touched` sum both
+        programs, `step_counts` has each program's columns. Each is a
+        uint32 that wraps: take differences modulo 2**32."""
+        if "counts" not in self.cache:
+            return {}
+
+        from concurrent.futures import TimeoutError as _FutTimeout
+
+        try:
+            rows = self._on_engine_thread(
+                lambda: np.asarray(self.cache["counts"]).tolist(), 30.0)
+        except _FutTimeout:     # a wedged loop: the other stats still go
+            return {}
+        names = self.model.COUNTS
+        by_program = {program: dict(zip(names, row))
+                      for program, row in zip(("decode", "chunk"), rows)}
+        return {"step_counts": by_program,
+                **{f"moe_{k}": sum(p[k] for p in by_program.values())
+                   % 2 ** 32 for k in ("expert_rows", "experts_touched")}}
+
     def engine_stats(self) -> dict:
         from ray_tpu.utils.platform import device_report
 
@@ -934,7 +989,9 @@ class LLMEngine:
             queue_wait = dict(self.lifecycle["queue_wait_s"])
             ttft = dict(self.lifecycle["ttft_s"])
         ttft_avg = ttft["sum"] / ttft["count"] if ttft["count"] else 0.0
-        return {# what this engine's process runs JAX on
+        return {**self._device_counters(),
+                "kv_bytes_per_token": self.kv_bytes_per_token,
+                # what this engine's process runs JAX on
                 "devices": device_report(),
                 "total_generated": self.total_generated,
                 "engine_steps": self.engine_steps,
@@ -1135,6 +1192,7 @@ class OpenAIServer(LLMServer):
         if (not self.lora_root or not model or model == self.model_id
                 or ":" not in str(model)):
             return self.engine
+        _require_gpt2(self.engine.family, "LoRA multiplexing")
         adapter_id = str(model).rsplit(":", 1)[1]
         eng = self._lora_engines.get(adapter_id)
         if eng is not None:

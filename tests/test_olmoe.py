@@ -315,12 +315,19 @@ def test_grouped_matmul_over_a_shard_of_the_groups(first):
                                    atol=1e-3, rtol=1e-3)
 
 
-@pytest.mark.parametrize("m,k,n,want", [
-    (229376, 2048, 1024, (512, 1024, 1024)),     # the cell's gate/up product
-    (229376, 1024, 2048, (512, 1024, 1024)),     # and its down product
-    (192, 64, 32, (64, 64, 32)), (8, 2048, 1024, (8, 1024, 1024))])
-def test_grouped_matmul_tiles(m, k, n, want):
-    assert _tiling(m, k, n) == want
+@pytest.mark.parametrize("m,k,n,groups,want", [
+    (229376, 2048, 1024, 64, (512, 1024, 1024)),  # the cell's gate/up product
+    (229376, 1024, 2048, 64, (512, 1024, 1024)),  # and its down product
+    (229376, 2048, 1024, 16, (512, 1024, 1024)),  # a shard of the experts
+    # few rows a group (serving): 128 rows by the whole contraction
+    (192, 2048, 768, 128, (64, 2048, 768)),       # a Kanana decode step
+    (192, 768, 2048, 128, (64, 768, 2048)),
+    (24576, 2048, 768, 128, (128, 2048, 768)),    # and a chunk step
+    (24576, 768, 2048, 128, (128, 768, 2048)),
+    (192, 64, 32, 4, (64, 64, 32)), (8, 2048, 1024, 4, (8, 2048, 1024)),
+    (192, 4096, 4096, 8, (64, 2048, 1024))])
+def test_grouped_matmul_tiles(m, k, n, groups, want):
+    assert _tiling(m, k, n, groups) == want
 
 
 def test_a_loss_with_aux_puts_it_in_the_steps_metrics():

@@ -249,6 +249,61 @@ def test_serving_step_converts_no_weights(chips, program, C, widths):
     assert sorted(table) == sorted(["parameter"] + relaid), table
 
 
+# GPT-2 XL's two serving programs as the serving cells run them (8 slots of
+# 1,024 positions, chunks of 128, the padded vocabulary), per device: the
+# parent of PR 29 compiled to these bytes, and to the same instructions but
+# for the table of file names and line numbers (PR 29 changed the engine
+# and the pool around them, not them)
+GPT2_XL_SERVING_BYTES = {"decode_step": 6_314_675_200,
+                         "prefill_chunk": 6_314_743_808}
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_gpt2_xl_serving_programs_are_the_parents(chips, program):
+    lowered, _, _ = _serving_step(chips, program, 128, preset="gpt2-1.5b",
+                                  vocab_size=50304)
+    assert _per_device_bytes(lowered.compile()) == \
+        GPT2_XL_SERVING_BYTES[program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_kanana_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-kanana-docqa`'s two programs, as its configuration
+    file has them (Kanana-2-30B-A3B's widths, 1 + 7 layers, 32 slots of
+    4,096 positions, chunks of 128): the bytes the file gives, room for the
+    prefix pool beside the larger, the experts three grouped-matmul kernels
+    in the loop's body, no copy of a cache leaf or of a layer of one, and
+    the three copies of a layer's routed experts' matrices out of the stack
+    that the scan makes today (ROADMAP S12 takes them out, and this pin
+    with them: 38.6 -> 13.2 ms a decode step, PERF.md PR 29)."""
+    import json
+    import sys
+
+    chip_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    sys.path[:0] = [p for p in (chip_dir, os.path.join(chip_dir, "rehearse"))
+                    if p not in sys.path]
+    from compile_kanana_for_v5e import (CONFIG, compile_step, made_of,
+                                        pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    want = (memory["decode_step_bytes"] if program == "decode" else memory[
+        "prefill_chunk_bytes_by_chunk_size"][
+            str(config["deployment"]["prefill_chunk_size"])])
+    assert sized["total"] == want
+    assert sized["arguments"] >= 0.6 * HBM_BYTES
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    assert made_of(compiled.as_text(), config) == {
+        "grouped_matmul_kernels": 3, "cache_copies": [],
+        "expert_weight_copies": ["fusion"] * 3}
+
+
 def test_token_selection_compiles_at_xl_vocabulary(chips):
     """`serve/sampling.select_tokens` over the serving cells' [8, 50304]
     logits: one program whose sort is in a branch of a conditional, and
