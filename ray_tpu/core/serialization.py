@@ -192,6 +192,17 @@ def loads(data: bytes) -> Any:
     return deserialize(SerializedObject.from_view(memoryview(data)))
 
 
+def owned(value: Any) -> Any:
+    """A copy of `value` that aliases nothing but private bytes of its
+    own. A deserialized value's out-of-band buffers (numpy arrays, arrow
+    tables) are views onto the object store's shared memory and are valid
+    only while the object is pinned: a task's arguments until it returns,
+    a `get` result while its ObjectRef lives. What must outlive that (an
+    actor keeping an argument past the call that brought it, a block
+    handed on after its ref is released) takes a copy first."""
+    return loads(dumps(value))
+
+
 def loads_view(view: memoryview) -> Any:
     """Deserialize from a BORROWED view without retaining it: the result
     owns its memory, so the caller may release/reuse the backing storage

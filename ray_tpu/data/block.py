@@ -46,6 +46,18 @@ def block_nbytes(block: Block) -> int:
     return 64 * len(block)  # rows of unknown size: rough per-row guess
 
 
+def block_owned(block: Block) -> Block:
+    """`block` with memory of its own (`serialization.owned` says why):
+    what `ray_tpu.get` returned is rewritten under a consumer that still
+    holds it once the ref is released and the store reuses the space."""
+    if isinstance(block, dict) and all(
+            isinstance(v, np.ndarray) for v in block.values()):
+        return {k: np.array(v) for k, v in block.items()}
+    from ray_tpu.core import serialization
+
+    return serialization.owned(block)
+
+
 def block_len(block: Block) -> int:
     if isinstance(block, dict):
         return len(next(iter(block.values()))) if block else 0

@@ -21,8 +21,9 @@ from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
 import numpy as np
 
 from ray_tpu.data.block import (Block, batch_to_block, block_concat,
-                                block_len, block_nbytes, block_slice,
-                                block_to_batch, rows_of, to_numpy_columns)
+                                block_len, block_nbytes, block_owned,
+                                block_slice, block_to_batch, rows_of,
+                                to_numpy_columns)
 
 DEFAULT_WINDOW = 8  # initial in-flight block tasks (adapts to a byte budget)
 # streaming memory budget (reference resource_budget_backpressure_policy):
@@ -465,7 +466,10 @@ class Dataset:
         results: Dict[int, Any] = {}
         try:
             for idx, ref in executor.run():
-                block = ray_tpu.get(ref)
+                # the ref is released below and its space reused while the
+                # consumer may still hold the block (a trainer keeps its
+                # batches): the block must own its memory first
+                block = block_owned(ray_tpu.get(ref))
                 state["bytes"] += block_nbytes(block)
                 state["blocks"] += 1
                 results[idx] = block

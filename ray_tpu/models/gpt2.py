@@ -284,9 +284,17 @@ def hidden_states(params: Params, tokens: jax.Array,
 
     block_fn = partial(_block, cfg=cfg)
     if cfg.remat:
+        from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+        # a Pallas call is not a dot: the policies that save matmul
+        # outputs save the attention kernel's two residuals by name too,
+        # or its forward would run again in the backward pass
+        cp = jax.checkpoint_policies
+        named = cp.save_only_these_names(*RESIDUAL_NAMES)
         policies = {
-            "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            "dots_all": jax.checkpoint_policies.dots_saveable,
+            "dots": cp.save_from_both_policies(
+                cp.dots_with_no_batch_dims_saveable, named),
+            "dots_all": cp.save_from_both_policies(cp.dots_saveable, named),
         }
         policy = policies.get(cfg.remat_policy)
         block_fn = (jax.checkpoint(block_fn, policy=policy) if policy

@@ -58,25 +58,41 @@ def chunked_cross_entropy(x: jax.Array, head: jax.Array, targets: jax.Array,
         return total / (B * T)
 
 
+# the shortest sequence at which the flash kernel beat XLA's dense attention
+# on the chip (`resolve_attn_impl`)
+FLASH_MIN_SEQ_LEN = 512
+
+
 def resolve_attn_impl(attn_impl: str, seq_len: int) -> str:
     """Shared auto attention-implementation policy for all model families.
 
     auto → ring when the active mesh shards the sequence axis; else the
-    Pallas flash kernel on the `tpu` backend from T >= 2048, where it no
-    longer materializes T² scores; XLA's fused dense attention below that
-    and on the CPU test backend. The crossover is a policy, not a current
-    measurement: the benchmark re-measures it (ROADMAP S2).
+    Pallas flash kernel (`ops/flash_attention.py`) on the `tpu` backend
+    from T=512 wherever its tiles divide the sequence (`tiles_divide`: T a
+    multiple of 128); XLA's dense attention, which writes `[B, H, T, T]`
+    scores to HBM, for shorter and other lengths and on the CPU test
+    backend. What the rule reads is what the call can observe: backend,
+    mesh, T.
+
+    The crossover is measured (benchmarks/flash_crossover.py on a TPU v5e,
+    PR 31; forward + backward of one layer's attention at GPT-2 small's
+    heads and 20,480 tokens, ms dense / flash): T=128 1.20 / 4.56, T=256
+    2.65 / 3.63, T=512 5.13 / 3.15, T=1024 10.03 / 3.68, T=2048 19.16 /
+    5.13; at OLMoE's T=4096 and 128-wide heads the dense path does not fit
+    the chip and the kernel takes 15.27. Below 512 a head is a single tile
+    and a grid step costs more than the scores it keeps out of HBM.
     """
     if attn_impl != "auto":
         return attn_impl
     import jax
 
+    from ray_tpu.ops.flash_attention import tiles_divide
     from ray_tpu.parallel.mesh import current_mesh
 
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         return "ring"
-    if (jax.default_backend() == "tpu" and seq_len >= 2048
-            and seq_len % 128 == 0):
+    if (jax.default_backend() == "tpu" and seq_len >= FLASH_MIN_SEQ_LEN
+            and tiles_divide(seq_len)):
         return "flash"
     return "dense"

@@ -14,6 +14,7 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.core import serialization
 from ray_tpu.train import session as session_lib
 from ray_tpu.train.checkpoint import Checkpoint
 
@@ -39,6 +40,12 @@ class TrainWorker:
 
         if backend_env:
             os.environ.update(backend_env)
+        # the train thread outlives this call, and the call's arguments
+        # alias its payload in the object store, which is released when the
+        # call returns: a dataset made `from_numpy` was rewritten under the
+        # worker once the store reused the space (from the second epoch on)
+        train_fn, train_config, dataset_shards = serialization.owned(
+            (train_fn, train_config, dataset_shards))
         resume = (Checkpoint(resume_checkpoint_path)
                   if resume_checkpoint_path else None)
         self._generation = generation

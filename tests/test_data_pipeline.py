@@ -632,6 +632,51 @@ def test_interest_widening_stops_locate_fallbacks():
 
 
 # --------------------------------------- continuous-ingest elastic drill
+def _three_epochs_loop(config):
+    """Walks the shard three times, holds the first batch throughout, and
+    reports how many batches were not the rows they should be."""
+    from ray_tpu import train
+
+    B, T = config["batch"], config["width"]
+
+    def want(k):
+        return (np.arange(k * B * T, (k + 1) * B * T, dtype=np.int64)
+                % 50257).astype(np.int32).reshape(B, T)
+
+    shard = train.get_dataset_shard("train")
+    first, wrong, seen = None, 0, 0
+    for _ in range(3):
+        for k, batch in enumerate(shard.iter_batches(batch_size=B)):
+            if first is None:
+                first = batch["tokens"]
+            wrong += not np.array_equal(batch["tokens"], want(k))
+            seen += 1
+    train.report({"wrong": int(wrong), "seen": seen,
+                  "first_intact": bool(np.array_equal(first, want(0)))})
+
+
+def test_trainer_dataset_survives_epochs_and_held_batches(cluster):
+    """A dataset made `from_numpy` reaches the worker inside the arguments
+    of `setup_and_start`, whose payload the object store releases when the
+    call returns, and every block a worker reads is released once it is
+    fetched. Until PR 31 both were read in place afterwards: from the
+    second epoch on, when the store had reused the space, every batch of
+    the benchmark's `train-small-1k` was other bytes (its 192 batches last
+    a window only since the step got faster), and a batch the loop had
+    kept was rewritten under it. Batches big enough for shared memory."""
+    from ray_tpu.train import JaxTrainer, ScalingConfig
+
+    n, B, T = 192, 20, 1025        # the cell's own: fewer did not reuse the space
+    tokens = (np.arange(n * B * T, dtype=np.int64) % 50257).astype(
+        np.int32).reshape(n * B, T)
+    result = JaxTrainer(
+        _three_epochs_loop, train_loop_config={"batch": B, "width": T},
+        scaling_config=ScalingConfig(num_workers=1),
+        datasets={"train": rdata.from_numpy({"tokens": tokens})}).fit()
+    assert result.metrics == {"wrong": 0, "seen": 3 * n,
+                              "first_intact": True}
+
+
 def _ingest_loop(config):
     import json as _json
     import os as _os

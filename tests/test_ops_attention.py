@@ -51,6 +51,151 @@ def test_flash_grads_match_reference():
                                    atol=5e-4, rtol=1e-3)
 
 
+# The three training cells' head shapes (T, Dh; a few heads of each), bf16
+# as the cells run them, interpreted on the CPU. The error is the root mean
+# square of the difference over that of the float32 reference, which is
+# computed from the same bf16 inputs. Readings over two seeds: the kernel
+# 0.0020-0.0021 forward and 0.0031-0.0034 on dq, dk, dv (the dense path,
+# which rounds `probs` to bf16 as the kernel rounds `p`: 0.0022-0.0023 and
+# 0.0031-0.0033: what is left is the bf16 result's own rounding); the dense
+# path with `probs` rounded to float8 (e4m3): 0.106-0.272 forward, 0.027-0.270
+# on the gradients. The limit lies between, with room on both sides.
+CELL_HEAD_SHAPES = {"train-small-1k": (1, 2, 1024, 64),
+                    "train-xl-fsdp4-1k": (1, 3, 1024, 64),
+                    "train-olmoe-4k": (1, 1, 4096, 128)}
+BF16_RMS_LIMIT = 6e-3
+
+
+def _rms_gap(got, want) -> float:
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+def _float32_reference(q, k, v):
+    return mha_reference(*(x.astype(jnp.float32) for x in (q, k, v)))
+
+
+def _dense_with_probs_rounded_to(dtype):
+    """The dense path with `probs` rounded to `dtype` before the product
+    with v: what a kernel that fed the MXU a narrower `p` would compute."""
+    def attend(q, k, v):
+        T, Dh = q.shape[2], q.shape[3]
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) / np.sqrt(Dh)
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(dtype).astype(q.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return attend
+
+
+def _weighted_sum_grads(fn, q, k, v):
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                            * w), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("cell", list(CELL_HEAD_SHAPES))
+def test_flash_forward_in_bf16_at_the_cells_head_shapes(cell):
+    q, k, v = _qkv(*CELL_HEAD_SHAPES[cell], dtype=jnp.bfloat16)
+    out = flash_attention(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    assert _rms_gap(out, _float32_reference(q, k, v)) < BF16_RMS_LIMIT
+
+
+@pytest.mark.parametrize("cell", list(CELL_HEAD_SHAPES))
+def test_flash_grads_in_bf16_at_the_cells_head_shapes(cell):
+    q, k, v = _qkv(*CELL_HEAD_SHAPES[cell], dtype=jnp.bfloat16, seed=1)
+    got = _weighted_sum_grads(flash_attention, q, k, v)
+    want = _weighted_sum_grads(_float32_reference, q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == jnp.bfloat16
+        assert _rms_gap(a, b) < BF16_RMS_LIMIT, name
+
+
+def test_a_float8_p_would_fail_the_bf16_limit():
+    """The limit above separates the precision the configurations state
+    from the nearest one below it."""
+    q, k, v = _qkv(*CELL_HEAD_SHAPES["train-small-1k"], dtype=jnp.bfloat16)
+    narrow = _dense_with_probs_rounded_to(jnp.float8_e4m3fn)
+    as_stated = _dense_with_probs_rounded_to(jnp.bfloat16)
+    want = _float32_reference(q, k, v)
+    assert _rms_gap(as_stated(q, k, v), want) < BF16_RMS_LIMIT
+    assert _rms_gap(narrow(q, k, v), want) > 4 * BF16_RMS_LIMIT
+    grads = _weighted_sum_grads(narrow, q, k, v)
+    for a, b in zip(grads, _weighted_sum_grads(_float32_reference, q, k, v)):
+        assert _rms_gap(a, b) > 4 * BF16_RMS_LIMIT
+
+
+@pytest.mark.parametrize("block,sub", [(128, 128), (256, 128), (512, 256)])
+def test_flash_tiles_do_not_change_the_result(block, sub):
+    """Tiles of `block` rows a program, scores `sub` x `sub` at a time:
+    tiles below, on and above the diagonal, and sub-blocks of each kind
+    inside a diagonal tile, against the dense reference in float32."""
+    q, k, v = _qkv(1, 2, 512, 64)
+    got = flash_attention(q, k, v, True, None, block, sub)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(mha_reference(q, k, v)),
+                               atol=2e-5, rtol=1e-4)
+    gf = _weighted_sum_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, None, block, sub),
+        q, k, v)
+    for a, b in zip(gf, _weighted_sum_grads(mha_reference, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-3)
+
+
+def test_remat_dots_saves_the_flash_residuals_by_name():
+    """A Pallas call is not a dot: under `remat_policy="dots"` the
+    forward kernel's `out` and `lse` are saved by their checkpoint names,
+    so a layer's backward holds dq and dk/dv and no second forward;
+    `full` recomputes everything, the forward kernel too."""
+    from ray_tpu.models import gpt2
+
+    def kernels(policy):
+        cfg = gpt2.GPT2Config.preset(
+            "gpt2-tiny", max_seq_len=128, attn_impl="flash", remat=True,
+            remat_policy=policy)
+        params = jax.eval_shape(
+            lambda: gpt2.init_params(jax.random.key(0), cfg))
+        batch = {"tokens": jax.ShapeDtypeStruct((2, 129), jnp.int32)}
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p, b: gpt2.loss_fn(p, b, cfg)))(params, batch)
+        return str(jaxpr).count("pallas_call")
+
+    assert kernels("dots") == 3
+    assert kernels("full") == 4
+
+
+@pytest.mark.parametrize("backend,axes,seq,asked,want", [
+    ("cpu", {}, 1024, "auto", "dense"),         # the tests' backend
+    ("tpu", {}, 1024, "auto", "flash"),         # the GPT-2 training cells
+    ("tpu", {}, 4096, "auto", "flash"),         # train-olmoe-4k
+    ("tpu", {}, 512, "auto", "flash"),          # the measured crossover
+    ("tpu", {}, 640, "auto", "flash"),          # any multiple of 128 above
+    ("tpu", {}, 256, "auto", "dense"),          # dense won at 128 and 256
+    ("tpu", {}, 1000, "auto", "dense"),         # the tiles do not divide it
+    ("tpu", {"dp": 2, "tp": 2}, 1024, "auto", "flash"),
+    ("tpu", {"dp": 2, "sp": 2}, 1024, "auto", "ring"),
+    ("cpu", {"sp": 4}, 1024, "auto", "ring"),
+    ("tpu", {}, 1024, "dense", "dense"),        # asked for by name
+    ("cpu", {}, 1000, "flash", "flash"),
+])
+def test_resolve_attn_impl(devices8, monkeypatch, backend, axes, seq, asked,
+                           want):
+    """The rule reads what the call can observe: backend, mesh, T."""
+    import contextlib
+
+    from ray_tpu.models.lm import resolve_attn_impl
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = int(np.prod(list(axes.values()) or [1]))
+    mesh = build_mesh(MeshConfig(**axes), devices=devices8[:n]) if axes \
+        else None
+    with use_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        assert resolve_attn_impl(asked, seq) == want
+
+
 def test_flash_rejects_indivisible_seq():
     q, k, v = _qkv(T=130)
     with pytest.raises(ValueError, match="divide"):
