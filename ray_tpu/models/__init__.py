@@ -1,21 +1,31 @@
 """Model families (pure JAX, TPU-first): gpt2, llama (GQA/RoPE/SwiGLU),
 moe (OLMoE / Mixtral sparse MoE: dropless sort-and-grouped-matmul routing,
 one-hot dispatch under expert parallelism), deepseek (DeepSeek-V3's layer
-for serving: latent attention over a latent cache, shared experts)."""
+for serving: latent attention over a latent cache, shared experts), brumby
+(Brumby's layer for serving: power retention over a recurrent state)."""
 
 from ray_tpu.models import gpt2
 
-__all__ = ["gpt2", "llama", "moe", "deepseek", "serving_family"]
+__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
 # with a `preset`, `init_params`, `resident_params`, `resident_specs`,
-# `init_cache`, `decode_step`, `prefill_chunk` (gpt2's signatures) and
-# `CACHE_TOKEN_AXIS`: the cache's leaves that hold a value a token, each
-# [layers, slots, ...], and which of their axes counts the tokens.
+# `init_cache`, `decode_step`, `prefill_chunk` (gpt2's signatures), and its
+# word on what the cache's leaves are, each [layers, slots, ...]:
+# `CACHE_TOKEN_AXIS`, the leaves that hold a value a token and which of
+# their axes counts the tokens (a prefix leaves rows behind, which the pool
+# keeps by the block; a slot's stale rows lie past its position), and
+# `CACHE_STATE` (optional, default none), the leaves that hold a slot's
+# recurrent state and have no token axis (a prefix leaves the state at its
+# end behind, which the pool keeps as a snapshot; a slot is zeroed when a
+# request is placed in it, and a step leaves an inactive slot's state as it
+# was). A family names the one kind or the other; both in one cache is
+# ROADMAP R9's. A leaf neither names is the programs' own (`counts`).
 _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "kanana": ("deepseek", "DeepseekConfig"),
-            "deepseek": ("deepseek", "DeepseekConfig")}
+            "deepseek": ("deepseek", "DeepseekConfig"),
+            "brumby": ("brumby", "BrumbyConfig")}
 
 
 def serving_family(preset: str):
@@ -32,7 +42,7 @@ def serving_family(preset: str):
 
 
 def __getattr__(name):
-    if name in ("llama", "moe", "deepseek"):
+    if name in ("llama", "moe", "deepseek", "brumby"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

@@ -17,6 +17,14 @@ per-slot cache (XLA-friendly static shapes; dynamic_update_slice on
 block boundaries). In-kernel gather-paging is a Pallas follow-up; the
 bookkeeping, hashing, eviction, and dedup semantics here are the real
 thing.
+
+A family whose cache is recurrent state (`for_cache(..., state=...)`) has no
+rows to keep by the block: what a prefix leaves behind is the state at its
+end. The pool then holds snapshots, one entry a prefix: the slot's state
+leaves as they stood when the prompt's last whole block had gone through,
+keyed by that boundary's chain hash, in the same table under the same LRU.
+`match_prefix` returns the longest boundary that has one, `copy_into_slot`
+copies one entry over the slot's whole state.
 """
 
 from __future__ import annotations
@@ -76,13 +84,20 @@ class PagedKVCache:
 
     @classmethod
     def for_cache(cls, cache: dict, token_axis: Dict[str, int],
-                  num_blocks: int = 64,
-                  block_size: int = 16) -> "PagedKVCache":
+                  num_blocks: int = 64, block_size: int = 16,
+                  state: Tuple[str, ...] = ()) -> "PagedKVCache":
         """A pool for the leaves of `cache` that `token_axis` names (leaf
-        -> the axis that counts tokens; axis 0 the layers, 1 the slots).
-        Leaves it does not name are not a token's and are not pooled."""
+        -> the axis that counts tokens; axis 0 the layers, 1 the slots), or
+        for those `state` names (a slot's recurrent state, no token axis:
+        `num_blocks` snapshots, taken at multiples of `block_size`). Leaves
+        neither names are not pooled."""
+        if token_axis and state:
+            raise NotImplementedError(
+                "a cache with rows a token beside state a slot needs a "
+                "pool of both kinds (ROADMAP R9); a family names one")
         self = cls.__new__(cls)
-        self._build({name: cache[name] for name in token_axis}, token_axis,
+        names = list(state) if state else list(token_axis)
+        self._build({name: cache[name] for name in names}, token_axis,
                     num_blocks, block_size)
         return self
 
@@ -94,13 +109,17 @@ class PagedKVCache:
         self.jax, self.jnp = jax, jnp
         self.block_size = block_size
         self.num_blocks = num_blocks
+        # leaves with no token axis: an entry is a snapshot of a slot
+        self.snapshots = bool(leaves) and not token_axis
         self.pools: Dict[str, "jax.Array"] = {}
         self._copiers: Dict[str, tuple] = {}
         by_geometry: dict = {}
         for name, leaf in leaves.items():
-            axis = token_axis[name]
+            axis = token_axis.get(name)
             block = list(leaf.shape)
-            block[1], block[axis] = 1, block_size
+            block[1] = 1
+            if axis is not None:
+                block[axis] = block_size
             geometry = (tuple(block), axis, jnp.dtype(leaf.dtype).name)
             if geometry not in by_geometry:
                 by_geometry[geometry] = self._copy_programs(tuple(block),
@@ -119,9 +138,10 @@ class PagedKVCache:
         self.tokens_reused = 0
         self.blocks_evicted = 0
 
-    def _copy_programs(self, block: tuple, axis: int) -> tuple:
+    def _copy_programs(self, block: tuple, axis: Optional[int]) -> tuple:
         """(copy_out, copy_in) for leaves whose one block of one slot is
-        `block` ([L, 1, ..., block_size at `axis`, ...])."""
+        `block` ([L, 1, ..., block_size at `axis`, ...]; with no `axis` a
+        slot's whole leaf, and `t0` is not looked at)."""
         jax = self.jax
 
         def at(second, t0):
@@ -174,6 +194,9 @@ class PagedKVCache:
         order or the hit/miss counters — the disagg decode side uses this
         to decide whether fetching remote KV would gain anything before
         it commits to a prefill RPC."""
+        if self.snapshots:
+            return max((n for h, n in chain_hashes(ids, self.block_size)
+                        if h in self._table), default=0)
         n = 0
         for h, _blk in self._chains(ids):
             if h not in self._table:
@@ -187,6 +210,16 @@ class PagedKVCache:
         return list(self._table)[-n:]
 
     def match_prefix(self, ids: List[int]) -> Tuple[int, List[int]]:
+        if self.snapshots:
+            # the longest boundary with a snapshot: one entry, whatever
+            # shorter ones exist
+            for h, n in reversed(chain_hashes(ids, self.block_size)):
+                if h in self._table:
+                    self._table.move_to_end(h)
+                    self.hits += 1
+                    self.tokens_reused += n
+                    return n, [self._table[h]]
+            return 0, []
         blocks: List[int] = []
         for h, _blk in self._chains(ids):
             blk_id = self._table.get(h)
@@ -218,29 +251,38 @@ class PagedKVCache:
     def store_prefix(self, ids: List[int], cache, slot: int) -> int:
         """Copy every full block of `ids` from `cache`'s dense slot lane
         into the pool (skipping chains already present). Returns the
-        number of NEW blocks stored. `cache` is the engine's dict of leaves."""
+        number of NEW blocks stored. `cache` is the engine's dict of leaves.
+
+        A pool of snapshots keeps the slot's state as it stands, under the
+        hash of `ids`' last whole block: the caller calls when the slot has
+        taken exactly those blocks and no token more."""
+        B = self.block_size
+        chain = chain_hashes(ids, B)
+        # (hash, where in the slot): a block of rows a hash, or the slot's
+        # state once, under the last hash
+        entries = (([(chain[-1][0], 0)] if chain else []) if self.snapshots
+                   else [(h, n - B) for h, n in chain])
         stored = 0
-        t0 = 0
-        for h, _blk in self._chains(ids):
-            if h not in self._table:
-                blk = self._alloc()
-                if blk is None:
-                    break
-                for name, (copy_out, _) in self._copiers.items():
-                    self.pools[name] = copy_out(self.pools[name],
-                                                cache[name], slot, t0, blk)
-                self._table[h] = blk
-                self._hash_of_block[blk] = h
-                stored += 1
-            else:
+        for h, t0 in entries:
+            if h in self._table:
                 self._table.move_to_end(h)
-            t0 += self.block_size
+                continue
+            blk = self._alloc()
+            if blk is None:
+                break
+            for name, (copy_out, _) in self._copiers.items():
+                self.pools[name] = copy_out(self.pools[name], cache[name],
+                                            slot, t0, blk)
+            self._table[h] = blk
+            self._hash_of_block[blk] = h
+            stored += 1
         return stored
 
     # --------------------------------------------------------------- load
     def copy_into_slot(self, cache, slot: int, blocks: List[int]):
         """Materialize matched pool blocks into cache slot lane starting
-        at position 0; returns the updated cache dict."""
+        at position 0 (a snapshot: its one entry over the slot's whole
+        state); returns the updated cache dict."""
         cache = dict(cache)
         t0 = 0
         for blk in blocks:
@@ -265,6 +307,13 @@ class PagedKVCache:
 # arrays, so the wire format is a plain numpy blob dict that can ride
 # the object store / an ObjectRef between actors.
 
+def _require_rows(kv: "PagedKVCache", what: str) -> None:
+    if kv.snapshots:
+        raise NotImplementedError(
+            f"{what} ships blocks of keys and values; a pool of state "
+            f"snapshots (the brumby family) has no wire format yet")
+
+
 def export_prefix(kv: "PagedKVCache", ids) -> Optional[dict]:
     """Serialize the pooled KV blocks covering `ids`' prefix into a
     host-memory blob: {"ids", "k", "v"} with k/v [n_blocks, L, H, Bs, Dh].
@@ -278,6 +327,7 @@ def export_prefix(kv: "PagedKVCache", ids) -> Optional[dict]:
     counted as `prefix_store_inline_skipped_total` on /metrics."""
     import numpy as np
 
+    _require_rows(kv, "export_prefix")
     n, blocks = kv.match_prefix(list(ids))
     if not blocks:
         return None
@@ -296,6 +346,7 @@ def import_prefix(kv: "PagedKVCache", blob: dict) -> int:
     already cached). Returns the number of new blocks installed."""
     if not blob:
         return 0
+    _require_rows(kv, "import_prefix")
     if blob["block_size"] != kv.block_size:
         raise ValueError(
             f"block_size mismatch: {blob['block_size']} != {kv.block_size}")

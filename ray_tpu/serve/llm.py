@@ -330,10 +330,17 @@ class LLMEngine:
         self.max_seq_len = min(max_seq_len, self.cfg.max_seq_len)
         self.cache = model.init_cache(self.cfg, max_batch, self.max_seq_len)
         cfg = self.cfg
-        # what a token leaves in the cache, all layers: a gauge
+        # the family's word on its cache (`models/__init__.py`): leaves with
+        # a value a token, or leaves that are a slot's recurrent state
+        self._state_leaves = tuple(getattr(model, "CACHE_STATE", ()))
+        # what a token leaves in the cache, all layers: a gauge (a family
+        # of state leaves nothing a token: `state_bytes_per_slot` is its)
         self.kv_bytes_per_token = sum(
             self.cache[name].nbytes for name in model.CACHE_TOKEN_AXIS
         ) // (max_batch * self.max_seq_len)
+        self.state_bytes_per_slot = sum(
+            self.cache[name].nbytes for name in self._state_leaves
+        ) // max_batch
         # paged prefix cache: shared-prompt requests skip prefill for the
         # cached span (reference: vLLM prefix caching behind serve.llm)
         self.kv = None
@@ -342,7 +349,7 @@ class LLMEngine:
 
             self.kv = PagedKVCache.for_cache(
                 self.cache, model.CACHE_TOKEN_AXIS, num_blocks=kv_blocks,
-                block_size=kv_block_size)
+                block_size=kv_block_size, state=self._state_leaves)
 
         # chunk must fit the serving window (prefill_chunk requires C <= T)
         self.prefill_chunk_size = max(1, min(prefill_chunk_size,
@@ -365,6 +372,19 @@ class LLMEngine:
             key = jax.random.fold_in(jax.random.key(seed), step)
             return select_tokens(logits, prev, produce, temperature,
                                  top_k.astype(jnp.int32), top_p, key)
+
+        def _reset(cache, slot):
+            # a new sequence starts from no state: stale rows of a KV slot
+            # lie past its position, stale state would be carried on
+            with jax.named_scope("kv_update"):
+                out = dict(cache)
+                for name in self._state_leaves:
+                    leaf = cache[name]
+                    out[name] = jax.lax.dynamic_update_slice(
+                        leaf, jnp.zeros((leaf.shape[0], 1) + leaf.shape[2:],
+                                        leaf.dtype),
+                        (0, slot) + (0,) * (leaf.ndim - 2))
+                return out
 
         def _merge(tokens, ids, decoding):
             # a chunk step's decode lanes read their token where the last
@@ -418,6 +438,7 @@ class LLMEngine:
             self._chunk_step = jax.jit(_chunk, donate_argnums=(1,))
             self._select = jax.jit(_select)
             self._merge = jax.jit(_merge)
+        self._reset_slot = jax.jit(_reset, donate_argnums=(0,))
         # each slot's newest token, where the selection left it: the next
         # step's decode lanes read it there, the host reads it a step late
         self._ids = jax.device_put(np.zeros((max_batch,), np.int32), rep)
@@ -430,6 +451,10 @@ class LLMEngine:
         self._slots: List[Optional[_Request]] = [None] * max_batch
         self._slot_pos = [0] * max_batch
         self._slot_prefill: List[List[int]] = [[] for _ in range(max_batch)]
+        # a family of state: the position at which each slot's state is to
+        # be pooled, between the chunk step that ends there and the next
+        # (0: nowhere). The prompt's last whole block, as the pool's rows
+        self._slot_snapshot_at = [0] * max_batch
         # async prefill fetch: requests whose KV blob is still in flight
         # park here (other lanes keep decoding); resolved ones re-enter
         # admission ahead of the queue
@@ -447,6 +472,9 @@ class LLMEngine:
         self.prefix_imports = 0        # deferred blobs installed
         self.prefix_blocks_imported = 0
         self.prefix_wait_timeouts = 0  # deadline hit: local prefill
+        self.slots_reset = 0           # slots zeroed for a new sequence
+        self.snapshots_pooled = 0      # states copied into the pool
+        self.snapshot_hits = 0         # requests that started from one
         self.last_ttft_s = 0.0         # submit -> first generated token
         # cumulative, so a reader takes deltas over its own window
         self.phase_s: Dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
@@ -743,6 +771,25 @@ class LLMEngine:
                 self._slot_prefill[i] = list(
                     req.prompt_ids[n_hit:])
                 req.reused_tokens = n_hit
+        if self._state_leaves:
+            self._place_state(i, req)
+
+    def _place_state(self, i: int, req: _Request) -> None:
+        """A slot of recurrent state takes a new sequence: the snapshot the
+        pool had (copied over the whole state above) or zeros; and where
+        its own snapshot is due, if the pool lacks the prompt's last whole
+        block."""
+        if req.reused_tokens:
+            self.snapshot_hits += 1
+        else:
+            self.cache = self._reset_slot(self.cache, np.int32(i))
+            self.slots_reset += 1
+        self._slot_snapshot_at[i] = 0
+        if self.kv is not None:
+            block = self.kv.block_size
+            boundary = (len(req.prompt_ids) - 1) // block * block
+            if boundary > req.reused_tokens:
+                self._slot_snapshot_at[i] = boundary
 
     def _sweep_streams(self) -> None:
         """Expire abandoned stream entries (client vanished): the sweep
@@ -832,6 +879,10 @@ class LLMEngine:
                     # requires pos0 + length <= T; _make_request already
                     # bounds prompts)
                     take = min(takes[i], self.max_seq_len - self._slot_pos[i])
+                    # a chunk ends where the slot's state is to be pooled
+                    due = self._slot_snapshot_at[i] - self._slot_pos[i]
+                    if due > 0:
+                        take = min(take, due)
                     if take <= 0:
                         continue
                     lengths[i] = take
@@ -840,7 +891,7 @@ class LLMEngine:
                 active = lengths > 0
             else:
                 lengths = active = decoding
-            lanes, prompts, last_prompts = [], [], []
+            lanes, prompts, last_prompts, snapshots = [], [], [], []
             produce = np.zeros((B,), bool)
             for i in live:
                 take = int(lengths[i])
@@ -848,6 +899,9 @@ class LLMEngine:
                     continue
                 req = self._slots[i]
                 self._slot_pos[i] += take
+                if self._slot_pos[i] == self._slot_snapshot_at[i]:
+                    snapshots.append((req.prompt_ids[:self._slot_pos[i]], i))
+                    self._slot_snapshot_at[i] = 0
                 if not decoding[i]:
                     del self._slot_prefill[i][:take]
                     self.tokens_prefilled += take
@@ -884,6 +938,14 @@ class LLMEngine:
                 logits, self._ids, produce, self._sampling,
                 np.uint32(self.engine_steps))
         self.engine_steps += 1
+        if snapshots:
+            # behind the chunk step that brought each slot to its boundary
+            # and ahead of the next, which moves the state on: the device
+            # keeps that order, and the state exists at no other time
+            with phase["publish"]:
+                for ids, i in snapshots:
+                    self.snapshots_pooled += self.kv.store_prefix(
+                        ids, self.cache, i)
         self._pool_prompts(last_prompts)
         return self._ids, lanes, prompts
 
@@ -891,7 +953,7 @@ class LLMEngine:
         """Copy each prefilled prompt's blocks from its slot into the
         prefix pool: dispatched behind the step that wrote the rows and
         ahead of any that rewrites them; the device keeps that order."""
-        if prompts and self.kv is not None:
+        if prompts and self.kv is not None and not self._state_leaves:
             with self._phase["publish"]:
                 for prompt_ids, i in prompts:
                     self.kv.store_prefix(prompt_ids, self.cache, i)
@@ -989,8 +1051,14 @@ class LLMEngine:
             queue_wait = dict(self.lifecycle["queue_wait_s"])
             ttft = dict(self.lifecycle["ttft_s"])
         ttft_avg = ttft["sum"] / ttft["count"] if ttft["count"] else 0.0
-        return {**self._device_counters(),
-                "kv_bytes_per_token": self.kv_bytes_per_token,
+        # the gauge of the cache's kind: bytes a token, or bytes a slot
+        kind = ({"kv_bytes_per_token": self.kv_bytes_per_token}
+                if not self._state_leaves else {
+                    "state_bytes_per_slot": self.state_bytes_per_slot,
+                    "slots_reset": self.slots_reset,
+                    "snapshots_pooled": self.snapshots_pooled,
+                    "snapshot_hits": self.snapshot_hits})
+        return {**self._device_counters(), **kind,
                 # what this engine's process runs JAX on
                 "devices": device_report(),
                 "total_generated": self.total_generated,

@@ -314,6 +314,50 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
         "expert_weight_copies": ["fusion"] * 3}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_brumby_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-brumby-fewshot`'s two programs, as its configuration
+    file has them (Brumby-14B-Base's widths, 8 layers, 16 slots of 275 MB of
+    float32 state, chunks of 64): the bytes the file gives, room for the
+    pool's four snapshots beside the larger; the decode program one Pallas
+    kernel in the layers' loop and no second copy of the state, of a layer
+    of it or of a slot's worth (its temporaries are 35 MB, a layer's
+    expanded queries and keys, against 4.4 GB of state: the kernel writes
+    the leaf where it reads it); the chunk program no kernel, no copy of the
+    leaf, and temporaries under two slots' state."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_brumby_for_v5e import (CONFIG, compile_step, made_of,
+                                        pool_bytes, program_bytes,
+                                        state_bytes_per_slot)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    want = (memory["decode_step_bytes"] if program == "decode" else memory[
+        "prefill_chunk_bytes_by_chunk_size"][
+            str(config["deployment"]["prefill_chunk_size"])])
+    assert sized["total"] == want
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 16 * 64 * 4 * 2)   # the chunk's tokens
+    assert sized["arguments"] >= 0.7 * HBM_BYTES
+    slot = state_bytes_per_slot(config)
+    assert slot == memory["state_bytes_per_slot"] == 274_759_680
+    assert pool_bytes(config) == memory["prefix_pool_bytes"] == 4 * slot
+    assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    assert sized["total"] + pool_bytes(config) >= 12e9
+    assert sized["temp"] < 2 * slot
+    if program == "decode":
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < slot // 4
+    assert made_of(compiled.as_text(), config) == {
+        "retention_kernels": int(program == "decode"),
+        "state_copies": [], "layer_copies": []}
+
+
 def test_token_selection_compiles_at_xl_vocabulary(chips):
     """`serve/sampling.select_tokens` over the serving cells' [8, 50304]
     logits: one program whose sort is in a branch of a conditional, and

@@ -343,8 +343,11 @@ def test_elastic_shrink_on_daemon_kill(tmp_path):
     history = str(tmp_path / "history.jsonl")
     cluster, nids = _start_elastic_cluster()
     try:
+        # paced above the controller's poll interval (0.2 s): it registers a
+        # checkpoint when it drains a report, so unpaced steps leave the
+        # restore point several steps behind the kill on an idle host
         logic, t, box = _run_controller_bg(tmp_path, "shrink", 12, history,
-                                           regrow=False)
+                                           regrow=False, step_s=0.3)
         _wait_history(history, lambda es: any(
             e["world"] == 2 and e["step"] >= 3 for e in es),
             timeout=180, what="2-worker progress")
