@@ -55,11 +55,6 @@ class GPT2Config:
     # attention implementation: auto | dense | flash (pallas) | ring | ulysses
     # auto: ring when the active mesh has sp>1, flash on TPU, dense otherwise
     attn_impl: str = "auto"
-    # chunked fused cross-entropy: unembed+CE computed per ce_chunk-token
-    # slice under jax.checkpoint, so the [B,T,V] logits (the single
-    # largest training buffer — ~3 GB at 350M/b14) never materialize;
-    # backward recomputes each chunk's logits. 0 = off (plain unembed+CE)
-    ce_chunk: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -269,12 +264,19 @@ def embed(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array:
         return constrain(x.astype(cfg.dtype), "batch", "seq", "embed")
 
 
+def final_hidden(params: Params, x: jax.Array, cfg: GPT2Config) -> tuple:
+    """(the final norm of x, the tied unembedding matrix [D, V] in the
+    compute dtype): what `unembed` multiplies and the fused loss takes
+    apart."""
+    with jax.named_scope("unembed_loss"):
+        return _layer_norm(x, params["ln_f"]), _w(params["wte"].T, cfg)
+
+
 def unembed(params: Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
     """final hidden [B,T,D] -> logits [B,T,vocab] (tied embeddings)."""
+    x, head = final_hidden(params, x, cfg)
     with jax.named_scope("unembed_loss"):
-        x = _layer_norm(x, params["ln_f"])
-        logits = x @ _w(params["wte"].T, cfg)
-        return constrain(logits, "batch", "seq", "vocab")
+        return constrain(x @ head, "batch", "seq", "vocab")
 
 
 def hidden_states(params: Params, tokens: jax.Array,
@@ -313,30 +315,14 @@ def forward(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array:
     return unembed(params, hidden_states(params, tokens, cfg), cfg)
 
 
-def chunked_ce(params: Params, x: jax.Array, targets: jax.Array,
-               cfg: GPT2Config) -> jax.Array:
-    """Fused unembed + cross-entropy over seq chunks: peak logits memory
-    drops from [B, T, V] to [B, ce_chunk, V] (fwd AND bwd — the chunk
-    body is rematerialized), freeing HBM for larger per-chip batches.
-    Numerically identical to unembed+cross_entropy (f32 reductions)."""
-    from ray_tpu.models.lm import chunked_cross_entropy
-
-    with jax.named_scope("unembed_loss"):
-        x = _layer_norm(x, params["ln_f"])
-        W = _w(params["wte"].T, cfg)                       # [D, V]
-    return chunked_cross_entropy(x, W, targets, cfg.ce_chunk)
-
-
 def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
     """Next-token cross-entropy. batch = {"tokens": [B,T+1] int32} or
     {"inputs": [B,T], "targets": [B,T]}."""
-    from ray_tpu.models.lm import cross_entropy, split_lm_batch
+    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
 
     inputs, targets = split_lm_batch(batch)
-    if cfg.ce_chunk:
-        return chunked_ce(params, hidden_states(params, inputs, cfg),
-                          targets, cfg)
-    return cross_entropy(forward(params, inputs, cfg), targets)
+    x = hidden_states(params, inputs, cfg)
+    return chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
 
 
 # ---------------------------------------------------------------------------

@@ -289,7 +289,7 @@ def embed(params: Params, tokens: jax.Array, cfg) -> jax.Array:
 
 def final_hidden(params: Params, x: jax.Array, cfg) -> tuple:
     """(the final norm of x, the unembedding matrix [D, V] in the compute
-    dtype): what `unembed` multiplies and a chunked loss takes apart."""
+    dtype): what `unembed` multiplies and the fused loss takes apart."""
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
@@ -302,8 +302,9 @@ def unembed(params: Params, x: jax.Array, cfg) -> jax.Array:
         return constrain(x @ head, "batch", "seq", "vocab")
 
 
-def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
-    """tokens [B,T] int32 -> logits [B,T,vocab] (compute dtype)."""
+def hidden_states(params: Params, tokens: jax.Array,
+                  cfg: LlamaConfig) -> jax.Array:
+    """tokens [B,T] int32 -> final hidden [B,T,D] (before the final norm)."""
     x = embed(params, tokens, cfg)
 
     block_fn = partial(_block, cfg=cfg)
@@ -313,14 +314,20 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     with jax.named_scope("layers"):     # the scan's own slices and stacks
         x, _ = lax.scan(lambda c, bp: (block_fn(c, bp), None), x,
                         params["blocks"])
-    return unembed(params, x, cfg)
+    return x
+
+
+def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """tokens [B,T] int32 -> logits [B,T,vocab] (compute dtype)."""
+    return unembed(params, hidden_states(params, tokens, cfg), cfg)
 
 
 def loss_fn(params: Params, batch: dict, cfg: LlamaConfig) -> jax.Array:
-    from ray_tpu.models.lm import cross_entropy, split_lm_batch
+    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
 
     inputs, targets = split_lm_batch(batch)
-    return cross_entropy(forward(params, inputs, cfg), targets)
+    x = hidden_states(params, inputs, cfg)
+    return chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
 
 
 # ---------------------------------------------------------------------------

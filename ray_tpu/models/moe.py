@@ -424,25 +424,15 @@ def routing(params: Params, tokens: jax.Array, cfg: MoEConfig) -> dict:
     return hidden_states(params, tokens, cfg)[2]
 
 
-# tokens of the sequence whose float32 logits exist at once in the loss
-CE_CHUNK = 1024
-
-
 def loss_fn(params: Params, batch: dict, cfg: MoEConfig):
     """(cross-entropy + aux_loss_weight · load-balancing loss +
     z_loss_weight · router z-loss, aux): `aux` holds what a MoE job
     watches and `train/spmd.compile_train` adds to the step's metrics."""
-    from ray_tpu.models.lm import (chunked_cross_entropy, cross_entropy,
-                                   split_lm_batch)
+    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
 
     inputs, targets = split_lm_batch(batch)
     x, aux, _ = hidden_states(params, inputs, cfg)
-    T = inputs.shape[1]
-    if T > CE_CHUNK and T % CE_CHUNK == 0:
-        ce = chunked_cross_entropy(*_llama.final_hidden(params, x, cfg),
-                                   targets, CE_CHUNK)
-    else:
-        ce = cross_entropy(_llama.unembed(params, x, cfg), targets)
+    ce = chunked_cross_entropy(*_llama.final_hidden(params, x, cfg), targets)
     loss = (ce + cfg.aux_loss_weight * aux["aux_loss"]
             + cfg.z_loss_weight * aux["z_loss"])
     return loss, {"router_aux_loss": aux["aux_loss"],

@@ -234,16 +234,17 @@ def _lower(program: str):
 
     from ray_tpu.models import gpt2
 
-    cfg = gpt2.GPT2Config.preset("gpt2-tiny",
-                                 ce_chunk=16 if program == "chunked_ce" else 0)
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny")
     params = jax.eval_shape(lambda: gpt2.init_params(jax.random.key(0), cfg))
     ints = jax.ShapeDtypeStruct((4,), jnp.int32)
     on = jax.ShapeDtypeStruct((4,), jnp.bool_)
     cache = jax.eval_shape(lambda: gpt2.init_cache(cfg, 4, 64))
-    if program in ("loss_fn", "chunked_ce"):
+    if program in ("loss_fn", "grad_of_chunked_loss"):
         batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
-        return jax.jit(lambda p, b: gpt2.loss_fn(p, b, cfg)).lower(
-            params, batch)
+        fn = lambda p, b: gpt2.loss_fn(p, b, cfg)        # noqa: E731
+        if program == "grad_of_chunked_loss":
+            fn = jax.value_and_grad(fn)
+        return jax.jit(fn).lower(params, batch)
     if program == "decode_step":
         return jax.jit(lambda p, c, t, pos, a: gpt2.decode_step(
             p, c, t, pos, a, cfg)).lower(params, cache, ints, ints, on)
@@ -254,11 +255,21 @@ def _lower(program: str):
 
 
 @pytest.mark.parametrize("program,want", [
-    ("loss_fn", MODEL_SCOPES), ("chunked_ce", MODEL_SCOPES),
+    ("loss_fn", MODEL_SCOPES), ("grad_of_chunked_loss", MODEL_SCOPES),
     ("decode_step", MODEL_SCOPES | {"kv_update"}),
     ("prefill_chunk", MODEL_SCOPES | {"kv_update"})])
-def test_every_scope_is_in_the_lowered_program(program, want):
-    assert _scopes_in(_lower(program)) == want
+def test_every_scope_is_in_the_lowered_program(monkeypatch, program, want):
+    if program == "grad_of_chunked_loss":
+        # the fused loss in two chunks, and its backward pass, which only
+        # scales what the forward made, under the same scope
+        from ray_tpu.models import lm
+
+        monkeypatch.setattr(lm, "LOGITS_CHUNK_BYTES", 2 * 16 * 512 * 4)
+    lowered = _lower(program)
+    assert _scopes_in(lowered) == want
+    if program == "grad_of_chunked_loss":
+        assert "transpose(jvp(unembed_loss))" in lowered.as_text(
+            debug_info=True)
 
 
 @pytest.fixture(scope="module")

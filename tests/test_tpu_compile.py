@@ -399,6 +399,38 @@ def _compile_train_step(train, batch, seq):
     return train.step_fn.lower(state, data).compile()
 
 
+def _head_products(text: str, vocab: int = 50304) -> list:
+    """(result, operands) of every product (a `dot`, or the `convolution`
+    the TPU compiler makes of one) with a vocabulary-wide operand or
+    result: the passes of the vocabulary head over the step's tokens. A
+    loop's body is in the text once, whatever its trip count."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+    found = []
+    for res, args in re.findall(
+            r"= (\w+\[[\d,]*\])\S* (?:dot|convolution)\(([^)]*)\)", text):
+        ops = [shapes.get(a.strip().split(" ")[-1], "?")
+               for a in args.split(",")]
+        if any(re.search(rf"\b{vocab}\b", s) for s in [res, *ops]):
+            found.append((res, ops))
+    return found
+
+
+def _cell_step(chips, config_name: str):
+    """(a training cell's step as its configuration file has it, compiled
+    for the described chips; the file)."""
+    chip_dir, spec = _chip_bench()
+    config = spec.load_json(os.path.join(chip_dir, "configs",
+                                         config_name + ".json"))
+    n = math.prod(config["job"]["mesh"].values())
+    prog = spec.family(config["family"]).build_train(
+        config["model"], config["job"], chips[:n], 0)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(prog.program.init_fn, jax.random.key(0)),
+        prog.program.state_sharding)
+    return prog.compile_step(state), config
+
+
 def _mosaic_calls(text: str) -> list:
     """The name stacks of the step's Mosaic kernels."""
     calls = re.findall(
@@ -433,6 +465,18 @@ def test_125m_train_step_compiles(chips, as_on_tpu, axes, n_chips):
         assert re.search(r"\ball-reduce(-start)?\(", text)
 
 
+# The training cells' steps with the kept attention kernel (PR 31) and the
+# fused loss (PR 34). The configuration files, which are the benchmark's,
+# still record 16,623,250,432 for OLMoE (the first kernel's `lse` and
+# `delta` were `[B, H, T, 1]` operands of the Mosaic calls, padded to 128
+# lanes in HBM; PR 31's step needed 16,490,938,368), 14,682,482,176 for
+# GPT-2 small (dense attention; 16,215,508,480 with the kernels' residuals
+# saved by name and whole float32 logits) and 14,074,273,792 for GPT-2 XL.
+OLMOE_STEP_BYTES = 16_269_134_336
+SMALL_STEP_BYTES = 12_672_434_688
+XL_STEP_BYTES = 11_712_688_128
+
+
 def test_gpt2_xl_fsdp4_step_compiles_with_the_kernel_in_shard_map(
         chips, as_on_tpu):
     """The cell `train-xl-fsdp4-1k`'s step as its configuration file has
@@ -441,32 +485,41 @@ def test_gpt2_xl_fsdp4_step_compiles_with_the_kernel_in_shard_map(
     each device runs it on its 8 sequences' 25 heads through `shard_map`;
     `full` runs the forward kernel again in the backward pass (4 calls);
     no `[., ., 1024, 1024]` tensor; and a chip needs less than the dense
-    step's 14.07 GB, which the file records (11.71 GB, PR 31)."""
-    chip_dir, spec = _chip_bench()
-    config = spec.load_json(os.path.join(
-        chip_dir, "configs", "gpt2-xl-train-fsdp4.json"))
-    prog = spec.family(config["family"]).build_train(
-        config["model"], config["job"], chips, 0)
-    state = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        jax.eval_shape(prog.program.init_fn, jax.random.key(0)),
-        prog.program.state_sharding)
-    compiled = prog.compile_step(state)
+    step's 14.07 GB, which the file records (11.71 GB, PRs 31 and 34). A
+    chip's 8 x 1,024 tokens are one chunk of the fused loss: the head's
+    three products as before (d(head) in the two halves the partitioner
+    makes of it), each chip's logits its own and no more."""
+    compiled, config = _cell_step(chips, "gpt2-xl-train-fsdp4")
     text = compiled.as_text()
+    products = _head_products(text)
+    assert len(products) == 4 and sum(
+        "[8,1024,50304" in res for res, _ in products) == 1, products
+    assert not re.search(r"\[(16|32),1024,50304", text)
     calls = _mosaic_calls(text)
     assert len(calls) == 4 and all(
         c.endswith("attn/shard_map/pallas_call") for c in calls), calls
     assert not re.search(r"\[\d+,\d+,1024,1024\]", text)
     assert re.search(r"\ball-gather(-start)?\(", text)
-    assert _per_device_bytes(compiled) < config["memory"][
+    assert _per_device_bytes(compiled) == XL_STEP_BYTES < config["memory"][
         "step_program_bytes_compiled_for_v5e"] == 14_074_273_792
 
 
-# OLMoE's step with the kept attention kernel (PR 31). The configuration
-# file, which is the benchmark's, still records the first kernel's
-# 16,623,250,432 (its `lse` and `delta` were `[B, H, T, 1]` operands of the
-# Mosaic calls, padded to 128 lanes in HBM: 268 MB each)
-OLMOE_STEP_BYTES = 16_490_938_368
+def test_gpt2_small_step_holds_a_chunk_of_logits(chips, as_on_tpu):
+    """The cell `train-small-1k`'s step as its configuration file has it
+    (batch 20, T=1024, remat `dots`): the fused loss takes the sequence in
+    chunks, so no `[20, 1024, 50304]` array exists in any dtype (the parent
+    wrote one in float32, 4.1 GB of its 16.2, and read it back in the
+    backward pass), the head is passed over three times, and the step
+    needs SMALL_STEP_BYTES where the file, which is the benchmark's, still
+    records the dense step's 14.68 GB."""
+    compiled, config = _cell_step(chips, "gpt2-small-train-1chip")
+    text = compiled.as_text()
+    assert not re.search(r"\[20,1024,50304", text)
+    assert len(_head_products(text)) == 3
+    calls = _mosaic_calls(text)
+    assert len(calls) == 3 and all("/attn/" in c for c in calls), calls
+    assert _per_device_bytes(compiled) == SMALL_STEP_BYTES < config["memory"][
+        "step_program_bytes_compiled_for_v5e"]
 
 
 def test_olmoe_train_step_compiles_at_the_published_widths(chips, as_on_tpu):
@@ -477,21 +530,22 @@ def test_olmoe_train_step_compiles_at_the_published_widths(chips, as_on_tpu):
     and the experts are nine grouped matmul kernels (three products
     forward, d-lhs and d-rhs of each backward); no `[., 4096, 64, .]`
     one-hot dispatch tensor and no dense product of an expert's width
-    exists."""
-    import json
+    exists; and the vocabulary head is passed over three times (a chunk's
+    logits, d(x), d(head): the parent's checkpointed scan made the logits
+    again in its backward pass, four), with no `[8, 4096, 50304]` array."""
+    _chip_bench()
+    from compile_olmoe_for_v5e import CONFIG, made_of
 
-    chip_dir, _ = _chip_bench()
-    from compile_olmoe_for_v5e import CONFIG, compile_step, made_of
-
-    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
-        config = json.load(f)
-    compiled = compile_step(config, chips)
+    compiled, config = _cell_step(chips, CONFIG)
     total = _per_device_bytes(compiled)
+    text = compiled.as_text()
+    products = _head_products(text)
+    assert len(products) == 3, products
+    assert not re.search(r"\[8,4096,50304", text)
     assert total == OLMOE_STEP_BYTES
     assert total <= config["memory"]["step_program_bytes_compiled_for_v5e"]
     assert 0.8 * HBM_BYTES <= total < HBM_BYTES
-    assert made_of(compiled.as_text(), config["model"],
-                   config["job"]["seq_len"]) == {
+    assert made_of(text, config["model"], config["job"]["seq_len"]) == {
         "flash_kernels": 3, "grouped_matmul_kernels": 9,
         "one_hot_dispatch_tensors": 0, "dense_expert_products": 0}
 
