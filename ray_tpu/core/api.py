@@ -8,6 +8,7 @@ Surface parity with the reference's Python API
 from __future__ import annotations
 
 import atexit
+import contextlib
 import functools
 import json
 import os
@@ -67,9 +68,12 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
     runtime_env keys override the driver's key-by-key."""
     global _client, _head_proc, _driver_runtime_env
     _driver_runtime_env = dict(runtime_env or {}) or None
-    with _lock:
+    from ray_tpu.util import tracing
+
+    with _lock, contextlib.ExitStack() as startup:
         if _client is not None:
             return _client.node_info
+        span = startup.enter_context(tracing.startup_span("startup.init"))
         if address is None and (cfg_addr := _config.get("address")):
             address = cfg_addr
         if address is not None and address.startswith("ray-tpu://"):
@@ -87,6 +91,8 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
             return client.node_info
         if address is None:
             session = f"s{uuid.uuid4().hex[:12]}"
+            tracing.startup_identity("driver", session)
+            t_head = time.time()
             cmd = [sys.executable, "-m", "ray_tpu.core.head_main",
                    "--session", session,
                    "--object-store-bytes",
@@ -112,15 +118,22 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
                 raise RuntimeError(f"head failed to start: {line!r}")
             port = int(line.split("=", 1)[1])
             host = "127.0.0.1"
+            # head process spawned -> it answers with its port
+            tracing.record_startup("startup.head", t_head, time.time(),
+                                   head_pid=_head_proc.pid)
         else:
             host, port_s = address.rsplit(":", 1)
             port = int(port_s)
             session = None
-        client = CoreClient(host, port, session or "joined", is_driver=True)
-        client.start()
+        with tracing.startup_span("startup.connect", head=f"{host}:{port}"):
+            client = CoreClient(host, port, session or "joined",
+                                is_driver=True)
+            client.start()
         if session is None:
             client.store.session = client.node_info["session"]
             client.store._arena = None  # re-derive arena name from the session
+            tracing.startup_identity("driver", client.node_info["session"])
+        span.attributes["worker_id"] = client.worker_id.hex()
         _client = client
         atexit.register(shutdown)
         return client.node_info

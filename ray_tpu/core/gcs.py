@@ -156,6 +156,10 @@ class WorkerInfo:
         # fallback to head_node when its daemon is mid-reconnect)
         self.declared_node: Optional[NodeID] = None
         self.log_tag: Optional[str] = None  # stem of its log files
+        # when this head started the process and when it registered: the
+        # ends of `sched.spawn`, written if it is ever granted chips
+        self.spawn_ts: Optional[float] = None
+        self.registered_ts = time.time()
 
 
 class ActorInfo:
@@ -168,6 +172,15 @@ class ActorInfo:
         self.restarts_left = spec["options"].get("max_restarts", 0)
         self.ready_event = asyncio.Event()
         self.death_cause: Optional[str] = None
+        self.place_ts = _chip_request_ts(spec)
+        self.decided_ts: Optional[float] = None
+
+
+def _chip_request_ts(spec: dict) -> Optional[float]:
+    """Now, for a task or actor that asks for TPU chips (the start of its
+    `sched.place` span); None for any other, which leaves no span."""
+    resources = spec["options"].get("resources") or {}
+    return time.time() if resources.get("TPU") else None
 
 
 class TaskRecord:
@@ -180,6 +193,8 @@ class TaskRecord:
         self.cancelled = False
         self.dispatch_ts: Optional[float] = None
         self.pinned: List[ObjectID] = []  # deps pinned while in flight
+        self.place_ts = _chip_request_ts(spec)
+        self.decided_ts: Optional[float] = None
 
 
 class TaskQueue:
@@ -356,6 +371,7 @@ class Head:
         self.job_counter = 0
         self.start_time = time.time()
         self._spawned: Dict[int, subprocess.Popen] = {}
+        self._spawn_ts: Dict[int, float] = {}   # pid -> when it was started
         # ring buffer of task lifecycle events (reference: task_event_buffer
         # → gcs_task_manager; feeds the state API + `timeline()`)
         from collections import OrderedDict, deque
@@ -510,6 +526,7 @@ class Head:
                            is_driver, node.node_id)
             w.host = _peer_host()  # reachable host for direct actor calls
             w.proc = self._spawned.pop(pid, None)
+            w.spawn_ts = self._spawn_ts.pop(pid, None)
             w.log_tag = log_tag    # maps this worker to its log files
             w.venv_key = venv_key
             # the node the worker CLAIMS to belong to (its spawn-time env),
@@ -1436,6 +1453,13 @@ class Head:
             self.lease_events.append({
                 "ts": time.time(), "kind": f"train_{phase}", "run": run,
                 "t0": t0, "t1": t1, **(detail or {})})
+            if phase == "group_start" and t0 is not None and t1 is not None:
+                # the same event in the start-up record: one file tells
+                # how a cluster's first job came up
+                from ray_tpu.util import tracing
+
+                tracing.record_startup("train.group_start", t0, t1, run=run,
+                                       **(detail or {}))
             return True
 
         async def chain_event(chain, kind, detail=None):
@@ -2258,6 +2282,7 @@ class Head:
             if w is None:
                 for _ in range(max(1, want_workers)):
                     self._request_worker(node, pip, venv_key)
+                self._spawn_decided(rec)
                 return "worker"
             self._acquire(w, resources, pg, bundle)
         else:
@@ -2269,8 +2294,10 @@ class Head:
             if w is None:
                 for _ in range(max(1, want_workers)):
                     self._request_worker(node, pip, venv_key)
+                self._spawn_decided(rec)
                 return "worker"
             self._acquire(w, resources)
+        self._record_chip_grant(rec, w, task_id=rec.task_id.hex())
         w.running_task = rec.task_id
         w.current_record = rec
         rec.dispatch_ts = time.time()
@@ -2342,6 +2369,7 @@ class Head:
             w = self._idle_worker_on(node, venv_key)
             if w is None:
                 self._request_worker(node, pip, venv_key)
+                self._spawn_decided(info)
                 return
             self._acquire(w, resources, pg, bundle)
         else:
@@ -2352,11 +2380,42 @@ class Head:
             w = self._idle_worker_on(node, venv_key)
             if w is None:
                 self._request_worker(node, pip, venv_key)
+                self._spawn_decided(info)
                 return
             self._acquire(w, resources)
+        self._record_chip_grant(info, w, actor_id=info.actor_id.hex())
         w.actor_id = info.actor_id
         info.worker = w
         w.conn.push("start_actor", spec=self._with_chips(info.spec, w))
+
+    # -------------------------------------------------- start-up record
+    @staticmethod
+    def _spawn_decided(request) -> None:
+        """A request for chips found no idle worker and a spawn was asked
+        for: where its `sched.place` span will end."""
+        if request.place_ts is not None and request.decided_ts is None:
+            request.decided_ts = time.time()
+
+    def _record_chip_grant(self, request, w: WorkerInfo, **ids) -> None:
+        """The start-up record of a task or actor (`request`) that was
+        just granted chips on `w`: `sched.place`, its arrival -> a worker
+        chosen or a spawn decided, and `sched.spawn`, that worker's
+        `Popen` -> its registration (a worker a node daemon started has no
+        `Popen` here: its span is left out). Nothing for a request that
+        asked for no chips."""
+        if request.place_ts is None or not w.tpu_chips:
+            return
+        from ray_tpu.util import tracing
+
+        ids.update(worker_id=w.worker_id.hex(), worker_pid=w.pid)
+        tracing.record_startup(
+            "sched.place", request.place_ts,
+            request.decided_ts or time.time(), chips=len(w.tpu_chips),
+            spawned=request.decided_ts is not None, **ids)
+        if w.spawn_ts is not None:
+            tracing.record_startup("sched.spawn", w.spawn_ts,
+                                   w.registered_ts, **ids)
+        request.place_ts = None     # once a placement
 
     # -------------------------------------------------------------- workers
     def _request_worker(self, node: NodeInfo, pip=None,
@@ -2445,11 +2504,13 @@ class Head:
         env = dict(env)
         env["RAY_TPU_LOG_TAG"] = tag
         env.setdefault("PYTHONUNBUFFERED", "1")
+        t_spawn = time.time()
         with out, err:
             proc = subprocess.Popen(
                 [python, "-m", "ray_tpu.core.worker_main"],
                 env=env, stdout=out, stderr=err)
         self._spawned[proc.pid] = proc
+        self._spawn_ts[proc.pid] = t_spawn
 
     def _on_log_batch(self, entries: List[dict]) -> None:
         """Freshly tailed worker-log lines (local monitor thread or a node
@@ -2558,6 +2619,8 @@ class Head:
                         info.restarts_left -= 1
                     info.state = "RESTARTING"
                     info.ready_event = asyncio.Event()
+                    info.place_ts = _chip_request_ts(info.spec)
+                    info.decided_ts = None
                     self._publish("actor_state", {"actor_id": w.actor_id.binary(),
                                                   "state": "RESTARTING"})
                     self._schedule_actor(info)

@@ -13,6 +13,7 @@ import json
 import os
 import signal
 import sys
+import time
 
 from ray_tpu.core import config as _config
 from ray_tpu.core.gcs import Head
@@ -45,6 +46,10 @@ async def amain(args) -> None:
                 os.unlink(seg)
             except OSError:
                 pass
+    from ray_tpu.util import tracing
+
+    tracing.startup_identity("head", args.session)
+    t_node = time.time()
     head = Head(session=args.session, num_cpus=args.num_cpus,
                 resources=json.loads(args.resources) if args.resources else None,
                 num_tpu_chips=args.num_tpu_chips,
@@ -52,6 +57,14 @@ async def amain(args) -> None:
                 max_workers=args.max_workers,
                 labels=json.loads(args.labels) if args.labels else None)
     port = await head.start(port=args.port)
+    # the head is its own first node: chips detected, store made, serving.
+    # Explicit times: a span left open here would be inherited as the
+    # parent by every task the server starts
+    tracing.record_startup(
+        "startup.node", t_node, time.time(),
+        proc_start_ts=tracing.process_start_ts(),
+        node_id=head.node_id.hex(),
+        chips=int(head.head_node.resources.get("TPU", 0)))
     restored = head.restore_snapshot() if args.restore else False
     if args.enable_snapshots:
         asyncio.ensure_future(head._snapshot_loop())

@@ -987,6 +987,9 @@ class NodeDaemon:
 async def amain(args):
     protocol.enable_eager_tasks(asyncio.get_running_loop())
     host, port_s = args.address.rsplit(":", 1)
+    from ray_tpu.util import tracing
+
+    t_node = time.time()
     daemon = NodeDaemon(
         host, int(port_s), num_cpus=args.num_cpus,
         num_tpu_chips=args.num_tpu_chips,
@@ -994,6 +997,13 @@ async def amain(args):
         labels=json.loads(args.labels) if args.labels else None,
         max_workers=args.max_workers)
     await daemon.start()
+    # chips detected -> registered with the head, which names the session
+    # (explicit times: the daemon's tasks must not inherit an open span)
+    tracing.startup_identity("node", daemon.session)
+    tracing.record_startup("startup.node", t_node, time.time(),
+                           proc_start_ts=tracing.process_start_ts(),
+                           node_id=daemon.node_id.hex(),
+                           chips=int(daemon.resources.get("TPU", 0)))
     print(f"RAY_TPU_NODE_ID={daemon.node_id.hex()}", flush=True)
     await daemon.run()
 

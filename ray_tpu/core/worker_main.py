@@ -382,16 +382,27 @@ class WorkerRuntime:
         self.client.current_actor_id = self.actor_id
 
         def _init():
-            self._claim_chips(spec)
-            if opts.get("runtime_env"):
-                from ray_tpu.core.runtime_env import AppliedEnv
+            from ray_tpu.util import tracing
 
-                # actors keep their env for life (dedicated-worker model);
-                # never restored — the worker exits with the actor
-                AppliedEnv(self.client, opts["runtime_env"])
-            cls = self.client.fn_manager.load(spec["cls_key"])
-            args, kwargs = self._resolve_args(spec["args"])
-            self.actor_instance = cls(*args, **kwargs)
+            # whole: the chips, the environment, the class's imports, the
+            # arguments and the constructor
+            with tracing.startup_span(
+                    "worker.actor_init", actor_id=self.actor_id.hex(),
+                    worker_id=self.client.worker_id.hex(),
+                    chips=len(spec.get("tpu_chips") or ())) as span:
+                self._claim_chips(spec)
+                if opts.get("runtime_env"):
+                    from ray_tpu.core.runtime_env import AppliedEnv
+
+                    # actors keep their env for life (dedicated-worker
+                    # model); never restored — the worker exits with the
+                    # actor
+                    AppliedEnv(self.client, opts["runtime_env"])
+                cls = self.client.fn_manager.load(spec["cls_key"])
+                span.attributes["actor_class"] = getattr(
+                    cls, "__name__", str(cls))
+                args, kwargs = self._resolve_args(spec["args"])
+                self.actor_instance = cls(*args, **kwargs)
 
         try:
             await loop.run_in_executor(self.actor_executors[DEFAULT_GROUP], _init)
@@ -481,18 +492,26 @@ class WorkerRuntime:
 
 
 def main():
+    t_main = time.time()
     from ray_tpu.core import config as _config
+    from ray_tpu.util import tracing
     from ray_tpu.utils.platform import enable_compile_cache
 
     enable_compile_cache()  # before any user code can import JAX
     head_host = _config.get("head_host")
     head_port = int(os.environ["RAY_TPU_HEAD_PORT"])
     session = os.environ["RAY_TPU_SESSION"]
+    tracing.startup_identity("worker", session)
     rt = WorkerRuntime(head_host, head_port, session)
     try:
         rt.start()
     except (ConnectionRefusedError, OSError, TimeoutError):
         sys.exit(0)  # head already gone: racing a cluster shutdown
+    # first line of main() -> registered; the interpreter's start and the
+    # imports above lie between `proc_start_ts` and the span's start
+    tracing.record_startup("worker.boot", t_main, time.time(),
+                           proc_start_ts=tracing.process_start_ts(),
+                           worker_id=rt.client.worker_id.hex())
     rt.run_forever()
 
 

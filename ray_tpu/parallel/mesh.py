@@ -23,6 +23,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.utils.platform import watch_compiles
+
 P = PartitionSpec
 
 # Canonical mesh axis names, outermost (DCN-tolerant) to innermost (ICI-only).
@@ -137,6 +139,7 @@ def build_mesh(
     `jax.experimental.mesh_utils.create_device_mesh` for ICI-optimal layout
     (we do that automatically when the topology is a known slice shape).
     """
+    watch_compiles()    # whoever builds a mesh is about to compile on it
     if devices is None:
         devices = jax.devices()
     devices = list(devices)

@@ -9,6 +9,7 @@ detached ServeController.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Dict, Optional
 
 import ray_tpu
@@ -114,9 +115,14 @@ def _get_or_create_controller():
 def start(http_host: str = "127.0.0.1", http_port: int = 0) -> int:
     """Start the HTTP ingress proxy; returns the bound port (reference
     serve.start(http_options=...))."""
-    controller = _get_or_create_controller()
-    return ray_tpu.get(controller.ensure_proxy.remote(http_host, http_port),
-                       timeout=120)
+    from ray_tpu.util import tracing
+
+    with tracing.startup_span("serve.proxy_start") as span:
+        controller = _get_or_create_controller()
+        port = ray_tpu.get(
+            controller.ensure_proxy.remote(http_host, http_port), timeout=120)
+        span.attributes["port"] = port
+    return port
 
 
 def _resolve_composition(value, controller):
@@ -151,6 +157,7 @@ def run(target: Deployment, *, name: Optional[str] = None,
     `_local_testing_mode=True` runs the deployment IN-PROCESS with no
     cluster (reference local_testing_mode): unit-test deployment logic
     without actors/proxies."""
+    run_ts = time.time()
     if compiled is not None:
         target = dataclasses.replace(target, compiled=bool(compiled))
     if _local_testing_mode:
@@ -168,8 +175,9 @@ def run(target: Deployment, *, name: Optional[str] = None,
         init_kwargs=(_resolve_composition(target.init_kwargs, controller)
                      if target.init_kwargs else target.init_kwargs))
     dep_name = name or target.name
-    ray_tpu.get(controller.deploy.remote(dep_name, target.to_config()),
-                timeout=60)
+    # `run_ts`: where the controller's `serve.deploy` span starts
+    ray_tpu.get(controller.deploy.remote(
+        dep_name, {**target.to_config(), "run_ts": run_ts}), timeout=60)
     if route_prefix is not None:
         ray_tpu.get(controller.set_route.remote(route_prefix, dep_name),
                     timeout=30)
@@ -180,8 +188,6 @@ def run(target: Deployment, *, name: Optional[str] = None,
 
 
 def _wait_healthy(controller, dep_name: str, timeout: float = 60):
-    import time
-
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         status = ray_tpu.get(controller.list_deployments.remote(), timeout=30)

@@ -228,6 +228,31 @@ class ServeController:
         info.replicas[tag] = handle
         info.replica_meta[tag] = {"healthy": True, "started": time.time()}
         info.version += 1
+        threading.Thread(
+            target=self._record_deploy, daemon=True, name="serve-deploy-span",
+            # the deployment's first replica answers for `serve.run`
+            args=(info.name, tag, handle,
+                  cfg.pop("run_ts", None) or time.time()),
+        ).start()
+
+    @staticmethod
+    def _record_deploy(name: str, tag: str, handle, start_ts: float) -> None:
+        """The start-up span `serve.deploy`: `serve.run` called (or, for a
+        replica the controller added later, its start) -> the replica
+        reported ready. A call queues behind the replica's `__init__`, so
+        the first answer is that report; off the reconcile loop, which a
+        constructor of minutes must not hold."""
+        from ray_tpu.util import tracing
+
+        try:
+            ray_tpu.get(handle.check_health.remote(),
+                        timeout=10 * REPLICA_INIT_GRACE_S)
+            ready = True
+        except Exception:  # noqa: BLE001 - it died or never answered: said so
+            ready = False
+        tracing.record_startup("serve.deploy", start_ts, time.time(),
+                               deployment=name, replica=tag, ready=ready,
+                               actor_id=handle._actor_id.hex())
 
     def _stop_replica(self, handle):
         def _drain_and_kill():

@@ -218,6 +218,7 @@ class LLMEngine:
     is learnt one step late, and the lane-step it cost is dropped.
     """
 
+    @tracing.startup_span("engine.init")
     def __init__(self, preset: str = "gpt2-tiny", max_batch: int = 4,
                  max_seq_len: int = 128, seed: int = 0,
                  model_overrides: Optional[dict] = None,
@@ -239,7 +240,22 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from ray_tpu.models import serving_family
+        from ray_tpu.utils.platform import watch_compiles
 
+        self._compile_watch = watch_compiles()
+
+        def stage(name: str, since: float, **attributes) -> float:
+            """Closes the start-up span `engine.<name>`; returns the next
+            stage's start. The first three close when the host has
+            dispatched their work, and only the last waits for the device:
+            a wait between them changes the order in which the weights,
+            the cache and the pool are allocated, and with it the decode
+            step's time on the chip (+1.9%, PERF.md section 6, PR 35)."""
+            now = time.time()
+            tracing.record_startup(f"engine.{name}", since, now, **attributes)
+            return now
+
+        t_stage = time.time()
         self.family, model, config_cls = serving_family(preset)
         self.jax, self.jnp, self.model = jax, jnp, model
         if checkpoint:
@@ -259,6 +275,7 @@ class LLMEngine:
                         else config_cls.preset(preset, **overrides))
             source = params_override
             self.checkpoint = checkpoint
+            weights_from = "caller"
         elif checkpoint:
             # REAL weights: architecture from the checkpoint sidecar,
             # runtime knobs (seq len etc.) from the preset/overrides.
@@ -283,12 +300,14 @@ class LLMEngine:
                               if store is not None else None)
                     if loaded is not None:
                         source, self.cfg = loaded
+                        weights_from = "peers"
                         _ws.observe_cold_start(
                             _time.perf_counter() - t0, "p2p")
                 except Exception:
                     source = None   # never fail init on the store
             if source is None:
                 source, self.cfg = model.load_params(checkpoint, cfg=base)
+                weights_from = "checkpoint"
                 if weight_store:
                     from ray_tpu.serve import weight_store as _ws
 
@@ -303,6 +322,9 @@ class LLMEngine:
             self.cfg = config_cls.preset(preset, **overrides)
             source = model.init_params(jax.random.key(seed), self.cfg)
             self.checkpoint = None
+            weights_from = "seed"
+        t_stage = stage("weights", t_stage, preset=preset,
+                        source=weights_from)
         # the replica's one copy of the weights on the device, and what
         # `_step` and `_chunk_step` take: converted once, here, to what the
         # step programs read (the model's `resident_params`). What was made,
@@ -311,6 +333,7 @@ class LLMEngine:
         # as well
         self.params = model.resident_params(source, self.cfg)
         del source
+        t_stage = stage("resident", t_stage)
         # weight identity for the cluster prefix store: engines whose KV
         # is interchangeable must agree on it. Checkpoint path or
         # preset+seed derive it; params_override callers (LoRA adapters)
@@ -350,6 +373,7 @@ class LLMEngine:
             self.kv = PagedKVCache.for_cache(
                 self.cache, model.CACHE_TOKEN_AXIS, num_blocks=kv_blocks,
                 block_size=kv_block_size, state=self._state_leaves)
+        t_stage = stage("cache", t_stage)
 
         # chunk must fit the serving window (prefill_chunk requires C <= T)
         self.prefill_chunk_size = max(1, min(prefill_chunk_size,
@@ -442,6 +466,14 @@ class LLMEngine:
         # each slot's newest token, where the selection left it: the next
         # step's decode lanes read it there, the host reads it a step late
         self._ids = jax.device_put(np.zeros((max_batch,), np.int32), rep)
+        # weights and cache laid over the tensor-parallel mesh (one chip:
+        # nothing moves), the step programs' wrappers made, and the
+        # constructor's one wait for the device: whatever the stages
+        # above left running there (`device_wait_s`)
+        t_wait = time.time()
+        jax.block_until_ready((self.params, self.cache, self._ids))
+        stage("place", t_stage, tensor_parallel_size=tensor_parallel_size,
+              device_wait_s=time.time() - t_wait)
         # rows temperature, top_k, top_p of each slot's request
         self._sampling = np.zeros((3, max_batch), np.float32)
         self.tokenizer = tokenizer if tokenizer is not None else ByteTokenizer()
@@ -1058,9 +1090,15 @@ class LLMEngine:
                     "slots_reset": self.slots_reset,
                     "snapshots_pooled": self.snapshots_pooled,
                     "snapshot_hits": self.snapshot_hits})
+        watch = self._compile_watch
         return {**self._device_counters(), **kind,
                 # what this engine's process runs JAX on
                 "devices": device_report(),
+                # programs this process has prepared (compiled or read
+                # from the cache) and the newest: a count that rises under
+                # load is a shape nobody warmed, and this is its name
+                "compiles": watch.count,
+                "last_compile": dict(watch.last) if watch.last else None,
                 "total_generated": self.total_generated,
                 "engine_steps": self.engine_steps,
                 "steps_dispatched_ahead": self.steps_dispatched_ahead,

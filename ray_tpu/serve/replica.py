@@ -32,12 +32,21 @@ class ReplicaActor:
         self._healthy = True
         self._draining = False
         self._metrics = None
-        if isinstance(cls_or_fn, type):
-            self.callable = cls_or_fn(*(init_args or ()), **(init_kwargs or {}))
-        else:
-            self.callable = cls_or_fn
-        if user_config is not None:
-            self._apply_user_config(user_config)
+        from ray_tpu.util import tracing
+
+        # the user's class constructed (and configured): where a model
+        # server builds its engine
+        with tracing.startup_span(
+                "replica.init", deployment=deployment_name,
+                replica=replica_tag,
+                callable=getattr(cls_or_fn, "__name__", str(cls_or_fn))):
+            if isinstance(cls_or_fn, type):
+                self.callable = cls_or_fn(*(init_args or ()),
+                                          **(init_kwargs or {}))
+            else:
+                self.callable = cls_or_fn
+            if user_config is not None:
+                self._apply_user_config(user_config)
 
     def _apply_user_config(self, user_config):
         reconfigure = getattr(self.callable, "reconfigure", None)

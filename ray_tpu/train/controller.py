@@ -41,8 +41,11 @@ class TrainControllerLogic:
     def __init__(self, train_fn: Callable, train_config: Any,
                  scaling_config: ScalingConfig, run_config: RunConfig,
                  backend=None, resume_from: Optional[str] = None,
-                 datasets: Optional[dict] = None):
+                 datasets: Optional[dict] = None,
+                 fit_ts: Optional[float] = None):
         self.train_fn = train_fn
+        # when the driver called `fit()`: the start of `train.fit`
+        self._fit_ts = fit_ts
         self.train_config = train_config
         self.scaling = scaling_config
         self.run_config = run_config
@@ -95,8 +98,15 @@ class TrainControllerLogic:
             client.head_request("train_event", run=self._run_name,
                                 phase=phase, t0=t0, t1=t1,
                                 detail=detail or None)
-        except Exception:
-            pass
+        except Exception:  # noqa: BLE001 - best-effort, but not silent
+            # the head never heard of it: say so in this process's own
+            # start-up record, so that a missing `group_start` is not
+            # read as a fast one
+            from ray_tpu.util import tracing
+
+            now = time.time()
+            tracing.record_startup(f"train.{phase}", t0 or now, t1 or now,
+                                   run=self._run_name, event_lost=True)
 
     def _arm_death_watch(self, group: WorkerGroup) -> None:
         """Subscribe to actor/node death events for this gang's members.
@@ -292,6 +302,16 @@ class TrainControllerLogic:
                 break
             else:
                 self._arm_death_watch(group)
+                if self._fit_ts is not None:
+                    # `fit()` -> every worker's loop running (its thread
+                    # started); a restarted group is not a start-up
+                    from ray_tpu.util import tracing
+
+                    tracing.record_startup(
+                        "train.fit", self._fit_ts, time.time(),
+                        run=self._run_name, world=self.current_world_size,
+                        actor_ids=",".join(group.actor_ids))
+                    self._fit_ts = None
                 self._emit_event(
                     "group_start", t0=t_sched, t1=time.time(),
                     world=self.current_world_size, generation=self.generation,
@@ -458,9 +478,9 @@ class TrainControllerActor:
     detached TrainController)."""
 
     def run(self, train_fn, train_config, scaling_config, run_config,
-            backend=None, resume_from=None, datasets=None):
+            backend=None, resume_from=None, datasets=None, fit_ts=None):
         logic = TrainControllerLogic(train_fn, train_config, scaling_config,
                                      run_config, backend=backend,
                                      resume_from=resume_from,
-                                     datasets=datasets)
+                                     datasets=datasets, fit_ts=fit_ts)
         return logic.run()

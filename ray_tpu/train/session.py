@@ -49,6 +49,10 @@ class TrainContext:
         self.reports: List[Dict[str, Any]] = []
         self.lock = threading.Lock()
         self.stop_requested = False
+        # when the worker's thread entered the user's loop, until the
+        # loop's first `compile_train` has written it down (start-up span
+        # `train.loop_prelude`)
+        self.loop_start_ts: Optional[float] = None
         # step telemetry: the window between consecutive report() calls
         self._step_wall_t0 = time.time()
         self._step_idx = 0

@@ -10,6 +10,7 @@ buffers donated so XLA updates weights in place, gradient allreduce riding ICI.
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -20,6 +21,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ray_tpu.parallel import mesh as mesh_lib
+from ray_tpu.util import tracing
+from ray_tpu.utils.platform import watch_compiles
 
 P = PartitionSpec
 
@@ -388,6 +391,38 @@ def _timed_hier_step(loss_fn, mesh: Mesh, topo, params_spec, batch_spec,
     return timed_step
 
 
+def _record_loop_prelude() -> None:
+    """In a trainer worker's loop, the start-up span `train.loop_prelude`:
+    the loop's first line -> its first `compile_train`. The user's own
+    code, and where a loop first touches JAX: the devices open in it."""
+    from ray_tpu.train import session
+
+    ctx = getattr(session._ctx, "value", None)
+    since = getattr(ctx, "loop_start_ts", None)
+    if since is not None:
+        ctx.loop_start_ts = None
+        tracing.record_startup("train.loop_prelude", since, time.time(),
+                               rank=ctx.rank)
+
+
+def _timed_first_call(init_fn):
+    """`init_fn`, its first real call inside the start-up span
+    `train.init_state`: the program prepared and the state made on the
+    devices (the call waits for it, as its caller is about to). A trace
+    of it (`jax.eval_shape`) passes through."""
+    pending = [True]
+
+    def init(key):
+        if not pending or isinstance(key, jax.core.Tracer):
+            return init_fn(key)
+        pending.clear()
+        with tracing.startup_span("train.init_state"):
+            return jax.block_until_ready(init_fn(key))
+
+    return init
+
+
+@tracing.startup_span("train.compile")
 def compile_train(
     loss_fn: Callable[[Any, Any], jax.Array],
     init_params_fn: Callable[[jax.Array], Any],
@@ -422,6 +457,8 @@ def compile_train(
     workload rows — an opt-in diagnostics window, not a replacement for
     the fused `step_fn`.
     """
+    watch_compiles()
+    _record_loop_prelude()
     optimizer = optimizer or default_optimizer()
     loss_aux_fn, loss_fn = _with_aux(loss_fn), _loss_only(loss_fn)
     hier = mesh_lib.is_hierarchical_mesh(mesh)
@@ -574,7 +611,8 @@ def compile_train(
         out_shardings=state_sharding,
         donate_argnums=(0,),
     )
-    return CompiledTrain(mesh=mesh, init_fn=init_fn, step_fn=step_fn,
+    return CompiledTrain(mesh=mesh, init_fn=_timed_first_call(init_fn),
+                         step_fn=step_fn,
                          batch_sharding=batch_sharding,
                          state_sharding=state_sharding,
                          grad_fn=grad_fn, apply_fn=apply_fn,

@@ -11,6 +11,7 @@ coordinator env vars for `jax.distributed.initialize`.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
@@ -104,6 +105,7 @@ class DataParallelTrainer:
         self.datasets = datasets or {}
 
     def fit(self, _in_process: bool = False) -> Result:
+        fit_ts = time.time()    # where the controller's `train.fit` starts
         resume = (self.resume_from_checkpoint.path
                   if self.resume_from_checkpoint else None)
         if _in_process or not ray_tpu.is_initialized():
@@ -113,7 +115,7 @@ class DataParallelTrainer:
             logic = TrainControllerLogic(
                 self.train_loop_per_worker, self.train_loop_config,
                 self.scaling_config, self.run_config, backend=self.backend,
-                resume_from=resume, datasets=self.datasets)
+                resume_from=resume, datasets=self.datasets, fit_ts=fit_ts)
             out = logic.run()
         else:
             controller = TrainControllerActor.options(
@@ -122,7 +124,7 @@ class DataParallelTrainer:
             out = ray_tpu.get(controller.run.remote(
                 self.train_loop_per_worker, self.train_loop_config,
                 self.scaling_config, self.run_config, self.backend, resume,
-                self.datasets),
+                self.datasets, fit_ts),
                 timeout=None)
             ray_tpu.kill(controller)
         result = Result(

@@ -10,6 +10,7 @@ reserved slice via the slice-name label selector (SURVEY §3.4).
 from __future__ import annotations
 
 import threading
+import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
@@ -17,6 +18,7 @@ import ray_tpu
 from ray_tpu.core import serialization
 from ray_tpu.train import session as session_lib
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 
 @ray_tpu.remote
@@ -29,15 +31,23 @@ class TrainWorker:
         self._error: Optional[str] = None
         self._done = False
 
+    @tracing.startup_span("train.worker_setup")
     def setup_and_start(self, train_fn, train_config, rank, world_size,
                         local_rank, node_rank, resume_checkpoint_path,
                         backend_env: Optional[Dict[str, str]] = None,
                         generation: int = 0, run_name: Optional[str] = None,
                         dataset_shards: Optional[dict] = None):
         import os
+        import sys
 
-        from ray_tpu.util import tracing
+        tracing.startup_attributes(rank=rank, world_size=world_size,
+                                   run=run_name)
+        if "jax" in sys.modules:
+            # a JAX trainer's worker: every program it prepares from here
+            # on is in the start-up record by name
+            from ray_tpu.utils.platform import watch_compiles
 
+            watch_compiles()
         if backend_env:
             os.environ.update(backend_env)
         # the train thread outlives this call, and the call's arguments
@@ -61,6 +71,7 @@ class TrainWorker:
 
         def _run():
             session_lib._set_context(self._ctx)
+            self._ctx.loop_start_ts = time.time()
             try:
                 with tracing.adopt_context(carrier):
                     if train_config is None:

@@ -18,6 +18,17 @@ to an OpenTelemetry SDK.
 Two clocks. `Span`s carry `time.time()`. The serving engine's loop also
 opens `annotate(...)` spans on the JAX profiler's clock, which a device
 trace shares. The vocabulary is in the README's observability section.
+
+Start-up is on the first clock. `startup_span` / `record_startup` keep one
+start-up record a process: ordinary `Span`s on `time.time()`, because that
+is the clock a cold start is felt on and the only one the driver, the
+head, a worker and JAX's own compile events (`utils/platform.
+watch_compiles`) share; no device trace runs while a process starts, so
+the profiler's clock has nothing to offer there. The record is always on
+(a slow start is looked into after the fact), bounded, nowhere near a hot
+path, and written a line a span to
+`<STATE_DIR>/<session>/logs/startup-<role>-<pid>.jsonl`, beside the worker
+logs, when the span closes.
 """
 
 from __future__ import annotations
@@ -25,6 +36,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import json
 import os
 from ray_tpu.core import config as _config
 import secrets
@@ -322,3 +334,147 @@ def request_span(name: str, carrier: Optional[Dict[str, str]],
     if not carrier and not is_enabled():
         return contextlib.nullcontext()
     return start_span(name, carrier=carrier, attributes=attributes)
+
+
+# ---------------------------------------------------------------- start-up
+# One record a process of where its start-up went (module docstring).
+STARTUP_SPANS_MAX = 512
+_startup: List[Span] = []
+_startup_lines: List[str] = []        # closed before the session was known
+_startup_role = "process"
+_startup_session: Optional[str] = None
+_startup_current: "contextvars.ContextVar[Optional[Span]]" = \
+    contextvars.ContextVar("ray_tpu_startup_span", default=None)
+
+
+def startup_identity(role: str, session: Optional[str]) -> None:
+    """Who this process is in its cluster: `role` names its file
+    (`driver`, `head`, `node`, `worker`) and `session` the directory. A
+    process that learns its session late (a driver: `init()` makes or is
+    told it) has buffered until now; a second session in one process (a
+    driver that calls `init()` again) starts the record anew."""
+    global _startup_role, _startup_session
+    with _lock:
+        if _startup_session is not None and session != _startup_session:
+            _startup.clear()
+            _startup_lines.clear()
+        _startup_role, _startup_session = role, session
+        lines = list(_startup_lines) if session else []
+        if lines:
+            _startup_lines.clear()
+    if lines:
+        _write_startup(lines)
+
+
+def startup_file() -> Optional[str]:
+    """Where this process's start-up spans are written; None until it
+    knows its session."""
+    if _startup_session is None:
+        return None
+    from ray_tpu.utils.platform import STATE_DIR
+
+    return os.path.join(STATE_DIR, _startup_session, "logs",
+                        f"startup-{_startup_role}-{os.getpid()}.jsonl")
+
+
+def _write_startup(lines: List[str]) -> None:
+    # open, append, close: a worker ends by signal, nothing waits for atexit
+    try:
+        path = startup_file()
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "a") as f:
+            f.write("".join(lines))
+    except OSError:
+        pass
+
+
+def startup_spans() -> List[Span]:
+    """This process's closed start-up spans, oldest first (at most
+    STARTUP_SPANS_MAX: later ones are counted where they are counted,
+    `jax_compiles_total`, and kept nowhere)."""
+    with _lock:
+        return list(_startup)
+
+
+def _new_startup(name: str, start_ts: float, attributes: dict) -> Span:
+    parent = _startup_current.get()
+    return Span(name=name,
+                trace_id=parent.trace_id if parent else secrets.token_hex(16),
+                span_id=secrets.token_hex(8),
+                parent_id=parent.span_id if parent else None,
+                attributes=dict(attributes), start_ts=start_ts)
+
+
+def _close_startup(span: Span, end_ts: float) -> None:
+    span.end_ts = end_ts
+    # who closed it: a driver learns its role inside its first span
+    span.attributes.update(role=_startup_role, pid=os.getpid())
+    with _lock:
+        if len(_startup) >= STARTUP_SPANS_MAX:
+            return
+        _startup.append(span)
+        line = json.dumps(span.to_dict()) + "\n"
+        if _startup_session is None:
+            _startup_lines.append(line)
+            line = None
+    if line is not None:
+        _write_startup([line])
+    if is_recording():
+        _finish(span)
+
+
+class startup_span(contextlib.ContextDecorator):
+    """A stage of this process's start-up: an ordinary `Span`, parented to
+    the start-up span open around it, recorded whether or not tracing is
+    on. `with startup_span(name, **attributes) as span` (its `attributes`
+    may be added to until it closes), or `@startup_span(name)` around a
+    whole function (plain data, so that an actor's class still pickles)."""
+
+    def __init__(self, name: str, **attributes):
+        self.name, self.attributes = name, attributes
+
+    def _recreate_cm(self) -> "startup_span":
+        return startup_span(self.name, **self.attributes)   # one a call
+
+    def __enter__(self) -> Span:
+        self._span = _new_startup(self.name, time.time(), self.attributes)
+        self._token = _startup_current.set(self._span)
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        _startup_current.reset(self._token)
+        _close_startup(self._span, time.time())
+
+
+def startup_attributes(**attributes) -> None:
+    """Adds to the start-up span open around the caller (a function under
+    `@startup_span(...)` learns its join keys inside); nothing without
+    one."""
+    span = _startup_current.get()
+    if span is not None:
+        span.attributes.update(attributes)
+
+
+def record_startup(name: str, start_ts: float, end_ts: float,
+                   **attributes) -> Span:
+    """A start-up stage with explicit times, for work that crosses
+    callbacks or whose ends are only known afterwards (as `record_span` is
+    to `start_span`). Never becomes current."""
+    span = _new_startup(name, start_ts, attributes)
+    _close_startup(span, end_ts)
+    return span
+
+
+def process_start_ts() -> Optional[float]:
+    """When the kernel started this process, on `time.time()`'s clock
+    (`/proc/self/stat`'s start time against `/proc/uptime`, both in clock
+    ticks of 10 ms): the interpreter's start and every import before a
+    process's first line lie between it and that line."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return time.time() - (uptime - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return None
