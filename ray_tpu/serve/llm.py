@@ -216,6 +216,10 @@ class LLMEngine:
     the device never waits for the host to learn a token. A reply that
     ends by length gives up its slot before its last step has run; an EOS
     is learnt one step late, and the lane-step it cost is dropped.
+
+    What differs between runs is an argument of a program, never a
+    constant of it: the persistent compile cache hands a replica every
+    program whatever `seed` it was started with.
     """
 
     @tracing.startup_span("engine.init")
@@ -391,11 +395,11 @@ class LLMEngine:
             return model.prefill_chunk(params, cache, tokens, pos0, length,
                                        active, cfg)
 
-        def _select(logits, prev, produce, sampling, step):
+        def _select(logits, prev, produce, sampling, step, key):
             temperature, top_k, top_p = sampling
-            key = jax.random.fold_in(jax.random.key(seed), step)
             return select_tokens(logits, prev, produce, temperature,
-                                 top_k.astype(jnp.int32), top_p, key)
+                                 top_k.astype(jnp.int32), top_p,
+                                 jax.random.fold_in(key, step))
 
         def _reset(cache, slot):
             # a new sequence starts from no state: stale rows of a KV slot
@@ -466,12 +470,16 @@ class LLMEngine:
         # each slot's newest token, where the selection left it: the next
         # step's decode lanes read it there, the host reads it a step late
         self._ids = jax.device_put(np.zeros((max_batch,), np.int32), rep)
+        # the selection's base key, resident beside them: each step folds
+        # its number into it on the device
+        self._key = jax.device_put(jax.random.key(seed), rep)
         # weights and cache laid over the tensor-parallel mesh (one chip:
         # nothing moves), the step programs' wrappers made, and the
         # constructor's one wait for the device: whatever the stages
         # above left running there (`device_wait_s`)
         t_wait = time.time()
-        jax.block_until_ready((self.params, self.cache, self._ids))
+        jax.block_until_ready(
+            (self.params, self.cache, self._ids, self._key))
         stage("place", t_stage, tensor_parallel_size=tensor_parallel_size,
               device_wait_s=time.time() - t_wait)
         # rows temperature, top_k, top_p of each slot's request
@@ -968,7 +976,7 @@ class LLMEngine:
         with phase["sample"]:
             self._ids = self._select(
                 logits, self._ids, produce, self._sampling,
-                np.uint32(self.engine_steps))
+                np.uint32(self.engine_steps), self._key)
         self.engine_steps += 1
         if snapshots:
             # behind the chunk step that brought each slot to its boundary

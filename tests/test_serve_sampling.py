@@ -164,3 +164,244 @@ def test_the_same_seed_requests_and_order_give_the_same_sampled_replies():
     first, again = serve(3), serve(3)
     assert first == again
     assert all(len(r) == 10 for r in first)
+
+
+# ------------------------------------------- the programs do not know the seed
+
+# the second is the kind of seed the benchmark's driver hands a run: more
+# than 32 signed bits hold
+SEEDS = (3, 2**31 + 17)
+PROGRAMS = ["_step", "_chunk_step", "_select", "_merge", "_reset_slot",
+            "_copy_out", "_copy_in"]
+
+
+def _engine(preset, seed, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+
+    return LLMEngine(preset=preset, max_batch=4, max_seq_len=96, seed=seed,
+                     prefill_chunk_size=16, kv_blocks=8, kv_block_size=8,
+                     **kw)
+
+
+def _arguments(eng, program):
+    """What `_dispatch_step`, `_admit` and the prefix pool pass `program`."""
+    B, C = eng.max_batch, eng.prefill_chunk_size
+    pos, lanes = np.zeros((B,), np.int32), np.ones((B,), bool)
+    tokens = np.zeros((B, C), np.int32)
+    if program == "_step":
+        return (eng.params, eng.cache, eng._ids, jnp.asarray(pos),
+                jnp.asarray(lanes))
+    if program == "_chunk_step":
+        return (eng.params, eng.cache, eng._merge(tokens, eng._ids, lanes),
+                jnp.asarray(pos), jnp.asarray(pos), jnp.asarray(lanes))
+    if program == "_select":
+        return (jnp.zeros((B, eng.cfg.vocab_size), jnp.float32), eng._ids,
+                lanes, eng._sampling, np.uint32(eng.engine_steps), eng._key)
+    if program == "_merge":
+        return tokens, eng._ids, lanes
+    if program == "_reset_slot":
+        return eng.cache, np.int32(1)
+    name = next(iter(eng.kv.pools))
+    pool, leaf = eng.kv.pools[name], eng.cache[name]
+    at = (np.int32(1), np.int32(8), np.int32(2))
+    return (pool, leaf) + at if program == "_copy_out" else (leaf, pool) + at
+
+
+def _lowered(eng, program):
+    fn = getattr(eng.kv if program.startswith("_copy") else eng, program)
+    return fn.lower(*_arguments(eng, program)).as_text()
+
+
+@pytest.fixture(scope="module", params=["gpt2-tiny", "brumby-tiny"])
+def engines_of_two_seeds(request):
+    engines = [_engine(request.param, seed) for seed in SEEDS]
+    yield engines
+    for eng in engines:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_a_program_of_the_engine_is_the_same_text_whatever_the_seed(
+        engines_of_two_seeds, program):
+    """What differs between runs is an argument of a program, never a
+    constant of it: the persistent compile cache keys on this text."""
+    first, second = (_lowered(eng, program) for eng in engines_of_two_seeds)
+    assert first == second
+    if program == "_select":
+        # the selection whole, its key the last of six arguments
+        (main,) = [line for line in first.splitlines()
+                   if "func.func public @main" in line]
+        assert "%arg5: tensor<2xui32>" in main and "%arg6" not in main
+        assert "stablehlo.sort" in first
+
+
+WARM_REPLICA = """
+import json, sys
+from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.util import tracing
+
+eng = LLMEngine(preset=sys.argv[1], max_batch=2, max_seq_len=96,
+                seed=int(sys.argv[2]), prefill_chunk_size=16,
+                kv_blocks=8, kv_block_size=8)
+prompt = [3 + i % 61 for i in range(37)]
+reply = eng.generate(prompt_ids=prompt, max_tokens=4, temperature=0.9,
+                     top_p=0.95)["token_ids"]
+eng.generate(prompt_ids=prompt, max_tokens=4)               # a pool hit
+eng.shutdown()
+print(json.dumps({"reply": reply, "compiles": [
+    [s.name, s.attributes["cache"]] for s in tracing.startup_spans()
+    if s.name.startswith("compile.")]}))
+"""
+
+
+@pytest.fixture(scope="module")
+def replicas_of_two_seeds(tmp_path_factory):
+    """`started(preset)`: what two replicas said, one after the other, each
+    started with a seed of its own on one fresh persistent cache, so that
+    the second is a warm start. The weights come from the seed too, so the
+    family's `init_*` programs are among the compiles."""
+    import functools
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    @functools.cache
+    def started(preset):
+        env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo,
+               "JAX_ENABLE_COMPILATION_CACHE": "1",
+               "JAX_COMPILATION_CACHE_DIR":
+                   str(tmp_path_factory.mktemp("jax_cache")),
+               "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+               "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
+        out = []
+        for seed in SEEDS:
+            p = subprocess.run(
+                [sys.executable, "-c", WARM_REPLICA, preset, str(seed)],
+                env=env, capture_output=True, text=True, timeout=300)
+            assert p.returncode == 0, p.stderr[-2000:]
+            out.append(json.loads(p.stdout.strip().splitlines()[-1]))
+        return out
+
+    return started
+
+
+@pytest.mark.parametrize("program", ["_step", "_chunk", "_select", "_merge",
+                                     "_copy_out", "_copy_in"])
+def test_a_warm_replica_with_another_seed_finds_the_program_in_the_cache(
+        replicas_of_two_seeds, program):
+    cold, warm = ([cache for name, cache in said["compiles"]
+                   if name == f"compile.{program}"]
+                  for said in replicas_of_two_seeds("gpt2-tiny"))
+    assert cold and set(cold) == {"miss"}
+    assert warm and set(warm) == {"hit"}
+
+
+@pytest.mark.parametrize("preset",
+                         ["gpt2-tiny", "deepseek-tiny", "brumby-tiny"])
+def test_a_warm_replica_with_another_seed_compiles_nothing(
+        replicas_of_two_seeds, preset):
+    cold, warm = replicas_of_two_seeds(preset)
+    assert [c for c in warm["compiles"] if c[1] != "hit"] == []
+    assert len(warm["compiles"]) == len(cold["compiles"])
+    assert {"compile._select", "compile._step", "compile._chunk"} <= {
+        name for name, _ in warm["compiles"]}
+    assert cold["reply"] != warm["reply"]       # and they draw differently
+
+
+# ------------------------------------------------- the bits are the parent's
+
+def _direct(seed, logits, prev, produce, sampling, step):
+    """`select_tokens` itself, on the key the engine's program must make."""
+    return np.asarray(jax.jit(select_tokens)(
+        jnp.asarray(logits), jnp.asarray(prev), jnp.asarray(produce),
+        jnp.asarray(sampling[0]), jnp.asarray(sampling[1], jnp.int32),
+        jnp.asarray(sampling[2]),
+        jax.random.fold_in(jax.random.key(seed), np.uint32(step)))).tolist()
+
+
+@pytest.mark.parametrize("seed", [0, 3, 2**31 + 17])
+def test_the_engines_ids_are_select_tokens_with_the_seeds_key_and_the_step(
+        seed):
+    """`fold_in(key(seed), step)` of a passed key is what the closed-over
+    one gave: greedy and sampling lanes mixed, idle lanes kept."""
+    eng = _engine("gpt2-tiny", seed, enable_prefix_caching=False)
+    try:
+        B, V_ = eng.max_batch, eng.cfg.vocab_size
+        logits = jnp.asarray(
+            np.random.default_rng(7).normal(0, 2, (B, V_)), jnp.float32)
+        prev = jnp.arange(100, 100 + B, dtype=jnp.int32)
+        produce = np.array([True, True, False, True])
+        sampling = np.array([[0.9, 0.0, 0.7, 1.3],        # temperature
+                             [0, 0, 5, 20],               # top_k
+                             [0.95, 1.0, 0.9, 1.0]], np.float32)
+        for step in (0, 1, 977, 2**31 + 5):
+            got = np.asarray(eng._select(logits, prev, produce, sampling,
+                                         np.uint32(step), eng._key)).tolist()
+            assert got == _direct(seed, logits, prev, produce, sampling, step)
+            assert got[1] == int(np.argmax(logits[1])) and got[2] == 102
+    finally:
+        eng.shutdown()
+
+
+def test_engines_of_two_seeds_draw_differently_and_choose_greedily_alike(
+        engines_of_two_seeds):
+    a, b = engines_of_two_seeds
+    B, V_ = a.max_batch, a.cfg.vocab_size
+    logits = jnp.asarray(
+        np.random.default_rng(11).normal(0, 1, (B, V_)), jnp.float32)
+    prev, produce = jnp.zeros((B,), jnp.int32), np.ones((B,), bool)
+    sampling = np.array([[1.0, 1.0, 1.0, 0.0], [0] * 4, [1.0] * 4],
+                        np.float32)
+    drawn = [[np.asarray(eng._select(logits, prev, produce, sampling,
+                                     np.uint32(step), eng._key)).tolist()
+              for step in range(8)] for eng in (a, b)]
+    assert [row[:3] for row in drawn[0]] != [row[:3] for row in drawn[1]]
+    assert {row[3] for rows in drawn for row in rows} == {
+        int(np.argmax(logits[3]))}
+
+
+# ------------------------------------------------------ tensor parallelism
+
+def test_a_tensor_parallel_engine_holds_its_key_on_every_chip_and_passes_it():
+    """The key is replicated over the mesh as the ids are, and a step hands
+    the selection the resident array: the host sends what it sent before
+    (which lanes produce, their settings, the step's number)."""
+    from ray_tpu.utils.platform import ensure_virtual_cpu
+
+    ensure_virtual_cpu(2)
+    seed = 11
+    eng = _engine("gpt2-tiny", seed, tensor_parallel_size=2,
+                  enable_prefix_caching=False)
+    try:
+        assert eng._key.sharding.is_fully_replicated
+        assert len(eng._key.sharding.device_set) == 2
+        assert jax.random.key_data(eng._key).tolist() == \
+            jax.random.key_data(jax.random.key(seed)).tolist()
+        calls, select = [], eng._select
+
+        def spy(*args):
+            ids = select(*args)
+            calls.append((args, ids))
+            return ids
+
+        eng._select = spy
+        reply = eng.generate(prompt_ids=list(range(3, 30)), max_tokens=5,
+                             temperature=0.9, top_p=0.95)["token_ids"]
+        assert len(reply) == 5
+        for args, ids in calls:
+            logits, prev, produce, sampling, step, key = args
+            assert key is eng._key
+            assert isinstance(logits, jax.Array) and isinstance(
+                prev, jax.Array)
+            assert [type(a) for a in (produce, sampling, step)] == [
+                np.ndarray, np.ndarray, np.uint32]
+            assert ids.sharding.is_fully_replicated
+            assert len(ids.sharding.device_set) == 2
+            assert np.asarray(ids).tolist() == _direct(
+                seed, logits, prev, produce, sampling, step)
+        assert [int(args[4]) for args, _ in calls] == list(range(len(calls)))
+    finally:
+        eng.shutdown()
