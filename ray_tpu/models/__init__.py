@@ -2,11 +2,14 @@
 moe (OLMoE / Mixtral sparse MoE: dropless sort-and-grouped-matmul routing,
 one-hot dispatch under expert parallelism), deepseek (DeepSeek-V3's layer
 for serving: latent attention over a latent cache, shared experts), brumby
-(Brumby's layer for serving: power retention over a recurrent state)."""
+(Brumby's layer for serving: power retention over a recurrent state),
+granite (Granite 4.0-H's layers for serving: Mamba-2 state a slot beside
+grouped-head keys and values a token, in one cache)."""
 
 from ray_tpu.models import gpt2
 
-__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "serving_family"]
+__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite",
+           "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
@@ -20,12 +23,14 @@ __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "serving_family"]
 # recurrent state and have no token axis (a prefix leaves the state at its
 # end behind, which the pool keeps as a snapshot; a slot is zeroed when a
 # request is placed in it, and a step leaves an inactive slot's state as it
-# was). A family names the one kind or the other; both in one cache is
-# ROADMAP R9's. A leaf neither names is the programs' own (`counts`).
+# was). A family may name both kinds (granite: the pool then keeps, under
+# one hash, a prefix's rows by the block and the state at its end, and a hit
+# needs both). A leaf neither names is the programs' own (`counts`).
 _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "kanana": ("deepseek", "DeepseekConfig"),
             "deepseek": ("deepseek", "DeepseekConfig"),
-            "brumby": ("brumby", "BrumbyConfig")}
+            "brumby": ("brumby", "BrumbyConfig"),
+            "granite": ("granite", "GraniteConfig")}
 
 
 def serving_family(preset: str):
@@ -42,7 +47,7 @@ def serving_family(preset: str):
 
 
 def __getattr__(name):
-    if name in ("llama", "moe", "deepseek", "brumby"):
+    if name in ("llama", "moe", "deepseek", "brumby", "granite"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

@@ -1,0 +1,743 @@
+"""Granite 4.0-H's layers for serving: Mamba-2 mixers over a recurrent state
+beside grouped-head attention over keys and values, in one cache.
+
+What is served is `ibm-granite/granite-4.0-h-micro` (`model_type:
+granitemoehybrid`, dense: no routed experts; preset `granite-4.0-h-micro`):
+40 layers by `layer_types`, attention at 5, 15, 25, 35 and Mamba-2
+elsewhere. With d the hidden size and eps 1e-5, no bias but the
+convolution's:
+
+    h0 = 12 E[token]                                   (embedding_multiplier)
+    h += 0.22 mixer(RMSNorm(h));  h += 0.22 mlp(RMSNorm(h))      (residual_)
+    mlp(u) = W_out (silu(a) * b),  [a, b] = W_in u     (shared_intermediate)
+    logits = RMSNorm(h) E^T / 8                  (tied table; logits_scaling)
+
+    attention: q = u W_q -> [32, 64], k, v = u W_k, u W_v -> [8, 64]; no
+      positions; scores q . k * 0.015625 (attention_multiplier, not 1/8);
+      causal softmax; query head j reads key-value head j // 4; W_o
+
+    Mamba-2 (64 heads of 64 lanes, one group, N = 128):
+      [z, xBC, dt] = W_in u           (4096 + 4352 + 64; held as w_zx, w_dt)
+      xBC = silu(conv1d_causal(xBC; w [4, 4352], b)), the last 4 positions
+      x [64, 64], B [128], C [128] = split(xBC)
+      dt = softplus(dt + dt_bias);  A = -exp(A_log), a head
+      S_t = exp(dt A) S_{t-1} + dt x_t B_t^T;   y_t = S_t C_t + D x_t
+      y = RMSNorm_4096(y * silu(z)) * g;  W_out
+
+The cache holds both kinds of leaf (`models/__init__.py`): `k` and `v`
+[attention layers, slots, 8, 64, T] hold a value a token (`CACHE_TOKEN_AXIS`)
+and `ssm` [Mamba layers, slots, N, H P] and `conv` [Mamba layers, slots, 3 x
+4352] a slot's state, float32, with no token axis (`CACHE_STATE`): the SSM
+state in `ops/ssm_update.py`'s layout and the last three inputs of the
+convolution. A prefix leaves its rows and the state at its end behind;
+`serve/kv_cache.py` pools both under one hash.
+
+The mixer exists in two forms and no third. The recurrence, one token a
+slot through the kernel `ssm_update`, is `decode_step` whole and, in
+`prefill_chunk`, every slot's first lane (a decode lane riding along is that
+and nothing else). A chunk's further lanes, M of them after position s, go
+through the SSD form, with a_i = sum_{s<m<=i} dt_m A:
+
+    y_i = e^{a_i} S_s C_i + sum_{s<j<=i} e^{a_i - a_j} (C_i . B_j) dt_j x_j
+    S_{s+M} = e^{a_{s+M}} S_s + sum_j e^{a_{s+M} - a_j} dt_j x_j B_j^T
+
+a slot at a time and only for the slots that prefill, as attention's scores
+for a whole chunk are ([8, 4 M, T] floats a slot; for every lane of every
+slot they would be [slots, 32, C, T]). A lane past a slot's length has
+dt = 0: it decays nothing and adds nothing; a slot with no valid lane keeps
+its rows, its state and its window bit for bit, in both programs.
+
+The weights exist only in the dtype the replica holds them, a layer at a
+time, as `models/brumby.py` makes its own: one stack a kind of layer. The
+convolution, `dt_bias`, `A_log`, `D`, W_in's dt columns and the norms'
+scales are float32, and so are the residual stream, everything projected
+(z, xBC, dt, the MLP's hidden lanes, attention's scores), the convolution
+and its window, dt, the decay, the state, its update and read-out, and the
+logits. A product's operands are bf16, the weight as it is held and the
+activation as the two bf16 pieces that add up to it (`_dot`); keys, values
+and attention's weights go as one piece.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.rows_write import TILE as _WRITE_WINDOW, rows_write
+from ray_tpu.ops.ssm_update import ssm_update
+
+Params = Any
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _published_layer_types() -> tuple:
+    return tuple("attention" if l % 10 == 5 else "mamba" for l in range(40))
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteConfig:
+    vocab_size: int = 100352
+    n_layer: int = 40
+    layer_types: tuple = _published_layer_types()
+    d_model: int = 2048
+    d_ff: int = 8192                 # shared_intermediate_size
+    n_head: int = 32
+    n_kv_head: int = 8
+    ssm_heads: int = 64              # mamba_n_heads
+    ssm_head_dim: int = 64           # mamba_d_head
+    ssm_state: int = 128             # mamba_d_state
+    ssm_groups: int = 1              # mamba_n_groups
+    ssm_conv: int = 4                # mamba_d_conv
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+    norm_eps: float = 1e-5
+    max_seq_len: int = 131072
+    dtype: Any = jnp.bfloat16        # compute
+    param_dtype: Any = jnp.bfloat16  # what the replica holds
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        assert len(self.layer_types) == self.n_layer, self.layer_types
+        assert set(self.layer_types) <= {"mamba", "attention"}
+        assert self.ssm_groups == 1, "one group of B and C is what is built"
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_head
+
+    @property
+    def queries_per_kv(self) -> int:
+        return self.n_head // self.n_kv_head
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """What goes through the convolution: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def layers_of(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    @classmethod
+    def preset(cls, name: str, **overrides) -> "GraniteConfig":
+        return cls(**{**PRESETS[name], **overrides})
+
+
+PRESETS = {
+    # ibm-granite/granite-4.0-h-micro config.json: the defaults
+    "granite-4.0-h-micro": dict(),
+    "granite-tiny": dict(
+        vocab_size=512, n_layer=6,
+        layer_types=("mamba", "mamba", "attention") * 2, d_model=64,
+        d_ff=128, n_head=4, n_kv_head=2, ssm_heads=4, ssm_head_dim=32,
+        ssm_state=16, max_seq_len=128),
+}
+
+# the serving contract (`models/__init__.py`): keys and values hold a value a
+# token, along axis 4; the SSM state and the convolution's window hold a
+# slot's state, [layers of the kind, slots, ...] with no token axis
+CACHE_TOKEN_AXIS = {"k": 4, "v": 4}
+CACHE_STATE = ("ssm", "conv")
+
+
+# ---------------------------------------------------------------------------
+# Weights, a layer at a time
+# ---------------------------------------------------------------------------
+
+# The seeded weights' spreads. Every matrix N(0, 0.02) and the MLP's down
+# projection 0.02 / sqrt(2 n_layer), as a fresh Hugging Face model. The
+# token table N(0, 0.005): the table is the head too, so a token's own row
+# stands out among the logits by 12 sqrt(d) std / rms(h) of their spread
+# (the embedding multiplier times the row's length over the stream's size
+# at the last layer, ~1.8): at 0.05 that is 15 spreads and every greedy
+# reply repeats its last token for ever, at 0.005 it is 1.5 and the largest
+# logit is some other token's. The first layer reads the table through its
+# norm, whatever its size. The Mamba-2 layer's own as its reference
+# implementation initialises them: A = U(1, 16), dt = exp(U(log 0.001,
+# log 0.1)) through the inverse of softplus into `dt_bias`, D = 1, the
+# convolution U(-1/2, 1/2) (a depthwise window of 4). With the projection's
+# part added dt A lies about 0.0005 to 3: a memory of one to two thousand
+# tokens, so that a fault in carrying state across chunks, snapshots and
+# slots cannot hide.
+EMBED_STD = 0.005
+A_RANGE = (1.0, 16.0)
+DT_RANGE = (0.001, 0.1)
+
+
+def _normal(key, shape, std, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _ones(n):
+    return {"scale": jnp.ones((n,), jnp.float32)}
+
+
+def _mlp_params(key, cfg: GraniteConfig) -> Params:
+    k_in, k_out = jax.random.split(key)
+    pd, D, F = cfg.param_dtype, cfg.d_model, cfg.d_ff
+    return {"w_in": _normal(k_in, (D, 2 * F), 0.02, pd),
+            "w_out": _normal(k_out, (F, D),
+                             0.02 / math.sqrt(2 * cfg.n_layer), pd)}
+
+
+def _init_layer(key: jax.Array, l, cfg: GraniteConfig, kind: str) -> Params:
+    ks = jax.random.split(jax.random.fold_in(key, l), 8)
+    pd, D = cfg.param_dtype, cfg.d_model
+    out = {"mixer_norm": _ones(D), "mlp_norm": _ones(D),
+           "mlp": _mlp_params(ks[0], cfg)}
+    if kind == "attention":
+        H, G, d = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+        out["attn"] = {"wq": _normal(ks[1], (D, H * d), 0.02, pd),
+                       "wk": _normal(ks[2], (D, G * d), 0.02, pd),
+                       "wv": _normal(ks[3], (D, G * d), 0.02, pd),
+                       "wo": _normal(ks[4], (H * d, D), 0.02, pd)}
+        return out
+    I, F, Hm, K = cfg.ssm_inner, cfg.conv_width, cfg.ssm_heads, cfg.ssm_conv
+    dt = jnp.exp(jax.random.uniform(ks[3], (Hm,), jnp.float32,
+                                    math.log(DT_RANGE[0]),
+                                    math.log(DT_RANGE[1])))
+    edge = 1.0 / math.sqrt(K)
+    out["ssm"] = {
+        # W_in's columns for z and xBC (8,448, a whole number of lane
+        # tiles), and its 64 for dt apart, float32: beside them the minor
+        # axis would be 8,512, and the TPU's compiler copies the whole stack
+        # into another layout on every step (1.26 GB; PERF.md, PR 38)
+        "w_zx": _normal(ks[1], (D, I + F), 0.02, pd),
+        "w_dt": _normal(ks[7], (D, Hm), 0.02, jnp.float32),
+        "w_out": _normal(ks[2], (I, D), 0.02, pd),
+        # softplus(dt_bias) = dt
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jax.random.uniform(ks[4], (Hm,), jnp.float32,
+                                            *A_RANGE)),
+        "d": jnp.ones((Hm,), jnp.float32),
+        # tap k of the window multiplies the input 3 - k positions back
+        "conv_w": jax.random.uniform(ks[5], (K, F), jnp.float32, -edge, edge),
+        "conv_b": jax.random.uniform(ks[6], (F,), jnp.float32, -edge, edge),
+        "norm": _ones(I)}
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_program(cfg: GraniteConfig, kind: str):
+    return jax.jit(lambda key, l: _init_layer(key, l, cfg, kind))
+
+
+def init_layer(key: jax.Array, l: int, cfg: GraniteConfig) -> Params:
+    """Layer l's weights from `fold_in(key, l)` and nothing else, of the
+    kind `cfg.layer_types[l]` names, by the one compiled program a kind
+    that makes them wherever they are made: a layer made alone is, to the
+    bit, the layer in `init_params`' tree."""
+    return _layer_program(cfg, cfg.layer_types[l])(key, jnp.int32(l))
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def init_ends(key: jax.Array, cfg: GraniteConfig) -> Params:
+    """What is not a layer: the table (tied: it is the head too) and the
+    final norm, from `fold_in(key, cfg.n_layer)`."""
+    k_emb = jax.random.fold_in(key, cfg.n_layer)
+    return {"wte": _normal(k_emb, (cfg.vocab_size, cfg.d_model), EMBED_STD,
+                           cfg.param_dtype),
+            "final_norm": _ones(cfg.d_model)}
+
+
+def init_params(key: jax.Array, cfg: GraniteConfig) -> Params:
+    """The whole tree, every leaf made in the dtype it is held in: `mamba`
+    and `attention`, one stack a kind of layer on a leading axis, in the
+    order the layers have. A stack is allocated once and each layer's
+    program writes its layer into it (donated), so the most that exists
+    beside the tree is one layer."""
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def put(stack, layer, i):
+        return jax.tree.map(
+            lambda s, a: lax.dynamic_update_index_in_dim(s, a, i, 0),
+            stack, layer)
+
+    out = dict(init_ends(key, cfg))
+    for kind in ("mamba", "attention"):
+        layers = [l for l, t in enumerate(cfg.layer_types) if t == kind]
+        if not layers:
+            continue
+        shapes = jax.eval_shape(lambda: init_layer(key, layers[0], cfg))
+        stack = jax.jit(lambda: jax.tree.map(
+            lambda s: jnp.zeros((len(layers),) + s.shape, s.dtype), shapes))()
+        for i, l in enumerate(layers):
+            stack = put(stack, init_layer(key, l, cfg), jnp.int32(i))
+        out[kind] = stack
+    return out
+
+
+def resident_params(params: Params, cfg: GraniteConfig) -> Params:
+    """`init_params` makes the tree a replica holds: nothing to convert."""
+    del cfg
+    return params
+
+
+def resident_specs(cfg: GraniteConfig, rules=None) -> Params:
+    raise NotImplementedError(
+        "the granite family is served on one chip: its weights, its rows "
+        "and its state have no partition specs yet (tensor_parallel_size > "
+        "1 is GPT-2's)")
+
+
+def num_params(cfg: GraniteConfig) -> int:
+    D, F = cfg.d_model, cfg.d_ff
+    mlp = 3 * D * F + 2 * D
+    attention = 2 * D * cfg.n_head * cfg.head_dim \
+        + 2 * D * cfg.n_kv_head * cfg.head_dim
+    I, Hm = cfg.ssm_inner, cfg.ssm_heads
+    mamba = (D * (I + cfg.conv_width + Hm) + I * D + 3 * Hm
+             + (cfg.ssm_conv + 1) * cfg.conv_width + I)
+    return (cfg.layers_of("mamba") * (mamba + mlp)
+            + cfg.layers_of("attention") * (attention + mlp)
+            + cfg.vocab_size * D + D)
+
+
+# ---------------------------------------------------------------------------
+# The cache
+# ---------------------------------------------------------------------------
+
+def init_cache(cfg: GraniteConfig, batch: int, max_len: Optional[int] = None):
+    """{"k", "v" [attention layers, B, G, 64, T]} in the compute dtype, by
+    the 8 key-value heads, not broadcast, the positions on the lanes
+    (`ops/rows_write.py`); {"ssm" [Mamba layers, B, N, H P],
+    "conv" [Mamba layers, B, 3 x 4352]} float32 (the three inputs side by
+    side on the lanes: as [.., 3, 4352] the compiler re-lays the leaf
+    round the layers' loop), zero, which is what a sequence starts from.
+    `max_len` sizes the rows alone: a slot's state is as large after one
+    token as after a million."""
+    T = max_len or cfg.max_seq_len
+    rows = (cfg.layers_of("attention"), batch, cfg.n_kv_head, cfg.head_dim,
+            T)
+    Lm = cfg.layers_of("mamba")
+    return {"k": jnp.zeros(rows, cfg.dtype), "v": jnp.zeros(rows, cfg.dtype),
+            "ssm": jnp.zeros((Lm, batch, cfg.ssm_state, cfg.ssm_inner),
+                             jnp.float32),
+            "conv": jnp.zeros(
+                (Lm, batch, (cfg.ssm_conv - 1) * cfg.conv_width),
+                jnp.float32)}
+
+
+# ---------------------------------------------------------------------------
+# The layers
+# ---------------------------------------------------------------------------
+#
+# Both programs take a slot's first lane through `_mamba_first` and
+# `_attention_first`, all slots at once: that is the whole decode program,
+# and in the chunk program it is every decode lane riding along and the
+# first token of every chunk. A slot whose chunk has further lanes takes
+# them through `_mamba_further` and `_attention_further`, a slot at a time
+# and only such slots (`_further_lanes`): the SSD form and a chunk's scores
+# are computed for the lanes that prefill, never for the padding of the
+# other slots' lanes, which at 48 slots of 64 lanes is 98% of them and cost
+# 190 of a chunk step's 235 ms when every slot went through the SSD form
+# (PERF.md, PR 38).
+
+def _w(p, cfg: GraniteConfig):
+    with jax.named_scope("weights_cast"):
+        return p.astype(cfg.dtype)
+
+
+def _dot(x, w, cfg: GraniteConfig):
+    """x [..., K] float32 times the weight w [K, N] -> [..., N] float32. The
+    operands are the compute dtype's, and x goes as the two pieces that add
+    up to it (its rounding and what the rounding left), side by side on the
+    rows of one product: one pass of the weight, which is what a decode
+    step's product costs, and none of the activations' rounding in the
+    result. With that rounding in every product of 80 sublayers the logits
+    lay 1.1% of their spread from the reference's, as far as a state held
+    in bfloat16 puts them (PERF.md, PR 38)."""
+    if cfg.dtype == jnp.float32:
+        return jnp.dot(x, w.astype(jnp.float32), precision=_HIGHEST)
+    x = x.astype(jnp.float32)
+    # `reduce_precision`, not a pair of conversions, which are the
+    # compiler's to remove (PERF.md, PR 29): the low piece would be zero
+    bits = jnp.finfo(cfg.dtype)
+    high = lax.reduce_precision(x, exponent_bits=bits.nexp,
+                                mantissa_bits=bits.nmant)
+    both = jnp.dot(jnp.stack([high, x - high]).astype(cfg.dtype), _w(w, cfg),
+                   preferred_element_type=jnp.float32)
+    return both[0] + both[1]
+
+
+def _over_lanes(per_head, cfg: GraniteConfig):
+    """[..., H] -> [..., H P]: a head's value over its P lanes."""
+    return jnp.repeat(per_head, cfg.ssm_head_dim, axis=-1)
+
+
+def _ssm_in(u32, p, cfg: GraniteConfig):
+    """The norm's output u32 [B,M,D] float32 -> z [B,M,I], xBC [B,M,F]
+    before the convolution, dt [B,M,H] after softplus, all float32."""
+    I = cfg.ssm_inner
+    with jax.named_scope("ssm_project"):
+        proj = _dot(u32, p["w_zx"], cfg)
+        dt = jnp.dot(u32, p["w_dt"], precision=_HIGHEST)
+        return proj[..., :I], proj[..., I:], \
+            jax.nn.softplus(dt + p["dt_bias"])
+
+
+def _ssm_out(y, z, p, cfg: GraniteConfig):
+    """The gate before the norm, one group of I lanes, then W_out."""
+    with jax.named_scope("ssm_project"):
+        y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+        return _dot(y, p["w_out"], cfg)
+
+
+def _conv(xbc, p, window, ok, cfg: GraniteConfig):
+    """The causal depthwise convolution of xbc [B,M,F] behind `window`
+    [B, (K-1) F], the K - 1 inputs before it, and the window left behind:
+    the K - 1 inputs that end at each slot's last valid lane (a slot with no
+    valid lane keeps its window bit for bit)."""
+    K = cfg.ssm_conv
+    B, M, F = xbc.shape
+    with jax.named_scope("ssm_conv"):
+        if M == 1:          # one lane: the window moves on by one input
+            ext = jnp.concatenate([window, xbc[:, 0]], axis=-1)   # [B, K F]
+            out = p["conv_b"] + sum(p["conv_w"][k] * ext[:, k * F:(k + 1) * F]
+                                    for k in range(K))
+            return (jax.nn.silu(out)[:, None],
+                    jnp.where(ok, ext[:, F:], window))
+        ext = jnp.concatenate([window.reshape(B, K - 1, F), xbc], axis=1)
+        out = p["conv_b"] + sum(p["conv_w"][k] * ext[:, k:k + M]
+                                for k in range(K))
+        at = ok.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]   # [B,K-1]
+        new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+        new = jnp.where(ok.any(axis=1)[:, None],
+                        new.reshape(B, (K - 1) * F), window)
+        return jax.nn.silu(out), new
+
+
+def _split_xbc(xbc, cfg: GraniteConfig):
+    I, N = cfg.ssm_inner, cfg.ssm_state
+    return xbc[..., :I], xbc[..., I:I + N], xbc[..., I + N:]
+
+
+def _ssd(x, b, c, dt, p, s, ok, cfg: GraniteConfig):
+    """The SSD form for M lanes a slot: x [B,M,I], b, c [B,M,N], dt [B,M,H],
+    the state s [B,N,I] before them, ok [B,M] -> (y [B,M,I], the state
+    after them)."""
+    B, M = ok.shape
+    H, P = cfg.ssm_heads, cfg.ssm_head_dim
+    with jax.named_scope("ssm_chunk"):
+        dt = jnp.where(ok[:, :, None], dt, 0.0)
+        a = jnp.cumsum(-dt * jnp.exp(p["a_log"]), axis=1)        # [B, M, H]
+        dtx = (_over_lanes(dt, cfg) * x).reshape(B, M, H, P)
+        # what the state held: e^{a_i} S_s C_i
+        y = _over_lanes(jnp.exp(a), cfg) * jnp.einsum(
+            "bin,bnf->bif", c, s, precision=_HIGHEST)
+        # within the chunk: (C_i . B_j) e^{a_i - a_j} dt_j x_j, j <= i
+        lane = jnp.arange(M)
+        seen = (lane[None, :] <= lane[:, None])[None, :, :, None]  # [1,i,j,1]
+        within = jnp.where(seen, jnp.exp(jnp.where(
+            seen, a[:, :, None, :] - a[:, None, :, :], 0.0)), 0.0)  # [B,i,j,H]
+        weight = within * jnp.einsum("bin,bjn->bij", c, b,
+                                     precision=_HIGHEST)[..., None]
+        y = y + jnp.einsum("bijh,bjhp->bihp", weight, dtx,
+                           precision=_HIGHEST).reshape(B, M, H * P)
+        y = y + _over_lanes(p["d"], cfg) * x
+        # the state at the chunk's end
+        total = a[:, -1]                                           # [B, H]
+        out_of = jnp.exp(total[:, None, :] - a)[..., None] * dtx   # [B,M,H,P]
+        s_new = _over_lanes(jnp.exp(total), cfg)[:, None, :] * s + jnp.einsum(
+            "bjn,bjf->bnf", b, out_of.reshape(B, M, H * P),
+            precision=_HIGHEST)
+        return y, jnp.where(ok.any(axis=1)[:, None, None], s_new, s)
+
+
+def _mamba_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
+    """One Mamba-2 mixer over every slot's first lane, x [B,1,D] float32,
+    by the recurrence: -> (x, cache). `on` [B]: the slots whose lane is
+    valid; the others keep their state and window bit for bit."""
+    del pos
+    p = bp["ssm"]
+    with jax.named_scope("attn"):
+        z, xbc, dt = _ssm_in(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
+                             p, cfg)
+        with jax.named_scope("ssm_conv"):
+            window = lax.dynamic_index_in_dim(cache["conv"], l, 0,
+                                              keepdims=False)
+        xbc, window = _conv(xbc, p, window, on[:, None], cfg)
+        with jax.named_scope("ssm_conv"):
+            conv = lax.dynamic_update_index_in_dim(cache["conv"], window,
+                                                   l, 0)
+        xs, b, c = _split_xbc(xbc[:, 0], cfg)
+        with jax.named_scope("ssm_update"):
+            dt = _over_lanes(dt[:, 0], cfg)                        # [B, I]
+            decay = jnp.exp(-dt * _over_lanes(jnp.exp(p["a_log"]), cfg))
+            ssm, y = ssm_update(cache["ssm"], l, decay, dt * xs, b, c, on)
+            y = y + _over_lanes(p["d"], cfg) * xs
+        o = _ssm_out(y[:, None], z, p, cfg)
+    return (x + cfg.residual_multiplier * o,
+            {**cache, "ssm": ssm, "conv": conv})
+
+
+def _mamba_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
+    """The same mixer over one slot's further lanes, x [1,M,D], by the SSD
+    form from the state its first lane left: -> (x, cache)."""
+    del pos
+    p = bp["ssm"]
+    N, I = cfg.ssm_state, cfg.ssm_inner
+    W = cache["conv"].shape[-1]
+    with jax.named_scope("attn"):
+        z, xbc, dt = _ssm_in(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
+                             p, cfg)
+        with jax.named_scope("ssm_conv"):
+            window = lax.dynamic_slice(cache["conv"], (l, slot, 0),
+                                       (1, 1, W))[0]
+        xbc, window = _conv(xbc, p, window, ok, cfg)
+        with jax.named_scope("ssm_conv"):
+            conv = lax.dynamic_update_slice(cache["conv"], window[None],
+                                            (l, slot, 0))
+        xs, b, c = _split_xbc(xbc, cfg)
+        with jax.named_scope("ssm_chunk"):
+            s = lax.dynamic_slice(cache["ssm"], (l, slot, 0, 0),
+                                  (1, 1, N, I))[0]
+        y, s = _ssd(xs, b, c, dt, p, s, ok, cfg)
+        with jax.named_scope("ssm_chunk"):
+            ssm = lax.dynamic_update_slice(cache["ssm"], s[None],
+                                           (l, slot, 0, 0))
+        o = _ssm_out(y, z, p, cfg)
+    return (x + cfg.residual_multiplier * o,
+            {**cache, "ssm": ssm, "conv": conv})
+
+
+def _qkv(x, bp, cfg: GraniteConfig):
+    """x [B,M,D] -> q [B,M,G,R,d], k, v [B,M,G,d] in the compute dtype."""
+    B, M, _ = x.shape
+    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
+    p = bp["attn"]
+    with jax.named_scope("gqa_project"):
+        u = rms_norm(x, bp["mixer_norm"], cfg.norm_eps)
+        return tuple(
+            _dot(u, p[name], cfg).astype(cfg.dtype).reshape(B, M, *shape)
+            for name, shape in (("wq", (G, R, d)), ("wk", (G, d)),
+                                ("wv", (G, d))))
+
+
+def _attn_out(x, y, bp, cfg: GraniteConfig):
+    B, M, _ = x.shape
+    with jax.named_scope("gqa_project"):
+        o = _dot(y.reshape(B, M, -1), bp["attn"]["wo"], cfg)
+    return x + cfg.residual_multiplier * o
+
+
+def _attend(q, k, v, at, cfg: GraniteConfig):
+    """q [..., Q, d] at positions `at` [..., Q] over the cached rows k, v
+    [..., d, T] of its key-value head -> [..., Q, d] float32: scores times
+    the model's multiplier, causal softmax, weighted values."""
+    T = k.shape[-1]
+    scores = jnp.einsum("...qd,...dt->...qt", q, k,
+                        preferred_element_type=jnp.float32)
+    seen = jnp.arange(T) <= at[..., None]
+    probs = jax.nn.softmax(jnp.where(
+        seen, scores * cfg.attention_multiplier, -1e30), axis=-1)
+    return jnp.einsum("...qt,...dt->...qd", probs.astype(cfg.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _attention_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
+    """One grouped-head attention mixer over every slot's first lane, x
+    [B,1,D], at position pos [B]: -> (x, cache)."""
+    B = x.shape[0]
+    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(x, bp, cfg)
+        with jax.named_scope("kv_update"):
+            ck = rows_write(cache["k"], l, k[:, 0], pos, on)
+            cv = rows_write(cache["v"], l, v[:, 0], pos, on)
+        with jax.named_scope("gqa_attend"):
+            y = _attend(q[:, 0], ck[l], cv[l],
+                        jnp.broadcast_to(pos[:, None, None], (B, G, R)), cfg)
+        x = _attn_out(x, y, bp, cfg)
+    return x, {**cache, "k": ck, "v": cv}
+
+
+def _write_slot(c, l, slot, val, pos, ok):
+    """Layer l of the carried leaf c [L,B,G,d,T] takes val [M,G,d] at
+    positions pos.. of slot `slot` where ok [M]: one window of W >= M
+    positions read, blended and written in place (`dynamic_update_slice`
+    clamps its start near the end of the sequence, so an unmasked block
+    write would smear garbage lanes over valid earlier positions). The lanes
+    are moved by a 0/1 matrix: exact, one product of 1 a lane."""
+    _, _, G, d, T = c.shape
+    M = val.shape[0]
+    W = min(T, max(M, _WRITE_WINDOW))
+    start = jnp.clip(pos, 0, T - W)
+    hit = ((jnp.arange(W)[:, None] - (pos - start)) == jnp.arange(M)) & ok
+    moved = jnp.einsum("wm,mgd->gdw", hit.astype(val.dtype), val,
+                       precision=_HIGHEST)
+    at = (l, slot, 0, 0, start)
+    old = lax.dynamic_slice(c, at, (1, 1, G, d, W))
+    new = jnp.where(hit.any(axis=-1), moved, old[0, 0])
+    return lax.dynamic_update_slice(c, new[None, None], at)
+
+
+def _attention_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
+    """The same mixer over one slot's further lanes, x [1,M,D], the first of
+    them at position pos: its scores are [G, R M, T] floats."""
+    M = x.shape[1]
+    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
+    T = cache["k"].shape[4]
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(x, bp, cfg)
+        with jax.named_scope("kv_update"):
+            ck = _write_slot(cache["k"], l, slot, k[0], pos, ok[0])
+            cv = _write_slot(cache["v"], l, slot, v[0], pos, ok[0])
+        with jax.named_scope("gqa_attend"):
+            rows = [lax.dynamic_slice(leaf, (l, slot, 0, 0, 0),
+                                      (1, 1, G, d, T))[0, 0]
+                    for leaf in (ck, cv)]
+            # [M,G,R,d] -> [G, R M, d]: a head's queries side by side
+            qs = jnp.transpose(q[0], (1, 2, 0, 3)).reshape(G, R * M, d)
+            at = jnp.broadcast_to(pos + jnp.tile(jnp.arange(M), R),
+                                  (G, R * M))
+            y = _attend(qs, *rows, at, cfg)
+            y = jnp.transpose(y.reshape(G, R, M, d), (2, 0, 1, 3))[None]
+        x = _attn_out(x, y, bp, cfg)
+    return x, {**cache, "k": ck, "v": cv}
+
+
+def _mlp(x, bp, cfg: GraniteConfig):
+    with jax.named_scope("mlp"):
+        ab = _dot(rms_norm(x, bp["mlp_norm"], cfg.norm_eps),
+                  bp["mlp"]["w_in"], cfg)
+        a, b = ab[..., :cfg.d_ff], ab[..., cfg.d_ff:]
+        o = _dot(jax.nn.silu(a) * b, bp["mlp"]["w_out"], cfg)
+        return x + cfg.residual_multiplier * o
+
+
+_FIRST = {"mamba": _mamba_first, "attention": _attention_first}
+_FURTHER = {"mamba": _mamba_further, "attention": _attention_further}
+
+
+def _layer_weights(stack: Params, l) -> Params:
+    """Layer l of a kind's stack: its weights sliced where they lie."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stack)
+
+
+def _further_lanes(kind: str, x, stack, cfg: GraniteConfig, cache, l, pos,
+                   ok):
+    """Layer l of `kind`'s stack over the lanes after the first, x [B,M,D]
+    with ok [B,M], the first of them at pos [B]: a slot at a time, and only
+    the slots that have such lanes. The weights are sliced inside the
+    branch: sliced once for both paths, the compiler copies every matrix
+    out of the stack (6.4 GB a chunk step)."""
+    B, M, D = x.shape
+
+    def slot(b, x, cache):
+        bp = _layer_weights(stack, l)
+        xb = lax.dynamic_slice(x, (b, 0, 0), (1, M, D))
+        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
+        at = lax.dynamic_index_in_dim(pos, b, 0, keepdims=False)
+        xb, cache = _FURTHER[kind](xb, bp, cfg, cache, l, b, at, okb)
+        return lax.dynamic_update_slice(x, _mlp(xb, bp, cfg), (b, 0, 0)), \
+            cache
+
+    def body(b, carry):
+        more = lax.dynamic_index_in_dim(ok, b, 0, keepdims=False).any()
+        return lax.cond(more, slot, lambda b, *same: same, b, *carry)
+
+    return lax.fori_loop(0, B, body, (x, cache))
+
+
+def _logits(params: Params, x, cfg: GraniteConfig):
+    with jax.named_scope("unembed_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return _dot(x, params["wte"].T, cfg) / cfg.logits_scaling
+
+
+def _period(cfg: GraniteConfig):
+    """(how many times the layer pattern repeats, one period as runs of a
+    kind: [(kind, layers)]): the loop over the layers is a loop over the
+    periods, whose body holds a loop a run."""
+    types = cfg.layer_types
+    size = next(n for n in range(1, len(types) + 1)
+                if len(types) % n == 0
+                and types == types[:n] * (len(types) // n))
+    runs: list = []
+    for kind in types[:size]:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return len(types) // size, [(kind, n) for kind, n in runs]
+
+
+def _forward(params: Params, cache, tokens, pos0, length, active,
+             cfg: GraniteConfig):
+    B, C = tokens.shape
+    on = active & (length > 0)
+    further = (jnp.arange(1, C)[None, :] < length[:, None]) & on[:, None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(jnp.float32) \
+            * cfg.embedding_multiplier                           # [B, C, D]
+    periods, runs = _period(cfg)
+    per_period = {kind: sum(n for k, n in runs if k == kind)
+                  for kind in _FIRST}
+
+    def layer(kind: str, l, first, rest, cache):
+        bp = _layer_weights(params[kind], l)
+        first, cache = _FIRST[kind](first, bp, cfg, cache, l, pos0, on)
+        first = _mlp(first, bp, cfg)
+        if C > 1:
+            rest, cache = _further_lanes(kind, rest, params[kind], cfg, cache,
+                                         l, pos0 + 1, further)
+        return first, rest, cache
+
+    def period(carry, n):
+        done = {kind: n * per_period[kind] for kind in _FIRST}
+        for kind, count in runs:
+            start = done[kind]
+            if count == 1:
+                carry = layer(kind, start, *carry)
+            else:
+                carry = lax.fori_loop(
+                    0, count, lambda j, c, kind=kind, start=start:
+                    layer(kind, start + j, *c), carry)
+            done[kind] = start + count
+        return carry, None
+
+    # the cache is a carry: one buffer a leaf from layer to layer, written
+    # in place where the caller donates it
+    with jax.named_scope("layers"):
+        (first, rest, cache), _ = lax.scan(
+            period, (x[:, :1], x[:, 1:], dict(cache)), jnp.arange(periods))
+    x = jnp.concatenate([first, rest], axis=1)
+    last = jnp.clip(length - 1, 0, C - 1)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return _logits(params, x_last, cfg), cache
+
+
+def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
+                  length: jax.Array, active: jax.Array, cfg: GraniteConfig):
+    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
+    slot), pos0 [B] (the position of the chunk's first token: the rows are
+    written there; the state does not read it), length [B] (valid tokens,
+    0..C), active [B] -> (logits [B, vocab] float32 at each slot's last
+    valid lane, the cache). Inactive and zero-length slots leave their
+    rows, their state and their window as they were, bit for bit, and their
+    logits are garbage. The state continues whatever the slot held: a new
+    sequence's slot is the caller's to zero. Donate `cache`."""
+    return _forward(params, cache, tokens, pos0, length, active, cfg)
+
+
+def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
+                active: jax.Array, cfg: GraniteConfig):
+    """`gpt2.decode_step`'s contract: tokens [B], pos [B], active [B] ->
+    (logits [B, vocab] float32, the cache): the recurrence through the
+    state-update kernel and attention over the cached rows, one token a
+    slot; the chunk program's first lane, and nothing else of it."""
+    return _forward(params, cache, tokens[:, None], pos,
+                    active.astype(jnp.int32), active, cfg)

@@ -1,0 +1,145 @@
+"""The one-token update-and-read-out of a Mamba-2 layer's SSM state, a Pallas
+kernel on the TPU.
+
+A head keeps `S [P, N]` in float32 (P the head's lanes, N the state size).
+One token does, for every head h,
+
+    S_h <- exp(dt_h A_h) S_h + dt_h x_h B^T        y_h = S_h C
+
+(one group: B and C [N] are shared by the heads), which reads and writes
+the whole state once and is bound by that.
+
+The layout. A slot's state of one layer is held as `[N, H P]`: the state's
+index on the sublanes, every head's lanes side by side on the lanes (4,096
+for 64 heads of 64, a whole number of lane tiles; `[H, P, N]`, the
+recurrence's own order, would put N = 128 on the lanes and make the
+read-out `S C` a reduction along them, one shuffle tree a tile). So the
+decay `exp(dt A)` and the input `dt x` are rows (a value a lane, spread
+over a head's P lanes by the caller), B and C are columns, the rank-one
+update is a column times a row, and the read-out a sum over sublanes: a
+multiply-add a tile on the VPU, kept as eight sublane-partials until a
+stretch of lanes has gone by.
+
+`ssm_update` takes the whole leaf [layers, slots, N, H P] and the layer to
+work on; the kernel aliases the state to its output: under a jit that
+donates the cache nothing of the state's size is held beside it (Brumby's
+lesson, PERF.md PR 33). A slot that is not active is copied through, bit
+for bit.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# a grid step takes one slot's state of the layer whole, 2 MB in one
+# stretch of HBM at the published sizes: its four buffers are 8 MiB of VMEM
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+STRIP = 8                    # sublanes of a float32 tile
+LANE_TILE = 1024             # lanes a pass of the strips' loop covers
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _update_plain(state, layer, decay, dtx, b, c, active):
+    """The same arithmetic in plain XLA (the CPU backend's path, and what
+    the kernel is tested against)."""
+    s = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)  # [B,N,F]
+    s_new = decay[:, None, :] * s + b[:, :, None] * dtx[:, None, :]
+    s_new = jnp.where(active.astype(bool)[:, None, None], s_new, s)
+    y = jnp.sum(s_new * c[:, :, None], axis=1)
+    return lax.dynamic_update_index_in_dim(state, s_new, layer, 0), y
+
+
+def _kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref, cols_ref,
+            so_ref, y_ref, *, n_state: int, lanes: int, tile: int):
+    """One slot's state of one layer: [N, lanes], one stretch of HBM."""
+    del layer_ref
+    slot = pl.program_id(0)
+
+    @pl.when(active_ref[slot] == 0)
+    def _():
+        so_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(active_ref[slot] != 0)
+    def _():
+        for j in range(lanes // tile):
+            at_lanes = slice(j * tile, (j + 1) * tile)
+            decay = decay_ref[0, :, at_lanes]                   # [1, tile]
+            dtx = dtx_ref[0, :, at_lanes]
+
+            # a strip of eight of the state's rows at a time: the update and
+            # the read-out's partial sums, in and out of VMEM once
+            def strip(i, acc, at_lanes=at_lanes, decay=decay, dtx=dtx):
+                at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
+                b_col = jnp.broadcast_to(cols_ref[0, at, 0:1], (STRIP, tile))
+                c_col = jnp.broadcast_to(cols_ref[0, at, 1:2], (STRIP, tile))
+                new = decay * s_ref[0, 0, at, at_lanes] + b_col * dtx
+                so_ref[0, 0, at, at_lanes] = new
+                return acc + new * c_col
+
+            partial = lax.fori_loop(0, n_state // STRIP, strip,
+                                    jnp.zeros((STRIP, tile), jnp.float32))
+            y_ref[0, :, at_lanes] = partial.sum(axis=0, keepdims=True)
+
+
+def _update_kernel(state, layer, decay, dtx, b, c, active, interpret: bool):
+    L, B, N, F = state.shape
+    tile = min(LANE_TILE, F)
+    assert F % tile == 0 and tile % 128 == 0 and N % STRIP == 0, (N, F)
+    cols = jnp.stack([b, c], axis=-1)                           # [B, N, 2]
+
+    def leaf(slot, layer, on):
+        return layer[0], slot, 0, 0
+
+    def own(slot, layer, on):
+        return slot, 0, 0
+
+    row = pl.BlockSpec((1, 1, F), own)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B,),
+        in_specs=[pl.BlockSpec((1, 1, N, F), leaf), row, row,
+                  pl.BlockSpec((1, N, 2), own)],
+        out_specs=[pl.BlockSpec((1, 1, N, F), leaf), row])
+    state, y = pl.pallas_call(
+        functools.partial(_kernel, n_state=N, lanes=F, tile=tile),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((B, 1, F), jnp.float32)],
+        # operands count the two prefetched scalars: the state is written
+        # where it is read
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="ssm_update", interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
+      state, decay[:, None], dtx[:, None], cols)
+    return state, y[:, 0]
+
+
+def ssm_update(state: jax.Array, layer, decay, dtx, b, c, active, *,
+               kernel: bool | None = None, interpret: bool = False):
+    """One token a slot through layer `layer` of the state.
+
+    state [L, B, N, F] float32 (F = heads x lanes a head), decay and dtx
+    [B, F] (`exp(dt A)` and `dt x`, a head's value over its lanes), b and c
+    [B, N], active [B] -> (state, y [B, F]): the read-out is of the state
+    after the update and is garbage for a slot that is not active, whose
+    state comes back bit for bit. On the TPU (or with `interpret`, or
+    `kernel=True`) the state goes through the Pallas kernel, which writes
+    the leaf in place; elsewhere through plain XLA."""
+    if kernel is None:
+        kernel = interpret or _on_tpu()
+    if kernel:
+        return _update_kernel(state, layer, decay, dtx, b, c, active,
+                              interpret)
+    return _update_plain(state, layer, decay, dtx, b, c, active)

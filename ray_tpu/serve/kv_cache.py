@@ -25,6 +25,17 @@ leaves as they stood when the prompt's last whole block had gone through,
 keyed by that boundary's chain hash, in the same table under the same LRU.
 `match_prefix` returns the longest boundary that has one, `copy_into_slot`
 copies one entry over the slot's whole state.
+
+A family whose cache holds both (rows a token in some layers, state a slot
+in others) gets a pool of both kinds. An entry for a prefix is a snapshot of
+the state at a block boundary plus the row blocks up to that boundary: one
+hash table, one LRU, `num_blocks` blocks of rows and `num_snapshots`
+snapshots. A hit is the longest boundary that has both; rows without a
+snapshot at their end are no hit (`rows_without_snapshot_tokens` counts the
+tokens prefilled again for it); a block is not evicted while a pooled
+snapshot stands on it, and a snapshot that makes room goes before its blocks
+do. A prefix's blocks move between pool and slot in one program a leaf,
+whatever their number.
 """
 
 from __future__ import annotations
@@ -85,32 +96,42 @@ class PagedKVCache:
     @classmethod
     def for_cache(cls, cache: dict, token_axis: Dict[str, int],
                   num_blocks: int = 64, block_size: int = 16,
-                  state: Tuple[str, ...] = ()) -> "PagedKVCache":
+                  state: Tuple[str, ...] = (),
+                  num_snapshots: Optional[int] = None) -> "PagedKVCache":
         """A pool for the leaves of `cache` that `token_axis` names (leaf
-        -> the axis that counts tokens; axis 0 the layers, 1 the slots), or
+        -> the axis that counts tokens; axis 0 the layers, 1 the slots) and
         for those `state` names (a slot's recurrent state, no token axis:
-        `num_blocks` snapshots, taken at multiples of `block_size`). Leaves
+        snapshots, taken at multiples of `block_size`). With both kinds,
+        `num_blocks` counts the blocks of rows and `num_snapshots` the
+        snapshots (by default one for every whole slot of rows the blocks
+        hold); with state alone `num_blocks` counts the snapshots. Leaves
         neither names are not pooled."""
-        if token_axis and state:
-            raise NotImplementedError(
-                "a cache with rows a token beside state a slot needs a "
-                "pool of both kinds (ROADMAP R9); a family names one")
         self = cls.__new__(cls)
-        names = list(state) if state else list(token_axis)
-        self._build({name: cache[name] for name in names}, token_axis,
-                    num_blocks, block_size)
+        if not (token_axis and state):
+            num_snapshots = num_blocks if state else 0
+        elif num_snapshots is None:
+            name, axis = next(iter(token_axis.items()))
+            num_snapshots = max(
+                1, num_blocks * block_size // cache[name].shape[axis])
+        self._build({name: cache[name]
+                     for name in list(token_axis) + list(state)},
+                    token_axis, num_blocks, block_size, num_snapshots)
         return self
 
     def _build(self, leaves: dict, token_axis: Dict[str, int],
-               num_blocks: int, block_size: int) -> None:
+               num_blocks: int, block_size: int,
+               num_snapshots: int = 0) -> None:
         import jax
         import jax.numpy as jnp
 
         self.jax, self.jnp = jax, jnp
         self.block_size = block_size
         self.num_blocks = num_blocks
-        # leaves with no token axis: an entry is a snapshot of a slot
-        self.snapshots = bool(leaves) and not token_axis
+        self.num_snapshots = num_snapshots
+        # leaves with no token axis: an entry holds a snapshot of a slot
+        self.snapshots = any(name not in token_axis for name in leaves)
+        # both kinds: an entry is a snapshot and the row blocks under it
+        self.both = self.snapshots and bool(token_axis)
         self.pools: Dict[str, "jax.Array"] = {}
         self._copiers: Dict[str, tuple] = {}
         by_geometry: dict = {}
@@ -122,21 +143,37 @@ class PagedKVCache:
                 block[axis] = block_size
             geometry = (tuple(block), axis, jnp.dtype(leaf.dtype).name)
             if geometry not in by_geometry:
-                by_geometry[geometry] = self._copy_programs(tuple(block),
-                                                            axis)
+                by_geometry[geometry] = (
+                    self._copy_programs_many(tuple(block), axis)
+                    if self.both and axis is not None
+                    else self._copy_programs(tuple(block), axis))
+            if self.both and axis is not None:
+                # the most blocks a prefix has: a slot's rows
+                self._most_blocks = leaf.shape[axis] // block_size
             self._copiers[name] = by_geometry[geometry]
-            block[1] = num_blocks
+            block[1] = num_snapshots if self.both and axis is None \
+                else num_blocks
             self.pools[name] = jnp.zeros(tuple(block), leaf.dtype)
+        self._rows = [n for n in leaves if n in token_axis]
+        self._states = [n for n in leaves if n not in token_axis]
         # the first leaf's two programs, under the names they have had
         self._copy_out, self._copy_in = next(iter(self._copiers.values()))
         self._free: List[int] = list(range(num_blocks))
         # chain hash -> block id, LRU order (least recent first)
         self._table: "OrderedDict[bytes, int]" = OrderedDict()
         self._hash_of_block: Dict[int, bytes] = {}
+        # a pool of both kinds: boundary hash -> snapshot id, the blocks
+        # each snapshot stands on, and how many snapshots stand on a block
+        self._free_snapshots: List[int] = list(range(num_snapshots))
+        self._snapshot_at: Dict[bytes, int] = {}
+        self._stands_on: Dict[bytes, List[int]] = {}
+        self._pins: Dict[int, int] = {}
         # counters (tests + /stats)
         self.hits = 0
         self.tokens_reused = 0
         self.blocks_evicted = 0
+        self.snapshots_evicted = 0
+        self.rows_without_snapshot_tokens = 0
 
     def _copy_programs(self, block: tuple, axis: Optional[int]) -> tuple:
         """(copy_out, copy_in) for leaves whose one block of one slot is
@@ -158,6 +195,40 @@ class PagedKVCache:
                 data = jax.lax.dynamic_slice(pool, at(blk, 0), block)
                 return jax.lax.dynamic_update_slice(cache, data,
                                                     at(slot, t0))
+
+        return (jax.jit(_copy_out, donate_argnums=(0,)),
+                jax.jit(_copy_in, donate_argnums=(0,)))
+
+    def _copy_programs_many(self, block: tuple, axis: int) -> tuple:
+        """(copy_out, copy_in) for a prefix's blocks of rows in one program:
+        `blocks` int32 pool block ids, `at` int32 which block of the slot
+        each is (position `at * block_size`), the first `n` of them valid
+        (`_padded` makes them, each as long as a slot has blocks)."""
+        jax = self.jax
+        size = block[axis]
+
+        def where(second, t0):
+            return tuple(second if i == 1 else t0 if i == axis else 0
+                         for i in range(len(block)))
+
+        def _copy_out(pool, cache, slot, blocks, at, n):
+            def one(i, pool):
+                data = jax.lax.dynamic_slice(
+                    cache, where(slot, at[i] * size), block)
+                return jax.lax.dynamic_update_slice(pool, data,
+                                                    where(blocks[i], 0))
+
+            with jax.named_scope("prefix_pool"):
+                return jax.lax.fori_loop(0, n, one, pool)
+
+        def _copy_in(cache, pool, slot, blocks, at, n):
+            def one(i, cache):
+                data = jax.lax.dynamic_slice(pool, where(blocks[i], 0), block)
+                return jax.lax.dynamic_update_slice(
+                    cache, data, where(slot, at[i] * size))
+
+            with jax.named_scope("prefix_pool"):
+                return jax.lax.fori_loop(0, n, one, cache)
 
         return (jax.jit(_copy_out, donate_argnums=(0,)),
                 jax.jit(_copy_in, donate_argnums=(0,)))
@@ -194,6 +265,8 @@ class PagedKVCache:
         order or the hit/miss counters — the disagg decode side uses this
         to decide whether fetching remote KV would gain anything before
         it commits to a prefill RPC."""
+        if self.both:
+            return self._longest_entry(chain_hashes(ids, self.block_size))[0]
         if self.snapshots:
             return max((n for h, n in chain_hashes(ids, self.block_size)
                         if h in self._table), default=0)
@@ -209,7 +282,33 @@ class PagedKVCache:
         what this engine advertises as its resident-prefix routing hint."""
         return list(self._table)[-n:]
 
+    def _longest_entry(self, chain) -> Tuple[int, int]:
+        """(tokens up to the longest boundary whose rows are pooled from the
+        root on and whose snapshot stands, tokens whose rows are pooled from
+        the root on)."""
+        rows = 0
+        for h, n in chain:
+            if h not in self._table:
+                break
+            rows = n
+        hit = max((n for h, n in chain[:rows // self.block_size]
+                   if h in self._snapshot_at), default=0)
+        return hit, rows
+
     def match_prefix(self, ids: List[int]) -> Tuple[int, List[int]]:
+        if self.both:
+            chain = chain_hashes(ids, self.block_size)
+            n, rows = self._longest_entry(chain)
+            # rows without a snapshot at their end are no hit
+            self.rows_without_snapshot_tokens += rows - n
+            blocks = []
+            for h, _ in chain[:n // self.block_size]:
+                self._table.move_to_end(h)
+                blocks.append(self._table[h])
+            if blocks:
+                self.hits += 1
+                self.tokens_reused += n
+            return n, blocks
         if self.snapshots:
             # the longest boundary with a snapshot: one entry, whatever
             # shorter ones exist
@@ -237,15 +336,33 @@ class PagedKVCache:
     def _alloc(self) -> Optional[int]:
         if self._free:
             return self._free.pop()
-        if not self._table:
-            return None
         # evict the least-recently-matched chain entry. A child whose
         # parent is evicted can never match again (match walks from the
-        # root) and ages out the same way.
-        _h, blk = self._table.popitem(last=False)
-        self._hash_of_block.pop(blk, None)
-        self.blocks_evicted += 1
-        return blk
+        # root) and ages out the same way. A block under a pooled snapshot
+        # stays: the snapshot would be gone with it
+        for h, blk in self._table.items():
+            if not self._pins.get(blk):
+                del self._table[h]
+                self._hash_of_block.pop(blk, None)
+                self.blocks_evicted += 1
+                return blk
+        # every block stands under a snapshot: the least recently used
+        # snapshot goes, and its blocks may follow
+        snapshot = self._drop_snapshot()
+        if snapshot is None:
+            return None
+        self._free_snapshots.append(snapshot)
+        return self._alloc()
+
+    def _drop_snapshot(self) -> Optional[int]:
+        """Evict the least recently used snapshot; its id, or None."""
+        for h in self._table:
+            if h in self._snapshot_at:
+                for blk in self._stands_on.pop(h):
+                    self._pins[blk] -= 1
+                self.snapshots_evicted += 1
+                return self._snapshot_at.pop(h)
+        return None
 
     # -------------------------------------------------------------- store
     def store_prefix(self, ids: List[int], cache, slot: int) -> int:
@@ -258,6 +375,8 @@ class PagedKVCache:
         taken exactly those blocks and no token more."""
         B = self.block_size
         chain = chain_hashes(ids, B)
+        if self.both:
+            return self._store_entry(chain, cache, slot)
         # (hash, where in the slot): a block of rows a hash, or the slot's
         # state once, under the last hash
         entries = (([(chain[-1][0], 0)] if chain else []) if self.snapshots
@@ -278,12 +397,78 @@ class PagedKVCache:
             stored += 1
         return stored
 
+    def _store_entry(self, chain, cache, slot: int) -> int:
+        """A pool of both kinds keeps the slot's rows up to `chain`'s last
+        boundary and its state as it stands there (the caller calls when the
+        slot has taken exactly those blocks): 1 when a new snapshot was
+        stored, 0 when it stood already or nothing could make room."""
+        if not chain or len(chain) > self.num_blocks:
+            return 0            # nothing whole, or more than the pool holds
+        if chain[-1][0] in self._snapshot_at:
+            for h, _ in chain:
+                self._table.move_to_end(h)
+            return 0
+        held, new = [], []
+        for i, (h, _) in enumerate(chain):
+            blk = self._table.get(h)
+            if blk is None:
+                blk = self._alloc()
+                if blk is None:
+                    break
+                self._table[h] = blk
+                self._hash_of_block[blk] = h
+                new.append((blk, i))
+            else:
+                self._table.move_to_end(h)
+            # held against this call's own evictions
+            self._pins[blk] = self._pins.get(blk, 0) + 1
+            held.append(blk)
+        if new:
+            blocks, at = self._padded(new)
+            for name in self._rows:
+                self.pools[name] = self._copiers[name][0](
+                    self.pools[name], cache[name], slot, blocks, at, len(new))
+        snapshot = None
+        if len(held) == len(chain):
+            snapshot = (self._free_snapshots.pop() if self._free_snapshots
+                        else self._drop_snapshot())
+        if snapshot is None:        # rows without a snapshot: no entry
+            for blk in held:
+                self._pins[blk] -= 1
+            return 0
+        for name in self._states:
+            self.pools[name] = self._copiers[name][0](
+                self.pools[name], cache[name], slot, 0, snapshot)
+        self._snapshot_at[chain[-1][0]] = snapshot
+        self._stands_on[chain[-1][0]] = held
+        return 1
+
+    def _padded(self, pairs) -> tuple:
+        """(block ids, which block of the slot) as the many-block programs
+        take them: int32 [the most blocks a slot has], the rest zeros."""
+        import numpy as np
+
+        out = np.zeros((2, self._most_blocks), np.int32)
+        out[:, :len(pairs)] = np.asarray(pairs, np.int32).T
+        return out[0], out[1]
+
     # --------------------------------------------------------------- load
     def copy_into_slot(self, cache, slot: int, blocks: List[int]):
         """Materialize matched pool blocks into cache slot lane starting
         at position 0 (a snapshot: its one entry over the slot's whole
         state); returns the updated cache dict."""
         cache = dict(cache)
+        if self.both:
+            # the entry whose last block this is: its rows, then its state
+            ids, at = self._padded([(b, i) for i, b in enumerate(blocks)])
+            for name in self._rows:
+                cache[name] = self._copiers[name][1](
+                    cache[name], self.pools[name], slot, ids, at, len(blocks))
+            snapshot = self._snapshot_at[self._hash_of_block[blocks[-1]]]
+            for name in self._states:
+                cache[name] = self._copiers[name][1](
+                    cache[name], self.pools[name], slot, 0, snapshot)
+            return cache
         t0 = 0
         for blk in blocks:
             for name, (_, copy_in) in self._copiers.items():
@@ -294,10 +479,17 @@ class PagedKVCache:
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
-        return {"blocks_used": self.num_blocks - len(self._free),
-                "prefix_hits": self.hits,
-                "tokens_reused": self.tokens_reused,
-                "blocks_evicted": self.blocks_evicted}
+        out = {"blocks_used": self.num_blocks - len(self._free),
+               "prefix_hits": self.hits,
+               "tokens_reused": self.tokens_reused,
+               "blocks_evicted": self.blocks_evicted}
+        if self.both:
+            out.update(
+                snapshots_used=self.num_snapshots - len(self._free_snapshots),
+                snapshots_evicted=self.snapshots_evicted,
+                rows_without_snapshot_tokens=(
+                    self.rows_without_snapshot_tokens))
+        return out
 
 
 # ----------------------------------------------------- KV transfer (P/D)
@@ -310,8 +502,9 @@ class PagedKVCache:
 def _require_rows(kv: "PagedKVCache", what: str) -> None:
     if kv.snapshots:
         raise NotImplementedError(
-            f"{what} ships blocks of keys and values; a pool of state "
-            f"snapshots (the brumby family) has no wire format yet")
+            f"{what} ships blocks of keys and values; a pool that holds "
+            f"state snapshots (the brumby and granite families) has no "
+            f"wire format yet")
 
 
 def export_prefix(kv: "PagedKVCache", ids) -> Optional[dict]:

@@ -358,10 +358,10 @@ class LLMEngine:
         self.cache = model.init_cache(self.cfg, max_batch, self.max_seq_len)
         cfg = self.cfg
         # the family's word on its cache (`models/__init__.py`): leaves with
-        # a value a token, or leaves that are a slot's recurrent state
+        # a value a token, leaves that are a slot's recurrent state, or both
         self._state_leaves = tuple(getattr(model, "CACHE_STATE", ()))
-        # what a token leaves in the cache, all layers: a gauge (a family
-        # of state leaves nothing a token: `state_bytes_per_slot` is its)
+        # what a token leaves in the cache, all layers, and what a slot's
+        # state holds: two gauges, each 0 for a family without that kind
         self.kv_bytes_per_token = sum(
             self.cache[name].nbytes for name in model.CACHE_TOKEN_AXIS
         ) // (max_batch * self.max_seq_len)
@@ -491,9 +491,10 @@ class LLMEngine:
         self._slots: List[Optional[_Request]] = [None] * max_batch
         self._slot_pos = [0] * max_batch
         self._slot_prefill: List[List[int]] = [[] for _ in range(max_batch)]
-        # a family of state: the position at which each slot's state is to
-        # be pooled, between the chunk step that ends there and the next
-        # (0: nowhere). The prompt's last whole block, as the pool's rows
+        # a family with state: the position at which each slot's state (and
+        # the rows up to it, where the cache has both) is to be pooled,
+        # between the chunk step that ends there and the next (0: nowhere).
+        # The prompt's last whole block, as the pool's rows
         self._slot_snapshot_at = [0] * max_batch
         # async prefill fetch: requests whose KV blob is still in flight
         # park here (other lanes keep decoding); resolved ones re-enter
@@ -509,6 +510,9 @@ class LLMEngine:
         self.overrun_lane_steps = 0
         self.chunk_steps = 0           # steps that ran the chunked program
         self.tokens_prefilled = 0      # prompt tokens processed
+        # positions the steps' lanes attended over: each lane's last
+        # position in its step, summed (what a cache of rows is read for)
+        self.positions_attended = 0
         self.prefix_imports = 0        # deferred blobs installed
         self.prefix_blocks_imported = 0
         self.prefix_wait_timeouts = 0  # deadline hit: local prefill
@@ -939,6 +943,7 @@ class LLMEngine:
                     continue
                 req = self._slots[i]
                 self._slot_pos[i] += take
+                self.positions_attended += self._slot_pos[i]
                 if self._slot_pos[i] == self._slot_snapshot_at[i]:
                     snapshots.append((req.prompt_ids[:self._slot_pos[i]], i))
                     self._slot_snapshot_at[i] = 0
@@ -992,7 +997,10 @@ class LLMEngine:
     def _pool_prompts(self, prompts):
         """Copy each prefilled prompt's blocks from its slot into the
         prefix pool: dispatched behind the step that wrote the rows and
-        ahead of any that rewrites them; the device keeps that order."""
+        ahead of any that rewrites them; the device keeps that order. A
+        family with state has pooled what it can by now: its snapshot, and
+        with it the rows up to the snapshot's boundary, between two chunk
+        steps (rows past a snapshot are no hit, so none are pooled here)."""
         if prompts and self.kv is not None and not self._state_leaves:
             with self._phase["publish"]:
                 for prompt_ids, i in prompts:
@@ -1091,13 +1099,20 @@ class LLMEngine:
             queue_wait = dict(self.lifecycle["queue_wait_s"])
             ttft = dict(self.lifecycle["ttft_s"])
         ttft_avg = ttft["sum"] / ttft["count"] if ttft["count"] else 0.0
-        # the gauge of the cache's kind: bytes a token, or bytes a slot
-        kind = ({"kv_bytes_per_token": self.kv_bytes_per_token}
-                if not self._state_leaves else {
-                    "state_bytes_per_slot": self.state_bytes_per_slot,
-                    "slots_reset": self.slots_reset,
-                    "snapshots_pooled": self.snapshots_pooled,
-                    "snapshot_hits": self.snapshot_hits})
+        # the gauges of the cache's kinds: bytes a token, bytes a slot
+        kind = {}
+        if self.kv_bytes_per_token or not self._state_leaves:
+            kind["kv_bytes_per_token"] = self.kv_bytes_per_token
+        if self._state_leaves:
+            kind.update(state_bytes_per_slot=self.state_bytes_per_slot,
+                        slots_reset=self.slots_reset,
+                        snapshots_pooled=self.snapshots_pooled,
+                        snapshot_hits=self.snapshot_hits)
+        if self.kv is not None and self.kv.both:
+            # prompt tokens whose rows the pool held and which were
+            # prefilled again because no snapshot stood at their boundary
+            kind["rows_without_snapshot_tokens"] = \
+                self.kv.rows_without_snapshot_tokens
         watch = self._compile_watch
         return {**self._device_counters(), **kind,
                 # what this engine's process runs JAX on
@@ -1113,6 +1128,7 @@ class LLMEngine:
                 "overrun_lane_steps": self.overrun_lane_steps,
                 "chunk_steps": self.chunk_steps,
                 "tokens_prefilled": self.tokens_prefilled,
+                "positions_attended": self.positions_attended,
                 "prefix_imports": self.prefix_imports,
                 "prefix_blocks_imported": self.prefix_blocks_imported,
                 "prefix_wait_timeouts": self.prefix_wait_timeouts,

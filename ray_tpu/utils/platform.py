@@ -57,14 +57,25 @@ def enable_compile_cache() -> str:
     version of the code compiled it first: a device trace of this version
     would show another's scopes, or none (PR 23: a parent commit that ran
     first on a shared cache left the serving programs unnamed). The price is
-    a compile after an edit that moves the model's lines."""
+    a compile after an edit that moves the model's lines.
+
+    An operation's location is its own line, not the stack of calls that led
+    to it: with ten frames of traceback in a location (JAX's default) the
+    metadata, and so the key, differs between two processes that reach the
+    same program by different callers, and a benchmark's reference check
+    compiled every program again that its replica had compiled a minute
+    before (13 programs, ~20 s of a cold traced run: PERF.md, PRs 33, 38).
+    One frame, not `jax_include_full_tracebacks_in_locations=False`: that
+    drops the named scopes from the operations' names with the frames."""
     path = os.environ["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
     os.environ["JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"] = "true"
+    os.environ["JAX_TRACEBACK_IN_LOCATIONS_LIMIT"] = "1"
     jax = sys.modules.get("jax")
     if jax is not None:
         jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_compilation_cache_include_metadata_in_key",
                           True)
+        jax.config.update("jax_traceback_in_locations_limit", 1)
     return path
 
 

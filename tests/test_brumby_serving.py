@@ -301,8 +301,10 @@ def test_the_transfers_refuse_a_pool_of_snapshots_by_name():
         eng.import_prefix({"ids": PROMPT})
     with pytest.raises(NotImplementedError, match="brumby"):
         eng.prefix_model_key
-    with pytest.raises(NotImplementedError, match="R9"):
-        PagedKVCache.for_cache(eng.cache, {"state": 2}, state=("norm",))
+    # rows beside state were refused here until PR 38: a pool of both now
+    # (`tests/test_granite_serving.py` holds its rules)
+    assert PagedKVCache.for_cache(eng.cache, {"state": 2}, state=("norm",),
+                                  num_blocks=2, block_size=8).both
 
 
 # ------------------------------------------------------------------ engine

@@ -90,3 +90,48 @@ def test_unset_processes_agree_on_in_checkout_path(tmp_path, monkeypatch):
 def test_in_checkout_cache_is_not_committed():
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+_SAME_PROGRAM = """
+import os, sys
+{pre}
+from ray_tpu.utils.platform import enable_compile_cache
+enable_compile_cache()
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+def program(x):
+    with jax.named_scope("attn"):
+        return jnp.tanh(x @ x.T).sum()
+
+def reached_by(callers, x):
+    return reached_by(callers - 1, x) if callers else jax.jit(program)(x)
+
+reached_by(int(sys.argv[1]), jnp.ones((8, 8)))
+text = jax.jit(program).lower(jnp.ones((8, 8))).compile().as_text()
+assert "jit(program)/attn/tanh" in text      # the scopes stay in the names
+print(len(os.listdir(os.environ["JAX_COMPILATION_CACHE_DIR"])))
+"""
+
+
+@pytest.mark.parametrize("jax_first", [False, True])
+def test_a_program_reached_by_other_callers_is_found_in_the_cache(
+        tmp_path, jax_first):
+    """Two processes that reach the same jitted program through different
+    call stacks (a replica's engine loop, a benchmark's reference check)
+    share its cache entry: an operation's location is its own line, and its
+    name still carries the named scopes the trace readers sum by."""
+    env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    counts = []
+    for callers in (0, 3):
+        out = subprocess.run(
+            [sys.executable, "-c",
+             _SAME_PROGRAM.format(pre="import jax" if jax_first else ""),
+             str(callers)],
+            env=env, capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        counts.append(int(out.stdout.strip().splitlines()[-1]))
+    assert counts[0] > 0 and counts[1] == counts[0]

@@ -358,6 +358,53 @@ def test_brumby_serving_programs_compile_at_the_configurations_sizes(
         "state_copies": [], "layer_copies": []}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-granite-docgen`'s two programs, as its configuration
+    file has them (granite-4.0-h-micro whole: 36 Mamba-2 and 4 attention
+    layers, 48 slots of 77 MB of float32 state and 8,192 positions of rows,
+    chunks of 64): the bytes the file gives, room for the pool of both kinds
+    beside the larger; four Pallas kernels in both programs (the state's
+    update in each of the two Mamba runs' loop bodies, a row's write for keys
+    and for values: a chunk's first lane is the decode program's); no
+    instruction copies a cache leaf (the kernel
+    aliases the SSM state, the layers' loops carry the four leaves) or
+    materialises one layer's state for all slots; the decode program's
+    temporaries are one attention layer's scores, the chunk program's under
+    a quarter of a gigabyte: neither computes the padding of 48 x 64 lanes."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_granite_for_v5e import (CONFIG, cache_bytes, compile_step,
+                                         made_of, pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    want = (memory["decode_step_bytes"] if program == "decode" else memory[
+        "prefill_chunk_bytes_by_chunk_size"][
+            str(config["deployment"]["prefill_chunk_size"])])
+    assert sized["total"] == want
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 48 * 64 * 4 * 2)   # the chunk's tokens
+    assert sized["arguments"] >= 0.75 * HBM_BYTES
+    assert cache_bytes(config) == {
+        "state_bytes_per_slot": memory["state_bytes_per_slot"],
+        "kv_bytes_per_token": memory["kv_bytes_per_token"]} == {
+        "state_bytes_per_slot": 77_377_536, "kv_bytes_per_token": 8192}
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    if program == "decode":
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 26
+    else:
+        assert sized["temp"] < 2 ** 28
+    assert made_of(compiled.as_text(), config) == {
+        "kernels": 4, "leaf_copies": {}, "ssm_layer_copies": []}
+
+
 def test_token_selection_compiles_at_xl_vocabulary(chips):
     """`serve/sampling.select_tokens` over the serving cells' [8, 50304]
     logits: one program whose sort is in a branch of a conditional, and
