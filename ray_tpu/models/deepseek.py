@@ -50,6 +50,14 @@ the norms' scales are float32 (they are used in float32), and so is the
 residual stream inside the step programs (`_layers`). Router and
 experts are `models/moe.py`'s: `_route` and the one sorted grouped-matmul
 path `_experts`, at any number of rows.
+
+The two step programs are one function (`_chunk`): `decode_step` is every
+slot's first lane through the layers, all slots at once, and
+`prefill_chunk` is that plus the lanes after the first of the slots whose
+chunk has any, a slot at a time (`_further_lanes`): a lane is computed only
+where the plan put a token, so a chunk step costs a decode step and a term
+a slot that prefills, not B x C lanes whoever prefills (PERF.md, PR 39;
+`benchmarks/kanana_chunk_lanes.py` has the table).
 """
 
 from __future__ import annotations
@@ -302,26 +310,35 @@ def init_cache(cfg: DeepseekConfig, batch: int,
 _WRITE_WINDOW = 128
 
 
-def _cache_write(c, l, val, pos0, ok):
-    """Layer l of the carried leaf c [L,B,T,F] takes val [B,C,F]: lane i of
-    slot b goes to position pos0[b] + i where ok[b, i]; nothing else
+def _cache_write(c, l, val, pos0, ok, slot=None):
+    """Layer l of the carried leaf c [L,B,T,F] takes val [N,C,F]: lane i of
+    row n goes to position pos0[n] + i where ok[n, i], in slot n (N = B), or
+    in `slot` for the one row of that slot's own lanes; nothing else
     changes. `gpt2._cache_write` without the heads: per slot one window of
     W >= C positions is read, blended and written back in place."""
-    _, B, T, F = c.shape
-    C = val.shape[1]
+    T, F = c.shape[2:]
+    N, C = val.shape[:2]
     W = min(T, max(C, _WRITE_WINDOW))
     start = jnp.clip(pos0 // W * W if C == 1 else pos0, 0, T - W)
-    src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [B, W]
-    hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [B, W, C]
+    src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [N, W]
+    hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [N, W, C]
     moved = jnp.einsum("bwc,bcf->bwf", hit.astype(val.dtype), val,
                        precision=lax.Precision.HIGHEST)
-    take = hit.any(axis=-1)                                           # [B, W]
-    for b in range(B):
-        at = (l, b, start[b], 0)
+    take = hit.any(axis=-1)                                           # [N, W]
+    for b in range(N):
+        at = (l, b if slot is None else slot, start[b], 0)
         old = lax.dynamic_slice(c, at, (1, 1, W, F))
         new = jnp.where(take[b][:, None], moved[b], old)
         c = lax.dynamic_update_slice(c, new, at)
     return c
+
+
+def _rows(c, l, slot=None):
+    """Layer l of the carried leaf c [L,B,T,F] as attention reads it: every
+    slot's rows [B,T,F], or `slot`'s alone [1,T,F], where they lie."""
+    if slot is None:
+        return c[l]
+    return lax.dynamic_slice(c, (l, slot, 0, 0), (1, 1) + c.shape[2:])[0]
 
 
 # ---------------------------------------------------------------------------
@@ -339,10 +356,12 @@ def _swiglu(h, p, cfg: DeepseekConfig):
     return (jax.nn.silu(g) * u) @ _w(p["wd"], cfg)
 
 
-def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok):
-    """x [B,C,D] float32 += absorbed attention of its C lanes (positions
-    `pos` [B,C], written where `ok`) against layer l of the carried
-    caches."""
+def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
+               slot=None):
+    """x [N,C,D] float32 += absorbed attention of its C lanes (positions
+    `pos` [N,C], written where `ok`) against layer l of the carried
+    caches: row n is slot n (N = B), or the one row is `slot`'s own lanes
+    against that slot's rows alone."""
     B, C, _ = x.shape
     H, r = cfg.n_head, cfg.kv_lora_rank
     n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -352,27 +371,29 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok):
         h = rms_norm(x, bp["attn_norm"], cfg.norm_eps).astype(cfg.dtype)
         with jax.named_scope("mla_project"):
             q = jnp.einsum("bcd,dhk->bchk", h, _w(p["wq"], cfg))
-            ckr = h @ _w(p["wkva"], cfg)                          # [B,C,r+p]
+            ckr = h @ _w(p["wkva"], cfg)                          # [N,C,r+p]
             c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
             cos, sin = rope_freqs(pos, cfg.qk_rope_head_dim, cfg.rope_theta)
             cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-            q_rope = apply_rope(q[..., n:], cos, sin)             # [B,C,H,p]
+            q_rope = apply_rope(q[..., n:], cos, sin)             # [N,C,H,p]
             k_r = apply_rope(ckr[..., None, r:], cos, sin)[:, :, 0]
             wkvb = _w(p["wkvb"], cfg)
             q_abs = jnp.einsum("bchn,rhn->bchr", q[..., :n], wkvb[..., :n])
         with jax.named_scope("kv_update"):
-            lat = _cache_write(lat, l, c, pos0, ok)
-            kr = _cache_write(kr, l, k_r, pos0, ok)
+            lat = _cache_write(lat, l, c, pos0, ok, slot)
+            kr = _cache_write(kr, l, k_r, pos0, ok, slot)
         with jax.named_scope("mla_attend"):
-            scores = (jnp.einsum("bchr,btr->bhct", q_abs, lat[l],
+            latents = _rows(lat, l, slot)                           # [N,T,r]
+            scores = (jnp.einsum("bchr,btr->bhct", q_abs, latents,
                                  preferred_element_type=jnp.float32)
-                      + jnp.einsum("bchp,btp->bhct", q_rope, kr[l],
+                      + jnp.einsum("bchp,btp->bhct", q_rope,
+                                   _rows(kr, l, slot),
                                    preferred_element_type=jnp.float32))
             scores = scores / math.sqrt(cfg.qk_head_dim)
             t_idx = jnp.arange(T)[None, None, None, :]
             scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            mixed = jnp.einsum("bhct,btr->bchr", probs, lat[l])   # [B,C,H,r]
+            mixed = jnp.einsum("bhct,btr->bchr", probs, latents)  # [N,C,H,r]
         with jax.named_scope("mla_project"):
             o = jnp.einsum("bchr,rhv->bchv", mixed, wkvb[..., n:])
             x = x + jnp.dot(o.reshape(B, C, H * v), _w(p["wo"], cfg),
@@ -380,11 +401,12 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok):
     return x, lat, kr
 
 
-def _expert_mlp(x, bp, cfg: DeepseekConfig, counts, ok):
-    """x [B,C,D] += routed experts + shared experts; `counts` [4] += the
-    first four of `COUNTS`, over the lanes that are `ok`."""
+def _expert_mlp(x, bp, cfg: DeepseekConfig, given, ok):
+    """x [N,C,D] += routed experts + shared experts; `given` [E] += the
+    (lane, expert) rows each expert was given for the lanes that are
+    `ok`."""
     B, C, D = x.shape
-    E, K = cfg.n_experts, cfg.experts_per_token
+    K = cfg.experts_per_token
     with jax.named_scope("mlp"):
         h32 = rms_norm(x, bp["mlp_norm"], cfg.norm_eps)
         h = h32.astype(cfg.dtype)
@@ -393,24 +415,65 @@ def _expert_mlp(x, bp, cfg: DeepseekConfig, counts, ok):
         _, _, gates, experts = _moe._route(h32.reshape(B * C, D),
                                            m["router"], cfg, m["bias"])
         with jax.named_scope("moe_router"):
-            given = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
+            given = given.at[experts.reshape(-1)].add(
                 jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
-            counts = counts + jnp.stack(
-                [jnp.sum(given), jnp.sum(given > 0), jnp.max(given),
-                 jnp.ones((), jnp.int32)]).astype(counts.dtype)
         routed = _moe._experts(
             h, gates.reshape(B, C, K), experts.reshape(B, C, K),
             _w(m["wg"], cfg), _w(m["wu"], cfg), _w(m["wd"], cfg), cfg)
         with jax.named_scope("moe_shared"):
             shared = _swiglu(h, bp["shared"], cfg)
         x = x + routed.astype(x.dtype) + shared.astype(x.dtype)
-    return x, counts
+    return x, given
+
+
+def _expert_counts(given):
+    """The first four of `COUNTS` of one expert layer's step, from the rows
+    `given` [E] each expert got over all of the step's valid lanes."""
+    with jax.named_scope("moe_router"):
+        return jnp.stack([jnp.sum(given), jnp.sum(given > 0), jnp.max(given),
+                          jnp.ones((), jnp.int32)]).astype(jnp.uint32)
 
 
 def _dense_mlp(x, bp, cfg: DeepseekConfig):
     with jax.named_scope("mlp"):
         h = rms_norm(x, bp["mlp_norm"], cfg.norm_eps).astype(cfg.dtype)
         return x + _swiglu(h, bp["mlp"], cfg).astype(x.dtype)
+
+
+def _mlp(x, bp, cfg: DeepseekConfig, given, ok):
+    """The layer's second half, by what its weights are: (x, given)."""
+    if "moe" in bp:
+        return _expert_mlp(x, bp, cfg, given, ok)
+    return _dense_mlp(x, bp, cfg), given
+
+
+def _further_lanes(rest, bp, cfg: DeepseekConfig, lat, kr, given, l, pos,
+                   ok):
+    """One layer over the lanes after the first, rest [B,M,D] with ok
+    [B,M], the first of them at position pos [B]: a slot at a time and only
+    the slots that have such lanes (the others cost a predicate each), its
+    scores [1,H,M,T] against its own rows, its experts over its own M
+    lanes. The weights are the ones the first lanes read, `bp` as the
+    layers' loop has it: the loop copies a layer's three expert matrices
+    out of the stack once (ROADMAP S12a) and every slot's kernels read that
+    copy; sliced again inside the branch they are copied again for every
+    slot that prefills (`benchmarks/kanana_chunk_lanes.py` has both)."""
+    B, M, D = rest.shape
+
+    def slot(b, rest, lat, kr, given):
+        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
+        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
+        at = lax.dynamic_slice(pos, (b,), (1,))
+        xb, lat, kr = _attention(xb, bp, cfg, lat, kr, l, at,
+                                 at[:, None] + jnp.arange(M), okb, slot=b)
+        xb, given = _mlp(xb, bp, cfg, given, okb)
+        return lax.dynamic_update_slice(rest, xb, (b, 0, 0)), lat, kr, given
+
+    def body(b, carry):
+        more = lax.dynamic_index_in_dim(ok, b, 0, keepdims=False).any()
+        return lax.cond(more, slot, lambda b, *same: same, b, *carry)
+
+    return lax.fori_loop(0, B, body, (rest, lat, kr, given))
 
 
 def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
@@ -420,33 +483,64 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     rounds every layer's sum to 8 bits, which at these widths is most of
     what separates the program from the reference: PERF.md, PR 29), what a
     product reads of it is the norm's output in the compute dtype, and the
-    router reads that output before it is rounded. The dense layers
-    stand before the loop, the expert layers are one scan over their
-    stacked weights (which has each layer's three expert matrices copied
-    out of the stack for the kernels: ROADMAP S12). `program` is the row of
-    the cache's `counts` that this program's counts go to. Returns
-    (x, cache)."""
+    router reads that output before it is rounded.
+
+    A layer computes a lane only where the plan put a token (PERF.md, PR
+    39). Every slot's first lane goes through the layer all slots at once:
+    that is the whole decode program, and in the chunk program every decode
+    lane riding along and the first token of every chunk. The lanes after
+    it go through `_further_lanes`, only the slots that have them, a slot
+    at a time: C of them a slot, the last one padding, so that the rows a
+    slot's experts sort come in whole tiles of the grouped matmul
+    (`ops/grouped_matmul._tiling` halves a tile until it divides the rows:
+    127 lanes x 6 would be tiles of 2 rows). A step costs the decode
+    program's time plus a term a slot that prefills, where all B x C lanes
+    through every layer cost the worst case whoever prefilled (305 ms at 32
+    x 128 for one slot's question).
+
+    The dense layers stand before the loop, the expert layers are one scan
+    over their stacked weights (which has each layer's three expert
+    matrices copied out of the stack for the kernels: ROADMAP S12).
+    `program` is the row of the cache's `counts` that this program's
+    counts go to. Returns (x, cache)."""
+    C = x.shape[1]
     lat, kr = cache["latent"], cache["k_rope"]
     counts = jnp.zeros((4,), jnp.uint32)
     n_dense = cfg.n_dense_layer
-    for l in range(n_dense):
-        bp = jax.tree.map(lambda a, l=l: a[l], params["dense"])
-        x, lat, kr = _attention(x, bp, cfg, lat, kr, l, pos0, pos, ok)
-        x = _dense_mlp(x, bp, cfg)
+    first, on = x[:, :1], ok[:, :1]
+    rest = further = None
+    if C > 1:
+        rest = jnp.pad(x[:, 1:], ((0, 0), (0, 1), (0, 0)))
+        further = jnp.pad(ok[:, 1:], ((0, 0), (0, 1)))
 
-    def body(carry, layer):
-        x, lat, kr, counts = carry
-        l, bp = layer
-        x, lat, kr = _attention(x, bp, cfg, lat, kr, l, pos0, pos, ok)
-        x, counts = _expert_mlp(x, bp, cfg, counts, ok)
-        return (x, lat, kr, counts), None
+    def layer(l, bp, first, rest, lat, kr, counts):
+        given = jnp.zeros((cfg.n_experts,), jnp.int32)
+        first, lat, kr = _attention(first, bp, cfg, lat, kr, l, pos0,
+                                    pos[:, :1], on)
+        first, given = _mlp(first, bp, cfg, given, on)
+        if rest is not None:
+            rest, lat, kr, given = _further_lanes(
+                rest, bp, cfg, lat, kr, given, l, pos0 + 1, further)
+        if "moe" in bp:
+            counts = counts + _expert_counts(given)
+        return first, rest, lat, kr, counts
+
+    carry = (first, rest, lat, kr, counts)
+    for l in range(n_dense):
+        carry = layer(l, jax.tree.map(lambda a, l=l: a[l], params["dense"]),
+                      *carry)
 
     # as `gpt2._cached_layers`: the caches are carries, one buffer from
     # layer to layer, written in place where the caller donates them
     with jax.named_scope("layers"):
-        (x, lat, kr, counts), _ = lax.scan(
-            body, (x, lat, kr, counts),
+        carry, _ = lax.scan(
+            lambda carry, layer_: (layer(*layer_, *carry), None), carry,
             (jnp.arange(n_dense, cfg.n_layer), params["blocks"]))
+    first, rest, lat, kr, counts = carry
+    if rest is not None:
+        x = jnp.concatenate([first, rest[:, :C - 1]], axis=1)
+    else:
+        x = first
     with jax.named_scope("moe_router"):
         attended = jnp.sum(jnp.where(ok, pos + 1, 0)).astype(jnp.uint32)
         counts = cache["counts"].at[program].add(

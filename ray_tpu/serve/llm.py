@@ -209,6 +209,17 @@ class LLMEngine:
     `max_num_batched_tokens` per step, with decode lanes reserved first
     so prefill can't starve decode. `scheduler` names that one loop:
     deployment configs carry the key, and any other value is refused.
+    The default budget, `max(2 B, B + C)`, lets at most two slots prefill
+    in a step beside the decode lanes. What a larger one costs is the
+    family's chunk program's to say: one that runs all B x C lanes through
+    its layers costs the same whatever the plan handed out; one that
+    computes a slot's further lanes only where it has any, a slot at a
+    time, grows with the slots that prefill (`chunk_prefilling_slots` in
+    `engine_stats()`), and past some number of them costs more than all
+    lanes at once would: the latent-attention family at Kanana's widths,
+    32 slots and chunks of 128, takes 43.5 ms + 15.8 ms a slot that
+    prefills where all lanes took 310, so 17 slots at once (`benchmarks/
+    kanana_chunk_lanes.py` has the table; PERF.md §7).
 
     The next token of every slot is chosen on the device
     (`serve/sampling.select_tokens`) and stays there as the next step's
@@ -509,6 +520,13 @@ class LLMEngine:
         # lane-steps run for a request its EOS had already ended
         self.overrun_lane_steps = 0
         self.chunk_steps = 0           # steps that ran the chunked program
+        # what the plan handed those steps: the lanes that were a token's,
+        # and the slots that had more than one (a family whose chunk program
+        # computes a slot's further lanes only where there are any ran
+        # chunk_steps x B + chunk_prefilling_slots x (C - 1) lanes for them,
+        # one that computes every lane chunk_steps x B x C)
+        self.chunk_tokens = 0
+        self.chunk_prefilling_slots = 0
         self.tokens_prefilled = 0      # prompt tokens processed
         # positions the steps' lanes attended over: each lane's last
         # position in its step, summed (what a cache of rows is read for)
@@ -933,6 +951,8 @@ class LLMEngine:
                     if not decoding[i]:
                         tokens[i, :take] = self._slot_prefill[i][:take]
                 active = lengths > 0
+                self.chunk_tokens += int(lengths.sum())
+                self.chunk_prefilling_slots += int((lengths > 1).sum())
             else:
                 lengths = active = decoding
             lanes, prompts, last_prompts, snapshots = [], [], [], []
@@ -1127,6 +1147,8 @@ class LLMEngine:
                 "steps_dispatched_ahead": self.steps_dispatched_ahead,
                 "overrun_lane_steps": self.overrun_lane_steps,
                 "chunk_steps": self.chunk_steps,
+                "chunk_tokens": self.chunk_tokens,
+                "chunk_prefilling_slots": self.chunk_prefilling_slots,
                 "tokens_prefilled": self.tokens_prefilled,
                 "positions_attended": self.positions_attended,
                 "prefix_imports": self.prefix_imports,

@@ -51,8 +51,9 @@ def test_the_tiny_preset_is_the_model_the_reference_is_given():
     assert tiny() == deepseek.DeepseekConfig.preset("deepseek-tiny")
 
 
-def engine(compute=F32, **kwargs):
-    eng = LLMEngine(preset="deepseek-tiny", max_batch=3, max_seq_len=96,
+def engine(compute=F32, max_batch=3, **kwargs):
+    eng = LLMEngine(preset="deepseek-tiny", max_batch=max_batch,
+                    max_seq_len=96,
                     seed=SEED, model_overrides=dict(compute), kv_blocks=12,
                     kv_block_size=8, prefill_chunk_size=16, **kwargs)
     eng.shutdown()              # the loop: the programs are driven by hand
@@ -163,6 +164,165 @@ def test_other_slots_and_the_padding_lanes_leave_no_trace():
     np.testing.assert_array_equal(
         np.asarray(eng.cache["latent"][:, 1, :len(PROMPT)]),
         np.asarray(eng.cache["latent"][:, 2, :len(PROMPT)]))
+    # a chunk step with lanes for slot 2 alone: an inactive slot that was
+    # handed tokens and an active one of no length keep every row, bit for
+    # bit, and so do slot 2's positions past its three lanes
+    before = {k: np.asarray(eng.cache[k]).copy()
+              for k in deepseek.CACHE_TOKEN_AXIS}
+    at = len(PROMPT) + 3
+    tokens = np.random.default_rng(1).integers(0, 512, (3, 16)).astype(
+        np.int32)
+    _, eng.cache = eng._chunk_step(
+        eng.params, eng.cache, tokens, np.array([5, 7, at], np.int32),
+        np.array([9, 0, 3], np.int32), np.array([False, True, True]))
+    for name, was in before.items():
+        now = np.asarray(eng.cache[name])
+        np.testing.assert_array_equal(now[:, :2], was[:, :2])
+        np.testing.assert_array_equal(now[:, 2, :at], was[:, 2, :at])
+        np.testing.assert_array_equal(now[:, 2, at + 3:], was[:, 2, at + 3:])
+        assert not np.array_equal(now[:, 2, at:at + 3], was[:, 2, at:at + 3])
+
+
+# ------------------------------- a chunk step computes the plan's lanes only
+
+# slot: (tokens before the step, the step's lanes, active)
+MIXED = [(12, 0, True), (9, 1, True), (20, 5, True), (0, 16, True),
+         (4, 7, False), (30, 16, True)]
+
+
+@pytest.fixture(scope="module")
+def mixed_step():
+    """One chunk step whose slots have 0, 1, 5, 16, (inactive) 7 and 16
+    lanes, three of them with lanes past the first at once, from a cache
+    that holds each slot's tokens before; and the same lanes a token at a
+    time through the decode program from a copy of that cache."""
+    B, C = len(MIXED), 16
+    eng = engine(max_batch=B)
+    rng = np.random.default_rng(7)
+    rows = [rng.integers(0, 512, before + lanes).tolist()
+            for before, lanes, _ in MIXED]
+    pos0 = np.array([before for before, _, _ in MIXED], np.int32)
+    length = np.array([lanes for _, lanes, _ in MIXED], np.int32)
+    active = np.array([on for _, _, on in MIXED])
+
+    def decode(cache, at, on):
+        """Each slot's token at position `at` (any, where it is not `on`)."""
+        tokens = np.array([row[min(p, len(row) - 1)] if row else 0
+                           for row, p in zip(rows, at)], np.int32)
+        return eng._step(eng.params, cache, tokens, at.astype(np.int32), on)
+
+    # what each slot holds before the step: a token at a time
+    for j in range(int(pos0.max())):
+        _, eng.cache = decode(eng.cache, np.minimum(j, pos0), j < pos0)
+    start = jax.tree.map(lambda a: np.asarray(a).copy(), eng.cache)
+
+    tokens = np.zeros((B, C), np.int32)
+    for b, row in enumerate(rows):
+        tokens[b, :length[b]] = row[pos0[b]:]
+    tokens[4] = 3                                 # an inactive slot's are read
+    logits, after = eng._chunk_step(eng.params, jax.tree.map(jnp.asarray,
+                                                             start),
+                                    tokens, pos0, length, active)
+    by_step = jax.tree.map(jnp.asarray, start)
+    last = np.zeros((B, 512), np.float32)
+    first = None
+    for j in range(C):
+        on = active & (j < length)
+        out, by_step = decode(by_step, pos0 + np.minimum(j, length), on)
+        first = np.asarray(out) if j == 0 else first
+        last[on] = np.asarray(out)[on]
+    valid = [b for b in range(B) if active[b] and length[b]]
+    return dict(eng=eng, rows=rows, pos0=pos0, length=length, active=active,
+                valid=valid, start=start, logits=np.asarray(logits),
+                after=jax.tree.map(np.asarray, after), first=first,
+                last=last, by_step=jax.tree.map(np.asarray, by_step))
+
+
+def reference_walk(cfg, row):
+    """The reference over one row: (final hidden -> logits [T, V], what each
+    expert layer's router chose [T, K])."""
+    key = jax.random.key(SEED)
+    x = deepseek.init_ends(key, cfg)["wte"][jnp.asarray(row)].astype(
+        jnp.float32)
+    chosen = []
+    for l in range(cfg.n_layer):
+        x, experts = kanana.reference_layer(
+            x, deepseek.init_layer(key, l, cfg), MODEL)
+        if experts is not None:
+            chosen.append(np.asarray(experts))
+    return np.asarray(kanana.reference_head(
+        x, deepseek.init_ends(key, cfg), MODEL)), chosen
+
+
+def test_a_mixed_chunk_step_gives_the_references_logits_and_the_decode_programs_cache(  # noqa: E501
+        mixed_step):
+    m = mixed_step
+    for b in m["valid"]:
+        want, _ = reference_walk(m["eng"].cfg, m["rows"][b])
+        assert np.abs(m["logits"][b] - want[-1]).max() <= \
+            FLOAT32_LOGIT_TOLERANCE, b
+        np.testing.assert_allclose(m["logits"][b], m["last"][b], atol=2e-6)
+    for name in deepseek.CACHE_TOKEN_AXIS:
+        np.testing.assert_allclose(m["after"][name], m["by_step"][name],
+                                   atol=2e-6)
+        for b in m["valid"]:                       # and they were written
+            at = slice(m["pos0"][b], m["pos0"][b] + m["length"][b])
+            assert np.abs(m["after"][name][:, b, at]).max(axis=-1).all()
+
+
+def test_a_slot_of_one_lane_rides_a_chunk_step_as_the_decode_program_takes_it(
+        mixed_step):
+    """Slot 1's one lane: the logits and the rows `decode_step` gives on the
+    same cache, bit for bit."""
+    m = mixed_step
+    np.testing.assert_array_equal(m["logits"][1], m["first"][1])
+    for name in deepseek.CACHE_TOKEN_AXIS:
+        np.testing.assert_array_equal(m["after"][name][:, 1],
+                                      m["by_step"][name][:, 1])
+
+
+def test_a_mixed_chunk_step_leaves_what_it_was_not_handed(mixed_step):
+    m = mixed_step
+    for name in deepseek.CACHE_TOKEN_AXIS:
+        was, now = m["start"][name], m["after"][name]
+        for b in range(len(MIXED)):
+            end = m["pos0"][b] + (m["length"][b] if b in m["valid"] else 0)
+            np.testing.assert_array_equal(now[:, b, :m["pos0"][b]],
+                                          was[:, b, :m["pos0"][b]])
+            np.testing.assert_array_equal(now[:, b, end:], was[:, b, end:])
+        for b in (0, 4):             # no length; inactive with seven lanes
+            np.testing.assert_array_equal(now[:, b], was[:, b])
+
+
+def test_the_chunk_programs_counts_are_over_the_lanes_the_plan_handed_out(
+        mixed_step):
+    m = mixed_step
+    cfg = m["eng"].cfg
+    E, K = cfg.n_experts, cfg.experts_per_token
+    given = np.zeros((cfg.n_layer - cfg.n_dense_layer, E), np.int64)
+    attended = touched_a_slot = 0
+    for b in m["valid"]:
+        _, chosen = reference_walk(cfg, m["rows"][b])
+        for l, experts in enumerate(chosen):
+            own = np.bincount(experts[m["pos0"][b]:].reshape(-1), minlength=E)
+            given[l] += own
+            touched_a_slot += int((own > 0).sum())
+        attended += sum(range(m["pos0"][b] + 1,
+                              m["pos0"][b] + m["length"][b] + 1))
+    lanes = int(m["length"][m["valid"]].sum())
+    assert lanes == 1 + 5 + 16 + 16
+    moved = (m["after"]["counts"].astype(np.int64)
+             - m["start"]["counts"].astype(np.int64))
+    assert moved[0].tolist() == [0] * 5       # the decode program's row
+    assert dict(zip(deepseek.COUNTS, moved[1].tolist())) == {
+        "expert_rows": lanes * K * len(given),
+        "experts_touched": int((given > 0).sum()),
+        "busiest_expert_rows": int(given.max(axis=1).sum()),
+        "expert_layer_steps": len(given),
+        "attended_positions": attended}
+    # over the union of the step's lanes, not slot by slot
+    assert int((given > 0).sum()) < touched_a_slot
+
 
 
 # ------------------------------------------------------- latent attention
@@ -311,8 +471,9 @@ def test_the_expert_layer_is_the_dense_sum_over_all_experts_plus_the_shared():
     cfg = tiny(**F32)
     bp = one_layer(cfg)
     x = jax.random.normal(jax.random.key(7), (2, 9, cfg.d_model))
-    got, counts = deepseek._expert_mlp(x, bp, cfg, jnp.zeros((4,), jnp.uint32),
-                                       jnp.ones((2, 9), bool))
+    got, given = deepseek._expert_mlp(x, bp, cfg, jnp.zeros((8,), jnp.int32),
+                                      jnp.ones((2, 9), bool))
+    counts = deepseek._expert_counts(given)
     h = kanana._rms_norm(x.reshape(18, -1), bp["mlp_norm"]["scale"],
                          cfg.norm_eps)
     _, _, gates, experts = moe._route(h, bp["moe"]["router"], cfg,
@@ -334,9 +495,9 @@ def test_nothing_is_dropped_when_every_token_wants_the_same_experts():
     skew = {**bp, "moe": {**bp["moe"], "bias": jnp.array(
         [5., 5., 5., 0, 0, 0, 0, 0])}}
     x = jax.random.normal(jax.random.key(8), (1, 40, cfg.d_model))
-    got, counts = deepseek._expert_mlp(
-        x, skew, cfg, jnp.zeros((4,), jnp.uint32), jnp.ones((1, 40), bool))
-    rows, touched, busiest, _ = counts.tolist()
+    got, given = deepseek._expert_mlp(
+        x, skew, cfg, jnp.zeros((8,), jnp.int32), jnp.ones((1, 40), bool))
+    rows, touched, busiest, _ = deepseek._expert_counts(given).tolist()
     assert (rows, touched, busiest) == (120, 3, 40)
     h = kanana._rms_norm(x[0], bp["mlp_norm"]["scale"], cfg.norm_eps)
     s = jax.nn.sigmoid(h @ bp["moe"]["router"])[:, :3]
@@ -543,6 +704,38 @@ def test_a_chunk_of_one_token_counts_as_a_chunk_and_a_failed_read_is_raised():
     eng.cache = {"counts": None}        # a renamed or broken leaf
     with pytest.raises(TypeError):
         eng.engine_stats()
+
+
+@pytest.mark.parametrize("preset", ["deepseek-tiny", "brumby-tiny"],
+                         ids=["rows", "state"])
+def test_the_engine_counts_the_lanes_its_plan_hands_the_chunk_steps(preset):
+    """`chunk_tokens`: the lanes of the chunk steps that were a token's, a
+    decode lane riding along among them; `chunk_prefilling_slots`: the
+    slots that had more than one. A prompt's last token alone is a chunk
+    step and no such slot."""
+    eng = LLMEngine(preset=preset, max_batch=2, max_seq_len=96, seed=SEED,
+                    prefill_chunk_size=8, enable_prefix_caching=False)
+
+    def counted():
+        stats = eng.engine_stats()
+        return tuple(stats[k] for k in ("chunk_steps", "chunk_tokens",
+                                        "chunk_prefilling_slots"))
+
+    try:
+        assert counted() == (0, 0, 0)
+        eng.generate(prompt_ids=list(range(3, 20)), max_tokens=3)
+        assert counted() == (3, 17, 2)                # 8, 8 and 1 lanes
+        # ten tokens beside a slot that decodes: 1 + 8, then 1 + 2
+        sid = eng.start_stream(prompt_ids=[5, 6, 7, 8, 9], max_tokens=80)
+        while not eng.stream_next(sid, timeout=30.0)["token_ids"]:
+            pass
+        assert counted() == (4, 22, 3)
+        eng.generate(prompt_ids=list(range(30, 40)), max_tokens=2)
+        assert not eng._streams[sid][0].done.is_set()
+        assert counted() == (6, 34, 5)
+        assert eng.engine_stats()["tokens_prefilled"] == 17 + 5 + 10
+    finally:
+        eng.shutdown()
 
 
 def test_gpt2s_stats_gain_the_gauge_and_no_counter():
