@@ -4,11 +4,13 @@ one-hot dispatch under expert parallelism), deepseek (DeepSeek-V3's layer
 for serving: latent attention over a latent cache, shared experts), brumby
 (Brumby's layer for serving: power retention over a recurrent state),
 granite (Granite 4.0-H's layers for serving: Mamba-2 state a slot beside
-grouped-head keys and values a token, in one cache)."""
+grouped-head keys and values a token, in one cache), kimi (Kimi Linear's
+layers for serving: delta-rule state a slot beside un-rotated latent rows a
+token, and a share of each layer's routed experts)."""
 
 from ray_tpu.models import gpt2
 
-__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite",
+__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi",
            "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
@@ -23,14 +25,15 @@ __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite",
 # recurrent state and have no token axis (a prefix leaves the state at its
 # end behind, which the pool keeps as a snapshot; a slot is zeroed when a
 # request is placed in it, and a step leaves an inactive slot's state as it
-# was). A family may name both kinds (granite: the pool then keeps, under
+# was). A family may name both kinds (granite, kimi: the pool then keeps, under
 # one hash, a prefix's rows by the block and the state at its end, and a hit
 # needs both). A leaf neither names is the programs' own (`counts`).
 _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "kanana": ("deepseek", "DeepseekConfig"),
             "deepseek": ("deepseek", "DeepseekConfig"),
             "brumby": ("brumby", "BrumbyConfig"),
-            "granite": ("granite", "GraniteConfig")}
+            "granite": ("granite", "GraniteConfig"),
+            "kimi": ("kimi", "KimiConfig")}
 
 
 def serving_family(preset: str):
@@ -47,7 +50,7 @@ def serving_family(preset: str):
 
 
 def __getattr__(name):
-    if name in ("llama", "moe", "deepseek", "brumby", "granite"):
+    if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

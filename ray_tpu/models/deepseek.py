@@ -357,11 +357,13 @@ def _swiglu(h, p, cfg: DeepseekConfig):
 
 
 def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
-               slot=None):
+               slot=None, rope: bool = True):
     """x [N,C,D] float32 += absorbed attention of its C lanes (positions
     `pos` [N,C], written where `ok`) against layer l of the carried
     caches: row n is slot n (N = B), or the one row is `slot`'s own lanes
-    against that slot's rows alone."""
+    against that slot's rows alone. Without `rope` the p lanes go
+    un-rotated, a shared key that knows no position (Kimi Linear's
+    `mla_use_nope`; `models/kimi.py` is held to this form)."""
     B, C, _ = x.shape
     H, r = cfg.n_head, cfg.kv_lora_rank
     n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -373,10 +375,13 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
             q = jnp.einsum("bcd,dhk->bchk", h, _w(p["wq"], cfg))
             ckr = h @ _w(p["wkva"], cfg)                          # [N,C,r+p]
             c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
-            cos, sin = rope_freqs(pos, cfg.qk_rope_head_dim, cfg.rope_theta)
-            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-            q_rope = apply_rope(q[..., n:], cos, sin)             # [N,C,H,p]
-            k_r = apply_rope(ckr[..., None, r:], cos, sin)[:, :, 0]
+            q_rope, k_r = q[..., n:], ckr[..., r:]                # [N,C,H,p]
+            if rope:
+                cos, sin = rope_freqs(pos, cfg.qk_rope_head_dim,
+                                      cfg.rope_theta)
+                cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+                q_rope = apply_rope(q_rope, cos, sin)
+                k_r = apply_rope(k_r[:, :, None], cos, sin)[:, :, 0]
             wkvb = _w(p["wkvb"], cfg)
             q_abs = jnp.einsum("bchn,rhn->bchr", q[..., :n], wkvb[..., :n])
         with jax.named_scope("kv_update"):

@@ -1,0 +1,974 @@
+"""Kimi Linear's layers for serving: Kimi Delta Attention (KDA) over a
+recurrent state beside latent attention (MLA) without positions, then
+routed experts of which this chip may hold a share.
+
+What is served is `moonshotai/Kimi-Linear-48B-A3B-Instruct` (`model_type:
+kimi_linear`; preset `kimi-linear-48b-a3b`): 27 layers counted from 1, MLA
+at `full_attn_layers` (4, 8, .., 24, 27) and KDA elsewhere; layer 1's MLP
+is dense, every other layer's the expert block. With d the hidden size, eps
+1e-5, no bias but the output gate's:
+
+    x += mixer(RMSNorm(x));  x += mlp(RMSNorm(x))
+    final RMSNorm, untied head, logits float32
+
+    KDA (32 heads of 128 lanes), u the normed input:
+      q, k, v = silu(conv1d_causal_4(u W_q | W_k | W_v)), depthwise over
+        the last 4 positions; q, k L2-normalised a head; q * 128^-1/2
+      a = exp(-exp(A_log_h) softplus((u W_f1) W_f2 + dt_bias)) in (0,1)^128
+        a head: a decay a key channel;  b = sigmoid(u W_b), a head
+      S~ = Diag(a_t) S_{t-1};  S_t = S~ + b_t k_t (v_t - S~^T k_t)^T
+      o_t = S_t^T q_t;   y = (RMSNorm_128(o_t) * sigmoid((u W_g1) W_g2 +
+        c_g)) W_o
+
+    MLA (`models/deepseek.py`'s docstring, `mla_use_nope`: no rotation):
+      q = u W_q -> [32, 128 + 64];  [c, k_r] = u W_kva -> 512 + 64;
+      c = RMSNorm(c); absorbed form over a cache of 576 values a token;
+      the 64 lanes stay as a shared un-rotated key
+
+    experts (`models/moe.py`): s = sigmoid(h W_g) over all E = 256, the 8
+      largest of s + b chosen, gates s / (sum + 1e-20) * 2.446;
+      x += sum_k g_k SwiGLU_1024^(e_k)(h) + SwiGLU_1024^shared(h)
+
+**The chip's share.** `experts_held` E' and `first_expert` say which of the
+E experts of every expert layer this replica holds: the router keeps its E
+outputs and its 8 a token, `moe._experts` computes the held experts' part of
+the sum for the (token, slot) pairs routed to them, and what the absent
+experts would have added is left out: nothing stands in for the other
+chips. `vocab_size` rows of the table and the head are this chip's slice.
+The held experts of all expert layers are one stack `[layers x E', d, F]`
+that no layers' loop slices: a layer hands `_experts` the whole stack with
+its ids offset by the layer (and the pairs of absent experts sent past the
+stack's end), so no program copies an expert matrix (ROADMAP S12a).
+
+The cache holds both kinds of leaf (`models/__init__.py`): `kda` [KDA
+layers, slots, 32, 128, 128] and `conv` [KDA layers, slots, 3 x 12288] a
+slot's state, float32 (`CACHE_STATE`: S in `ops/kda_update.py`'s layout and
+the last three inputs of the convolutions of q, k and v side by side), and
+`latent` / `k_rope` [MLA layers, slots, T, 512 | 64] a value a token
+(`CACHE_TOKEN_AXIS`), and `counts`, the programs' own.
+
+The mixers exist in two forms and no third (`models/granite.py`). The
+recurrence, one token a slot through the kernel `kda_update`, is
+`decode_step` whole and, in `prefill_chunk`, every slot's first lane. A
+chunk's further lanes go, a slot at a time and only for the slots that
+prefill, through the chunked form of the delta rule, M lanes after state
+S_0, with g_i = sum_{m<=i} log a_m a channel:
+
+    A_ij = b_i sum_c k_ic k_jc exp(g_ic - g_jc), j < i
+    (I + A) U = b * (V - (K * exp(g)) S_0)
+    o_i = S_0^T (q_i * exp(g_i)) + sum_{j<=i} (sum_c q_ic k_jc
+          exp(g_ic - g_jc)) u_j
+    S_M = Diag(exp(g_M)) S_0 + sum_j (k_j * exp(g_M - g_j)) u_j^T
+
+in runs of `SUBCHUNK` lanes, the state handed from run to run. Differences
+of g are taken before the exponential (a quotient of two cumulative
+products overflows under strong decay); the unit-lower-triangular solve is
+(I - N)^-1 = (I + N)(I + N^2)(I + N^4)(I + N^8), exact for the nilpotent
+N = -A of 16 rows. A lane past a slot's length has a = 1, b = 0: it decays
+nothing and writes nothing.
+
+The weights exist only in the dtype the replica holds them; float32 are the
+norms' scales, the convolution, `dt_bias`, `A_log`, W_b, the gate's bias,
+the router and its bias, and so are the residual stream, everything
+projected, the decay, the state, its update and read-out, the router and
+the logits. A product's operands are bf16, the weight as it is held and the
+activation as the two bf16 pieces that add up to it (`_dot`, and
+`moe._experts` for float32 rows); the latent rows and attention's weights
+go as one piece.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import types
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import moe as _moe
+from ray_tpu.models.deepseek import _cache_write, _rows
+from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.kda_update import kda_update
+
+Params = Any
+_HIGHEST = lax.Precision.HIGHEST
+SUBCHUNK = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiConfig:
+    vocab_size: int = 163840
+    n_layer: int = 27
+    mla_layers: tuple = (4, 8, 12, 16, 20, 24, 27)   # counted from 1
+    n_dense_layer: int = 1           # first_k_dense_replace
+    d_model: int = 2304
+    d_ff: int = 9216                 # the dense layer's SwiGLU
+    d_ff_expert: int = 1024
+    n_experts: int = 256             # what the router scores
+    experts_held: int = 256          # E': what this replica holds of them
+    first_expert: int = 0
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    router_scoring: str = "sigmoid"
+    routed_scaling_factor: float = 2.446
+    n_head: int = 32                 # MLA
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_conv: int = 4                # short_conv_kernel_size
+    kda_rank: int = 128              # the two gates' low rank (assumed)
+    norm_eps: float = 1e-5
+    max_seq_len: int = 1048576
+    dtype: Any = jnp.bfloat16        # compute
+    param_dtype: Any = jnp.bfloat16  # what the replica holds
+
+    def __post_init__(self):
+        object.__setattr__(self, "mla_layers", tuple(self.mla_layers))
+        assert all(1 <= l <= self.n_layer for l in self.mla_layers)
+        assert (0 <= self.first_expert
+                and self.first_expert + self.experts_held <= self.n_experts)
+
+    @property
+    def layer_types(self) -> tuple:
+        return tuple("mla" if l + 1 in self.mla_layers else "kda"
+                     for l in range(self.n_layer))
+
+    def layers_of(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    @property
+    def n_expert_layer(self) -> int:
+        return self.n_layer - self.n_dense_layer
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Values a token leaves in the cache, an MLA layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @classmethod
+    def preset(cls, name: str, **overrides) -> "KimiConfig":
+        return cls(**{**PRESETS[name], **overrides})
+
+
+PRESETS = {
+    # moonshotai/Kimi-Linear-48B-A3B-Instruct config.json: the defaults
+    "kimi-linear-48b-a3b": dict(),
+    "kimi-tiny": dict(
+        vocab_size=512, n_layer=5, mla_layers=(3, 5), n_dense_layer=1,
+        d_model=64, d_ff=128, d_ff_expert=32, n_experts=8, experts_held=8,
+        experts_per_token=3, n_head=4, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, kda_heads=2, kda_head_dim=16,
+        kda_rank=8, max_seq_len=128),
+}
+
+# the serving contract (`models/__init__.py`): the latent and the shared key
+# hold a value a token, along axis 2; the delta-rule state and the
+# convolutions' window hold a slot's state, with no token axis
+CACHE_TOKEN_AXIS = {"latent": 2, "k_rope": 2}
+CACHE_STATE = ("kda", "conv")
+
+# the columns of the cache's `counts` leaf, each a sum over a program's
+# executions (`deepseek.COUNTS`, whose first five these are): over the
+# expert layers, the (lane, expert) rows the experts held here were given
+# for valid lanes, the held experts that got at least one, the most that
+# one of them got, and 1; once a step the positions the valid lanes attend
+# to; and, over the expert layers again, all the valid lanes' (lane,
+# expert) pairs, held or not: 8 a lane
+COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
+          "expert_layer_steps", "attended_positions", "expert_rows_all")
+
+
+# ---------------------------------------------------------------------------
+# Weights, a layer at a time
+# ---------------------------------------------------------------------------
+
+# The seeded weights' spreads. Every matrix N(0, 0.02) and every down
+# projection 0.02 / sqrt(2 n_layer), as a fresh Hugging Face model; the
+# token table 0.3 and the selection bias 0.02 by `models/deepseek.py`'s
+# argument (with the table at 0.02 the stream is a fifth of what the first
+# layers add to it and any rounding becomes another expert for some token;
+# the head is untied, so granite's lesson on a tied table does not apply).
+# The KDA layer's own as the public reference layer initialises them:
+# A = U(1, 16) a head, dt = exp(U(log 0.001, log 0.1)) a channel through the
+# inverse of softplus into `dt_bias`, the depthwise convolution
+# U(-1/2, 1/2) (a window of 4). With the projection's part added the decay's
+# rate lies about 0.001 to 1.6 a token: a memory of one to a thousand
+# tokens, so that a fault in carrying state across chunks, snapshots and
+# slots cannot hide.
+EMBED_STD, ROUTER_BIAS_STD = 0.3, 0.02
+A_RANGE = (1.0, 16.0)
+DT_RANGE = (0.001, 0.1)
+
+
+def _normal(key, shape, std, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _ones(n):
+    return {"scale": jnp.ones((n,), jnp.float32)}
+
+
+def _swiglu_params(key, cfg: KimiConfig, width: int) -> Params:
+    k_in, k_out = jax.random.split(key)
+    pd, D = cfg.param_dtype, cfg.d_model
+    return {"w_in": _normal(k_in, (D, 2 * width), 0.02, pd),
+            "w_out": _normal(k_out, (width, D),
+                             0.02 / math.sqrt(2 * cfg.n_layer), pd)}
+
+
+def _kda_params(key, cfg: KimiConfig) -> Params:
+    ks = jax.random.split(key, 10)
+    pd, D, I = cfg.param_dtype, cfg.d_model, cfg.kda_inner
+    H, R, K = cfg.kda_heads, cfg.kda_rank, cfg.kda_conv
+    dt = jnp.exp(jax.random.uniform(ks[0], (I,), jnp.float32,
+                                    math.log(DT_RANGE[0]),
+                                    math.log(DT_RANGE[1])))
+    edge = 1.0 / math.sqrt(K)
+    return {
+        # W_q, W_k, W_v side by side: one product, one convolution
+        "w_qkv": _normal(ks[1], (D, 3 * I), 0.02, pd),
+        # tap k of the window multiplies the input 3 - k positions back
+        "conv_w": jax.random.uniform(ks[2], (K, 3 * I), jnp.float32,
+                                     -edge, edge),
+        # the three narrow projections of u side by side: the decay gate's
+        # and the output gate's low ranks and b's H columns (padded to whole
+        # lane tiles; as [D, H] float32 alone they cost a decode step 0.57
+        # ms a layer, a tenth of it: PERF.md, PR 40): one product
+        "w_fgb": jnp.concatenate([
+            _normal(ks[3], (D, R), 0.02, pd), _normal(ks[7], (D, R), 0.02, pd),
+            _normal(ks[6], (D, H), 0.02, pd),
+            jnp.zeros((D, -H % 128), pd)], axis=1),
+        "w_f2": _normal(ks[4], (R, I), 0.02, pd),
+        # softplus(dt_bias) = dt
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jax.random.uniform(ks[5], (H,), jnp.float32,
+                                            *A_RANGE)),
+        "w_g2": _normal(ks[8], (R, I), 0.02, pd),
+        "g_bias": jnp.zeros((I,), jnp.float32),
+        "o_norm": _ones(cfg.kda_head_dim),
+        "w_o": _normal(ks[9], (I, D), 0.02, pd)}
+
+
+def _mla_params(key, cfg: KimiConfig) -> Params:
+    ks = jax.random.split(key, 4)
+    pd, D, H = cfg.param_dtype, cfg.d_model, cfg.n_head
+    return {"wq": _normal(ks[0], (D, H * cfg.qk_head_dim), 0.02, pd),
+            "wkva": _normal(ks[1], (D, cfg.cache_width), 0.02, pd),
+            "kv_norm": _ones(cfg.kv_lora_rank),
+            # W_kvb by head, its two halves apart as the absorbed form
+            # multiplies them: [H, n, r] into the query, [H, r, v] out of
+            # the weighted latents
+            "w_uk": _normal(ks[2], (H, cfg.qk_nope_head_dim,
+                                    cfg.kv_lora_rank), 0.02, pd),
+            "w_uv": _normal(jax.random.fold_in(ks[2], 1),
+                            (H, cfg.kv_lora_rank, cfg.v_head_dim), 0.02, pd),
+            "wo": _normal(ks[3], (H * cfg.v_head_dim, D), 0.02, pd)}
+
+
+def _expert_params(key, cfg: KimiConfig) -> Params:
+    """The held experts' matrices: expert e's from `fold_in(key, e)` and
+    nothing else, so that every share of a layer holds the same expert
+    e."""
+    pd, D, F = cfg.param_dtype, cfg.d_model, cfg.d_ff_expert
+    resid_std = 0.02 / math.sqrt(2 * cfg.n_layer)
+
+    def one(e):
+        ks = jax.random.split(jax.random.fold_in(key, e), 3)
+        return {"wg": _normal(ks[0], (D, F), 0.02, pd),
+                "wu": _normal(ks[1], (D, F), 0.02, pd),
+                "wd": _normal(ks[2], (F, D), resid_std, pd)}
+
+    # a loop, not `vmap`: one expert's three matrices are the program, which
+    # compiles in a fifth of the time of all 64 side by side (8 s of a cold
+    # replica's start a kind of layer)
+    return lax.map(one, cfg.first_expert + jnp.arange(cfg.experts_held))
+
+
+def _init_layer(key: jax.Array, l, cfg: KimiConfig, kind: str,
+                dense: bool) -> Params:
+    ks = jax.random.split(jax.random.fold_in(key, l), 6)
+    D, E = cfg.d_model, cfg.n_experts
+    out = {kind: {"norm": _ones(D),
+                  **(_mla_params if kind == "mla" else _kda_params)(ks[0],
+                                                                    cfg)}}
+    if dense:
+        out["dense"] = {"norm": _ones(D),
+                        **_swiglu_params(ks[1], cfg, cfg.d_ff)}
+        return out
+    out["moe"] = {
+        "norm": _ones(D),
+        "router": _normal(ks[2], (D, E), 0.02, jnp.float32),
+        "bias": _normal(ks[3], (E,), ROUTER_BIAS_STD, jnp.float32),
+        "shared": _swiglu_params(ks[4], cfg,
+                                 cfg.n_shared_experts * cfg.d_ff_expert)}
+    out["experts"] = _expert_params(ks[5], cfg)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_program(cfg: KimiConfig, kind: str, dense: bool):
+    return jax.jit(lambda key, l: _init_layer(key, l, cfg, kind, dense))
+
+
+def init_layer(key: jax.Array, l: int, cfg: KimiConfig) -> Params:
+    """Layer l's weights (l from 0) from `fold_in(key, l)` and nothing
+    else: its mixer under `kda` or `mla`, its MLP under `dense` or under
+    `moe` (router, bias, shared expert) and `experts` (the held experts'
+    [E', ...]). One compiled program a kind of layer makes them wherever
+    they are made, so a layer made alone is, to the bit, the layer in
+    `init_params`' tree."""
+    return _layer_program(cfg, cfg.layer_types[l], l < cfg.n_dense_layer)(
+        key, jnp.int32(l))
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def init_ends(key: jax.Array, cfg: KimiConfig) -> Params:
+    """What is not a layer: the table, the final norm and the untied head,
+    from `fold_in(key, cfg.n_layer)`."""
+    k_emb, k_head = jax.random.split(jax.random.fold_in(key, cfg.n_layer))
+    pd, D, V = cfg.param_dtype, cfg.d_model, cfg.vocab_size
+    return {"wte": _normal(k_emb, (V, D), EMBED_STD, pd),
+            "final_norm": _ones(D),
+            "lm_head": _normal(k_head, (D, V), 0.02, pd)}
+
+
+def _stack_index(cfg: KimiConfig) -> list:
+    """For each layer, where its parts lie: {part: index in that part's
+    stack}."""
+    seen: dict = {}
+    out = []
+    for l, kind in enumerate(cfg.layer_types):
+        parts = [kind] + (["dense"] if l < cfg.n_dense_layer
+                          else ["moe", "experts"])
+        at = {}
+        for part in parts:
+            at[part] = seen.get(part, 0)
+            seen[part] = at[part] + 1
+        out.append(at)
+    return out
+
+
+def init_params(key: jax.Array, cfg: KimiConfig) -> Params:
+    """The whole tree, every leaf made in the dtype it is held in: `kda`,
+    `mla`, `dense` and `moe`, one stack a part on a leading axis in the
+    order the layers have, and `experts` [expert layers x E', ...], the
+    held experts of every expert layer end to end. A stack is allocated
+    once and each layer's program writes its layer into it (donated), so
+    the most that exists beside the tree is one layer."""
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def put(stack, part, i):
+        def into(s, a):
+            a = a.reshape((-1,) + s.shape[1:])
+            return lax.dynamic_update_slice_in_dim(s, a, i * a.shape[0], 0)
+
+        return jax.tree.map(into, stack, part)
+
+    def empty(part: str, like: Params, layers: int) -> Params:
+        # `experts` comes [E', ...] a layer and lies [layers x E', ...]
+        def zeros(a):
+            shape = ((layers * a.shape[0],) + a.shape[1:]
+                     if part == "experts" else (layers,) + a.shape)
+            return jnp.zeros(shape, a.dtype)
+
+        return jax.jit(lambda: jax.tree.map(zeros, like))()
+
+    index = _stack_index(cfg)
+    layers_of = {part: 1 + max(at[part] for at in index if part in at)
+                 for part in set().union(*index)}
+    out = dict(init_ends(key, cfg))
+    for l, at in enumerate(index):
+        layer = init_layer(key, l, cfg)
+        for part, i in at.items():
+            if part not in out:
+                out[part] = empty(part, jax.eval_shape(lambda: layer[part]),
+                                  layers_of[part])
+            out[part] = put(out[part], layer[part], jnp.int32(i))
+        del layer
+    return out
+
+
+def resident_params(params: Params, cfg: KimiConfig) -> Params:
+    """`init_params` makes the tree a replica holds: nothing to convert."""
+    del cfg
+    return params
+
+
+def resident_specs(cfg: KimiConfig, rules=None) -> Params:
+    raise NotImplementedError(
+        "the kimi family is served on one chip, which holds its share of "
+        "the experts and of the vocabulary: its weights, its rows and its "
+        "state have no partition specs and the shares no exchange yet "
+        "(tensor_parallel_size > 1 is GPT-2's)")
+
+
+def num_params(cfg: KimiConfig) -> int:
+    """What this replica holds: the held experts and the vocabulary's
+    slice, not the published whole."""
+    D, I, R, H = cfg.d_model, cfg.kda_inner, cfg.kda_rank, cfg.kda_heads
+    kda = (D * 3 * I + cfg.kda_conv * 3 * I + D * (2 * R + H + -H % 128)
+           + 2 * R * I + 2 * I + H + cfg.kda_head_dim + I * D + D)
+    r, Hm = cfg.kv_lora_rank, cfg.n_head
+    mla = (D * Hm * cfg.qk_head_dim + D * cfg.cache_width + r
+           + r * Hm * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+           + Hm * cfg.v_head_dim * D + D)
+    F = cfg.d_ff_expert
+    moe = (D + D * cfg.n_experts + cfg.n_experts
+           + (cfg.experts_held + cfg.n_shared_experts) * 3 * D * F)
+    dense = D + 3 * D * cfg.d_ff
+    return (cfg.layers_of("kda") * kda + cfg.layers_of("mla") * mla
+            + cfg.n_dense_layer * dense + cfg.n_expert_layer * moe
+            + 2 * cfg.vocab_size * D + D)
+
+
+# ---------------------------------------------------------------------------
+# The cache
+# ---------------------------------------------------------------------------
+
+def init_cache(cfg: KimiConfig, batch: int, max_len: Optional[int] = None):
+    """{"kda" [KDA layers, B, H, 128, 128], "conv" [KDA layers, B, 3 x 3 H
+    128]} float32 (the three inputs of the convolutions of q, k and v side
+    by side on the lanes, as granite's window), zero, which is what a
+    sequence starts from; {"latent" [MLA layers, B, T, r], "k_rope" [MLA
+    layers, B, T, p]} in the compute dtype, `deepseek.init_cache`'s two
+    leaves; and `counts` uint32 [2, len(COUNTS)], the programs' own, row 0
+    `decode_step`'s and row 1 `prefill_chunk`'s (they wrap: a reader takes
+    differences modulo 2**32). `max_len` sizes the rows alone."""
+    T = max_len or cfg.max_seq_len
+    Lk, Lm = cfg.layers_of("kda"), cfg.layers_of("mla")
+    P = cfg.kda_head_dim
+    return {"kda": jnp.zeros((Lk, batch, cfg.kda_heads, P, P), jnp.float32),
+            "conv": jnp.zeros(
+                (Lk, batch, (cfg.kda_conv - 1) * 3 * cfg.kda_inner),
+                jnp.float32),
+            "latent": jnp.zeros((Lm, batch, T, cfg.kv_lora_rank), cfg.dtype),
+            "k_rope": jnp.zeros((Lm, batch, T, cfg.qk_rope_head_dim),
+                                cfg.dtype),
+            "counts": jnp.zeros((2, len(COUNTS)), jnp.uint32)}
+
+
+# ---------------------------------------------------------------------------
+# The layers
+# ---------------------------------------------------------------------------
+
+def _pieces(x, cfg: KimiConfig):
+    """x float32 -> [2, ...] in the compute dtype: its rounding and what the
+    rounding left. `reduce_precision`, not a pair of conversions, which are
+    the compiler's to remove (PERF.md, PR 29)."""
+    bits = jnp.finfo(cfg.dtype)
+    high = lax.reduce_precision(x, exponent_bits=bits.nexp,
+                                mantissa_bits=bits.nmant)
+    return jnp.stack([high, x - high]).astype(cfg.dtype)
+
+
+def _dot(x, w, cfg: KimiConfig):
+    """x [..., K] float32 times the weight w [K, N] -> [..., N] float32:
+    `granite._dot`. The operands are the compute dtype's, and x goes as the
+    two pieces that add up to it, side by side on the rows of one product:
+    one pass of the weight, and none of the activations' rounding in the
+    result (which alone would put the logits as far from the reference's as
+    a state held in bfloat16 does: PERF.md, PR 38)."""
+    x = x.astype(jnp.float32)
+    if cfg.dtype == jnp.float32:
+        return jnp.dot(x, w.astype(jnp.float32), precision=_HIGHEST)
+    both = jnp.dot(_pieces(x, cfg), w.astype(cfg.dtype),
+                   preferred_element_type=jnp.float32)
+    return both[0] + both[1]
+
+
+def _swiglu(h, p, cfg: KimiConfig):
+    ab = _dot(h, p["w_in"], cfg)
+    a, b = jnp.split(ab, 2, axis=-1)
+    return _dot(jax.nn.silu(a) * b, p["w_out"], cfg)
+
+
+def _layer_weights(stack: Params, i) -> Params:
+    """Entry i of a part's stack: its weights sliced where they lie."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
+
+
+def _over_lanes(per_head, cfg: KimiConfig):
+    """[..., H] -> [..., H P]: a head's value over its P lanes."""
+    return jnp.repeat(per_head, cfg.kda_head_dim, axis=-1)
+
+
+def _kda_in(u, p, cfg: KimiConfig):
+    """The norm's output u [B,M,D] float32 -> q, k and v side by side before
+    the convolution [B,M,3I], the log of the decay [B,M,I] (<= 0), b
+    [B,M,H] and the output gate [B,M,I], all float32."""
+    with jax.named_scope("kda_project"):
+        R, H = cfg.kda_rank, cfg.kda_heads
+        qkv = _dot(u, p["w_qkv"], cfg)
+        narrow = _dot(u, p["w_fgb"], cfg)
+        f = _dot(narrow[..., :R], p["w_f2"], cfg)
+        log_a = -_over_lanes(jnp.exp(p["a_log"]), cfg) * jax.nn.softplus(
+            f + p["dt_bias"])
+        b = jax.nn.sigmoid(narrow[..., 2 * R:2 * R + H])
+        gate = jax.nn.sigmoid(
+            _dot(narrow[..., R:2 * R], p["w_g2"], cfg) + p["g_bias"])
+        return qkv, log_a, b, gate
+
+
+def _conv(qkv, p, window, ok, cfg: KimiConfig):
+    """The causal depthwise convolution of qkv [B,M,F] behind `window`
+    [B, (K-1) F], the K - 1 inputs before it, then silu, and the window
+    left behind: the K - 1 inputs that end at each slot's last valid lane
+    (a slot with no valid lane keeps its window bit for bit)."""
+    K = cfg.kda_conv
+    B, M, F = qkv.shape
+    with jax.named_scope("kda_project"):
+        if M == 1:          # one lane: the window moves on by one input
+            ext = jnp.concatenate([window, qkv[:, 0]], axis=-1)   # [B, K F]
+            out = sum(p["conv_w"][k] * ext[:, k * F:(k + 1) * F]
+                      for k in range(K))
+            return (jax.nn.silu(out)[:, None],
+                    jnp.where(ok, ext[:, F:], window))
+        ext = jnp.concatenate([window.reshape(B, K - 1, F), qkv], axis=1)
+        out = sum(p["conv_w"][k] * ext[:, k:k + M] for k in range(K))
+        at = ok.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]   # [B,K-1]
+        new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+        new = jnp.where(ok.any(axis=1)[:, None],
+                        new.reshape(B, (K - 1) * F), window)
+        return jax.nn.silu(out), new
+
+
+def _heads(qkv, cfg: KimiConfig):
+    """The convolution's output [..., 3I] -> q, k, v [..., H, P]: q and k
+    of length 1 a head, q times P^-1/2."""
+    H, P = cfg.kda_heads, cfg.kda_head_dim
+    q, k, v = (t.reshape(*t.shape[:-1], H, P)
+               for t in jnp.split(qkv, 3, axis=-1))
+
+    def unit(t):
+        return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * P ** -0.5, unit(k), v
+
+
+def _kda_out(x, o, gate, p, cfg: KimiConfig):
+    """o [B,M,H,P]: the norm a head, the gate, W_o, the residual."""
+    B, M = o.shape[:2]
+    with jax.named_scope("kda_project"):
+        y = rms_norm(o, p["o_norm"], cfg.norm_eps).reshape(B, M, -1) * gate
+        return x + _dot(y, p["w_o"], cfg)
+
+
+def _kda_first(x, p, cfg: KimiConfig, cache, i, on):
+    """KDA layer `i` of the stack over every slot's first lane, x [B,1,D]
+    float32, by the recurrence: -> (x, cache). `on` [B]: the slots whose
+    lane is valid; the others keep their state and window bit for bit."""
+    H, P = cfg.kda_heads, cfg.kda_head_dim
+    B = x.shape[0]
+    with jax.named_scope("attn"):
+        qkv, log_a, b, gate = _kda_in(rms_norm(x, p["norm"], cfg.norm_eps),
+                                      p, cfg)
+        with jax.named_scope("kda_project"):
+            window = lax.dynamic_index_in_dim(cache["conv"], i, 0,
+                                              keepdims=False)
+        qkv, window = _conv(qkv, p, window, on[:, None], cfg)
+        with jax.named_scope("kda_project"):
+            conv = lax.dynamic_update_index_in_dim(cache["conv"], window, i,
+                                                   0)
+            q, k, v = _heads(qkv[:, 0], cfg)                     # [B, H, P]
+        with jax.named_scope("kda_update"):
+            a = jnp.exp(log_a[:, 0]).reshape(B, H, P)
+            kda, o = kda_update(cache["kda"], i, a, k, q, v, b[:, 0], on)
+        x = _kda_out(x, o[:, None], gate, p, cfg)
+    return x, {**cache, "kda": kda, "conv": conv}
+
+
+def _delta_chunk(q, k, v, log_a, b, s):
+    """The chunked form for m lanes of one slot: q, k, log_a [m,H,N], v
+    [m,H,P], b [m,H], the state s [H,N,P] before them -> (o [m,H,P], the
+    state after them). A lane that is not valid comes with log_a = 0 and
+    b = 0."""
+    m = q.shape[0]
+    g = jnp.cumsum(log_a, axis=0)                                  # [m,H,N]
+    lane = jnp.arange(m)
+    upto = (lane[None, :] <= lane[:, None])[:, :, None, None]     # [i,j,1,1]
+    # exp(g_i - g_j) for j <= i: the difference first, never a quotient
+    within = jnp.where(upto, jnp.exp(jnp.where(
+        upto, g[:, None] - g[None, :], 0.0)), 0.0)                 # [i,j,H,N]
+    kk = jnp.einsum("ihc,jhc,ijhc->hij", k, k, within, precision=_HIGHEST)
+    qk = jnp.einsum("ihc,jhc,ijhc->hij", q, k, within, precision=_HIGHEST)
+    strict = (lane[None, :] < lane[:, None])[None]
+    n = -jnp.where(strict, kk * b.T[:, :, None], 0.0)        # N = -A [H,m,m]
+    # (I + A)^-1 = (I + N)(I + N^2)(I + N^4)..: N^m = 0
+    inverse = jnp.eye(m, dtype=n.dtype) + n
+    power = n
+    for _ in range(max(0, math.ceil(math.log2(m)) - 1)):
+        power = jnp.einsum("hij,hjk->hik", power, power, precision=_HIGHEST)
+        inverse = inverse + jnp.einsum("hij,hjk->hik", inverse, power,
+                                       precision=_HIGHEST)
+    grown = jnp.exp(g)
+    held = jnp.einsum("ihc,hcp->ihp", k * grown, s, precision=_HIGHEST)
+    u = jnp.einsum("hij,jhp->ihp", inverse, b[..., None] * (v - held),
+                   precision=_HIGHEST)
+    o = (jnp.einsum("ihc,hcp->ihp", q * grown, s, precision=_HIGHEST)
+         + jnp.einsum("hij,jhp->ihp", qk, u, precision=_HIGHEST))
+    to_end = jnp.exp(g[-1][None] - g)                              # [m,H,N]
+    s_new = grown[-1][..., None] * s + jnp.einsum(
+        "jhc,jhp->hcp", k * to_end, u, precision=_HIGHEST)
+    return o, s_new
+
+
+def _kda_further(x, p, cfg: KimiConfig, cache, i, slot, ok):
+    """The same layer over one slot's further lanes, x [1,M,D], by the
+    chunked form from the state its first lane left, in runs of `SUBCHUNK`
+    lanes and only as many runs as hold a valid lane: -> (x, cache)."""
+    H, P = cfg.kda_heads, cfg.kda_head_dim
+    M = x.shape[1]
+    W = cache["conv"].shape[-1]
+    m = min(SUBCHUNK, M)
+    runs = -(-M // m)
+    with jax.named_scope("attn"):
+        qkv, log_a, b, gate = _kda_in(rms_norm(x, p["norm"], cfg.norm_eps),
+                                      p, cfg)
+        with jax.named_scope("kda_project"):
+            window = lax.dynamic_slice(cache["conv"], (i, slot, 0),
+                                       (1, 1, W))[0]
+        qkv, window = _conv(qkv, p, window, ok, cfg)
+        with jax.named_scope("kda_project"):
+            conv = lax.dynamic_update_slice(cache["conv"], window[None],
+                                            (i, slot, 0))
+            q, k, v = _heads(qkv[0], cfg)                        # [M, H, P]
+        with jax.named_scope("kda_chunk"):
+            valid = ok[0]
+            log_a = jnp.where(valid[:, None], log_a[0], 0.0).reshape(M, H, P)
+            b = jnp.where(valid[:, None], b[0], 0.0)
+
+            def by_run(t):
+                t = jnp.pad(t, ((0, runs * m - M),) + ((0, 0),) * (t.ndim - 1))
+                return t.reshape(runs, m, *t.shape[1:])
+
+            lanes = tuple(by_run(t) for t in (q, k, v, log_a, b))
+            s = lax.dynamic_slice(cache["kda"], (i, slot, 0, 0, 0),
+                                  (1, 1, H, P, P))[0, 0]
+
+            def run(r, carry):
+                s, o = carry
+                o_r, s = _delta_chunk(*(lax.dynamic_index_in_dim(
+                    t, r, 0, keepdims=False) for t in lanes), s)
+                return s, lax.dynamic_update_index_in_dim(o, o_r, r, 0)
+
+            s, o = lax.fori_loop(
+                0, (valid.sum() + m - 1) // m, run,
+                (s, jnp.zeros((runs, m, H, P), jnp.float32)))
+            kda = lax.dynamic_update_slice(cache["kda"], s[None, None],
+                                           (i, slot, 0, 0, 0))
+        x = _kda_out(x, o.reshape(1, runs * m, H, P)[:, :M], gate, p, cfg)
+    return x, {**cache, "kda": kda, "conv": conv}
+
+
+def _write_first(c, i, val, pos, ok, slot=None):
+    """Layer i of the carried leaf c [L,B,T,F] takes val [B,1,F]: slot b's
+    row goes to position pos[b] where ok[b, 0], one scatter for all slots
+    (`deepseek._cache_write` at one lane is a read, a blend and a write a
+    slot: 900 small operations a layer at 128 slots, two fifths of a decode
+    step's and of what a trace of it holds)."""
+    del slot
+    B, T = val.shape[0], c.shape[2]
+    at = jnp.where(ok[:, 0], pos, T)            # past the end: dropped
+    return c.at[i, jnp.arange(B), at].set(val[:, 0], mode="drop",
+                                          unique_indices=True)
+
+
+def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
+    """MLA layer `i` of the stack: x [N,C,D] float32 += absorbed attention
+    of its C lanes (positions `pos` [N,C], written where `ok`) against the
+    carried rows, `deepseek._attention` without the rotation and with the
+    projections' activations as two pieces: row n is slot n (N = B), or the
+    one row is `slot`'s own lanes against that slot's rows alone."""
+    B, C, _ = x.shape
+    H, r = cfg.n_head, cfg.kv_lora_rank
+    n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
+    lat, kr = cache["latent"], cache["k_rope"]
+    T = lat.shape[2]
+    with jax.named_scope("attn"):
+        u = rms_norm(x, p["norm"], cfg.norm_eps)
+        with jax.named_scope("mla_project"):
+            q = _dot(u, p["wq"], cfg).reshape(B, C, H, cfg.qk_head_dim)
+            ckr = _dot(u, p["wkva"], cfg)                         # [N,C,r+p]
+            c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
+            # a product batched by the head comes out head first (the CPU
+            # backend has no other float32 product of two bf16 operands)
+            q_abs = jnp.moveaxis(jnp.sum(jnp.einsum(
+                "abchn,hnr->habcr", _pieces(q[..., :n], cfg),
+                p["w_uk"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32), axis=1), 0, 2).astype(
+                    cfg.dtype)                                    # [N,C,H,r]
+            q_r = q[..., n:].astype(cfg.dtype)
+        with jax.named_scope("kv_update"):
+            write = _write_first if slot is None and C == 1 else _cache_write
+            lat = write(lat, i, c.astype(cfg.dtype), pos0, ok, slot)
+            kr = write(kr, i, ckr[..., r:].astype(cfg.dtype), pos0, ok, slot)
+        with jax.named_scope("mla_attend"):
+            latents = _rows(lat, i, slot)                           # [N,T,r]
+            scores = (jnp.einsum("bchr,btr->bhct", q_abs, latents,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bchp,btp->bhct", q_r, _rows(kr, i, slot),
+                                   preferred_element_type=jnp.float32))
+            scores = scores / math.sqrt(cfg.qk_head_dim)
+            t_idx = jnp.arange(T)[None, None, None, :]
+            scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            # [N,H,C,r], the heads before the lanes: with the lanes first
+            # the CPU backend has no float32 product of two bf16 operands
+            mixed = jnp.einsum("bhct,btr->bhcr", probs, latents,
+                               preferred_element_type=jnp.float32)
+        with jax.named_scope("mla_project"):
+            o = jnp.moveaxis(jnp.sum(jnp.einsum(
+                "abhcr,hrv->habcv", _pieces(mixed, cfg),
+                p["w_uv"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32), axis=1), 0, 2)
+            x = x + _dot(o.reshape(B, C, H * v), p["wo"], cfg)
+    return x, {**cache, "latent": lat, "k_rope": kr}
+
+
+def _dense_mlp(x, p, cfg: KimiConfig):
+    with jax.named_scope("mlp"):
+        return x + _swiglu(rms_norm(x, p["norm"], cfg.norm_eps), p, cfg)
+
+
+def _expert_mlp(x, p, experts_of_all_layers, j, cfg: KimiConfig, given, ok):
+    """x [N,C,D] += the held experts' part of the routed sum + the shared
+    expert, for expert layer j; `given` [E] += the (lane, expert) pairs of
+    the lanes that are `ok`, over all E.
+
+    The router scores all E experts and chooses K of them. A pair whose
+    expert is held, e in first_expert..+E', goes to entry j E' + e -
+    first_expert of the stack of every layer's held experts; a pair whose
+    expert is not goes past the stack's end, where `moe._experts`
+    (`first_expert` 0 of a stack shorter than the ids) gives it no row of
+    any matrix and zeroes it. The stack is handed over whole: the groups of
+    the other layers are empty, and nothing is sliced out of it."""
+    B, C, D = x.shape
+    K, held = cfg.experts_per_token, cfg.experts_held
+    stack = experts_of_all_layers["wg"].shape[0]
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["norm"], cfg.norm_eps)
+        _, _, gates, experts = _moe._route(h.reshape(B * C, D), p["router"],
+                                           cfg, p["bias"])
+        with jax.named_scope("moe_router"):
+            given = given.at[experts.reshape(-1)].add(
+                jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
+            local = experts - cfg.first_expert
+            entry = jnp.where((local >= 0) & (local < held),
+                              j * held + local, stack)
+        routed = _moe._experts(
+            h, gates.reshape(B, C, K), entry.reshape(B, C, K),
+            *(experts_of_all_layers[w] for w in ("wg", "wu", "wd")),
+            types.SimpleNamespace(n_experts=stack + 1, experts_per_token=K,
+                                  dtype=jnp.float32),
+            first_expert=jnp.int32(0))
+        with jax.named_scope("moe_shared"):
+            shared = _swiglu(h, p["shared"], cfg)
+        return x + routed + shared, given
+
+
+def _expert_counts(given, cfg: KimiConfig):
+    """One expert layer's step in `COUNTS`' order but the positions: from
+    the pairs `given` [E] each expert got over all the step's valid
+    lanes."""
+    with jax.named_scope("moe_router"):
+        held = lax.dynamic_slice_in_dim(given, cfg.first_expert,
+                                        cfg.experts_held)
+        return jnp.stack([jnp.sum(held), jnp.sum(held > 0), jnp.max(held),
+                          jnp.ones((), jnp.int32), jnp.zeros((), jnp.int32),
+                          jnp.sum(given)]).astype(jnp.uint32)
+
+
+def _further_lanes(rest, mixer: str, mixer_stack, i, mlp_stack, mlp_i,
+                   experts, j, cfg: KimiConfig, cache, given, pos, ok,
+                   prefilling):
+    """One layer over the lanes after the first, rest [B,M,D] with ok
+    [B,M], the first of them at position pos [B]: a slot at a time and only
+    the slots that have such lanes, `prefilling` = (their indices first in
+    a [B] array, how many they are): the loop runs that many times, where a
+    loop over all the slots with a branch each costs three small operations
+    a slot a layer, 3,500 a chunk step at 128 slots (PERF.md, PR 40). The
+    weights are sliced inside the loop (`granite._further_lanes`)."""
+    B, M, D = rest.shape
+    slots, count = prefilling
+
+    def slot(n, carry):
+        rest, cache, given = carry
+        b = lax.dynamic_index_in_dim(slots, n, 0, keepdims=False)
+        p = _layer_weights(mixer_stack, i)
+        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
+        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
+        if mixer == "mla":
+            at = lax.dynamic_slice(pos, (b,), (1,))
+            xb, cache = _mla(xb, p, cfg, cache, i, at,
+                             at[:, None] + jnp.arange(M), okb, slot=b)
+        else:
+            xb, cache = _kda_further(xb, p, cfg, cache, i, b, okb)
+        mp = _layer_weights(mlp_stack, mlp_i)
+        if experts is None:
+            xb = _dense_mlp(xb, mp, cfg)
+        else:
+            xb, given = _expert_mlp(xb, mp, experts, j, cfg, given, okb)
+        return lax.dynamic_update_slice(rest, xb, (b, 0, 0)), cache, given
+
+    return lax.fori_loop(0, count, slot, (rest, cache, given))
+
+
+def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
+           on, further, prefilling, first, rest, cache, counts):
+    """One layer: the mixer `mixer` with entry i of its stack, then the
+    dense MLP with entry mlp_i of its stack (j None) or expert layer j's
+    block. Every slot's first lane all slots at once, then the further
+    lanes of the slots that have any."""
+    dense = j is None
+    mlp_stack = params["dense" if dense else "moe"]
+    experts = None if dense else params["experts"]
+    given = jnp.zeros((cfg.n_experts,), jnp.int32)
+    p = _layer_weights(params[mixer], i)
+    if mixer == "mla":
+        first, cache = _mla(first, p, cfg, cache, i, pos0, pos0[:, None],
+                            on[:, None])
+    else:
+        first, cache = _kda_first(first, p, cfg, cache, i, on)
+    mp = _layer_weights(mlp_stack, mlp_i)
+    if dense:
+        first = _dense_mlp(first, mp, cfg)
+    else:
+        first, given = _expert_mlp(first, mp, experts, j, cfg, given,
+                                   on[:, None])
+    if rest is not None:
+        rest, cache, given = _further_lanes(
+            rest, mixer, params[mixer], i, mlp_stack, mlp_i, experts, j, cfg,
+            cache, given, pos0 + 1, further, prefilling)
+    if not dense:
+        counts = counts + _expert_counts(given, cfg)
+    return first, rest, cache, counts
+
+
+def _logits(params: Params, x, cfg: KimiConfig):
+    with jax.named_scope("unembed_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return _dot(x, params["lm_head"], cfg)
+
+
+def _forward(params: Params, cache, tokens, pos0, length, active,
+             cfg: KimiConfig, program: int):
+    """Both step programs. A layer computes a lane only where the plan put
+    a token (`models/deepseek.py`, PR 39): every slot's first lane goes
+    through the layer all slots at once, which is the whole decode program;
+    the lanes after it a slot at a time, only the slots that have them, C of
+    them a slot with the last one padding, so that the rows a slot's experts
+    sort come in whole tiles of the grouped matmul.
+
+    The dense layers stand before the loops. The loops carry the cache, one
+    buffer a leaf from layer to layer, written in place where the caller
+    donates it, and close over the experts' stack, which they never
+    slice."""
+    B, C = tokens.shape
+    lane = jnp.arange(C)
+    on = active & (length > 0)
+    ok = (lane[None, :] < length[:, None]) & active[:, None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
+    first, rest, further, prefilling = x[:, :1], None, None, None
+    if C > 1:
+        rest = jnp.pad(x[:, 1:], ((0, 0), (0, 1), (0, 0)))
+        further = jnp.pad(ok[:, 1:], ((0, 0), (0, 1)))
+        with jax.named_scope("embed"):
+            more = further.any(axis=1)
+            # the slots that have further lanes first, and how many
+            prefilling = (jnp.argsort(~more, stable=True).astype(jnp.int32),
+                          more.sum().astype(jnp.int32))
+    counts = jnp.zeros((len(COUNTS),), jnp.uint32)
+    leaves = {k: v for k, v in cache.items() if k != "counts"}
+    index = _stack_index(cfg)
+    n_dense = cfg.n_dense_layer
+    # The layers as runs of one kind (a mixer and dense or experts): one
+    # loop over the runs, whose body holds one loop a kind, and a kind's
+    # loop turns as many times as the run is long if the run is of that
+    # kind and not at all if it is not. No branch takes a layer's kind: a
+    # leaf that passes through a conditional untouched is copied on its way
+    # (1.9 GB of state a step for each MLA layer, found by
+    # rehearse/compile_kimi_for_v5e.py), and a loop that turns no time hands
+    # its carry on where it lies. Nothing of a layer stands outside the
+    # runs' loop either: a trace holds one event a step for it and none of
+    # the gaps between its operations.
+    kinds = [(mixer, l < n_dense) for l, mixer in enumerate(cfg.layer_types)]
+    runs = []                             # (kind, first layer, layers)
+    for l, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, l, 1])
+    bodies = sorted(set(kinds))
+    first_layer = jnp.asarray([first_l for _, first_l, _ in runs])
+    turns = {kind: jnp.asarray([n if k == kind else 0 for k, _, n in runs])
+             for kind in bodies}
+    mixer_at = jnp.asarray([at[mixer] for at, mixer in zip(
+        index, cfg.layer_types)])
+
+    def layer(kind, l, carry):
+        mixer, dense = kind
+        return _layer(mixer, mixer_at[l], l if dense else l - n_dense,
+                      None if dense else l - n_dense, params, cfg, pos0, on,
+                      further, prefilling, *carry)
+
+    def run(r, carry):
+        start = first_layer[r]
+        for kind in bodies:
+            carry = lax.fori_loop(start, start + turns[kind][r],
+                                  functools.partial(layer, kind), carry)
+        return carry
+
+    with jax.named_scope("layers"):
+        carry = lax.fori_loop(0, len(runs), run,
+                              (first, rest, leaves, counts))
+    first, rest, leaves, counts = carry
+    x = first if rest is None else jnp.concatenate(
+        [first, rest[:, :C - 1]], axis=1)
+    with jax.named_scope("moe_router"):
+        attended = jnp.sum(jnp.where(ok, pos0[:, None] + lane + 1, 0))
+        counts = counts.at[COUNTS.index("attended_positions")].set(
+            attended.astype(jnp.uint32))
+        counts = cache["counts"].at[program].add(counts)
+    last = jnp.clip(length - 1, 0, C - 1)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return _logits(params, x_last, cfg), {**leaves, "counts": counts}
+
+
+def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
+                  length: jax.Array, active: jax.Array, cfg: KimiConfig):
+    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
+    slot), pos0 [B] (the position of the chunk's first token: the rows are
+    written there; the state does not read it), length [B] (valid tokens,
+    0..C), active [B] -> (logits [B, vocab] float32 at each slot's last
+    valid lane, the cache). Inactive and zero-length slots leave their
+    rows, their state and their window as they were, bit for bit, and their
+    logits are garbage. The state continues whatever the slot held: a new
+    sequence's slot is the caller's to zero. Donate `cache`."""
+    return _forward(params, cache, tokens, pos0, length, active, cfg, 1)
+
+
+def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
+                active: jax.Array, cfg: KimiConfig):
+    """`gpt2.decode_step`'s contract: tokens [B], pos [B], active [B] ->
+    (logits [B, vocab] float32, the cache): the recurrence through the
+    delta-rule kernel and attention over the cached rows, one token a slot;
+    the chunk program's first lane, and nothing else of it."""
+    return _forward(params, cache, tokens[:, None], pos,
+                    active.astype(jnp.int32), active, cfg, 0)

@@ -265,6 +265,26 @@ def _permute_bwd(res, g):
 _permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 
+def _rows_times_experts(xs, w, group_sizes, first_expert):
+    """`grouped_matmul` of the sorted rows xs [R, K] with the experts'
+    matrices w. Rows and matrices of one dtype go as they are. Float32 rows
+    against matrices held narrower (a served program whose activations stay
+    float32, `models/kimi.py`) go as the two pieces of the matrices' dtype
+    that add up to them, a row's two side by side so that the groups stay
+    sorted, and come back float32: one pass of the matrices, none of the
+    rows' rounding in the result."""
+    if xs.dtype == w.dtype:
+        return grouped_matmul(xs, w, group_sizes, first_expert)
+    bits = jnp.finfo(w.dtype)
+    high = lax.reduce_precision(xs, exponent_bits=bits.nexp,
+                                mantissa_bits=bits.nmant)
+    pieces = jnp.stack([high, xs - high], axis=1).astype(w.dtype)
+    both = grouped_matmul(pieces.reshape(-1, xs.shape[-1]), w,
+                          2 * group_sizes, first_expert,
+                          out_dtype=jnp.float32)
+    return both[0::2] + both[1::2]
+
+
 def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
              first_expert=None):
     """The experts' part of the layer for the tokens x [B,T,D] (`gates`,
@@ -297,17 +317,21 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
             xs = jnp.where(mine, xs, 0)
 
     with jax.named_scope("moe_experts"):
-        g = grouped_matmul(xs, wg, group_sizes, first_expert)
-        u = grouped_matmul(xs, wu, group_sizes, first_expert)
+        g = _rows_times_experts(xs, wg, group_sizes, first_expert)
+        u = _rows_times_experts(xs, wu, group_sizes, first_expert)
         h = jax.nn.silu(g) * u
-        ys = grouped_matmul(h, wd, group_sizes, first_expert)
+        ys = _rows_times_experts(h, wd, group_sizes, first_expert)
 
     with jax.named_scope("moe_dispatch"):
         if first_expert is not None:
             ys = jnp.where(mine, ys, 0)
         back = _permute_rows(ys, inverse, order).reshape(N, K, D)
+        # float32 rows keep their gates whole; a product of two bf16
+        # operands is exact in float32 whatever the precision says
         out = jnp.einsum("nkd,nk->nd", back,
                          gates.reshape(N, K).astype(cfg.dtype),
+                         precision=(lax.Precision.HIGHEST
+                                    if back.dtype == jnp.float32 else None),
                          preferred_element_type=jnp.float32)
         return out.reshape(B, T, D)
 

@@ -73,23 +73,26 @@ def _tiling(m: int, k: int, n: int, groups: int) -> tuple:
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    first_group: jax.Array | None = None,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False, out_dtype=None) -> jax.Array:
     """lhs [M, K], rhs [G, K, N], group_sizes [G] int32 -> [M, N] in
-    lhs's dtype (float32 accumulation). With `first_group` (an int32
+    lhs's dtype, or in `out_dtype` (float32 accumulation). With `first_group` (an int32
     scalar) `rhs` is a shard, groups first_group..+G of the `group_sizes`
     [G_all] that `lhs` is sorted by; the rows of the other groups come back
     unwritten from the kernel (zero from `ragged_dot`): the caller masks."""
     backend = jax.default_backend()
+    out_dtype = out_dtype or lhs.dtype
     if backend == "cpu" and not interpret:
         if first_group is None:
-            return lax.ragged_dot(lhs, rhs, group_sizes)
+            return lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=out_dtype)
         # one more group in front takes the rows before the shard's
         before = jnp.sum(jnp.where(
             jnp.arange(group_sizes.shape[0]) < first_group, group_sizes, 0))
         own = lax.dynamic_slice(group_sizes, (first_group,), (rhs.shape[0],))
         return lax.ragged_dot(
             lhs, jnp.concatenate([jnp.zeros_like(rhs[:1]), rhs]),
-            jnp.concatenate([before[None], own]))
+            jnp.concatenate([before[None], own]),
+            preferred_element_type=out_dtype)
     if backend not in ("tpu", "cpu"):
         raise RuntimeError(
             f"grouped_matmul compiles for the tpu backend and runs as "
@@ -97,5 +100,5 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.shape[0])
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, first_group,
+    return gmm(lhs, rhs, group_sizes, out_dtype, tiling, first_group,
                interpret=interpret)

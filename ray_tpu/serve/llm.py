@@ -1093,8 +1093,11 @@ class LLMEngine:
         and only here, on the engine's thread between two passes (the leaf
         is donated to every step), at the cost of waiting for the step in
         flight. `moe_expert_rows` and `moe_experts_touched` sum both
-        programs, `step_counts` has each program's columns. Each is a
-        uint32 that wraps: take differences modulo 2**32."""
+        programs (the rows the experts held here were given and the held
+        experts that got one; `moe_expert_rows_all`, where the family
+        counts it, every valid lane's pairs, held here or not),
+        `step_counts` has each program's columns. Each is a uint32 that
+        wraps: take differences modulo 2**32."""
         if "counts" not in self.cache:
             return {}
 
@@ -1110,7 +1113,8 @@ class LLMEngine:
                       for program, row in zip(("decode", "chunk"), rows)}
         return {"step_counts": by_program,
                 **{f"moe_{k}": sum(p[k] for p in by_program.values())
-                   % 2 ** 32 for k in ("expert_rows", "experts_touched")}}
+                   % 2 ** 32 for k in ("expert_rows", "experts_touched",
+                                       "expert_rows_all") if k in names}}
 
     def engine_stats(self) -> dict:
         from ray_tpu.utils.platform import device_report

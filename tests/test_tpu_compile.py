@@ -433,6 +433,61 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
         "kernels": 4, "leaf_copies": {}, "ssm_layer_copies": []}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_kimi_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-kimi-longgen`'s two programs, as its configuration
+    file has them (Kimi-Linear-48B-A3B's widths, layers 1-9 with 64 of 256
+    experts a layer and a quarter of the vocabulary, 128 slots of 15.7 MB of
+    float32 state and 10,240 positions of latent rows, chunks of 128): the
+    bytes the file gives, room for the pool of both kinds beside the larger;
+    the Pallas kernels (the delta rule's update in the dense layer's body
+    and in the KDA expert layers', the experts' three grouped matmuls in
+    each of the two expert bodies: 8 in the decode program, and the further
+    lanes' six more in the chunk program, whose chunked delta rule is no
+    kernel); no instruction
+    copies a cache leaf (the kernel aliases the state, the layers' loops
+    carry the four leaves, and no layer's kind is a branch: a loop a kind
+    that turns as often as the run is long or not at all) or materialises
+    one layer's state for all slots; **none materialises an expert matrix**,
+    one layer's [64, d, F] or the stack's [512, d, F] (ROADMAP S12a: the
+    grouped matmuls read the stack where it lies); the decode program's
+    temporaries are one MLA layer's scores and the head's pieces, the chunk
+    program's under half a gigabyte: neither computes the padding of 128 x
+    128 lanes."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_kimi_for_v5e import (CONFIG, cache_bytes, compile_step,
+                                      made_of, pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    want = (memory["decode_step_bytes"] if program == "decode" else memory[
+        "prefill_chunk_bytes_by_chunk_size"][
+            str(config["deployment"]["prefill_chunk_size"])])
+    assert sized["total"] == want
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 128 * 128 * 4)     # the chunk's tokens
+    assert sized["arguments"] >= 0.75 * HBM_BYTES
+    assert cache_bytes(config) == {
+        "state_bytes_per_slot": memory["state_bytes_per_slot"],
+        "kv_bytes_per_token": memory["kv_bytes_per_token"]} == {
+        "state_bytes_per_slot": 15_712_256, "kv_bytes_per_token": 2304}
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    if program == "decode":
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 28
+    else:
+        assert sized["temp"] < 2 ** 29
+    assert made_of(compiled.as_text(), config) == {
+        "kernels": 8 if program == "decode" else 14, "leaf_copies": {},
+        "kda_layer_copies": [], "expert_matrix_copies": []}
+
+
 def test_token_selection_compiles_at_xl_vocabulary(chips):
     """`serve/sampling.select_tokens` over the serving cells' [8, 50304]
     logits: one program whose sort is in a branch of a conditional, and
