@@ -73,6 +73,7 @@ from jax import lax
 
 from ray_tpu.models import moe as _moe
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs
+from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
 
 Params = Any
 
@@ -282,9 +283,11 @@ def num_params(cfg: DeepseekConfig) -> int:
 # executions: over the expert layers, the (lane, expert) rows the experts
 # were given for valid lanes, the experts that got at least one, the most
 # that one expert got, and 1; and once a step the positions the valid
-# lanes attend to (position + 1 each)
+# lanes attend to (position + 1 each) and the positions whose rows a
+# layer's attention read for them (`ops/mla_attend.read_positions` for the
+# first lanes, all T for each slot that has further lanes)
 COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
-          "expert_layer_steps", "attended_positions")
+          "expert_layer_steps", "attended_positions", "read_positions")
 
 
 def init_cache(cfg: DeepseekConfig, batch: int,
@@ -293,7 +296,7 @@ def init_cache(cfg: DeepseekConfig, batch: int,
     compute dtype: c after its norm and the shared rotary key after RoPE,
     two leaves because they are two operands (the scores contract both, the
     weighted sum only the latent) and a [.., T, r + p] leaf would be sliced
-    inside every layer; and `counts` uint32 [2, 5], not a token's: what
+    inside every layer; and `counts` uint32 [2, 6], not a token's: what
     the step programs count themselves, row 0 `decode_step`'s, row 1
     `prefill_chunk`'s; `COUNTS` names the columns
     (`serve/llm.py` reads them for `stats()`; they wrap, so a reader takes
@@ -367,7 +370,6 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
     B, C, _ = x.shape
     H, r = cfg.n_head, cfg.kv_lora_rank
     n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
-    T = lat.shape[2]
     p = bp["attn"]
     with jax.named_scope("attn"):
         h = rms_norm(x, bp["attn_norm"], cfg.norm_eps).astype(cfg.dtype)
@@ -388,17 +390,17 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
             lat = _cache_write(lat, l, c, pos0, ok, slot)
             kr = _cache_write(kr, l, k_r, pos0, ok, slot)
         with jax.named_scope("mla_attend"):
-            latents = _rows(lat, l, slot)                           # [N,T,r]
-            scores = (jnp.einsum("bchr,btr->bhct", q_abs, latents,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bchp,btp->bhct", q_rope,
-                                   _rows(kr, l, slot),
-                                   preferred_element_type=jnp.float32))
-            scores = scores / math.sqrt(cfg.qk_head_dim)
-            t_idx = jnp.arange(T)[None, None, None, :]
-            scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            mixed = jnp.einsum("bhct,btr->bchr", probs, latents)  # [N,C,H,r]
+            scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+            if slot is None and C == 1:
+                # every slot's one lane, the decode program's work: a
+                # slot's rows read once and to its own position
+                mixed = mla_attend(q_abs[:, 0], q_rope[:, 0], lat, kr, l,
+                                   pos[:, 0], ok[:, 0], scale)[:, :, None]
+            else:
+                mixed = attend_rows(q_abs, q_rope, _rows(lat, l, slot),
+                                    _rows(kr, l, slot), pos, scale)
+            # [N,H,C,r] float32 -> [N,C,H,r]
+            mixed = jnp.moveaxis(mixed, 1, 2).astype(cfg.dtype)
         with jax.named_scope("mla_project"):
             o = jnp.einsum("bchr,rhv->bchv", mixed, wkvb[..., n:])
             x = x + jnp.dot(o.reshape(B, C, H * v), _w(p["wo"], cfg),
@@ -548,8 +550,12 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
         x = first
     with jax.named_scope("moe_router"):
         attended = jnp.sum(jnp.where(ok, pos + 1, 0)).astype(jnp.uint32)
+        T = lat.shape[2]
+        read = read_positions(pos0, on[:, 0], T)
+        if further is not None:
+            read = read + (further.any(axis=1).sum() * T).astype(jnp.uint32)
         counts = cache["counts"].at[program].add(
-            jnp.concatenate([counts, attended[None]]))
+            jnp.concatenate([counts, jnp.stack([attended, read])]))
     return x, {"latent": lat, "k_rope": kr, "counts": counts}
 
 

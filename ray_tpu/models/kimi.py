@@ -93,6 +93,7 @@ from ray_tpu.models import moe as _moe
 from ray_tpu.models.deepseek import _cache_write, _rows
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.kda_update import kda_update
+from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
 
 Params = Any
 _HIGHEST = lax.Precision.HIGHEST
@@ -184,14 +185,16 @@ CACHE_TOKEN_AXIS = {"latent": 2, "k_rope": 2}
 CACHE_STATE = ("kda", "conv")
 
 # the columns of the cache's `counts` leaf, each a sum over a program's
-# executions (`deepseek.COUNTS`, whose first five these are): over the
+# executions (`deepseek.COUNTS`, whose six the first six are): over the
 # expert layers, the (lane, expert) rows the experts held here were given
 # for valid lanes, the held experts that got at least one, the most that
 # one of them got, and 1; once a step the positions the valid lanes attend
-# to; and, over the expert layers again, all the valid lanes' (lane,
-# expert) pairs, held or not: 8 a lane
+# to and the positions whose rows an MLA layer read for them; and, over the
+# expert layers again, all the valid lanes' (lane, expert) pairs, held or
+# not: 8 a lane
 COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
-          "expert_layer_steps", "attended_positions", "expert_rows_all")
+          "expert_layer_steps", "attended_positions", "read_positions",
+          "expert_rows_all")
 
 
 # ---------------------------------------------------------------------------
@@ -699,7 +702,6 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
     H, r = cfg.n_head, cfg.kv_lora_rank
     n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
     lat, kr = cache["latent"], cache["k_rope"]
-    T = lat.shape[2]
     with jax.named_scope("attn"):
         u = rms_norm(x, p["norm"], cfg.norm_eps)
         with jax.named_scope("mla_project"):
@@ -719,19 +721,14 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
             lat = write(lat, i, c.astype(cfg.dtype), pos0, ok, slot)
             kr = write(kr, i, ckr[..., r:].astype(cfg.dtype), pos0, ok, slot)
         with jax.named_scope("mla_attend"):
-            latents = _rows(lat, i, slot)                           # [N,T,r]
-            scores = (jnp.einsum("bchr,btr->bhct", q_abs, latents,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bchp,btp->bhct", q_r, _rows(kr, i, slot),
-                                   preferred_element_type=jnp.float32))
-            scores = scores / math.sqrt(cfg.qk_head_dim)
-            t_idx = jnp.arange(T)[None, None, None, :]
-            scores = jnp.where(t_idx <= pos[:, None, :, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            # [N,H,C,r], the heads before the lanes: with the lanes first
-            # the CPU backend has no float32 product of two bf16 operands
-            mixed = jnp.einsum("bhct,btr->bhcr", probs, latents,
-                               preferred_element_type=jnp.float32)
+            scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+            if slot is None and C == 1:
+                mixed = mla_attend(q_abs[:, 0], q_r[:, 0], lat, kr, i,
+                                   pos[:, 0], ok[:, 0], scale)[:, :, None]
+            else:
+                mixed = attend_rows(q_abs, q_r, _rows(lat, i, slot),
+                                    _rows(kr, i, slot), pos, scale)
+            # [N,H,C,r] float32, the heads before the lanes
         with jax.named_scope("mla_project"):
             o = jnp.moveaxis(jnp.sum(jnp.einsum(
                 "abhcr,hrv->habcv", _pieces(mixed, cfg),
@@ -791,6 +788,7 @@ def _expert_counts(given, cfg: KimiConfig):
                                         cfg.experts_held)
         return jnp.stack([jnp.sum(held), jnp.sum(held > 0), jnp.max(held),
                           jnp.ones((), jnp.int32), jnp.zeros((), jnp.int32),
+                          jnp.zeros((), jnp.int32),
                           jnp.sum(given)]).astype(jnp.uint32)
 
 
@@ -945,6 +943,11 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
         attended = jnp.sum(jnp.where(ok, pos0[:, None] + lane + 1, 0))
         counts = counts.at[COUNTS.index("attended_positions")].set(
             attended.astype(jnp.uint32))
+        T = cache["latent"].shape[2]
+        read = read_positions(pos0, on, T)
+        if prefilling is not None:
+            read = read + (prefilling[1] * T).astype(jnp.uint32)
+        counts = counts.at[COUNTS.index("read_positions")].set(read)
         counts = cache["counts"].at[program].add(counts)
     last = jnp.clip(length - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]

@@ -5,6 +5,8 @@ kanana.py`, the absorbed form of latent attention against the plain one,
 the router, the prefix pool over latent leaves, and the engine end to end.
 """
 
+import functools
+import importlib
 import os
 import sys
 import time
@@ -24,6 +26,8 @@ from families import kanana  # noqa: E402
 
 from ray_tpu.models import deepseek, moe, serving_family  # noqa: E402
 from ray_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
+
+ma = importlib.import_module("ray_tpu.ops.mla_attend")
 from ray_tpu.serve.kv_cache import PagedKVCache  # noqa: E402
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
 
@@ -313,16 +317,55 @@ def test_the_chunk_programs_counts_are_over_the_lanes_the_plan_handed_out(
     assert lanes == 1 + 5 + 16 + 16
     moved = (m["after"]["counts"].astype(np.int64)
              - m["start"]["counts"].astype(np.int64))
-    assert moved[0].tolist() == [0] * 5       # the decode program's row
+    assert moved[0].tolist() == [0] * 6       # the decode program's row
     assert dict(zip(deepseek.COUNTS, moved[1].tolist())) == {
         "expert_rows": lanes * K * len(given),
         "experts_touched": int((given > 0).sum()),
         "busiest_expert_rows": int(given.max(axis=1).sum()),
         "expert_layer_steps": len(given),
-        "attended_positions": attended}
+        "attended_positions": attended,
+        # the plain form: all T of a slot for its first lane with every
+        # slot's, and all T again for its further lanes against its own
+        "read_positions": 96 * (len(m["valid"]) + int(
+            (m["length"][m["valid"]] > 1).sum()))}
     # over the union of the step's lanes, not slot by slot
     assert int((given > 0).sum()) < touched_a_slot
 
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_both_programs_count_the_positions_read_beside_the_attended(
+        monkeypatch, form):
+    """`read_positions` beside `attended_positions` in both programs' rows
+    of the counts: a prompt of 37 in chunks of 16, then two decode steps at
+    positions 37 and 38. The plain form reads all T = 96 positions of a
+    live slot a layer's call; the kernel (interpreted, blocks of 16) a
+    slot's position rounded up to a block for every slot's first lane, and
+    the further lanes stay the plain form's. What was attended is the
+    same, and so are the logits."""
+    T, block = 96, 16
+    bf16 = {"dtype": jnp.bfloat16, "param_dtype": jnp.bfloat16}
+    _, plain = through_the_programs(engine(bf16), PROMPT, 3)
+    if form == "kernel":
+        monkeypatch.setattr(ma, "BLOCK", block)
+        for name in ("mla_attend", "read_positions"):
+            monkeypatch.setattr(deepseek, name, functools.partial(
+                getattr(ma, name), interpret=True))
+    eng = engine(bf16)
+    _, logits = through_the_programs(eng, PROMPT, 3)
+    decode, chunk = (dict(zip(deepseek.COUNTS, row)) for row in np.asarray(
+        eng.cache["counts"]).tolist())
+    assert chunk["attended_positions"] == sum(range(1, 38))
+    assert decode["attended_positions"] == 38 + 39
+    if form == "plain":
+        assert chunk["read_positions"] == 3 * T + 3 * T
+        assert decode["read_positions"] == 2 * T
+        np.testing.assert_array_equal(logits, plain)
+    else:
+        assert chunk["read_positions"] == (16 + 32 + 48) + 3 * T
+        assert decode["read_positions"] == 48 + 48
+        np.testing.assert_allclose(logits, plain, atol=5e-2)
+        assert np.abs(plain).max() > 0.5
 
 
 # ------------------------------------------------------- latent attention
@@ -680,6 +723,11 @@ def test_one_streamed_completion_through_the_openai_server():
         assert chunk["expert_rows"] == 3 * 2 * (37 + 5)
         assert chunk["attended_positions"] == sum(range(1, 38)) + sum(
             range(33, 38))
+        # the plain form reads all 96 positions: three chunks then one, a
+        # first lane and further lanes each; five decode steps a request
+        assert chunk["read_positions"] == 96 * 2 * (3 + 1)
+        assert decode["read_positions"] == 96 * 5 * 2
+        assert decode["attended_positions"] == 2 * sum(range(38, 43))
         assert stats["moe_expert_rows"] == (decode["expert_rows"]
                                             + chunk["expert_rows"])
         assert 0 < stats["moe_experts_touched"] <= stats["moe_expert_rows"]

@@ -8,6 +8,8 @@ inactive, both pooled between two chunk steps, found again); the share tied
 to the model; and what the family refuses by name."""
 
 import dataclasses
+import functools
+import importlib
 import os
 import sys
 
@@ -25,6 +27,8 @@ from families import kimi as family  # noqa: E402
 
 from ray_tpu.models import deepseek, kimi, serving_family  # noqa: E402
 from ray_tpu.ops import kda_update as ku  # noqa: E402
+
+ma = importlib.import_module("ray_tpu.ops.mla_attend")
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
 
 # the tiny preset in the source's key names, for the reference: 5 layers
@@ -437,6 +441,40 @@ def test_mla_without_rotation_is_deepseeks_with_rotation_off():
     assert np.abs(np.asarray(rotated - want)).max() > 1e-4
 
 
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_both_programs_count_the_positions_read_beside_the_attended(
+        monkeypatch, form):
+    """`read_positions` beside `attended_positions` in both programs' rows
+    of the counts: a prompt of 37 in chunks of 16 (its first lane with
+    every slot's, its further lanes against its own rows: all T = 96 each
+    in the plain form), then two decode steps at positions 37 and 38. In
+    the plain form a live slot's first lane reads all T too; under the
+    kernel (interpreted, blocks of 16) its position rounded up to a block.
+    What was attended is the same, and so are the logits."""
+    T, block = 96, 16
+    _, plain = through_the_programs(engine(BF16), PROMPT, 3)
+    if form == "kernel":
+        monkeypatch.setattr(ma, "BLOCK", block)
+        for name in ("mla_attend", "read_positions"):
+            monkeypatch.setattr(kimi, name, functools.partial(
+                getattr(ma, name), interpret=True))
+    eng = engine(BF16)
+    _, logits = through_the_programs(eng, PROMPT, 3)
+    decode, chunk = (dict(zip(kimi.COUNTS, row)) for row in np.asarray(
+        eng.cache["counts"]).tolist())
+    assert chunk["attended_positions"] == sum(range(1, 38))
+    assert decode["attended_positions"] == 38 + 39
+    if form == "plain":
+        assert chunk["read_positions"] == 3 * T + 3 * T
+        assert decode["read_positions"] == 2 * T
+        np.testing.assert_array_equal(logits, plain)
+    else:
+        assert chunk["read_positions"] == (16 + 32 + 48) + 3 * T
+        assert decode["read_positions"] == 48 + 48
+        np.testing.assert_allclose(logits, plain, atol=2e-3)
+        assert np.abs(plain).max() > 0.5
+
+
 # -------------------------------------------------------------------- pool
 
 def test_a_pool_hit_gives_the_logits_of_a_cold_prefill():
@@ -487,7 +525,7 @@ def test_the_presets_name_picks_the_module():
                  "CACHE_TOKEN_AXIS", "CACHE_STATE", "COUNTS"):
         assert hasattr(kimi, name), name
     assert kimi.CACHE_TOKEN_AXIS and kimi.CACHE_STATE
-    assert kimi.COUNTS[:5] == deepseek.COUNTS     # Kanana's readers read it
+    assert kimi.COUNTS[:6] == deepseek.COUNTS     # Kanana's readers read it
     with open(os.path.join(REPO, "ray_tpu", "serve", "llm.py")) as f:
         assert "kimi" not in f.read()         # the engine knows the contract
 
@@ -562,6 +600,9 @@ def test_the_loop_serves_what_the_programs_give_and_pools_between_chunks():
         decode = stats["step_counts"]["decode"]
         assert decode["expert_layer_steps"] % 4 == 0
         assert decode["expert_rows_all"] == 3 * decode["expert_layer_steps"]
+        # the plain form: all 96 positions of a live slot a decode step
+        assert decode["read_positions"] == 96 * 3 * 7 \
+            > decode["attended_positions"] > 0
     finally:
         eng.shutdown()
 

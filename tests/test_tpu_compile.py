@@ -284,8 +284,14 @@ def test_gpt2_xl_serving_programs_are_the_parents(chips, program):
 # slot's first lane and the further lanes only of the slots that prefill. The
 # configuration file is the benchmark's and keeps the all-lanes form's
 # 15,700,020,736 B (`memory.prefill_chunk_bytes_by_chunk_size`) until a
-# `benchmark` issue, so the pin is this file's own
-KANANA_CHUNK_BYTES = 12_782_068_224
+# `benchmark` issue, so the pin is this file's own. Since PR 41 every slot's
+# first lane attends through the `mla_attend` kernel (one in the dense
+# layer's body, one in the expert layers'): the chunk program lost the first
+# lanes' scores, and the decode program, whose temporaries are a copy of a
+# layer's expert matrices whatever attention does, gained the kernel's
+# operands: 790,528 B over the file's `decode_step_bytes` 11,773,044,224
+KANANA_CHUNK_BYTES = 12_781_761_024
+KANANA_DECODE_BYTES = 11_773_834_752
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -298,8 +304,11 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
     copies of a layer's routed experts' matrices out of the stack that the
     scan makes today (ROADMAP S12 takes them out, and this pin with them:
     38.6 -> 13.2 ms a decode step, PERF.md PR 29). The decode program: the
-    bytes the file gives, the experts three grouped-matmul kernels in the
-    loop's body. The chunk program: three more in the branch of a slot that
+    experts' three grouped-matmul kernels in the loop's body and the
+    `mla_attend` kernel in both bodies (the dense layer's and the loop's:
+    `made_of` counts every Pallas kernel under the older name), no float32
+    scores `[32, 32, 1, 4096]` written. The chunk program: three more in
+    the branch of a slot that
     prefills, which read the same three copies (sliced again inside the
     branch there are six); no array over all 32 x 128 lanes' scores
     `[32,32,128,4096]` (4.33 GB of temporaries before PR 39), but the
@@ -320,25 +329,27 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
     assert pool_bytes(config) == memory["prefix_pool_bytes"]
     assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
     hlo = compiled.as_text()
+    d, heads = config["deployment"], config["model"]["num_attention_heads"]
+    assert d["max_batch"] == heads == 32     # the two 32s below
+    # every slot's first lane: its scores never leave the kernel
+    assert _written_arrays(hlo, "32,32,(?:1,)?4096", "f32") == []
     if program == "decode":
-        assert sized["total"] == memory["decode_step_bytes"]
+        assert sized["total"] == KANANA_DECODE_BYTES
         assert made_of(hlo, config) == {
-            "grouped_matmul_kernels": 3, "cache_copies": [],
+            "grouped_matmul_kernels": 3 + 2, "cache_copies": [],
             "expert_weight_copies": ["fusion"] * 3}
         return
     chunk = str(config["deployment"]["prefill_chunk_size"])
     assert sized["total"] == KANANA_CHUNK_BYTES \
         < memory["prefill_chunk_bytes_by_chunk_size"][chunk]
     assert sized["temp"] < 1.5e9
-    d, heads = config["deployment"], config["model"]["num_attention_heads"]
-    assert d["max_batch"] == heads == 32     # the two 32s below
     assert _written_arrays(hlo, f"32,32,{chunk},4096", r"\w+") == []
     assert _written_arrays(hlo, f"1,32,{chunk},4096", "f32")     # one slot's
     # a whole leaf is only what the branch of a slot that prefills hands
     # back (two leaves, a dense layer's loop and the expert layers'): it
     # writes its window where the leaf lies, as the first lanes do
     assert made_of(hlo, config) == {
-        "grouped_matmul_kernels": 6, "cache_copies": ["conditional"] * 4,
+        "grouped_matmul_kernels": 6 + 2, "cache_copies": ["conditional"] * 4,
         "expert_weight_copies": ["fusion"] * 3}
 
 
@@ -433,6 +444,18 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
         "kernels": 4, "leaf_copies": {}, "ssm_layer_copies": []}
 
 
+# Kimi's programs since PR 41: every slot's first lane attends through the
+# `mla_attend` kernel, so the decode program's temporaries are no longer one
+# MLA layer's float32 scores for all slots (168 MB of the file's
+# `decode_step_temp_bytes` 174,842,368) but the head's pieces, and the chunk
+# program holds the kernel's operands beside one slot's scores. The
+# configuration file is the benchmark's and keeps PR 40's bytes
+# (13,785,504,256 and 14,075,276,800) until a `benchmark` issue
+KIMI_DECODE_BYTES = 13_642_395_648
+KIMI_DECODE_TEMP_BYTES = 31_733_760
+KIMI_CHUNK_BYTES = 14_075_437_568
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         chips, as_on_tpu, program):
@@ -443,18 +466,19 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
     bytes the file gives, room for the pool of both kinds beside the larger;
     the Pallas kernels (the delta rule's update in the dense layer's body
     and in the KDA expert layers', the experts' three grouped matmuls in
-    each of the two expert bodies: 8 in the decode program, and the further
-    lanes' six more in the chunk program, whose chunked delta rule is no
-    kernel); no instruction
+    each of the two expert bodies, `mla_attend` in the MLA body: 9 in the
+    decode program, and the further lanes' six more in the chunk program,
+    whose chunked delta rule and whose attention over one slot's rows are no
+    kernels); no float32 scores `[128, 32, 1, 10240]` of every slot's first
+    lane written; no instruction
     copies a cache leaf (the kernel aliases the state, the layers' loops
     carry the four leaves, and no layer's kind is a branch: a loop a kind
     that turns as often as the run is long or not at all) or materialises
     one layer's state for all slots; **none materialises an expert matrix**,
     one layer's [64, d, F] or the stack's [512, d, F] (ROADMAP S12a: the
     grouped matmuls read the stack where it lies); the decode program's
-    temporaries are one MLA layer's scores and the head's pieces, the chunk
-    program's under half a gigabyte: neither computes the padding of 128 x
-    128 lanes."""
+    temporaries are the head's pieces, the chunk program's under half a
+    gigabyte: neither computes the padding of 128 x 128 lanes."""
     import json
 
     chip_dir, _ = _chip_bench()
@@ -466,10 +490,14 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
     memory = config["memory"]
     compiled = compile_step(config, chips, program)
     sized = program_bytes(compiled)
-    want = (memory["decode_step_bytes"] if program == "decode" else memory[
-        "prefill_chunk_bytes_by_chunk_size"][
-            str(config["deployment"]["prefill_chunk_size"])])
-    assert sized["total"] == want
+    chunk = str(config["deployment"]["prefill_chunk_size"])
+    if program == "decode":
+        assert sized["total"] == KIMI_DECODE_BYTES \
+            < memory["decode_step_bytes"]
+    else:
+        assert sized["total"] == KIMI_CHUNK_BYTES
+        assert KIMI_CHUNK_BYTES - memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk] == 160_768
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 128 * 128 * 4)     # the chunk's tokens
     assert sized["arguments"] >= 0.75 * HBM_BYTES
@@ -480,12 +508,44 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
     assert pool_bytes(config) == memory["prefix_pool_bytes"]
     assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
     if program == "decode":
-        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 28
+        assert sized["temp"] == KIMI_DECODE_TEMP_BYTES \
+            < memory["decode_step_temp_bytes"] // 5
     else:
         assert sized["temp"] < 2 ** 29
-    assert made_of(compiled.as_text(), config) == {
-        "kernels": 8 if program == "decode" else 14, "leaf_copies": {},
+    hlo = compiled.as_text()
+    assert _written_arrays(hlo, "128,32,(?:1,)?10240", "f32") == []
+    assert made_of(hlo, config) == {
+        "kernels": 9 if program == "decode" else 15, "leaf_copies": {},
         "kda_layer_copies": [], "expert_matrix_copies": []}
+
+
+@pytest.mark.parametrize("L,B,T,block", [
+    (2, 128, 10240, 1024), (8, 32, 4096, 1024), (2, 8, 1000, 256)],
+    ids=["kimi", "kanana", "a-ragged-last-block"])
+def test_mla_attend_kernel_reads_the_leaves_where_they_lie(
+        chips, monkeypatch, L, B, T, block):
+    """`ops/mla_attend.py` alone at the published head count and widths and
+    the two cells' cache shapes: Mosaic accepts the blocks (a length that no
+    whole block divides too), and the program holds nothing beside its
+    arguments: the rotary key's leaf, which the compiler keeps with the
+    positions on the lanes, is handed over as it lies, not copied into 128
+    padded lanes a position (671 MB a call at Kimi's shape)."""
+    op = importlib.import_module("ray_tpu.ops.mla_attend")
+    assert op.BLOCK == 1024
+    monkeypatch.setattr(op, "BLOCK", block)
+    assert op._block(T) == block
+    one = SingleDeviceSharding(chips[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: op.mla_attend(
+        *a, 192 ** -0.5, kernel=True)).lower(
+        arr((B, 32, 512)), arr((B, 32, 64)), arr((L, B, T, 512)),
+        arr((L, B, T, 64)), arr((), jnp.int32), arr((B,), jnp.int32),
+        arr((B,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_token_selection_compiles_at_xl_vocabulary(chips):
