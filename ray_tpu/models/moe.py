@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import llama as _llama
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.expert_mlp import expert_mlp
+from ray_tpu.ops.grouped_matmul import TILE_M, grouped_matmul
 from ray_tpu.parallel.mesh import constrain, current_mesh, logical_to_spec
 
 Params = Any
@@ -272,7 +273,11 @@ def _rows_times_experts(xs, w, group_sizes, first_expert):
     float32, `models/kimi.py`) go as the two pieces of the matrices' dtype
     that add up to them, a row's two side by side so that the groups stay
     sorted, and come back float32: one pass of the matrices, none of the
-    rows' rounding in the result."""
+    rows' rounding in the result. The pieces and both pieces' products pass
+    through HBM here: it is the form of the `cpu` backend, of many rows a
+    group, and what `ops/expert_mlp.py` is tested against; a served
+    program's few float32 rows a group take that kernel on the chip
+    (`_one_kernel`), where the pieces never leave VMEM."""
     if xs.dtype == w.dtype:
         return grouped_matmul(xs, w, group_sizes, first_expert)
     bits = jnp.finfo(w.dtype)
@@ -283,6 +288,28 @@ def _rows_times_experts(xs, w, group_sizes, first_expert):
                           2 * group_sizes, first_expert,
                           out_dtype=jnp.float32)
     return both[0::2] + both[1::2]
+
+
+def _three_products(xs, wg, wu, wd, group_sizes, first_expert):
+    """The experts' SwiGLU of the sorted rows as three grouped matmuls."""
+    g = _rows_times_experts(xs, wg, group_sizes, first_expert)
+    u = _rows_times_experts(xs, wu, group_sizes, first_expert)
+    return _rows_times_experts(jax.nn.silu(g) * u, wd, group_sizes,
+                               first_expert)
+
+
+def _one_kernel(xs, w) -> bool:
+    """Whether the experts' SwiGLU goes as `ops/expert_mlp.py`'s one kernel
+    and not as three grouped matmuls: on the tpu backend, float32 rows
+    against matrices held narrower (`_rows_times_experts`' two-piece case,
+    where the three calls write and read back both pieces' float32
+    products) and fewer than `TILE_M` rows a group (`grouped_matmul`'s own
+    few-rows rule; the kernel holds an expert's weight tile while its rows
+    go by, which many rows an expert would make the MXU's work). Rows in
+    the matrices' dtype (`models/deepseek.py`, training) keep the three
+    calls, which have a backward pass."""
+    return (jax.default_backend() == "tpu" and xs.dtype == jnp.float32
+            and w.dtype != xs.dtype and xs.shape[0] < w.shape[0] * TILE_M)
 
 
 def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
@@ -317,10 +344,8 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
             xs = jnp.where(mine, xs, 0)
 
     with jax.named_scope("moe_experts"):
-        g = _rows_times_experts(xs, wg, group_sizes, first_expert)
-        u = _rows_times_experts(xs, wu, group_sizes, first_expert)
-        h = jax.nn.silu(g) * u
-        ys = _rows_times_experts(h, wd, group_sizes, first_expert)
+        form = expert_mlp if _one_kernel(xs, wg) else _three_products
+        ys = form(xs, wg, wu, wd, group_sizes, first_expert)
 
     with jax.named_scope("moe_dispatch"):
         if first_expert is not None:

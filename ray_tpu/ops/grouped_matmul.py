@@ -33,7 +33,17 @@ whole contraction in one tile (64, 2048, 768) / (64, 768, 2048) 1.38 ms
 (64, 2048, 768) 2.62). A row tile far above a group's rows multiplies
 mostly masked rows, and a contraction cut in two reads each row tile
 twice: so below `TILE_M` rows a group the tiles are 128 rows by the whole
-contraction. On the `cpu` backend
+contraction.
+
+Which regime takes which path (`models/moe.py`, `_one_kernel`): rows in the
+matrices' own dtype come here, three calls an expert layer, at any number of
+rows a group: training (OLMoE, with the custom VJP) and Kanana's serving
+programs. Float32 rows against bf16 matrices at fewer than `TILE_M` rows a
+group (Kimi's serving programs) do not since PR 42: there the three calls
+were nine, with the rows' two pieces and both pieces' float32 products in
+HBM between them, and `ops/expert_mlp.py` does the layer's whole SwiGLU in
+one kernel. Float32 rows at many rows a group still come here as two pieces
+(`moe._rows_times_experts`). On the `cpu` backend
 — the tests' virtual mesh, and nothing else — the same arithmetic is
 `jax.lax.ragged_dot`; `interpret=True` runs the kernel itself there
 (tests/test_olmoe.py).

@@ -1,0 +1,168 @@
+"""`ops/expert_mlp.py`: the kernel, interpreted, against the three grouped
+matmuls and the SwiGLU it stands for (`moe._three_products`); its plan; and
+where `moe._experts` takes it."""
+
+import functools
+import importlib
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import moe
+
+op = importlib.import_module("ray_tpu.ops.expert_mlp")
+
+D = 128
+SMALL = (32, 16, 128)       # rows a block, rows a product, columns of F
+
+
+def _operands(R, F, G, dtype, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    bf = jnp.bfloat16
+    return (jax.random.normal(ks[0], (R, D), jnp.float32).astype(dtype),
+            (jax.random.normal(ks[1], (G, D, F)) / D ** 0.5).astype(bf),
+            (jax.random.normal(ks[2], (G, D, F)) / D ** 0.5).astype(bf),
+            (jax.random.normal(ks[3], (G, F, D)) / F ** 0.5).astype(bf))
+
+
+def _both(sizes, G, F=256, first=None, dtype=jnp.float32, tiles=SMALL):
+    """(the kernel's rows, the three products') of the stack's groups, and
+    the kernel's whole result."""
+    sizes = np.asarray(sizes, np.int32)
+    R = int(sizes.sum())
+    args = (*_operands(R, F, G, dtype), jnp.asarray(sizes),
+            None if first is None else jnp.int32(first))
+    got = jax.jit(lambda *a: op.expert_mlp(*a, tiles=tiles, interpret=True))(
+        *args)
+    want = jax.jit(moe._three_products)(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype
+    ends = np.cumsum(sizes)
+    lo = (ends - sizes)[first or 0]
+    hi = ends[(first or 0) + G - 1]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return got[lo:hi], want[lo:hi], got
+
+
+# two pieces a float32 row in both forms, float32 sums in another order
+EXACT = dict(rtol=0, atol=2e-5)
+# the three products round g, u and the result to bf16 between them
+ONE_PIECE = dict(rtol=0, atol=4e-2)
+
+
+@pytest.mark.parametrize("sizes,G,kwargs,tolerance", [
+    ([5, 0, 7, 3], 4, {}, EXACT),
+    ([5, 0, 0, 9, 2], 5, {}, EXACT),
+    ([20, 30, 14], 3, {}, EXACT),
+    ([3, 128, 5, 24], 4, {}, EXACT),
+    ([3, 128, 5, 120], 4, {"tiles": None}, EXACT),
+    ([5, 7, 3, 49], 3, {"first": 0}, EXACT),
+    ([0, 0, 0, 0, 4, 0, 6, 1, 0, 0, 0, 0, 37], 12, {"first": 0}, EXACT),
+    ([11, 0, 4, 0, 6, 1, 26], 4, {"first": 2}, EXACT),
+    ([5, 0, 7, 3, 49], 5, {"dtype": jnp.bfloat16}, ONE_PIECE),
+    ([20, 30, 14], 3, {"dtype": jnp.bfloat16}, ONE_PIECE),
+    ([5, 0, 7, 20], 4, {"F": 320}, EXACT),
+    ([5, 0, 7, 20], 4, {"F": 200}, EXACT),
+    ([2, 1], 2, {}, EXACT),
+    ([9, 0, 31, 63], 4, {"tiles": (64, 32, 256), "F": 512}, EXACT)],
+    ids=["an-empty-group-between-two-touched", "two-empty-groups",
+         "groups-that-cross-a-row-tile", "one-expert-with-128-rows",
+         "128-rows-at-the-ops-own-tiles", "rows-past-the-stacks-end",
+         "a-layer-of-the-stack-other-than-the-first",
+         "a-shard-that-starts-at-group-2", "one-piece-rows",
+         "one-piece-rows-across-tiles", "F-in-tiles-and-a-part-of-one",
+         "F-no-multiple-of-the-lanes", "fewer-rows-than-a-sublane-tile",
+         "two-tiles-of-F-and-of-rows"])
+def test_the_kernel_is_the_three_products_and_the_swiglu(sizes, G, kwargs,
+                                                         tolerance):
+    got, want, _ = _both(sizes, G, **kwargs)
+    np.testing.assert_allclose(got, want, **tolerance)
+    assert np.abs(want).max() > 0.3
+
+
+def test_rows_of_no_matrix_come_back_zero_beside_a_held_group_or_unread():
+    """Groups past the stack's end: their rows in a row tile that a held
+    group shares are zero; the tiles past it are never visited."""
+    sizes = [5, 7, 100]                     # the stack holds the first two
+    got, want, whole = _both(sizes, 2, first=0)
+    np.testing.assert_allclose(got, want, **EXACT)
+    assert not whole[12:32].any()
+    *_, visits = op._plan(jnp.asarray(sizes, jnp.int32), jnp.int32(0), 2,
+                          112, 32)
+    assert int(visits) == 2
+
+
+def test_the_plan_visits_each_touched_expert_once_a_row_tile_it_has_rows_in():
+    sizes = jnp.asarray([3, 0, 40, 0, 21, 64], jnp.int32)
+    (group, tile, lo, hi), visits = op._plan(sizes, None, 6, 128, 32)
+    n = int(visits)
+    assert n == 6 and group.shape == (6 + 4 - 1,)
+    assert list(zip(*(np.asarray(a)[:n].tolist()
+                      for a in (group, tile, lo, hi)))) == [
+        (0, 0, 0, 3), (2, 0, 3, 32), (2, 1, 0, 11), (4, 1, 11, 32),
+        (5, 2, 0, 32), (5, 3, 0, 32)]
+
+
+def test_a_float32_row_goes_as_both_its_pieces_and_h_too():
+    """Against the product of the float32 rows themselves (float64): the
+    kernel's result is what two pieces leave of a row away (2^-17 of it),
+    the one-piece product (rows rounded to bf16, `h` too) a hundred times
+    as far: what a `bfloat16_state`-style degradation is caught by."""
+    sizes = jnp.asarray([9, 0, 31, 24], jnp.int32)
+    xs, wg, wu, wd = _operands(64, 256, 4, jnp.float32)
+    run = jax.jit(lambda *a: op.expert_mlp(*a, tiles=SMALL, interpret=True))
+    two = np.asarray(run(xs, wg, wu, wd, sizes), np.float64)
+    one = np.asarray(run(xs.astype(jnp.bfloat16), wg, wu, wd, sizes),
+                     np.float64)
+    group = np.repeat(np.arange(4), np.asarray(sizes))
+    x, g, u, d = (np.asarray(a, np.float64) for a in (xs, wg, wu, wd))
+    a, b = (np.einsum("rd,rdf->rf", x, w[group]) for w in (g, u))
+    exact = np.einsum("rf,rfd->rd", a / (1 + np.exp(-a)) * b, d[group])
+    assert np.abs(two - exact).max() < 3e-5
+    assert np.abs(one - exact).max() > 100 * np.abs(two - exact).max()
+
+
+@pytest.mark.parametrize("rows,held,stack,backend,takes", [
+    (jnp.float32, jnp.bfloat16, 512, "tpu", True),
+    (jnp.float32, jnp.bfloat16, 512, "cpu", False),
+    (jnp.bfloat16, jnp.bfloat16, 512, "tpu", False),
+    (jnp.float32, jnp.float32, 512, "tpu", False),
+    (jnp.float32, jnp.bfloat16, 1, "tpu", False)],
+    ids=["float32-rows-few-a-group", "off-the-chip", "rows-of-one-piece",
+         "matrices-as-wide-as-the-rows", "many-rows-a-group"])
+def test_the_experts_take_the_kernel_where_their_arguments_say(
+        monkeypatch, rows, held, stack, backend, takes):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    xs = jax.ShapeDtypeStruct((1024, 64), rows)
+    w = jax.ShapeDtypeStruct((stack, 64, 32), held)
+    assert moe._one_kernel(xs, w) is takes
+
+
+def test_experts_through_the_kernel_are_experts_through_the_three_products(
+        monkeypatch):
+    """`moe._experts` as `models/kimi.py` calls it (float32 tokens, a bf16
+    stack of every layer's held experts, the pairs of experts that are not
+    held past its end) on the chip's branch, the kernel interpreted, against
+    the branch the CPU takes."""
+    N, K, held, layers, F = 24, 4, 4, 3, 256
+    stack = held * layers
+    ks = jax.random.split(jax.random.key(1), 3)
+    x = jax.random.normal(ks[0], (N, 1, D), jnp.float32)
+    gates = jax.nn.softmax(jax.random.normal(ks[1], (N, 1, K)))
+    local = jax.random.randint(ks[2], (N, 1, K), -4, 8)
+    entry = jnp.where((local >= 0) & (local < held), 2 * held + local, stack)
+    _, wg, wu, wd = _operands(1, F, stack, jnp.float32)
+    cfg = types.SimpleNamespace(n_experts=stack + 1, experts_per_token=K,
+                                dtype=jnp.float32)
+    run = jax.jit(lambda: moe._experts(x, gates, entry, wg, wu, wd, cfg,
+                                       first_expert=jnp.int32(0)))
+    want = np.asarray(run())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "expert_mlp", functools.partial(
+        op.expert_mlp, tiles=SMALL, interpret=True))
+    got = np.asarray(jax.jit(lambda: moe._experts(
+        x, gates, entry, wg, wu, wd, cfg, first_expert=jnp.int32(0)))())
+    np.testing.assert_allclose(got, want, **EXACT)
+    assert np.abs(want).max() > 0.1

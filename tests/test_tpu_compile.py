@@ -444,16 +444,19 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
         "kernels": 4, "leaf_copies": {}, "ssm_layer_copies": []}
 
 
-# Kimi's programs since PR 41: every slot's first lane attends through the
-# `mla_attend` kernel, so the decode program's temporaries are no longer one
-# MLA layer's float32 scores for all slots (168 MB of the file's
-# `decode_step_temp_bytes` 174,842,368) but the head's pieces, and the chunk
-# program holds the kernel's operands beside one slot's scores. The
+# Kimi's programs since PR 42: every slot's first lane attends through the
+# `mla_attend` kernel (PR 41: the decode program's temporaries are no longer
+# one MLA layer's float32 scores for all slots, 168 MB of the file's
+# `decode_step_temp_bytes` 174,842,368, but the head's pieces), and an expert
+# layer's routed SwiGLU is one `expert_mlp` kernel (PR 42: the rows' pieces
+# and both pieces' float32 products `[2048, 1024]`, `[2048, 1024]`,
+# `[2048, 2304]` are no temporaries any more; with the three grouped matmuls
+# the programs needed 13,642,395,648 / 31,733,760 and 14,075,437,568). The
 # configuration file is the benchmark's and keeps PR 40's bytes
 # (13,785,504,256 and 14,075,276,800) until a `benchmark` issue
-KIMI_DECODE_BYTES = 13_642_395_648
-KIMI_DECODE_TEMP_BYTES = 31_733_760
-KIMI_CHUNK_BYTES = 14_075_437_568
+KIMI_DECODE_BYTES = 13_639_474_176
+KIMI_DECODE_TEMP_BYTES = 28_812_288
+KIMI_CHUNK_BYTES = 14_072_986_624
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -465,18 +468,20 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
     float32 state and 10,240 positions of latent rows, chunks of 128): the
     bytes the file gives, room for the pool of both kinds beside the larger;
     the Pallas kernels (the delta rule's update in the dense layer's body
-    and in the KDA expert layers', the experts' three grouped matmuls in
-    each of the two expert bodies, `mla_attend` in the MLA body: 9 in the
-    decode program, and the further lanes' six more in the chunk program,
-    whose chunked delta rule and whose attention over one slot's rows are no
+    and in the KDA expert layers', one `expert_mlp` in each of the two
+    expert bodies, `mla_attend` in the MLA body: 5 in the decode program,
+    and the further lanes' two more `expert_mlp` in the chunk program, whose
+    chunked delta rule and whose attention over one slot's rows are no
     kernels); no float32 scores `[128, 32, 1, 10240]` of every slot's first
-    lane written; no instruction
+    lane written, and none of the experts' products of both pieces of 1,024
+    rows, `[2048, F]` or `[2048, d]`: g, u and h stay in VMEM; no
+    instruction
     copies a cache leaf (the kernel aliases the state, the layers' loops
     carry the four leaves, and no layer's kind is a branch: a loop a kind
     that turns as often as the run is long or not at all) or materialises
     one layer's state for all slots; **none materialises an expert matrix**,
     one layer's [64, d, F] or the stack's [512, d, F] (ROADMAP S12a: the
-    grouped matmuls read the stack where it lies); the decode program's
+    kernel reads the stack where it lies); the decode program's
     temporaries are the head's pieces, the chunk program's under half a
     gigabyte: neither computes the padding of 128 x 128 lanes."""
     import json
@@ -495,9 +500,8 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         assert sized["total"] == KIMI_DECODE_BYTES \
             < memory["decode_step_bytes"]
     else:
-        assert sized["total"] == KIMI_CHUNK_BYTES
-        assert KIMI_CHUNK_BYTES - memory[
-            "prefill_chunk_bytes_by_chunk_size"][chunk] == 160_768
+        assert sized["total"] == KIMI_CHUNK_BYTES < memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk]
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 128 * 128 * 4)     # the chunk's tokens
     assert sized["arguments"] >= 0.75 * HBM_BYTES
@@ -514,9 +518,43 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         assert sized["temp"] < 2 ** 29
     hlo = compiled.as_text()
     assert _written_arrays(hlo, "128,32,(?:1,)?10240", "f32") == []
+    assert _written_arrays(hlo, "20(?:48|32),(?:1024|2304)", "f32") == []
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in _mosaic_calls(hlo)) == (2 if program == "decode"
+                                                else 4)
     assert made_of(hlo, config) == {
-        "kernels": 9 if program == "decode" else 15, "leaf_copies": {},
+        "kernels": 5 if program == "decode" else 7, "leaf_copies": {},
         "kda_layer_copies": [], "expert_matrix_copies": []}
+
+
+@pytest.mark.parametrize("rows,F,tiles", [
+    (1024, 1024, None), (1016, 1024, None), (1024, 1024, (128, 32, 256)),
+    (1024, 1280, None)],
+    ids=["kimi", "rows-that-end-inside-a-block", "smaller-tiles",
+         "F-that-ends-inside-a-tile"])
+def test_expert_mlp_kernel_reads_the_stack_where_it_lies(chips, rows, F,
+                                                         tiles):
+    """`ops/expert_mlp.py` alone at Kimi's widths, rows and stack (float32
+    rows, the 8 x 64 held experts' bf16 matrices, the groups' sizes with the
+    one past the stack's end): Mosaic accepts the blocks (the whole
+    contraction over 2,304 lanes in one, a last block of rows or of F that
+    hangs over the end), it is one kernel, and the program holds nothing
+    beside its arguments and its result but the plan's few kilobytes: no
+    slice or copy of a layer's matrices, no pieces, no products."""
+    op = importlib.import_module("ray_tpu.ops.expert_mlp")
+    assert (op.TILE_ROWS, op.SUB_ROWS, op.TILE_F) == (256, 64, 512)
+    one = SingleDeviceSharding(chips[0])
+    D, G = 2304, 512
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: op.expert_mlp(*a, tiles=tiles)).lower(
+        arr((rows, D), jnp.float32), arr((G, D, F)), arr((G, D, F)),
+        arr((G, F, D)), arr((G + 1,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 @pytest.mark.parametrize("L,B,T,block", [
