@@ -20,9 +20,10 @@ Forms:
            slot's first lane, the further lanes only of the slots that
            prefill, a slot at a time, with the layer's weights as the
            layers' loop already holds them
-  sliced   the same but for one thing: a slot's branch slices the layer's
-           weights out of the stack itself (`granite._further_lanes` does
-           so: there slicing once made the compiler copy every matrix)
+  sliced   the same but for one thing: a slot's turn of the loop slices
+           the layer's weights out of the stack itself (granite and kimi do
+           so: there slicing once made the compiler copy every matrix;
+           `lm.each_slot` has the rule)
 
 Measured (TPU v5 lite, one chip, PR 39; ms a step, calls dispatched back to
 back, every slot at position 2,560):
@@ -42,6 +43,10 @@ prefilling at once: the engine's default budget (`max_num_batched_tokens`
 B + C) hands out at most two; a budget that lets 17 or more slots prefill
 in one step (about 17 C = 2,176 tokens here) buys steps that cost more
 than they did before PR 39, up to 549 ms where all 32 do.
+
+Since PR 43 the loop over the slots is `models/lm.each_slot`: as many turns
+as slots prefill, where the loop this table timed turned over all 32 with a
+branch each.
 """
 
 from __future__ import annotations
@@ -61,9 +66,8 @@ sys.path[:0] = [REPO, CHIP_DIR]
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from ray_tpu.models import deepseek
+from ray_tpu.models import deepseek, lm
 
 CONFIG = "kanana-2-30b-a3b-serve-1chip"
 POSITION = 2560          # the cell's slots decode at 2,100-3,650
@@ -72,32 +76,25 @@ POSITION = 2560          # the cell's slots decode at 2,100-3,650
 def further_lanes_sliced(stacks: dict):
     """`deepseek._further_lanes` but for where a slot's weights come from:
     layer l of `stacks` (the engine's `dense` and `blocks`), sliced inside
-    the branch."""
+    the loop's body."""
     n_dense = jax.tree.leaves(stacks["dense"])[0].shape[0]
 
-    def further_lanes(rest, bp, cfg, lat, kr, given, l, pos, ok):
-        B, M, D = rest.shape
+    def further_lanes(rest, bp, cfg, lat, kr, given, l, pos, ok, prefilling):
+        M = rest.shape[1]
         stack, at = ((stacks["blocks"], l - n_dense) if "moe" in bp
                      else (stacks["dense"], l))
 
-        def slot(b, rest, lat, kr, given):
-            own = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
-                a, at, 0, keepdims=False), stack)
-            xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
-            okb = lax.dynamic_slice(ok, (b, 0), (1, M))
-            first = lax.dynamic_slice(pos, (b,), (1,))
+        def slot(b, carry):
+            rest, lat, kr, given = carry
+            own = lm.layer_weights(stack, at)
+            xb, okb, first = lm.slot_lanes(b, rest, ok, pos)
             xb, lat, kr = deepseek._attention(
                 xb, own, cfg, lat, kr, l, first,
                 first[:, None] + jnp.arange(M), okb, slot=b)
             xb, given = deepseek._mlp(xb, own, cfg, given, okb)
-            return (lax.dynamic_update_slice(rest, xb, (b, 0, 0)), lat, kr,
-                    given)
+            return lm.put_lanes(rest, xb, b), lat, kr, given
 
-        def body(b, carry):
-            more = lax.dynamic_index_in_dim(ok, b, 0, keepdims=False).any()
-            return lax.cond(more, slot, lambda b, *same: same, b, *carry)
-
-        return lax.fori_loop(0, B, body, (rest, lat, kr, given))
+        return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
 
     return further_lanes
 

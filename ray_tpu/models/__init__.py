@@ -1,6 +1,7 @@
-"""Model families (pure JAX, TPU-first): gpt2, llama (GQA/RoPE/SwiGLU),
-moe (OLMoE / Mixtral sparse MoE: dropless sort-and-grouped-matmul routing,
-one-hot dispatch under expert parallelism), deepseek (DeepSeek-V3's layer
+"""Model families (pure JAX, TPU-first): gpt2, llama (GQA/RoPE/SwiGLU, for
+training only: it has no serving programs), moe (OLMoE / Mixtral sparse MoE:
+dropless sort-and-grouped-matmul routing, one-hot dispatch under expert
+parallelism), deepseek (DeepSeek-V3's layer
 for serving: latent attention over a latent cache, shared experts), brumby
 (Brumby's layer for serving: power retention over a recurrent state),
 granite (Granite 4.0-H's layers for serving: Mamba-2 state a slot beside
@@ -28,6 +29,20 @@ __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi",
 # was). A family may name both kinds (granite, kimi: the pool then keeps, under
 # one hash, a prefix's rows by the block and the state at its end, and a hit
 # needs both). A leaf neither names is the programs' own (`counts`).
+#
+# What a family borrows and what it holds. `models/lm.py` ("The serving
+# families") has what every family needs and none owns: seeded weights made
+# a layer at a time into a stack (`normal`, `ones`, `layer_program`,
+# `stack_layers`, `resident_params`, `layer_weights`), the product that
+# keeps a float32 activation whole (`dot`, `weight`; `ops/pieces.py`), the
+# short convolution (`short_conv`), and the lanes of a chunk: the contract
+# of `prefill_chunk`, the split into every slot's first lane and the rest
+# (`split_lanes`, `join_lanes`, `last_valid_lane`) and the loop over the
+# slots that prefill (`each_slot`, `slot_lanes`, `put_lanes`), whose
+# docstring is the rule on where a body takes its weights from. A family
+# module holds its config and presets, its `_init_layer` bodies, its
+# cache's leaves, its layers' arithmetic and its two programs, and nothing
+# that another family has too; it imports no underscore name of another.
 _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "kanana": ("deepseek", "DeepseekConfig"),
             "deepseek": ("deepseek", "DeepseekConfig"),

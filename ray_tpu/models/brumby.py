@@ -38,7 +38,9 @@ a_i = sum_{s < m <= i} log g_m:
 a slot at a time, so that the expanded queries of one slot's chunk
 (C x H x 8,320 floats) are all that is held of them. A lane past a slot's
 length has log g = 0 and adds nothing; a slot with no valid lane is
-skipped and keeps its state bit for bit, in both programs.
+skipped (`lm.each_slot` turns over the others alone) and keeps its state
+bit for bit, in both programs (`models/lm.py`, "The lanes of a chunk", has
+the contract).
 
 The weights exist only in the dtype the replica holds them, a layer at a
 time, as `models/deepseek.py` makes its own; the gate's projection, its
@@ -58,6 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import lm
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs
 from ray_tpu.ops import power_retention as _pr
 
@@ -124,48 +127,35 @@ EMBED_STD, ATTN_OUT_STD = 0.3, 0.02
 GATE_BIAS_RANGE = (4.0, 8.0)
 
 
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _init_layer(key: jax.Array, l, cfg: BrumbyConfig) -> Params:
     ks = jax.random.split(jax.random.fold_in(key, l), 9)
     pd, D, F = cfg.param_dtype, cfg.d_model, cfg.d_ff
     H, G, d = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     lo, hi = GATE_BIAS_RANGE
-
-    def ones(n):
-        return {"scale": jnp.ones((n,), jnp.float32)}
-
     return {
-        "attn_norm": ones(D),
+        "attn_norm": lm.ones(D),
         "attn": {
-            "wq": _normal(ks[0], (D, H, d), 0.02, pd),
-            "wk": _normal(ks[1], (D, G, d), 0.02, pd),
-            "wv": _normal(ks[2], (D, G, d), 0.02, pd),
-            "wg": _normal(ks[3], (D, G), 0.02, jnp.float32),
+            "wq": lm.normal(ks[0], (D, H, d), 0.02, pd),
+            "wk": lm.normal(ks[1], (D, G, d), 0.02, pd),
+            "wv": lm.normal(ks[2], (D, G, d), 0.02, pd),
+            "wg": lm.normal(ks[3], (D, G), 0.02, jnp.float32),
             "bg": jax.random.uniform(ks[4], (G,), jnp.float32, lo, hi),
-            "q_norm": ones(d), "k_norm": ones(d),
-            "wo": _normal(ks[5], (H * d, D), ATTN_OUT_STD, pd),
+            "q_norm": lm.ones(d), "k_norm": lm.ones(d),
+            "wo": lm.normal(ks[5], (H * d, D), ATTN_OUT_STD, pd),
         },
-        "mlp_norm": ones(D),
-        "mlp": {"wg": _normal(ks[6], (D, F), 0.02, pd),
-                "wu": _normal(ks[7], (D, F), 0.02, pd),
-                "wd": _normal(ks[8], (F, D),
+        "mlp_norm": lm.ones(D),
+        "mlp": {"wg": lm.normal(ks[6], (D, F), 0.02, pd),
+                "wu": lm.normal(ks[7], (D, F), 0.02, pd),
+                "wd": lm.normal(ks[8], (F, D),
                               0.02 / math.sqrt(2 * cfg.n_layer), pd)},
     }
 
 
-@functools.lru_cache(maxsize=None)
-def _layer_program(cfg: BrumbyConfig):
-    return jax.jit(lambda key, l: _init_layer(key, l, cfg))
-
-
 def init_layer(key: jax.Array, l: int, cfg: BrumbyConfig) -> Params:
     """Layer l's weights from `fold_in(key, l)` and nothing else, by the one
-    compiled program that makes them wherever they are made: a layer made
-    alone is, to the bit, the layer in `init_params`' tree."""
-    return _layer_program(cfg)(key, jnp.int32(l))
+    compiled program (`lm.layer_program`): a layer made alone is, to the
+    bit, the layer in `init_params`' tree."""
+    return lm.layer_program(_init_layer, cfg)(key, jnp.int32(l))
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -175,34 +165,20 @@ def init_ends(key: jax.Array, cfg: BrumbyConfig) -> Params:
     (eager, each matrix would exist in float32 first: 3.1 GB)."""
     k_emb, k_head = jax.random.split(jax.random.fold_in(key, cfg.n_layer))
     pd, D, V = cfg.param_dtype, cfg.d_model, cfg.vocab_size
-    return {"wte": _normal(k_emb, (V, D), EMBED_STD, pd),
-            "final_norm": {"scale": jnp.ones((D,), jnp.float32)},
-            "lm_head": _normal(k_head, (D, V), 0.02, pd)}
+    return {"wte": lm.normal(k_emb, (V, D), EMBED_STD, pd),
+            "final_norm": lm.ones(D),
+            "lm_head": lm.normal(k_head, (D, V), 0.02, pd)}
 
 
 def init_params(key: jax.Array, cfg: BrumbyConfig) -> Params:
     """The whole tree, every leaf made in the dtype it is held in, `blocks`
-    stacked on a leading layer axis: the stack is allocated once and each
-    layer's program writes its layer into it (donated), so the most that
-    exists beside the tree is one layer."""
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def put(stack, layer, i):
-        return jax.tree.map(
-            lambda s, a: lax.dynamic_update_index_in_dim(s, a, i, 0),
-            stack, layer)
-
-    shapes = jax.eval_shape(lambda: init_layer(key, 0, cfg))
-    blocks = jax.jit(lambda: jax.tree.map(
-        lambda s: jnp.zeros((cfg.n_layer,) + s.shape, s.dtype), shapes))()
-    for i in range(cfg.n_layer):
-        blocks = put(blocks, init_layer(key, i, cfg), jnp.int32(i))
-    return {**init_ends(key, cfg), "blocks": blocks}
+    stacked on a leading layer axis a layer at a time (`lm.stack_layers`:
+    the most that exists beside the tree is one layer)."""
+    return {**init_ends(key, cfg), "blocks": lm.stack_layers(
+        lambda l: init_layer(key, l, cfg), cfg.n_layer)}
 
 
-def resident_params(params: Params, cfg: BrumbyConfig) -> Params:
-    """`init_params` makes the tree a replica holds: nothing to convert."""
-    del cfg
-    return params
+resident_params = lm.resident_params
 
 
 def resident_specs(cfg: BrumbyConfig, rules=None) -> Params:
@@ -240,11 +216,6 @@ def init_cache(cfg: BrumbyConfig, batch: int, max_len: Optional[int] = None):
 _HIGHEST = lax.Precision.HIGHEST
 
 
-def _w(p, cfg: BrumbyConfig):
-    with jax.named_scope("weights_cast"):
-        return p.astype(cfg.dtype)
-
-
 def _project(x, bp, cfg: BrumbyConfig, pos):
     """x [B,C,D] float32 -> q [B,C,H,d], k, v [B,C,G,d] and the gates'
     logarithms [B,C,G], all float32, q and k normed and turned."""
@@ -252,11 +223,11 @@ def _project(x, bp, cfg: BrumbyConfig, pos):
     with jax.named_scope("retention_project"):
         h32 = rms_norm(x, bp["attn_norm"], cfg.norm_eps)
         h = h32.astype(cfg.dtype)
-        q = jnp.einsum("bcd,dhk->bchk", h, _w(p["wq"], cfg),
+        q = jnp.einsum("bcd,dhk->bchk", h, lm.weight(p["wq"], cfg.dtype),
                        preferred_element_type=jnp.float32)
-        k = jnp.einsum("bcd,dhk->bchk", h, _w(p["wk"], cfg),
+        k = jnp.einsum("bcd,dhk->bchk", h, lm.weight(p["wk"], cfg.dtype),
                        preferred_element_type=jnp.float32)
-        v = jnp.einsum("bcd,dhk->bchk", h, _w(p["wv"], cfg),
+        v = jnp.einsum("bcd,dhk->bchk", h, lm.weight(p["wv"], cfg.dtype),
                        preferred_element_type=jnp.float32)
         # the gate reads the norm's float32 output, not its rounding
         log_g = jax.nn.log_sigmoid(
@@ -283,12 +254,14 @@ def _retention_step(q, k, v, log_g, state, norm, l, active,
     return y.reshape(B, G * R, d), state, norm
 
 
-def _retention_chunk(q, k, v, log_g, state, norm, l, ok, cfg: BrumbyConfig):
+def _retention_chunk(q, k, v, log_g, state, norm, l, ok, prefilling,
+                     cfg: BrumbyConfig):
     """The chunked form for C lanes a slot: q [B,C,H,d], k, v [B,C,G,d],
     log_g [B,C,G], ok [B,C] -> (y [B,C,H,d], state, norm). A slot at a time
     (its state is one stretch of the leaf, and its expanded queries,
-    C x H x W floats, all that is held of them); a slot with no valid lane
-    is skipped, and its state is what it was."""
+    C x H x W floats, all that is held of them), the slots `prefilling`
+    alone (`lm.slots_first` of those with a valid lane): any other is
+    skipped, and its state is what it was."""
     B, C = ok.shape
     G, R, d, W = (cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim,
                   cfg.expanded_width)
@@ -301,7 +274,8 @@ def _retention_chunk(q, k, v, log_g, state, norm, l, ok, cfg: BrumbyConfig):
         def take(x, b):
             return lax.dynamic_index_in_dim(x, b, 0, keepdims=False)
 
-        def slot(b, state, norm, ys):
+        def slot(b, carry):
+            state, norm, ys = carry
             s = lax.dynamic_slice(state, (l, b, 0, 0, 0),
                                   (1, 1, G, d, W))[0, 0]           # [G,d,W]
             z = lax.dynamic_slice(norm, (l, b, 0, 0), (1, 1, G, W))[0, 0]
@@ -334,30 +308,29 @@ def _retention_chunk(q, k, v, log_g, state, norm, l, ok, cfg: BrumbyConfig):
                                              (l, b, 0, 0)),
                     lax.dynamic_update_slice(ys, y[None], (b, 0, 0, 0, 0)))
 
-        def body(b, carry):
-            return lax.cond(take(ok, b).any(), slot,
-                            lambda b, *same: same, b, *carry)
-
-        state, norm, ys = lax.fori_loop(
-            0, B, body, (state, norm, jnp.zeros((B, C, G, R, d), jnp.float32)))
+        state, norm, ys = lm.each_slot(
+            prefilling, slot,
+            (state, norm, jnp.zeros((B, C, G, R, d), jnp.float32)))
     return ys.reshape(B, C, G * R, d), state, norm
 
 
-def _mixer(x, bp, cfg: BrumbyConfig, state, norm, l, pos, ok, step: bool):
+def _mixer(x, bp, cfg: BrumbyConfig, state, norm, l, pos, ok, prefilling):
+    """The recurrence for one lane a slot (`prefilling` None: the decode
+    program), else the chunked form for the slots `prefilling`."""
     B, C, _ = x.shape
     with jax.named_scope("attn"):
         q, k, v, log_g = _project(x, bp, cfg, pos)
-        if step:
+        if prefilling is None:
             y, state, norm = _retention_step(
                 q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], state, norm, l,
                 ok[:, 0], cfg)
             y = y[:, None]
         else:
             y, state, norm = _retention_chunk(q, k, v, log_g, state, norm,
-                                              l, ok, cfg)
+                                              l, ok, prefilling, cfg)
         with jax.named_scope("retention_project"):
             o = jnp.dot(y.reshape(B, C, -1).astype(cfg.dtype),
-                        _w(bp["attn"]["wo"], cfg),
+                        lm.weight(bp["attn"]["wo"], cfg.dtype),
                         preferred_element_type=x.dtype)
     return x + o, state, norm
 
@@ -366,15 +339,16 @@ def _mlp(x, bp, cfg: BrumbyConfig):
     with jax.named_scope("mlp"):
         h = rms_norm(x, bp["mlp_norm"], cfg.norm_eps).astype(cfg.dtype)
         p = bp["mlp"]
-        up = jax.nn.silu(h @ _w(p["wg"], cfg)) * (h @ _w(p["wu"], cfg))
-        return x + jnp.dot(up, _w(p["wd"], cfg),
+        wg, wu = lm.weight(p["wg"], cfg.dtype), lm.weight(p["wu"], cfg.dtype)
+        up = jax.nn.silu(h @ wg) * (h @ wu)
+        return x + jnp.dot(up, lm.weight(p["wd"], cfg.dtype),
                            preferred_element_type=x.dtype)
 
 
 def _logits(params: Params, x, cfg: BrumbyConfig):
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
-        return jnp.dot(x, _w(params["lm_head"], cfg),
+        return jnp.dot(x, lm.weight(params["lm_head"], cfg.dtype),
                        preferred_element_type=jnp.float32)
 
 
@@ -386,11 +360,14 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     ok = (lane[None, :] < length[:, None]) & active[:, None]
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
+        # the chunk program's slots: those with a valid lane, once a step
+        prefilling = None if step else lm.slots_first(ok.any(axis=1))
 
     def body(carry, layer):
         x, state, norm = carry
         l, bp = layer
-        x, state, norm = _mixer(x, bp, cfg, state, norm, l, pos, ok, step)
+        x, state, norm = _mixer(x, bp, cfg, state, norm, l, pos, ok,
+                                prefilling)
         return (_mlp(x, bp, cfg), state, norm), None
 
     # the state is a carry: one buffer from layer to layer, written in place
@@ -399,20 +376,16 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
         (x, state, norm), _ = lax.scan(
             body, (x, cache["state"], cache["norm"]),
             (jnp.arange(cfg.n_layer), params["blocks"]))
-    last = jnp.clip(length - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _logits(params, x_last, cfg), {"state": state, "norm": norm}
+    return (_logits(params, lm.last_valid_lane(x, length), cfg),
+            {"state": state, "norm": norm})
 
 
 def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
                   length: jax.Array, active: jax.Array, cfg: BrumbyConfig):
-    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
-    slot), pos0 [B] (the position of the chunk's first token: RoPE reads it,
-    the state does not), length [B] (valid tokens, 0..C), active [B] ->
-    (logits [B, vocab] float32 at each slot's last valid lane, the cache).
-    Inactive and zero-length slots leave their state as it was, bit for bit,
-    and their logits are garbage. The state continues whatever the slot
-    held: a new sequence's slot is the caller's to zero. Donate `cache`."""
+    """`gpt2.prefill_chunk`'s signature and every family's contract
+    (`models/lm.py`, "The lanes of a chunk"): -> (logits [B, vocab] float32
+    at each slot's last valid lane, the cache). RoPE reads pos0; the state
+    does not. Donate `cache`."""
     return _forward(params, cache, tokens, pos0, length, active, cfg, False)
 
 

@@ -54,16 +54,16 @@ path `_experts`, at any number of rows.
 The two step programs are one function (`_chunk`): `decode_step` is every
 slot's first lane through the layers, all slots at once, and
 `prefill_chunk` is that plus the lanes after the first of the slots whose
-chunk has any, a slot at a time (`_further_lanes`): a lane is computed only
-where the plan put a token, so a chunk step costs a decode step and a term
-a slot that prefills, not B x C lanes whoever prefills (PERF.md, PR 39;
-`benchmarks/kanana_chunk_lanes.py` has the table).
+chunk has any, a slot at a time (`models/lm.py`, "The lanes of a chunk",
+has the loop and the contract; `_further_lanes` is a slot's layer): a lane
+is computed only where the plan put a token, so a chunk step costs a decode
+step and a term a slot that prefills, not B x C lanes whoever prefills
+(PERF.md, PR 39; `benchmarks/kanana_chunk_lanes.py` has the table).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Optional
 
@@ -71,7 +71,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import moe as _moe
+from ray_tpu.models import lm, moe as _moe
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs
 from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
 
@@ -149,21 +149,17 @@ CACHE_TOKEN_AXIS = {"latent": 2, "k_rope": 2}
 EMBED_STD, ATTN_OUT_STD, ROUTER_BIAS_STD = 0.3, 0.02, 0.02
 
 
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _attn_params(key, cfg: DeepseekConfig) -> Params:
     ks = jax.random.split(key, 4)
     pd, D, H = cfg.param_dtype, cfg.d_model, cfg.n_head
     return {
-        "wq": _normal(ks[0], (D, H, cfg.qk_head_dim), 0.02, pd),
-        "wkva": _normal(ks[1], (D, cfg.cache_width), 0.02, pd),
-        "kv_norm": {"scale": jnp.ones((cfg.kv_lora_rank,), jnp.float32)},
-        "wkvb": _normal(ks[2], (cfg.kv_lora_rank, H,
+        "wq": lm.normal(ks[0], (D, H, cfg.qk_head_dim), 0.02, pd),
+        "wkva": lm.normal(ks[1], (D, cfg.cache_width), 0.02, pd),
+        "kv_norm": lm.ones(cfg.kv_lora_rank),
+        "wkvb": lm.normal(ks[2], (cfg.kv_lora_rank, H,
                                 cfg.qk_nope_head_dim + cfg.v_head_dim),
                         0.02, pd),
-        "wo": _normal(ks[3], (H * cfg.v_head_dim, D), ATTN_OUT_STD, pd),
+        "wo": lm.normal(ks[3], (H * cfg.v_head_dim, D), ATTN_OUT_STD, pd),
     }
 
 
@@ -171,9 +167,9 @@ def _swiglu_params(key, cfg: DeepseekConfig, width: int,
                    resid_std: float) -> Params:
     ks = jax.random.split(key, 3)
     pd, D = cfg.param_dtype, cfg.d_model
-    return {"wg": _normal(ks[0], (D, width), 0.02, pd),
-            "wu": _normal(ks[1], (D, width), 0.02, pd),
-            "wd": _normal(ks[2], (width, D), resid_std, pd)}
+    return {"wg": lm.normal(ks[0], (D, width), 0.02, pd),
+            "wu": lm.normal(ks[1], (D, width), 0.02, pd),
+            "wd": lm.normal(ks[2], (width, D), resid_std, pd)}
 
 
 def _init_layer(key: jax.Array, l, cfg: DeepseekConfig,
@@ -181,36 +177,31 @@ def _init_layer(key: jax.Array, l, cfg: DeepseekConfig,
     ks = jax.random.split(jax.random.fold_in(key, l), 6)
     pd, D, E, F = cfg.param_dtype, cfg.d_model, cfg.n_experts, cfg.d_ff_expert
     resid_std = 0.02 / math.sqrt(2 * cfg.n_layer)
-    ones = {"scale": jnp.ones((D,), jnp.float32)}
-    layer = {"attn_norm": ones, "attn": _attn_params(ks[0], cfg),
-             "mlp_norm": ones}
+    layer = {"attn_norm": lm.ones(D), "attn": _attn_params(ks[0], cfg),
+             "mlp_norm": lm.ones(D)}
     if dense:
         layer["mlp"] = _swiglu_params(ks[1], cfg, cfg.d_ff, resid_std)
         return layer
     layer["moe"] = {
-        "router": _normal(ks[1], (D, E), 0.02, jnp.float32),
-        "bias": _normal(ks[2], (E,), ROUTER_BIAS_STD, jnp.float32),
-        "wg": _normal(ks[3], (E, D, F), 0.02, pd),
-        "wu": _normal(ks[4], (E, D, F), 0.02, pd),
-        "wd": _normal(jax.random.fold_in(ks[4], 1), (E, F, D), resid_std, pd),
+        "router": lm.normal(ks[1], (D, E), 0.02, jnp.float32),
+        "bias": lm.normal(ks[2], (E,), ROUTER_BIAS_STD, jnp.float32),
+        "wg": lm.normal(ks[3], (E, D, F), 0.02, pd),
+        "wu": lm.normal(ks[4], (E, D, F), 0.02, pd),
+        "wd": lm.normal(jax.random.fold_in(ks[4], 1), (E, F, D), resid_std,
+                        pd),
     }
     layer["shared"] = _swiglu_params(
         ks[5], cfg, cfg.n_shared_experts * F, resid_std)
     return layer
 
 
-@functools.lru_cache(maxsize=None)
-def _layer_program(cfg: DeepseekConfig, dense: bool):
-    return jax.jit(lambda key, l: _init_layer(key, l, cfg, dense))
-
-
 def init_layer(key: jax.Array, l: int, cfg: DeepseekConfig) -> Params:
     """Layer l's weights from `fold_in(key, l)` and nothing else: a dense
-    layer for l < cfg.n_dense_layer, else an expert layer. One compiled
-    program a kind of layer makes them wherever they are made (a sum fused
-    another way may round another way), so a layer made alone is, to the
-    bit, the layer in `init_params`' tree."""
-    return _layer_program(cfg, l < cfg.n_dense_layer)(key, jnp.int32(l))
+    layer for l < cfg.n_dense_layer, else an expert layer, by the one
+    compiled program a kind (`lm.layer_program`): a layer made alone is, to
+    the bit, the layer in `init_params`' tree."""
+    return lm.layer_program(_init_layer, cfg, l < cfg.n_dense_layer)(
+        key, jnp.int32(l))
 
 
 def init_ends(key: jax.Array, cfg: DeepseekConfig) -> Params:
@@ -218,41 +209,25 @@ def init_ends(key: jax.Array, cfg: DeepseekConfig) -> Params:
     from `fold_in(key, cfg.n_layer)`."""
     k_emb, k_head = jax.random.split(jax.random.fold_in(key, cfg.n_layer))
     pd, D, V = cfg.param_dtype, cfg.d_model, cfg.vocab_size
-    return {"wte": _normal(k_emb, (V, D), EMBED_STD, pd),
-            "final_norm": {"scale": jnp.ones((D,), jnp.float32)},
-            "lm_head": _normal(k_head, (D, V), 0.02, pd)}
+    return {"wte": lm.normal(k_emb, (V, D), EMBED_STD, pd),
+            "final_norm": lm.ones(D),
+            "lm_head": lm.normal(k_head, (D, V), 0.02, pd)}
 
 
 def init_params(key: jax.Array, cfg: DeepseekConfig) -> Params:
     """The whole tree, every leaf made in the dtype it is held in: `dense`
-    [n_dense_layer, ...] and `blocks` [n_layer - n_dense_layer, ...]
-    stacked on a leading layer axis. A stack is allocated once and each
-    layer's program writes its layer into it (donated), so the most that
-    exists beside the tree is one layer: no float32 copy of the tree, and
-    no second copy of a stack."""
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def put(stack, layer, i):
-        return jax.tree.map(
-            lambda s, a: lax.dynamic_update_index_in_dim(s, a, i, 0),
-            stack, layer)
-
-    def stack(first: int, n: int):
-        shapes = jax.eval_shape(lambda: init_layer(key, first, cfg))
-        out = jax.jit(lambda: jax.tree.map(
-            lambda s: jnp.zeros((n,) + s.shape, s.dtype), shapes))()
-        for i in range(n):
-            out = put(out, init_layer(key, first + i, cfg), jnp.int32(i))
-        return out
-
+    [n_dense_layer, ...] and `blocks` [n_layer - n_dense_layer, ...], a
+    layer at a time into a stack (`lm.stack_layers`: the most that exists
+    beside the tree is one layer)."""
     k = cfg.n_dense_layer
     return {**jax.jit(init_ends, static_argnums=(1,))(key, cfg),
-            "dense": stack(0, k), "blocks": stack(k, cfg.n_layer - k)}
+            "dense": lm.stack_layers(
+                lambda i: init_layer(key, i, cfg), k),
+            "blocks": lm.stack_layers(
+                lambda i: init_layer(key, k + i, cfg), cfg.n_layer - k)}
 
 
-def resident_params(params: Params, cfg: DeepseekConfig) -> Params:
-    """`init_params` makes the tree a replica holds: nothing to convert."""
-    del cfg
-    return params
+resident_params = lm.resident_params
 
 
 def resident_specs(cfg: DeepseekConfig, rules=None) -> Params:
@@ -313,7 +288,7 @@ def init_cache(cfg: DeepseekConfig, batch: int,
 _WRITE_WINDOW = 128
 
 
-def _cache_write(c, l, val, pos0, ok, slot=None):
+def cache_write(c, l, val, pos0, ok, slot=None):
     """Layer l of the carried leaf c [L,B,T,F] takes val [N,C,F]: lane i of
     row n goes to position pos0[n] + i where ok[n, i], in slot n (N = B), or
     in `slot` for the one row of that slot's own lanes; nothing else
@@ -336,7 +311,7 @@ def _cache_write(c, l, val, pos0, ok, slot=None):
     return c
 
 
-def _rows(c, l, slot=None):
+def rows(c, l, slot=None):
     """Layer l of the carried leaf c [L,B,T,F] as attention reads it: every
     slot's rows [B,T,F], or `slot`'s alone [1,T,F], where they lie."""
     if slot is None:
@@ -348,15 +323,10 @@ def _rows(c, l, slot=None):
 # The layer
 # ---------------------------------------------------------------------------
 
-def _w(p, cfg: DeepseekConfig):
-    with jax.named_scope("weights_cast"):
-        return p.astype(cfg.dtype)
-
-
 def _swiglu(h, p, cfg: DeepseekConfig):
-    g = h @ _w(p["wg"], cfg)
-    u = h @ _w(p["wu"], cfg)
-    return (jax.nn.silu(g) * u) @ _w(p["wd"], cfg)
+    g = h @ lm.weight(p["wg"], cfg.dtype)
+    u = h @ lm.weight(p["wu"], cfg.dtype)
+    return (jax.nn.silu(g) * u) @ lm.weight(p["wd"], cfg.dtype)
 
 
 def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
@@ -374,8 +344,8 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
     with jax.named_scope("attn"):
         h = rms_norm(x, bp["attn_norm"], cfg.norm_eps).astype(cfg.dtype)
         with jax.named_scope("mla_project"):
-            q = jnp.einsum("bcd,dhk->bchk", h, _w(p["wq"], cfg))
-            ckr = h @ _w(p["wkva"], cfg)                          # [N,C,r+p]
+            q = jnp.einsum("bcd,dhk->bchk", h, lm.weight(p["wq"], cfg.dtype))
+            ckr = h @ lm.weight(p["wkva"], cfg.dtype)             # [N,C,r+p]
             c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
             q_rope, k_r = q[..., n:], ckr[..., r:]                # [N,C,H,p]
             if rope:
@@ -384,11 +354,11 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
                 cos, sin = cos[:, :, None, :], sin[:, :, None, :]
                 q_rope = apply_rope(q_rope, cos, sin)
                 k_r = apply_rope(k_r[:, :, None], cos, sin)[:, :, 0]
-            wkvb = _w(p["wkvb"], cfg)
+            wkvb = lm.weight(p["wkvb"], cfg.dtype)
             q_abs = jnp.einsum("bchn,rhn->bchr", q[..., :n], wkvb[..., :n])
         with jax.named_scope("kv_update"):
-            lat = _cache_write(lat, l, c, pos0, ok, slot)
-            kr = _cache_write(kr, l, k_r, pos0, ok, slot)
+            lat = cache_write(lat, l, c, pos0, ok, slot)
+            kr = cache_write(kr, l, k_r, pos0, ok, slot)
         with jax.named_scope("mla_attend"):
             scale = 1.0 / math.sqrt(cfg.qk_head_dim)
             if slot is None and C == 1:
@@ -397,13 +367,14 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
                 mixed = mla_attend(q_abs[:, 0], q_rope[:, 0], lat, kr, l,
                                    pos[:, 0], ok[:, 0], scale)[:, :, None]
             else:
-                mixed = attend_rows(q_abs, q_rope, _rows(lat, l, slot),
-                                    _rows(kr, l, slot), pos, scale)
+                mixed = attend_rows(q_abs, q_rope, rows(lat, l, slot),
+                                    rows(kr, l, slot), pos, scale)
             # [N,H,C,r] float32 -> [N,C,H,r]
             mixed = jnp.moveaxis(mixed, 1, 2).astype(cfg.dtype)
         with jax.named_scope("mla_project"):
             o = jnp.einsum("bchr,rhv->bchv", mixed, wkvb[..., n:])
-            x = x + jnp.dot(o.reshape(B, C, H * v), _w(p["wo"], cfg),
+            x = x + jnp.dot(o.reshape(B, C, H * v),
+                            lm.weight(p["wo"], cfg.dtype),
                             preferred_element_type=x.dtype)
     return x, lat, kr
 
@@ -426,7 +397,7 @@ def _expert_mlp(x, bp, cfg: DeepseekConfig, given, ok):
                 jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
         routed = _moe._experts(
             h, gates.reshape(B, C, K), experts.reshape(B, C, K),
-            _w(m["wg"], cfg), _w(m["wu"], cfg), _w(m["wd"], cfg), cfg)
+            *(lm.weight(m[w], cfg.dtype) for w in ("wg", "wu", "wd")), cfg)
         with jax.named_scope("moe_shared"):
             shared = _swiglu(h, bp["shared"], cfg)
         x = x + routed.astype(x.dtype) + shared.astype(x.dtype)
@@ -455,32 +426,26 @@ def _mlp(x, bp, cfg: DeepseekConfig, given, ok):
 
 
 def _further_lanes(rest, bp, cfg: DeepseekConfig, lat, kr, given, l, pos,
-                   ok):
+                   ok, prefilling):
     """One layer over the lanes after the first, rest [B,M,D] with ok
-    [B,M], the first of them at position pos [B]: a slot at a time and only
-    the slots that have such lanes (the others cost a predicate each), its
-    scores [1,H,M,T] against its own rows, its experts over its own M
-    lanes. The weights are the ones the first lanes read, `bp` as the
-    layers' loop has it: the loop copies a layer's three expert matrices
-    out of the stack once (ROADMAP S12a) and every slot's kernels read that
-    copy; sliced again inside the branch they are copied again for every
-    slot that prefills (`benchmarks/kanana_chunk_lanes.py` has both)."""
-    B, M, D = rest.shape
+    [B,M], the first of them at position pos [B], for the slots
+    `prefilling` a slot at a time (`lm.each_slot`): a slot's scores
+    [1,H,M,T] against its own rows, its experts over its own M lanes. The
+    weights are the ones the first lanes read, `bp` as the layers' scan
+    holds it: the scan copies a layer's three expert matrices out of the
+    stack once (ROADMAP S12a) and every slot's kernels read that copy
+    (`lm.each_slot` has what slicing them again costs)."""
+    M = rest.shape[1]
 
-    def slot(b, rest, lat, kr, given):
-        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
-        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
-        at = lax.dynamic_slice(pos, (b,), (1,))
+    def slot(b, carry):
+        rest, lat, kr, given = carry
+        xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         xb, lat, kr = _attention(xb, bp, cfg, lat, kr, l, at,
                                  at[:, None] + jnp.arange(M), okb, slot=b)
         xb, given = _mlp(xb, bp, cfg, given, okb)
-        return lax.dynamic_update_slice(rest, xb, (b, 0, 0)), lat, kr, given
+        return lm.put_lanes(rest, xb, b), lat, kr, given
 
-    def body(b, carry):
-        more = lax.dynamic_index_in_dim(ok, b, 0, keepdims=False).any()
-        return lax.cond(more, slot, lambda b, *same: same, b, *carry)
-
-    return lax.fori_loop(0, B, body, (rest, lat, kr, given))
+    return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
 
 
 def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
@@ -492,18 +457,13 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     product reads of it is the norm's output in the compute dtype, and the
     router reads that output before it is rounded.
 
-    A layer computes a lane only where the plan put a token (PERF.md, PR
-    39). Every slot's first lane goes through the layer all slots at once:
-    that is the whole decode program, and in the chunk program every decode
-    lane riding along and the first token of every chunk. The lanes after
-    it go through `_further_lanes`, only the slots that have them, a slot
-    at a time: C of them a slot, the last one padding, so that the rows a
-    slot's experts sort come in whole tiles of the grouped matmul
-    (`ops/grouped_matmul._tiling` halves a tile until it divides the rows:
-    127 lanes x 6 would be tiles of 2 rows). A step costs the decode
-    program's time plus a term a slot that prefills, where all B x C lanes
-    through every layer cost the worst case whoever prefilled (305 ms at 32
-    x 128 for one slot's question).
+    A layer computes a lane only where the plan put a token (`models/lm.py`,
+    "The lanes of a chunk"): every slot's first lane all slots at once, the
+    lanes after it through `_further_lanes`, C of them a slot, the last one
+    padding for the grouped matmul's tiles (`lm.split_lanes`). A step costs
+    the decode program's time plus a term a slot that prefills, where all
+    B x C lanes through every layer cost the worst case whoever prefilled
+    (305 ms at 32 x 128 for one slot's question).
 
     The dense layers stand before the loop, the expert layers are one scan
     over their stacked weights (which has each layer's three expert
@@ -514,20 +474,17 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     lat, kr = cache["latent"], cache["k_rope"]
     counts = jnp.zeros((4,), jnp.uint32)
     n_dense = cfg.n_dense_layer
-    first, on = x[:, :1], ok[:, :1]
-    rest = further = None
-    if C > 1:
-        rest = jnp.pad(x[:, 1:], ((0, 0), (0, 1), (0, 0)))
-        further = jnp.pad(ok[:, 1:], ((0, 0), (0, 1)))
+    first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
 
     def layer(l, bp, first, rest, lat, kr, counts):
         given = jnp.zeros((cfg.n_experts,), jnp.int32)
         first, lat, kr = _attention(first, bp, cfg, lat, kr, l, pos0,
-                                    pos[:, :1], on)
-        first, given = _mlp(first, bp, cfg, given, on)
+                                    pos[:, :1], on[:, None])
+        first, given = _mlp(first, bp, cfg, given, on[:, None])
         if rest is not None:
             rest, lat, kr, given = _further_lanes(
-                rest, bp, cfg, lat, kr, given, l, pos0 + 1, further)
+                rest, bp, cfg, lat, kr, given, l, pos0 + 1, further,
+                prefilling)
         if "moe" in bp:
             counts = counts + _expert_counts(given)
         return first, rest, lat, kr, counts
@@ -544,16 +501,13 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
             lambda carry, layer_: (layer(*layer_, *carry), None), carry,
             (jnp.arange(n_dense, cfg.n_layer), params["blocks"]))
     first, rest, lat, kr, counts = carry
-    if rest is not None:
-        x = jnp.concatenate([first, rest[:, :C - 1]], axis=1)
-    else:
-        x = first
+    x = lm.join_lanes(first, rest, C)
     with jax.named_scope("moe_router"):
         attended = jnp.sum(jnp.where(ok, pos + 1, 0)).astype(jnp.uint32)
         T = lat.shape[2]
-        read = read_positions(pos0, on[:, 0], T)
-        if further is not None:
-            read = read + (further.any(axis=1).sum() * T).astype(jnp.uint32)
+        read = read_positions(pos0, on, T)
+        if prefilling is not None:
+            read = read + (prefilling[1] * T).astype(jnp.uint32)
         counts = cache["counts"].at[program].add(
             jnp.concatenate([counts, jnp.stack([attended, read])]))
     return x, {"latent": lat, "k_rope": kr, "counts": counts}
@@ -562,7 +516,7 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
 def _logits(params: Params, x, cfg: DeepseekConfig):
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
-        return jnp.dot(x, _w(params["lm_head"], cfg),
+        return jnp.dot(x, lm.weight(params["lm_head"], cfg.dtype),
                        preferred_element_type=jnp.float32)
 
 
@@ -576,19 +530,14 @@ def _chunk(params: Params, cache, tokens, pos0, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     x, cache = _layers(x, params, cache, cfg, pos0, pos, ok, program)
-    last = jnp.clip(length - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _logits(params, x_last, cfg), cache
+    return _logits(params, lm.last_valid_lane(x, length), cfg), cache
 
 
 def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
                   length: jax.Array, active: jax.Array, cfg: DeepseekConfig):
-    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
-    slot), pos0 [B] (the chunk's first cache position), length [B] (valid
-    tokens, 0..C), active [B] -> (logits [B, vocab] float32 at each slot's
-    last valid lane, the cache). Inactive and zero-length slots leave the
-    cache as it was and their logits are garbage; pos0 + length <= T and
-    C <= T are the caller's to keep. Donate `cache`."""
+    """`gpt2.prefill_chunk`'s signature and every family's contract
+    (`models/lm.py`, "The lanes of a chunk"): -> (logits [B, vocab] float32
+    at each slot's last valid lane, the cache). Donate `cache`."""
     return _chunk(params, cache, tokens, pos0, length, active, cfg, 1)
 
 

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import lm
 from ray_tpu.parallel.mesh import constrain, logical_to_spec
 
 Params = Any  # nested dict pytree
@@ -184,19 +185,11 @@ def _layer_norm(x, p, eps=1e-5):
                 + p["bias"].astype(jnp.float32)).astype(x.dtype)
 
 
-def _w(p, cfg: "GPT2Config"):
-    """A weight in the compute dtype. Every conversion goes through here,
-    so that the innermost scope of its operation names it."""
-    with jax.named_scope("weights_cast"):
-        return p.astype(cfg.dtype)
-
-
 def _attention(x, p, cfg: GPT2Config):
-    from ray_tpu.models.lm import resolve_attn_impl
-
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
-    qkv = x @ _w(p["wqkv"], cfg) + _w(p["bqkv"], cfg)
+    qkv = x @ lm.weight(p["wqkv"], cfg.dtype) \
+        + lm.weight(p["bqkv"], cfg.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
@@ -205,7 +198,7 @@ def _attention(x, p, cfg: GPT2Config):
     k = constrain(k, "batch", "heads", "seq", None)
     v = constrain(v, "batch", "heads", "seq", None)
 
-    impl = resolve_attn_impl(cfg.attn_impl, T)
+    impl = lm.resolve_attn_impl(cfg.attn_impl, T)
     if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 
@@ -227,15 +220,15 @@ def _attention(x, p, cfg: GPT2Config):
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
-    out = out @ _w(p["wo"], cfg) + _w(p["bo"], cfg)
+    out = out @ lm.weight(p["wo"], cfg.dtype) + lm.weight(p["bo"], cfg.dtype)
     return out
 
 
 def _mlp(x, p, cfg: GPT2Config):
-    h = x @ _w(p["wi"], cfg) + _w(p["bi"], cfg)
+    h = x @ lm.weight(p["wi"], cfg.dtype) + lm.weight(p["bi"], cfg.dtype)
     h = constrain(h, "batch", "seq", "mlp")
     h = jax.nn.gelu(h, approximate=True)
-    return h @ _w(p["wo"], cfg) + _w(p["bo"], cfg)
+    return h @ lm.weight(p["wo"], cfg.dtype) + lm.weight(p["bo"], cfg.dtype)
 
 
 def _block(x, bp, cfg: GPT2Config):
@@ -269,7 +262,8 @@ def final_hidden(params: Params, x: jax.Array, cfg: GPT2Config) -> tuple:
     compute dtype): what `unembed` multiplies and the fused loss takes
     apart."""
     with jax.named_scope("unembed_loss"):
-        return _layer_norm(x, params["ln_f"]), _w(params["wte"].T, cfg)
+        return (_layer_norm(x, params["ln_f"]),
+                lm.weight(params["wte"].T, cfg.dtype))
 
 
 def unembed(params: Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
@@ -318,11 +312,9 @@ def forward(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array:
 def loss_fn(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
     """Next-token cross-entropy. batch = {"tokens": [B,T+1] int32} or
     {"inputs": [B,T], "targets": [B,T]}."""
-    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
-
-    inputs, targets = split_lm_batch(batch)
+    inputs, targets = lm.split_lm_batch(batch)
     x = hidden_states(params, inputs, cfg)
-    return chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
+    return lm.chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
 
 
 # ---------------------------------------------------------------------------
@@ -356,7 +348,8 @@ def resident_params(params: Params, cfg: GPT2Config) -> Params:
 
     @jax.jit
     def convert(wte, stale):
-        return _w(wte.T, cfg), jax.tree.map(lambda v: _w(v, cfg), stale)
+        return lm.weight(wte.T, cfg.dtype), jax.tree.map(
+            lambda v: lm.weight(v, cfg.dtype), stale)
 
     unembed, fresh = convert(params["wte"], stale)
     return {**params, "unembed": unembed,
@@ -372,8 +365,8 @@ def _unembedding(params: Params, cfg: GPT2Config) -> jax.Array:
     folds the transpose into the product and sums in another order, and
     the two kinds of tree would differ in the logits' last bit."""
     if "unembed" in params:
-        return _w(params["unembed"], cfg)
-    return lax.optimization_barrier(_w(params["wte"].T, cfg))
+        return lm.weight(params["unembed"], cfg.dtype)
+    return lax.optimization_barrier(lm.weight(params["wte"].T, cfg.dtype))
 
 
 # the cache's leaves that hold a value a token, and the axis that counts
@@ -479,8 +472,8 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     def layer(x, ck, cv, bp, l):                          # ck/cv [L,B,H,T,Dh]
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1"])
-            qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
-                _w(bp["attn"]["bqkv"], cfg)
+            qkv = h @ lm.weight(bp["attn"]["wqkv"], cfg.dtype) + \
+                lm.weight(bp["attn"]["bqkv"], cfg.dtype)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(B, H, Dh)
             k = k.reshape(B, H, 1, Dh)
@@ -496,8 +489,8 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
             attn = jnp.einsum("bht,bhtd->bhd", probs, cv[l])
             attn = attn.reshape(B, H * Dh)
-            attn = attn @ _w(bp["attn"]["wo"], cfg) + \
-                _w(bp["attn"]["bo"], cfg)
+            attn = attn @ lm.weight(bp["attn"]["wo"], cfg.dtype) + \
+                lm.weight(bp["attn"]["bo"], cfg.dtype)
             x = x + attn
         with jax.named_scope("mlp"):
             x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
@@ -544,8 +537,8 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
     def layer(x, ck, cv, bp, l):                          # ck/cv [L,B,H,T,Dh]
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1"])
-            qkv = h @ _w(bp["attn"]["wqkv"], cfg) + \
-                _w(bp["attn"]["bqkv"], cfg)
+            qkv = h @ lm.weight(bp["attn"]["wqkv"], cfg.dtype) + \
+                lm.weight(bp["attn"]["bqkv"], cfg.dtype)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)  # [B,H,C,Dh]
             k = k.reshape(B, C, H, Dh).transpose(0, 2, 1, 3)
@@ -566,8 +559,8 @@ def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
             attn = jnp.einsum("bhct,bhtd->bhcd", probs, cv[l])
             attn = attn.transpose(0, 2, 1, 3).reshape(B, C, H * Dh)
-            attn = attn @ _w(bp["attn"]["wo"], cfg) + \
-                _w(bp["attn"]["bo"], cfg)
+            attn = attn @ lm.weight(bp["attn"]["wo"], cfg.dtype) + \
+                lm.weight(bp["attn"]["bo"], cfg.dtype)
             x = x + attn
         with jax.named_scope("mlp"):
             x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)

@@ -41,7 +41,8 @@ through the SSD form, with a_i = sum_{s<m<=i} dt_m A:
     y_i = e^{a_i} S_s C_i + sum_{s<j<=i} e^{a_i - a_j} (C_i . B_j) dt_j x_j
     S_{s+M} = e^{a_{s+M}} S_s + sum_j e^{a_{s+M} - a_j} dt_j x_j B_j^T
 
-a slot at a time and only for the slots that prefill, as attention's scores
+a slot at a time and only for the slots that prefill (`models/lm.py`, "The
+lanes of a chunk", has the loop and the contract), as attention's scores
 for a whole chunk are ([8, 4 M, T] floats a slot; for every lane of every
 slot they would be [slots, 32, C, T]). A lane past a slot's length has
 dt = 0: it decays nothing and adds nothing; a slot with no valid lane keeps
@@ -54,8 +55,8 @@ scales are float32, and so are the residual stream, everything projected
 (z, xBC, dt, the MLP's hidden lanes, attention's scores), the convolution
 and its window, dt, the decay, the state, its update and read-out, and the
 logits. A product's operands are bf16, the weight as it is held and the
-activation as the two bf16 pieces that add up to it (`_dot`); keys, values
-and attention's weights go as one piece.
+activation as the two bf16 pieces that add up to it (`lm.dot`); keys,
+values and attention's weights go as one piece.
 """
 
 from __future__ import annotations
@@ -69,6 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import lm
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.rows_write import TILE as _WRITE_WINDOW, rows_write
 from ray_tpu.ops.ssm_update import ssm_update
@@ -176,33 +178,25 @@ A_RANGE = (1.0, 16.0)
 DT_RANGE = (0.001, 0.1)
 
 
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-def _ones(n):
-    return {"scale": jnp.ones((n,), jnp.float32)}
-
-
 def _mlp_params(key, cfg: GraniteConfig) -> Params:
     k_in, k_out = jax.random.split(key)
     pd, D, F = cfg.param_dtype, cfg.d_model, cfg.d_ff
-    return {"w_in": _normal(k_in, (D, 2 * F), 0.02, pd),
-            "w_out": _normal(k_out, (F, D),
+    return {"w_in": lm.normal(k_in, (D, 2 * F), 0.02, pd),
+            "w_out": lm.normal(k_out, (F, D),
                              0.02 / math.sqrt(2 * cfg.n_layer), pd)}
 
 
 def _init_layer(key: jax.Array, l, cfg: GraniteConfig, kind: str) -> Params:
     ks = jax.random.split(jax.random.fold_in(key, l), 8)
     pd, D = cfg.param_dtype, cfg.d_model
-    out = {"mixer_norm": _ones(D), "mlp_norm": _ones(D),
+    out = {"mixer_norm": lm.ones(D), "mlp_norm": lm.ones(D),
            "mlp": _mlp_params(ks[0], cfg)}
     if kind == "attention":
         H, G, d = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        out["attn"] = {"wq": _normal(ks[1], (D, H * d), 0.02, pd),
-                       "wk": _normal(ks[2], (D, G * d), 0.02, pd),
-                       "wv": _normal(ks[3], (D, G * d), 0.02, pd),
-                       "wo": _normal(ks[4], (H * d, D), 0.02, pd)}
+        out["attn"] = {"wq": lm.normal(ks[1], (D, H * d), 0.02, pd),
+                       "wk": lm.normal(ks[2], (D, G * d), 0.02, pd),
+                       "wv": lm.normal(ks[3], (D, G * d), 0.02, pd),
+                       "wo": lm.normal(ks[4], (H * d, D), 0.02, pd)}
         return out
     I, F, Hm, K = cfg.ssm_inner, cfg.conv_width, cfg.ssm_heads, cfg.ssm_conv
     dt = jnp.exp(jax.random.uniform(ks[3], (Hm,), jnp.float32,
@@ -214,9 +208,9 @@ def _init_layer(key: jax.Array, l, cfg: GraniteConfig, kind: str) -> Params:
         # tiles), and its 64 for dt apart, float32: beside them the minor
         # axis would be 8,512, and the TPU's compiler copies the whole stack
         # into another layout on every step (1.26 GB; PERF.md, PR 38)
-        "w_zx": _normal(ks[1], (D, I + F), 0.02, pd),
-        "w_dt": _normal(ks[7], (D, Hm), 0.02, jnp.float32),
-        "w_out": _normal(ks[2], (I, D), 0.02, pd),
+        "w_zx": lm.normal(ks[1], (D, I + F), 0.02, pd),
+        "w_dt": lm.normal(ks[7], (D, Hm), 0.02, jnp.float32),
+        "w_out": lm.normal(ks[2], (I, D), 0.02, pd),
         # softplus(dt_bias) = dt
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "a_log": jnp.log(jax.random.uniform(ks[4], (Hm,), jnp.float32,
@@ -225,21 +219,17 @@ def _init_layer(key: jax.Array, l, cfg: GraniteConfig, kind: str) -> Params:
         # tap k of the window multiplies the input 3 - k positions back
         "conv_w": jax.random.uniform(ks[5], (K, F), jnp.float32, -edge, edge),
         "conv_b": jax.random.uniform(ks[6], (F,), jnp.float32, -edge, edge),
-        "norm": _ones(I)}
+        "norm": lm.ones(I)}
     return out
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_program(cfg: GraniteConfig, kind: str):
-    return jax.jit(lambda key, l: _init_layer(key, l, cfg, kind))
 
 
 def init_layer(key: jax.Array, l: int, cfg: GraniteConfig) -> Params:
     """Layer l's weights from `fold_in(key, l)` and nothing else, of the
     kind `cfg.layer_types[l]` names, by the one compiled program a kind
-    that makes them wherever they are made: a layer made alone is, to the
-    bit, the layer in `init_params`' tree."""
-    return _layer_program(cfg, cfg.layer_types[l])(key, jnp.int32(l))
+    (`lm.layer_program`): a layer made alone is, to the bit, the layer in
+    `init_params`' tree."""
+    return lm.layer_program(_init_layer, cfg, cfg.layer_types[l])(
+        key, jnp.int32(l))
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -247,41 +237,26 @@ def init_ends(key: jax.Array, cfg: GraniteConfig) -> Params:
     """What is not a layer: the table (tied: it is the head too) and the
     final norm, from `fold_in(key, cfg.n_layer)`."""
     k_emb = jax.random.fold_in(key, cfg.n_layer)
-    return {"wte": _normal(k_emb, (cfg.vocab_size, cfg.d_model), EMBED_STD,
+    return {"wte": lm.normal(k_emb, (cfg.vocab_size, cfg.d_model), EMBED_STD,
                            cfg.param_dtype),
-            "final_norm": _ones(cfg.d_model)}
+            "final_norm": lm.ones(cfg.d_model)}
 
 
 def init_params(key: jax.Array, cfg: GraniteConfig) -> Params:
     """The whole tree, every leaf made in the dtype it is held in: `mamba`
     and `attention`, one stack a kind of layer on a leading axis, in the
-    order the layers have. A stack is allocated once and each layer's
-    program writes its layer into it (donated), so the most that exists
-    beside the tree is one layer."""
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def put(stack, layer, i):
-        return jax.tree.map(
-            lambda s, a: lax.dynamic_update_index_in_dim(s, a, i, 0),
-            stack, layer)
-
+    order the layers have, a layer at a time (`lm.stack_layers`: the most
+    that exists beside the tree is one layer)."""
     out = dict(init_ends(key, cfg))
     for kind in ("mamba", "attention"):
         layers = [l for l, t in enumerate(cfg.layer_types) if t == kind]
-        if not layers:
-            continue
-        shapes = jax.eval_shape(lambda: init_layer(key, layers[0], cfg))
-        stack = jax.jit(lambda: jax.tree.map(
-            lambda s: jnp.zeros((len(layers),) + s.shape, s.dtype), shapes))()
-        for i, l in enumerate(layers):
-            stack = put(stack, init_layer(key, l, cfg), jnp.int32(i))
-        out[kind] = stack
+        if layers:
+            out[kind] = lm.stack_layers(
+                lambda i: init_layer(key, layers[i], cfg), len(layers))
     return out
 
 
-def resident_params(params: Params, cfg: GraniteConfig) -> Params:
-    """`init_params` makes the tree a replica holds: nothing to convert."""
-    del cfg
-    return params
+resident_params = lm.resident_params
 
 
 def resident_specs(cfg: GraniteConfig, rules=None) -> Params:
@@ -338,50 +313,18 @@ def init_cache(cfg: GraniteConfig, batch: int, max_len: Optional[int] = None):
 # and in the chunk program it is every decode lane riding along and the
 # first token of every chunk. A slot whose chunk has further lanes takes
 # them through `_mamba_further` and `_attention_further`, a slot at a time
-# and only such slots (`_further_lanes`): the SSD form and a chunk's scores
-# are computed for the lanes that prefill, never for the padding of the
-# other slots' lanes, which at 48 slots of 64 lanes is 98% of them and cost
-# 190 of a chunk step's 235 ms when every slot went through the SSD form
-# (PERF.md, PR 38).
-
-def _w(p, cfg: GraniteConfig):
-    with jax.named_scope("weights_cast"):
-        return p.astype(cfg.dtype)
-
-
-def _dot(x, w, cfg: GraniteConfig):
-    """x [..., K] float32 times the weight w [K, N] -> [..., N] float32. The
-    operands are the compute dtype's, and x goes as the two pieces that add
-    up to it (its rounding and what the rounding left), side by side on the
-    rows of one product: one pass of the weight, which is what a decode
-    step's product costs, and none of the activations' rounding in the
-    result. With that rounding in every product of 80 sublayers the logits
-    lay 1.1% of their spread from the reference's, as far as a state held
-    in bfloat16 puts them (PERF.md, PR 38)."""
-    if cfg.dtype == jnp.float32:
-        return jnp.dot(x, w.astype(jnp.float32), precision=_HIGHEST)
-    x = x.astype(jnp.float32)
-    # `reduce_precision`, not a pair of conversions, which are the
-    # compiler's to remove (PERF.md, PR 29): the low piece would be zero
-    bits = jnp.finfo(cfg.dtype)
-    high = lax.reduce_precision(x, exponent_bits=bits.nexp,
-                                mantissa_bits=bits.nmant)
-    both = jnp.dot(jnp.stack([high, x - high]).astype(cfg.dtype), _w(w, cfg),
-                   preferred_element_type=jnp.float32)
-    return both[0] + both[1]
-
-
-def _over_lanes(per_head, cfg: GraniteConfig):
-    """[..., H] -> [..., H P]: a head's value over its P lanes."""
-    return jnp.repeat(per_head, cfg.ssm_head_dim, axis=-1)
-
+# and only such slots (`lm.each_slot`; `_further_lanes` is a slot's layer):
+# the SSD form and a chunk's scores are computed for the lanes that prefill,
+# never for the padding of the other slots' lanes, which at 48 slots of 64
+# lanes is 98% of them and cost 190 of a chunk step's 235 ms when every
+# slot went through the SSD form (PERF.md, PR 38).
 
 def _ssm_in(u32, p, cfg: GraniteConfig):
     """The norm's output u32 [B,M,D] float32 -> z [B,M,I], xBC [B,M,F]
     before the convolution, dt [B,M,H] after softplus, all float32."""
     I = cfg.ssm_inner
     with jax.named_scope("ssm_project"):
-        proj = _dot(u32, p["w_zx"], cfg)
+        proj = lm.dot(u32, p["w_zx"], cfg.dtype)
         dt = jnp.dot(u32, p["w_dt"], precision=_HIGHEST)
         return proj[..., :I], proj[..., I:], \
             jax.nn.softplus(dt + p["dt_bias"])
@@ -391,31 +334,12 @@ def _ssm_out(y, z, p, cfg: GraniteConfig):
     """The gate before the norm, one group of I lanes, then W_out."""
     with jax.named_scope("ssm_project"):
         y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-        return _dot(y, p["w_out"], cfg)
+        return lm.dot(y, p["w_out"], cfg.dtype)
 
 
-def _conv(xbc, p, window, ok, cfg: GraniteConfig):
-    """The causal depthwise convolution of xbc [B,M,F] behind `window`
-    [B, (K-1) F], the K - 1 inputs before it, and the window left behind:
-    the K - 1 inputs that end at each slot's last valid lane (a slot with no
-    valid lane keeps its window bit for bit)."""
-    K = cfg.ssm_conv
-    B, M, F = xbc.shape
+def _conv(xbc, p, window, ok):
     with jax.named_scope("ssm_conv"):
-        if M == 1:          # one lane: the window moves on by one input
-            ext = jnp.concatenate([window, xbc[:, 0]], axis=-1)   # [B, K F]
-            out = p["conv_b"] + sum(p["conv_w"][k] * ext[:, k * F:(k + 1) * F]
-                                    for k in range(K))
-            return (jax.nn.silu(out)[:, None],
-                    jnp.where(ok, ext[:, F:], window))
-        ext = jnp.concatenate([window.reshape(B, K - 1, F), xbc], axis=1)
-        out = p["conv_b"] + sum(p["conv_w"][k] * ext[:, k:k + M]
-                                for k in range(K))
-        at = ok.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]   # [B,K-1]
-        new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
-        new = jnp.where(ok.any(axis=1)[:, None],
-                        new.reshape(B, (K - 1) * F), window)
-        return jax.nn.silu(out), new
+        return lm.short_conv(xbc, p["conv_w"], window, ok, p["conv_b"])
 
 
 def _split_xbc(xbc, cfg: GraniteConfig):
@@ -432,9 +356,9 @@ def _ssd(x, b, c, dt, p, s, ok, cfg: GraniteConfig):
     with jax.named_scope("ssm_chunk"):
         dt = jnp.where(ok[:, :, None], dt, 0.0)
         a = jnp.cumsum(-dt * jnp.exp(p["a_log"]), axis=1)        # [B, M, H]
-        dtx = (_over_lanes(dt, cfg) * x).reshape(B, M, H, P)
+        dtx = (lm.over_lanes(dt, P) * x).reshape(B, M, H, P)
         # what the state held: e^{a_i} S_s C_i
-        y = _over_lanes(jnp.exp(a), cfg) * jnp.einsum(
+        y = lm.over_lanes(jnp.exp(a), P) * jnp.einsum(
             "bin,bnf->bif", c, s, precision=_HIGHEST)
         # within the chunk: (C_i . B_j) e^{a_i - a_j} dt_j x_j, j <= i
         lane = jnp.arange(M)
@@ -445,13 +369,13 @@ def _ssd(x, b, c, dt, p, s, ok, cfg: GraniteConfig):
                                      precision=_HIGHEST)[..., None]
         y = y + jnp.einsum("bijh,bjhp->bihp", weight, dtx,
                            precision=_HIGHEST).reshape(B, M, H * P)
-        y = y + _over_lanes(p["d"], cfg) * x
+        y = y + lm.over_lanes(p["d"], P) * x
         # the state at the chunk's end
         total = a[:, -1]                                           # [B, H]
         out_of = jnp.exp(total[:, None, :] - a)[..., None] * dtx   # [B,M,H,P]
-        s_new = _over_lanes(jnp.exp(total), cfg)[:, None, :] * s + jnp.einsum(
-            "bjn,bjf->bnf", b, out_of.reshape(B, M, H * P),
-            precision=_HIGHEST)
+        s_new = lm.over_lanes(jnp.exp(total), P)[:, None, :] * s \
+            + jnp.einsum("bjn,bjf->bnf", b, out_of.reshape(B, M, H * P),
+                         precision=_HIGHEST)
         return y, jnp.where(ok.any(axis=1)[:, None, None], s_new, s)
 
 
@@ -460,23 +384,23 @@ def _mamba_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
     by the recurrence: -> (x, cache). `on` [B]: the slots whose lane is
     valid; the others keep their state and window bit for bit."""
     del pos
-    p = bp["ssm"]
+    p, P = bp["ssm"], cfg.ssm_head_dim
     with jax.named_scope("attn"):
         z, xbc, dt = _ssm_in(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
                              p, cfg)
         with jax.named_scope("ssm_conv"):
             window = lax.dynamic_index_in_dim(cache["conv"], l, 0,
                                               keepdims=False)
-        xbc, window = _conv(xbc, p, window, on[:, None], cfg)
+        xbc, window = _conv(xbc, p, window, on[:, None])
         with jax.named_scope("ssm_conv"):
             conv = lax.dynamic_update_index_in_dim(cache["conv"], window,
                                                    l, 0)
         xs, b, c = _split_xbc(xbc[:, 0], cfg)
         with jax.named_scope("ssm_update"):
-            dt = _over_lanes(dt[:, 0], cfg)                        # [B, I]
-            decay = jnp.exp(-dt * _over_lanes(jnp.exp(p["a_log"]), cfg))
+            dt = lm.over_lanes(dt[:, 0], P)                        # [B, I]
+            decay = jnp.exp(-dt * lm.over_lanes(jnp.exp(p["a_log"]), P))
             ssm, y = ssm_update(cache["ssm"], l, decay, dt * xs, b, c, on)
-            y = y + _over_lanes(p["d"], cfg) * xs
+            y = y + lm.over_lanes(p["d"], P) * xs
         o = _ssm_out(y[:, None], z, p, cfg)
     return (x + cfg.residual_multiplier * o,
             {**cache, "ssm": ssm, "conv": conv})
@@ -495,7 +419,7 @@ def _mamba_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
         with jax.named_scope("ssm_conv"):
             window = lax.dynamic_slice(cache["conv"], (l, slot, 0),
                                        (1, 1, W))[0]
-        xbc, window = _conv(xbc, p, window, ok, cfg)
+        xbc, window = _conv(xbc, p, window, ok)
         with jax.named_scope("ssm_conv"):
             conv = lax.dynamic_update_slice(cache["conv"], window[None],
                                             (l, slot, 0))
@@ -520,7 +444,8 @@ def _qkv(x, bp, cfg: GraniteConfig):
     with jax.named_scope("gqa_project"):
         u = rms_norm(x, bp["mixer_norm"], cfg.norm_eps)
         return tuple(
-            _dot(u, p[name], cfg).astype(cfg.dtype).reshape(B, M, *shape)
+            lm.dot(u, p[name], cfg.dtype).astype(cfg.dtype).reshape(
+                B, M, *shape)
             for name, shape in (("wq", (G, R, d)), ("wk", (G, d)),
                                 ("wv", (G, d))))
 
@@ -528,7 +453,7 @@ def _qkv(x, bp, cfg: GraniteConfig):
 def _attn_out(x, y, bp, cfg: GraniteConfig):
     B, M, _ = x.shape
     with jax.named_scope("gqa_project"):
-        o = _dot(y.reshape(B, M, -1), bp["attn"]["wo"], cfg)
+        o = lm.dot(y.reshape(B, M, -1), bp["attn"]["wo"], cfg.dtype)
     return x + cfg.residual_multiplier * o
 
 
@@ -610,10 +535,10 @@ def _attention_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
 
 def _mlp(x, bp, cfg: GraniteConfig):
     with jax.named_scope("mlp"):
-        ab = _dot(rms_norm(x, bp["mlp_norm"], cfg.norm_eps),
-                  bp["mlp"]["w_in"], cfg)
+        ab = lm.dot(rms_norm(x, bp["mlp_norm"], cfg.norm_eps),
+                    bp["mlp"]["w_in"], cfg.dtype)
         a, b = ab[..., :cfg.d_ff], ab[..., cfg.d_ff:]
-        o = _dot(jax.nn.silu(a) * b, bp["mlp"]["w_out"], cfg)
+        o = lm.dot(jax.nn.silu(a) * b, bp["mlp"]["w_out"], cfg.dtype)
         return x + cfg.residual_multiplier * o
 
 
@@ -621,41 +546,26 @@ _FIRST = {"mamba": _mamba_first, "attention": _attention_first}
 _FURTHER = {"mamba": _mamba_further, "attention": _attention_further}
 
 
-def _layer_weights(stack: Params, l) -> Params:
-    """Layer l of a kind's stack: its weights sliced where they lie."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stack)
-
-
 def _further_lanes(kind: str, x, stack, cfg: GraniteConfig, cache, l, pos,
-                   ok):
+                   ok, prefilling):
     """Layer l of `kind`'s stack over the lanes after the first, x [B,M,D]
-    with ok [B,M], the first of them at pos [B]: a slot at a time, and only
-    the slots that have such lanes. The weights are sliced inside the
-    branch: sliced once for both paths, the compiler copies every matrix
-    out of the stack (6.4 GB a chunk step)."""
-    B, M, D = x.shape
+    with ok [B,M], the first of them at pos [B], for the slots `prefilling`
+    a slot at a time (`lm.each_slot`, which has why the weights are sliced
+    inside the body here)."""
+    def slot(b, carry):
+        x, cache = carry
+        bp = lm.layer_weights(stack, l, turn=b)
+        xb, okb, at = lm.slot_lanes(b, x, ok, pos)
+        xb, cache = _FURTHER[kind](xb, bp, cfg, cache, l, b, at[0], okb)
+        return lm.put_lanes(x, _mlp(xb, bp, cfg), b), cache
 
-    def slot(b, x, cache):
-        bp = _layer_weights(stack, l)
-        xb = lax.dynamic_slice(x, (b, 0, 0), (1, M, D))
-        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
-        at = lax.dynamic_index_in_dim(pos, b, 0, keepdims=False)
-        xb, cache = _FURTHER[kind](xb, bp, cfg, cache, l, b, at, okb)
-        return lax.dynamic_update_slice(x, _mlp(xb, bp, cfg), (b, 0, 0)), \
-            cache
-
-    def body(b, carry):
-        more = lax.dynamic_index_in_dim(ok, b, 0, keepdims=False).any()
-        return lax.cond(more, slot, lambda b, *same: same, b, *carry)
-
-    return lax.fori_loop(0, B, body, (x, cache))
+    return lm.each_slot(prefilling, slot, (x, cache))
 
 
 def _logits(params: Params, x, cfg: GraniteConfig):
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return _dot(x, params["wte"].T, cfg) / cfg.logits_scaling
+        return lm.dot(x, params["wte"].T, cfg.dtype) / cfg.logits_scaling
 
 
 def _period(cfg: GraniteConfig):
@@ -678,22 +588,25 @@ def _period(cfg: GraniteConfig):
 def _forward(params: Params, cache, tokens, pos0, length, active,
              cfg: GraniteConfig):
     B, C = tokens.shape
-    on = active & (length > 0)
-    further = (jnp.arange(1, C)[None, :] < length[:, None]) & on[:, None]
+    ok = (jnp.arange(C)[None, :] < length[:, None]) & active[:, None]
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32) \
             * cfg.embedding_multiplier                           # [B, C, D]
+    # the further lanes are C - 1 a slot: no experts, no tile to fill
+    first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=False)
     periods, runs = _period(cfg)
     per_period = {kind: sum(n for k, n in runs if k == kind)
                   for kind in _FIRST}
 
     def layer(kind: str, l, first, rest, cache):
-        bp = _layer_weights(params[kind], l)
+        bp = lm.layer_weights(params[kind], l)
         first, cache = _FIRST[kind](first, bp, cfg, cache, l, pos0, on)
         first = _mlp(first, bp, cfg)
-        if C > 1:
+        if rest is not None:
+            # the loop writes the rows where the first lanes read them
+            first, cache = lax.optimization_barrier((first, cache))
             rest, cache = _further_lanes(kind, rest, params[kind], cfg, cache,
-                                         l, pos0 + 1, further)
+                                         l, pos0 + 1, further, prefilling)
         return first, rest, cache
 
     def period(carry, n):
@@ -713,23 +626,17 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     # in place where the caller donates it
     with jax.named_scope("layers"):
         (first, rest, cache), _ = lax.scan(
-            period, (x[:, :1], x[:, 1:], dict(cache)), jnp.arange(periods))
-    x = jnp.concatenate([first, rest], axis=1)
-    last = jnp.clip(length - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _logits(params, x_last, cfg), cache
+            period, (first, rest, dict(cache)), jnp.arange(periods))
+    x = lm.last_valid_lane(lm.join_lanes(first, rest, C), length)
+    return _logits(params, x, cfg), cache
 
 
 def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
                   length: jax.Array, active: jax.Array, cfg: GraniteConfig):
-    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
-    slot), pos0 [B] (the position of the chunk's first token: the rows are
-    written there; the state does not read it), length [B] (valid tokens,
-    0..C), active [B] -> (logits [B, vocab] float32 at each slot's last
-    valid lane, the cache). Inactive and zero-length slots leave their
-    rows, their state and their window as they were, bit for bit, and their
-    logits are garbage. The state continues whatever the slot held: a new
-    sequence's slot is the caller's to zero. Donate `cache`."""
+    """`gpt2.prefill_chunk`'s signature and every family's contract
+    (`models/lm.py`, "The lanes of a chunk"): -> (logits [B, vocab] float32
+    at each slot's last valid lane, the cache). The rows are written from
+    pos0; the state does not read it. Donate `cache`."""
     return _forward(params, cache, tokens, pos0, length, active, cfg)
 
 

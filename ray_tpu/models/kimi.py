@@ -51,7 +51,8 @@ The mixers exist in two forms and no third (`models/granite.py`). The
 recurrence, one token a slot through the kernel `kda_update`, is
 `decode_step` whole and, in `prefill_chunk`, every slot's first lane. A
 chunk's further lanes go, a slot at a time and only for the slots that
-prefill, through the chunked form of the delta rule, M lanes after state
+prefill (`models/lm.py`, "The lanes of a chunk", has the loop and the
+contract), through the chunked form of the delta rule, M lanes after state
 S_0, with g_i = sum_{m<=i} log a_m a channel:
 
     A_ij = b_i sum_c k_ic k_jc exp(g_ic - g_jc), j < i
@@ -72,7 +73,7 @@ norms' scales, the convolution, `dt_bias`, `A_log`, W_b, the gate's bias,
 the router and its bias, and so are the residual stream, everything
 projected, the decay, the state, its update and read-out, the router and
 the logits. A product's operands are bf16, the weight as it is held and the
-activation as the two bf16 pieces that add up to it (`_dot`, and
+activation as the two bf16 pieces that add up to it (`lm.dot`, and
 `moe._experts` for float32 rows); the latent rows and attention's weights
 go as one piece.
 """
@@ -89,10 +90,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import moe as _moe
-from ray_tpu.models.deepseek import _cache_write, _rows
+from ray_tpu.models import lm, moe as _moe
+from ray_tpu.models.deepseek import cache_write, rows
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.kda_update import kda_update
+from ray_tpu.ops.pieces import pieces
 from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
 
 Params = Any
@@ -219,19 +221,11 @@ A_RANGE = (1.0, 16.0)
 DT_RANGE = (0.001, 0.1)
 
 
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-def _ones(n):
-    return {"scale": jnp.ones((n,), jnp.float32)}
-
-
 def _swiglu_params(key, cfg: KimiConfig, width: int) -> Params:
     k_in, k_out = jax.random.split(key)
     pd, D = cfg.param_dtype, cfg.d_model
-    return {"w_in": _normal(k_in, (D, 2 * width), 0.02, pd),
-            "w_out": _normal(k_out, (width, D),
+    return {"w_in": lm.normal(k_in, (D, 2 * width), 0.02, pd),
+            "w_out": lm.normal(k_out, (width, D),
                              0.02 / math.sqrt(2 * cfg.n_layer), pd)}
 
 
@@ -245,7 +239,7 @@ def _kda_params(key, cfg: KimiConfig) -> Params:
     edge = 1.0 / math.sqrt(K)
     return {
         # W_q, W_k, W_v side by side: one product, one convolution
-        "w_qkv": _normal(ks[1], (D, 3 * I), 0.02, pd),
+        "w_qkv": lm.normal(ks[1], (D, 3 * I), 0.02, pd),
         # tap k of the window multiplies the input 3 - k positions back
         "conv_w": jax.random.uniform(ks[2], (K, 3 * I), jnp.float32,
                                      -edge, edge),
@@ -254,34 +248,35 @@ def _kda_params(key, cfg: KimiConfig) -> Params:
         # lane tiles; as [D, H] float32 alone they cost a decode step 0.57
         # ms a layer, a tenth of it: PERF.md, PR 40): one product
         "w_fgb": jnp.concatenate([
-            _normal(ks[3], (D, R), 0.02, pd), _normal(ks[7], (D, R), 0.02, pd),
-            _normal(ks[6], (D, H), 0.02, pd),
+            lm.normal(ks[3], (D, R), 0.02, pd),
+            lm.normal(ks[7], (D, R), 0.02, pd),
+            lm.normal(ks[6], (D, H), 0.02, pd),
             jnp.zeros((D, -H % 128), pd)], axis=1),
-        "w_f2": _normal(ks[4], (R, I), 0.02, pd),
+        "w_f2": lm.normal(ks[4], (R, I), 0.02, pd),
         # softplus(dt_bias) = dt
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "a_log": jnp.log(jax.random.uniform(ks[5], (H,), jnp.float32,
                                             *A_RANGE)),
-        "w_g2": _normal(ks[8], (R, I), 0.02, pd),
+        "w_g2": lm.normal(ks[8], (R, I), 0.02, pd),
         "g_bias": jnp.zeros((I,), jnp.float32),
-        "o_norm": _ones(cfg.kda_head_dim),
-        "w_o": _normal(ks[9], (I, D), 0.02, pd)}
+        "o_norm": lm.ones(cfg.kda_head_dim),
+        "w_o": lm.normal(ks[9], (I, D), 0.02, pd)}
 
 
 def _mla_params(key, cfg: KimiConfig) -> Params:
     ks = jax.random.split(key, 4)
     pd, D, H = cfg.param_dtype, cfg.d_model, cfg.n_head
-    return {"wq": _normal(ks[0], (D, H * cfg.qk_head_dim), 0.02, pd),
-            "wkva": _normal(ks[1], (D, cfg.cache_width), 0.02, pd),
-            "kv_norm": _ones(cfg.kv_lora_rank),
+    return {"wq": lm.normal(ks[0], (D, H * cfg.qk_head_dim), 0.02, pd),
+            "wkva": lm.normal(ks[1], (D, cfg.cache_width), 0.02, pd),
+            "kv_norm": lm.ones(cfg.kv_lora_rank),
             # W_kvb by head, its two halves apart as the absorbed form
             # multiplies them: [H, n, r] into the query, [H, r, v] out of
             # the weighted latents
-            "w_uk": _normal(ks[2], (H, cfg.qk_nope_head_dim,
+            "w_uk": lm.normal(ks[2], (H, cfg.qk_nope_head_dim,
                                     cfg.kv_lora_rank), 0.02, pd),
-            "w_uv": _normal(jax.random.fold_in(ks[2], 1),
+            "w_uv": lm.normal(jax.random.fold_in(ks[2], 1),
                             (H, cfg.kv_lora_rank, cfg.v_head_dim), 0.02, pd),
-            "wo": _normal(ks[3], (H * cfg.v_head_dim, D), 0.02, pd)}
+            "wo": lm.normal(ks[3], (H * cfg.v_head_dim, D), 0.02, pd)}
 
 
 def _expert_params(key, cfg: KimiConfig) -> Params:
@@ -293,9 +288,9 @@ def _expert_params(key, cfg: KimiConfig) -> Params:
 
     def one(e):
         ks = jax.random.split(jax.random.fold_in(key, e), 3)
-        return {"wg": _normal(ks[0], (D, F), 0.02, pd),
-                "wu": _normal(ks[1], (D, F), 0.02, pd),
-                "wd": _normal(ks[2], (F, D), resid_std, pd)}
+        return {"wg": lm.normal(ks[0], (D, F), 0.02, pd),
+                "wu": lm.normal(ks[1], (D, F), 0.02, pd),
+                "wd": lm.normal(ks[2], (F, D), resid_std, pd)}
 
     # a loop, not `vmap`: one expert's three matrices are the program, which
     # compiles in a fifth of the time of all 64 side by side (8 s of a cold
@@ -307,37 +302,31 @@ def _init_layer(key: jax.Array, l, cfg: KimiConfig, kind: str,
                 dense: bool) -> Params:
     ks = jax.random.split(jax.random.fold_in(key, l), 6)
     D, E = cfg.d_model, cfg.n_experts
-    out = {kind: {"norm": _ones(D),
+    out = {kind: {"norm": lm.ones(D),
                   **(_mla_params if kind == "mla" else _kda_params)(ks[0],
                                                                     cfg)}}
     if dense:
-        out["dense"] = {"norm": _ones(D),
+        out["dense"] = {"norm": lm.ones(D),
                         **_swiglu_params(ks[1], cfg, cfg.d_ff)}
         return out
     out["moe"] = {
-        "norm": _ones(D),
-        "router": _normal(ks[2], (D, E), 0.02, jnp.float32),
-        "bias": _normal(ks[3], (E,), ROUTER_BIAS_STD, jnp.float32),
+        "norm": lm.ones(D),
+        "router": lm.normal(ks[2], (D, E), 0.02, jnp.float32),
+        "bias": lm.normal(ks[3], (E,), ROUTER_BIAS_STD, jnp.float32),
         "shared": _swiglu_params(ks[4], cfg,
                                  cfg.n_shared_experts * cfg.d_ff_expert)}
     out["experts"] = _expert_params(ks[5], cfg)
     return out
 
 
-@functools.lru_cache(maxsize=None)
-def _layer_program(cfg: KimiConfig, kind: str, dense: bool):
-    return jax.jit(lambda key, l: _init_layer(key, l, cfg, kind, dense))
-
-
 def init_layer(key: jax.Array, l: int, cfg: KimiConfig) -> Params:
     """Layer l's weights (l from 0) from `fold_in(key, l)` and nothing
     else: its mixer under `kda` or `mla`, its MLP under `dense` or under
     `moe` (router, bias, shared expert) and `experts` (the held experts'
-    [E', ...]). One compiled program a kind of layer makes them wherever
-    they are made, so a layer made alone is, to the bit, the layer in
-    `init_params`' tree."""
-    return _layer_program(cfg, cfg.layer_types[l], l < cfg.n_dense_layer)(
-        key, jnp.int32(l))
+    [E', ...]), by the one compiled program a kind (`lm.layer_program`): a
+    layer made alone is, to the bit, the layer in `init_params`' tree."""
+    return lm.layer_program(_init_layer, cfg, cfg.layer_types[l],
+                            l < cfg.n_dense_layer)(key, jnp.int32(l))
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -346,9 +335,9 @@ def init_ends(key: jax.Array, cfg: KimiConfig) -> Params:
     from `fold_in(key, cfg.n_layer)`."""
     k_emb, k_head = jax.random.split(jax.random.fold_in(key, cfg.n_layer))
     pd, D, V = cfg.param_dtype, cfg.d_model, cfg.vocab_size
-    return {"wte": _normal(k_emb, (V, D), EMBED_STD, pd),
-            "final_norm": _ones(D),
-            "lm_head": _normal(k_head, (D, V), 0.02, pd)}
+    return {"wte": lm.normal(k_emb, (V, D), EMBED_STD, pd),
+            "final_norm": lm.ones(D),
+            "lm_head": lm.normal(k_head, (D, V), 0.02, pd)}
 
 
 def _stack_index(cfg: KimiConfig) -> list:
@@ -371,45 +360,29 @@ def init_params(key: jax.Array, cfg: KimiConfig) -> Params:
     """The whole tree, every leaf made in the dtype it is held in: `kda`,
     `mla`, `dense` and `moe`, one stack a part on a leading axis in the
     order the layers have, and `experts` [expert layers x E', ...], the
-    held experts of every expert layer end to end. A stack is allocated
-    once and each layer's program writes its layer into it (donated), so
-    the most that exists beside the tree is one layer."""
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def put(stack, part, i):
-        def into(s, a):
-            a = a.reshape((-1,) + s.shape[1:])
-            return lax.dynamic_update_slice_in_dim(s, a, i * a.shape[0], 0)
-
-        return jax.tree.map(into, stack, part)
-
-    def empty(part: str, like: Params, layers: int) -> Params:
-        # `experts` comes [E', ...] a layer and lies [layers x E', ...]
-        def zeros(a):
-            shape = ((layers * a.shape[0],) + a.shape[1:]
-                     if part == "experts" else (layers,) + a.shape)
-            return jnp.zeros(shape, a.dtype)
-
-        return jax.jit(lambda: jax.tree.map(zeros, like))()
-
+    held experts of every expert layer end to end. A layer's program makes
+    all of its parts at once, so the layers' loop is here and not
+    `lm.stack_layers`; the stacks are `lm`'s: allocated once, a layer
+    written at a time (donated), so the most that exists beside the tree
+    is one layer."""
     index = _stack_index(cfg)
-    layers_of = {part: 1 + max(at[part] for at in index if part in at)
-                 for part in set().union(*index)}
     out = dict(init_ends(key, cfg))
     for l, at in enumerate(index):
         layer = init_layer(key, l, cfg)
         for part, i in at.items():
             if part not in out:
-                out[part] = empty(part, jax.eval_shape(lambda: layer[part]),
-                                  layers_of[part])
-            out[part] = put(out[part], layer[part], jnp.int32(i))
+                like, n = layer[part], sum(part in at for at in index)
+                if part == "experts":       # [E', ...] a layer, end to end
+                    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                        a.shape[1:], a.dtype), like)
+                    n *= cfg.experts_held
+                out[part] = lm.empty_stack(like, n)
+            out[part] = lm.put_layer(out[part], layer[part], jnp.int32(i))
         del layer
     return out
 
 
-def resident_params(params: Params, cfg: KimiConfig) -> Params:
-    """`init_params` makes the tree a replica holds: nothing to convert."""
-    del cfg
-    return params
+resident_params = lm.resident_params
 
 
 def resident_specs(cfg: KimiConfig, rules=None) -> Params:
@@ -469,46 +442,10 @@ def init_cache(cfg: KimiConfig, batch: int, max_len: Optional[int] = None):
 # The layers
 # ---------------------------------------------------------------------------
 
-def _pieces(x, cfg: KimiConfig):
-    """x float32 -> [2, ...] in the compute dtype: its rounding and what the
-    rounding left. `reduce_precision`, not a pair of conversions, which are
-    the compiler's to remove (PERF.md, PR 29)."""
-    bits = jnp.finfo(cfg.dtype)
-    high = lax.reduce_precision(x, exponent_bits=bits.nexp,
-                                mantissa_bits=bits.nmant)
-    return jnp.stack([high, x - high]).astype(cfg.dtype)
-
-
-def _dot(x, w, cfg: KimiConfig):
-    """x [..., K] float32 times the weight w [K, N] -> [..., N] float32:
-    `granite._dot`. The operands are the compute dtype's, and x goes as the
-    two pieces that add up to it, side by side on the rows of one product:
-    one pass of the weight, and none of the activations' rounding in the
-    result (which alone would put the logits as far from the reference's as
-    a state held in bfloat16 does: PERF.md, PR 38)."""
-    x = x.astype(jnp.float32)
-    if cfg.dtype == jnp.float32:
-        return jnp.dot(x, w.astype(jnp.float32), precision=_HIGHEST)
-    both = jnp.dot(_pieces(x, cfg), w.astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
-    return both[0] + both[1]
-
-
 def _swiglu(h, p, cfg: KimiConfig):
-    ab = _dot(h, p["w_in"], cfg)
+    ab = lm.dot(h, p["w_in"], cfg.dtype)
     a, b = jnp.split(ab, 2, axis=-1)
-    return _dot(jax.nn.silu(a) * b, p["w_out"], cfg)
-
-
-def _layer_weights(stack: Params, i) -> Params:
-    """Entry i of a part's stack: its weights sliced where they lie."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
-
-
-def _over_lanes(per_head, cfg: KimiConfig):
-    """[..., H] -> [..., H P]: a head's value over its P lanes."""
-    return jnp.repeat(per_head, cfg.kda_head_dim, axis=-1)
+    return lm.dot(jax.nn.silu(a) * b, p["w_out"], cfg.dtype)
 
 
 def _kda_in(u, p, cfg: KimiConfig):
@@ -517,38 +454,20 @@ def _kda_in(u, p, cfg: KimiConfig):
     [B,M,H] and the output gate [B,M,I], all float32."""
     with jax.named_scope("kda_project"):
         R, H = cfg.kda_rank, cfg.kda_heads
-        qkv = _dot(u, p["w_qkv"], cfg)
-        narrow = _dot(u, p["w_fgb"], cfg)
-        f = _dot(narrow[..., :R], p["w_f2"], cfg)
-        log_a = -_over_lanes(jnp.exp(p["a_log"]), cfg) * jax.nn.softplus(
-            f + p["dt_bias"])
+        qkv = lm.dot(u, p["w_qkv"], cfg.dtype)
+        narrow = lm.dot(u, p["w_fgb"], cfg.dtype)
+        f = lm.dot(narrow[..., :R], p["w_f2"], cfg.dtype)
+        log_a = -lm.over_lanes(jnp.exp(p["a_log"]), cfg.kda_head_dim) \
+            * jax.nn.softplus(f + p["dt_bias"])
         b = jax.nn.sigmoid(narrow[..., 2 * R:2 * R + H])
         gate = jax.nn.sigmoid(
-            _dot(narrow[..., R:2 * R], p["w_g2"], cfg) + p["g_bias"])
+            lm.dot(narrow[..., R:2 * R], p["w_g2"], cfg.dtype) + p["g_bias"])
         return qkv, log_a, b, gate
 
 
-def _conv(qkv, p, window, ok, cfg: KimiConfig):
-    """The causal depthwise convolution of qkv [B,M,F] behind `window`
-    [B, (K-1) F], the K - 1 inputs before it, then silu, and the window
-    left behind: the K - 1 inputs that end at each slot's last valid lane
-    (a slot with no valid lane keeps its window bit for bit)."""
-    K = cfg.kda_conv
-    B, M, F = qkv.shape
+def _conv(qkv, p, window, ok):
     with jax.named_scope("kda_project"):
-        if M == 1:          # one lane: the window moves on by one input
-            ext = jnp.concatenate([window, qkv[:, 0]], axis=-1)   # [B, K F]
-            out = sum(p["conv_w"][k] * ext[:, k * F:(k + 1) * F]
-                      for k in range(K))
-            return (jax.nn.silu(out)[:, None],
-                    jnp.where(ok, ext[:, F:], window))
-        ext = jnp.concatenate([window.reshape(B, K - 1, F), qkv], axis=1)
-        out = sum(p["conv_w"][k] * ext[:, k:k + M] for k in range(K))
-        at = ok.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]   # [B,K-1]
-        new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
-        new = jnp.where(ok.any(axis=1)[:, None],
-                        new.reshape(B, (K - 1) * F), window)
-        return jax.nn.silu(out), new
+        return lm.short_conv(qkv, p["conv_w"], window, ok)
 
 
 def _heads(qkv, cfg: KimiConfig):
@@ -569,7 +488,7 @@ def _kda_out(x, o, gate, p, cfg: KimiConfig):
     B, M = o.shape[:2]
     with jax.named_scope("kda_project"):
         y = rms_norm(o, p["o_norm"], cfg.norm_eps).reshape(B, M, -1) * gate
-        return x + _dot(y, p["w_o"], cfg)
+        return x + lm.dot(y, p["w_o"], cfg.dtype)
 
 
 def _kda_first(x, p, cfg: KimiConfig, cache, i, on):
@@ -584,7 +503,7 @@ def _kda_first(x, p, cfg: KimiConfig, cache, i, on):
         with jax.named_scope("kda_project"):
             window = lax.dynamic_index_in_dim(cache["conv"], i, 0,
                                               keepdims=False)
-        qkv, window = _conv(qkv, p, window, on[:, None], cfg)
+        qkv, window = _conv(qkv, p, window, on[:, None])
         with jax.named_scope("kda_project"):
             conv = lax.dynamic_update_index_in_dim(cache["conv"], window, i,
                                                    0)
@@ -646,7 +565,7 @@ def _kda_further(x, p, cfg: KimiConfig, cache, i, slot, ok):
         with jax.named_scope("kda_project"):
             window = lax.dynamic_slice(cache["conv"], (i, slot, 0),
                                        (1, 1, W))[0]
-        qkv, window = _conv(qkv, p, window, ok, cfg)
+        qkv, window = _conv(qkv, p, window, ok)
         with jax.named_scope("kda_project"):
             conv = lax.dynamic_update_slice(cache["conv"], window[None],
                                             (i, slot, 0))
@@ -682,7 +601,7 @@ def _kda_further(x, p, cfg: KimiConfig, cache, i, slot, ok):
 def _write_first(c, i, val, pos, ok, slot=None):
     """Layer i of the carried leaf c [L,B,T,F] takes val [B,1,F]: slot b's
     row goes to position pos[b] where ok[b, 0], one scatter for all slots
-    (`deepseek._cache_write` at one lane is a read, a blend and a write a
+    (`deepseek.cache_write` at one lane is a read, a blend and a write a
     slot: 900 small operations a layer at 128 slots, two fifths of a decode
     step's and of what a trace of it holds)."""
     del slot
@@ -705,19 +624,19 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
     with jax.named_scope("attn"):
         u = rms_norm(x, p["norm"], cfg.norm_eps)
         with jax.named_scope("mla_project"):
-            q = _dot(u, p["wq"], cfg).reshape(B, C, H, cfg.qk_head_dim)
-            ckr = _dot(u, p["wkva"], cfg)                         # [N,C,r+p]
+            q = lm.dot(u, p["wq"], cfg.dtype).reshape(B, C, H, cfg.qk_head_dim)
+            ckr = lm.dot(u, p["wkva"], cfg.dtype)                 # [N,C,r+p]
             c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
             # a product batched by the head comes out head first (the CPU
             # backend has no other float32 product of two bf16 operands)
             q_abs = jnp.moveaxis(jnp.sum(jnp.einsum(
-                "abchn,hnr->habcr", _pieces(q[..., :n], cfg),
+                "abchn,hnr->habcr", pieces(q[..., :n], cfg.dtype),
                 p["w_uk"].astype(cfg.dtype),
                 preferred_element_type=jnp.float32), axis=1), 0, 2).astype(
                     cfg.dtype)                                    # [N,C,H,r]
             q_r = q[..., n:].astype(cfg.dtype)
         with jax.named_scope("kv_update"):
-            write = _write_first if slot is None and C == 1 else _cache_write
+            write = _write_first if slot is None and C == 1 else cache_write
             lat = write(lat, i, c.astype(cfg.dtype), pos0, ok, slot)
             kr = write(kr, i, ckr[..., r:].astype(cfg.dtype), pos0, ok, slot)
         with jax.named_scope("mla_attend"):
@@ -726,15 +645,15 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
                 mixed = mla_attend(q_abs[:, 0], q_r[:, 0], lat, kr, i,
                                    pos[:, 0], ok[:, 0], scale)[:, :, None]
             else:
-                mixed = attend_rows(q_abs, q_r, _rows(lat, i, slot),
-                                    _rows(kr, i, slot), pos, scale)
+                mixed = attend_rows(q_abs, q_r, rows(lat, i, slot),
+                                    rows(kr, i, slot), pos, scale)
             # [N,H,C,r] float32, the heads before the lanes
         with jax.named_scope("mla_project"):
             o = jnp.moveaxis(jnp.sum(jnp.einsum(
-                "abhcr,hrv->habcv", _pieces(mixed, cfg),
+                "abhcr,hrv->habcv", pieces(mixed, cfg.dtype),
                 p["w_uv"].astype(cfg.dtype),
                 preferred_element_type=jnp.float32), axis=1), 0, 2)
-            x = x + _dot(o.reshape(B, C, H * v), p["wo"], cfg)
+            x = x + lm.dot(o.reshape(B, C, H * v), p["wo"], cfg.dtype)
     return x, {**cache, "latent": lat, "k_rope": kr}
 
 
@@ -796,35 +715,28 @@ def _further_lanes(rest, mixer: str, mixer_stack, i, mlp_stack, mlp_i,
                    experts, j, cfg: KimiConfig, cache, given, pos, ok,
                    prefilling):
     """One layer over the lanes after the first, rest [B,M,D] with ok
-    [B,M], the first of them at position pos [B]: a slot at a time and only
-    the slots that have such lanes, `prefilling` = (their indices first in
-    a [B] array, how many they are): the loop runs that many times, where a
-    loop over all the slots with a branch each costs three small operations
-    a slot a layer, 3,500 a chunk step at 128 slots (PERF.md, PR 40). The
-    weights are sliced inside the loop (`granite._further_lanes`)."""
-    B, M, D = rest.shape
-    slots, count = prefilling
+    [B,M], the first of them at position pos [B], for the slots
+    `prefilling` a slot at a time (`lm.each_slot`, which has why the weights
+    are sliced inside the body here)."""
+    M = rest.shape[1]
 
-    def slot(n, carry):
+    def slot(b, carry):
         rest, cache, given = carry
-        b = lax.dynamic_index_in_dim(slots, n, 0, keepdims=False)
-        p = _layer_weights(mixer_stack, i)
-        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
-        okb = lax.dynamic_slice(ok, (b, 0), (1, M))
+        p = lm.layer_weights(mixer_stack, i)
+        xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         if mixer == "mla":
-            at = lax.dynamic_slice(pos, (b,), (1,))
             xb, cache = _mla(xb, p, cfg, cache, i, at,
                              at[:, None] + jnp.arange(M), okb, slot=b)
         else:
             xb, cache = _kda_further(xb, p, cfg, cache, i, b, okb)
-        mp = _layer_weights(mlp_stack, mlp_i)
+        mp = lm.layer_weights(mlp_stack, mlp_i)
         if experts is None:
             xb = _dense_mlp(xb, mp, cfg)
         else:
             xb, given = _expert_mlp(xb, mp, experts, j, cfg, given, okb)
-        return lax.dynamic_update_slice(rest, xb, (b, 0, 0)), cache, given
+        return lm.put_lanes(rest, xb, b), cache, given
 
-    return lax.fori_loop(0, count, slot, (rest, cache, given))
+    return lm.each_slot(prefilling, slot, (rest, cache, given))
 
 
 def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
@@ -837,13 +749,13 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
     mlp_stack = params["dense" if dense else "moe"]
     experts = None if dense else params["experts"]
     given = jnp.zeros((cfg.n_experts,), jnp.int32)
-    p = _layer_weights(params[mixer], i)
+    p = lm.layer_weights(params[mixer], i)
     if mixer == "mla":
         first, cache = _mla(first, p, cfg, cache, i, pos0, pos0[:, None],
                             on[:, None])
     else:
         first, cache = _kda_first(first, p, cfg, cache, i, on)
-    mp = _layer_weights(mlp_stack, mlp_i)
+    mp = lm.layer_weights(mlp_stack, mlp_i)
     if dense:
         first = _dense_mlp(first, mp, cfg)
     else:
@@ -861,17 +773,17 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
 def _logits(params: Params, x, cfg: KimiConfig):
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return _dot(x, params["lm_head"], cfg)
+        return lm.dot(x, params["lm_head"], cfg.dtype)
 
 
 def _forward(params: Params, cache, tokens, pos0, length, active,
              cfg: KimiConfig, program: int):
     """Both step programs. A layer computes a lane only where the plan put
-    a token (`models/deepseek.py`, PR 39): every slot's first lane goes
-    through the layer all slots at once, which is the whole decode program;
-    the lanes after it a slot at a time, only the slots that have them, C of
-    them a slot with the last one padding, so that the rows a slot's experts
-    sort come in whole tiles of the grouped matmul.
+    a token (`models/lm.py`, "The lanes of a chunk"): every slot's first
+    lane all slots at once, which is the whole decode program; the lanes
+    after it a slot at a time, only the slots that have them, C of them a
+    slot with the last one padding for the grouped matmul's tiles
+    (`lm.split_lanes`).
 
     The dense layers stand before the loops. The loops carry the cache, one
     buffer a leaf from layer to layer, written in place where the caller
@@ -879,19 +791,10 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     slice."""
     B, C = tokens.shape
     lane = jnp.arange(C)
-    on = active & (length > 0)
     ok = (lane[None, :] < length[:, None]) & active[:, None]
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
-    first, rest, further, prefilling = x[:, :1], None, None, None
-    if C > 1:
-        rest = jnp.pad(x[:, 1:], ((0, 0), (0, 1), (0, 0)))
-        further = jnp.pad(ok[:, 1:], ((0, 0), (0, 1)))
-        with jax.named_scope("embed"):
-            more = further.any(axis=1)
-            # the slots that have further lanes first, and how many
-            prefilling = (jnp.argsort(~more, stable=True).astype(jnp.int32),
-                          more.sum().astype(jnp.int32))
+    first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
     counts = jnp.zeros((len(COUNTS),), jnp.uint32)
     leaves = {k: v for k, v in cache.items() if k != "counts"}
     index = _stack_index(cfg)
@@ -937,8 +840,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
         carry = lax.fori_loop(0, len(runs), run,
                               (first, rest, leaves, counts))
     first, rest, leaves, counts = carry
-    x = first if rest is None else jnp.concatenate(
-        [first, rest[:, :C - 1]], axis=1)
+    x = lm.join_lanes(first, rest, C)
     with jax.named_scope("moe_router"):
         attended = jnp.sum(jnp.where(ok, pos0[:, None] + lane + 1, 0))
         counts = counts.at[COUNTS.index("attended_positions")].set(
@@ -949,21 +851,16 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
             read = read + (prefilling[1] * T).astype(jnp.uint32)
         counts = counts.at[COUNTS.index("read_positions")].set(read)
         counts = cache["counts"].at[program].add(counts)
-    last = jnp.clip(length - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _logits(params, x_last, cfg), {**leaves, "counts": counts}
+    return (_logits(params, lm.last_valid_lane(x, length), cfg),
+            {**leaves, "counts": counts})
 
 
 def prefill_chunk(params: Params, cache, tokens: jax.Array, pos0: jax.Array,
                   length: jax.Array, active: jax.Array, cfg: KimiConfig):
-    """`gpt2.prefill_chunk`'s contract: tokens [B, C] (left-aligned chunk a
-    slot), pos0 [B] (the position of the chunk's first token: the rows are
-    written there; the state does not read it), length [B] (valid tokens,
-    0..C), active [B] -> (logits [B, vocab] float32 at each slot's last
-    valid lane, the cache). Inactive and zero-length slots leave their
-    rows, their state and their window as they were, bit for bit, and their
-    logits are garbage. The state continues whatever the slot held: a new
-    sequence's slot is the caller's to zero. Donate `cache`."""
+    """`gpt2.prefill_chunk`'s signature and every family's contract
+    (`models/lm.py`, "The lanes of a chunk"): -> (logits [B, vocab] float32
+    at each slot's last valid lane, the cache). The rows are written from
+    pos0; the state does not read it. Donate `cache`."""
     return _forward(params, cache, tokens, pos0, length, active, cfg, 1)
 
 

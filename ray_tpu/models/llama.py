@@ -23,12 +23,13 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import lm
 from ray_tpu.parallel.mesh import constrain, logical_to_spec
 
 Params = Any
@@ -168,13 +169,6 @@ def rms_norm(x, p, eps: float):
         return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
-def _w(p, cfg):
-    """A weight in the compute dtype. Every conversion goes through here,
-    so that the innermost scope of its operation names it."""
-    with jax.named_scope("weights_cast"):
-        return p.astype(cfg.dtype)
-
-
 def rope_freqs(positions: jax.Array, head_dim: int, theta: float):
     """positions [...,T] int32 -> (cos, sin) each [...,T, head_dim/2] f32."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
@@ -193,18 +187,16 @@ def attention(x, p, cfg) -> jax.Array:
     """Causal GQA with RoPE. x [B,T,D]; p has wq/wk/wv/wo and, for a
     model with QK-norm (OLMoE), `q_norm`/`k_norm`: an RMSNorm over the
     whole projection, before it is split into heads and before RoPE."""
-    from ray_tpu.models.lm import resolve_attn_impl
-
     B, T, D = x.shape
     H, KV, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    q = x @ _w(p["wq"], cfg)
-    k = x @ _w(p["wk"], cfg)
+    q = x @ lm.weight(p["wq"], cfg.dtype)
+    k = x @ lm.weight(p["wk"], cfg.dtype)
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(B, T, H, Dh)
     k = k.reshape(B, T, KV, Dh)
-    v = (x @ _w(p["wv"], cfg)).reshape(B, T, KV, Dh)
+    v = (x @ lm.weight(p["wv"], cfg.dtype)).reshape(B, T, KV, Dh)
 
     cos, sin = rope_freqs(jnp.arange(T), Dh, cfg.rope_theta)
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -226,7 +218,7 @@ def attention(x, p, cfg) -> jax.Array:
     k = constrain(k, "batch", "heads", "seq", None)
     v = constrain(v, "batch", "heads", "seq", None)
 
-    impl = resolve_attn_impl(cfg.attn_impl, T)
+    impl = lm.resolve_attn_impl(cfg.attn_impl, T)
     if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 
@@ -248,15 +240,15 @@ def attention(x, p, cfg) -> jax.Array:
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
-    return out @ _w(p["wo"], cfg)
+    return out @ lm.weight(p["wo"], cfg.dtype)
 
 
 def swiglu(x, p, cfg) -> jax.Array:
-    g = x @ _w(p["wg"], cfg)
-    u = x @ _w(p["wu"], cfg)
+    g = x @ lm.weight(p["wg"], cfg.dtype)
+    u = x @ lm.weight(p["wu"], cfg.dtype)
     h = jax.nn.silu(g) * u
     h = constrain(h, "batch", "seq", "mlp")
-    return h @ _w(p["wd"], cfg)
+    return h @ lm.weight(p["wd"], cfg.dtype)
 
 
 def attention_residual(x, bp, cfg) -> jax.Array:
@@ -293,7 +285,7 @@ def final_hidden(params: Params, x: jax.Array, cfg) -> tuple:
     with jax.named_scope("unembed_loss"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
-        return x, _w(head, cfg)
+        return x, lm.weight(head, cfg.dtype)
 
 
 def unembed(params: Params, x: jax.Array, cfg) -> jax.Array:
@@ -323,71 +315,9 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
 
 
 def loss_fn(params: Params, batch: dict, cfg: LlamaConfig) -> jax.Array:
-    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
-
-    inputs, targets = split_lm_batch(batch)
+    inputs, targets = lm.split_lm_batch(batch)
     x = hidden_states(params, inputs, cfg)
-    return chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
-
-
-# ---------------------------------------------------------------------------
-# KV-cache decode (serving path; GQA cache holds n_kv_head only)
-# ---------------------------------------------------------------------------
-
-def init_cache(cfg: LlamaConfig, batch: int, max_len: Optional[int] = None):
-    T = max_len or cfg.max_seq_len
-    shape = (cfg.n_layer, batch, cfg.n_kv_head, T, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
-                active: jax.Array, cfg: LlamaConfig):
-    """One continuous-batch decode step (same contract as gpt2.decode_step):
-    tokens [B] int32, pos [B] int32, active [B] bool ->
-    (logits [B,vocab] f32, new_cache)."""
-    B = tokens.shape[0]
-    H, KV, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    T = cache["k"].shape[3]
-    x = params["wte"][tokens].astype(cfg.dtype)               # [B, D]
-    cos, sin = rope_freqs(pos, Dh, cfg.rope_theta)            # [B, Dh/2]
-
-    def upd_one(c_b, val_b, p_b):
-        return lax.dynamic_update_slice(c_b, val_b[:, None, :], (0, p_b, 0))
-
-    def layer(x, scanned):
-        bp, ck, cv = scanned
-        h = rms_norm(x, bp["attn_norm"], cfg.norm_eps)
-        q = (h @ bp["attn"]["wq"].astype(cfg.dtype)).reshape(B, H, Dh)
-        k = (h @ bp["attn"]["wk"].astype(cfg.dtype)).reshape(B, KV, Dh)
-        v = (h @ bp["attn"]["wv"].astype(cfg.dtype)).reshape(B, KV, Dh)
-        q = apply_rope(q, cos[:, None, :], sin[:, None, :])
-        k = apply_rope(k, cos[:, None, :], sin[:, None, :])
-        ck_new = jax.vmap(upd_one)(ck, k, pos)
-        cv_new = jax.vmap(upd_one)(cv, v, pos)
-        ck = jnp.where(active[:, None, None, None], ck_new, ck)
-        cv = jnp.where(active[:, None, None, None], cv_new, cv)
-        # grouped scores: q [B, KV, G, Dh] against cache [B, KV, T, Dh]
-        qg = q.reshape(B, KV, cfg.q_per_kv, Dh)
-        scores = jnp.einsum("bkgd,bktd->bkgt", qg, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(Dh)
-        t_idx = jnp.arange(T)[None, None, None, :]
-        scores = jnp.where(t_idx <= pos[:, None, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bkgt,bktd->bkgd", probs, cv).reshape(B, H * Dh)
-        x = x + attn @ bp["attn"]["wo"].astype(cfg.dtype)
-        h = rms_norm(x, bp["mlp_norm"], cfg.norm_eps)
-        g = h @ bp["mlp"]["wg"].astype(cfg.dtype)
-        u = h @ bp["mlp"]["wu"].astype(cfg.dtype)
-        x = x + (jax.nn.silu(g) * u) @ bp["mlp"]["wd"].astype(cfg.dtype)
-        return x, (ck, cv)
-
-    x, (new_k, new_v) = lax.scan(layer, x,
-                                 (params["blocks"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    return lm.chunked_cross_entropy(*final_hidden(params, x, cfg), targets)
 
 
 def num_params(cfg: LlamaConfig) -> int:

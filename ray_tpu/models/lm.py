@@ -1,11 +1,18 @@
-"""Shared language-model loss plumbing used by every model family."""
+"""What the model families share: the language-model loss and the attention
+policy of the training families, and (from "The serving families" down) what
+a family served by `serve/llm.LLMEngine` borrows: seeded weights made a
+layer at a time into a stack, the product that keeps a float32 activation
+whole, the short convolution, and the lanes of a chunk."""
 
 from __future__ import annotations
 
+import functools
 from functools import partial
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def split_lm_batch(batch: dict):
@@ -252,3 +259,266 @@ def resolve_attn_impl(attn_impl: str, seq_len: int) -> str:
             and tiles_divide(seq_len)):
         return "flash"
     return "dense"
+
+
+# ---------------------------------------------------------------------------
+# The serving families (`models/__init__.py` has the protocol)
+# ---------------------------------------------------------------------------
+#
+# Plain functions that a family calls with its own layer as an argument:
+# what deepseek, brumby, granite and kimi each had a copy of. A family
+# module holds its config, its `_init_layer` bodies, its cache's leaves, its
+# layers' arithmetic and its two programs.
+
+Params = Any
+
+
+# -- weights, a layer at a time ---------------------------------------------
+
+def normal(key, shape, std, dtype):
+    """N(0, std) drawn in float32, held in `dtype`."""
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def ones(n: int) -> Params:
+    """A norm's scale, float32 (it is used in float32)."""
+    return {"scale": jnp.ones((n,), jnp.float32)}
+
+
+@functools.lru_cache(maxsize=None)
+def layer_program(make: Callable, *static):
+    """The one compiled program that makes a kind of layer, `make(key, l,
+    *static)` with `static` what selects the kind (the config, `dense`, the
+    mixer's name): wherever a layer is made it is made by this program (a
+    sum fused another way may round another way), so a layer made alone is,
+    to the bit, the layer in the family's `init_params` tree."""
+    return jax.jit(lambda key, l: make(key, l, *static))
+
+
+def empty_stack(like: Params, n: int) -> Params:
+    """Zeros [n, ...] for every leaf of `like` (arrays or their shapes),
+    made where they will lie by one program."""
+    return jax.jit(lambda: jax.tree.map(
+        lambda a: jnp.zeros((n,) + a.shape, a.dtype), like))()
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def put_layer(stack: Params, layer: Params, i) -> Params:
+    """The stack with `layer` as its entry i, written where the stack lies
+    (donated). A leaf of `layer` that has a leading axis of its own where
+    the stack's leaf has none (Kimi's held experts, [E', ...] a layer in a
+    stack [layers x E', ...]) is entries i E' .. (i + 1) E'."""
+    def into(s, a):
+        a = a.reshape((-1,) + s.shape[1:])
+        return lax.dynamic_update_slice_in_dim(s, a, i * a.shape[0], 0)
+
+    return jax.tree.map(into, stack, layer)
+
+
+def stack_layers(make: Callable, n: int) -> Params:
+    """`make(i)`, the tree of a stack's entry i (a family's `init_layer` at
+    the layer that entry is), for i in 0..n-1, stacked on a leading axis.
+    The stack is allocated once and each entry is written into it, donated,
+    so the most that exists beside the tree is one layer: no float32 copy
+    of the tree and no second copy of a stack, which is what lets a 20.3 GB
+    model start on a 16.9 GB chip (PERF.md section 4, PR 29)."""
+    stack = empty_stack(jax.eval_shape(lambda: make(0)), n)
+    for i in range(n):
+        stack = put_layer(stack, make(i), jnp.int32(i))
+    return stack
+
+
+def resident_params(params: Params, cfg) -> Params:
+    """A family whose `init_params` makes the tree a replica holds has
+    nothing to convert: its `resident_params` is this."""
+    del cfg
+    return params
+
+
+def layer_weights(stack: Params, i, turn=None) -> Params:
+    """Entry i of a stack: its weights sliced where they lie. `turn`, in the
+    body of a loop whose every turn takes the same entry (`each_slot`'s b),
+    makes the slice that turn's own: the compiler lifts a slice that no
+    turn changes out of the loop, joins it with the first lanes' and copies
+    every matrix out of the stack, 1.6 GB a chunk step of granite's."""
+    if turn is not None:
+        i, _ = lax.optimization_barrier((i, turn))
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
+
+
+# -- a layer's arithmetic -----------------------------------------------------
+
+def weight(p, dtype):
+    """A weight as a product reads it: in the compute dtype (a conversion
+    only where the replica holds it in another)."""
+    with jax.named_scope("weights_cast"):
+        return p.astype(dtype)
+
+
+def dot(x, w, dtype):
+    """x [..., K] float32 times the weight w [K, N] -> [..., N] float32. The
+    operands are the compute dtype's, and x goes as the two pieces that add
+    up to it (its rounding and what the rounding left: `ops/pieces.py`),
+    side by side on the rows of one product: one pass of the weight, which
+    is what a decode step's product costs, and none of the activation's
+    rounding in the result. With that rounding in every product of 80
+    sublayers granite's logits lay 1.1% of their spread from the
+    reference's, as far as a state held in bfloat16 puts them (PERF.md, PR
+    38). A float32 compute dtype is one product at full precision."""
+    # here, not at the top: `ray_tpu.ops` brings Pallas in, a second of
+    # every process's start that imports a model (gpt2 and llama import
+    # this module)
+    from ray_tpu.ops.pieces import pieces
+
+    x = x.astype(jnp.float32)
+    if dtype == jnp.float32:
+        return jnp.dot(x, w.astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST)
+    both = jnp.dot(pieces(x, dtype), weight(w, dtype),
+                   preferred_element_type=jnp.float32)
+    return both[0] + both[1]
+
+
+def over_lanes(per_head, lanes: int):
+    """[..., H] -> [..., H lanes]: a head's value over its lanes."""
+    return jnp.repeat(per_head, lanes, axis=-1)
+
+
+def short_conv(x, taps, window, ok, bias=None):
+    """The causal depthwise convolution of x [B,M,F] behind `window`
+    [B, (K-1) F], the K - 1 inputs before it side by side, by `taps` [K,F]
+    (tap k multiplies the input K - 1 - k positions back) and `bias` [F] if
+    the family has one, then silu: -> (out [B,M,F], the window left
+    behind), the K - 1 inputs that end at each slot's last valid lane by
+    `ok` [B,M]. A slot with no valid lane keeps its window bit for bit. One
+    lane (M = 1: every slot's first lane, the decode program) moves the
+    window on by one input; M lanes gather it. Call it under the family's
+    own scope: the per-layer readers sum by scope."""
+    K = taps.shape[0]
+    B, M, F = x.shape
+    if M == 1:
+        ext = jnp.concatenate([window, x[:, 0]], axis=-1)          # [B, K F]
+        out = sum(taps[k] * ext[:, k * F:(k + 1) * F] for k in range(K))
+        new = jnp.where(ok, ext[:, F:], window)
+        out = out[:, None]
+    else:
+        ext = jnp.concatenate([window.reshape(B, K - 1, F), x], axis=1)
+        out = sum(taps[k] * ext[:, k:k + M] for k in range(K))
+        at = ok.sum(axis=1)[:, None] + jnp.arange(K - 1)[None, :]  # [B,K-1]
+        new = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+        new = jnp.where(ok.any(axis=1)[:, None],
+                        new.reshape(B, (K - 1) * F), window)
+    return jax.nn.silu(out if bias is None else bias + out), new
+
+
+# -- the lanes of a chunk ----------------------------------------------------
+#
+# `prefill_chunk`'s contract, for every family (`gpt2.prefill_chunk` has the
+# signature): tokens [B, C] (a left-aligned chunk a slot), pos0 [B] (the
+# position of the chunk's first token), length [B] (valid tokens, 0..C),
+# active [B] -> (logits [B, vocab] float32 at each slot's last valid lane,
+# the cache). An inactive or zero-length slot leaves every leaf of the cache
+# as it was, bit for bit (rows, state and window alike), and its logits are
+# garbage. Recurrent state continues whatever the slot held: a new
+# sequence's slot is the caller's to zero. pos0 + length <= T and C <= T are
+# the caller's to keep. `decode_step` is the same program at one lane a slot.
+#
+# A program computes a lane only where the plan put a token (PERF.md, PRs 38
+# and 39). Every slot's first lane goes through a layer all slots at once:
+# that is the whole decode program, and in the chunk program every decode
+# lane riding along and the first token of every chunk. The lanes after it
+# go a slot at a time and only the slots that have any (`each_slot`), so a
+# chunk step costs the decode program's time plus a term a slot that
+# prefills, not B x C lanes whoever prefills.
+
+def slots_first(has):
+    """has [B] bool -> (the indices of the slots that have it first, in
+    index order, in a [B] int32 array; how many they are): what `each_slot`
+    turns over."""
+    return (jnp.argsort(~has, stable=True).astype(jnp.int32),
+            has.sum().astype(jnp.int32))
+
+
+def split_lanes(x, ok, pad: bool):
+    """x [B,C,D] and ok [B,C] (a lane is a token's) -> (first [B,1,D], on
+    [B], rest, further, prefilling): every slot's first lane and whether it
+    is valid; for C > 1 the lanes after it, rest [B,M,D] with further
+    [B,M], and `slots_first` of the slots that have any (None, all three,
+    at C = 1: the decode program never enters the loop). M is C - 1, or C
+    with `pad`, the last lane padding: a family with routed experts pads,
+    so that the rows a slot's experts sort come in whole tiles of the
+    grouped matmul (`ops/grouped_matmul._tiling` halves a tile until it
+    divides the rows: 127 lanes x 6 would be tiles of 2 rows)."""
+    first, on = x[:, :1], ok[:, 0]
+    if x.shape[1] == 1:
+        return first, on, None, None, None
+    rest, further = x[:, 1:], ok[:, 1:]
+    if pad:
+        rest = jnp.pad(rest, ((0, 0), (0, 1), (0, 0)))
+        further = jnp.pad(further, ((0, 0), (0, 1)))
+    with jax.named_scope("embed"):
+        prefilling = slots_first(further.any(axis=1))
+    return first, on, rest, further, prefilling
+
+
+def join_lanes(first, rest, C: int):
+    """`split_lanes`' inverse: x [B,C,D]."""
+    if rest is None:
+        return first
+    return jnp.concatenate([first, rest[:, :C - 1]], axis=1)
+
+
+def last_valid_lane(x, length):
+    """x [B,C,D] -> [B,D]: each slot's lane length - 1 (lane 0 of a slot
+    with none, whose logits are garbage)."""
+    last = jnp.clip(length - 1, 0, x.shape[1] - 1)
+    return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+
+
+def each_slot(slots, slot: Callable, carry):
+    """`slot(b, carry) -> carry` for each slot b of `slots` =
+    `slots_first(..)`, in index order: the loop turns as often as there are
+    such slots and not at all when there are none, so a slot without lanes
+    costs nothing and its part of every carried leaf is never touched. (A
+    loop over all B slots with a `lax.cond` each cost three small
+    operations a slot a layer, 3,500 a chunk step at Kimi's 128 slots:
+    PERF.md, PR 40; and a leaf that passes through a conditional untouched
+    may be copied on its way.)
+
+    The body stays the family's, because how a layer's weights reach it is
+    measured, and a new family reads this first. Where the layers' loop is
+    a scan over the stacked weights, close over the layer as the scan holds
+    it (deepseek): sliced again inside the body, a layer's three expert
+    matrices are copied once more for every slot that prefills, 41.7 ms a
+    slot for 15.8 (`benchmarks/kanana_chunk_lanes.py`). Where the layers'
+    loop indexes the stack, slice the layer inside the body too (granite,
+    kimi: `layer_weights`, with the body's b as its `turn` where the
+    compiler would lift the slice out again): sliced once for both the
+    first lanes and the loop, the compiler copies every matrix out of the
+    stack, 6.4 GB a chunk step (PERF.md, PR 38).
+
+    And what the loop writes in place, the first lanes have to have read:
+    where nothing a body takes comes from the first lanes' pass (granite:
+    no expert counts go in), the compiler cannot tell which is first and
+    copies the leaf for the loop, 1.6 GB a leaf a layer. Tie them before
+    the loop: `first, cache = lax.optimization_barrier((first, cache))`."""
+    indices, count = slots
+    return lax.fori_loop(
+        0, count, lambda n, carry: slot(
+            lax.dynamic_index_in_dim(indices, n, 0, keepdims=False), carry),
+        carry)
+
+
+def slot_lanes(b, rest, ok, pos):
+    """What a body of `each_slot` takes of the further lanes: slot b's own
+    xb [1,M,D], okb [1,M] and the position of the first of them, at [1]."""
+    M, D = rest.shape[1:]
+    return (lax.dynamic_slice(rest, (b, 0, 0), (1, M, D)),
+            lax.dynamic_slice(ok, (b, 0), (1, M)),
+            lax.dynamic_slice(pos, (b,), (1,)))
+
+
+def put_lanes(rest, xb, b):
+    """`rest` with slot b's lanes xb [1,M,D] back in their place."""
+    return lax.dynamic_update_slice(rest, xb, (b, 0, 0))

@@ -38,9 +38,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import llama as _llama
+from ray_tpu.models import llama as _llama, lm
 from ray_tpu.ops.expert_mlp import expert_mlp
 from ray_tpu.ops.grouped_matmul import TILE_M, grouped_matmul
+from ray_tpu.ops.pieces import pieces
 from ray_tpu.parallel.mesh import constrain, current_mesh, logical_to_spec
 
 Params = Any
@@ -271,22 +272,18 @@ def _rows_times_experts(xs, w, group_sizes, first_expert):
     matrices w. Rows and matrices of one dtype go as they are. Float32 rows
     against matrices held narrower (a served program whose activations stay
     float32, `models/kimi.py`) go as the two pieces of the matrices' dtype
-    that add up to them, a row's two side by side so that the groups stay
-    sorted, and come back float32: one pass of the matrices, none of the
-    rows' rounding in the result. The pieces and both pieces' products pass
-    through HBM here: it is the form of the `cpu` backend, of many rows a
-    group, and what `ops/expert_mlp.py` is tested against; a served
-    program's few float32 rows a group take that kernel on the chip
-    (`_one_kernel`), where the pieces never leave VMEM."""
+    that add up to them (`ops/pieces.py`), a row's two side by side so that
+    the groups stay sorted, and come back float32: one pass of the
+    matrices, none of the rows' rounding in the result. The pieces and both
+    pieces' products pass through HBM here: it is the form of the `cpu`
+    backend, of many rows a group, and what `ops/expert_mlp.py` is tested
+    against; a served program's few float32 rows a group take that kernel
+    on the chip (`_one_kernel`), where the pieces never leave VMEM."""
     if xs.dtype == w.dtype:
         return grouped_matmul(xs, w, group_sizes, first_expert)
-    bits = jnp.finfo(w.dtype)
-    high = lax.reduce_precision(xs, exponent_bits=bits.nexp,
-                                mantissa_bits=bits.nmant)
-    pieces = jnp.stack([high, xs - high], axis=1).astype(w.dtype)
-    both = grouped_matmul(pieces.reshape(-1, xs.shape[-1]), w,
-                          2 * group_sizes, first_expert,
-                          out_dtype=jnp.float32)
+    both = grouped_matmul(
+        pieces(xs, w.dtype, axis=1).reshape(-1, xs.shape[-1]), w,
+        2 * group_sizes, first_expert, out_dtype=jnp.float32)
     return both[0::2] + both[1::2]
 
 
@@ -402,7 +399,7 @@ def moe_layer(x, p, cfg: MoEConfig):
     N = B * T
     logits, probs, gates, experts = _route(x.reshape(N, D), p["router"], cfg)
     gates, experts = gates.reshape(B, T, K), experts.reshape(B, T, K)
-    weights = [_llama._w(p[k], cfg) for k in ("wg", "wu", "wd")]
+    weights = [lm.weight(p[k], cfg.dtype) for k in ("wg", "wu", "wd")]
     mesh = current_mesh()
     if mesh is None or mesh.size == 1:
         out = _experts(x, gates, experts, *weights, cfg)
@@ -477,11 +474,10 @@ def loss_fn(params: Params, batch: dict, cfg: MoEConfig):
     """(cross-entropy + aux_loss_weight · load-balancing loss +
     z_loss_weight · router z-loss, aux): `aux` holds what a MoE job
     watches and `train/spmd.compile_train` adds to the step's metrics."""
-    from ray_tpu.models.lm import chunked_cross_entropy, split_lm_batch
-
-    inputs, targets = split_lm_batch(batch)
+    inputs, targets = lm.split_lm_batch(batch)
     x, aux, _ = hidden_states(params, inputs, cfg)
-    ce = chunked_cross_entropy(*_llama.final_hidden(params, x, cfg), targets)
+    ce = lm.chunked_cross_entropy(*_llama.final_hidden(params, x, cfg),
+                                  targets)
     loss = (ce + cfg.aux_loss_weight * aux["aux_loss"]
             + cfg.z_loss_weight * aux["z_loss"])
     return loss, {"router_aux_loss": aux["aux_loss"],

@@ -46,6 +46,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.pieces import pieces
+
 # a grid step takes one slot's state of the layer whole, 2 MB in one stretch
 # of HBM at the published sizes: its four buffers are 8 MiB of VMEM
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
@@ -103,16 +105,6 @@ def _kernel(layer_ref, active_ref, s_ref, cols_ref, pick_ref, rows_ref,
             so_ref[0, 0, h] = decayed + k * u
 
 
-def _pieces(x):
-    """x float32 [...] -> [..., 3] bf16 that add up to it."""
-    out = []
-    for _ in range(PIECES):
-        piece = lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-        out.append(piece)
-        x = x - piece
-    return jnp.stack(out, axis=-1).astype(jnp.bfloat16)
-
-
 @functools.lru_cache(maxsize=None)
 def _pick(p: int) -> np.ndarray:
     """[GROUP, 128, 3 P] 0/1: entry j sums, for each of head j's three
@@ -131,7 +123,8 @@ def _update_kernel(state, layer, a, k, q, v, b, active, interpret: bool):
     assert N % STRIP == 0 and P % LANES == 0, (N, P)
     groups = -(-H // GROUP)
     # [B, H, N, 3 vectors x 3 pieces] -> [B, N, H x 16 lanes]
-    cols = _pieces(jnp.stack([a, k, q], axis=-1)).reshape(B, H, N, 3 * PIECES)
+    cols = pieces(jnp.stack([a, k, q], axis=-1), jnp.bfloat16, PIECES,
+                  axis=-1).reshape(B, H, N, 3 * PIECES)
     cols = jnp.pad(cols, ((0, 0), (0, groups * GROUP - H), (0, 0),
                           (0, HEAD_LANES - 3 * PIECES)))
     cols = jnp.transpose(cols, (0, 2, 1, 3)).reshape(B, N, groups * LANES)

@@ -290,7 +290,10 @@ def test_gpt2_xl_serving_programs_are_the_parents(chips, program):
 # lanes' scores, and the decode program, whose temporaries are a copy of a
 # layer's expert matrices whatever attention does, gained the kernel's
 # operands: 790,528 B over the file's `decode_step_bytes` 11,773,044,224
-KANANA_CHUNK_BYTES = 12_781_761_024
+# Since PR 43 the slots that prefill are `lm.each_slot`'s, a loop of as many
+# turns, where a loop over all 32 held a conditional each (12,781,761,024 B,
+# and the branch handed back a copy of each leaf it wrote: four)
+KANANA_CHUNK_BYTES = 12_785_953_792
 KANANA_DECODE_BYTES = 11_773_834_752
 
 
@@ -345,12 +348,20 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
     assert sized["temp"] < 1.5e9
     assert _written_arrays(hlo, f"32,32,{chunk},4096", r"\w+") == []
     assert _written_arrays(hlo, f"1,32,{chunk},4096", "f32")     # one slot's
-    # a whole leaf is only what the branch of a slot that prefills hands
-    # back (two leaves, a dense layer's loop and the expert layers'): it
-    # writes its window where the leaf lies, as the first lanes do
+    # no whole leaf is written anywhere: a slot that prefills writes its
+    # window where the leaf lies, as the first lanes do
     assert made_of(hlo, config) == {
-        "grouped_matmul_kernels": 6 + 2, "cache_copies": ["conditional"] * 4,
+        "grouped_matmul_kernels": 6 + 2, "cache_copies": [],
         "expert_weight_copies": ["fusion"] * 3}
+
+
+# Brumby's and granite's chunk programs since PR 43: `lm.each_slot` turns
+# over the slots that have lanes, where a loop over all slots held a
+# conditional each. The configuration files are the benchmark's and keep the
+# older form's bytes (13,163,250,176 and 13,507,884,032) until a `benchmark`
+# issue, so the pins are this file's own, and no larger
+BRUMBY_CHUNK_BYTES = 13_159_184_896
+GRANITE_CHUNK_BYTES = 13_457_487_872
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -380,6 +391,9 @@ def test_brumby_serving_programs_compile_at_the_configurations_sizes(
     want = (memory["decode_step_bytes"] if program == "decode" else memory[
         "prefill_chunk_bytes_by_chunk_size"][
             str(config["deployment"]["prefill_chunk_size"])])
+    if program == "prefill":
+        assert BRUMBY_CHUNK_BYTES <= want
+        want = BRUMBY_CHUNK_BYTES
     assert sized["total"] == want
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 16 * 64 * 4 * 2)   # the chunk's tokens
@@ -426,6 +440,9 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
     want = (memory["decode_step_bytes"] if program == "decode" else memory[
         "prefill_chunk_bytes_by_chunk_size"][
             str(config["deployment"]["prefill_chunk_size"])])
+    if program == "prefill":
+        assert GRANITE_CHUNK_BYTES <= want
+        want = GRANITE_CHUNK_BYTES
     assert sized["total"] == want
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 48 * 64 * 4 * 2)   # the chunk's tokens
