@@ -471,6 +471,15 @@ def _engine_logits_together(eng, served: list) -> list:
     rows = [[row] for row in chunks(slots, start, prompts)]
     at = np.asarray(slots)
     pos = [len(p) for p in prompts]
+    unread: list = []         # (a step's rows on their way to the host, live)
+
+    def read_oldest():
+        picked, live = unread.pop(0)
+        step = np.asarray(picked)
+        for i, slot in enumerate(slots):
+            if live[slot]:
+                rows[i].append(step[i])
+
     for j in range(max(len(s["token_ids"]) for s in served) - 1):
         tokens, where = np.zeros((B,), np.int32), np.zeros((B,), np.int32)
         live = np.zeros((B,), bool)
@@ -480,10 +489,16 @@ def _engine_logits_together(eng, served: list) -> list:
                 live[slot] = True
         logits, eng.cache = eng._step(eng.params, eng.cache, tokens, where,
                                       live)
-        step = np.asarray(logits[at])
-        for i, slot in enumerate(slots):
-            if live[slot]:
-                rows[i].append(step[i])
+        picked = logits[at]
+        picked.copy_to_host_async()
+        unread.append((picked, live))
+        # the step before is read only now, with this one dispatched: the
+        # device never waits for the host's conversion (PR 45), and no more
+        # than two steps' rows wait on the device
+        if len(unread) == 2:
+            read_oldest()
+    while unread:
+        read_oldest()
     return [np.stack(r) for r in rows]
 
 

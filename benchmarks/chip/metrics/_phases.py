@@ -39,7 +39,7 @@ def _idle_pct_of(path: str):
     devices, host_lines = _events.load(path)
     if not devices:
         return None
-    _, gaps, window = _events.idlest(devices)
+    _, gaps, window = _events.idlest(path)
     idle = idle_by_phase(gaps, tr.dispatch_thread(host_lines))
     if not idle or not window:
         return None                   # a program without the phases

@@ -15,8 +15,6 @@ from __future__ import annotations
 import functools
 import re
 
-import trace_reduce as tr
-
 from . import _events
 
 SCOPES = frozenset({"embed", "ln", "attn", "mlp", "unembed_loss",
@@ -39,22 +37,26 @@ def scope_of(tf_op) -> str:
     return UNSCOPED
 
 
-def self_time_by_scope(ops: list, meta: dict) -> dict:
-    """ns of self time by scope, for one device's `(start, end, metadata
-    id)` operations and its table of metadata."""
+def self_time_by_scope(own: list, meta: dict) -> dict:
+    """ns of self time by scope, for one device's walk (`_events.walked`'s
+    `own`) and its table of metadata."""
     out: dict = {}
-    for ident, own in tr.self_intervals(ops):
-        scope = scope_of(meta.get(ident, {}).get("tf_op"))
-        out[scope] = out.get(scope, 0.0) + tr.length(own)
+    scopes: dict = {}                 # by metadata id: one lookup an id
+    for ident, _, ns in own:
+        if ident not in scopes:
+            scopes[ident] = scope_of(meta.get(ident, {}).get("tf_op"))
+        out[scopes[ident]] = out.get(scopes[ident], 0.0) + ns
     return out
 
 
 @functools.lru_cache(maxsize=2)
 def _shares_of(path: str):
     devices, _ = _events.load(path)
+    walked = _events.walk(path)
     total: dict = {}
-    for d in devices.values():
-        for scope, ns in self_time_by_scope(d["ops"], d["meta"]).items():
+    for name, d in devices.items():
+        for scope, ns in self_time_by_scope(walked[name]["own"],
+                                            d["meta"]).items():
             total[scope] = total.get(scope, 0.0) + ns
     whole = sum(total.values())
     if not whole or set(total) <= {UNSCOPED}:

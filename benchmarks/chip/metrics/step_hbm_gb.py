@@ -12,15 +12,8 @@ from . import _events
 MODULE = "jit__step"
 
 
-def leaves(ops: list) -> list:
-    """The `(start, end, name)` events that enclose no other."""
-    order = sorted(ops, key=lambda ev: (ev[0], -ev[1]))
-    return [ev for ev, nxt in zip(order, order[1:] + [None])
-            if nxt is None or nxt[0] >= ev[1]]
-
-
 def bytes_per_execution(ops: list, modules: list, meta: dict) -> list:
-    ops = leaves(ops)                  # sorted by start
+    """`ops`: a device's leaves by start (`_events.walked`'s `leaves`)."""
     starts = [s for s, _, _ in ops]
     out = []
     for m0, m1, name in modules:
@@ -40,9 +33,10 @@ def read(record):
         devices, _ = _events.load(path)
         if not devices:
             return None
-        worst, _, _ = _events.idlest(devices)
+        worst, _, _ = _events.idlest(path)
         d = devices[worst]
-        runs = bytes_per_execution(d["ops"], d["modules"], d["meta"])
+        runs = bytes_per_execution(_events.walk(path)[worst]["leaves"],
+                                   d["modules"], d["meta"])
     except (OSError, ValueError, IndexError, UnicodeDecodeError):
         return None
     runs = [b for b in runs if b]

@@ -81,9 +81,11 @@ def subtract(a: list, b: list) -> list:
     return out
 
 
-def overlap(intervals: list, disjoint: list) -> float:
-    """Length of the part of `intervals` inside the disjoint sorted ones."""
-    starts = [s for s, _ in disjoint]
+def overlap(intervals: list, disjoint: list, starts=None) -> float:
+    """Length of the part of `intervals` inside the disjoint sorted ones;
+    `starts`, their starts, where the caller asks many times."""
+    if starts is None:
+        starts = [s for s, _ in disjoint]
     total = 0.0
     for s, e in intervals:
         k = max(bisect.bisect_right(starts, s) - 1, 0)
@@ -93,24 +95,46 @@ def overlap(intervals: list, disjoint: list) -> float:
     return total
 
 
-def self_intervals(events: list) -> list:
+def start_order(events: list) -> list:
+    """The indices of one line's events by start, an enclosing event
+    before what it encloses: the one order every walk of a line takes."""
+    return sorted(range(len(events)),
+                  key=lambda i: (events[i][0], -events[i][1]))
+
+
+def self_intervals(events: list, order=None) -> list:
     """For `(start, end, name)` events of one line, nested or not:
-    `(name, [intervals])` with each event's own intervals, its directly
-    nested events cut out."""
-    order = sorted(range(len(events)),
-                   key=lambda i: (events[i][0], -events[i][1]))
-    children: dict = {i: [] for i in order}
+    `(name, [intervals])` in `start_order` with each event's own
+    intervals, its directly nested events cut out."""
+    if order is None:
+        order = start_order(events)
+    children: dict = {}
     stack: list = []
     for i in order:
         s, e, _ = events[i]
         while stack and events[stack[-1]][1] <= s:
             stack.pop()
         if stack and e <= events[stack[-1]][1]:
-            children[stack[-1]].append([s, e])
+            children.setdefault(stack[-1], []).append([s, e])
         stack.append(i)
-    return [(events[i][2],
-             subtract([[events[i][0], events[i][1]]], union(children[i])))
-            for i in order]
+    out = []
+    for i in order:
+        s, e, name = events[i]
+        if i in children:
+            own = subtract([[s, e]], union(children[i]))
+        else:
+            own = [[s, e]] if s < e else []
+        out.append((name, own))
+    return out
+
+
+def leaves(events: list, order=None) -> list:
+    """The events that enclose no other, in `start_order`."""
+    if order is None:
+        order = start_order(events)
+    ordered = [events[i] for i in order]
+    return [ev for ev, nxt in zip(ordered, ordered[1:] + [None])
+            if nxt is None or nxt[0] >= ev[1]]
 
 
 # ------------------------------------------------------------- the reduction
@@ -203,8 +227,9 @@ def reduce_events(devices: dict, host_events: list) -> dict:
     n = len(per_device)
     gaps = subtract([[t0, t1]], per_device[worst]["busy"])
     by_label: dict = {}
+    gap_starts = [s for s, _ in gaps]         # once, not once a host event
     for name, own in self_intervals(host_events):
-        inside = overlap(own, gaps)
+        inside = overlap(own, gaps, gap_starts)
         if inside:
             label = _clean(name)
             by_label[label] = by_label.get(label, 0.0) + inside / 1e9

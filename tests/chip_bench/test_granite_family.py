@@ -26,6 +26,7 @@ from generators import closed_loop_documents  # noqa: E402
 from harness import spec  # noqa: E402
 from metrics import _moe_scopes, _scopes, _ssm_scopes  # noqa: E402
 from test_hot_path_metrics import DEVICE, _msg, _plane  # noqa: E402
+from test_kanana_family import DECODE  # noqa: E402
 
 CONFIG = spec.load_json(os.path.join(
     CHIP_DIR, "configs", "granite-4.0-h-micro-serve-1chip.json"))
@@ -47,9 +48,9 @@ TINY = {"vocab_size": 512, "num_hidden_layers": 6,
 OWN = {"ssm_update_time_pct", "ssm_conv_time_pct", "ssm_project_time_pct",
        "ssm_chunk_time_pct", "gqa_attend_time_pct", "ssm_update_roofline_pct",
        "gqa_attend_roofline_pct", "rows_without_snapshot_tokens",
-       "engine_attn_time_pct.docgen", "engine_mlp_time_pct.docgen",
-       "engine_head_time_pct.docgen", "engine_prefix_pool_time_pct.docgen",
-       "kv_bytes_per_token.docgen"}
+       "engine_attn_time_pct", "engine_mlp_time_pct",
+       "engine_head_time_pct", "engine_prefix_pool_time_pct",
+       "kv_bytes_per_token"}
 
 
 # ------------------------------------------------------------ configuration
@@ -124,29 +125,27 @@ def test_the_program_is_built_at_the_published_widths():
     assert tok.encode(tok.decode([1, 100351, 7])) == [1, 100351, 7]
 
 
-def test_the_cell_reads_the_decode_metrics_that_exist_for_it_and_its_own():
-    bench = spec.benchmark()
+def the_cell_reads_what_it_reads(bench):
+    """Holds the cell to what it reads, never to who else reads it: a
+    later cell joins an entry's list (`test_a_tenth_cell.py`)."""
     cell = spec.cell(bench, CELL)
     assert cell["chips"] == 1 and cell["traffic"] == TRAFFIC
     assert {m["name"] for m in cell["end_to_end"]} == {"serve_tokens_per_s",
                                                        "setup_s"}
     names = {m["name"] for m in cell["per_layer"]}
-    decode = {m["name"] for m in bench["per_layer"]
-              if m["name"].endswith(".decode")}
+    assert DECODE <= names
     # the experts' three can only read null here: the model has none
-    assert decode - names == {"moe_router_time_pct.decode",
-                              "moe_dispatch_time_pct.decode",
-                              "moe_experts_time_pct.decode"}
-    # both gauges, for the first time in one cell (the rows' under a name of
-    # its own: a test of PR 29's pins the other entry's list to its cell)
-    assert {"kv_bytes_per_token.docgen", "state_bytes_per_slot",
+    assert names.isdisjoint({"moe_router_time_pct.decode",
+                             "moe_dispatch_time_pct.decode",
+                             "moe_experts_time_pct.decode"})
+    # both gauges, for the first time in one cell
+    assert {"kv_bytes_per_token", "state_bytes_per_slot",
             "setup_engine_build_s"} <= names
-    assert "kv_bytes_per_token" not in names
     # "contains", never "ends with": later PRs append too
     assert OWN <= names
     for m in bench["per_layer"]:
         if m["name"] in OWN:
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]
             assert m["moves"] == "serve_tokens_per_s"
             assert spec.metric_reader(m["name"]) is not None
     layers = {m["name"]: m["layer"] for m in bench["per_layer"]}
@@ -156,6 +155,10 @@ def test_the_cell_reads_the_decode_metrics_that_exist_for_it_and_its_own():
         "prefix_reuse_pct.decode"]
     assert layers["ssm_update_time_pct"] == layers["mla_attend_time_pct"]
     assert len(bench["per_layer"]) <= 128
+
+
+def test_the_cell_reads_the_decode_metrics_that_exist_for_it_and_its_own():
+    the_cell_reads_what_it_reads(spec.benchmark())
 
 
 def test_the_traffic_is_the_issues_letter_for_letter():
@@ -586,13 +589,13 @@ def served_record(tmp_path_factory):
     ("ssm_update_time_pct", 20.0), ("ssm_conv_time_pct", 10.0),
     ("ssm_project_time_pct", 10.0), ("ssm_chunk_time_pct", 10.0),
     ("gqa_attend_time_pct", 10.0),
-    ("engine_attn_time_pct.docgen", 60.0),
-    ("engine_mlp_time_pct.docgen", 10.0),
-    ("engine_head_time_pct.docgen", 10.0),
-    ("engine_prefix_pool_time_pct.docgen", 10.0),
+    ("engine_attn_time_pct", 60.0),
+    ("engine_mlp_time_pct", 10.0),
+    ("engine_head_time_pct", 10.0),
+    ("engine_prefix_pool_time_pct", 10.0),
     ("kv_update_time_pct.decode", 10.0),
     ("state_bytes_per_slot", 77_377_536),
-    ("kv_bytes_per_token.docgen", 8192),
+    ("kv_bytes_per_token", 8192),
     ("rows_without_snapshot_tokens", 0),
     # 8 ns of the 20 a step spends under ssm_update; 4 of gqa_attend's 10
     ("ssm_update_roofline_pct", 40.0), ("gqa_attend_roofline_pct", 40.0)])
@@ -601,9 +604,9 @@ def test_every_new_entry_reads_its_number(served_record, name, want):
 
 
 @pytest.mark.parametrize("name", sorted(
-    OWN - {"engine_attn_time_pct.docgen", "engine_mlp_time_pct.docgen",
-           "engine_head_time_pct.docgen", "kv_bytes_per_token.docgen",
-           "engine_prefix_pool_time_pct.docgen"}))
+    OWN - {"engine_attn_time_pct", "engine_mlp_time_pct",
+           "engine_head_time_pct", "kv_bytes_per_token",
+           "engine_prefix_pool_time_pct"}))
 def test_a_program_without_the_scopes_and_counters_reads_as_nothing(
         name, served_record):
     """The parent's engine has neither: None, not 0 and not a crash."""
@@ -659,7 +662,7 @@ def test_the_cell_runs_end_to_end_on_the_cpu_at_a_tiny_size():
         "the other set of metrics:")[1].strip().splitlines()[0])
     assert other["prefix_reuse_pct.decode"]["value"] > 80
     assert other["state_bytes_per_slot"]["value"] == 4 * (16 * 128 + 480) * 4
-    assert other["kv_bytes_per_token.docgen"]["value"] == 2 * 2 * 2 * 16 * 2
+    assert other["kv_bytes_per_token"]["value"] == 2 * 2 * 2 * 16 * 2
     assert other["rows_without_snapshot_tokens"]["value"] == 0
     assert "'ok': True" in out.stderr and "'tokens_checked'" in out.stderr
 

@@ -98,30 +98,45 @@ def test_the_program_is_built_at_the_published_widths():
     assert tok.encode(tok.decode([0, 128255, 7])) == [0, 128255, 7]
 
 
-def test_the_cell_reads_the_decode_metrics_and_its_own():
-    bench = spec.benchmark()
+# What every document cell reads of the first decode cell's readings, by
+# name and never by count: a later PR may append a `.decode` reading for any
+# cell, these or `serve-xl-decode` alone (`test_a_tenth_cell.py`).
+DECODE = {"stream_open_ms.decode", "engine_step_ms.decode",
+          "tokens_per_step.decode", "decode_device_ms.decode",
+          "prefill_device_ms.decode", "prefix_reuse_pct.decode",
+          "device_idle_pct.decode", "gen_late_p99_ms.decode",
+          "engine_host_ms.decode", "queue_wait_mean_ms.decode",
+          "idle_in_fetch_pct.decode", "idle_in_sample_pct.decode",
+          "idle_in_loop_pct.decode", "kv_update_time_pct.decode",
+          "unscoped_time_pct.decode", "step_hbm_gb.decode",
+          "layers_time_pct.decode"}
+OWN = {"engine_attn_time_pct", "engine_mlp_time_pct", "engine_head_time_pct",
+       "engine_prefix_pool_time_pct", "moe_router_time_pct.decode",
+       "moe_dispatch_time_pct.decode", "moe_experts_time_pct.decode",
+       "mla_attend_time_pct", "mla_project_time_pct", "moe_shared_time_pct",
+       "moe_experts_touched_per_layer", "moe_decode_load_max_over_mean",
+       "kv_bytes_per_token", "moe_experts_decode_roofline_pct",
+       "mla_attend_roofline_pct"}
+
+
+def the_cell_reads_what_it_reads(bench):
+    """Holds the cell to what it reads, never to who else reads it: a
+    later cell joins an entry's list (`test_a_tenth_cell.py`)."""
     cell = spec.cell(bench, CELL)
     assert cell["chips"] == 1 and cell["traffic"] == TRAFFIC
     assert {m["name"] for m in cell["end_to_end"]} == {"serve_tokens_per_s",
                                                        "setup_s"}
     names = {m["name"] for m in cell["per_layer"]}
-    decode = {m["name"] for m in bench["per_layer"]
-              if m["name"].endswith(".decode")}
-    assert decode <= names
-    own = {m["name"] for m in bench["per_layer"]
-           if m.get("workloads") == [CELL]}
-    assert own == {
-        "engine_attn_time_pct", "engine_mlp_time_pct", "engine_head_time_pct",
-        "engine_prefix_pool_time_pct", "moe_router_time_pct.decode",
-        "moe_dispatch_time_pct.decode", "moe_experts_time_pct.decode",
-        "mla_attend_time_pct", "mla_project_time_pct", "moe_shared_time_pct",
-        "moe_experts_touched_per_layer", "moe_decode_load_max_over_mean",
-        "kv_bytes_per_token", "moe_experts_decode_roofline_pct",
-        "mla_attend_roofline_pct"}
+    assert DECODE <= names and OWN <= names
     for m in cell["per_layer"]:
         assert spec.metric_reader(m["name"]) is not None, m["name"]
-        if m["name"] in own:
+        if m["name"] in OWN:
+            assert CELL in m["workloads"]
             assert m["moves"] == "serve_tokens_per_s"
+
+
+def test_the_cell_reads_the_decode_metrics_and_its_own():
+    the_cell_reads_what_it_reads(spec.benchmark())
 
 
 def test_the_traffic_is_the_issues_letter_for_letter():

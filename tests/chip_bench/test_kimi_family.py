@@ -28,6 +28,7 @@ from generators import closed_loop_documents  # noqa: E402
 from harness import spec  # noqa: E402
 from metrics import _kda_scopes, _moe_scopes, _scopes  # noqa: E402
 from test_hot_path_metrics import DEVICE, _msg, _plane  # noqa: E402
+from test_kanana_family import DECODE  # noqa: E402
 
 CONFIG = spec.load_json(os.path.join(
     CHIP_DIR, "configs", "kimi-linear-48b-a3b-serve-1chip.json"))
@@ -49,12 +50,12 @@ TINY_MODEL = {**CONFIG["model"], **TINY, "num_experts": 4,
               "num_experts_per_token": 3, "router_outputs": 8,
               "first_expert": 2}
 OWN = {"kda_update_time_pct", "kda_chunk_time_pct", "kda_project_time_pct",
-       "kda_update_roofline_pct", "mla_attend_time_pct.longgen",
-       "mla_attend_roofline_pct.longgen", "moe_experts_time_pct.longgen",
-       "moe_experts_decode_roofline_pct.longgen", "moe_held_rows_pct",
-       "engine_attn_time_pct.longgen", "engine_mlp_time_pct.longgen",
-       "engine_head_time_pct.longgen", "engine_prefix_pool_time_pct.longgen",
-       "kv_bytes_per_token.longgen"}
+       "kda_update_roofline_pct", "mla_attend_time_pct",
+       "mla_attend_roofline_pct", "moe_experts_time_pct.decode",
+       "moe_experts_decode_roofline_pct", "moe_held_rows_pct",
+       "engine_attn_time_pct", "engine_mlp_time_pct",
+       "engine_head_time_pct", "engine_prefix_pool_time_pct",
+       "kv_bytes_per_token"}
 
 
 # ------------------------------------------------------------ configuration
@@ -165,40 +166,43 @@ def test_the_program_is_built_at_the_published_widths():
     assert tok.encode(tok.decode([1, 40958, 7])) == [1, 40958, 7]
 
 
-def test_the_cell_reads_the_decode_metrics_that_exist_for_it_and_its_own():
-    bench = spec.benchmark()
+def the_cell_reads_what_it_reads(bench):
+    """Holds the cell to what it reads, never to who else reads it: a
+    later cell joins an entry's list (`test_a_tenth_cell.py`)."""
     cell = spec.cell(bench, CELL)
     assert cell["chips"] == 1 and cell["traffic"] == TRAFFIC
     assert {m["name"] for m in cell["end_to_end"]} == {"serve_tokens_per_s",
                                                        "setup_s"}
     names = {m["name"] for m in cell["per_layer"]}
-    decode = {m["name"] for m in bench["per_layer"]
-              if m["name"].endswith(".decode")}
-    # the experts' three are Kanana's own entries (a test of PR 29's pins
-    # their lists): this cell reads the experts under `.longgen`
-    assert decode - names == {"moe_router_time_pct.decode",
-                              "moe_dispatch_time_pct.decode",
-                              "moe_experts_time_pct.decode"}
-    assert {"state_bytes_per_slot", "setup_engine_build_s"} <= names
-    assert "kv_bytes_per_token" not in names
+    assert DECODE <= names
+    # of the experts' three it reads the experts' own (Kanana's entry, one
+    # reading for both cells since PR 45); the router's and the dispatch's
+    # shares were never listed here
+    assert names.isdisjoint({"moe_router_time_pct.decode",
+                             "moe_dispatch_time_pct.decode"})
+    assert {"state_bytes_per_slot", "kv_bytes_per_token",
+            "setup_engine_build_s"} <= names
     # "contains", never "ends with": later PRs append too
     assert OWN <= names
     for m in bench["per_layer"]:
         if m["name"] in OWN:
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]
             assert m["moves"] == "serve_tokens_per_s"
             assert spec.metric_reader(m["name"]) is not None
     layers = {m["name"]: m["layer"] for m in bench["per_layer"]}
     assert layers["kda_update_roofline_pct"] == layers[
-        "moe_experts_decode_roofline_pct.longgen"] == layers[
-        "mla_attend_roofline_pct.longgen"] == layers[
+        "moe_experts_decode_roofline_pct"] == layers[
         "mla_attend_roofline_pct"]
     assert layers["kda_update_time_pct"] == layers["mla_attend_time_pct"]
-    assert layers["engine_prefix_pool_time_pct.longgen"] == layers[
+    assert layers["engine_prefix_pool_time_pct"] == layers[
         "prefix_reuse_pct.decode"]
     assert len(bench["per_layer"]) <= 128
     (workload,) = [w for w in bench["workloads"] if w["name"] == CELL]
     assert "4x their share" in workload["why"]   # attention sees more
+
+
+def test_the_cell_reads_the_decode_metrics_that_exist_for_it_and_its_own():
+    the_cell_reads_what_it_reads(spec.benchmark())
 
 
 def test_the_traffic_is_the_issues_letter_for_letter():
@@ -707,21 +711,21 @@ def served_record(tmp_path_factory):
 
 @pytest.mark.parametrize("name,want", [
     ("kda_update_time_pct", 20.0), ("kda_project_time_pct", 10.0),
-    ("kda_chunk_time_pct", 10.0), ("mla_attend_time_pct.longgen", 10.0),
-    ("moe_experts_time_pct.longgen", 10.0),
-    ("engine_attn_time_pct.longgen", 50.0),
-    ("engine_mlp_time_pct.longgen", 20.0),
-    ("engine_head_time_pct.longgen", 10.0),
-    ("engine_prefix_pool_time_pct.longgen", 10.0),
+    ("kda_chunk_time_pct", 10.0), ("mla_attend_time_pct", 10.0),
+    ("moe_experts_time_pct.decode", 10.0),
+    ("engine_attn_time_pct", 50.0),
+    ("engine_mlp_time_pct", 20.0),
+    ("engine_head_time_pct", 10.0),
+    ("engine_prefix_pool_time_pct", 10.0),
     ("kv_update_time_pct.decode", 10.0),
     ("state_bytes_per_slot", 15_712_256),
-    ("kv_bytes_per_token.longgen", 2304),
+    ("kv_bytes_per_token", 2304),
     ("moe_held_rows_pct", 100 * 22_938 / 81_920),
     # 8 ns of the 20 a step spends under kda_update; 4 of mla_attend's 10; 5
     # of moe_experts' 10
     ("kda_update_roofline_pct", 40.0),
-    ("mla_attend_roofline_pct.longgen", 40.0),
-    ("moe_experts_decode_roofline_pct.longgen", 50.0)])
+    ("mla_attend_roofline_pct", 40.0),
+    ("moe_experts_decode_roofline_pct", 50.0)])
 def test_every_new_entry_reads_its_number(served_record, name, want):
     assert spec.metric_reader(name).read(served_record) == pytest.approx(want)
 
@@ -795,7 +799,7 @@ def test_the_cell_runs_end_to_end_on_the_cpu_at_a_tiny_size():
     assert other["prefix_reuse_pct.decode"]["value"] > 80
     assert other["state_bytes_per_slot"]["value"] == 3 * (
         2 * 16 * 16 + 3 * 3 * 32) * 4
-    assert other["kv_bytes_per_token.longgen"]["value"] == 2 * 40 * 2
+    assert other["kv_bytes_per_token"]["value"] == 2 * 40 * 2
     # 64 of 256 held: a quarter of the pairs, under the seed's skew
     assert 10 < other["moe_held_rows_pct"]["value"] < 45
     assert "'ok': True" in out.stderr and "'tokens_checked'" in out.stderr
