@@ -16,8 +16,10 @@ the decode program); `check`, the reference check's engine: 4 of 128 slots
 with routing of their own, the others' rows all alike: 259 rows on 10
 experts, 126 on one. A call's least time is `moe_experts_decode_cost`
 (`benchmarks/chip/families/kanana.py`: each touched expert's three matrices
-and each held row in and out once at the HBM's peak), which the cell's
-`moe_experts_decode_roofline_pct.longgen` divides by the scope's time.
+and each held row in and out once at the HBM's peak), which
+`moe_experts_decode_roofline_pct` (one entry for every cell with routed
+experts since PR 45: Kanana's, Kimi's and Keye's) divides by the scope's
+time.
 
 Measured on a v5e (PR 42, 200 calls in one program; ms a call and the share
 of that cost; a tiling is rows a block : rows a product : columns of F):

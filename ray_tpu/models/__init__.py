@@ -7,11 +7,14 @@ for serving: latent attention over a latent cache, shared experts), brumby
 granite (Granite 4.0-H's layers for serving: Mamba-2 state a slot beside
 grouped-head keys and values a token, in one cache), kimi (Kimi Linear's
 layers for serving: delta-rule state a slot beside un-rotated latent rows a
-token, and a share of each layer's routed experts)."""
+token, and a share of each layer's routed experts), keye (Keye-VL-2.0's
+language model for serving: grouped-head attention over the 2,048 rows a
+learned indexer chooses of a slot's, three leaves a token, routed
+experts)."""
 
 from ray_tpu.models import gpt2
 
-__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi",
+__all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
            "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
@@ -48,7 +51,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "deepseek": ("deepseek", "DeepseekConfig"),
             "brumby": ("brumby", "BrumbyConfig"),
             "granite": ("granite", "GraniteConfig"),
-            "kimi": ("kimi", "KimiConfig")}
+            "kimi": ("kimi", "KimiConfig"),
+            "keye": ("keye", "KeyeConfig")}
 
 
 def serving_family(preset: str):
@@ -65,7 +69,8 @@ def serving_family(preset: str):
 
 
 def __getattr__(name):
-    if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi"):
+    if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
+                "keye"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

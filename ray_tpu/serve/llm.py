@@ -1095,9 +1095,12 @@ class LLMEngine:
         flight. `moe_expert_rows` and `moe_experts_touched` sum both
         programs (the rows the experts held here were given and the held
         experts that got one; `moe_expert_rows_all`, where the family
-        counts it, every valid lane's pairs, held here or not),
-        `step_counts` has each program's columns. Each is a uint32 that
-        wraps: take differences modulo 2**32."""
+        counts it, every valid lane's pairs, held here or not;
+        `positions_indexed` and `rows_selected`, where a learned indexer
+        chooses the rows attention reads: each valid lane's pos + 1 in its
+        step and its min(pos + 1, topk)), `step_counts` has each program's
+        columns. Each is a uint32 that wraps: take differences modulo
+        2**32."""
         if "counts" not in self.cache:
             return {}
 
@@ -1111,10 +1114,14 @@ class LLMEngine:
         names = self.model.COUNTS
         by_program = {program: dict(zip(names, row))
                       for program, row in zip(("decode", "chunk"), rows)}
+        sums = {"expert_rows": "moe_expert_rows",
+                "experts_touched": "moe_experts_touched",
+                "expert_rows_all": "moe_expert_rows_all",
+                "positions_indexed": "positions_indexed",
+                "rows_selected": "rows_selected"}
         return {"step_counts": by_program,
-                **{f"moe_{k}": sum(p[k] for p in by_program.values())
-                   % 2 ** 32 for k in ("expert_rows", "experts_touched",
-                                       "expert_rows_all") if k in names}}
+                **{stat: sum(p[k] for p in by_program.values()) % 2 ** 32
+                   for k, stat in sums.items() if k in names}}
 
     def engine_stats(self) -> dict:
         from ray_tpu.utils.platform import device_report

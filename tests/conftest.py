@@ -25,3 +25,27 @@ def devices8():
     if len(devs) < 8:
         pytest.skip("needs 8 virtual devices")
     return devs[:8]
+
+
+# `tests/chip_bench/test_a_tenth_cell.py` (PR 45) appends a cell to a copy
+# of BENCHMARK.json in memory and, in one test, first holds the copy to ten
+# cells: the file stood at nine. PR 46 appends the tenth cell to the file
+# itself, so the copy holds eleven and that line fails, the test's other
+# lines never run. The file is the benchmark's (`paths`), which a
+# `model_config` PR may add to and not edit, so the line stands: the test is
+# marked here as expected to fail on it, and
+# `tests/chip_bench/test_keye_family.py` holds the same test with the count
+# read from the file (`test_a_later_cell_still_reads_what_the_cell_it_is_like
+# _reads`). A `benchmark` PR should change `== 10` to the file's count plus
+# one and take this hook out (PERF.md section 7).
+_PINNED_TO_NINE_CELLS = ("test_a_tenth_cell.py::test_the_tenth_cell_reads_"
+                         "what_the_cell_it_is_like_reads_and_its_own")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(_PINNED_TO_NINE_CELLS):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins BENCHMARK.json to nine cells (its line 60); "
+                       "the benchmark's file, not this PR's to edit",
+                strict=False))

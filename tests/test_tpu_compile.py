@@ -544,6 +544,60 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         "kda_layer_copies": [], "expert_matrix_copies": []}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_keye_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-keye-longdoc`'s two programs, as its configuration
+    file has them (Keye-VL-2.0-30B-A3B's language model at depth 6 of 48,
+    all 128 experts a layer, the whole vocabulary, 32 slots of 13,312
+    positions of three leaves a token, chunks of 128): the bytes the file
+    gives, with `==`, and room for the pool of all three leaves beside the
+    larger; one `expert_mlp` kernel in the layers' loop and the chunk
+    program's second for the further lanes (the indexer, the choice, the
+    gather and attention over the chosen rows are XLA's); no instruction
+    copies a cache leaf (`k`, `v` or `ik`, whole or a layer of it: the
+    layers' loop carries the three and writes rows in place); none copies
+    an expert matrix out of the stack, a layer's [128, d, F] or the whole
+    [768, d, F] (ROADMAP S12a; Kanana's form would); and the decode program
+    writes no float32 `[32, 32, 13312]` array of every slot's scores over
+    every position: attention reads the 2,048 chosen rows."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_keye_for_v5e import (CONFIG, compile_step,
+                                      kv_bytes_per_token, made_of,
+                                      pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    chunk = config["deployment"]["prefill_chunk_size"]
+    if program == "decode":
+        assert sized["total"] == memory["decode_step_bytes"]
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 27
+    else:
+        assert sized["total"] == memory[
+            "prefill_chunk_bytes_by_chunk_size"][str(chunk)]
+        assert sized["temp"] < 2 ** 28
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 32 * chunk * 4)    # the chunk's tokens
+    assert sized["arguments"] >= 0.75 * HBM_BYTES
+    assert kv_bytes_per_token(config) == memory["kv_bytes_per_token"] \
+        == 6 * (2 * 4 * 128 + 64) * 2 == 13_056
+    assert pool_bytes(config) == memory["prefix_pool_bytes"] \
+        == 416 * 128 * 13_056
+    assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    hlo = compiled.as_text()
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in _mosaic_calls(hlo)) == (1 if program == "decode"
+                                                else 2)
+    assert made_of(hlo, config) == {
+        "kernels": 1 if program == "decode" else 2, "leaf_copies": {},
+        "expert_matrix_copies": [], "dense_scores": []}
+
+
 @pytest.mark.parametrize("rows,F,tiles", [
     (1024, 1024, None), (1016, 1024, None), (1024, 1024, (128, 32, 256)),
     (1024, 1280, None)],
