@@ -34,8 +34,13 @@ snapshots. A hit is the longest boundary that has both; rows without a
 snapshot at their end are no hit (`rows_without_snapshot_tokens` counts the
 tokens prefilled again for it); a block is not evicted while a pooled
 snapshot stands on it, and a snapshot that makes room goes before its blocks
-do. A prefix's blocks move between pool and slot in one program a leaf,
-whatever their number.
+do.
+
+Whatever the pool's kind, a prefix's blocks of rows move between pool and
+slot in one program call a leaf, whatever their number (a loop on the device
+over the blocks a small int32 `plan` lists), and a snapshot in one call a
+state leaf. A store or an admission makes its plan once, puts it on the
+device once and hands it to every leaf's call.
 """
 
 from __future__ import annotations
@@ -64,6 +69,11 @@ def chain_hashes(ids: List[int], block_size: int) -> List[Tuple[bytes, int]]:
         h = _chain_hash(h, tuple(ids[i:i + block_size]))
         out.append((h, i + block_size))
     return out
+
+
+def _major_to_minor(array) -> tuple:
+    """The order in which the device holds `array`'s axes."""
+    return array.format.layout.major_to_minor
 
 
 class PagedKVCache:
@@ -134,23 +144,21 @@ class PagedKVCache:
         self.both = self.snapshots and bool(token_axis)
         self.pools: Dict[str, "jax.Array"] = {}
         self._copiers: Dict[str, tuple] = {}
-        by_geometry: dict = {}
+        # rows leaves whose pool the device lays out otherwise than the
+        # cache's (`_laid_apart`: known at the first store or admission)
+        self._apart: Optional[List[str]] = None
+        # leaf -> (one block of one slot, axis, dtype); geometry -> programs
+        self._geometry: Dict[str, tuple] = {}
+        self._programs: Dict[tuple, tuple] = {}
         for name, leaf in leaves.items():
             axis = token_axis.get(name)
             block = list(leaf.shape)
             block[1] = 1
             if axis is not None:
                 block[axis] = block_size
-            geometry = (tuple(block), axis, jnp.dtype(leaf.dtype).name)
-            if geometry not in by_geometry:
-                by_geometry[geometry] = (
-                    self._copy_programs_many(tuple(block), axis)
-                    if self.both and axis is not None
-                    else self._copy_programs(tuple(block), axis))
-            if self.both and axis is not None:
-                # the most blocks a prefix has: a slot's rows
-                self._most_blocks = leaf.shape[axis] // block_size
-            self._copiers[name] = by_geometry[geometry]
+            self._geometry[name] = (tuple(block), axis,
+                                    jnp.dtype(leaf.dtype).name)
+            self._copiers[name] = self._programs_of(name)
             block[1] = num_snapshots if self.both and axis is None \
                 else num_blocks
             self.pools[name] = jnp.zeros(tuple(block), leaf.dtype)
@@ -174,64 +182,88 @@ class PagedKVCache:
         self.blocks_evicted = 0
         self.snapshots_evicted = 0
         self.rows_without_snapshot_tokens = 0
+        # calls of the programs that move blocks of rows (one a leaf a
+        # store or an admission) and the blocks they moved
+        self.copy_in_calls = self.copy_in_blocks = 0
+        self.copy_out_calls = self.copy_out_blocks = 0
 
-    def _copy_programs(self, block: tuple, axis: Optional[int]) -> tuple:
+    def _programs_of(self, name: str, loop: bool = True) -> tuple:
+        """Leaf `name`'s (copy_out, copy_in): leaves of one geometry share a
+        pair."""
+        key = self._geometry[name] + (loop,)
+        if key not in self._programs:
+            self._programs[key] = self._copy_programs(*key[:2], loop)
+        return self._programs[key]
+
+    def _copy_programs(self, block: tuple, axis: Optional[int],
+                       loop: bool = True) -> tuple:
         """(copy_out, copy_in) for leaves whose one block of one slot is
         `block` ([L, 1, ..., block_size at `axis`, ...]; with no `axis` a
-        slot's whole leaf, and `t0` is not looked at)."""
+        slot's whole leaf). Each takes what it writes into (donated), what
+        it reads and a `plan` (`_plan` makes it). With an `axis` a loop over
+        the plan's blocks of rows writes each into the carry in place
+        (without `loop`, the plan's first block and no other); with none the
+        plan's one entry moves."""
         jax = self.jax
-
-        def at(second, t0):
-            return tuple(second if i == 1 else t0 if i == axis else 0
-                         for i in range(len(block)))
-
-        def _copy_out(pool, cache, slot, t0, blk):
-            with jax.named_scope("prefix_pool"):
-                data = jax.lax.dynamic_slice(cache, at(slot, t0), block)
-                return jax.lax.dynamic_update_slice(pool, data, at(blk, 0))
-
-        def _copy_in(cache, pool, slot, t0, blk):
-            with jax.named_scope("prefix_pool"):
-                data = jax.lax.dynamic_slice(pool, at(blk, 0), block)
-                return jax.lax.dynamic_update_slice(cache, data,
-                                                    at(slot, t0))
-
-        return (jax.jit(_copy_out, donate_argnums=(0,)),
-                jax.jit(_copy_in, donate_argnums=(0,)))
-
-    def _copy_programs_many(self, block: tuple, axis: int) -> tuple:
-        """(copy_out, copy_in) for a prefix's blocks of rows in one program:
-        `blocks` int32 pool block ids, `at` int32 which block of the slot
-        each is (position `at * block_size`), the first `n` of them valid
-        (`_padded` makes them, each as long as a slot has blocks)."""
-        jax = self.jax
-        size = block[axis]
 
         def where(second, t0):
             return tuple(second if i == 1 else t0 if i == axis else 0
                          for i in range(len(block)))
 
-        def _copy_out(pool, cache, slot, blocks, at, n):
-            def one(i, pool):
-                data = jax.lax.dynamic_slice(
-                    cache, where(slot, at[i] * size), block)
-                return jax.lax.dynamic_update_slice(pool, data,
-                                                    where(blocks[i], 0))
+        def each(plan, move, carry):
+            """`move(carry, slot, pool block, position in the slot)` for the
+            plan's entry, or for each of its blocks of rows in turn."""
+            slot = plan[0]
+            if axis is None:
+                return move(carry, slot, plan[1], 0)
+            most = (plan.shape[0] - 3) // 2
+
+            def one(i, carry):
+                return move(carry, slot, plan[3 + i],
+                            plan[3 + most + i] * block[axis])
+
+            return jax.lax.fori_loop(0, plan[2], one, carry) if loop \
+                else one(0, carry)
+
+        def _copy_out(pool, cache, plan):
+            def move(pool, slot, blk, t0):
+                data = jax.lax.dynamic_slice(cache, where(slot, t0), block)
+                return jax.lax.dynamic_update_slice(pool, data, where(blk, 0))
 
             with jax.named_scope("prefix_pool"):
-                return jax.lax.fori_loop(0, n, one, pool)
+                return each(plan, move, pool)
 
-        def _copy_in(cache, pool, slot, blocks, at, n):
-            def one(i, cache):
-                data = jax.lax.dynamic_slice(pool, where(blocks[i], 0), block)
-                return jax.lax.dynamic_update_slice(
-                    cache, data, where(slot, at[i] * size))
+        def _copy_in(cache, pool, plan):
+            def move(cache, slot, blk, t0):
+                data = jax.lax.dynamic_slice(pool, where(blk, 0), block)
+                return jax.lax.dynamic_update_slice(cache, data,
+                                                    where(slot, t0))
 
             with jax.named_scope("prefix_pool"):
-                return jax.lax.fori_loop(0, n, one, cache)
+                return each(plan, move, cache)
 
         return (jax.jit(_copy_out, donate_argnums=(0,)),
                 jax.jit(_copy_in, donate_argnums=(0,)))
+
+    def _plan(self, cache, slot: int, entry: int = 0, rows=()):
+        """What one store or one admission moves, as every leaf's program
+        takes it, on the device once: int32 [slot, `entry` (the one entry of
+        the leaves without a token axis), how many blocks of rows, each's
+        pool block id, each's place among the slot's blocks], the two lists
+        as long as a slot of `cache` has blocks. `rows`: (block id, place)
+        pairs."""
+        import numpy as np
+
+        most = 0
+        if self._rows:
+            name = self._rows[0]
+            most = cache[name].shape[self._geometry[name][1]] \
+                // self.block_size
+        plan = np.zeros((3 + 2 * most,), np.int32)
+        plan[:3] = slot, entry, len(rows)
+        if rows:
+            plan[3:].reshape(2, most)[:, :len(rows)] = np.asarray(rows).T
+        return self.jnp.asarray(plan)
 
     # GPT-2's two pools by name: the transfer blobs below are theirs
     @property
@@ -367,35 +399,63 @@ class PagedKVCache:
     # -------------------------------------------------------------- store
     def store_prefix(self, ids: List[int], cache, slot: int) -> int:
         """Copy every full block of `ids` from `cache`'s dense slot lane
-        into the pool (skipping chains already present). Returns the
-        number of NEW blocks stored. `cache` is the engine's dict of leaves.
+        into the pool (skipping chains already present): the new blocks in
+        one program call a leaf. Returns the number of NEW blocks stored.
+        `cache` is the engine's dict of leaves.
 
         A pool of snapshots keeps the slot's state as it stands, under the
         hash of `ids`' last whole block: the caller calls when the slot has
         taken exactly those blocks and no token more."""
-        B = self.block_size
-        chain = chain_hashes(ids, B)
+        chain = chain_hashes(ids, self.block_size)
         if self.both:
             return self._store_entry(chain, cache, slot)
-        # (hash, where in the slot): a block of rows a hash, or the slot's
-        # state once, under the last hash
-        entries = (([(chain[-1][0], 0)] if chain else []) if self.snapshots
-                   else [(h, n - B) for h, n in chain])
-        stored = 0
-        for h, t0 in entries:
+        # a block of rows a hash, or the slot's state once, under the last
+        hashes = [h for h, _ in chain]
+        if self.snapshots:
+            hashes = hashes[-1:]
+        new = []
+        for i, h in enumerate(hashes):
             if h in self._table:
                 self._table.move_to_end(h)
                 continue
             blk = self._alloc()
             if blk is None:
                 break
-            for name, (copy_out, _) in self._copiers.items():
-                self.pools[name] = copy_out(self.pools[name], cache[name],
-                                            slot, t0, blk)
             self._table[h] = blk
             self._hash_of_block[blk] = h
-            stored += 1
-        return stored
+            new.append((blk, i))
+        if new and self.snapshots:
+            self._to_pool(cache, slot, entry=new[0][0])
+        elif new:
+            self._to_pool(cache, slot, rows=new)
+        return len(new)
+
+    def _to_pool(self, cache, slot: int, rows=(),
+                 entry: Optional[int] = None) -> None:
+        """The slot's blocks `rows` ((pool block, place in the slot) pairs)
+        and, with an `entry`, its state as that entry."""
+        for names, plan in self._calls(cache, slot, rows, entry or 0,
+                                       entry is not None):
+            for name in names:
+                self.pools[name] = self._copiers[name][0](
+                    self.pools[name], cache[name], plan)
+                self.copy_out_calls += name in self._rows
+        self.copy_out_blocks += len(self._rows) * len(rows)
+
+    def _calls(self, cache, slot: int, rows, entry: int, states: bool):
+        """The program calls of one store or one admission, as (leaves,
+        their plan): every block of `rows` in one call a leaf behind one
+        plan, and the state leaves' `entry` with them. A leaf laid apart
+        (`_laid_apart`) takes a block a call, each plan made when its calls
+        are, so that the device starts on the first block while the host
+        makes the next."""
+        apart = self._laid_apart(cache)
+        together = [n for n in self._rows if rows and n not in apart] + (
+            self._states if states else [])
+        if together:
+            yield together, self._plan(cache, slot, entry, rows)
+        for pair in rows if apart else ():
+            yield apart, self._plan(cache, slot, rows=[pair])
 
     def _store_entry(self, chain, cache, slot: int) -> int:
         """A pool of both kinds keeps the slot's rows up to `chain`'s last
@@ -423,66 +483,69 @@ class PagedKVCache:
             # held against this call's own evictions
             self._pins[blk] = self._pins.get(blk, 0) + 1
             held.append(blk)
-        if new:
-            blocks, at = self._padded(new)
-            for name in self._rows:
-                self.pools[name] = self._copiers[name][0](
-                    self.pools[name], cache[name], slot, blocks, at, len(new))
         snapshot = None
         if len(held) == len(chain):
             snapshot = (self._free_snapshots.pop() if self._free_snapshots
                         else self._drop_snapshot())
+        if new or snapshot is not None:
+            self._to_pool(cache, slot, new, snapshot)
         if snapshot is None:        # rows without a snapshot: no entry
             for blk in held:
                 self._pins[blk] -= 1
             return 0
-        for name in self._states:
-            self.pools[name] = self._copiers[name][0](
-                self.pools[name], cache[name], slot, 0, snapshot)
         self._snapshot_at[chain[-1][0]] = snapshot
         self._stands_on[chain[-1][0]] = held
         return 1
 
-    def _padded(self, pairs) -> tuple:
-        """(block ids, which block of the slot) as the many-block programs
-        take them: int32 [the most blocks a slot has], the rest zeros."""
-        import numpy as np
-
-        out = np.zeros((2, self._most_blocks), np.int32)
-        out[:, :len(pairs)] = np.asarray(pairs, np.int32).T
-        return out[0], out[1]
-
     # --------------------------------------------------------------- load
     def copy_into_slot(self, cache, slot: int, blocks: List[int]):
-        """Materialize matched pool blocks into cache slot lane starting
-        at position 0 (a snapshot: its one entry over the slot's whole
-        state); returns the updated cache dict."""
+        """Materialize what `match_prefix` found into cache slot lane: the
+        blocks of rows from position 0 on, in one program call a leaf
+        whatever their number, and the entry's snapshot over the slot's
+        whole state (a pool of snapshots alone: `blocks` is that one
+        entry); returns the updated cache dict."""
         cache = dict(cache)
+        rows, entry = [(b, i) for i, b in enumerate(blocks)], 0
         if self.both:
-            # the entry whose last block this is: its rows, then its state
-            ids, at = self._padded([(b, i) for i, b in enumerate(blocks)])
-            for name in self._rows:
+            # the entry whose last block this is
+            entry = self._snapshot_at[self._hash_of_block[blocks[-1]]]
+        elif self.snapshots:
+            rows, entry = [], blocks[0]
+        for names, plan in self._calls(cache, slot, rows, entry, True):
+            for name in names:
                 cache[name] = self._copiers[name][1](
-                    cache[name], self.pools[name], slot, ids, at, len(blocks))
-            snapshot = self._snapshot_at[self._hash_of_block[blocks[-1]]]
-            for name in self._states:
-                cache[name] = self._copiers[name][1](
-                    cache[name], self.pools[name], slot, 0, snapshot)
-            return cache
-        t0 = 0
-        for blk in blocks:
-            for name, (_, copy_in) in self._copiers.items():
-                cache[name] = copy_in(cache[name], self.pools[name], slot,
-                                      t0, blk)
-            t0 += self.block_size
+                    cache[name], self.pools[name], plan)
+                self.copy_in_calls += name in self._rows
+        self.copy_in_blocks += len(self._rows) * len(rows)
         return cache
+
+    def _laid_apart(self, cache) -> List[str]:
+        """The rows leaves whose pool the device lays out otherwise than the
+        leaf of the cache (a TPU puts the blocks of GPT-2's pool by head
+        along the lanes, 16 x 64 being less than a tile: ROADMAP S6). A
+        loop that reads such a pool is compiled with a copy of the whole
+        pool in the cache's layout, eight times its bytes, and one that
+        writes it gains nothing on the device, so these leaves move a block
+        a call, by the loop's body alone. Asked of the arrays once, at the
+        first store or admission."""
+        if self._apart is None:
+            self._apart = [n for n in self._rows
+                           if _major_to_minor(self.pools[n])
+                           != _major_to_minor(cache[n])]
+            for name in self._apart:
+                self._copiers[name] = self._programs_of(name, loop=False)
+        return self._apart
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
         out = {"blocks_used": self.num_blocks - len(self._free),
                "prefix_hits": self.hits,
                "tokens_reused": self.tokens_reused,
-               "blocks_evicted": self.blocks_evicted}
+               "blocks_evicted": self.blocks_evicted,
+               "copy_in_calls": self.copy_in_calls,
+               "copy_in_blocks": self.copy_in_blocks,
+               "copy_out_calls": self.copy_out_calls,
+               "copy_out_blocks": self.copy_out_blocks}
         if self.both:
             out.update(
                 snapshots_used=self.num_snapshots - len(self._free_snapshots),

@@ -640,9 +640,13 @@ def test_gpt2s_pool_is_the_two_arrays_it_was():
     assert kv.pool_k.shape == kv.pool_v.shape == (2, 5, 3, 8, 4)
     assert list(kv.pools) == ["k", "v"]
     assert kv._copiers["k"] is kv._copiers["v"]        # one pair of programs
-    text = kv._copy_out.lower(kv.pool_k, jnp.zeros((2, 2, 3, 32, 4)), 0, 0,
-                              1).as_text()
-    assert text.count("dynamic_slice") == 1
+    leaf = jnp.zeros((2, 2, 3, 32, 4))
+    text = kv._copy_out.lower(kv.pool_k, leaf,
+                              kv._plan({"k": leaf}, 0, rows=[(1, 0)])
+                              ).as_text()
+    # the loop's one window of the leaf into the pool; the other slices
+    # read the plan
+    assert text.count("sizes = [2, 1, 3, 8, 4]") == 1
     assert text.count("dynamic_update_slice") == 1
 
 

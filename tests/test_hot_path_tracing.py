@@ -307,8 +307,9 @@ def test_the_prefix_pools_copies_are_scoped():
     kv = PagedKVCache(n_layer=2, n_head=2, head_dim=4, num_blocks=8,
                       block_size=4)
     cache = jnp.zeros((2, 2, 2, 32, 4), jnp.float32)
-    out = kv._copy_out.lower(kv.pool_k, cache, 0, 0, 1)
-    back = kv._copy_in.lower(cache, kv.pool_k, 0, 0, 1)
+    plan = kv._plan({"k": cache}, slot=0, rows=[(1, 0), (2, 3)])
+    out = kv._copy_out.lower(kv.pool_k, cache, plan)
+    back = kv._copy_in.lower(cache, kv.pool_k, plan)
     assert _scopes_in(out) == _scopes_in(back) == {"prefix_pool"}
 
 

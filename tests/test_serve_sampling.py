@@ -203,8 +203,9 @@ def _arguments(eng, program):
         return eng.cache, np.int32(1)
     name = next(iter(eng.kv.pools))
     pool, leaf = eng.kv.pools[name], eng.cache[name]
-    at = (np.int32(1), np.int32(8), np.int32(2))
-    return (pool, leaf) + at if program == "_copy_out" else (leaf, pool) + at
+    plan = eng.kv._plan(eng.cache, slot=1, entry=2, rows=[(2, 1)])
+    return (pool, leaf, plan) if program == "_copy_out" else (leaf, pool,
+                                                              plan)
 
 
 def _lowered(eng, program):

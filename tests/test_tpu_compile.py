@@ -598,6 +598,97 @@ def test_keye_serving_programs_compile_at_the_configurations_sizes(
         "expert_matrix_copies": [], "dense_scores": []}
 
 
+def _pools_programs(config: dict, chips):
+    """(leaf, its shape in the cell's cache, the pool's, the plan's, the
+    pool's (copy_out, copy_in) that loop over a plan's blocks) for every
+    rows leaf of the cell whose configuration file this is, all on the
+    described chip."""
+    from ray_tpu.models import serving_family
+    from ray_tpu.serve.kv_cache import PagedKVCache
+
+    _, spec = _chip_bench()
+    d = config["deployment"]
+    _, model, _ = serving_family(d["preset"])
+    family = spec.family(config["family"])
+    # GPT-2's family takes the file's `model` block, the later ones the file
+    cfg = family.program_config(
+        config["model"] if config["family"] == "gpt2" else config)
+    cache = jax.eval_shape(lambda: model.init_cache(
+        cfg, d["max_batch"], d["max_seq_len"]))
+    kv = PagedKVCache(1, 1, 1, num_blocks=1, block_size=1)  # any: a maker
+    one = SingleDeviceSharding(chips[0])
+    for name, axis in model.CACHE_TOKEN_AXIS.items():
+        leaf = cache[name]
+        block = list(leaf.shape)
+        block[1], block[axis] = 1, d["kv_block_size"]
+        pool = [d["kv_blocks"] if i == 1 else n for i, n in enumerate(block)]
+        most = leaf.shape[axis] // d["kv_block_size"]
+        yield (name,
+               jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=one),
+               jax.ShapeDtypeStruct(tuple(pool), leaf.dtype, sharding=one),
+               jax.ShapeDtypeStruct((3 + 2 * most,), jnp.int32, sharding=one),
+               lambda loop, block=tuple(block), axis=axis:
+               kv._copy_programs(block, axis, loop))
+
+
+@pytest.mark.parametrize("config_name,apart", [
+    ("gpt2-xl-serve-1chip", True),
+    ("kanana-2-30b-a3b-serve-1chip", False),
+    ("keye-vl-2.0-30b-a3b-serve-1chip", False)])
+def test_the_prefix_pools_programs_move_blocks_in_place(chips, config_name,
+                                                        apart):
+    """`serve/kv_cache.py`'s two programs a rows leaf, at the three
+    rows-only pools' and caches' sizes from their configuration files: the
+    loop over a plan's blocks writes each into the donated carry in place (no
+    instruction writes a whole leaf or a whole pool but a window into the
+    carry), its temporaries a few blocks' bytes and not a leaf's.
+
+    GPT-2's pool by head is the exception the pool reads off its arrays
+    (`_laid_apart`): the chip lays `[48, 128, 25, 16, 64]` out with the 128
+    blocks along the lanes (16 x 64 is less than a tile) and the cache with
+    its positions there, the loop that reads such a pool is compiled with a
+    copy of the whole pool in the cache's layout (2.5 GB, eight times the
+    pool's bytes: it would not fit beside the cell's 15.5 GB), and so its
+    blocks move a call each, by the loop's body alone, as PR 46's did (a
+    block padded to 128 lanes: 315 MB of temporaries, as then)."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    with open(os.path.join(chip_dir, "configs", config_name + ".json")) as f:
+        config = json.load(f)
+    in_place = {"parameter", "get-tuple-element", "tuple", "while",
+                "dynamic-update-slice", "bitcast"}
+    for name, leaf, pool, plan, programs in _pools_programs(config, chips):
+        dims = "|".join(",".join(map(str, a.shape)) for a in (leaf, pool))
+        a_block = math.prod(pool.shape) // pool.shape[1] * 2
+        copy_out, copy_in = programs(True)
+        out = copy_out.lower(pool, leaf, plan).compile()
+        back = copy_in.lower(leaf, pool, plan).compile()
+        cache_order, pool_order = (
+            f.layout.major_to_minor for f in back.input_formats[0][:2])
+        assert (cache_order != pool_order) == apart, (name, cache_order,
+                                                      pool_order)
+        if apart:
+            assert back.memory_analysis().temp_size_in_bytes > \
+                math.prod(leaf.shape) * 2, name     # what the loop would cost
+            copy_out, copy_in = programs(False)
+            out = copy_out.lower(pool, leaf, plan).compile()
+            back = copy_in.lower(leaf, pool, plan).compile()
+        for compiled, whole in ((out, pool), (back, leaf)):
+            hlo = compiled.as_text()
+            written = _written_arrays(hlo, dims)
+            # the one window into the carry, alone or fused with the slice
+            # it writes (a fusion that ends in a window writes in place)
+            assert {op for op, _ in written} <= in_place | {"fusion"}, (
+                name, written)
+            assert sum(op in ("dynamic-update-slice", "fusion")
+                       for op, _ in written) == 1, (name, written)
+            m = compiled.memory_analysis()
+            assert m.alias_size_in_bytes == math.prod(whole.shape) * 2
+            assert m.temp_size_in_bytes < (
+                130 if apart else 4) * a_block, (name, m)
+
+
 @pytest.mark.parametrize("rows,F,tiles", [
     (1024, 1024, None), (1016, 1024, None), (1024, 1024, (128, 32, 256)),
     (1024, 1280, None)],
