@@ -13,40 +13,40 @@ chiprun_out/KANANA_CHUNK_LANES.{json,md} (a run's output, never committed).
 
 Forms:
   decode   `deepseek.decode_step`: what every slot's first lane costs
-  parent   `prefill_chunk` of a checkout of the parent commit (`--parent`;
-           left out when the directory is not there): all 32 x 128 lanes
-           through every layer, whoever prefills
   reused   `deepseek.prefill_chunk`: the decode program's work on every
            slot's first lane, the further lanes only of the slots that
            prefill, a slot at a time, with the layer's weights as the
-           layers' loop already holds them
+           layers' scan holds them and the routed experts' matrices where
+           the stack of all layers' holds them
   sliced   the same but for one thing: a slot's turn of the loop slices
-           the layer's weights out of the stack itself (granite and kimi do
-           so: there slicing once made the compiler copy every matrix;
-           `lm.each_slot` has the rule)
+           the layer's weights (all but the routed experts') out of the
+           stack itself (granite and kimi do so: there slicing once made
+           the compiler copy every matrix; `lm.each_slot` has the rule)
+  parent_decode, parent   the two programs of a checkout of the parent
+           commit (`--parent`; left out when the directory is not there)
 
-Measured (TPU v5 lite, one chip, PR 39; ms a step, calls dispatched back to
-back, every slot at position 2,560):
+Measured (TPU v5 lite, one chip, PR 48, the parent PR 47's tree; ms a step,
+calls dispatched back to back, every slot at position 2,560):
 
     slots that prefill      0      1      2      4      8      32
-    decode              41.15
-    parent                     309.82                           309.80
-    reused              43.53  59.40  75.26 106.86 169.78  548.56
-    sliced              41.96  83.70 125.41 208.77 375.20 1374.63
+    decode              13.94
+    reused              15.67  31.61  47.52  79.33 142.63  523.66
+    sliced              16.18  32.03  47.86  79.51 142.40  521.25
+    parent_decode       39.64
+    parent              41.49  57.29  73.02 104.47 167.11  544.17
 
-`reused` is kept: a slot that prefills costs 15.8 ms with the weights the
-layers' loop already copied out of the stack (ROADMAP S12a) and 41.7 ms when
-its branch slices them again (the three copies a layer, once more for every
-slot). A step in which no slot prefills costs the decode program's time and
-2.4 ms of predicates. The all-lanes form's 310 ms is passed at 17 slots
-prefilling at once: the engine's default budget (`max_num_batched_tokens`
-B + C) hands out at most two; a budget that lets 17 or more slots prefill
-in one step (about 17 C = 2,176 tokens here) buys steps that cost more
-than they did before PR 39, up to 549 ms where all 32 do.
-
-Since PR 43 the loop over the slots is `models/lm.each_slot`: as many turns
-as slots prefill, where the loop this table timed turned over all 32 with a
-branch each.
+A step in which no slot prefills costs the decode program's time and 1.7 ms
+of predicates, a slot that prefills 15.9 ms, as on the parent: what PR 48
+took out is the 25.7 ms a step that copied each expert layer's three
+matrices out of the stack, once a step whoever prefilled. `reused` and
+`sliced` no longer differ (until PR 48 a turn that sliced the layer again
+copied its three expert matrices again, 41.7 ms a slot for 15.8, PR 39):
+the scan's form stays because it is the shorter. The all-lanes form of PR 38
+(310 ms a step whoever prefilled) is passed where 19 slots prefill at once:
+the engine's default budget (`max_num_batched_tokens` B + C) hands out at
+most two; a budget that lets 19 or more slots prefill in one step (about
+19 C = 2,432 tokens here) buys steps that cost more than all lanes at once
+did, up to 524 ms where all 32 do.
 """
 
 from __future__ import annotations
@@ -75,23 +75,29 @@ POSITION = 2560          # the cell's slots decode at 2,100-3,650
 
 def further_lanes_sliced(stacks: dict):
     """`deepseek._further_lanes` but for where a slot's weights come from:
-    layer l of `stacks` (the engine's `dense` and `blocks`), sliced inside
-    the loop's body."""
+    layer l of `stacks` (the engine's `dense` and `blocks`, the latter
+    without the routed experts' matrices, which are `stack`'s in both
+    forms), sliced inside the loop's body."""
     n_dense = jax.tree.leaves(stacks["dense"])[0].shape[0]
+    moe = stacks["blocks"]["moe"]
+    blocks = {**stacks["blocks"],
+              "moe": {k: moe[k] for k in moe if k not in deepseek.ROUTED}}
 
-    def further_lanes(rest, bp, cfg, lat, kr, given, l, pos, ok, prefilling):
+    def further_lanes(rest, bp, stack, cfg, lat, kr, given, l, pos, ok,
+                      prefilling):
         M = rest.shape[1]
-        stack, at = ((stacks["blocks"], l - n_dense) if "moe" in bp
-                     else (stacks["dense"], l))
+        layers, at = ((blocks, l - n_dense) if "moe" in bp
+                      else (stacks["dense"], l))
 
         def slot(b, carry):
             rest, lat, kr, given = carry
-            own = lm.layer_weights(stack, at)
+            own = lm.layer_weights(layers, at)
             xb, okb, first = lm.slot_lanes(b, rest, ok, pos)
             xb, lat, kr = deepseek._attention(
                 xb, own, cfg, lat, kr, l, first,
                 first[:, None] + jnp.arange(M), okb, slot=b)
-            xb, given = deepseek._mlp(xb, own, cfg, given, okb)
+            xb, given = deepseek._mlp(xb, own, stack, l - n_dense, cfg,
+                                      given, okb)
             return lm.put_lanes(rest, xb, b), lat, kr, given
 
         return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
@@ -99,8 +105,13 @@ def further_lanes_sliced(stacks: dict):
     return further_lanes
 
 
-def forms(cfg, parent: str) -> dict:
-    """name -> the chunk program, jitted as the engine jits it."""
+def forms(cfg, parent: str) -> tuple:
+    """(name -> the decode program, name -> the chunk program), jitted as
+    the engine jits them."""
+    def decode(module):
+        return jax.jit(lambda p, c, t, pos, a: module.decode_step(
+            p, c, t, pos, a, cfg), donate_argnums=(1,))
+
     def chunk(module):
         return jax.jit(
             lambda p, c, t, pos0, n, a: module.prefill_chunk(
@@ -111,16 +122,18 @@ def forms(cfg, parent: str) -> dict:
                                further_lanes_sliced(p)):
             return deepseek.prefill_chunk(p, c, t, pos0, n, a, cfg)
 
-    out = {"reused": chunk(deepseek),
-           "sliced": jax.jit(sliced, donate_argnums=(1,))}
+    decodes = {"decode": decode(deepseek)}
+    chunks = {"reused": chunk(deepseek),
+              "sliced": jax.jit(sliced, donate_argnums=(1,))}
     path = os.path.join(parent, "ray_tpu", "models", "deepseek.py")
     if os.path.isfile(path):
         spec = importlib.util.spec_from_file_location("parent_deepseek", path)
         module = importlib.util.module_from_spec(spec)
         sys.modules[spec.name] = module       # its dataclass looks itself up
         spec.loader.exec_module(module)
-        out["parent"] = chunk(module)
-    return out
+        decodes["parent_decode"] = decode(module)
+        chunks["parent"] = chunk(module)
+    return decodes, chunks
 
 
 def timed_ms(step, cache, args, seconds: float = 2.0):
@@ -193,16 +206,14 @@ def main() -> None:
                                   "at": POSITION, "layers": cfg.n_layer},
                        "rows": rows}, f, indent=1)
 
-    decode = jax.jit(lambda p, c, t, pos, a: deepseek.decode_step(
-        p, c, t, pos, a, cfg), donate_argnums=(1,))
-    record("decode", 0, decode, (tokens[:, 0], pos0, on))
     wanted = [f for f in args.forms.split(",") if f]
-    for form, fn in forms(cfg, args.parent).items():
+    decodes, chunks = forms(cfg, args.parent)
+    for form, fn in decodes.items():
+        record(form, 0, fn, (tokens[:, 0], pos0, on))
+    for form, fn in chunks.items():
         if wanted and form not in wanted:
             continue
         for n in (int(s) for s in args.slots.split(",")):
-            if form == "parent" and n not in (1, B):
-                continue                  # every lane, whoever prefills
             length = jnp.where(jnp.arange(B) < n, C, 1).astype(jnp.int32)
             record(form, n, fn, (tokens, pos0, length, on))
     lines = ["| form | slots that prefill | ms a step |", "| --- | --- | --- |"]

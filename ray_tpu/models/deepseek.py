@@ -47,9 +47,9 @@ The weights exist only in the dtype the replica holds them
 layer l from `fold_in(key, l)` and nothing else, so the engine, a
 reference and a test make the same layer alone. The router, its bias and
 the norms' scales are float32 (they are used in float32), and so is the
-residual stream inside the step programs (`_layers`). Router and
-experts are `models/moe.py`'s: `_route` and the one sorted grouped-matmul
-path `_experts`, at any number of rows.
+residual stream inside the step programs (`_layers`). Router and experts
+are `models/moe.py`'s `_route` and `_experts`, the one sorted grouped-matmul
+path, over every expert layer's experts as one stack (`_expert_stack`).
 
 The two step programs are one function (`_chunk`): `decode_step` is every
 slot's first lane through the layers, all slots at once, and
@@ -379,12 +379,29 @@ def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
     return x, lat, kr
 
 
-def _expert_mlp(x, bp, cfg: DeepseekConfig, given, ok):
-    """x [N,C,D] += routed experts + shared experts; `given` [E] += the
-    (lane, expert) rows each expert was given for the lanes that are
-    `ok`."""
+# an expert layer's routed experts in `moe`: what the layers' loop leaves out
+ROUTED = ("wg", "wu", "wd")
+
+
+def _expert_stack(blocks: Params, cfg: DeepseekConfig) -> tuple:
+    """The routed experts of every expert layer as the kernels read them:
+    `blocks.moe`'s wg, wu [n, E, D, F] and wd [n, E, F, D] seen as [n E, ..],
+    a merge of the leading axes that moves nothing. No loop slices it
+    (`models/kimi.py`'s form): a layer's [E, D, F] taken out of the stack
+    is a 0.4 GB copy for the kernel, three a layer a step."""
+    return tuple(lm.weight(blocks["moe"][w], cfg.dtype).reshape(
+        (-1,) + blocks["moe"][w].shape[2:]) for w in ROUTED)
+
+
+def _expert_mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok):
+    """x [N,C,D] += routed experts + shared experts of expert layer i
+    (layer n_dense_layer + i), whose routed experts are entries i E ..
+    (i + 1) E of `stack` (`_expert_stack`): the stack goes to the kernels
+    whole with the ids offset by the layer, and the other layers' groups
+    are empty. `given` [E] += the (lane, expert) rows each expert was given
+    for the lanes that are `ok`, by the layer's own ids."""
     B, C, D = x.shape
-    K = cfg.experts_per_token
+    K, E = cfg.experts_per_token, cfg.n_experts
     with jax.named_scope("mlp"):
         h32 = rms_norm(x, bp["mlp_norm"], cfg.norm_eps)
         h = h32.astype(cfg.dtype)
@@ -396,8 +413,8 @@ def _expert_mlp(x, bp, cfg: DeepseekConfig, given, ok):
             given = given.at[experts.reshape(-1)].add(
                 jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
         routed = _moe._experts(
-            h, gates.reshape(B, C, K), experts.reshape(B, C, K),
-            *(lm.weight(m[w], cfg.dtype) for w in ("wg", "wu", "wd")), cfg)
+            h, gates.reshape(B, C, K), (i * E + experts).reshape(B, C, K),
+            *stack, dataclasses.replace(cfg, n_experts=stack[0].shape[0]))
         with jax.named_scope("moe_shared"):
             shared = _swiglu(h, bp["shared"], cfg)
         x = x + routed.astype(x.dtype) + shared.astype(x.dtype)
@@ -418,23 +435,22 @@ def _dense_mlp(x, bp, cfg: DeepseekConfig):
         return x + _swiglu(h, bp["mlp"], cfg).astype(x.dtype)
 
 
-def _mlp(x, bp, cfg: DeepseekConfig, given, ok):
+def _mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok):
     """The layer's second half, by what its weights are: (x, given)."""
     if "moe" in bp:
-        return _expert_mlp(x, bp, cfg, given, ok)
+        return _expert_mlp(x, bp, stack, i, cfg, given, ok)
     return _dense_mlp(x, bp, cfg), given
 
 
-def _further_lanes(rest, bp, cfg: DeepseekConfig, lat, kr, given, l, pos,
-                   ok, prefilling):
+def _further_lanes(rest, bp, stack, cfg: DeepseekConfig, lat, kr, given, l,
+                   pos, ok, prefilling):
     """One layer over the lanes after the first, rest [B,M,D] with ok
     [B,M], the first of them at position pos [B], for the slots
     `prefilling` a slot at a time (`lm.each_slot`): a slot's scores
     [1,H,M,T] against its own rows, its experts over its own M lanes. The
-    weights are the ones the first lanes read, `bp` as the layers' scan
-    holds it: the scan copies a layer's three expert matrices out of the
-    stack once (ROADMAP S12a) and every slot's kernels read that copy
-    (`lm.each_slot` has what slicing them again costs)."""
+    weights are the ones the first lanes read: `bp` as the layers' scan
+    holds it (`lm.each_slot` has the rule) and the experts' `stack`, which
+    every slot's kernels read where it lies."""
     M = rest.shape[1]
 
     def slot(b, carry):
@@ -442,7 +458,8 @@ def _further_lanes(rest, bp, cfg: DeepseekConfig, lat, kr, given, l, pos,
         xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         xb, lat, kr = _attention(xb, bp, cfg, lat, kr, l, at,
                                  at[:, None] + jnp.arange(M), okb, slot=b)
-        xb, given = _mlp(xb, bp, cfg, given, okb)
+        xb, given = _mlp(xb, bp, stack, l - cfg.n_dense_layer, cfg, given,
+                         okb)
         return lm.put_lanes(rest, xb, b), lat, kr, given
 
     return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
@@ -466,24 +483,31 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     (305 ms at 32 x 128 for one slot's question).
 
     The dense layers stand before the loop, the expert layers are one scan
-    over their stacked weights (which has each layer's three expert
-    matrices copied out of the stack for the kernels: ROADMAP S12).
-    `program` is the row of the cache's `counts` that this program's
-    counts go to. Returns (x, cache)."""
+    over their stacked weights but the routed experts' three matrices,
+    which the scan would slice and the compiler then copy for the kernels,
+    0.4 GB each, two thirds of a decode step (PERF.md, PR 48): the loop
+    closes over those whole (`_expert_stack`). `program` is the row of the
+    cache's `counts` that this program's counts go to. Returns (x,
+    cache)."""
     C = x.shape[1]
     lat, kr = cache["latent"], cache["k_rope"]
     counts = jnp.zeros((4,), jnp.uint32)
     n_dense = cfg.n_dense_layer
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    blocks = params["blocks"]
+    stack = _expert_stack(blocks, cfg)
+    rest_of = {**blocks, "moe": {k: w for k, w in blocks["moe"].items()
+                                 if k not in ROUTED}}
 
     def layer(l, bp, first, rest, lat, kr, counts):
         given = jnp.zeros((cfg.n_experts,), jnp.int32)
         first, lat, kr = _attention(first, bp, cfg, lat, kr, l, pos0,
                                     pos[:, :1], on[:, None])
-        first, given = _mlp(first, bp, cfg, given, on[:, None])
+        first, given = _mlp(first, bp, stack, l - n_dense, cfg, given,
+                            on[:, None])
         if rest is not None:
             rest, lat, kr, given = _further_lanes(
-                rest, bp, cfg, lat, kr, given, l, pos0 + 1, further,
+                rest, bp, stack, cfg, lat, kr, given, l, pos0 + 1, further,
                 prefilling)
         if "moe" in bp:
             counts = counts + _expert_counts(given)
@@ -499,7 +523,7 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     with jax.named_scope("layers"):
         carry, _ = lax.scan(
             lambda carry, layer_: (layer(*layer_, *carry), None), carry,
-            (jnp.arange(n_dense, cfg.n_layer), params["blocks"]))
+            (jnp.arange(n_dense, cfg.n_layer), rest_of))
     first, rest, lat, kr, counts = carry
     x = lm.join_lanes(first, rest, C)
     with jax.named_scope("moe_router"):

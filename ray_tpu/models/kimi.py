@@ -38,7 +38,7 @@ chips. `vocab_size` rows of the table and the head are this chip's slice.
 The held experts of all expert layers are one stack `[layers x E', d, F]`
 that no layers' loop slices: a layer hands `_experts` the whole stack with
 its ids offset by the layer (and the pairs of absent experts sent past the
-stack's end), so no program copies an expert matrix (ROADMAP S12a).
+stack's end), so no program copies an expert matrix (PERF.md, PR 48).
 
 The cache holds both kinds of leaf (`models/__init__.py`): `kda` [KDA
 layers, slots, 32, 128, 128] and `conv` [KDA layers, slots, 3 x 12288] a

@@ -487,11 +487,11 @@ def each_slot(slots, slot: Callable, carry):
     may be copied on its way.)
 
     The body stays the family's, because how a layer's weights reach it is
-    measured, and a new family reads this first. Where the layers' loop is
-    a scan over the stacked weights, close over the layer as the scan holds
-    it (deepseek): sliced again inside the body, a layer's three expert
-    matrices are copied once more for every slot that prefills, 41.7 ms a
-    slot for 15.8 (`benchmarks/kanana_chunk_lanes.py`). Where the layers'
+    measured, and a new family reads this first. No loop slices a routed
+    expert's matrix, a copy for its kernel: a body reads the stack of every
+    layer's experts whole, as the first lanes do (PERF.md, PR 48). Where the
+    layers' loop is a scan over the other weights, close over the layer as
+    the scan holds it (deepseek; sliced again, the same). Where the layers'
     loop indexes the stack, slice the layer inside the body too (granite,
     kimi: `layer_weights`, with the body's b as its `turn` where the
     compiler would lift the slice out again): sliced once for both the

@@ -217,8 +217,8 @@ class LLMEngine:
     time, grows with the slots that prefill (`chunk_prefilling_slots` in
     `engine_stats()`), and past some number of them costs more than all
     lanes at once would: the latent-attention family at Kanana's widths,
-    32 slots and chunks of 128, takes 43.5 ms + 15.8 ms a slot that
-    prefills where all lanes took 310, so 17 slots at once (`benchmarks/
+    32 slots and chunks of 128, takes 15.7 ms + 15.9 ms a slot that
+    prefills where all lanes took 310, so 19 slots at once (`benchmarks/
     kanana_chunk_lanes.py` has the table; PERF.md §7).
 
     The next token of every slot is chosen on the device

@@ -370,10 +370,18 @@ def test_both_programs_count_the_positions_read_beside_the_attended(
 
 # ------------------------------------------------------- latent attention
 
+@functools.lru_cache(maxsize=None)
+def seeded(cfg):
+    return deepseek.init_params(jax.random.key(SEED), cfg)
+
+
 def one_layer(cfg, l=1):
     return jax.tree.map(lambda a: a[l - cfg.n_dense_layer],
-                        deepseek.init_params(jax.random.key(SEED),
-                                             cfg)["blocks"])
+                        seeded(cfg)["blocks"])
+
+
+def every_layers_experts(cfg):
+    return deepseek._expert_stack(seeded(cfg)["blocks"], cfg)
 
 
 def test_absorbed_attention_is_plain_attention():
@@ -512,10 +520,11 @@ def test_the_router_runs_in_float32_whatever_the_compute_dtype():
 
 def test_the_expert_layer_is_the_dense_sum_over_all_experts_plus_the_shared():
     cfg = tiny(**F32)
-    bp = one_layer(cfg)
+    bp = one_layer(cfg, l=2)            # the stack's second eight experts
     x = jax.random.normal(jax.random.key(7), (2, 9, cfg.d_model))
-    got, given = deepseek._expert_mlp(x, bp, cfg, jnp.zeros((8,), jnp.int32),
-                                      jnp.ones((2, 9), bool))
+    got, given = deepseek._expert_mlp(
+        x, bp, every_layers_experts(cfg), 1, cfg,
+        jnp.zeros((8,), jnp.int32), jnp.ones((2, 9), bool))
     counts = deepseek._expert_counts(given)
     h = kanana._rms_norm(x.reshape(18, -1), bp["mlp_norm"]["scale"],
                          cfg.norm_eps)
@@ -539,7 +548,8 @@ def test_nothing_is_dropped_when_every_token_wants_the_same_experts():
         [5., 5., 5., 0, 0, 0, 0, 0])}}
     x = jax.random.normal(jax.random.key(8), (1, 40, cfg.d_model))
     got, given = deepseek._expert_mlp(
-        x, skew, cfg, jnp.zeros((8,), jnp.int32), jnp.ones((1, 40), bool))
+        x, skew, every_layers_experts(cfg), 0, cfg,
+        jnp.zeros((8,), jnp.int32), jnp.ones((1, 40), bool))
     rows, touched, busiest, _ = deepseek._expert_counts(given).tolist()
     assert (rows, touched, busiest) == (120, 3, 40)
     h = kanana._rms_norm(x[0], bp["mlp_norm"]["scale"], cfg.norm_eps)
@@ -551,6 +561,71 @@ def test_nothing_is_dropped_when_every_token_wants_the_same_experts():
         for e in range(3))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=2e-6)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_stack_of_all_layers_experts_is_each_layers_own_sliced_out(
+        monkeypatch, program):
+    """Both programs hand the kernels the stack of both expert layers'
+    experts with the ids offset by the layer. The form that went (PR 48)
+    sliced a layer's eight out of the stack and gave them the layer's own
+    ids: kept here as the reference, it gives the same logits, rows and
+    counts to the bit (the cpu backend's `ragged_dot` multiplies a row by
+    its group's matrix, whatever the other groups are). Offset by the
+    layer's number where the expert layer's is meant (l E for (l - 1) E),
+    the first expert layer reads the second's experts and the second none:
+    the comparison tells that apart."""
+    cfg = tiny()                                     # bf16, as it is served
+    B, T, C = 4, 96, 16
+    params = seeded(cfg)
+    rng = np.random.default_rng(11)
+    held = np.array([21, 9, 30, 14], np.int32)       # tokens a slot holds
+    active = np.array([True, True, True, False])
+    tokens = jnp.asarray(rng.integers(0, 512, (B, 32)), jnp.int32)
+    cache = deepseek.init_cache(cfg, B, T)
+    for at in (0, 16):                               # what the slots hold
+        _, cache = deepseek.prefill_chunk(
+            params, cache, tokens[:, at:at + 16], jnp.full((B,), at),
+            jnp.clip(held - at, 0, 16), jnp.ones((B,), bool), cfg)
+    start = jax.tree.map(np.asarray, cache)
+    step = jnp.asarray(rng.integers(0, 512, (B, C)), jnp.int32)
+    if program == "decode_step":
+        args = (step[:, 0], jnp.asarray(held), jnp.asarray(active))
+    else:           # slot 1 prefills 16 lanes, the others ride along with one
+        args = (step, jnp.asarray(held), jnp.array([1, C, 1, 1], jnp.int32),
+                jnp.asarray(active))
+    stacked = deepseek._expert_mlp
+
+    def run(expert_mlp):
+        monkeypatch.setattr(deepseek, "_expert_mlp", expert_mlp)
+        logits, after = jax.jit(
+            lambda p, c, *a: getattr(deepseek, program)(p, c, *a, cfg))(
+            params, jax.tree.map(jnp.asarray, start), *args)
+        return np.asarray(logits)[active], jax.tree.map(np.asarray, after)
+
+    def sliced(x, bp, stack, i, cfg, given, ok):
+        E = cfg.n_experts
+        own = tuple(jax.lax.dynamic_slice_in_dim(w, i * E, E) for w in stack)
+        return stacked(x, bp, own, 0, cfg, given, ok)
+
+    def by_the_layers_number(x, bp, stack, i, cfg, given, ok):
+        return stacked(x, bp, stack, i + cfg.n_dense_layer, cfg, given, ok)
+
+    got, after = run(stacked)
+    want, after_sliced = run(sliced)
+    np.testing.assert_array_equal(got, want)
+    for name in deepseek.CACHE_TOKEN_AXIS:
+        np.testing.assert_array_equal(after[name], after_sliced[name])
+        assert not np.array_equal(after[name], start[name])
+    row = int(program == "prefill_chunk")
+    moved = after["counts"][row, :4] - start["counts"][row, :4]
+    np.testing.assert_array_equal(after["counts"], after_sliced["counts"])
+    lanes = 3 if program == "decode_step" else 2 + C
+    assert moved[0] == lanes * cfg.experts_per_token * 2 and moved[3] == 2
+    # (the tiny preset's experts add little to a stream the table sets:
+    # 2e-3 of the logits' spread, a thousand roundings of a float32 sum)
+    wrong, _ = run(by_the_layers_number)
+    assert np.abs(wrong - want).max() > 1e-3 * (want.max() - want.min())
 
 
 @pytest.mark.parametrize("rows,groups", [(192, 128), (24, 8), (8, 8)])

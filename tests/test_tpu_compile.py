@@ -283,18 +283,19 @@ def test_gpt2_xl_serving_programs_are_the_parents(chips, program):
 # Kanana's chunk program since PR 39: the decode program's work on every
 # slot's first lane and the further lanes only of the slots that prefill. The
 # configuration file is the benchmark's and keeps the all-lanes form's
-# 15,700,020,736 B (`memory.prefill_chunk_bytes_by_chunk_size`) until a
-# `benchmark` issue, so the pin is this file's own. Since PR 41 every slot's
-# first lane attends through the `mla_attend` kernel (one in the dense
-# layer's body, one in the expert layers'): the chunk program lost the first
-# lanes' scores, and the decode program, whose temporaries are a copy of a
-# layer's expert matrices whatever attention does, gained the kernel's
-# operands: 790,528 B over the file's `decode_step_bytes` 11,773,044,224
-# Since PR 43 the slots that prefill are `lm.each_slot`'s, a loop of as many
-# turns, where a loop over all 32 held a conditional each (12,781,761,024 B,
-# and the branch handed back a copy of each leaf it wrote: four)
-KANANA_CHUNK_BYTES = 12_785_953_792
-KANANA_DECODE_BYTES = 11_773_834_752
+# 15,700,020,736 B (`memory.prefill_chunk_bytes_by_chunk_size`) and a
+# `decode_step_bytes` of 11,773,044,224 until a `benchmark` issue, so the
+# pins are this file's own. Since PR 41 every slot's first lane attends
+# through the `mla_attend` kernel (one in the dense layer's body, one in the
+# expert layers'): the chunk program lost the first lanes' scores. Since PR
+# 43 the slots that prefill are `lm.each_slot`'s, a loop of as many turns.
+# Since PR 48 the layers' loop closes over the stack of every layer's routed
+# experts and slices none out of it: the decode program's temporaries were a
+# copy of one matrix at a time (0.40 GB of its 11,773,834,752 B) and are the
+# kernels' operands now (3.8 MB), the chunk program held all three across
+# the loop over the slots (1.21 GB of its 12,785,953,792 B)
+KANANA_CHUNK_BYTES = 11_574_284_800
+KANANA_DECODE_BYTES = 11_371_212_288
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -303,20 +304,19 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
     """The cell `serve-kanana-docqa`'s two programs, as its configuration
     file has them (Kanana-2-30B-A3B's widths, 1 + 7 layers, 32 slots of
     4,096 positions, chunks of 128): room for the prefix pool beside the
-    larger, no copy of a cache leaf or of a layer of one, and the three
-    copies of a layer's routed experts' matrices out of the stack that the
-    scan makes today (ROADMAP S12 takes them out, and this pin with them:
-    38.6 -> 13.2 ms a decode step, PERF.md PR 29). The decode program: the
+    larger, no copy of a cache leaf or of a layer of one, and **none of a
+    routed expert matrix**, a layer's [128, d, F] or the stack's
+    [896, d, F]: until PR 48 the scan sliced a layer's three out of the
+    stack and the compiler copied each for the kernels, 22.5 of a decode
+    step's 37.5 ms (PERF.md, PR 48). The decode program: the
     experts' three grouped-matmul kernels in the loop's body and the
     `mla_attend` kernel in both bodies (the dense layer's and the loop's:
     `made_of` counts every Pallas kernel under the older name), no float32
     scores `[32, 32, 1, 4096]` written. The chunk program: three more in
-    the branch of a slot that
-    prefills, which read the same three copies (sliced again inside the
-    branch there are six); no array over all 32 x 128 lanes' scores
-    `[32,32,128,4096]` (4.33 GB of temporaries before PR 39), but the
-    three copies alive across the loop over the slots, 1.2 of its 1.41 GB
-    (the decode program holds one at a time)."""
+    the body of a slot that prefills, which read the same stack; no array
+    over all 32 x 128 lanes' scores `[32,32,128,4096]` (4.33 GB of
+    temporaries before PR 39), and temporaries of a fifth of a gigabyte,
+    one slot's scores and the lanes' float32 stream."""
     import json
 
     chip_dir, _ = _chip_bench()
@@ -337,22 +337,24 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
     # every slot's first lane: its scores never leave the kernel
     assert _written_arrays(hlo, "32,32,(?:1,)?4096", "f32") == []
     if program == "decode":
-        assert sized["total"] == KANANA_DECODE_BYTES
+        assert sized["total"] == KANANA_DECODE_BYTES \
+            < memory["decode_step_bytes"]
+        assert sized["temp"] < 2 ** 22
         assert made_of(hlo, config) == {
             "grouped_matmul_kernels": 3 + 2, "cache_copies": [],
-            "expert_weight_copies": ["fusion"] * 3}
+            "expert_weight_copies": []}
         return
     chunk = str(config["deployment"]["prefill_chunk_size"])
     assert sized["total"] == KANANA_CHUNK_BYTES \
         < memory["prefill_chunk_bytes_by_chunk_size"][chunk]
-    assert sized["temp"] < 1.5e9
+    assert sized["temp"] < 2 ** 28
     assert _written_arrays(hlo, f"32,32,{chunk},4096", r"\w+") == []
     assert _written_arrays(hlo, f"1,32,{chunk},4096", "f32")     # one slot's
     # no whole leaf is written anywhere: a slot that prefills writes its
     # window where the leaf lies, as the first lanes do
     assert made_of(hlo, config) == {
         "grouped_matmul_kernels": 6 + 2, "cache_copies": [],
-        "expert_weight_copies": ["fusion"] * 3}
+        "expert_weight_copies": []}
 
 
 # Brumby's and granite's chunk programs since PR 43: `lm.each_slot` turns
@@ -558,7 +560,7 @@ def test_keye_serving_programs_compile_at_the_configurations_sizes(
     copies a cache leaf (`k`, `v` or `ik`, whole or a layer of it: the
     layers' loop carries the three and writes rows in place); none copies
     an expert matrix out of the stack, a layer's [128, d, F] or the whole
-    [768, d, F] (ROADMAP S12a; Kanana's form would); and the decode program
+    [768, d, F] (Kanana's form until PR 48 did); and the decode program
     writes no float32 `[32, 32, 13312]` array of every slot's scores over
     every position: attention reads the 2,048 chosen rows."""
     import json
