@@ -1,0 +1,39 @@
+#!/usr/bin/env python3
+"""`cpu_cell.py` for the Solar cell: the same rehearsal (one cell end to end
+on the CPU at a tiny size, nothing it prints a measurement), with the model
+cut in the source's key names and the contexts cut to the tiny window,
+which `cpu_cell.TINY` does not know. The share stays the file's: the router
+scores 320 experts, 8 a token, of which the first 40 are held.
+
+    JAX_PLATFORMS=cpu python benchmarks/chip/rehearse/cpu_cell_solar.py \
+        --workload serve-solar-longctx [--seconds 8] [--trace 1]
+"""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import cpu_cell  # noqa: E402
+
+TINY_MODEL = {"vocab_size": 512, "num_hidden_layers": 4, "gqa_layers": [0],
+              "linear_attn_config": {
+                  "head_dim": 16, "num_heads": 2, "num_kv_heads": None,
+                  "short_conv_kernel_size": 4},
+              "hidden_size": 64, "intermediate_size": 128,
+              "moe_intermediate_size": 40, "num_attention_heads": 4,
+              "num_key_value_heads": 2, "head_dim": 16}
+TINY_DEPLOYMENT = {"preset": "solar-tiny", "max_seq_len": 128,
+                   "max_batch": 4, "prefill_chunk_size": 16,
+                   "kv_blocks": 48, "kv_block_size": 8}
+TINY_TRAFFIC = {"clients": 6, "requests_per_client": 500, "documents": 3,
+                "document_uniform": [48, 80], "document_block": 8,
+                "question_uniform": [2, 8], "output_uniform": [8, 24],
+                "ramp_s": 2.0, "trace_seconds": 1.0}
+
+cpu_cell.TINY_MODEL = TINY_MODEL
+cpu_cell.TINY["serve"] = {"deployment": TINY_DEPLOYMENT,
+                          "traffic": TINY_TRAFFIC}
+
+if __name__ == "__main__":
+    sys.exit(cpu_cell.main())

@@ -90,6 +90,27 @@ SHAPES = {   # name: (tokens, of which with routing of their own)
 TILINGS = ((256, 64, 256), (128, 64, 256), (512, 64, 256), (256, 32, 256),
            (256, 128, 256), (128, 128, 256), (256, 64, 128), (256, 64, 512),
            (256, 64, 1024), (256, 128, 512), (128, 64, 512), (128, 32, 512))
+# `--model solar`: Solar-Open2-250B as its cell holds it, 4 layers' 40 held
+# experts of 320 in one stack, d 4,096 and F 1,280 = 2.5 column tiles of 512:
+# the overhang (three steps, 1,536 columns computed) against the tiles that
+# divide it. Measured on a v5e (PR 49, 100 calls in one program; ms a call
+# and the share of the cost; decode: 40 tokens, 30 held rows on 18 experts,
+# least 0.692 ms; chunk: 128 tokens, 96 held rows on 29 experts, least 1.116):
+#     tiling        decode          chunk
+#     three gmm     1.037  66.8%    1.782  62.6%
+#     256:64:512    0.815  84.9%    1.300  85.9%   (the overhang: 3 steps)
+#     256:64:640    0.794  87.1%    1.256  88.8%   (`_column_tile`'s choice)
+#     256:64:256    0.788  87.8%    1.253  89.1%
+#     256:64:128    0.782  88.5%    1.250  89.2%
+#     256:32:640    0.787  87.9%    1.250  89.3%
+#     128:64:640    0.787  88.0%    1.252  89.1%
+# A tile that divides F takes 2.6-3.4% off the overhang; among those that do
+# the call is the touched matrices' DMA whatever the tile (a step of 128
+# columns moves 3.1 MB, and at d 4,096 its ~1.2 us hide under it).
+SOLAR = dict(D=4096, F=1280, E=320, HELD=40, LAYERS=4, FIRST=0,
+             SHAPES={"decode": (40, 40), "chunk": (128, 128)},
+             TILINGS=((256, 64, 512), (256, 64, 640), (256, 64, 256),
+                      (256, 64, 128), (256, 32, 640), (128, 64, 640)))
 
 
 def _routing(np, tokens: int, own: int, seed: int):
@@ -108,7 +129,12 @@ def main() -> int:
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--tilings", default="",
                     help="rows:sub:f,... in place of the sweep")
+    ap.add_argument("--model", default="kimi", choices=("kimi", "solar"))
     args = ap.parse_args()
+    if args.model == "solar":
+        globals().update(SOLAR)
+        args.shapes = ",".join(s for s in args.shapes.split(",")
+                               if s in SHAPES)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -204,8 +230,9 @@ def main() -> int:
             print(name, label, json.dumps(shape["forms"][label]), flush=True)
         out[name] = shape
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(REPO, "chiprun_out", "expert_mlp_tiles.json"),
-              "w") as f:
+    name = "expert_mlp_tiles" + ("" if args.model == "kimi"
+                                 else "_" + args.model)
+    with open(os.path.join(REPO, "chiprun_out", name + ".json"), "w") as f:
         json.dump(out, f, indent=1)
     return 0
 

@@ -10,12 +10,14 @@ layers for serving: delta-rule state a slot beside un-rotated latent rows a
 token, and a share of each layer's routed experts), keye (Keye-VL-2.0's
 language model for serving: grouped-head attention over the 2,048 rows a
 learned indexer chooses of a slot's, three leaves a token, routed
-experts)."""
+experts), solar (Solar Open2's layers for serving: kimi's programs with a
+third mixer, gated un-rotated grouped-head attention over keys and values by
+head beside the delta-rule state)."""
 
 from ray_tpu.models import gpt2
 
 __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
-           "serving_family"]
+           "solar", "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
@@ -52,7 +54,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "brumby": ("brumby", "BrumbyConfig"),
             "granite": ("granite", "GraniteConfig"),
             "kimi": ("kimi", "KimiConfig"),
-            "keye": ("keye", "KeyeConfig")}
+            "keye": ("keye", "KeyeConfig"),
+            "solar": ("solar", "KimiConfig")}
 
 
 def serving_family(preset: str):
@@ -70,7 +73,7 @@ def serving_family(preset: str):
 
 def __getattr__(name):
     if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
-                "keye"):
+                "keye", "solar"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

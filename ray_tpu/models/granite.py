@@ -72,7 +72,7 @@ from jax import lax
 
 from ray_tpu.models import lm
 from ray_tpu.models.llama import rms_norm
-from ray_tpu.ops.rows_write import TILE as _WRITE_WINDOW, rows_write
+from ray_tpu.ops.rows_write import rows_write
 from ray_tpu.ops.ssm_update import ssm_update
 
 Params = Any
@@ -438,16 +438,10 @@ def _mamba_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
 
 def _qkv(x, bp, cfg: GraniteConfig):
     """x [B,M,D] -> q [B,M,G,R,d], k, v [B,M,G,d] in the compute dtype."""
-    B, M, _ = x.shape
-    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
-    p = bp["attn"]
     with jax.named_scope("gqa_project"):
-        u = rms_norm(x, bp["mixer_norm"], cfg.norm_eps)
-        return tuple(
-            lm.dot(u, p[name], cfg.dtype).astype(cfg.dtype).reshape(
-                B, M, *shape)
-            for name, shape in (("wq", (G, R, d)), ("wk", (G, d)),
-                                ("wv", (G, d))))
+        return lm.gqa_qkv(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
+                          bp["attn"], cfg.n_kv_head, cfg.queries_per_kv,
+                          cfg.head_dim, cfg.dtype)
 
 
 def _attn_out(x, y, bp, cfg: GraniteConfig):
@@ -458,17 +452,9 @@ def _attn_out(x, y, bp, cfg: GraniteConfig):
 
 
 def _attend(q, k, v, at, cfg: GraniteConfig):
-    """q [..., Q, d] at positions `at` [..., Q] over the cached rows k, v
-    [..., d, T] of its key-value head -> [..., Q, d] float32: scores times
-    the model's multiplier, causal softmax, weighted values."""
-    T = k.shape[-1]
-    scores = jnp.einsum("...qd,...dt->...qt", q, k,
-                        preferred_element_type=jnp.float32)
-    seen = jnp.arange(T) <= at[..., None]
-    probs = jax.nn.softmax(jnp.where(
-        seen, scores * cfg.attention_multiplier, -1e30), axis=-1)
-    return jnp.einsum("...qt,...dt->...qd", probs.astype(cfg.dtype), v,
-                      preferred_element_type=jnp.float32)
+    """`lm.gqa_attend` (every grouped-head family's) at the model's
+    multiplier."""
+    return lm.gqa_attend(q, k, v, at, cfg.attention_multiplier, cfg.dtype)
 
 
 def _attention_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
@@ -488,26 +474,6 @@ def _attention_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
     return x, {**cache, "k": ck, "v": cv}
 
 
-def _write_slot(c, l, slot, val, pos, ok):
-    """Layer l of the carried leaf c [L,B,G,d,T] takes val [M,G,d] at
-    positions pos.. of slot `slot` where ok [M]: one window of W >= M
-    positions read, blended and written in place (`dynamic_update_slice`
-    clamps its start near the end of the sequence, so an unmasked block
-    write would smear garbage lanes over valid earlier positions). The lanes
-    are moved by a 0/1 matrix: exact, one product of 1 a lane."""
-    _, _, G, d, T = c.shape
-    M = val.shape[0]
-    W = min(T, max(M, _WRITE_WINDOW))
-    start = jnp.clip(pos, 0, T - W)
-    hit = ((jnp.arange(W)[:, None] - (pos - start)) == jnp.arange(M)) & ok
-    moved = jnp.einsum("wm,mgd->gdw", hit.astype(val.dtype), val,
-                       precision=_HIGHEST)
-    at = (l, slot, 0, 0, start)
-    old = lax.dynamic_slice(c, at, (1, 1, G, d, W))
-    new = jnp.where(hit.any(axis=-1), moved, old[0, 0])
-    return lax.dynamic_update_slice(c, new[None, None], at)
-
-
 def _attention_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
     """The same mixer over one slot's further lanes, x [1,M,D], the first of
     them at position pos: its scores are [G, R M, T] floats."""
@@ -517,8 +483,8 @@ def _attention_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
     with jax.named_scope("attn"):
         q, k, v = _qkv(x, bp, cfg)
         with jax.named_scope("kv_update"):
-            ck = _write_slot(cache["k"], l, slot, k[0], pos, ok[0])
-            cv = _write_slot(cache["v"], l, slot, v[0], pos, ok[0])
+            ck = lm.gqa_write_slot(cache["k"], l, slot, k[0], pos, ok[0])
+            cv = lm.gqa_write_slot(cache["v"], l, slot, v[0], pos, ok[0])
         with jax.named_scope("gqa_attend"):
             rows = [lax.dynamic_slice(leaf, (l, slot, 0, 0, 0),
                                       (1, 1, G, d, T))[0, 0]

@@ -1,6 +1,7 @@
 """Kimi Linear's layers for serving: Kimi Delta Attention (KDA) over a
 recurrent state beside latent attention (MLA) without positions, then
-routed experts of which this chip may hold a share.
+routed experts of which this chip may hold a share; and Solar Open2's,
+which are the same delta rule beside gated grouped-head attention.
 
 What is served is `moonshotai/Kimi-Linear-48B-A3B-Instruct` (`model_type:
 kimi_linear`; preset `kimi-linear-48b-a3b`): 27 layers counted from 1, MLA
@@ -29,6 +30,30 @@ is dense, every other layer's the expert block. With d the hidden size, eps
       largest of s + b chosen, gates s / (sum + 1e-20) * 2.446;
       x += sum_k g_k SwiGLU_1024^(e_k)(h) + SwiGLU_1024^shared(h)
 
+`upstage/Solar-Open2-250B` (`model_type: solar_open2`; preset
+`solar-open2-250b`; `models/solar.py` is the word `solar`) is served
+here too, as a third kind of mixer and two switches, because everything
+else of it is this module's: 48 layers counted from 0, softmax attention
+at `gqa_layers` (0, 4, .., 44) and KDA elsewhere at 64 heads, every layer's
+MLP the expert block (320 experts of 1,280, scaling 1.0), no dense layer.
+
+    KDA as above with b = 2 sigmoid(u W_b) (`kda_neg_eigval`: a
+      transition's eigenvalue along k reaches into (-1, 1))
+    softmax layer (`gqa`), 64 query and 8 key-value heads of 128, no
+      rotation, no q/k norm:  q = u W_q;  k, v = u W_k, u W_v, cached by
+      the 8 heads;  o_h = softmax_{t<=pos}(q_h . k_{h//8,t} / sqrt(128))
+      v_{h//8};  y = (o * sigmoid(u W_gate)) W_o
+
+Its rows are keys and values by head, `k`, `v` [softmax layers, slots, 8,
+T, 128] (`lm`'s grouped-head arithmetic, which granite's attention layers
+run too: `gqa_qkv`, `gqa_attend`, `gqa_write_slot`; a decode step's
+position through `ops/rows_write.py`). A chunk's further lanes attend a
+block of positions at a time and only as far as the slot's own
+(`lm.gqa_attend_blocks`): at 25,600 positions the plain form's scores for
+one slot's 8 x 128 queries are 0.84 GB. The decode program reads all T
+positions a lane (`read_positions` counts T for each). A float32 q and
+the probabilities meet the bf16 rows as two pieces (`lm.gqa_attend`).
+
 **The chip's share.** `experts_held` E' and `first_expert` say which of the
 E experts of every expert layer this replica holds: the router keeps its E
 outputs and its 8 a token, `moe._experts` computes the held experts' part of
@@ -44,8 +69,10 @@ The cache holds both kinds of leaf (`models/__init__.py`): `kda` [KDA
 layers, slots, 32, 128, 128] and `conv` [KDA layers, slots, 3 x 12288] a
 slot's state, float32 (`CACHE_STATE`: S in `ops/kda_update.py`'s layout and
 the last three inputs of the convolutions of q, k and v side by side), and
-`latent` / `k_rope` [MLA layers, slots, T, 512 | 64] a value a token
-(`CACHE_TOKEN_AXIS`), and `counts`, the programs' own.
+`latent` / `k_rope` [MLA layers, slots, T, 512 | 64] or `k` / `v` a value
+a token (`CACHE_TOKEN_AXIS` here and in `models/solar.py`; a configuration's
+cache has the leaves of the kinds of layer it has), and `counts`, the
+programs' own.
 
 The mixers exist in two forms and no third (`models/granite.py`). The
 recurrence, one token a slot through the kernel `kda_update`, is
@@ -96,6 +123,7 @@ from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.kda_update import kda_update
 from ray_tpu.ops.pieces import pieces
 from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
+from ray_tpu.ops.rows_write import rows_write
 
 Params = Any
 _HIGHEST = lax.Precision.HIGHEST
@@ -119,7 +147,10 @@ class KimiConfig:
     norm_topk_prob: bool = True
     router_scoring: str = "sigmoid"
     routed_scaling_factor: float = 2.446
-    n_head: int = 32                 # MLA
+    n_head: int = 32                 # MLA's, or the softmax layers' queries
+    gqa_layers: tuple = ()           # counted from 0, as their source does
+    n_kv_head: int = 8               # the softmax layers' key-value heads
+    gqa_head_dim: int = 128
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -128,6 +159,7 @@ class KimiConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4                # short_conv_kernel_size
     kda_rank: int = 128              # the two gates' low rank (assumed)
+    kda_neg_eigval: bool = False     # b = 2 sigmoid (kda_allow_neg_eigval)
     norm_eps: float = 1e-5
     max_seq_len: int = 1048576
     dtype: Any = jnp.bfloat16        # compute
@@ -135,13 +167,19 @@ class KimiConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "mla_layers", tuple(self.mla_layers))
+        object.__setattr__(self, "gqa_layers", tuple(self.gqa_layers))
         assert all(1 <= l <= self.n_layer for l in self.mla_layers)
+        assert all(0 <= l < self.n_layer for l in self.gqa_layers)
+        # one kind of attention a configuration: the cache holds its rows,
+        # and `_read_positions` counts by which leaves there are
+        assert not (self.mla_layers and self.gqa_layers)
         assert (0 <= self.first_expert
                 and self.first_expert + self.experts_held <= self.n_experts)
 
     @property
     def layer_types(self) -> tuple:
-        return tuple("mla" if l + 1 in self.mla_layers else "kda"
+        return tuple("mla" if l + 1 in self.mla_layers
+                     else "gqa" if l in self.gqa_layers else "kda"
                      for l in range(self.n_layer))
 
     def layers_of(self, kind: str) -> int:
@@ -154,6 +192,10 @@ class KimiConfig:
     @property
     def kda_inner(self) -> int:
         return self.kda_heads * self.kda_head_dim
+
+    @property
+    def queries_per_kv(self) -> int:
+        return self.n_head // self.n_kv_head
 
     @property
     def qk_head_dim(self) -> int:
@@ -172,6 +214,20 @@ class KimiConfig:
 PRESETS = {
     # moonshotai/Kimi-Linear-48B-A3B-Instruct config.json: the defaults
     "kimi-linear-48b-a3b": dict(),
+    # upstage/Solar-Open2-250B config.json; intermediate_size 10,240 is used
+    # by no layer (first_k_dense_replace 0)
+    "solar-open2-250b": dict(
+        vocab_size=196608, n_layer=48, mla_layers=(),
+        gqa_layers=tuple(range(0, 48, 4)), n_dense_layer=0, d_model=4096,
+        d_ff=10240, d_ff_expert=1280, n_experts=320, experts_held=320,
+        routed_scaling_factor=1.0, n_head=64, n_kv_head=8, gqa_head_dim=128,
+        kda_heads=64, kda_neg_eigval=True),
+    "solar-tiny": dict(
+        vocab_size=512, n_layer=8, mla_layers=(), gqa_layers=(0, 4),
+        n_dense_layer=0, d_model=64, d_ff=128, d_ff_expert=40, n_experts=16,
+        experts_held=16, experts_per_token=3, routed_scaling_factor=1.0,
+        n_head=4, n_kv_head=2, gqa_head_dim=16, kda_heads=2, kda_head_dim=16,
+        kda_rank=8, kda_neg_eigval=True, max_seq_len=128),
     "kimi-tiny": dict(
         vocab_size=512, n_layer=5, mla_layers=(3, 5), n_dense_layer=1,
         d_model=64, d_ff=128, d_ff_expert=32, n_experts=8, experts_held=8,
@@ -182,7 +238,9 @@ PRESETS = {
 
 # the serving contract (`models/__init__.py`): the latent and the shared key
 # hold a value a token, along axis 2; the delta-rule state and the
-# convolutions' window hold a slot's state, with no token axis
+# convolutions' window hold a slot's state, with no token axis. (A
+# configuration with softmax layers has keys and values by head instead:
+# `models/solar.py` is this module under that word on the leaves.)
 CACHE_TOKEN_AXIS = {"latent": 2, "k_rope": 2}
 CACHE_STATE = ("kda", "conv")
 
@@ -191,7 +249,9 @@ CACHE_STATE = ("kda", "conv")
 # expert layers, the (lane, expert) rows the experts held here were given
 # for valid lanes, the held experts that got at least one, the most that
 # one of them got, and 1; once a step the positions the valid lanes attend
-# to and the positions whose rows an MLA layer read for them; and, over the
+# to and the positions whose rows an MLA or softmax layer read for them
+# (`mla_attend` to each lane's block, the grouped-head plain form all T, a
+# chunk's further lanes to the slot's block); and, over the
 # expert layers again, all the valid lanes' (lane, expert) pairs, held or
 # not: 8 a lane
 COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
@@ -279,6 +339,21 @@ def _mla_params(key, cfg: KimiConfig) -> Params:
             "wo": lm.normal(ks[3], (H * cfg.v_head_dim, D), 0.02, pd)}
 
 
+def _gqa_params(key, cfg: KimiConfig) -> Params:
+    ks = jax.random.split(key, 5)
+    pd, D = cfg.param_dtype, cfg.d_model
+    H, G, d = cfg.n_head, cfg.n_kv_head, cfg.gqa_head_dim
+    return {"wq": lm.normal(ks[0], (D, H * d), 0.02, pd),
+            "wk": lm.normal(ks[1], (D, G * d), 0.02, pd),
+            "wv": lm.normal(ks[2], (D, G * d), 0.02, pd),
+            # the output gate, a value an output lane (assumed element-wise)
+            "w_gate": lm.normal(ks[3], (D, H * d), 0.02, pd),
+            "wo": lm.normal(ks[4], (H * d, D), 0.02, pd)}
+
+
+_MIXER_PARAMS = {"kda": _kda_params, "mla": _mla_params, "gqa": _gqa_params}
+
+
 def _expert_params(key, cfg: KimiConfig) -> Params:
     """The held experts' matrices: expert e's from `fold_in(key, e)` and
     nothing else, so that every share of a layer holds the same expert
@@ -302,9 +377,7 @@ def _init_layer(key: jax.Array, l, cfg: KimiConfig, kind: str,
                 dense: bool) -> Params:
     ks = jax.random.split(jax.random.fold_in(key, l), 6)
     D, E = cfg.d_model, cfg.n_experts
-    out = {kind: {"norm": lm.ones(D),
-                  **(_mla_params if kind == "mla" else _kda_params)(ks[0],
-                                                                    cfg)}}
+    out = {kind: {"norm": lm.ones(D), **_MIXER_PARAMS[kind](ks[0], cfg)}}
     if dense:
         out["dense"] = {"norm": lm.ones(D),
                         **_swiglu_params(ks[1], cfg, cfg.d_ff)}
@@ -324,7 +397,8 @@ def init_layer(key: jax.Array, l: int, cfg: KimiConfig) -> Params:
     else: its mixer under `kda` or `mla`, its MLP under `dense` or under
     `moe` (router, bias, shared expert) and `experts` (the held experts'
     [E', ...]), by the one compiled program a kind (`lm.layer_program`): a
-    layer made alone is, to the bit, the layer in `init_params`' tree."""
+    layer made alone is, to the bit, the layer in `init_params`' tree. The
+    mixer lies under its kind's name, `kda`, `mla` or `gqa`."""
     return lm.layer_program(_init_layer, cfg, cfg.layer_types[l],
                             l < cfg.n_dense_layer)(key, jnp.int32(l))
 
@@ -403,11 +477,15 @@ def num_params(cfg: KimiConfig) -> int:
     mla = (D * Hm * cfg.qk_head_dim + D * cfg.cache_width + r
            + r * Hm * (cfg.qk_nope_head_dim + cfg.v_head_dim)
            + Hm * cfg.v_head_dim * D + D)
+    gqa = (2 * D * Hm * cfg.gqa_head_dim
+           + 2 * D * cfg.n_kv_head * cfg.gqa_head_dim
+           + Hm * cfg.gqa_head_dim * D + D)
     F = cfg.d_ff_expert
     moe = (D + D * cfg.n_experts + cfg.n_experts
            + (cfg.experts_held + cfg.n_shared_experts) * 3 * D * F)
     dense = D + 3 * D * cfg.d_ff
     return (cfg.layers_of("kda") * kda + cfg.layers_of("mla") * mla
+            + cfg.layers_of("gqa") * gqa
             + cfg.n_dense_layer * dense + cfg.n_expert_layer * moe
             + 2 * cfg.vocab_size * D + D)
 
@@ -426,16 +504,22 @@ def init_cache(cfg: KimiConfig, batch: int, max_len: Optional[int] = None):
     `decode_step`'s and row 1 `prefill_chunk`'s (they wrap: a reader takes
     differences modulo 2**32). `max_len` sizes the rows alone."""
     T = max_len or cfg.max_seq_len
-    Lk, Lm = cfg.layers_of("kda"), cfg.layers_of("mla")
+    Lk, Lm, Lg = (cfg.layers_of(kind) for kind in ("kda", "mla", "gqa"))
     P = cfg.kda_head_dim
-    return {"kda": jnp.zeros((Lk, batch, cfg.kda_heads, P, P), jnp.float32),
-            "conv": jnp.zeros(
-                (Lk, batch, (cfg.kda_conv - 1) * 3 * cfg.kda_inner),
-                jnp.float32),
-            "latent": jnp.zeros((Lm, batch, T, cfg.kv_lora_rank), cfg.dtype),
-            "k_rope": jnp.zeros((Lm, batch, T, cfg.qk_rope_head_dim),
-                                cfg.dtype),
-            "counts": jnp.zeros((2, len(COUNTS)), jnp.uint32)}
+    out = {"kda": jnp.zeros((Lk, batch, cfg.kda_heads, P, P), jnp.float32),
+           "conv": jnp.zeros(
+               (Lk, batch, (cfg.kda_conv - 1) * 3 * cfg.kda_inner),
+               jnp.float32)}
+    if Lm:
+        out.update(
+            latent=jnp.zeros((Lm, batch, T, cfg.kv_lora_rank), cfg.dtype),
+            k_rope=jnp.zeros((Lm, batch, T, cfg.qk_rope_head_dim), cfg.dtype))
+    if Lg:
+        # by the key-value heads, a position a row of the head's lanes
+        by_head = (Lg, batch, cfg.n_kv_head, T, cfg.gqa_head_dim)
+        out.update(k=jnp.zeros(by_head, cfg.dtype),
+                   v=jnp.zeros(by_head, cfg.dtype))
+    return {**out, "counts": jnp.zeros((2, len(COUNTS)), jnp.uint32)}
 
 
 # ---------------------------------------------------------------------------
@@ -460,6 +544,8 @@ def _kda_in(u, p, cfg: KimiConfig):
         log_a = -lm.over_lanes(jnp.exp(p["a_log"]), cfg.kda_head_dim) \
             * jax.nn.softplus(f + p["dt_bias"])
         b = jax.nn.sigmoid(narrow[..., 2 * R:2 * R + H])
+        if cfg.kda_neg_eigval:
+            b = 2.0 * b
         gate = jax.nn.sigmoid(
             lm.dot(narrow[..., R:2 * R], p["w_g2"], cfg.dtype) + p["g_bias"])
         return qkv, log_a, b, gate
@@ -657,6 +743,50 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
     return x, {**cache, "latent": lat, "k_rope": kr}
 
 
+def _gqa(x, p, cfg: KimiConfig, cache, i, pos0, ok, slot=None):
+    """Softmax layer `i` of the stack: x [N,C,D] float32 += gated
+    grouped-head attention of its lanes against the carried rows of `k` and
+    `v`. Row n is slot n at one lane (N = B, C = 1: the plain form over all
+    T positions), or the one row is `slot`'s own further lanes, the first
+    at position pos0 [1], against that slot's rows a block at a time."""
+    B, C, _ = x.shape
+    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.gqa_head_dim
+    scale = 1.0 / math.sqrt(d)
+    with jax.named_scope("attn"):
+        with jax.named_scope("gqa_project"):
+            u = rms_norm(x, p["norm"], cfg.norm_eps)
+            # q stays float32: its two pieces meet the cached rows
+            q, k, v = lm.gqa_qkv(u, p, G, R, d, cfg.dtype, jnp.float32)
+            gate = jax.nn.sigmoid(lm.dot(u, p["w_gate"], cfg.dtype))
+        if slot is None:
+            with jax.named_scope("kv_update"):
+                ck = rows_write(cache["k"], i, k[:, 0], pos0, ok[:, 0])
+                cv = rows_write(cache["v"], i, v[:, 0], pos0, ok[:, 0])
+            with jax.named_scope("gqa_attend"):
+                y = lm.gqa_attend(
+                    q[:, 0], ck[i], cv[i],
+                    jnp.broadcast_to(pos0[:, None, None], (B, G, R)), scale,
+                    cfg.dtype)[:, None]                        # [B,1,G,R,d]
+        else:
+            with jax.named_scope("kv_update"):
+                ck = lm.gqa_write_slot(cache["k"], i, slot, k[0], pos0[0],
+                                       ok[0])
+                cv = lm.gqa_write_slot(cache["v"], i, slot, v[0], pos0[0],
+                                       ok[0])
+            with jax.named_scope("gqa_attend"):
+                # [C,G,R,d] -> [G, R C, d]: a head's queries side by side
+                qs = jnp.transpose(q[0], (1, 2, 0, 3)).reshape(G, R * C, d)
+                at = jnp.broadcast_to(
+                    pos0[0] + jnp.tile(jnp.arange(C), R), (G, R * C))
+                last = pos0[0] + jnp.maximum(ok[0].sum(), 1) - 1
+                y = lm.gqa_attend_blocks(qs, ck, cv, i, slot, at, last,
+                                         scale, cfg.dtype)
+                y = jnp.transpose(y.reshape(G, R, C, d), (2, 0, 1, 3))[None]
+        with jax.named_scope("gqa_project"):
+            x = x + lm.dot(y.reshape(B, C, -1) * gate, p["wo"], cfg.dtype)
+    return x, {**cache, "k": ck, "v": cv}
+
+
 def _dense_mlp(x, p, cfg: KimiConfig):
     with jax.named_scope("mlp"):
         return x + _swiglu(rms_norm(x, p["norm"], cfg.norm_eps), p, cfg)
@@ -727,6 +857,8 @@ def _further_lanes(rest, mixer: str, mixer_stack, i, mlp_stack, mlp_i,
         if mixer == "mla":
             xb, cache = _mla(xb, p, cfg, cache, i, at,
                              at[:, None] + jnp.arange(M), okb, slot=b)
+        elif mixer == "gqa":
+            xb, cache = _gqa(xb, p, cfg, cache, i, at, okb, slot=b)
         else:
             xb, cache = _kda_further(xb, p, cfg, cache, i, b, okb)
         mp = lm.layer_weights(mlp_stack, mlp_i)
@@ -753,6 +885,8 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
     if mixer == "mla":
         first, cache = _mla(first, p, cfg, cache, i, pos0, pos0[:, None],
                             on[:, None])
+    elif mixer == "gqa":
+        first, cache = _gqa(first, p, cfg, cache, i, pos0, on[:, None])
     else:
         first, cache = _kda_first(first, p, cfg, cache, i, on)
     mp = lm.layer_weights(mlp_stack, mlp_i)
@@ -768,6 +902,27 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
     if not dense:
         counts = counts + _expert_counts(given, cfg)
     return first, rest, cache, counts
+
+
+def _read_positions(cache, pos0, length, on, further):
+    """The positions whose rows one attention layer read for a step's valid
+    lanes: every slot's first lane to its block through `mla_attend`, or
+    all T in the grouped-head plain form; a prefilling slot's further lanes
+    all T of latent rows, or the grouped-head blocks to the slot's last
+    lane."""
+    if "latent" in cache:
+        T = cache["latent"].shape[2]
+        read = read_positions(pos0, on, T)
+        if further is not None:
+            read = read + (further.any(axis=1).sum() * T).astype(jnp.uint32)
+        return read
+    T = cache["k"].shape[3]
+    read = on.sum() * T
+    if further is not None:
+        turns, block = lm.gqa_blocks(pos0 + jnp.maximum(length, 1) - 1, T)
+        read = read + jnp.sum(jnp.where(further.any(axis=1),
+                                        turns * block, 0))
+    return read.astype(jnp.uint32)
 
 
 def _logits(params: Params, x, cfg: KimiConfig):
@@ -845,11 +1000,8 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
         attended = jnp.sum(jnp.where(ok, pos0[:, None] + lane + 1, 0))
         counts = counts.at[COUNTS.index("attended_positions")].set(
             attended.astype(jnp.uint32))
-        T = cache["latent"].shape[2]
-        read = read_positions(pos0, on, T)
-        if prefilling is not None:
-            read = read + (prefilling[1] * T).astype(jnp.uint32)
-        counts = counts.at[COUNTS.index("read_positions")].set(read)
+        counts = counts.at[COUNTS.index("read_positions")].set(
+            _read_positions(cache, pos0, length, on, further))
         counts = cache["counts"].at[program].add(counts)
     return (_logits(params, lm.last_valid_lane(x, length), cfg),
             {**leaves, "counts": counts})

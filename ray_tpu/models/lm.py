@@ -522,3 +522,158 @@ def slot_lanes(b, rest, ok, pos):
 def put_lanes(rest, xb, b):
     """`rest` with slot b's lanes xb [1,M,D] back in their place."""
     return lax.dynamic_update_slice(rest, xb, (b, 0, 0))
+
+
+# -- grouped-head attention over rows by head --------------------------------
+#
+# What granite's and Solar's softmax layers share (`models/granite.py`,
+# `models/kimi.py`): keys and values cached by the G key-value heads. Where
+# a head has 64 lanes the leaves are [layers, slots, G, d, T], the positions
+# on the lanes, which is how the TPU's compiler lays a `[.., T, 64]` array
+# out anyway (granite; `ops/rows_write.py` writes a decode step's one
+# position a slot); where it has 128 they are [layers, slots, G, T, d], a
+# position a row of 128 lanes (Solar: handed leaves with the positions last,
+# the compiler re-laid both, 2.1 GB each, on the way into every chunk step
+# and out of it, because the further lanes slice the positions by the
+# block). Which way round a leaf lies is read off its shape against the
+# head's d (`ops/rows_write.positions_last`). The caller keeps its named
+# scopes (`gqa_project`, `kv_update`, `gqa_attend`): the per-layer readers
+# sum by them.
+
+GQA_BLOCK = 1024             # positions a turn of `gqa_attend_blocks` reads
+_MASKED = -1e30
+
+
+def gqa_qkv(u, p, G: int, R: int, d: int, dtype, q_dtype=None):
+    """The normed input u [B,M,D] float32 -> q [B,M,G,R,d], k, v [B,M,G,d]
+    in the compute dtype, by `p`'s `wq`, `wk`, `wv`; q in `q_dtype` where
+    the family keeps it whole (float32)."""
+    B, M, _ = u.shape
+    return tuple(
+        dot(u, p[name], dtype).astype(to).reshape(B, M, *shape)
+        for name, shape, to in (("wq", (G, R, d), q_dtype or dtype),
+                                ("wk", (G, d), dtype), ("wv", (G, d), dtype)))
+
+
+def _row_pieces(x, dtype):
+    """x [..., Q, n] -> the rows a product with cached rows of `dtype`
+    takes: x itself where it is of that dtype, else its two pieces of it
+    (`ops/pieces.py`'s arithmetic) stacked on the rows, [..., 2 Q, n]: the
+    cached rows pass once, and the result's halves add up
+    (`_rows_added`)."""
+    if x.dtype == dtype:
+        return x
+    from ray_tpu.ops.pieces import pieces    # not at the top: `dot` has why
+
+    return jnp.concatenate(list(pieces(x, dtype)), axis=-2)
+
+
+def _rows_added(y, Q: int):
+    return y if y.shape[-2] == Q else y[..., :Q, :] + y[..., Q:, :]
+
+
+def gqa_attend(q, k, v, at, scale: float, dtype):
+    """q [..., Q, d] at positions `at` [..., Q] over the cached rows k, v
+    [..., d, T] (or [..., T, d]) of its key-value head -> [..., Q, d]
+    float32: scores times `scale`, causal softmax, weighted values. Every
+    one of the T positions is read, whatever `at` is. q in the rows' dtype
+    and the probabilities rounded to it go as one piece (granite); a float32
+    q and its probabilities as the two pieces that add up to them (Solar,
+    which states a float32 q: as one piece its logits lie 0.005-0.009 from
+    the reference's where the two pieces' lie 0.0001-0.0007, PERF.md PR 49)."""
+    from ray_tpu.ops.rows_write import positions_last  # `dot` has why here
+
+    last = positions_last(k.shape, q.shape[-1])
+    rows = "dt" if last else "td"
+    T = k.shape[-1 if last else -2]
+    Q, whole = q.shape[-2], q.dtype != dtype
+    scores = _rows_added(jnp.einsum(
+        f"...qd,...{rows}->...qt", _row_pieces(q, dtype), k,
+        preferred_element_type=jnp.float32), Q)
+    seen = jnp.arange(T) <= at[..., None]
+    probs = jax.nn.softmax(jnp.where(seen, scores * scale, _MASKED), axis=-1)
+    probs = _row_pieces(probs, dtype) if whole else probs.astype(dtype)
+    return _rows_added(jnp.einsum(f"...qt,...{rows}->...qd", probs, v,
+                                  preferred_element_type=jnp.float32), Q)
+
+
+def gqa_blocks(last, T: int):
+    """How many turns `gqa_attend_blocks` takes to reach position `last`,
+    and the positions a turn reads."""
+    block = min(GQA_BLOCK, T)
+    return last // block + 1, block
+
+
+def gqa_attend_blocks(q, ck, cv, l, slot, at, last, scale: float, dtype):
+    """`gqa_attend` for one slot's queries q [G, Q, d] at positions `at`
+    [G, Q] against layer l of the leaves ck, cv [L,B,G,T,d], a block of
+    positions at a time and only as far as position `last` (the slot's
+    furthest query): the block's scores [G, Q, block] float32, the running
+    maximum, sum and weighted values, one division at the end. The plain
+    form's scores for a chunk's Q = R M queries are [G, Q, T] floats, 0.84
+    GB at 8 x 128 queries and 25,600 positions; a block's are 34 MB (twice
+    that for a float32 q's two pieces). The precision is the plain form's:
+    q and the probabilities as one piece or as two, by q's dtype."""
+    G, Q, d = q.shape
+    T = ck.shape[3]
+    turns, block = gqa_blocks(last, T)
+    whole = q.dtype != dtype
+    q = _row_pieces(q, dtype)
+
+    def turn(j, carry):
+        m, s, acc = carry
+        # the last block of a T that no block divides starts early: the
+        # positions before j block were the turn before's
+        start = jnp.minimum(j * block, T - block)
+        k, v = (lax.dynamic_slice(leaf, (l, slot, 0, start, 0),
+                                  (1, 1, G, block, d))[0, 0]
+                for leaf in (ck, cv))
+        t = start + jnp.arange(block)
+        scores = _rows_added(jnp.einsum(
+            "gqd,gtd->gqt", q, k, preferred_element_type=jnp.float32),
+            Q) * scale
+        seen = (t >= j * block) & (t <= at[..., None])
+        scores = jnp.where(seen, scores, _MASKED)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        # a block that holds nothing a query may see leaves it as it was
+        probs = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+        grown = jnp.exp(m - m_new)
+        s = grown * s + jnp.sum(probs, axis=-1)
+        probs = _row_pieces(probs, dtype) if whole else probs.astype(dtype)
+        acc = grown[..., None] * acc + _rows_added(jnp.einsum(
+            "gqt,gtd->gqd", probs, v, preferred_element_type=jnp.float32), Q)
+        return m_new, s, acc
+
+    _, s, acc = lax.fori_loop(
+        0, turns, turn, (jnp.full((G, Q), _MASKED, jnp.float32),
+                         jnp.zeros((G, Q), jnp.float32),
+                         jnp.zeros((G, Q, d), jnp.float32)))
+    return acc / jnp.maximum(s, 1e-30)[..., None]
+
+
+def gqa_write_slot(c, l, slot, val, pos, ok):
+    """Layer l of the carried leaf c [L,B,G,d,T] (or [L,B,G,T,d]) takes val
+    [M,G,d] at positions pos.. of slot `slot` where ok [M]: one window of
+    W >= M positions read, blended and written in place
+    (`dynamic_update_slice` clamps its start near the end of the sequence,
+    so an unmasked block write would smear garbage lanes over valid earlier
+    positions). The lanes are moved by a 0/1 matrix: exact, one product of
+    1 a lane."""
+    from ray_tpu.ops.rows_write import TILE, positions_last  # as above
+
+    G = c.shape[2]
+    last = positions_last(c.shape, val.shape[-1])
+    d, T = c.shape[3:] if last else c.shape[3:][::-1]
+    M = val.shape[0]
+    W = min(T, max(M, TILE))
+    start = jnp.clip(pos, 0, T - W)
+    hit = ((jnp.arange(W)[:, None] - (pos - start)) == jnp.arange(M)) & ok
+    moved = jnp.einsum("wm,mgd->gdw" if last else "wm,mgd->gwd",
+                       hit.astype(val.dtype), val,
+                       precision=lax.Precision.HIGHEST)
+    at = (l, slot, 0, 0, start) if last else (l, slot, 0, start, 0)
+    old = lax.dynamic_slice(c, at, (1, 1, G) + moved.shape[1:])
+    written = hit.any(axis=-1)
+    new = jnp.where(written if last else written[:, None], moved,
+                    old[0, 0])
+    return lax.dynamic_update_slice(c, new[None, None], at)

@@ -42,6 +42,13 @@ in is neither read nor written (the caller masks, as after
 rows in: once, unless its rows cross one of the R / `TILE_ROWS` - 1 tile
 boundaries. Within a tile the expert's rows are taken `SUB_ROWS` at a time
 with the weight tile resident, so an expert with many rows re-reads nothing.
+
+Which widths run the overhang branch (`F % tf` in the kernel: the last
+column tile hangs over the matrices' end and is masked): 768 (Keye, Kanana),
+one and a half tiles of 512, where the other choice is two steps too; not
+1,024 (Kimi, OLMoE), which 512 divides; and not 1,280 (Solar), for which
+`_column_tile` takes 640, two steps that compute no column twice where three
+of 512 would compute 1,536 for 1,280.
 """
 
 from __future__ import annotations
@@ -66,6 +73,9 @@ from jax.experimental.pallas import tpu as pltpu
 # tiles: 14.2 MB of weights, 4.7 of rows, 4.7 of output
 TILE_ROWS, SUB_ROWS, TILE_F = 256, 64, 512
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# what the weights' tiles may take of it, two buffers each of three matrices
+WEIGHT_TILES_BYTES = 40 * 1024 * 1024
+LANES = 128
 
 
 def _pieces(x, dtype, n: int):
@@ -162,11 +172,30 @@ def _plan(group_sizes, first_group, G: int, R: int, tm: int):
             jnp.minimum(of(ends) - tile * tm, tm)), upto[-1]
 
 
-def _tiles(R: int, F: int, tiles=None) -> tuple:
+def _column_tile(D: int, F: int, itemsize: int) -> int:
+    """Columns of F a grid step takes. `TILE_F`, the last tile hanging over
+    the matrices' end and masked, for a width of at most two tiles (768:
+    Keye's and Kanana's, two steps either way, and what the table above was
+    measured at; 1,024 divides). A wider F that `TILE_F` does not divide
+    (1,280 = 2.5 tiles: three steps would compute 1,536 columns, a fifth of
+    the work for nothing) takes the whole lane tiles nearest `TILE_F`, and
+    at least half of it, that divide F and whose weight tiles fit
+    `WEIGHT_TILES_BYTES`, and keeps the overhang where there are none (640
+    at d = 4,096: two steps, 31.5 MB of weights' buffers;
+    `benchmarks/expert_mlp_tiles.py --model solar` times it against 256, 128
+    and the overhang)."""
+    if F <= 2 * TILE_F or F % TILE_F == 0:
+        return min(TILE_F, F)
+    fits = [n for n in range(TILE_F // 2, F + 1, LANES) if F % n == 0
+            and 2 * 3 * D * n * itemsize <= WEIGHT_TILES_BYTES]
+    return min(fits, key=lambda n: (abs(n - TILE_F), -n)) if fits else TILE_F
+
+
+def _tiles(R: int, D: int, F: int, itemsize: int, tiles=None) -> tuple:
     """(rows a block, rows a product, columns of F a grid step), cut to the
     problem: a product's rows whole bf16 sublane tiles, a block whole
     products and within the rows where there are that many."""
-    tm, sub, tf = tiles or (TILE_ROWS, SUB_ROWS, TILE_F)
+    tm, sub, tf = tiles or (TILE_ROWS, SUB_ROWS, _column_tile(D, F, itemsize))
     sub = min(sub, -(-R // 16) * 16)
     tm = max(sub, min(tm, R) // sub * sub)
     return tm, sub, min(tf, F)
@@ -189,7 +218,7 @@ def expert_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     pieces = 1 if xs.dtype == wg.dtype else 2
     if pieces == 2 and xs.dtype != jnp.float32:
         raise ValueError(f"rows {xs.dtype} against matrices {wg.dtype}")
-    tm, sub, tf = _tiles(R, F, tiles)
+    tm, sub, tf = _tiles(R, D, F, wg.dtype.itemsize, tiles)
     x = xs if R >= tm else jnp.pad(xs, ((0, tm - R), (0, 0)))
     plan, visits = _plan(group_sizes, first_group, G, R, tm)
 

@@ -29,6 +29,13 @@ column, in float32: one pass of the MXU a head, no shuffle, nothing
 rounded. The rows come as `rows [slots, 8, H P]` (b v, b and k . q, a
 head's value over its P lanes).
 
+The head count is the leaf's: the kernel's loop over heads, the columns'
+operand (`H / 8` groups of 128 lanes) and the rows' width follow `state`'s
+shape, and nothing is written for one count (32 as Kimi publishes it, 64 as
+Solar does; a count that is no multiple of 8 pads its last group). `b` in
+(0, 2), Solar's write strength, is arithmetic outside the kernel, which
+takes `b v` and `b` as rows.
+
 `kda_update` takes the whole leaf and the layer to work on; the kernel
 aliases the state to its output, so under a jit that donates the cache
 nothing of the state's size is held beside it. A slot that is not active is
@@ -48,8 +55,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.pieces import pieces
 
-# a grid step takes one slot's state of the layer whole, 2 MB in one stretch
-# of HBM at the published sizes: its four buffers are 8 MiB of VMEM
+# a grid step takes one slot's state of the layer whole, the leaf's H heads
+# of 64 KB in one stretch of HBM, and holds four such buffers in VMEM (in and
+# out, two each): 2 MB a slot and 8 MiB at 32 heads (Kimi), 4 MB and 16 MiB
+# at 64 (Solar); the columns' operand and the rows are 0.3 MB more
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 STRIP = 8                    # sublanes of a float32 tile
 LANES = 128

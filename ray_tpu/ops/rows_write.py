@@ -11,9 +11,19 @@ PERF.md, PR 38); here it is one call a leaf a layer whose grid steps take a
 slot's tile of 128 positions each, picked by the position, and write it
 where they read it: the leaf is aliased to the output. A slot that is not
 `on` gets its tile back bit for bit.
+
+A head of 128 lanes is held the other way, `[layers, slots, G, T, d]`, a
+position a row of the head's lanes (`models/lm.py`, "grouped-head
+attention", has why): a slot's tile of 128 positions is then [G, 128, d]
+and one of its rows is written. Which way round a leaf lies is read off its
+shape against `val`'s d (`positions_last`). (One scatter for all
+slots, the other way to write a position a slot in one operation, made the
+compiler re-lay both leaves round every step, T before G: 2.1 GB each.)
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,43 +38,53 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _write_plain(c, layer, val, pos, on):
+def _write_plain(c, layer, val, pos, on, positions_last: bool):
     """The same in plain XLA (the CPU backend's path, and what the kernel is
     tested against): the whole layer blended."""
     old = lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)  # [B,G,d,T]
-    hit = (jnp.arange(c.shape[-1])[None, :] == pos[:, None]) & on[:, None]
-    new = jnp.where(hit[:, None, None, :], val[..., None], old)
+    axis = 3 if positions_last else 2
+    hit = (jnp.arange(old.shape[axis])[None, :] == pos[:, None]) & on[:, None]
+    hit = jnp.expand_dims(hit, (1, 2) if positions_last else (1, 3))
+    new = jnp.where(hit, jnp.expand_dims(val, axis), old)
     return lax.dynamic_update_index_in_dim(c, new, layer, 0)
 
 
-def _kernel(layer_ref, tile_ref, lane_ref, c_ref, val_ref, out_ref):
+def _kernel(layer_ref, tile_ref, lane_ref, c_ref, val_ref, out_ref, *,
+            axis: int):
     del layer_ref, tile_ref
     slot = pl.program_id(0)
-    old = c_ref[0, 0]                                         # [G, d, TILE]
-    lanes = lax.broadcasted_iota(jnp.int32, old.shape, 2)
+    old = c_ref[0, 0]                             # [G, d, TILE] | [G, TILE, d]
+    at = lax.broadcasted_iota(jnp.int32, old.shape, axis)
     # a slot that is not on has lane -1: nothing is picked
-    out_ref[0, 0] = jnp.where(lanes == lane_ref[slot],
+    out_ref[0, 0] = jnp.where(at == lane_ref[slot],
                               jnp.broadcast_to(val_ref[0], old.shape), old)
 
 
-def _write_kernel(c, layer, val, pos, on, interpret: bool):
-    L, B, G, d, T = c.shape
+def _write_kernel(c, layer, val, pos, on, interpret: bool,
+                  positions_last: bool):
+    L, B, G = c.shape[:3]
+    d, T = c.shape[3:] if positions_last else c.shape[3:][::-1]
     assert T % TILE == 0, T
 
     def tile(slot, layer, tiles, lanes):
-        return layer[0], slot, 0, 0, tiles[slot]
+        if positions_last:
+            return layer[0], slot, 0, 0, tiles[slot]
+        return layer[0], slot, 0, tiles[slot], 0
 
     def own(slot, layer, tiles, lanes):
         return slot, 0, 0, 0
 
+    window = (1, 1, G, d, TILE) if positions_last else (1, 1, G, TILE, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B,),
-        in_specs=[pl.BlockSpec((1, 1, G, d, TILE), tile),
-                  pl.BlockSpec((1, G, d, 1), own)],
-        out_specs=pl.BlockSpec((1, 1, G, d, TILE), tile))
+        in_specs=[pl.BlockSpec(window, tile),
+                  pl.BlockSpec((1, G, d, 1) if positions_last
+                               else (1, G, 1, d), own)],
+        out_specs=pl.BlockSpec(window, tile))
     pos = jnp.clip(pos, 0, T - 1)
     return pl.pallas_call(
-        _kernel, grid_spec=grid_spec,
+        functools.partial(_kernel, axis=2 if positions_last else 1),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
         # operands count the three prefetched scalars: the leaf is written
         # where it is read
@@ -73,17 +93,28 @@ def _write_kernel(c, layer, val, pos, on, interpret: bool):
             dimension_semantics=("arbitrary",)),
         name="rows_write", interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos // TILE,
-      jnp.where(on, pos % TILE, -1), c, val[..., None])
+      jnp.where(on, pos % TILE, -1), c,
+      jnp.expand_dims(val, 3 if positions_last else 2))
+
+
+def positions_last(rows_shape, d: int) -> bool:
+    """Whether rows [..., d, T] (True) or [..., T, d] hold a head of d
+    lanes: the two cannot be told apart where T is d."""
+    assert rows_shape[-1] != rows_shape[-2], rows_shape
+    assert d in rows_shape[-2:], (rows_shape, d)
+    return rows_shape[-2] == d
 
 
 def rows_write(c: jax.Array, layer, val, pos, on, *,
                kernel: bool | None = None, interpret: bool = False):
-    """Layer `layer` of the leaf c [L, B, G, d, T] takes val [B, G, d] at
-    position pos[b] of every slot that is `on` [B]; nothing else changes.
+    """Layer `layer` of the leaf c [L, B, G, d, T] (or [L, B, G, T, d])
+    takes val [B, G, d] at position pos[b] of every slot that is `on` [B];
+    nothing else changes.
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which writes the leaf in place; elsewhere through plain XLA."""
+    last = positions_last(c.shape, val.shape[-1])
     if kernel is None:
         kernel = interpret or _on_tpu()
     if kernel:
-        return _write_kernel(c, layer, val, pos, on, interpret)
-    return _write_plain(c, layer, val, pos, on)
+        return _write_kernel(c, layer, val, pos, on, interpret, last)
+    return _write_plain(c, layer, val, pos, on, last)

@@ -518,8 +518,14 @@ def test_the_family_refuses_what_cannot_carry_its_cache_by_name():
 # ------------------------------------------------------------------ engine
 
 def test_the_presets_name_picks_the_module():
+    # the module's presets are two families' (`models/solar.py` is this
+    # module under another word on the cache's leaves): the word picks
     for preset in kimi.PRESETS:
-        assert serving_family(preset) == ("kimi", kimi, kimi.KimiConfig)
+        word = preset.split("-")[0]
+        module = importlib.import_module(f"ray_tpu.models.{word}")
+        assert serving_family(preset) == (word, module, kimi.KimiConfig)
+        assert module.decode_step is kimi.decode_step
+    assert {p.split("-")[0] for p in kimi.PRESETS} == {"kimi", "solar"}
     for name in ("init_params", "resident_params", "resident_specs",
                  "init_cache", "decode_step", "prefill_chunk",
                  "CACHE_TOKEN_AXIS", "CACHE_STATE", "COUNTS"):

@@ -546,6 +546,75 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         "kda_layer_copies": [], "expert_matrix_copies": []}
 
 
+# The cell `serve-solar-longctx`'s two programs (PR 49), from
+# rehearse/compile_solar_for_v5e.py: what the configuration file's `memory`
+# gives, with `==`
+SOLAR_DECODE_BYTES = 12_181_509_632
+SOLAR_DECODE_TEMP_BYTES = 814_899_712
+SOLAR_CHUNK_BYTES = 12_353_619_968
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_solar_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-solar-longctx`'s two programs, as its configuration
+    file has them (Solar-Open2-250B's widths, one period of layers with 40 of
+    320 experts a layer and an eighth of the vocabulary, 40 slots of 13.5 MB
+    of float32 state and 25,600 positions of keys and values by head, chunks
+    of 128): the bytes the file gives, with `==`, and room for the pool of
+    both kinds beside the larger, between 70% and 95% of the chip; the Pallas
+    kernels (the two `rows_write` of the softmax layer's body, the delta
+    rule's update at 64 heads in the KDA body, one `expert_mlp` in each of
+    the two bodies: 5 in the decode program, and the further lanes' two more
+    `expert_mlp` in the chunk program); no float32 scores of one slot's 8 x
+    128 queries over all 25,600 positions (the further lanes attend a block
+    at a time); no instruction copies a cache leaf (`k` and `v` are held
+    [.., 8, 25600, 128]: with the positions last the compiler re-laid both
+    round every chunk step, and under one scatter for all slots' positions
+    round every decode step, 2.1 GB each) or materialises one layer's state
+    for all slots or an expert matrix."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_solar_for_v5e import (CONFIG, cache_bytes, compile_step,
+                                       made_of, pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    chunk = str(config["deployment"]["prefill_chunk_size"])
+    if program == "decode":
+        assert sized["total"] == SOLAR_DECODE_BYTES \
+            == memory["decode_step_bytes"]
+        assert sized["temp"] == SOLAR_DECODE_TEMP_BYTES \
+            == memory["decode_step_temp_bytes"]
+    else:
+        assert sized["total"] == SOLAR_CHUNK_BYTES == memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk]
+        assert sized["temp"] < 2 ** 30
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 40 * 128 * 4)      # the chunk's tokens
+    assert cache_bytes(config) == {
+        "state_bytes_per_slot": memory["state_bytes_per_slot"],
+        "kv_bytes_per_token": memory["kv_bytes_per_token"]} == {
+        "state_bytes_per_slot": 13_467_648, "kv_bytes_per_token": 4096}
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert 0.70 * HBM_BYTES <= SOLAR_CHUNK_BYTES + pool_bytes(config) \
+        <= 0.95 * HBM_BYTES
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in calls) == (2 if program == "decode" else 4)
+    assert sum("/kv_update/" in c and "rows_write" in c for c in calls) == 2
+    assert sum("/kda_update/" in c for c in calls) == 1
+    assert made_of(hlo, config) == {
+        "kernels": 5 if program == "decode" else 7, "whole_slot_scores": [],
+        "leaf_copies": {},
+        "kda_layer_copies": [], "expert_matrix_copies": []}
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_keye_serving_programs_compile_at_the_configurations_sizes(
         chips, as_on_tpu, program):
@@ -636,7 +705,8 @@ def _pools_programs(config: dict, chips):
 @pytest.mark.parametrize("config_name,apart", [
     ("gpt2-xl-serve-1chip", True),
     ("kanana-2-30b-a3b-serve-1chip", False),
-    ("keye-vl-2.0-30b-a3b-serve-1chip", False)])
+    ("keye-vl-2.0-30b-a3b-serve-1chip", False),
+    ("solar-open2-250b-serve-1chip", False)])
 def test_the_prefix_pools_programs_move_blocks_in_place(chips, config_name,
                                                         apart):
     """`serve/kv_cache.py`'s two programs a rows leaf, at the three
@@ -693,9 +763,9 @@ def test_the_prefix_pools_programs_move_blocks_in_place(chips, config_name,
 
 @pytest.mark.parametrize("rows,F,tiles", [
     (1024, 1024, None), (1016, 1024, None), (1024, 1024, (128, 32, 256)),
-    (1024, 1280, None)],
+    (1024, 1280, (256, 64, 512)), (1024, 1280, None)],
     ids=["kimi", "rows-that-end-inside-a-block", "smaller-tiles",
-         "F-that-ends-inside-a-tile"])
+         "F-that-ends-inside-a-tile", "F-in-two-tiles-of-640"])
 def test_expert_mlp_kernel_reads_the_stack_where_it_lies(chips, rows, F,
                                                          tiles):
     """`ops/expert_mlp.py` alone at Kimi's widths, rows and stack (float32
@@ -714,6 +784,27 @@ def test_expert_mlp_kernel_reads_the_stack_where_it_lies(chips, rows, F,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = jax.jit(lambda *a: op.expert_mlp(*a, tiles=tiles)).lower(
+        arr((rows, D), jnp.float32), arr((G, D, F)), arr((G, D, F)),
+        arr((G, F, D)), arr((G + 1,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_expert_mlp_kernel_at_solars_widths(chips):
+    """d = 4,096 and F = 1,280 with the stack of 4 x 40 held experts and a
+    decode step's 320 rows: the column tile is 640 (two steps, no overhang),
+    its three matrices' double buffers 31.5 MB of VMEM, and Mosaic takes
+    them beside the rows' and the output's blocks."""
+    op = importlib.import_module("ray_tpu.ops.expert_mlp")
+    one = SingleDeviceSharding(chips[0])
+    D, F, G, rows = 4096, 1280, 160, 320
+    assert op._tiles(rows, D, F, 2) == (256, 64, 640)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(op.expert_mlp).lower(
         arr((rows, D), jnp.float32), arr((G, D, F)), arr((G, D, F)),
         arr((G, F, D)), arr((G + 1,), jnp.int32),
         arr((), jnp.int32)).compile()
