@@ -2,11 +2,13 @@
 (`utils.platform.enable_compile_cache`): `JAX_COMPILATION_CACHE_DIR`
 verbatim when the environment sets it, else one fixed path inside the
 checkout that every process agrees on — never a temp name, pid or time.
+And the one cache that a run of these tests has for itself (conftest.py).
 """
 
 import os
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -135,3 +137,30 @@ def test_a_program_reached_by_other_callers_is_found_in_the_cache(
         assert out.returncode == 0, out.stderr[-2000:]
         counts.append(int(out.stdout.strip().splitlines()[-1]))
     assert counts[0] > 0 and counts[1] == counts[0]
+
+
+def test_a_run_of_the_tests_compiles_a_program_once():
+    """conftest.py gives a run of the tests one cache of its own, under the
+    temporary directory and not in the checkout: a program that a second
+    test (here, a second function object) builds again is read from it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.utils.platform import watch_compiles
+
+    run_cache = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    assert jax.config.jax_compilation_cache_dir == run_cache
+    assert os.path.dirname(run_cache) == tempfile.gettempdir()
+    assert run_cache != IN_CHECKOUT
+
+    def built_anew():
+        def once_a_run(x):      # no other test's program: 23 x 29, PR 50
+            return jnp.tanh(x @ x.T).sum() * 50.0
+
+        return jax.jit(once_a_run)
+
+    watch, said = watch_compiles(), []
+    for _ in range(2):
+        built_anew()(jnp.ones((23, 29))).block_until_ready()
+        said.append((watch.last["fun"], watch.last["cache"]))
+    assert said == [("once_a_run", "miss"), ("once_a_run", "hit")]

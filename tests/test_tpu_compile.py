@@ -5,9 +5,9 @@ Nothing runs here, so nothing is said about results or times: a pass means
 the chip's compiler accepts the program (tiling, VMEM, partitioning,
 per-device memory), which interpret mode and the CPU backend cannot tell.
 Code that asks `jax.default_backend()` sees the CPU in this process, so the
-tests steer it onto its TPU branch themselves. conftest.py keeps the
-persistent compilation cache off: an executable compiled for a described
-chip cannot be read back without one.
+tests steer it onto its TPU branch themselves. The module keeps the run's
+persistent compilation cache (conftest.py) off while its tests run: an
+executable compiled for a described chip cannot be read back without one.
 """
 
 import importlib
@@ -35,12 +35,23 @@ HBM_BYTES = 16909336064        # a v5e chip's memory_stats()["bytes_limit"]
 @pytest.fixture(scope="module")
 def chips():
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        return topologies.get_topology_desc(
+        devices = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:  # noqa: BLE001 - no libtpu, or it cannot describe
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    # a program this module compiles twice would be found in the cache, fail
+    # to load ("DeserializeLoadedExecutable not implemented") and compile
+    # again, and every full-size executable would be written out for nothing.
+    # JAX asks once a process whether it uses the cache: make it ask again.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture
