@@ -610,11 +610,14 @@ def phase_serve(args, result: dict) -> None:
         worker_devices_are_tpu("serve", "replica", replica()["devices"], 1)
         entries = cache_entries()
 
+        def ttft_sum():
+            return replica()["ttft_s"]["sum"]
+
         first = prompt_text(SEED, 0, SERVE_FIRST_LEN)
         cold = post_completion(port, first)
-        cold_ttft = replica()["last_ttft_s"]
+        cold_ttft = ttft_sum()
         again = post_completion(port, first)
-        warm_ttft = replica()["last_ttft_s"]
+        warm_ttft = ttft_sum() - cold_ttft
         burst = post_all(port, [prompt_text(SEED, 1 + i, n)
                                 for i, n in enumerate(SERVE_BURST_LENS)])
         replies = [cold, again] + burst
@@ -639,7 +642,7 @@ def phase_serve(args, result: dict) -> None:
         say("serve", f"burst ({len(burst)} prompts at once): wall "
                      f"{max(r['wall_s'] for r in burst):.2f}s for the "
                      f"slowest, engine mean time to first token "
-                     f"{stats['ttft_avg_s']:.3f}s over all 8")
+                     f"{stats['ttft_s']['sum'] / 8:.3f}s over all 8")
         say("serve", f"engine: {stats['engine_steps']} steps, "
                      f"{stats['chunk_steps']} chunked, "
                      f"{stats['tokens_prefilled']} prompt tokens prefilled, "

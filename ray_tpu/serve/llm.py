@@ -21,21 +21,29 @@ import queue
 import threading
 import time
 import uuid
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu.util import tracing
 
-# What the engine's thread does in one pass of its loop. Each is an
-# `engine.<phase>` span on the profiler's clock and a cumulative timer in
-# `engine_stats()["phase_s"]`. The loop runs one step ahead of what it has
-# read: `dispatch` launches a step program and `sample` the selection of
-# its tokens on the device; `fetch` is the wait for the ids of the step
-# dispatched a pass earlier, `notify` and `publish` what follows from them.
-ENGINE_PHASES = ("calls", "admit", "plan", "dispatch", "fetch", "sample",
-                 "publish", "notify", "empty")
+# What the engine's thread does in one pass of its loop, and it is always in
+# exactly one of them. Each is an `engine.<phase>` span on the profiler's
+# clock and two cumulative timers, wall and CPU seconds, in `engine_stats()`'s
+# `phase_s` and `phase_cpu_s`. The loop runs one step ahead of what it has
+# read: `put` hands the step's host arrays to the device, `dispatch` launches
+# a step program and `sample` the selection of its tokens on the device;
+# `fetch` is the wait for the ids of the step dispatched a pass earlier,
+# `notify` and `publish` what follows from them; `release` is the pass's
+# tail, where the step before's arrays go, up to the next pass's `calls`.
+ENGINE_PHASES = ("calls", "admit", "plan", "put", "dispatch", "fetch",
+                 "sample", "publish", "notify", "empty", "release")
+# a pass of the loop longer than this is counted and kept by name
+# (`engine_stats()["slow_passes"]`): no client's log holds a gap between two
+# tokens this long unless the engine stalled, and the longest step program
+# of any preset (a 180 ms chunk step) stays under it
+SLOW_PASS_S = 0.25
 # seconds; shared by the two request-lifecycle histograms
 _LIFECYCLE_BOUNDARIES = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                          0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
@@ -62,24 +70,41 @@ def _get_lifecycle_metrics() -> dict:
     return _lifecycle_metrics
 
 
-class _Phase:
-    """One engine phase as a context: an `engine.<name>` annotation around
-    a cumulative `perf_counter` timer. Phases never nest and only the
-    engine's thread enters them, so an engine reuses one object a phase."""
+class _Phases:
+    """The engine thread's lap timer. `to(name)` ends the phase the thread
+    is in and opens `name`: one reading of each clock a boundary, charged to
+    the phase that ends there, so the phases' wall seconds sum to the
+    loop's. `cpu` is fed from `thread_time()` at the same boundaries: a
+    phase's wall less its CPU seconds is the time the thread was in it and
+    not running (the device's in `fetch`, the sleep's in `empty`, anywhere
+    else the interpreter lock's or the scheduler's). Each phase is an
+    `engine.<name>` annotation on the profiler's clock, closed before the
+    next opens. Only the engine's thread calls `start` and `to`."""
 
-    __slots__ = ("_acc", "_name", "_label", "_ann", "_t0")
+    __slots__ = ("wall", "cpu", "t", "c", "_labels", "_name", "_ann")
 
-    def __init__(self, acc: Dict[str, float], name: str):
-        self._acc, self._name, self._label = acc, name, f"engine.{name}"
+    def __init__(self):
+        self.wall: Dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
+        self.cpu: Dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
+        self._labels = {n: f"engine.{n}" for n in ENGINE_PHASES}
+        self.t = self.c = 0.0      # the clocks at the newest boundary
 
-    def __enter__(self):
-        self._ann = tracing.annotate(self._label)
+    def start(self, name: str) -> None:
+        self._name = name
+        self._ann = tracing.annotate(self._labels[name])
         self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self.t, self.c = time.perf_counter(), time.thread_time()
 
-    def __exit__(self, *exc):
-        self._acc[self._name] += time.perf_counter() - self._t0
-        self._ann.__exit__(*exc)
+    def to(self, name: str) -> float:
+        t, c = time.perf_counter(), time.thread_time()
+        self._ann.__exit__(None, None, None)
+        was = self._name
+        self.wall[was] += t - self.t
+        self.cpu[was] += c - self.c
+        self.t, self.c, self._name = t, c, name
+        self._ann = tracing.annotate(self._labels[name])
+        self._ann.__enter__()
+        return t
 
 
 class ByteTokenizer:
@@ -537,11 +562,13 @@ class LLMEngine:
         self.slots_reset = 0           # slots zeroed for a new sequence
         self.snapshots_pooled = 0      # states copied into the pool
         self.snapshot_hits = 0         # requests that started from one
-        self.last_ttft_s = 0.0         # submit -> first generated token
         # cumulative, so a reader takes deltas over its own window
-        self.phase_s: Dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
-        self._phase = {n: _Phase(self.phase_s, n) for n in ENGINE_PHASES}
+        self._phases = _Phases()
         self.loop_busy_s = 0.0         # loop passes that ran a step
+        # passes longer than SLOW_PASS_S: how many, their seconds, and the
+        # newest sixteen by name (`_keep_slow_pass`)
+        self.slow_passes = {"count": 0, "seconds": 0.0}
+        self._slow_passes_kept: deque = deque(maxlen=16)
         # request lifecycle: observations and their sum, in seconds
         self._stats_lock = threading.Lock()
         self.lifecycle = {"queue_wait_s": {"count": 0, "sum": 0.0},
@@ -866,42 +893,81 @@ class LLMEngine:
         and dispatches step n+1 from what the host already knows (which
         slots are live, their positions, which end by length), and only
         then reads step n's ids. The tokens themselves stay on the device
-        (`self._ids`), so nothing the next step needs waits for the host."""
-        last_sweep = time.time()
-        phase = self._phase
+        (`self._ids`), so nothing the next step needs waits for the host.
+
+        A pass runs from one opening of `calls` to the next, and every
+        moment of it lies in one phase (`_Phases`)."""
+        phases = self._phases
+        phases.start("release")
+        last_sweep = t_pass = phases.t
         unread = None         # the step dispatched and not read yet
-        t_pass = time.perf_counter()
+        busy = False          # the pass ran or read a step
+        mark = self._pass_mark()
         while not self._stop.is_set():
-            if time.time() - last_sweep > 60:
-                last_sweep = time.time()
-                self._sweep_streams()
+            now = phases.to("calls")
+            took = now - t_pass       # the pass that ended at this reading
+            if busy:
+                self.loop_busy_s += took
+            if took > SLOW_PASS_S:
+                self._keep_slow_pass(took, mark)
+            t_pass, mark = now, self._pass_mark()
             # marshalled work (KV exports) runs between steps: the pool
             # can't mutate under an export that shares this thread
-            with phase["calls"]:
-                for _ in range(8):
-                    try:
-                        fn = self._engine_calls.get_nowait()
-                    except queue.Empty:
-                        break
-                    try:
-                        fn()
-                    except Exception:
-                        pass
-            with phase["admit"]:
-                self._admit()
+            for _ in range(8):
+                try:
+                    fn = self._engine_calls.get_nowait()
+                except queue.Empty:
+                    break
+                try:
+                    fn()
+                except Exception:
+                    pass
+            phases.to("admit")
+            self._admit()
             step = self._dispatch_step()
             if unread is not None:
                 if step is not None:
                     self.steps_dispatched_ahead += 1
                 self._read_step(*unread)
             elif step is None:
-                with phase["empty"]:
-                    time.sleep(0.005)
-            now = time.perf_counter()
-            if step is not None or unread is not None:
-                self.loop_busy_s += now - t_pass
-            t_pass = now
+                phases.to("empty")
+                time.sleep(0.005)
+            # the pass's tail: `unread = step` lets the step before's
+            # arrays go, and the loop's turn is inside the phase too
+            now = phases.to("release")
+            if now - last_sweep > 60:
+                last_sweep = now
+                self._sweep_streams()
+            busy = step is not None or unread is not None
             unread = step
+
+    def _pass_mark(self) -> tuple:
+        """What a pass keeps of its start so that `_keep_slow_pass` can say
+        what moved during it: the phases' wall seconds, the thread's CPU
+        clock, and four counts."""
+        return (tuple(self._phases.wall.values()), self._phases.c,
+                self._compile_watch.count,
+                self.lifecycle["queue_wait_s"]["count"], self.chunk_steps)
+
+    def _keep_slow_pass(self, wall_s: float, mark: tuple) -> None:
+        """The pass that just ended took longer than SLOW_PASS_S: count it
+        and keep its record among the newest. `t_end` is on `time.time()`,
+        the clock of a client's log, so a gap in which no client got a
+        token can be laid beside it; `step` is `engine_steps` at its end,
+        so a reader keeps those of its own window."""
+        wall0, cpu0, compiles0, admitted0, chunk_steps0 = mark
+        phases = self._phases
+        self.slow_passes["count"] += 1
+        self.slow_passes["seconds"] += wall_s
+        self._slow_passes_kept.append({
+            "step": self.engine_steps, "t_end": time.time(),
+            "wall_s": wall_s, "cpu_s": phases.c - cpu0,
+            "phases": {k: v - v0 for (k, v), v0
+                       in zip(phases.wall.items(), wall0) if v - v0 > 0.001},
+            "live": sum(r is not None for r in self._slots),
+            "admitted": self.lifecycle["queue_wait_s"]["count"] - admitted0,
+            "chunked": self.chunk_steps > chunk_steps0,
+            "compiles": self._compile_watch.count - compiles0})
 
     def _dispatch_step(self):
         """Plan and dispatch one step for every live slot, then the
@@ -917,100 +983,105 @@ class LLMEngine:
         of the chunked program (decode lanes reserved first, one token
         each; prefilling lanes up to a chunk of their prompt), else one
         single-token step of the decode program."""
-        jnp, phase = self.jnp, self._phase
+        jnp, phases = self.jnp, self._phases
         B, C = self.max_batch, self.prefill_chunk_size
-        with phase["plan"]:
-            live = [i for i, r in enumerate(self._slots) if r is not None]
-            if not live:
-                return None
-            pos = np.asarray(self._slot_pos, np.int32)
-            decoding = np.zeros((B,), bool)
+        phases.to("plan")
+        live = [i for i, r in enumerate(self._slots) if r is not None]
+        if not live:
+            return None
+        pos = np.asarray(self._slot_pos, np.int32)
+        decoding = np.zeros((B,), bool)
+        for i in live:
+            decoding[i] = not self._slot_prefill[i]
+        chunked = not decoding[live].all()
+        if chunked:
+            pending = [len(self._slot_prefill[i])
+                       if self._slots[i] is not None else 0
+                       for i in range(B)]
+            takes = plan_chunk_budget(pending, list(decoding), C,
+                                      self.max_num_batched_tokens)
+            tokens = np.zeros((B, C), np.int32)
+            lengths = np.zeros((B,), np.int32)
             for i in live:
-                decoding[i] = not self._slot_prefill[i]
-            chunked = not decoding[live].all()
-            if chunked:
-                pending = [len(self._slot_prefill[i])
-                           if self._slots[i] is not None else 0
-                           for i in range(B)]
-                takes = plan_chunk_budget(pending, list(decoding), C,
-                                          self.max_num_batched_tokens)
-                tokens = np.zeros((B, C), np.int32)
-                lengths = np.zeros((B,), np.int32)
-                for i in live:
-                    # never step past the serving window (prefill_chunk
-                    # requires pos0 + length <= T; _make_request already
-                    # bounds prompts)
-                    take = min(takes[i], self.max_seq_len - self._slot_pos[i])
-                    # a chunk ends where the slot's state is to be pooled
-                    due = self._slot_snapshot_at[i] - self._slot_pos[i]
-                    if due > 0:
-                        take = min(take, due)
-                    if take <= 0:
-                        continue
-                    lengths[i] = take
-                    if not decoding[i]:
-                        tokens[i, :take] = self._slot_prefill[i][:take]
-                active = lengths > 0
-                self.chunk_tokens += int(lengths.sum())
-                self.chunk_prefilling_slots += int((lengths > 1).sum())
-            else:
-                lengths = active = decoding
-            lanes, prompts, last_prompts, snapshots = [], [], [], []
-            produce = np.zeros((B,), bool)
-            for i in live:
-                take = int(lengths[i])
+                # never step past the serving window (prefill_chunk
+                # requires pos0 + length <= T; _make_request already
+                # bounds prompts)
+                take = min(takes[i], self.max_seq_len - self._slot_pos[i])
+                # a chunk ends where the slot's state is to be pooled
+                due = self._slot_snapshot_at[i] - self._slot_pos[i]
+                if due > 0:
+                    take = min(take, due)
                 if take <= 0:
                     continue
-                req = self._slots[i]
-                self._slot_pos[i] += take
-                self.positions_attended += self._slot_pos[i]
-                if self._slot_pos[i] == self._slot_snapshot_at[i]:
-                    snapshots.append((req.prompt_ids[:self._slot_pos[i]], i))
-                    self._slot_snapshot_at[i] = 0
+                lengths[i] = take
                 if not decoding[i]:
-                    del self._slot_prefill[i][:take]
-                    self.tokens_prefilled += take
-                    if self._slot_prefill[i]:
-                        continue  # chunk didn't cover the prompt yet
-                # the chunk ends at the prompt's final token (or this is a
-                # decode lane): its last-position logits are a token's
-                produce[i] = True
-                req.scheduled += 1
-                ends = (req.scheduled >= req.max_tokens
-                        or self._slot_pos[i] >= self.max_seq_len - 1)
-                if ends:
-                    self._slots[i] = None
-                if not decoding[i]:
-                    # pooled while the request holds the slot: when its
-                    # first token is read, or here if this step is its last
-                    (last_prompts if ends else prompts).append(
-                        (req.prompt_ids, i))
-                lanes.append((i, req, ends))
-        with phase["dispatch"]:
-            if chunked:
-                logits, self.cache = self._chunk_step(
-                    self.params, self.cache,
-                    self._merge(tokens, self._ids, decoding),
-                    jnp.asarray(pos), jnp.asarray(lengths),
-                    jnp.asarray(active))
-                self.chunk_steps += 1
-            else:
-                logits, self.cache = self._step(
-                    self.params, self.cache, self._ids, jnp.asarray(pos),
-                    jnp.asarray(active))
-        with phase["sample"]:
-            self._ids = self._select(
-                logits, self._ids, produce, self._sampling,
-                np.uint32(self.engine_steps), self._key)
+                    tokens[i, :take] = self._slot_prefill[i][:take]
+            active = lengths > 0
+            self.chunk_tokens += int(lengths.sum())
+            self.chunk_prefilling_slots += int((lengths > 1).sum())
+        else:
+            lengths = active = decoding
+        lanes, prompts, last_prompts, snapshots = [], [], [], []
+        produce = np.zeros((B,), bool)
+        for i in live:
+            take = int(lengths[i])
+            if take <= 0:
+                continue
+            req = self._slots[i]
+            self._slot_pos[i] += take
+            self.positions_attended += self._slot_pos[i]
+            if self._slot_pos[i] == self._slot_snapshot_at[i]:
+                snapshots.append((req.prompt_ids[:self._slot_pos[i]], i))
+                self._slot_snapshot_at[i] = 0
+            if not decoding[i]:
+                del self._slot_prefill[i][:take]
+                self.tokens_prefilled += take
+                if self._slot_prefill[i]:
+                    continue  # chunk didn't cover the prompt yet
+            # the chunk ends at the prompt's final token (or this is a
+            # decode lane): its last-position logits are a token's
+            produce[i] = True
+            req.scheduled += 1
+            ends = (req.scheduled >= req.max_tokens
+                    or self._slot_pos[i] >= self.max_seq_len - 1)
+            if ends:
+                self._slots[i] = None
+            if not decoding[i]:
+                # pooled while the request holds the slot: when its
+                # first token is read, or here if this step is its last
+                (last_prompts if ends else prompts).append(
+                    (req.prompt_ids, i))
+            lanes.append((i, req, ends))
+        # the step's own host arrays go over under their own phase, in the
+        # order the programs take them; `sample`'s ride its call
+        phases.to("put")
+        pos = jnp.asarray(pos)
+        if chunked:
+            lengths = jnp.asarray(lengths)
+        active = jnp.asarray(active)
+        phases.to("dispatch")
+        if chunked:
+            logits, self.cache = self._chunk_step(
+                self.params, self.cache,
+                self._merge(tokens, self._ids, decoding), pos, lengths,
+                active)
+            self.chunk_steps += 1
+        else:
+            logits, self.cache = self._step(
+                self.params, self.cache, self._ids, pos, active)
+        phases.to("sample")
+        self._ids = self._select(
+            logits, self._ids, produce, self._sampling,
+            np.uint32(self.engine_steps), self._key)
         self.engine_steps += 1
         if snapshots:
             # behind the chunk step that brought each slot to its boundary
             # and ahead of the next, which moves the state on: the device
             # keeps that order, and the state exists at no other time
-            with phase["publish"]:
-                for ids, i in snapshots:
-                    self.snapshots_pooled += self.kv.store_prefix(
-                        ids, self.cache, i)
+            phases.to("publish")
+            for ids, i in snapshots:
+                self.snapshots_pooled += self.kv.store_prefix(
+                    ids, self.cache, i)
         self._pool_prompts(last_prompts)
         return self._ids, lanes, prompts
 
@@ -1022,9 +1093,9 @@ class LLMEngine:
         with it the rows up to the snapshot's boundary, between two chunk
         steps (rows past a snapshot are no hit, so none are pooled here)."""
         if prompts and self.kv is not None and not self._state_leaves:
-            with self._phase["publish"]:
-                for prompt_ids, i in prompts:
-                    self.kv.store_prefix(prompt_ids, self.cache, i)
+            self._phases.to("publish")
+            for prompt_ids, i in prompts:
+                self.kv.store_prefix(prompt_ids, self.cache, i)
 
     def _read_step(self, ids, lanes, prompts):
         """Read a dispatched step's ids (the wait for the device is here),
@@ -1036,31 +1107,30 @@ class LLMEngine:
         copies into the pool are tens of programs: dispatched with the
         step they would hold the host, and the device, between the first
         token and its reader. Each request still holds its slot here."""
-        with self._phase["fetch"]:
-            ids = np.asarray(ids)
-        with self._phase["notify"]:
-            for i, req, ends in lanes:
-                if req.done.is_set():
-                    self.overrun_lane_steps += 1
-                    continue
-                nxt = int(ids[i])
-                if req.t_first is None:
-                    req.t_first = time.time()
-                    self.last_ttft_s = req.t_first - req.t_enqueue
-                    self._observe("ttft_s", self.last_ttft_s)
-                req.generated.append(nxt)
-                self.total_generated += 1
-                stop = nxt == self.tokenizer.eos_id
-                if stop or ends:
-                    req.finish_reason = "stop" if stop else "length"
-                    if self._slots[i] is req:
-                        self._slots[i] = None
-                    req.t_done = time.time()
-                    if req.trace_carrier is not None:
-                        self._record_request_spans(req)
-                    req.done.set()
-                with req.progress:
-                    req.progress.notify_all()
+        self._phases.to("fetch")
+        ids = np.asarray(ids)
+        self._phases.to("notify")
+        for i, req, ends in lanes:
+            if req.done.is_set():
+                self.overrun_lane_steps += 1
+                continue
+            nxt = int(ids[i])
+            if req.t_first is None:
+                req.t_first = time.time()
+                self._observe("ttft_s", req.t_first - req.t_enqueue)
+            req.generated.append(nxt)
+            self.total_generated += 1
+            stop = nxt == self.tokenizer.eos_id
+            if stop or ends:
+                req.finish_reason = "stop" if stop else "length"
+                if self._slots[i] is req:
+                    self._slots[i] = None
+                req.t_done = time.time()
+                if req.trace_carrier is not None:
+                    self._record_request_spans(req)
+                req.done.set()
+            with req.progress:
+                req.progress.notify_all()
         self._pool_prompts(prompts)
 
     @staticmethod
@@ -1129,7 +1199,6 @@ class LLMEngine:
         with self._stats_lock:
             queue_wait = dict(self.lifecycle["queue_wait_s"])
             ttft = dict(self.lifecycle["ttft_s"])
-        ttft_avg = ttft["sum"] / ttft["count"] if ttft["count"] else 0.0
         # the gauges of the cache's kinds: bytes a token, bytes a slot
         kind = {}
         if self.kv_bytes_per_token or not self._state_leaves:
@@ -1144,7 +1213,7 @@ class LLMEngine:
             # prefilled again because no snapshot stood at their boundary
             kind["rows_without_snapshot_tokens"] = \
                 self.kv.rows_without_snapshot_tokens
-        watch = self._compile_watch
+        watch, phases = self._compile_watch, self._phases
         return {**self._device_counters(), **kind,
                 # what this engine's process runs JAX on
                 "devices": device_report(),
@@ -1166,11 +1235,19 @@ class LLMEngine:
                 "prefix_blocks_imported": self.prefix_blocks_imported,
                 "prefix_wait_timeouts": self.prefix_wait_timeouts,
                 "deferred": len(self._deferred),
-                "ttft_avg_s": round(ttft_avg, 6),
-                "last_ttft_s": round(self.last_ttft_s, 6),
-                # cumulative since the engine started: read deltas
-                "phase_s": dict(self.phase_s),
+                # cumulative since the engine started: read deltas. A
+                # phase's wall less its CPU seconds is the time the thread
+                # was in it and not running
+                "phase_s": dict(phases.wall),
+                "phase_cpu_s": dict(phases.cpu),
                 "loop_busy_s": self.loop_busy_s,
+                # CPU seconds, the engine's thread's at its newest phase
+                # boundary and the whole process's now: the difference's
+                # growth is what the replica's other threads burnt
+                "cpu_s": {"engine_thread": phases.c,
+                          "process": time.process_time()},
+                "slow_passes": {**self.slow_passes,
+                                "newest": list(self._slow_passes_kept)},
                 "queue_wait_s": queue_wait,
                 "ttft_s": ttft}
 

@@ -6,6 +6,7 @@ import re
 import subprocess
 import sys
 import threading
+import time
 
 import pytest
 
@@ -100,16 +101,22 @@ def _generate(eng, n: int, **kw) -> None:
         assert not t.is_alive()
 
 
+def _grown(before, after, key):
+    return {k: after[key][k] - before[key][k] for k in after[key]}
+
+
 def test_engine_phase_timers_account_for_the_loops_busy_time(engine):
     from ray_tpu.serve.llm import ENGINE_PHASES
 
     _generate(engine, 1)        # the first request prepares the programs
-    before = engine.engine_stats()
-    _generate(engine, 6, temperature=0.7, top_p=0.9)   # more than slots
-    after = engine.engine_stats()
-    assert set(after["phase_s"]) == set(ENGINE_PHASES)
-    d = {k: after["phase_s"][k] - before["phase_s"][k]
-         for k in ENGINE_PHASES}
+    before, t0 = engine.engine_stats(), time.perf_counter()
+    for _ in range(3):          # more than slots, for a second or more
+        _generate(engine, 6, temperature=0.7, top_p=0.9)
+    after, t1 = engine.engine_stats(), time.perf_counter()
+    assert (set(after["phase_s"]) == set(after["phase_cpu_s"])
+            == set(ENGINE_PHASES))
+    d, cpu = (_grown(before, after, key)
+              for key in ("phase_s", "phase_cpu_s"))
     assert all(v >= 0 for v in d.values())
     # `dispatch` is the step program's launch and `fetch` the wait for its
     # [B] ids, a step late; `sample` is no longer the host choosing tokens
@@ -117,10 +124,184 @@ def test_engine_phase_timers_account_for_the_loops_busy_time(engine):
     # which is waited for in `fetch` with the step itself
     assert d["dispatch"] > 0 and d["fetch"] > 0
     assert 0 < d["sample"] < d["fetch"]
+    # the thread is always in one phase: together they are the loop's wall
+    # time, but for the lap that was open at either reading
+    assert sum(d.values()) == pytest.approx(t1 - t0, rel=0.01)
+    # a phase's CPU seconds are part of its wall seconds, but for the
+    # moment between a boundary's two clock readings
+    for k in ENGINE_PHASES:
+        assert 0 <= cpu[k] <= d[k] + 0.002, (k, cpu[k], d[k])
+    # waiting for the device and sleeping are not running
+    assert cpu["empty"] < 0.5 * d["empty"] or d["empty"] < 0.01
+    # the passes that ran a step, tails and all, and no other
     busy = after["loop_busy_s"] - before["loop_busy_s"]
-    phased = sum(v for k, v in d.items() if k != "empty")
-    assert busy > 0 and abs(phased - busy) <= 0.05 * busy, (phased, busy)
+    assert 0 < busy <= sum(d.values()) - d["empty"] + 1e-6
+    assert busy >= sum(d.values()) - 2 * d["empty"] - 0.05 * (t1 - t0)
     assert after["engine_steps"] > before["engine_steps"]
+
+
+def test_the_lap_timer_charges_a_reading_to_the_phase_that_ends_there():
+    from ray_tpu.serve.llm import ENGINE_PHASES, _Phases
+
+    laps = _Phases()
+    assert list(laps.wall) == list(laps.cpu) == list(ENGINE_PHASES)
+    t0 = time.perf_counter()
+    laps.start("calls")
+    c0 = laps.c
+    time.sleep(0.05)
+    at = laps.to("fetch")
+    _spin(0.05)
+    laps.to("release")
+    t1 = time.perf_counter()
+    assert at == pytest.approx(t0 + 0.05, abs=0.04) and laps.t <= t1
+    # asleep in the one, running in the other; `release` is still open
+    assert laps.wall["calls"] >= 0.05 > 0.01 > laps.cpu["calls"]
+    assert laps.wall["fetch"] >= laps.cpu["fetch"] - 0.002 >= 0.045
+    assert laps.wall["release"] == laps.cpu["release"] == 0.0
+    assert sum(laps.wall.values()) == pytest.approx(laps.t - t0, abs=0.001)
+    assert sum(laps.cpu.values()) == pytest.approx(laps.c - c0, abs=1e-6)
+
+
+def test_cpu_seconds_of_the_thread_and_the_process_are_read_at_the_call(
+        engine):
+    before = engine.engine_stats()
+    _generate(engine, 2)
+    after = engine.engine_stats()
+    cpu = _grown(before, after, "phase_cpu_s")
+    c0, c1 = before["cpu_s"], after["cpu_s"]
+    assert set(c1) == {"engine_thread", "process"}
+    # the thread's own clock at its newest boundary: what its phases sum to
+    thread = c1["engine_thread"] - c0["engine_thread"]
+    assert thread == pytest.approx(sum(cpu.values()), abs=0.02)
+    # and part of the whole process's, which other threads add to
+    assert 0 < thread <= c1["process"] - c0["process"] + 0.02
+
+
+def _slow_passes_since(engine, before):
+    """(how many more slow passes `count` says, the records kept since)."""
+    after = engine.engine_stats()["slow_passes"]
+    seen = {(r["step"], r["t_end"]) for r in before["newest"]}
+    return (after["count"] - before["count"],
+            after["seconds"] - before["seconds"],
+            [r for r in after["newest"]
+             if (r["step"], r["t_end"]) not in seen])
+
+
+def _spin(seconds: float) -> None:
+    # so much of this thread's own CPU time, however loaded the machine
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        pass
+
+
+@pytest.mark.parametrize("call,running", [
+    (lambda: time.sleep(0.4), False), (lambda: _spin(0.4), True)],
+    ids=["sleeps", "spins"])
+def test_a_slow_pass_is_kept_by_name_and_says_whether_the_thread_ran(
+        engine, call, running):
+    before = engine.engine_stats()["slow_passes"]
+    t0 = time.time()
+    engine._on_engine_thread(call, 30.0)
+    _generate(engine, 1)             # the pass has ended by now
+    count, seconds, kept = _slow_passes_since(engine, before)
+    (slow,) = [r for r in kept if r["phases"].get("calls", 0) >= 0.4]
+    assert count == len(kept) and seconds == pytest.approx(
+        sum(r["wall_s"] for r in kept))
+    assert set(slow) == {"step", "t_end", "wall_s", "cpu_s", "phases",
+                         "live", "admitted", "chunked", "compiles"}
+    assert slow["wall_s"] >= slow["phases"]["calls"] >= 0.4
+    assert sum(slow["phases"].values()) == pytest.approx(slow["wall_s"],
+                                                         abs=0.011)
+    if running:
+        assert slow["cpu_s"] >= 0.3
+    else:
+        assert slow["cpu_s"] < 0.1
+    # on the clock of a client's log, and nothing else happened in it
+    assert t0 + 0.4 <= slow["t_end"] <= time.time()
+    assert slow["step"] <= engine.engine_stats()["engine_steps"]
+    assert (slow["live"], slow["admitted"], slow["chunked"],
+            slow["compiles"]) == (0, 0, False, 0)
+
+
+def test_the_newest_sixteen_slow_passes_are_kept_and_all_are_counted(
+        engine, monkeypatch):
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(llm, "SLOW_PASS_S", 0.1)
+    before = engine.engine_stats()["slow_passes"]
+    started = []
+    for _ in range(20):
+        started.append(time.time())
+        engine._on_engine_thread(lambda: time.sleep(0.12), 30.0)
+        time.sleep(0.04)             # the pass ends before the next starts
+    count, _, kept = _slow_passes_since(engine, before)
+    newest = engine.engine_stats()["slow_passes"]["newest"]
+    assert len(newest) == 16 and kept == newest
+    assert count >= 20
+    # in the order they ended, and (if no other pass was slow) none older
+    # than the fifth call's
+    assert [r["t_end"] for r in newest] == sorted(r["t_end"] for r in newest)
+    mine = [r for r in newest if r["phases"].get("calls", 0) >= 0.12]
+    assert len(mine) >= 16 - (count - 20)
+    assert all(r["t_end"] > started[4] for r in mine)
+
+
+def test_release_and_put_grow_under_load_and_an_idle_engine_dispatches_nothing(
+        engine):
+    _generate(engine, 1)
+    before = engine.engine_stats()
+    _generate(engine, 3)
+    loaded = engine.engine_stats()
+    d = _grown(before, loaded, "phase_s")
+    assert d["release"] > 0 and d["put"] > 0
+    time.sleep(0.3)                  # some fifty passes with nothing to run
+    idle = _grown(loaded, engine.engine_stats(), "phase_s")
+    for k in ("put", "dispatch", "sample", "fetch", "publish", "notify"):
+        assert idle[k] == 0, k
+    for k in ("calls", "admit", "plan", "empty", "release"):
+        assert idle[k] > 0, k
+    assert sum(idle.values()) == pytest.approx(0.3, abs=0.05)
+
+
+def test_a_pass_opens_its_phases_in_the_loops_order_each_closed_first(
+        engine, monkeypatch):
+    log = []
+
+    class Recorded:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+
+    monkeypatch.setattr(tracing, "annotate", Recorded)
+    _generate(engine, 1)
+    time.sleep(0.05)                 # a few passes with nothing to run
+    monkeypatch.undo()
+    # from the first pass that opened under the recorder
+    events = log[log.index(("open", "engine.calls")):]
+    assert all(name.startswith("engine.") for _, name in events)
+    # each phase is closed before the next opens
+    assert all(a == ("open", b[1]) and b[0] == "close"
+               for a, b in zip(events[::2], events[1::2]))
+    opened = [name[len("engine."):] for kind, name in events
+              if kind == "open"]
+    passes, at = [], [i for i, n in enumerate(opened) if n == "calls"]
+    for i, j in zip(at, at[1:]):
+        passes.append(opened[i:j])
+    # a pass that dispatched a step and read the one before it
+    stepping = [p for p in passes if "dispatch" in p and "fetch" in p]
+    assert stepping
+    for p in stepping:
+        assert [n for n in p if n != "publish"] == [
+            "calls", "admit", "plan", "put", "dispatch", "sample", "fetch",
+            "notify", "release"]
+        assert p[p.index("dispatch") - 1] == "put"
+    # and one with nothing to run
+    assert ["calls", "admit", "plan", "empty", "release"] in passes
 
 
 def test_engine_histograms_count_each_finished_request_once(engine):
@@ -133,11 +314,10 @@ def test_engine_histograms_count_each_finished_request_once(engine):
         assert a["sum"] > b["sum"]
     # a request waits for a slot before it is prefilled
     assert after["ttft_s"]["sum"] > after["queue_wait_s"]["sum"]
-    # the older readings are derived from the same count and sum
+    # a mean, or one request's, is the count's and the sum's to give
+    assert "ttft_avg_s" not in after and "last_ttft_s" not in after
     ttft = after["ttft_s"]
-    assert after["ttft_avg_s"] == pytest.approx(
-        ttft["sum"] / ttft["count"], abs=1e-5)
-    assert after["last_ttft_s"] > 0
+    assert 0 < ttft["sum"] / ttft["count"] < 60
 
 
 def test_engine_counters_only_grow(engine):
