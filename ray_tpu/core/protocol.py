@@ -570,6 +570,11 @@ class Server:
     async def stop(self) -> None:
         if self._server:
             self._server.close()
-            await self._server.wait_closed()
+        # our ends first: since Python 3.12 `wait_closed` waits for every
+        # accepted connection, and a peer that never hangs up (a worker
+        # whose daemon was killed before it could pass `kill_worker` on)
+        # would hold the head's shutdown for ever
         for conn in list(self.connections):
             await conn.close()
+        if self._server:
+            await self._server.wait_closed()

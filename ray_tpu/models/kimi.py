@@ -47,12 +47,11 @@ MLP the expert block (320 experts of 1,280, scaling 1.0), no dense layer.
 Its rows are keys and values by head, `k`, `v` [softmax layers, slots, 8,
 T, 128] (`lm`'s grouped-head arithmetic, which granite's attention layers
 run too: `gqa_qkv`, `gqa_attend`, `gqa_write_slot`; a decode step's
-position through `ops/rows_write.py`). A chunk's further lanes attend a
-block of positions at a time and only as far as the slot's own
-(`lm.gqa_attend_blocks`): at 25,600 positions the plain form's scores for
-one slot's 8 x 128 queries are 0.84 GB. The decode program reads all T
-positions a lane (`read_positions` counts T for each). A float32 q and
-the probabilities meet the bf16 rows as two pieces (`lm.gqa_attend`).
+position through `ops/rows_write.py`). Nothing reads a slot's rows past
+its position: every slot's first lane, the decode program whole, goes
+through the kernel `ops/gqa_attend.py` (off the chip the plain form), a
+chunk's further lanes a block of positions at a time (`gqa_attend_blocks`).
+A float32 q and the probabilities meet the bf16 rows as two pieces.
 
 **The chip's share.** `experts_held` E' and `first_expert` say which of the
 E experts of every expert layer this replica holds: the router keeps its E
@@ -120,6 +119,7 @@ from jax import lax
 from ray_tpu.models import lm, moe as _moe
 from ray_tpu.models.deepseek import cache_write, rows
 from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.gqa_attend import gqa_attend, read_positions as gqa_read
 from ray_tpu.ops.kda_update import kda_update
 from ray_tpu.ops.pieces import pieces
 from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
@@ -746,9 +746,9 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
 def _gqa(x, p, cfg: KimiConfig, cache, i, pos0, ok, slot=None):
     """Softmax layer `i` of the stack: x [N,C,D] float32 += gated
     grouped-head attention of its lanes against the carried rows of `k` and
-    `v`. Row n is slot n at one lane (N = B, C = 1: the plain form over all
-    T positions), or the one row is `slot`'s own further lanes, the first
-    at position pos0 [1], against that slot's rows a block at a time."""
+    `v`. Row n is slot n at one lane (N = B, C = 1: `ops/gqa_attend.py`, to
+    each slot's position), or the one row is `slot`'s own further lanes, the
+    first at position pos0 [1], against that slot's rows a block at a time."""
     B, C, _ = x.shape
     G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.gqa_head_dim
     scale = 1.0 / math.sqrt(d)
@@ -763,10 +763,10 @@ def _gqa(x, p, cfg: KimiConfig, cache, i, pos0, ok, slot=None):
                 ck = rows_write(cache["k"], i, k[:, 0], pos0, ok[:, 0])
                 cv = rows_write(cache["v"], i, v[:, 0], pos0, ok[:, 0])
             with jax.named_scope("gqa_attend"):
-                y = lm.gqa_attend(
-                    q[:, 0], ck[i], cv[i],
-                    jnp.broadcast_to(pos0[:, None, None], (B, G, R)), scale,
-                    cfg.dtype)[:, None]                        # [B,1,G,R,d]
+                # the leaves whole and the layer's index: the kernel's index
+                # map picks a block where it lies, nothing slices a layer
+                y = gqa_attend(q[:, 0], ck, cv, i, pos0, ok[:, 0],
+                               scale)[:, None]                 # [B,1,G,R,d]
         else:
             with jax.named_scope("kv_update"):
                 ck = lm.gqa_write_slot(cache["k"], i, slot, k[0], pos0[0],
@@ -906,10 +906,10 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
 
 def _read_positions(cache, pos0, length, on, further):
     """The positions whose rows one attention layer read for a step's valid
-    lanes: every slot's first lane to its block through `mla_attend`, or
-    all T in the grouped-head plain form; a prefilling slot's further lanes
-    all T of latent rows, or the grouped-head blocks to the slot's last
-    lane."""
+    lanes: every slot's first lane to its block through `mla_attend` or
+    `gqa_attend` (all T in their plain forms); a prefilling slot's further
+    lanes all T of latent rows, or the grouped-head blocks to the slot's
+    last lane."""
     if "latent" in cache:
         T = cache["latent"].shape[2]
         read = read_positions(pos0, on, T)
@@ -917,7 +917,7 @@ def _read_positions(cache, pos0, length, on, further):
             read = read + (further.any(axis=1).sum() * T).astype(jnp.uint32)
         return read
     T = cache["k"].shape[3]
-    read = on.sum() * T
+    read = gqa_read(pos0, on, T)
     if further is not None:
         turns, block = lm.gqa_blocks(pos0 + jnp.maximum(length, 1) - 1, T)
         read = read + jnp.sum(jnp.where(further.any(axis=1),

@@ -91,14 +91,14 @@ def attend_rows(q_abs, q_r, latents, keys, pos, scale):
                       preferred_element_type=jnp.float32)
 
 
-def _block(T: int) -> int:
-    """Positions a grid step takes: all T where they fit one block, else the
-    longest stretch of whole lane tiles within `BLOCK` that divides T, else
-    `BLOCK` itself with the last block ragged."""
-    if T <= BLOCK:
-        return T
-    whole = [n for n in range(LANES, BLOCK + 1, LANES) if T % n == 0]
-    return whole[-1] if whole else BLOCK
+def _block(T: int, most: int | None = None) -> int:
+    """Positions a grid step takes, `most` at most (`BLOCK`, or a sibling's
+    own): all T where they fit one block, else the longest stretch of whole
+    lane tiles within a block that divides T, else `most` itself with the
+    last block ragged."""
+    most = min(T, most or BLOCK)
+    whole = [n for n in range(LANES, most + 1, LANES) if T % n == 0]
+    return most if most == T or not whole else whole[-1]
 
 
 def _kernel(layer_ref, src_ref, first_ref, last_ref, pos_ref, qa_ref, qr_ref,
@@ -226,13 +226,13 @@ def mla_attend(q_abs: jax.Array, q_r: jax.Array, lat: jax.Array,
 
 
 def read_positions(pos, live, T: int, *, kernel: bool | None = None,
-                   interpret: bool = False):
-    """The positions whose rows one call of `mla_attend` reads, summed over
-    the live slots (uint32): all T a slot in the plain form, a slot's
-    position rounded up to a block under the kernel."""
+                   interpret: bool = False, most: int | None = None):
+    """The positions whose rows one call of `mla_attend` (or of a sibling
+    whose blocks are at most `most`) reads, summed over the live slots
+    (uint32): all T a slot plain, its position rounded up to a block here."""
     live = live.astype(bool)
     each = T
     if _use_kernel(kernel, interpret):
-        block = _block(T)
+        block = _block(T, most)
         each = jnp.minimum((jnp.clip(pos, 0, T - 1) // block + 1) * block, T)
     return jnp.sum(jnp.where(live, each, 0)).astype(jnp.uint32)

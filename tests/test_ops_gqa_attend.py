@@ -1,0 +1,244 @@
+"""`ops/gqa_attend.py`: the decode kernel, interpreted, against the plain
+`lm.gqa_attend` over the same rows at Solar's head sizes; where
+`kimi._gqa` calls it; and what the decode program counts as read."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import kimi, lm
+
+op = importlib.import_module("ray_tpu.ops.gqa_attend")
+mla = importlib.import_module("ray_tpu.ops.mla_attend")
+
+G, R, D = 8, 8, 128
+SCALE = 1.0 / np.sqrt(D)
+BLOCK = 128
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+def _operands(B, T, q_dtype, L=1, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    # q as large as it takes for a softmax that is not flat: the weights of
+    # a few hundred positions differ by orders of magnitude
+    return (4 * jax.random.normal(ks[0], (B, G, R, D), F32).astype(q_dtype),
+            jax.random.normal(ks[1], (L, B, G, T, D), F32).astype(BF16),
+            jax.random.normal(ks[2], (L, B, G, T, D), F32).astype(BF16))
+
+
+def _both(monkeypatch, T, pos, live, q_dtype=F32, L=1, layer=0, block=BLOCK):
+    """(the kernel's values, the plain form's) [B, G, R, d] as numpy."""
+    monkeypatch.setattr(op, "BLOCK", block)
+    pos = jnp.asarray(pos, jnp.int32)
+    live = jnp.asarray(live, bool)
+    args = (*_operands(len(pos), T, q_dtype, L), jnp.int32(layer), pos, live)
+    got = jax.jit(lambda *a: op.gqa_attend(*a, SCALE, interpret=True))(*args)
+    want = jax.jit(lambda *a: op.gqa_attend(*a, SCALE, kernel=False))(*args)
+    return np.asarray(got), np.asarray(want)
+
+
+# Two pieces carry 16 bits of q and of a probability: what is left is the
+# order of the sums, a block at a time. One piece rounds the probabilities
+# to bf16, the plain form the normalised ones and the kernel the
+# unnormalised: 2^-9 of a weighted sum of unit-variance values either way.
+TOLERANCE = {F32: dict(rtol=0, atol=2e-5), BF16: dict(rtol=0, atol=2e-2)}
+PIECES = pytest.mark.parametrize("q_dtype", [F32, BF16],
+                                 ids=["float32-q-two-pieces",
+                                      "bf16-q-one-piece"])
+
+
+@PIECES
+@pytest.mark.parametrize("pos", [
+    [0, 0, 0], [BLOCK - 1] * 3, [BLOCK] * 3, [4 * BLOCK - 1] * 3,
+    [0, BLOCK - 1, BLOCK], [3 * BLOCK + 5, 17, 4 * BLOCK - 1]],
+    ids=["first", "a-blocks-last", "a-blocks-first", "the-leafs-last",
+         "ragged-at-the-edges", "ragged"])
+def test_the_kernel_is_the_plain_form_to_each_slots_own_position(
+        monkeypatch, pos, q_dtype):
+    got, want = _both(monkeypatch, 4 * BLOCK, pos, [True] * 3, q_dtype)
+    np.testing.assert_allclose(got, want, **TOLERANCE[q_dtype])
+    assert np.abs(want).max() > 0.5
+
+
+def test_one_piece_of_a_float32_q_is_another_function(monkeypatch):
+    """What the two pieces are for: the same q rounded to the rows' dtype
+    lies a hundred tolerances from the float32 q's values, in the kernel as
+    in the plain form."""
+    pos = [3 * BLOCK + 5, 17, 4 * BLOCK - 1]
+    two, want = _both(monkeypatch, 4 * BLOCK, pos, [True] * 3, F32)
+    one, _ = _both(monkeypatch, 4 * BLOCK, pos, [True] * 3, BF16)
+    assert np.abs(one - want).max() > 100 * TOLERANCE[F32]["atol"]
+    np.testing.assert_allclose(two, want, **TOLERANCE[F32])
+
+
+@PIECES
+@pytest.mark.parametrize("live", [
+    [False, True, True, True], [True, True, True, False],
+    [False, False, True, False], [False] * 4],
+    ids=["the-first", "the-last", "all-but-one", "all"])
+def test_a_dead_slot_reads_nothing_and_the_others_are_exact(
+        monkeypatch, live, q_dtype):
+    pos = [300, 3 * BLOCK + 1, 40, 2 * BLOCK]
+    got, want = _both(monkeypatch, 4 * BLOCK, pos, live, q_dtype)
+    on = np.asarray(live)
+    np.testing.assert_allclose(got[on], want[on], **TOLERANCE[q_dtype])
+    assert np.isfinite(got).all() and not got[~on].any()
+    # a dead slot's grid steps stay on the block the live slot before it
+    # ended on (none before it: slot 0's block 0): the pipeline moves nothing
+    src, first, last, _ = (np.asarray(a) for a in mla._plan(
+        jnp.asarray(pos), jnp.asarray(live), 4 * BLOCK, BLOCK))
+    assert (first[~on] == last[~on]).all() and not first[on].any()
+    assert (src[on] == np.flatnonzero(on)).all()
+
+
+@PIECES
+def test_the_layer_worked_on_is_the_one_named(monkeypatch, q_dtype):
+    pos = [5, 2 * BLOCK - 1]
+    got, want = _both(monkeypatch, 2 * BLOCK, pos, [True] * 2, q_dtype,
+                      L=3, layer=2)
+    np.testing.assert_allclose(got, want, **TOLERANCE[q_dtype])
+    other, _ = _both(monkeypatch, 2 * BLOCK, pos, [True] * 2, q_dtype,
+                     L=3, layer=1)
+    assert np.abs(other - want).max() > 0.1
+
+
+@PIECES
+@pytest.mark.parametrize("T,block,pos", [
+    (3 * BLOCK + 40, BLOCK, [3 * BLOCK + 39, 3 * BLOCK, 7]),
+    (200, 256, [199, 0, 100])], ids=["a-ragged-last-block", "one-block"])
+def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
+                                                   pos, q_dtype):
+    # 424 has no divisor that is whole lane tiles: its last block hangs over
+    monkeypatch.setattr(op, "BLOCK", block)
+    assert op._block(T) == min(T, block)
+    got, want = _both(monkeypatch, T, pos, [True] * 3, q_dtype, block=block)
+    np.testing.assert_allclose(got, want, **TOLERANCE[q_dtype])
+
+
+@pytest.mark.parametrize("T,most,block", [
+    (25600, 1024, 1024), (25600, 2048, 1280), (25600, 2560, 2560),
+    (96, 1024, 96), (1000, 256, 256)])
+def test_the_block_is_this_kernels_own_and_divides_the_length(
+        monkeypatch, T, most, block):
+    monkeypatch.setattr(op, "BLOCK", most)
+    monkeypatch.setattr(mla, "BLOCK", 384)           # not the sibling's
+    assert op._block(T) == block
+
+
+def test_leaves_with_the_positions_on_the_lanes_are_refused():
+    q, ck, _ = _operands(2, 64, F32)
+    last = jnp.swapaxes(ck, 3, 4)                    # [L, B, G, d, T]
+    with pytest.raises(AssertionError):
+        op.gqa_attend(q, last, last, 0, jnp.zeros(2, jnp.int32),
+                      jnp.ones(2, bool), SCALE, kernel=False)
+
+
+def test_read_positions_are_a_slots_position_rounded_up_to_a_block(
+        monkeypatch):
+    monkeypatch.setattr(op, "BLOCK", BLOCK)
+    monkeypatch.setattr(mla, "BLOCK", 3 * BLOCK)     # not the sibling's
+    T = 3 * BLOCK + 40
+    pos = jnp.asarray([0, BLOCK - 1, BLOCK, T - 1, 77])
+    live = jnp.asarray([True, True, True, True, False])
+    assert int(op.read_positions(pos, live, T, kernel=False)) == 4 * T
+    assert int(op.read_positions(pos, live, T, interpret=True)) == (
+        BLOCK + BLOCK + 2 * BLOCK + T)
+
+
+# ------------------------------------------------------ where it is called
+
+def _solar_layer():
+    cfg = kimi.KimiConfig.preset("solar-tiny")
+    bp = jax.tree.map(lambda a: a[1], kimi.init_params(
+        jax.random.key(0), cfg)["gqa"])
+    return cfg, bp
+
+
+def _one_layers_call(C, slot, B=3, T=32):
+    """Trace softmax layer 1 of the tiny Solar with C lanes a row."""
+    cfg, bp = _solar_layer()
+    N = B if slot is None else 1
+    cache = kimi.init_cache(cfg, B, T)
+    x = jnp.ones((N, C, cfg.d_model), jnp.float32)
+    out = kimi._gqa(x, bp, cfg, cache, 1, jnp.arange(N, dtype=jnp.int32) + 2,
+                    jnp.ones((N, C), bool), slot)[0]
+    assert out.shape == x.shape and bool(jnp.isfinite(out).all())
+
+
+@pytest.mark.parametrize("C,slot,through", [(1, None, True), (4, 1, False)],
+                         ids=["every-slots-one-lane", "one-slots-lanes"])
+def test_the_softmax_layer_calls_the_op_for_every_slots_one_lane_only(
+        monkeypatch, C, slot, through):
+    calls = []
+
+    def seen(*args, **kwargs):
+        calls.append((args[0].dtype, args[1].shape, args[2].shape))
+        return op.gqa_attend(*args, **kwargs)
+
+    monkeypatch.setattr(kimi, "gqa_attend", seen)
+    _one_layers_call(C, slot)
+    # a float32 q, and both leaves whole: [L, B, G, T, d]
+    assert calls == ([(F32, (2, 3, 2, 32, 16), (2, 3, 2, 32, 16))]
+                     if through else [])
+
+
+def test_the_layer_through_the_kernel_is_the_layer_through_the_plain_form(
+        monkeypatch):
+    """`kimi._gqa` at one lane a slot with a slot that is not on: what it
+    adds to x through the kernel is what it adds through `lm.gqa_attend`,
+    with no backend asked."""
+    cfg, bp = _solar_layer()
+    B, T = 4, 40
+    monkeypatch.setattr(op, "BLOCK", 16)             # 40: a ragged last block
+    ks = jax.random.split(jax.random.key(3), 3)
+    cache = kimi.init_cache(cfg, B, T)
+    cache = {**cache, **{name: jax.random.normal(
+        k, cache[name].shape, F32).astype(cache[name].dtype)
+        for name, k in zip(("k", "v"), ks)}}
+    x = jax.random.normal(ks[2], (B, 1, cfg.d_model), F32)
+    pos0 = jnp.asarray([0, 39, 16, 7], jnp.int32)
+    ok = jnp.asarray([True, True, False, True])[:, None]
+
+    def layer(interpret):
+        how = dict(interpret=True) if interpret else dict(kernel=False)
+        monkeypatch.setattr(kimi, "gqa_attend", lambda *a: op.gqa_attend(
+            *a, **how))
+        return jax.jit(lambda x, cache: kimi._gqa(
+            x, bp, cfg, cache, 1, pos0, ok))(x, cache)
+
+    (got, got_cache), (want, want_cache) = layer(True), layer(False)
+    on = np.asarray(ok[:, 0])
+    np.testing.assert_allclose(np.asarray(got)[on], np.asarray(want)[on],
+                               rtol=0, atol=1e-5)
+    assert np.abs(np.asarray(want - x)[on]).max() > 1e-2
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_cache[name], F32),
+                                      np.asarray(want_cache[name], F32))
+
+
+@pytest.mark.parametrize("on_the_chip", [False, True],
+                         ids=["plain-form", "kernels-path"])
+def test_the_programs_count_of_positions_read_follows_the_path(
+        monkeypatch, on_the_chip):
+    """`kimi._read_positions`' grouped-head branch: all T a live slot in
+    the plain form, its position rounded up to a block where the kernel
+    runs; a prefilling slot's further lanes `lm.gqa_attend_blocks`' turns
+    either way."""
+    monkeypatch.setattr(mla, "_on_tpu", lambda: on_the_chip)
+    monkeypatch.setattr(op, "BLOCK", 32)
+    monkeypatch.setattr(lm, "GQA_BLOCK", 48)
+    T = 160
+    cache = {"k": jnp.zeros((1, 4, 2, T, 16), BF16)}
+    pos0 = jnp.asarray([0, 31, 32, 159], jnp.int32)
+    on = jnp.asarray([True, True, True, False])
+    first = 4 * 32 if on_the_chip else 3 * T
+    assert int(kimi._read_positions(cache, pos0, None, on, None)) == first
+    # slot 1 prefills 20 lanes from position 31: its further lanes reach
+    # position 50, two turns of 48
+    length = jnp.asarray([1, 20, 1, 0], jnp.int32)
+    further = jnp.arange(19)[None, :] < (length - 1)[:, None]
+    assert int(kimi._read_positions(cache, pos0, length, on, further)) == (
+        first + 2 * 48)

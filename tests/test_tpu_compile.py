@@ -557,12 +557,16 @@ def test_kimi_serving_programs_compile_at_the_configurations_sizes(
         "kda_layer_copies": [], "expert_matrix_copies": []}
 
 
-# The cell `serve-solar-longctx`'s two programs (PR 49), from
-# rehearse/compile_solar_for_v5e.py: what the configuration file's `memory`
-# gives, with `==`
-SOLAR_DECODE_BYTES = 12_181_509_632
-SOLAR_DECODE_TEMP_BYTES = 814_899_712
-SOLAR_CHUNK_BYTES = 12_353_619_968
+# The cell `serve-solar-longctx`'s two programs, from
+# rehearse/compile_solar_for_v5e.py. Since PR 52 every slot's first lane
+# attends through `ops/gqa_attend.py`: the decode program's temporaries were
+# 814,899,712 B, the two pieces' float32 scores over all 25,600 positions and
+# their probabilities, and the chunk program held the same beside its own.
+# The configuration file is the benchmark's and keeps PR 49's bytes
+# (12,181,509,632 and 12,353,619,968) until a `benchmark` issue
+SOLAR_DECODE_BYTES = 11_394_551_296
+SOLAR_DECODE_TEMP_BYTES = 27_941_376
+SOLAR_CHUNK_BYTES = 11_888_031_232
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -572,14 +576,17 @@ def test_solar_serving_programs_compile_at_the_configurations_sizes(
     file has them (Solar-Open2-250B's widths, one period of layers with 40 of
     320 experts a layer and an eighth of the vocabulary, 40 slots of 13.5 MB
     of float32 state and 25,600 positions of keys and values by head, chunks
-    of 128): the bytes the file gives, with `==`, and room for the pool of
-    both kinds beside the larger, between 70% and 95% of the chip; the Pallas
-    kernels (the two `rows_write` of the softmax layer's body, the delta
-    rule's update at 64 heads in the KDA body, one `expert_mlp` in each of
-    the two bodies: 5 in the decode program, and the further lanes' two more
-    `expert_mlp` in the chunk program); no float32 scores of one slot's 8 x
-    128 queries over all 25,600 positions (the further lanes attend a block
-    at a time); no instruction copies a cache leaf (`k` and `v` are held
+    of 128): under the bytes the file gives, and room for the pool of both
+    kinds beside the larger, between 70% and 95% of the chip; the Pallas
+    kernels (the two `rows_write` and the one `gqa_attend` of the softmax
+    layer's body, the delta rule's update at 64 heads in the KDA body, one
+    `expert_mlp` in each of the two bodies: 6 in the decode program, and the
+    further lanes' two more `expert_mlp` in the chunk program); no float32
+    scores of one slot's 8 x 128 queries over all 25,600 positions (the
+    further lanes attend a block at a time) **and none of every slot's first
+    lane, `[40, 8, 16 | 8, 25600]`, nor their probabilities in bf16: they
+    stay in VMEM**; no instruction copies a cache leaf (the kernel takes `k`
+    and `v` whole with the layer's index: no 2.1 GB temporary; they are held
     [.., 8, 25600, 128]: with the positions last the compiler re-laid both
     round every chunk step, and under one scatter for all slots' positions
     round every decode step, 2.1 GB each) or materialises one layer's state
@@ -598,11 +605,11 @@ def test_solar_serving_programs_compile_at_the_configurations_sizes(
     chunk = str(config["deployment"]["prefill_chunk_size"])
     if program == "decode":
         assert sized["total"] == SOLAR_DECODE_BYTES \
-            == memory["decode_step_bytes"]
+            < memory["decode_step_bytes"]
         assert sized["temp"] == SOLAR_DECODE_TEMP_BYTES \
-            == memory["decode_step_temp_bytes"]
+            < memory["decode_step_temp_bytes"] // 25
     else:
-        assert sized["total"] == SOLAR_CHUNK_BYTES == memory[
+        assert sized["total"] == SOLAR_CHUNK_BYTES < memory[
             "prefill_chunk_bytes_by_chunk_size"][chunk]
         assert sized["temp"] < 2 ** 30
     assert sized["arguments"] == memory["arguments_bytes"] + (
@@ -619,9 +626,12 @@ def test_solar_serving_programs_compile_at_the_configurations_sizes(
     assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
                for c in calls) == (2 if program == "decode" else 4)
     assert sum("/kv_update/" in c and "rows_write" in c for c in calls) == 2
+    assert sum("/gqa_attend/" in c for c in calls) == 1
     assert sum("/kda_update/" in c for c in calls) == 1
+    for dtype in ("f32", "bf16"):
+        assert _written_arrays(hlo, "40,8,(?:16|8),25600", dtype) == []
     assert made_of(hlo, config) == {
-        "kernels": 5 if program == "decode" else 7, "whole_slot_scores": [],
+        "kernels": 6 if program == "decode" else 8, "whole_slot_scores": [],
         "leaf_copies": {},
         "kda_layer_copies": [], "expert_matrix_copies": []}
 
@@ -848,6 +858,35 @@ def test_mla_attend_kernel_reads_the_leaves_where_they_lie(
         arr((B, 32, 512)), arr((B, 32, 64)), arr((L, B, T, 512)),
         arr((L, B, T, 64)), arr((), jnp.int32), arr((B,), jnp.int32),
         arr((B,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32-q-two-pieces", "bf16-q-one-piece"])
+@pytest.mark.parametrize("T,block", [(25600, 1024), (25600, 2048)],
+                         ids=["whole-blocks", "a-ragged-last-block"])
+def test_gqa_attend_kernel_reads_the_leaves_where_they_lie(
+        chips, monkeypatch, T, block, q_dtype):
+    """`ops/gqa_attend.py` alone at Solar's cell's shape, 40 slots x 8
+    key-value heads x 25,600 positions of 128 lanes and 8 queries a head:
+    Mosaic accepts a block of all 8 heads of both leaves (2 MB each at
+    1,024 positions), the batched products of 16 (or 8) query rows, the two
+    pieces' concatenation and a last block that hangs over the leaf's end,
+    inside `VMEM_LIMIT_BYTES`; and the program holds nothing beside its
+    arguments: no layer of a leaf is sliced out (2.1 GB each)."""
+    op = importlib.import_module("ray_tpu.ops.gqa_attend")
+    assert op.BLOCK == 1024 and op._block(T) == 1024
+    one = SingleDeviceSharding(chips[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: op._attend_kernel(
+        *a, 128 ** -0.5, block, False)).lower(
+        arr((40, 8, 8, 128), q_dtype), arr((1, 40, 8, T, 128)),
+        arr((1, 40, 8, T, 128)), arr((), jnp.int32), arr((40,), jnp.int32),
+        arr((40,), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
