@@ -75,6 +75,7 @@ sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks", "chip")]
 # Kimi-Linear-48B-A3B as the cell holds it: 8 expert layers' 64 held experts
 # of 256 in one stack, 8 experts a token
 D, F, E, HELD, LAYERS, K = 2304, 1024, 256, 64, 8, 8
+GATE = True                     # a SwiGLU's three matrices
 FIRST = 64                      # the held experts are FIRST..+HELD of the E
 # how far a token-independent preference skews the seeded routing: at 1.6 a
 # step's 1,024 pairs touch ~43 of the 64 held experts (the cell's
@@ -112,6 +113,34 @@ SOLAR = dict(D=4096, F=1280, E=320, HELD=40, LAYERS=4, FIRST=0,
              TILINGS=((256, 64, 512), (256, 64, 640), (256, 64, 256),
                       (256, 64, 128), (256, 32, 640), (128, 64, 640)))
 
+# `--model nemotron`: Nemotron-3-Super-120B-A12B's routed experts as its cell
+# holds them, 5 layers' 128 held experts of 512 in one stack, the two-matrix
+# relu^2 form inside the latent: rows of 1,024, F 2,688 = 21 lane tiles = 3 x
+# 896, 22 experts a token (2,816 rows a step of which a quarter are held).
+# Measured on a v5e (PR 53, 100 calls in one program; ms a call and the share
+# of the cost; 709 held rows on 100 of the layer's 128 held experts, 41 on
+# the busiest, least 1.348 ms; `decode` and `chunk` are one shape here):
+#     tiling        decode
+#     two gmm       2.816  47.9%
+#     256:64:384    1.545  87.2%
+#     256:64:512    1.697  79.4%   (the overhang: 6 steps, 3,072 columns)
+#     256:64:896    1.526  88.3%
+#     256:64:2688   1.510  89.3%   (`_column_tile`'s choice: an expert a step)
+#     256:32:2688   1.496  90.1%
+#     256:32:896    1.513  89.1%
+#     128:64:896    1.565  86.1%
+#     512:64:896    1.513  89.1%
+#     256:128:896   1.649  81.8%
+# An expert is 11 MB, 13.4 us of DMA: a grid step's ~1.2 us shows at three
+# steps an expert (896) and at seven (384), and the overhang computes a
+# seventh of its columns for nothing.
+NEMOTRON = dict(D=1024, F=2688, E=512, HELD=128, LAYERS=5, FIRST=0, K=22,
+                GATE=False,
+                SHAPES={"decode": (128, 128), "chunk": (128, 128)},
+                TILINGS=((256, 64, 384), (256, 64, 896), (256, 64, 2688),
+                         (256, 64, 512), (256, 32, 896), (128, 64, 896),
+                         (512, 64, 896), (256, 128, 896), (256, 32, 2688)))
+
 
 def _routing(np, tokens: int, own: int, seed: int):
     """experts [tokens, K] over the E: a preference all tokens share plus a
@@ -129,10 +158,10 @@ def main() -> int:
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--tilings", default="",
                     help="rows:sub:f,... in place of the sweep")
-    ap.add_argument("--model", default="kimi", choices=("kimi", "solar"))
+    ap.add_argument("--model", default="kimi", choices=("kimi", "solar", "nemotron"))
     args = ap.parse_args()
-    if args.model == "solar":
-        globals().update(SOLAR)
+    if args.model != "kimi":
+        globals().update(SOLAR if args.model == "solar" else NEMOTRON)
         args.shapes = ",".join(s for s in args.shapes.split(",")
                                if s in SHAPES)
     import jax
@@ -141,6 +170,7 @@ def main() -> int:
     from jax import lax
 
     from families.kanana import moe_experts_decode_cost
+    from families.nemotron import moe_experts_cost
     from harness import spec
     from ray_tpu.models import moe
 
@@ -162,8 +192,8 @@ def main() -> int:
                                   / a ** 0.5).astype(bf),
                        jax.random.split(key, LAYERS)).reshape(stack, a, b)
 
-    wg, wu, wd = (matrices(ks[0], D, F), matrices(ks[1], D, F),
-                  matrices(ks[2], F, D))
+    wg, wu, wd = (matrices(ks[0], D, F) if GATE else None,
+                  matrices(ks[1], D, F), matrices(ks[2], F, D))
 
     for name in args.shapes.split(","):
         tokens, own = SHAPES[name]
@@ -176,9 +206,11 @@ def main() -> int:
             np.add.at(sizes[j], np.where(held, j * HELD + local, stack), 1)
         rows_held = int(held.sum())
         touched = int((sizes[0, :stack] > 0).sum())
-        cost = moe_experts_decode_cost(
+        cost = (moe_experts_decode_cost(
             {"hidden_size": D, "moe_intermediate_size": F}, rows_held,
-            touched)
+            touched) if GATE else moe_experts_cost(
+            {"moe_latent_size": D, "moe_intermediate_size": F}, rows_held,
+            touched))
         least = max(cost["bytes"] / peaks["hbm_bytes_per_s"],
                     cost["flops"] / peaks["bf16_flops_per_s"])
         x = jax.random.normal(ks[3], (tokens, D), jnp.float32)

@@ -12,12 +12,15 @@ language model for serving: grouped-head attention over the 2,048 rows a
 learned indexer chooses of a slot's, three leaves a token, routed
 experts), solar (Solar Open2's layers for serving: kimi's programs with a
 third mixer, gated un-rotated grouped-head attention over keys and values by
-head beside the delta-rule state)."""
+head beside the delta-rule state), nemotron (Nemotron-H's layers for serving:
+a layer is a Mamba-2 mixer in groups, an attention mixer or an expert block
+alone, the routed experts two-matrix relu^2 MLPs inside a narrow latent;
+`mamba2` is the Mamba-2 mixer that granite and nemotron share)."""
 
 from ray_tpu.models import gpt2
 
 __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
-           "solar", "serving_family"]
+           "solar", "nemotron", "mamba2", "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
@@ -55,7 +58,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "granite": ("granite", "GraniteConfig"),
             "kimi": ("kimi", "KimiConfig"),
             "keye": ("keye", "KeyeConfig"),
-            "solar": ("solar", "KimiConfig")}
+            "solar": ("solar", "KimiConfig"),
+            "nemotron": ("nemotron", "NemotronConfig")}
 
 
 def serving_family(preset: str):
@@ -73,7 +77,7 @@ def serving_family(preset: str):
 
 def __getattr__(name):
     if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
-                "keye", "solar"):
+                "keye", "solar", "nemotron", "mamba2"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

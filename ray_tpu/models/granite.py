@@ -16,7 +16,8 @@ convolution's:
       positions; scores q . k * 0.015625 (attention_multiplier, not 1/8);
       causal softmax; query head j reads key-value head j // 4; W_o
 
-    Mamba-2 (64 heads of 64 lanes, one group, N = 128):
+    Mamba-2 (64 heads of 64 lanes, one group, N = 128; `models/mamba2.py`,
+    which serves any number of groups):
       [z, xBC, dt] = W_in u           (4096 + 4352 + 64; held as w_zx, w_dt)
       xBC = silu(conv1d_causal(xBC; w [4, 4352], b)), the last 4 positions
       x [64, 64], B [128], C [128] = split(xBC)
@@ -70,13 +71,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import lm
+from ray_tpu.models import lm, mamba2
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.rows_write import rows_write
-from ray_tpu.ops.ssm_update import ssm_update
 
 Params = Any
-_HIGHEST = lax.Precision.HIGHEST
 
 
 def _published_layer_types() -> tuple:
@@ -110,7 +109,6 @@ class GraniteConfig:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         assert len(self.layer_types) == self.n_layer, self.layer_types
         assert set(self.layer_types) <= {"mamba", "attention"}
-        assert self.ssm_groups == 1, "one group of B and C is what is built"
 
     @property
     def head_dim(self) -> int:
@@ -122,12 +120,11 @@ class GraniteConfig:
 
     @property
     def ssm_inner(self) -> int:
-        return self.ssm_heads * self.ssm_head_dim
+        return mamba2.inner(self)
 
     @property
     def conv_width(self) -> int:
-        """What goes through the convolution: x, B and C."""
-        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+        return mamba2.conv_width(self)
 
     def layers_of(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
@@ -166,16 +163,8 @@ CACHE_STATE = ("ssm", "conv")
 # at the last layer, ~1.8): at 0.05 that is 15 spreads and every greedy
 # reply repeats its last token for ever, at 0.005 it is 1.5 and the largest
 # logit is some other token's. The first layer reads the table through its
-# norm, whatever its size. The Mamba-2 layer's own as its reference
-# implementation initialises them: A = U(1, 16), dt = exp(U(log 0.001,
-# log 0.1)) through the inverse of softplus into `dt_bias`, D = 1, the
-# convolution U(-1/2, 1/2) (a depthwise window of 4). With the projection's
-# part added dt A lies about 0.0005 to 3: a memory of one to two thousand
-# tokens, so that a fault in carrying state across chunks, snapshots and
-# slots cannot hide.
+# norm, whatever its size. The Mamba-2 layer's own are `mamba2.init`'s.
 EMBED_STD = 0.005
-A_RANGE = (1.0, 16.0)
-DT_RANGE = (0.001, 0.1)
 
 
 def _mlp_params(key, cfg: GraniteConfig) -> Params:
@@ -198,28 +187,7 @@ def _init_layer(key: jax.Array, l, cfg: GraniteConfig, kind: str) -> Params:
                        "wv": lm.normal(ks[3], (D, G * d), 0.02, pd),
                        "wo": lm.normal(ks[4], (H * d, D), 0.02, pd)}
         return out
-    I, F, Hm, K = cfg.ssm_inner, cfg.conv_width, cfg.ssm_heads, cfg.ssm_conv
-    dt = jnp.exp(jax.random.uniform(ks[3], (Hm,), jnp.float32,
-                                    math.log(DT_RANGE[0]),
-                                    math.log(DT_RANGE[1])))
-    edge = 1.0 / math.sqrt(K)
-    out["ssm"] = {
-        # W_in's columns for z and xBC (8,448, a whole number of lane
-        # tiles), and its 64 for dt apart, float32: beside them the minor
-        # axis would be 8,512, and the TPU's compiler copies the whole stack
-        # into another layout on every step (1.26 GB; PERF.md, PR 38)
-        "w_zx": lm.normal(ks[1], (D, I + F), 0.02, pd),
-        "w_dt": lm.normal(ks[7], (D, Hm), 0.02, jnp.float32),
-        "w_out": lm.normal(ks[2], (I, D), 0.02, pd),
-        # softplus(dt_bias) = dt
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "a_log": jnp.log(jax.random.uniform(ks[4], (Hm,), jnp.float32,
-                                            *A_RANGE)),
-        "d": jnp.ones((Hm,), jnp.float32),
-        # tap k of the window multiplies the input 3 - k positions back
-        "conv_w": jax.random.uniform(ks[5], (K, F), jnp.float32, -edge, edge),
-        "conv_b": jax.random.uniform(ks[6], (F,), jnp.float32, -edge, edge),
-        "norm": lm.ones(I)}
+    out["ssm"] = mamba2.init(ks[1:8], cfg)
     return out
 
 
@@ -271,10 +239,7 @@ def num_params(cfg: GraniteConfig) -> int:
     mlp = 3 * D * F + 2 * D
     attention = 2 * D * cfg.n_head * cfg.head_dim \
         + 2 * D * cfg.n_kv_head * cfg.head_dim
-    I, Hm = cfg.ssm_inner, cfg.ssm_heads
-    mamba = (D * (I + cfg.conv_width + Hm) + I * D + 3 * Hm
-             + (cfg.ssm_conv + 1) * cfg.conv_width + I)
-    return (cfg.layers_of("mamba") * (mamba + mlp)
+    return (cfg.layers_of("mamba") * (mamba2.num_params(cfg) + mlp)
             + cfg.layers_of("attention") * (attention + mlp)
             + cfg.vocab_size * D + D)
 
@@ -295,13 +260,8 @@ def init_cache(cfg: GraniteConfig, batch: int, max_len: Optional[int] = None):
     T = max_len or cfg.max_seq_len
     rows = (cfg.layers_of("attention"), batch, cfg.n_kv_head, cfg.head_dim,
             T)
-    Lm = cfg.layers_of("mamba")
     return {"k": jnp.zeros(rows, cfg.dtype), "v": jnp.zeros(rows, cfg.dtype),
-            "ssm": jnp.zeros((Lm, batch, cfg.ssm_state, cfg.ssm_inner),
-                             jnp.float32),
-            "conv": jnp.zeros(
-                (Lm, batch, (cfg.ssm_conv - 1) * cfg.conv_width),
-                jnp.float32)}
+            **mamba2.init_cache(cfg, cfg.layers_of("mamba"), batch)}
 
 
 # ---------------------------------------------------------------------------
@@ -319,121 +279,28 @@ def init_cache(cfg: GraniteConfig, batch: int, max_len: Optional[int] = None):
 # lanes is 98% of them and cost 190 of a chunk step's 235 ms when every
 # slot went through the SSD form (PERF.md, PR 38).
 
-def _ssm_in(u32, p, cfg: GraniteConfig):
-    """The norm's output u32 [B,M,D] float32 -> z [B,M,I], xBC [B,M,F]
-    before the convolution, dt [B,M,H] after softplus, all float32."""
-    I = cfg.ssm_inner
-    with jax.named_scope("ssm_project"):
-        proj = lm.dot(u32, p["w_zx"], cfg.dtype)
-        dt = jnp.dot(u32, p["w_dt"], precision=_HIGHEST)
-        return proj[..., :I], proj[..., I:], \
-            jax.nn.softplus(dt + p["dt_bias"])
-
-
-def _ssm_out(y, z, p, cfg: GraniteConfig):
-    """The gate before the norm, one group of I lanes, then W_out."""
-    with jax.named_scope("ssm_project"):
-        y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-        return lm.dot(y, p["w_out"], cfg.dtype)
-
-
-def _conv(xbc, p, window, ok):
-    with jax.named_scope("ssm_conv"):
-        return lm.short_conv(xbc, p["conv_w"], window, ok, p["conv_b"])
-
-
-def _split_xbc(xbc, cfg: GraniteConfig):
-    I, N = cfg.ssm_inner, cfg.ssm_state
-    return xbc[..., :I], xbc[..., I:I + N], xbc[..., I + N:]
-
-
-def _ssd(x, b, c, dt, p, s, ok, cfg: GraniteConfig):
-    """The SSD form for M lanes a slot: x [B,M,I], b, c [B,M,N], dt [B,M,H],
-    the state s [B,N,I] before them, ok [B,M] -> (y [B,M,I], the state
-    after them)."""
-    B, M = ok.shape
-    H, P = cfg.ssm_heads, cfg.ssm_head_dim
-    with jax.named_scope("ssm_chunk"):
-        dt = jnp.where(ok[:, :, None], dt, 0.0)
-        a = jnp.cumsum(-dt * jnp.exp(p["a_log"]), axis=1)        # [B, M, H]
-        dtx = (lm.over_lanes(dt, P) * x).reshape(B, M, H, P)
-        # what the state held: e^{a_i} S_s C_i
-        y = lm.over_lanes(jnp.exp(a), P) * jnp.einsum(
-            "bin,bnf->bif", c, s, precision=_HIGHEST)
-        # within the chunk: (C_i . B_j) e^{a_i - a_j} dt_j x_j, j <= i
-        lane = jnp.arange(M)
-        seen = (lane[None, :] <= lane[:, None])[None, :, :, None]  # [1,i,j,1]
-        within = jnp.where(seen, jnp.exp(jnp.where(
-            seen, a[:, :, None, :] - a[:, None, :, :], 0.0)), 0.0)  # [B,i,j,H]
-        weight = within * jnp.einsum("bin,bjn->bij", c, b,
-                                     precision=_HIGHEST)[..., None]
-        y = y + jnp.einsum("bijh,bjhp->bihp", weight, dtx,
-                           precision=_HIGHEST).reshape(B, M, H * P)
-        y = y + lm.over_lanes(p["d"], P) * x
-        # the state at the chunk's end
-        total = a[:, -1]                                           # [B, H]
-        out_of = jnp.exp(total[:, None, :] - a)[..., None] * dtx   # [B,M,H,P]
-        s_new = lm.over_lanes(jnp.exp(total), P)[:, None, :] * s \
-            + jnp.einsum("bjn,bjf->bnf", b, out_of.reshape(B, M, H * P),
-                         precision=_HIGHEST)
-        return y, jnp.where(ok.any(axis=1)[:, None, None], s_new, s)
-
-
 def _mamba_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
     """One Mamba-2 mixer over every slot's first lane, x [B,1,D] float32,
-    by the recurrence: -> (x, cache). `on` [B]: the slots whose lane is
-    valid; the others keep their state and window bit for bit."""
+    by the recurrence (`mamba2.first`): -> (x, cache). `on` [B]: the slots
+    whose lane is valid; the others keep their state and window bit for
+    bit."""
     del pos
-    p, P = bp["ssm"], cfg.ssm_head_dim
     with jax.named_scope("attn"):
-        z, xbc, dt = _ssm_in(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
-                             p, cfg)
-        with jax.named_scope("ssm_conv"):
-            window = lax.dynamic_index_in_dim(cache["conv"], l, 0,
-                                              keepdims=False)
-        xbc, window = _conv(xbc, p, window, on[:, None])
-        with jax.named_scope("ssm_conv"):
-            conv = lax.dynamic_update_index_in_dim(cache["conv"], window,
-                                                   l, 0)
-        xs, b, c = _split_xbc(xbc[:, 0], cfg)
-        with jax.named_scope("ssm_update"):
-            dt = lm.over_lanes(dt[:, 0], P)                        # [B, I]
-            decay = jnp.exp(-dt * lm.over_lanes(jnp.exp(p["a_log"]), P))
-            ssm, y = ssm_update(cache["ssm"], l, decay, dt * xs, b, c, on)
-            y = y + lm.over_lanes(p["d"], P) * xs
-        o = _ssm_out(y[:, None], z, p, cfg)
-    return (x + cfg.residual_multiplier * o,
-            {**cache, "ssm": ssm, "conv": conv})
+        o, cache = mamba2.first(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
+                                bp["ssm"], cfg, cache, l, on)
+    return x + cfg.residual_multiplier * o, cache
 
 
 def _mamba_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
     """The same mixer over one slot's further lanes, x [1,M,D], by the SSD
-    form from the state its first lane left: -> (x, cache)."""
+    form from the state its first lane left (`mamba2.further`): -> (x,
+    cache)."""
     del pos
-    p = bp["ssm"]
-    N, I = cfg.ssm_state, cfg.ssm_inner
-    W = cache["conv"].shape[-1]
     with jax.named_scope("attn"):
-        z, xbc, dt = _ssm_in(rms_norm(x, bp["mixer_norm"], cfg.norm_eps),
-                             p, cfg)
-        with jax.named_scope("ssm_conv"):
-            window = lax.dynamic_slice(cache["conv"], (l, slot, 0),
-                                       (1, 1, W))[0]
-        xbc, window = _conv(xbc, p, window, ok)
-        with jax.named_scope("ssm_conv"):
-            conv = lax.dynamic_update_slice(cache["conv"], window[None],
-                                            (l, slot, 0))
-        xs, b, c = _split_xbc(xbc, cfg)
-        with jax.named_scope("ssm_chunk"):
-            s = lax.dynamic_slice(cache["ssm"], (l, slot, 0, 0),
-                                  (1, 1, N, I))[0]
-        y, s = _ssd(xs, b, c, dt, p, s, ok, cfg)
-        with jax.named_scope("ssm_chunk"):
-            ssm = lax.dynamic_update_slice(cache["ssm"], s[None],
-                                           (l, slot, 0, 0))
-        o = _ssm_out(y, z, p, cfg)
-    return (x + cfg.residual_multiplier * o,
-            {**cache, "ssm": ssm, "conv": conv})
+        o, cache = mamba2.further(
+            rms_norm(x, bp["mixer_norm"], cfg.norm_eps), bp["ssm"], cfg,
+            cache, l, slot, ok)
+    return x + cfg.residual_multiplier * o, cache
 
 
 def _qkv(x, bp, cfg: GraniteConfig):

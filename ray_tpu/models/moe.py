@@ -288,16 +288,22 @@ def _rows_times_experts(xs, w, group_sizes, first_expert):
 
 
 def _three_products(xs, wg, wu, wd, group_sizes, first_expert):
-    """The experts' SwiGLU of the sorted rows as three grouped matmuls."""
-    g = _rows_times_experts(xs, wg, group_sizes, first_expert)
-    u = _rows_times_experts(xs, wu, group_sizes, first_expert)
-    return _rows_times_experts(jax.nn.silu(g) * u, wd, group_sizes,
-                               first_expert)
+    """The experts' MLP of the sorted rows as grouped matmuls: three for a
+    SwiGLU, two for the form without a gate matrix (`wg` None), relu(x
+    W_up)^2 W_down (Nemotron-H's `relu2`)."""
+    if wg is None:
+        h = jnp.square(jax.nn.relu(
+            _rows_times_experts(xs, wu, group_sizes, first_expert)))
+    else:
+        g = _rows_times_experts(xs, wg, group_sizes, first_expert)
+        u = _rows_times_experts(xs, wu, group_sizes, first_expert)
+        h = jax.nn.silu(g) * u
+    return _rows_times_experts(h, wd, group_sizes, first_expert)
 
 
 def _one_kernel(xs, w) -> bool:
-    """Whether the experts' SwiGLU goes as `ops/expert_mlp.py`'s one kernel
-    and not as three grouped matmuls: on the tpu backend, float32 rows
+    """Whether the experts' MLP (either form) goes as `ops/expert_mlp.py`'s
+    one kernel and not as grouped matmuls: on the tpu backend, float32 rows
     against matrices held narrower (`_rows_times_experts`' two-piece case,
     where the three calls write and read back both pieces' float32
     products) and fewer than `TILE_M` rows a group (`grouped_matmul`'s own
@@ -320,7 +326,15 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
     `first_expert`..+E' of the E and F' of an expert's columns. The
     grouped matmul then leaves the other experts' rows unwritten, so they
     are zeroed on the way in (for the backward pass) and out, and the
-    result is this shard's part of the sum."""
+    result is this shard's part of the sum.
+
+    `wg` None is an expert of two matrices, relu(x W_up)^2 W_down; x is then
+    whatever the experts read (Nemotron-H's are [latent, F]: x is the latent
+    and so is the result, a quarter of the hidden size's bytes a pair).
+    Which branch runs which widths: `_one_kernel` (the chip's few float32
+    rows a group: Kimi 1,024, Keye and Kanana 768, Solar 1,280, Nemotron-H
+    2,688 in its two-matrix form) or the grouped matmuls (the `cpu` backend,
+    training, many rows a group)."""
     B, T, D = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     N = B * T
@@ -337,11 +351,11 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
         xs = _permute_rows(pairs, order, inverse)      # rows by expert
         if first_expert is not None:
             local = flat[order] - first_expert
-            mine = ((local >= 0) & (local < wg.shape[0]))[:, None]
+            mine = ((local >= 0) & (local < wu.shape[0]))[:, None]
             xs = jnp.where(mine, xs, 0)
 
     with jax.named_scope("moe_experts"):
-        form = expert_mlp if _one_kernel(xs, wg) else _three_products
+        form = expert_mlp if _one_kernel(xs, wu) else _three_products
         ys = form(xs, wg, wu, wd, group_sizes, first_expert)
 
     with jax.named_scope("moe_dispatch"):

@@ -46,9 +46,18 @@ with the weight tile resident, so an expert with many rows re-reads nothing.
 Which widths run the overhang branch (`F % tf` in the kernel: the last
 column tile hangs over the matrices' end and is masked): 768 (Keye, Kanana),
 one and a half tiles of 512, where the other choice is two steps too; not
-1,024 (Kimi, OLMoE), which 512 divides; and not 1,280 (Solar), for which
+1,024 (Kimi, OLMoE), which 512 divides; not 1,280 (Solar), for which
 `_column_tile` takes 640, two steps that compute no column twice where three
-of 512 would compute 1,536 for 1,280.
+of 512 would compute 1,536 for 1,280; and not 2,688 (Nemotron-H), which goes
+whole.
+
+The form of two matrices (`wg` None): an expert is `wu` [G, D, F] and `wd`
+[G, F, D] and row i of the result `relu(xs[i] @ wu[e])^2 @ wd[e]`
+(Nemotron-H's `relu2` experts, whose D is the latent's 1,024 and not the
+hidden size): the same grid, plan and pieces, one matrix less a step. At
+2,816 rows of which 709 fall on 100 of a layer's 128 held experts a call
+takes 1.51 ms on the v5e where the two grouped matmuls take 2.82 and the
+touched matrices' bytes 1.35 (PERF.md, PR 53).
 """
 
 from __future__ import annotations
@@ -96,10 +105,13 @@ def _whole(both, n: int):
     return both[:rows] + both[rows:]
 
 
-def _kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, wg_ref, wu_ref,
-            wd_ref, o_ref, *, sub: int, tf: int, F: int, pieces: int):
-    """One F tile of one expert's rows in one row tile."""
+def _kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, *refs, sub: int,
+            tf: int, F: int, pieces: int):
+    """One F tile of one expert's rows in one row tile. `refs`: the tiles of
+    the matrices into F (`wg` and `wu`, or `wu` alone), `wd`'s, the output
+    block."""
     del group_ref
+    *up_refs, wd_ref, o_ref = refs
     v, f = pl.program_id(0), pl.program_id(1)
     before = tile_ref[jnp.maximum(v - 1, 0)]
 
@@ -108,27 +120,27 @@ def _kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, wg_ref, wu_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     lo, hi = lo_ref[v], hi_ref[v]
-    dtype = wg_ref.dtype
+    dtype = wd_ref.dtype
 
     def rows(s, carry):
         at = pl.multiple_of(s * sub, sub)
         p = _pieces(x_ref[pl.ds(at, sub), :], dtype, pieces)
-        g = _whole(jnp.dot(p, wg_ref[0],
-                           preferred_element_type=jnp.float32), pieces)
-        u = _whole(jnp.dot(p, wu_ref[0],
-                           preferred_element_type=jnp.float32), pieces)
-        row = at + lax.broadcasted_iota(jnp.int32, g.shape, 0)
+        *gate, u = (_whole(jnp.dot(p, w_ref[0],
+                                   preferred_element_type=jnp.float32),
+                           pieces) for w_ref in up_refs)
+        row = at + lax.broadcasted_iota(jnp.int32, u.shape, 0)
         mine = (row >= lo) & (row < hi)
         wd = wd_ref[0]
         if F % tf:
             # the last tile hangs over the matrices' end: what lies there
             # is whatever VMEM held, and 0 x NaN is no 0
-            col = f * tf + lax.broadcasted_iota(jnp.int32, g.shape, 1)
+            col = f * tf + lax.broadcasted_iota(jnp.int32, u.shape, 1)
             mine &= col < F
             at_f = f * tf + lax.broadcasted_iota(jnp.int32, wd.shape, 0)
             wd = jnp.where(at_f < F, wd, jnp.zeros_like(wd))
         # the other experts' rows, and the rows past the last: nothing
-        h = jnp.where(mine, jax.nn.silu(g) * u, 0.0)
+        h = jnp.where(mine, jax.nn.silu(gate[0]) * u if gate
+                      else jnp.square(jnp.maximum(u, 0.0)), 0.0)
         o_ref[pl.ds(at, sub), :] += _whole(
             jnp.dot(_pieces(h, dtype, pieces), wd,
                     preferred_element_type=jnp.float32), pieces)
@@ -172,53 +184,61 @@ def _plan(group_sizes, first_group, G: int, R: int, tm: int):
             jnp.minimum(of(ends) - tile * tm, tm)), upto[-1]
 
 
-def _column_tile(D: int, F: int, itemsize: int) -> int:
+def _column_tile(D: int, F: int, itemsize: int, matrices: int = 3) -> int:
     """Columns of F a grid step takes. `TILE_F`, the last tile hanging over
     the matrices' end and masked, for a width of at most two tiles (768:
     Keye's and Kanana's, two steps either way, and what the table above was
     measured at; 1,024 divides). A wider F that `TILE_F` does not divide
     (1,280 = 2.5 tiles: three steps would compute 1,536 columns, a fifth of
-    the work for nothing) takes the whole lane tiles nearest `TILE_F`, and
-    at least half of it, that divide F and whose weight tiles fit
-    `WEIGHT_TILES_BYTES`, and keeps the overhang where there are none (640
-    at d = 4,096: two steps, 31.5 MB of weights' buffers;
-    `benchmarks/expert_mlp_tiles.py --model solar` times it against 256, 128
-    and the overhang)."""
+    the work for nothing) takes the most whole lane tiles that divide F, at
+    least half of `TILE_F`, whose weight tiles (two buffers of each of the
+    `matrices`) fit `WEIGHT_TILES_BYTES`, and keeps the overhang where there
+    are none: 640 at d = 4,096 in three matrices (two steps, 31.5 MB of
+    weights' buffers; 1,280 whole would be 63 MB), and all 2,688 at d =
+    1,024 in two (Nemotron-H's experts in their latent: one step an expert,
+    22 MB; `benchmarks/expert_mlp_tiles.py --model solar | nemotron` time
+    each against the other tiles and the overhang)."""
     if F <= 2 * TILE_F or F % TILE_F == 0:
         return min(TILE_F, F)
     fits = [n for n in range(TILE_F // 2, F + 1, LANES) if F % n == 0
-            and 2 * 3 * D * n * itemsize <= WEIGHT_TILES_BYTES]
-    return min(fits, key=lambda n: (abs(n - TILE_F), -n)) if fits else TILE_F
+            and 2 * matrices * D * n * itemsize <= WEIGHT_TILES_BYTES]
+    return max(fits, default=TILE_F)
 
 
-def _tiles(R: int, D: int, F: int, itemsize: int, tiles=None) -> tuple:
+def _tiles(R: int, D: int, F: int, itemsize: int, tiles=None,
+           matrices: int = 3) -> tuple:
     """(rows a block, rows a product, columns of F a grid step), cut to the
     problem: a product's rows whole bf16 sublane tiles, a block whole
     products and within the rows where there are that many."""
-    tm, sub, tf = tiles or (TILE_ROWS, SUB_ROWS, _column_tile(D, F, itemsize))
+    tm, sub, tf = tiles or (TILE_ROWS, SUB_ROWS,
+                            _column_tile(D, F, itemsize, matrices))
     sub = min(sub, -(-R // 16) * 16)
     tm = max(sub, min(tm, R) // sub * sub)
     return tm, sub, min(tf, F)
 
 
-def expert_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-               group_sizes: jax.Array, first_group: jax.Array | None = None,
-               *, tiles: tuple | None = None,
+def expert_mlp(xs: jax.Array, wg: jax.Array | None, wu: jax.Array,
+               wd: jax.Array, group_sizes: jax.Array,
+               first_group: jax.Array | None = None, *,
+               tiles: tuple | None = None,
                interpret: bool = False) -> jax.Array:
     """xs [R, D] sorted by group, wg, wu [G, D, F], wd [G, F, D],
-    group_sizes [G_all] int32 -> [R, D] in xs's dtype. Float32 rows against
-    narrower matrices go as two pieces, rows in the matrices' dtype as one;
-    accumulation, `silu(g) * u` and the output block are float32 either way.
+    group_sizes [G_all] int32 -> [R, D] in xs's dtype; `wg` None is the form
+    of two matrices, relu(xs wu)^2 wd. Float32 rows against narrower
+    matrices go as two pieces, rows in the matrices' dtype as one;
+    accumulation, the hidden lanes and the output block are float32 either
+    way.
     With `first_group` (an int32 scalar) the stacks are groups
     first_group..+G of the G_all the rows are sorted by. The rows of the
     other groups come back zero where a group of the stack shares their row
     tile and unwritten elsewhere: the caller masks."""
     R, D = xs.shape
-    G, _, F = wg.shape
-    pieces = 1 if xs.dtype == wg.dtype else 2
+    G, _, F = wu.shape
+    ups = (wu,) if wg is None else (wg, wu)
+    pieces = 1 if xs.dtype == wu.dtype else 2
     if pieces == 2 and xs.dtype != jnp.float32:
-        raise ValueError(f"rows {xs.dtype} against matrices {wg.dtype}")
-    tm, sub, tf = _tiles(R, D, F, wg.dtype.itemsize, tiles)
+        raise ValueError(f"rows {xs.dtype} against matrices {wu.dtype}")
+    tm, sub, tf = _tiles(R, D, F, wu.dtype.itemsize, tiles, len(ups) + 1)
     x = xs if R >= tm else jnp.pad(xs, ((0, tm - R), (0, 0)))
     plan, visits = _plan(group_sizes, first_group, G, R, tm)
 
@@ -234,8 +254,7 @@ def expert_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4, grid=(visits, -(-F // tf)),
         in_specs=[pl.BlockSpec((tm, D), rows),
-                  pl.BlockSpec((1, D, tf), up),
-                  pl.BlockSpec((1, D, tf), up),
+                  *(pl.BlockSpec((1, D, tf), up) for _ in ups),
                   pl.BlockSpec((1, tf, D), down)],
         out_specs=pl.BlockSpec((tm, D), rows))
     out = pl.pallas_call(
@@ -246,5 +265,5 @@ def expert_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="expert_mlp", interpret=interpret,
-    )(*plan, x, wg, wu, wd)
+    )(*plan, x, *ups, wd)
     return out[:R].astype(xs.dtype)

@@ -6,8 +6,10 @@ One token does, for every head h,
 
     S_h <- exp(dt_h A_h) S_h + dt_h x_h B^T        y_h = S_h C
 
-(one group: B and C [N] are shared by the heads), which reads and writes
-the whole state once and is bound by that.
+which reads and writes the whole state once and is bound by that. B and C
+[N] belong to a group of heads: one group shares them among all the heads
+(granite), G groups give each run of H / G heads its own (Nemotron-H: 8
+groups of 16 heads).
 
 The layout. A slot's state of one layer is held as `[N, H P]`: the state's
 index on the sublanes, every head's lanes side by side on the lanes (4,096
@@ -18,7 +20,10 @@ decay `exp(dt A)` and the input `dt x` are rows (a value a lane, spread
 over a head's P lanes by the caller), B and C are columns, the rank-one
 update is a column times a row, and the read-out a sum over sublanes: a
 multiply-add a tile on the VPU, kept as eight sublane-partials until a
-stretch of lanes has gone by.
+stretch of lanes has gone by. A group's heads lie side by side, so a group
+is a stretch of H P / G lanes and its B and C the columns of the passes over
+that stretch (16 heads of 64 are 1,024 lanes: a column a pass of
+`LANE_TILE`).
 
 `ssm_update` takes the whole leaf [layers, slots, N, H P] and the layer to
 work on; the kernel aliases the state to its output: under a jit that
@@ -37,8 +42,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# a grid step takes one slot's state of the layer whole, 2 MB in one
-# stretch of HBM at the published sizes: its four buffers are 8 MiB of VMEM
+# a grid step takes one slot's state of the layer whole, in one stretch of
+# HBM: 2 MB at granite's published sizes, its four buffers 8 MiB of VMEM;
+# 4.19 MB at Nemotron-H's 128 heads, 16.8 MiB
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 STRIP = 8                    # sublanes of a float32 tile
 LANE_TILE = 1024             # lanes a pass of the strips' loop covers
@@ -48,18 +54,27 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _over_lanes(cols, lanes: int):
+    """b or c by group [B, G, N] -> [B, N, lanes]: a group's column over the
+    stretch of lanes its heads have."""
+    return jnp.repeat(jnp.swapaxes(cols, 1, 2), lanes // cols.shape[1],
+                      axis=-1)
+
+
 def _update_plain(state, layer, decay, dtx, b, c, active):
     """The same arithmetic in plain XLA (the CPU backend's path, and what
     the kernel is tested against)."""
     s = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)  # [B,N,F]
-    s_new = decay[:, None, :] * s + b[:, :, None] * dtx[:, None, :]
+    b, c = (_over_lanes(t, s.shape[-1]) for t in (b, c))
+    s_new = decay[:, None, :] * s + b * dtx[:, None, :]
     s_new = jnp.where(active.astype(bool)[:, None, None], s_new, s)
-    y = jnp.sum(s_new * c[:, :, None], axis=1)
+    y = jnp.sum(s_new * c, axis=1)
     return lax.dynamic_update_index_in_dim(state, s_new, layer, 0), y
 
 
 def _kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref, cols_ref,
-            so_ref, y_ref, *, n_state: int, lanes: int, tile: int):
+            so_ref, y_ref, *, n_state: int, lanes: int, tile: int,
+            groups: int):
     """One slot's state of one layer: [N, lanes], one stretch of HBM."""
     del layer_ref
     slot = pl.program_id(0)
@@ -73,15 +88,18 @@ def _kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref, cols_ref,
     def _():
         for j in range(lanes // tile):
             at_lanes = slice(j * tile, (j + 1) * tile)
+            g = 2 * (j * tile * groups // lanes)    # the pass's group's b
             decay = decay_ref[0, :, at_lanes]                   # [1, tile]
             dtx = dtx_ref[0, :, at_lanes]
 
             # a strip of eight of the state's rows at a time: the update and
             # the read-out's partial sums, in and out of VMEM once
-            def strip(i, acc, at_lanes=at_lanes, decay=decay, dtx=dtx):
+            def strip(i, acc, at_lanes=at_lanes, decay=decay, dtx=dtx, g=g):
                 at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
-                b_col = jnp.broadcast_to(cols_ref[0, at, 0:1], (STRIP, tile))
-                c_col = jnp.broadcast_to(cols_ref[0, at, 1:2], (STRIP, tile))
+                b_col = jnp.broadcast_to(cols_ref[0, at, g:g + 1],
+                                         (STRIP, tile))
+                c_col = jnp.broadcast_to(cols_ref[0, at, g + 1:g + 2],
+                                         (STRIP, tile))
                 new = decay * s_ref[0, 0, at, at_lanes] + b_col * dtx
                 so_ref[0, 0, at, at_lanes] = new
                 return acc + new * c_col
@@ -93,9 +111,14 @@ def _kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref, cols_ref,
 
 def _update_kernel(state, layer, decay, dtx, b, c, active, interpret: bool):
     L, B, N, F = state.shape
-    tile = min(LANE_TILE, F)
-    assert F % tile == 0 and tile % 128 == 0 and N % STRIP == 0, (N, F)
-    cols = jnp.stack([b, c], axis=-1)                           # [B, N, 2]
+    G = b.shape[1]
+    # a pass of the strips' loop lies within one group's lanes
+    tile = min(LANE_TILE, F // G)
+    assert F % (G * tile) == 0 and tile % 128 == 0 and N % STRIP == 0, \
+        (N, F, G)
+    # group g's b and c are columns 2 g and 2 g + 1
+    cols = jnp.swapaxes(jnp.stack([b, c], axis=-1), 1, 2).reshape(
+        B, N, 2 * G)
 
     def leaf(slot, layer, on):
         return layer[0], slot, 0, 0
@@ -107,10 +130,11 @@ def _update_kernel(state, layer, decay, dtx, b, c, active, interpret: bool):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(B,),
         in_specs=[pl.BlockSpec((1, 1, N, F), leaf), row, row,
-                  pl.BlockSpec((1, N, 2), own)],
+                  pl.BlockSpec((1, N, 2 * G), own)],
         out_specs=[pl.BlockSpec((1, 1, N, F), leaf), row])
     state, y = pl.pallas_call(
-        functools.partial(_kernel, n_state=N, lanes=F, tile=tile),
+        functools.partial(_kernel, n_state=N, lanes=F, tile=tile,
+                          groups=G),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                    jax.ShapeDtypeStruct((B, 1, F), jnp.float32)],
@@ -132,11 +156,14 @@ def ssm_update(state: jax.Array, layer, decay, dtx, b, c, active, *,
 
     state [L, B, N, F] float32 (F = heads x lanes a head), decay and dtx
     [B, F] (`exp(dt A)` and `dt x`, a head's value over its lanes), b and c
-    [B, N], active [B] -> (state, y [B, F]): the read-out is of the state
+    [B, N] (one group) or [B, G, N] (group g the heads whose lanes are
+    g F / G ..), active [B] -> (state, y [B, F]): the read-out is of the state
     after the update and is garbage for a slot that is not active, whose
     state comes back bit for bit. On the TPU (or with `interpret`, or
     `kernel=True`) the state goes through the Pallas kernel, which writes
     the leaf in place; elsewhere through plain XLA."""
+    if b.ndim == 2:
+        b, c = b[:, None], c[:, None]
     if kernel is None:
         kernel = interpret or _on_tpu()
     if kernel:

@@ -327,6 +327,14 @@ class PagedKVCache:
                    if h in self._snapshot_at), default=0)
         return hit, rows
 
+    def pooled_to(self, ids: List[int]) -> tuple:
+        """(`chain_hashes` of `ids`, the tokens they would be a hit for now,
+        the tokens whose rows are pooled from the root on, with a snapshot
+        at their end or without), for a pool of both kinds; nothing is
+        touched or counted."""
+        chain = chain_hashes(ids, self.block_size)
+        return (chain, *self._longest_entry(chain))
+
     def match_prefix(self, ids: List[int]) -> Tuple[int, List[int]]:
         if self.both:
             chain = chain_hashes(ids, self.block_size)

@@ -537,6 +537,11 @@ class LLMEngine:
         # admission ahead of the queue
         self._deferred: List[_Request] = []
         self._ready: List[_Request] = []
+        # a family of state: the boundaries at which live slots will pool
+        # their snapshot (boundary's chain hash -> slot), and the requests
+        # that wait for one of them (`_waits_for_prefix`)
+        self._prefix_in_flight: Dict[bytes, int] = {}
+        self._parked: List[_Request] = []
         self._stop = threading.Event()
         self.total_generated = 0
         self.engine_steps = 0          # jitted step calls (either kind)
@@ -774,9 +779,36 @@ class LLMEngine:
         for i in range(self.max_batch):
             if self._slots[i] is None:
                 req = self._next_ready()
+                while req is not None and self._waits_for_prefix(req):
+                    self._parked.append(req)
+                    req = self._next_ready()
                 if req is None:
                     return
                 self._place(i, req)
+
+    def _waits_for_prefix(self, req: _Request) -> bool:
+        """Whether a live slot is on its way to pooling a snapshot at a
+        boundary of this prompt that the pool has no snapshot at yet: the
+        request then waits for it and is a hit, where it would prefill the
+        same prefix beside it. Many clients that start at once over a few
+        shared preambles would each prefill their preamble, 128 slots x
+        1,700 tokens a chunk step or two at a time (PERF.md, PR 53); one a
+        preamble does, and the snapshot it leaves serves the rest."""
+        if not self._prefix_in_flight:
+            return False
+        chain, hit, _ = self.kv.pooled_to(req.prompt_ids[:-1])
+        return any(n > hit and h in self._prefix_in_flight
+                   for h, n in chain)
+
+    def _prefix_landed(self, i: int) -> None:
+        """Slot i's snapshot is pooled (or never will be): whoever waited
+        for it goes back to admission, ahead of the queue."""
+        landed = [h for h, slot in self._prefix_in_flight.items()
+                  if slot == i]
+        for h in landed:
+            del self._prefix_in_flight[h]
+        if landed and self._parked:
+            self._ready[:0], self._parked = self._parked, []
 
     def _next_ready(self) -> Optional[_Request]:
         """Next admittable request: resolved deferred requests first,
@@ -866,19 +898,31 @@ class LLMEngine:
     def _place_state(self, i: int, req: _Request) -> None:
         """A slot of recurrent state takes a new sequence: the snapshot the
         pool had (copied over the whole state above) or zeros; and where
-        its own snapshot is due, if the pool lacks the prompt's last whole
-        block."""
+        its own snapshot is due, if the pool lacks it: at the prompt's last
+        whole block, or, where the pool holds the prompt's first rows from
+        another request without a snapshot at their end, at that end. The
+        rows say how far the prefix is shared; a prompt whose own part is
+        longer than a block (an agent's task after a pooled preamble) would
+        leave every snapshot inside that part, where no other request finds
+        it, and the shared prefix would never be a hit (PERF.md, PR 53)."""
         if req.reused_tokens:
             self.snapshot_hits += 1
         else:
             self.cache = self._reset_slot(self.cache, np.int32(i))
             self.slots_reset += 1
         self._slot_snapshot_at[i] = 0
+        self._prefix_landed(i)
         if self.kv is not None:
             block = self.kv.block_size
             boundary = (len(req.prompt_ids) - 1) // block * block
+            if self.kv.both:
+                chain, _, rows = self.kv.pooled_to(req.prompt_ids[:-1])
+                if rows > req.reused_tokens:
+                    boundary = rows
             if boundary > req.reused_tokens:
                 self._slot_snapshot_at[i] = boundary
+                if self.kv.both:
+                    self._prefix_in_flight[chain[boundary // block - 1][0]] = i
 
     def _sweep_streams(self) -> None:
         """Expire abandoned stream entries (client vanished): the sweep
@@ -1082,6 +1126,7 @@ class LLMEngine:
             for ids, i in snapshots:
                 self.snapshots_pooled += self.kv.store_prefix(
                     ids, self.cache, i)
+                self._prefix_landed(i)
         self._pool_prompts(last_prompts)
         return self._ids, lanes, prompts
 

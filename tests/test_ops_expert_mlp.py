@@ -166,3 +166,100 @@ def test_experts_through_the_kernel_are_experts_through_the_three_products(
         x, gates, entry, wg, wu, wd, cfg, first_expert=jnp.int32(0)))())
     np.testing.assert_allclose(got, want, **EXACT)
     assert np.abs(want).max() > 0.1
+
+
+# ------------------------------------------------- the two-matrix relu^2 form
+
+def _both_without_a_gate(sizes, G, F=256, first=None, dtype=jnp.float32,
+                         tiles=SMALL):
+    """`_both` for an expert of two matrices (`wg` None): the kernel's rows
+    of the stack's groups against the two grouped matmuls'."""
+    sizes = np.asarray(sizes, np.int32)
+    R = int(sizes.sum())
+    xs, _, wu, wd = _operands(R, F, G, dtype)
+    args = (xs, None, wu, wd, jnp.asarray(sizes),
+            None if first is None else jnp.int32(first))
+    got = jax.jit(lambda *a: op.expert_mlp(*a, tiles=tiles, interpret=True))(
+        *args)
+    want = jax.jit(moe._three_products)(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype
+    ends = np.cumsum(sizes)
+    lo = (ends - sizes)[first or 0]
+    hi = ends[(first or 0) + G - 1]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return got[lo:hi], want[lo:hi], got
+
+
+@pytest.mark.parametrize("sizes,G,kwargs,tolerance", [
+    ([5, 0, 7, 3], 4, {}, EXACT),
+    ([20, 30, 14], 3, {}, EXACT),
+    ([3, 128, 5, 120], 4, {"tiles": None}, EXACT),
+    ([0, 0, 0, 0, 4, 0, 6, 1, 0, 0, 0, 0, 37], 12, {"first": 0}, EXACT),
+    ([11, 0, 4, 0, 6, 1, 26], 4, {"first": 2}, EXACT),
+    ([5, 0, 7, 3, 49], 5, {"dtype": jnp.bfloat16}, ONE_PIECE),
+    ([5, 0, 7, 20], 4, {"F": 200}, EXACT),
+    ([9, 0, 31, 63], 4, {"tiles": (64, 32, 128), "F": 384}, EXACT)],
+    ids=["an-empty-group-between-two-touched", "groups-that-cross-a-row-tile",
+         "128-rows-at-the-ops-own-tiles",
+         "a-layer-of-the-stack-other-than-the-first",
+         "a-shard-that-starts-at-group-2", "one-piece-rows",
+         "F-no-multiple-of-the-lanes", "three-tiles-of-F-that-divide-it"])
+def test_the_kernel_without_a_gate_is_two_products_and_relu_squared(
+        sizes, G, kwargs, tolerance):
+    """`wg` None: relu(xs wu)^2 wd, Nemotron-H's experts, against
+    `moe._three_products` in that form (two grouped matmuls), with empty
+    experts, rows crossing a row tile's end, a shard of the stack and rows
+    past its end."""
+    got, want, _ = _both_without_a_gate(sizes, G, **kwargs)
+    np.testing.assert_allclose(got, want, **tolerance)
+    assert np.abs(want).max() > 0.3
+
+
+def test_without_a_gate_the_result_is_relu_squared_in_float64():
+    sizes = jnp.asarray([9, 0, 31, 24], jnp.int32)
+    xs, _, wu, wd = _operands(64, 256, 4, jnp.float32)
+    two = np.asarray(jax.jit(lambda *a: op.expert_mlp(
+        *a, tiles=SMALL, interpret=True))(xs, None, wu, wd, sizes),
+        np.float64)
+    group = np.repeat(np.arange(4), np.asarray(sizes))
+    x, u, d = (np.asarray(a, np.float64) for a in (xs, wu, wd))
+    a = np.einsum("rd,rdf->rf", x, u[group])
+    exact = np.einsum("rf,rfd->rd", np.maximum(a, 0) ** 2, d[group])
+    assert np.abs(two - exact).max() < 1e-4
+    assert np.abs(exact).max() > 1.0
+
+
+def test_experts_without_a_gate_through_the_kernel_and_through_the_products(
+        monkeypatch):
+    """`moe._experts` as `models/nemotron.py` calls it (float32 latent rows,
+    a bf16 stack of every layer's held experts' two matrices, the pairs of
+    experts that are not held past its end) on the chip's branch, the kernel
+    interpreted, against the branch the CPU takes."""
+    N, K, held, layers, F, C = 24, 6, 4, 3, 384, 128
+    stack = held * layers
+    ks = jax.random.split(jax.random.key(3), 5)
+    wu = (jax.random.normal(ks[0], (stack, C, F)) / C ** 0.5).astype(
+        jnp.bfloat16)
+    wd = (jax.random.normal(ks[1], (stack, F, C)) / F ** 0.5).astype(
+        jnp.bfloat16)
+    x = jax.random.normal(ks[2], (1, N, C), jnp.float32)
+    gates = jax.nn.softmax(jax.random.normal(ks[3], (1, N, K)))
+    # layer 1's entries (4..7) and, for the absent experts, the end (12)
+    entry = jnp.where(jax.random.bernoulli(ks[4], 0.4, (1, N, K)),
+                      held + jax.random.randint(ks[4], (1, N, K), 0, held),
+                      stack)
+    cfg = types.SimpleNamespace(n_experts=stack + 1, experts_per_token=K,
+                                dtype=jnp.float32)
+    run = functools.partial(moe._experts, x, gates, entry, None, wu, wd, cfg,
+                            first_expert=jnp.int32(0))
+    plain = jax.jit(run)()
+    monkeypatch.setattr(moe, "_one_kernel", lambda xs, w: True)
+    monkeypatch.setattr(moe, "expert_mlp", functools.partial(
+        op.expert_mlp, interpret=True))
+    kernel = jax.jit(run)()
+    np.testing.assert_allclose(kernel, plain, rtol=0, atol=3e-5)
+    assert float(jnp.abs(plain).max()) > 0.1
+    # a token none of whose experts is held gets nothing
+    none_held = np.asarray((entry == stack).all(axis=-1))[0]
+    if none_held.any():
+        assert not np.asarray(plain)[0][none_held].any()

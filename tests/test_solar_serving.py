@@ -486,9 +486,11 @@ def test_a_share_of_the_experts_serves_the_references_logits():
 
 # ------------------------------------------------------------ the experts
 
-@pytest.mark.parametrize("tiles,steps", [(None, 2), ((256, 64, 512), 3),
+@pytest.mark.parametrize("tiles,steps", [(None, 1), ((256, 64, 640), 2),
+                                         ((256, 64, 512), 3),
                                          ((256, 64, 256), 5)],
-                         ids=["the-default-640", "the-overhang", "256"])
+                         ids=["the-default-whole-at-d-256", "640",
+                              "the-overhang", "256"])
 def test_the_experts_kernel_at_1280_is_the_grouped_matmuls(tiles, steps):
     """`ops/expert_mlp.py` interpreted at F = 1,280 (d cut to 256, six
     experts of a stack of eight with rows), float32 rows in two pieces:

@@ -637,6 +637,66 @@ def test_solar_serving_programs_compile_at_the_configurations_sizes(
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_nemotron_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-nemotron-reasoning`'s two programs, as its
+    configuration file has them (Nemotron-3-Super-120B-A12B's widths, layers
+    0-10 with 128 of 512 experts a layer and a quarter of the vocabulary, 128
+    slots of 21.6 MB of float32 state and 4,608 positions of keys and values
+    by 2 heads, chunks of 128), from rehearse/compile_nemotron_for_v5e.py:
+    the bytes the file gives, with ==, and room for the pool of both kinds
+    beside the larger, between 70% and 95% of the chip; the Pallas kernels
+    (the state's update at 8 groups in the Mamba-2 body, the two
+    `rows_write` and the one `gqa_attend` in the attention body, one
+    `expert_mlp` of two matrices in the expert body: 5 in the decode
+    program, and the further lanes' one more `expert_mlp` in the chunk
+    program); no instruction copies a cache leaf (with the SSD form's state
+    reshaped to [N, groups, lanes] the compiler re-laid the whole 2.7 GB
+    leaf, N last, round every Mamba-2 layer of a chunk step: the form takes
+    a group's stretch of lanes at a time) or materialises one layer's state
+    for all slots or an expert matrix."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_nemotron_for_v5e import (CONFIG, cache_bytes, compile_step,
+                                          made_of, pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    chunk = str(config["deployment"]["prefill_chunk_size"])
+    if program == "decode":
+        assert sized["total"] == memory["decode_step_bytes"]
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 26
+    else:
+        assert sized["total"] == memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk]
+        assert sized["temp"] < 2 ** 30
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 128 * 128 * 4)     # the chunk's tokens
+    assert cache_bytes(config) == {
+        "state_bytes_per_slot": memory["state_bytes_per_slot"],
+        "kv_bytes_per_token": memory["kv_bytes_per_token"]} == {
+        "state_bytes_per_slot": 21_585_920, "kv_bytes_per_token": 1024}
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert 0.70 * HBM_BYTES <= memory["prefill_chunk_bytes_by_chunk_size"][
+        chunk] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in calls) == (1 if program == "decode" else 2)
+    assert sum("/kv_update/" in c and "rows_write" in c for c in calls) == 2
+    assert sum("/gqa_attend/" in c for c in calls) == 1
+    assert sum("/ssm_update/" in c for c in calls) == 1
+    assert made_of(hlo, config) == {
+        "kernels": 5 if program == "decode" else 6, "whole_slot_scores": [],
+        "leaf_copies": {}, "ssm_layer_copies": [],
+        "expert_matrix_copies": []}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_keye_serving_programs_compile_at_the_configurations_sizes(
         chips, as_on_tpu, program):
     """The cell `serve-keye-longdoc`'s two programs, as its configuration
