@@ -32,17 +32,21 @@ w's constant scale, no always-kept first or local tokens.
 
 The cache names three leaves a token (`CACHE_TOKEN_AXIS`): `k` and `v`
 [layers, slots, T, 4 x 128] and the indexer's key `ik` [layers, slots, T,
-64], token-major, so that a chosen row is one contiguous read (positions
-on the lanes, granite's layout, would make a gather of rows a gather of
-columns); and `counts`, the programs' own. `serve/kv_cache.py` pools all
-three by the block.
+64], token-major: a token's values side by side, a key-value head 128
+lanes of them, so that a block of positions is one stretch of the leaf, a
+head's keys are lanes of it as they lie, and a chosen row is whole
+wherever it is read by index (positions on the lanes, granite's layout,
+would make a gather of rows a gather of columns); and `counts`, the
+programs' own. `serve/kv_cache.py` pools all three by the block.
 
 Both programs take every slot's first lane all slots at once
 (`_attend_first`: the indexer over the slot's `ik` rows, `ops/dsa.py`'s
-choice by index, a gather of the chosen rows of k and v, attention over
-them: the whole decode program) and a chunk's further lanes a slot at a
-time and only for the slots that prefill (`_attend_further`: the same set
-as a mask over the slot's rows; `models/lm.py`, "The lanes of a chunk").
+choice, and `ops/dsa_attend.py`: on the chip a kernel that reads the
+slot's rows of k and v once, to its position, with the set as a mask, and
+elsewhere a gather of the chosen rows and attention over them: the whole
+decode program) and a chunk's further lanes a slot at a time and only for
+the slots that prefill (`_attend_further`: the same set as a mask over the
+slot's rows, plain; `models/lm.py`, "The lanes of a chunk").
 The experts of all layers are one stack `[layers x 128, d, 768]` that no
 loop slices (`models/kimi.py`'s form): a layer hands `moe._experts` the
 whole stack with its ids offset by the layer.
@@ -74,6 +78,8 @@ from ray_tpu.models.gpt2 import _layer_norm
 from ray_tpu.models.kimi import _write_first
 from ray_tpu.models.llama import apply_rope, rms_norm
 from ray_tpu.ops import dsa
+from ray_tpu.ops.dsa_attend import (dsa_attend, read_positions as dsa_read,
+                                    rows_chosen)
 
 Params = Any
 SUBLANES = 32
@@ -148,10 +154,14 @@ CACHE_TOKEN_AXIS = {"k": 2, "v": 2, "ik": 2}
 # executions (`deepseek.COUNTS`' first four: over the layers, the (lane,
 # expert) rows of the valid lanes, the experts that got at least one, the
 # most that one of them got, and 1); once a step, over the valid lanes,
-# the positions the indexer scored (pos + 1 a lane) and the rows the
-# choice left attention to read (min(pos + 1, topk) a lane)
+# the positions the indexer scored (pos + 1 a lane), the rows the choice
+# left attention (min(pos + 1, topk) a lane), and the positions whose rows
+# of k and v one layer's attention fetched for them (`_read_positions`: on
+# the chip more than were chosen, a first lane's kernel reads to the
+# slot's position)
 COUNTS = ("expert_rows", "experts_touched", "busiest_expert_rows",
-          "expert_layer_steps", "positions_indexed", "rows_selected")
+          "expert_layer_steps", "positions_indexed", "rows_selected",
+          "read_positions")
 
 
 # ---------------------------------------------------------------------------
@@ -359,10 +369,10 @@ def _attn_out(x, y, p, cfg: KeyeConfig):
 def _attend_first(x, p, cfg: KeyeConfig, cache, l, pos, angles, on):
     """Layer l's attention over every slot's first lane, x [B,1,D] float32
     at row pos [B]: the rows written, the indexer over the slot's `ik`
-    rows, the choice by index, the chosen rows of k and v gathered, and
-    attention over them: -> (x, cache)."""
-    B = x.shape[0]
-    G, d = cfg.n_kv_head, cfg.head_dim
+    rows, the choice, and attention over the chosen rows of k and v, each
+    in the form the platform reads them (`ops/dsa_attend.py`: the leaves
+    whole and the set as a mask through the kernel, or the set's rows
+    gathered by index): -> (x, cache)."""
     with jax.named_scope("attn"):
         q, k, v, qi, ki, w = _project(x, p, cfg, angles)
         with jax.named_scope("kv_update"):
@@ -373,15 +383,17 @@ def _attend_first(x, p, cfg: KeyeConfig, cache, l, pos, angles, on):
         with jax.named_scope("dsa_index"):
             scores = dsa.index_scores(qi, w, rows(cik, l), pos[:, None])
         with jax.named_scope("dsa_select"):
-            idx, chosen = dsa.select_rows(scores[:, 0], cfg.index_topk)
+            chosen = rows_chosen(scores[:, 0], cfg.index_topk)
         with jax.named_scope("dsa_attend"):
-            K = idx.shape[1]
-            y = dsa.attend_selected(
-                q[:, 0], dsa.gather_rows(ck, l, idx).reshape(B, K, G, d),
-                dsa.gather_rows(cv, l, idx).reshape(B, K, G, d), chosen,
-                1.0 / math.sqrt(d))
+            y = dsa_attend(q[:, 0], ck, cv, l, pos, on, chosen,
+                           1.0 / math.sqrt(cfg.head_dim))
         x = _attn_out(x, y[:, None], p, cfg)
     return x, {**cache, "k": ck, "v": cv, "ik": cik}
+
+
+def _lanes_a_run(M: int) -> int:
+    """The further lanes `_attend_further` takes at a time, of M."""
+    return SUBLANES if M % SUBLANES == 0 else M
 
 
 def _attend_further(x, p, cfg: KeyeConfig, cache, l, slot, at, angles, ok):
@@ -393,7 +405,7 @@ def _attend_further(x, p, cfg: KeyeConfig, cache, l, slot, at, angles, ok):
     M = x.shape[1]
     G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
     T = cache["k"].shape[2]
-    m = SUBLANES if M % SUBLANES == 0 else M
+    m = _lanes_a_run(M)
     with jax.named_scope("attn"):
         q, k, v, qi, ki, w = _project(x, p, cfg, angles)
         with jax.named_scope("kv_update"):
@@ -475,6 +487,19 @@ def _further_lanes(rest, params: Params, l, cfg: KeyeConfig, cache, given,
     return lm.each_slot(prefilling, slot, (rest, cache, given))
 
 
+def _read_positions(T: int, pos0, on, further, cfg: KeyeConfig):
+    """The positions whose rows of k and v one layer's attention fetched
+    for a step's valid lanes: every slot's first lane what `dsa_attend`
+    reads for it (its position rounded up to a block through the kernel,
+    its chosen rows plain), a prefilling slot's further lanes all T a run."""
+    read = dsa_read(pos0, on, T, cfg.index_topk)
+    if further is not None:
+        m = _lanes_a_run(further.shape[1])
+        read = read + (jnp.sum((further.sum(axis=1) + m - 1) // m)
+                       * T).astype(jnp.uint32)
+    return read
+
+
 def _expert_counts(given):
     """`COUNTS`' first four, one expert layer's."""
     with jax.named_scope("moe_router"):
@@ -529,8 +554,10 @@ def _forward(params: Params, cache, tokens, pos0, positions, length, active,
     x = lm.join_lanes(first, rest, C)
     with jax.named_scope("moe_router"):
         seen = jnp.where(ok, pos0[:, None] + lane + 1, 0)
-        step = jnp.stack([jnp.sum(seen), jnp.sum(
-            jnp.minimum(seen, cfg.index_topk))]).astype(jnp.uint32)
+        step = jnp.stack([
+            jnp.sum(seen), jnp.sum(jnp.minimum(seen, cfg.index_topk)),
+            _read_positions(cache["k"].shape[2], pos0, on, further, cfg)
+        ]).astype(jnp.uint32)
         counts = cache["counts"].at[program].add(
             jnp.concatenate([counts, step]))
     return (_logits(params, lm.last_valid_lane(x, length), cfg),

@@ -9,17 +9,21 @@ Attention's lightning indexer, as `models/keye.py` serves it).
     o[t, h] = softmax_{s in S_t}(q[t, h] . K[s, g(h)] * scale) V[s, g(h)]
 
 The caches are token-major, `[layers, slots, T, F]`: a row is one token's F
-values side by side, so a chosen row is one contiguous read. Everything
-here is plain XLA: the scores a product of the query's two bf16 pieces
-against the rows as they are held, the choice `lax.top_k` (exact, and it
-hands equal values over lower index first: never `approx_max_k`, a choice
-by blocks or a window, which are other models), the read a gather of whole
-rows out of the leaf where it lies. Two forms of the choice, one set: by
-index for one query a slot (`select_rows`, the decode program and every
-slot's first lane: 2,048 rows read, not the slot's 13,312), and as a mask
-over a slot's T rows for the lanes of a chunk (`select_mask`: the k-th
-largest value, and of the rows that equal it the lowest indices that
-fill the set).
+values side by side. The scores and the choice are plain XLA: the scores a
+product of the query's two bf16 pieces against the rows as they are held,
+the choice `lax.top_k` (exact, and it hands equal values over lower index
+first: never `approx_max_k`, a choice by blocks or a window, which are
+other models). Two forms of the choice, one set: by index (`select_rows`)
+and as a mask over a slot's T rows (`select_mask`: the k-th largest value,
+and of the rows that equal it the lowest indices that fill the set). The
+read has two forms too. For one query a slot (the decode program and every
+slot's first lane) it is `ops/dsa_attend.py`'s: on the TPU a Pallas kernel
+that takes the mask and reads the slot's rows once, to its position,
+through VMEM; elsewhere, and as what that kernel is tested against, the
+plain path of this file, a gather of whole rows out of the leaf where it
+lies by `select_rows`' indices (`gather_rows`) and attention over the copy
+(`attend_selected`). For the lanes of a chunk it is `attend_masked` over
+one slot's rows under the mask.
 """
 
 from __future__ import annotations

@@ -431,6 +431,41 @@ def test_the_pool_takes_the_third_leaf_as_a_geometry():
     assert not pool.snapshots and not pool.both and "counts" not in pool.pools
 
 
+def test_the_programs_through_the_kernel_give_the_plain_paths_logits(
+        monkeypatch):
+    """Both programs with every slot's first lane through `ops/
+    dsa_attend.py`'s kernel, interpreted, in blocks of 32 positions (the set
+    a mask, the slot's rows read to its position) against the same programs
+    through the gather of the chosen rows: the float32 logits at every
+    generated position lie within the rounding of the accumulation's order,
+    and `read_positions` says which path read what."""
+    import functools
+    import importlib
+
+    op = importlib.import_module("ray_tpu.ops.dsa_attend")
+    plain = engine()
+    forced, want = through_the_programs(plain, PROMPT, N_DECODE)
+    monkeypatch.setattr(op, "BLOCK", 32)
+    for name, fn in (("rows_chosen", op.rows_chosen),
+                     ("dsa_attend", op.dsa_attend),
+                     ("dsa_read", op.read_positions)):
+        monkeypatch.setattr(keye, name, functools.partial(
+            fn, interpret=True))
+    through = engine()
+    _, got = through_the_programs(through, PROMPT, N_DECODE, forced=forced)
+    np.testing.assert_allclose(got, want, atol=FLOAT32_LOGIT_TOLERANCE)
+    assert np.abs(want).max() > 0.1
+
+    def read(eng):
+        return [dict(zip(keye.COUNTS, row))["read_positions"]
+                for row in np.asarray(eng.cache["counts"]).tolist()]
+
+    # 11 decode steps at positions 37-47, past the topk of 16; the chunks'
+    # first lanes at 0, 16 and 32 and their further lanes' one run each
+    assert read(plain) == [11 * 16, 1 + 16 + 16 + 3 * 96]
+    assert read(through) == [11 * 64, 32 + 32 + 64 + 3 * 96]
+
+
 def test_a_pool_hit_gives_the_logits_of_a_cold_prefill():
     """The prompt's whole blocks, all three leaves, into another slot, then
     the rest of the prompt: what a cold prefill of the whole prompt gives,
@@ -588,6 +623,10 @@ def test_the_loop_serves_what_the_programs_give_and_counts_what_it_read():
         assert counts["decode"]["positions_indexed"] == sum(range(38, 45))
         assert counts["decode"]["rows_selected"] == 7 * 16
         assert counts["chunk"]["rows_selected"] == sum(range(1, 17)) + 21 * 16
+        # off the chip a first lane's attention fetches its chosen rows (1,
+        # 16 and 16 in the chunks), a slot's further lanes all 96 a run
+        assert counts["decode"]["read_positions"] == 7 * 16
+        assert counts["chunk"]["read_positions"] == 1 + 16 + 16 + 3 * 96
         assert counts["decode"]["expert_layer_steps"] == 7 * 3
         assert counts["decode"]["expert_rows"] == 7 * 3 * 3
         again = eng.generate(prompt_ids=PROMPT, max_tokens=8)

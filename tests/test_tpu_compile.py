@@ -696,23 +696,40 @@ def test_nemotron_serving_programs_compile_at_the_configurations_sizes(
         "expert_matrix_copies": []}
 
 
+# The cell `serve-keye-longdoc`'s two programs, from
+# rehearse/compile_keye_for_v5e.py. Since PR 54 every slot's first lane
+# attends through `ops/dsa_attend.py`: the decode program's temporaries were
+# 68,891,648 B (a layer's indexer products for all slots, one leaf's gathered
+# rows `[32, 2048, 512]` and the head's pieces in turn) and the chunk
+# program's 137,844,736. The configuration file is the benchmark's and keeps
+# PR 46's bytes (14,403,657,728 and 14,472,610,816) until a `benchmark` issue
+KEYE_DECODE_BYTES = 14_336_121_344
+KEYE_DECODE_TEMP_BYTES = 1_355_264
+KEYE_CHUNK_BYTES = 14_403_216_896
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_keye_serving_programs_compile_at_the_configurations_sizes(
         chips, as_on_tpu, program):
     """The cell `serve-keye-longdoc`'s two programs, as its configuration
     file has them (Keye-VL-2.0-30B-A3B's language model at depth 6 of 48,
     all 128 experts a layer, the whole vocabulary, 32 slots of 13,312
-    positions of three leaves a token, chunks of 128): the bytes the file
-    gives, with `==`, and room for the pool of all three leaves beside the
-    larger; one `expert_mlp` kernel in the layers' loop and the chunk
-    program's second for the further lanes (the indexer, the choice, the
-    gather and attention over the chosen rows are XLA's); no instruction
-    copies a cache leaf (`k`, `v` or `ik`, whole or a layer of it: the
-    layers' loop carries the three and writes rows in place); none copies
-    an expert matrix out of the stack, a layer's [128, d, F] or the whole
-    [768, d, F] (Kanana's form until PR 48 did); and the decode program
-    writes no float32 `[32, 32, 13312]` array of every slot's scores over
-    every position: attention reads the 2,048 chosen rows."""
+    positions of three leaves a token, chunks of 128): under the bytes the
+    file gives and no more temporaries than before the kernel, and room for
+    the pool of all three leaves beside the larger; the Pallas kernels (one
+    `dsa_attend` and one `expert_mlp` in the layers' loop, and the chunk
+    program's second `expert_mlp` for the further lanes; the indexer and
+    the choice are XLA's); **no gather of the chosen rows, `bf16[65536,
+    512]`, nor the copy laid out by head, `[32, 2048, 4, 128]`, nor their
+    scores `[32, 4, 8, 2048]`: the rows go through VMEM where they lie**;
+    no instruction copies a cache leaf (`k`, `v` or `ik`, whole or a layer
+    of it: the layers' loop carries the three and writes rows in place,
+    and the kernel's call carries none through VMEM and back, as Solar's
+    `conv` leaf was at a kernel limit of 64 MB); none copies an expert
+    matrix out of the stack, a layer's [128, d, F] or the whole [768, d,
+    F] (Kanana's form until PR 48 did); and the decode program writes no
+    float32 `[32, 32, 13312]` array of every slot's scores over every
+    position."""
     import json
 
     chip_dir, _ = _chip_bench()
@@ -727,12 +744,14 @@ def test_keye_serving_programs_compile_at_the_configurations_sizes(
     sized = program_bytes(compiled)
     chunk = config["deployment"]["prefill_chunk_size"]
     if program == "decode":
-        assert sized["total"] == memory["decode_step_bytes"]
-        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 27
+        assert sized["total"] == KEYE_DECODE_BYTES \
+            < memory["decode_step_bytes"]
+        assert sized["temp"] == KEYE_DECODE_TEMP_BYTES \
+            < memory["decode_step_temp_bytes"] // 25
     else:
-        assert sized["total"] == memory[
+        assert sized["total"] == KEYE_CHUNK_BYTES < memory[
             "prefill_chunk_bytes_by_chunk_size"][str(chunk)]
-        assert sized["temp"] < 2 ** 28
+        assert sized["temp"] < 2 ** 27
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 32 * chunk * 4)    # the chunk's tokens
     assert sized["arguments"] >= 0.75 * HBM_BYTES
@@ -742,11 +761,16 @@ def test_keye_serving_programs_compile_at_the_configurations_sizes(
         == 416 * 128 * 13_056
     assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
     hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
     assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
-               for c in _mosaic_calls(hlo)) == (1 if program == "decode"
-                                                else 2)
+               for c in calls) == (1 if program == "decode" else 2)
+    assert sum("/attn/dsa_attend/" in c for c in calls) == 1
+    for dtype in ("bf16", "f32"):
+        assert _written_arrays(
+            hlo, "65536,512|32,2048,(?:512|4,128)|32,4,8,(?:1,)?2048",
+            dtype) == []
     assert made_of(hlo, config) == {
-        "kernels": 1 if program == "decode" else 2, "leaf_copies": {},
+        "kernels": 2 if program == "decode" else 3, "leaf_copies": {},
         "expert_matrix_copies": [], "dense_scores": []}
 
 
@@ -920,6 +944,37 @@ def test_mla_attend_kernel_reads_the_leaves_where_they_lie(
         arr((B,), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("T,block", [(13312, 1024), (13312, 512),
+                                     (13312, 2048), (13000, 1024)],
+                         ids=["the-cells", "half", "a-ragged-last-block",
+                              "a-length-no-block-divides"])
+def test_dsa_attend_kernel_reads_the_leaves_where_they_lie(chips, T, block):
+    """`ops/dsa_attend.py` alone at Keye's cell's shape, 32 slots x 13,312
+    positions of 4 x 128 lanes a leaf and 8 queries a head: Mosaic accepts a
+    block of positions of both leaves whole (1 MB each at 1,024), a head's
+    keys as 128 of the block's 512 lanes where they lie, products of 8
+    query rows, the mask's block `[1, block]` of int32 and a last block
+    that hangs over the leaf's end, inside `VMEM_LIMIT_BYTES`; and the
+    program holds nothing beside its arguments but the mask as int32: no
+    layer of a leaf is sliced out (436 MB each), no row is gathered."""
+    op = importlib.import_module("ray_tpu.ops.dsa_attend")
+    assert op.BLOCK == 1024 and op._block(13312) == 1024
+    assert op.VMEM_LIMIT_BYTES == 32 * 2 ** 20
+    one = SingleDeviceSharding(chips[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: op._attend_kernel(
+        *a, 128 ** -0.5, block, False)).lower(
+        arr((32, 4, 8, 128)), arr((6, 32, T, 512)), arr((6, 32, T, 512)),
+        arr((), jnp.int32), arr((32,), jnp.int32), arr((32,), jnp.bool_),
+        arr((32, T), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= 32 * T * 4 \
+        + 2 ** 16
 
 
 @pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16],
