@@ -141,6 +141,35 @@ NEMOTRON = dict(D=1024, F=2688, E=512, HELD=128, LAYERS=5, FIRST=0, K=22,
                          (256, 64, 512), (256, 32, 896), (128, 64, 896),
                          (512, 64, 896), (256, 128, 896), (256, 32, 2688)))
 
+# `--model longcat`: LongCat-Flash-Chat's routed experts as its cell holds
+# them, 4 double layers' 16 held experts of 512 in one stack beside 256
+# zero-compute outputs the router scores too (E = 768, 12 a token: 1,536
+# pairs a step of which ~2% are held), the widest row the kernel meets: d
+# 6,144, F 2,048 = 4 column tiles of 512, whose weight tiles' buffers are
+# 37.7 MB of `WEIGHT_TILES_BYTES` 40 and the float32 row and output blocks of
+# 256 rows 6.3 MB each. Measured on a v5e (PR 55, 100 calls in one program;
+# ms a call and the share of the cost; 15 held rows on 6 of the layer's 16
+# held experts, 5 on the busiest, least 0.554 ms; `decode` and `chunk` are
+# one shape here):
+#     tiling        decode
+#     three gmm     1.235  44.8%
+#     256:64:512    0.662  83.6%   (the op's own: `TILE_F` divides 2,048)
+#     128:64:512    0.658  84.1%
+#     64:64:512     0.653  84.7%
+#     256:64:256    0.660  83.8%
+#     128:64:256    0.659  84.1%
+#     256:32:512    0.657  84.3%
+#     256:64:128    0.642  86.2%
+#     128:64:1024   refused: VMEM (75 MB of weights' buffers)
+# An expert is 75.5 MB, 92 us of DMA, and six of them a call: whatever the
+# tile the call is their bytes plus ~0.1 ms (the plan, the first tiles' wait,
+# the 1,536 x 6,144 float32 output written once), and the tiles stay.
+LONGCAT = dict(D=6144, F=2048, E=768, HELD=16, LAYERS=4, FIRST=0, K=12,
+               SHAPES={"decode": (128, 128), "chunk": (128, 128)},
+               TILINGS=((256, 64, 512), (128, 64, 512), (64, 64, 512),
+                        (256, 64, 256), (128, 64, 256), (256, 32, 512),
+                        (256, 64, 128), (128, 64, 1024)))
+
 
 def _routing(np, tokens: int, own: int, seed: int):
     """experts [tokens, K] over the E: a preference all tokens share plus a
@@ -158,10 +187,11 @@ def main() -> int:
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--tilings", default="",
                     help="rows:sub:f,... in place of the sweep")
-    ap.add_argument("--model", default="kimi", choices=("kimi", "solar", "nemotron"))
+    ap.add_argument("--model", default="kimi", choices=("kimi", "solar", "nemotron", "longcat"))
     args = ap.parse_args()
     if args.model != "kimi":
-        globals().update(SOLAR if args.model == "solar" else NEMOTRON)
+        globals().update({"solar": SOLAR, "nemotron": NEMOTRON,
+                          "longcat": LONGCAT}[args.model])
         args.shapes = ",".join(s for s in args.shapes.split(",")
                                if s in SHAPES)
     import jax

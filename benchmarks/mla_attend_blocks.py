@@ -6,8 +6,9 @@ one program's loop as the layers' loop is.
     chiprun -- python benchmarks/mla_attend_blocks.py [--calls 200]
 
 Kimi's cell: 2 layers x 128 slots x 10,240 positions, the slots live at
-4,200-9,300; Kanana's: 8 x 32 x 4,096, live at 2,100-3,650; and Kimi's with
-4 slots of 128 live (the reference check's engine). A call's least time is
+4,200-9,300; Kanana's: 8 x 32 x 4,096, live at 2,100-3,650; Kimi's with
+4 slots of 128 live (the reference check's engine); and LongCat's, 8 x 128 x
+3,072 at 64 heads where the others have 32. A call's least time is
 its attended positions' r + p = 576 bf16 values read once at the HBM's peak
 (`benchmarks/chip/families/kanana.py` `mla_attend_cost`, which the cells'
 `mla_attend_roofline_pct` divides by the scope's time).
@@ -37,6 +38,24 @@ costs 0.144 us (the last column: 5,120 steps against 1,280), one that works
 plain form's values have an r.m.s. of 0.09-0.11. `ops/mla_attend.BLOCK` is
 1,024.
 
+LongCat's cell (PR 55, `--shapes longcat`: 8 sublayers x 128 slots x 3,072
+positions, live at 1,100-2,900, 64 heads; 100 calls; least 0.364 ms, the
+rows' bytes: at 120 operations a byte the products are still under them):
+
+    block   ms a call   roofline   read / attended
+    plain   1.514       24.0%      1.52
+    256     0.989       36.8%      1.06
+    512     0.755       48.2%      1.14
+    1,024   0.674       54.0%      1.28   (`_block(3072)`: the op's own)
+    1,536   0.635       57.2%      1.36
+    3,072   0.643       56.6%      1.52
+
+At 64 heads a grid step that works costs ~1.4 us where 32 heads' costs 0.35
+(`[64, 1024]` float32 scores, their exponentials and a `[64, 512]`
+accumulator on the VPU beside two products of 64 rows): the kernel's time
+is the steps' own work, not the rows' DMA, and a longer block buys 6% for a
+third more rows read. `BLOCK` stays 1,024 for every caller (ROADMAP S24).
+
 Writes `chiprun_out/mla_attend_blocks.json`. One process, which holds the
 chip.
 """
@@ -55,14 +74,19 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks", "chip")]
 
-H, R, P = 32, 512, 64
+R, P = 512, 64
 SCALE = 1.0 / math.sqrt(128 + P)
-SHAPES = {   # name: (layers, slots, T, live slots, positions from .. to)
-    "kimi": (2, 128, 10240, 128, 4200, 9300),
-    "kanana": (8, 32, 4096, 32, 2100, 3650),
-    "kimi-check": (2, 128, 10240, 4, 4200, 9300),
+SHAPES = {   # name: (layers, slots, T, live slots, positions from .. to, H)
+    "kimi": (2, 128, 10240, 128, 4200, 9300, 32),
+    "kanana": (8, 32, 4096, 32, 2100, 3650, 32),
+    "kimi-check": (2, 128, 10240, 4, 4200, 9300, 32),
+    # LongCat's cell (PR 55): 8 sublayers x 128 slots x 3,072, live at
+    # 1,100-2,900, 64 heads: `[64, block]` float32 scores and a `[64, 512]`
+    # accumulator a grid step, 120 operations a byte where Kanana's are 60
+    # and the chip's ridge is 240 (the table is in PERF.md section 6)
+    "longcat": (8, 128, 3072, 128, 1100, 2900, 64),
 }
-BLOCKS = (256, 512, 1024, 1280, 2048, 2560, 4096, 5120)
+BLOCKS = (256, 512, 1024, 1280, 1536, 2048, 2560, 3072, 4096, 5120)
 
 
 def main() -> int:
@@ -79,10 +103,10 @@ def main() -> int:
 
     op = importlib.import_module("ray_tpu.ops.mla_attend")
     out = {"device": jax.devices()[0].device_kind, "default_block": op.BLOCK}
-    peak = spec.peaks()[out["device"]]["hbm_bytes_per_s"]
+    peaks = spec.peaks()[out["device"]]
     bf = jnp.bfloat16
     for name in args.shapes.split(","):
-        L, B, T, n_live, lo, hi = SHAPES[name]
+        L, B, T, n_live, lo, hi, H = SHAPES[name]
         ks = jax.random.split(jax.random.key(0), 4)
         q_abs = jax.random.normal(ks[0], (B, H, R), jnp.float32).astype(bf)
         q_r = jax.random.normal(ks[1], (B, H, P), jnp.float32).astype(bf)
@@ -92,7 +116,11 @@ def main() -> int:
         pos = jnp.asarray(rng.integers(lo, hi, size=B), jnp.int32)
         live = jnp.asarray(np.arange(B) % (B // n_live) == 0)
         attended = int(jnp.sum(jnp.where(live, pos + 1, 0)))
-        least = attended * (R + P) * 2 / peak
+        # `mla_attend_cost`: the rows' bytes, or the absorbed form's
+        # operations, whichever bounds (the bytes, at 32 heads and at 64)
+        least = attended * max(
+            (R + P) * 2 / peaks["hbm_bytes_per_s"],
+            2.0 * H * (2 * R + P) / peaks["bf16_flops_per_s"])
         rows = {}
         forms = [("plain", None)] + [
             (str(b), b) for b in BLOCKS if b <= T and T % b == 0]

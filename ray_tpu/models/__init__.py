@@ -15,12 +15,17 @@ third mixer, gated un-rotated grouped-head attention over keys and values by
 head beside the delta-rule state), nemotron (Nemotron-H's layers for serving:
 a layer is a Mamba-2 mixer in groups, an attention mixer or an expert block
 alone, the routed experts two-matrix relu^2 MLPs inside a narrow latent;
-`mamba2` is the Mamba-2 mixer that granite and nemotron share)."""
+`mamba2` is the Mamba-2 mixer that granite and nemotron share), longcat
+(LongCat-Flash's layers for serving: a double layer of two latent-attention
+sublayers and two dense FFNs whose expert block is read at one sublayer and
+added at the next one's end, zero-compute experts beside the routed ones;
+`mla` is the latent-attention layer with positions that deepseek and longcat
+share, and the latent rows' write and read)."""
 
 from ray_tpu.models import gpt2
 
 __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
-           "solar", "nemotron", "mamba2", "serving_family"]
+           "solar", "nemotron", "mamba2", "longcat", "mla", "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
@@ -59,7 +64,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "kimi": ("kimi", "KimiConfig"),
             "keye": ("keye", "KeyeConfig"),
             "solar": ("solar", "KimiConfig"),
-            "nemotron": ("nemotron", "NemotronConfig")}
+            "nemotron": ("nemotron", "NemotronConfig"),
+            "longcat": ("longcat", "LongcatConfig")}
 
 
 def serving_family(preset: str):
@@ -77,7 +83,7 @@ def serving_family(preset: str):
 
 def __getattr__(name):
     if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
-                "keye", "solar", "nemotron", "mamba2"):
+                "keye", "solar", "nemotron", "mamba2", "longcat", "mla"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

@@ -23,7 +23,12 @@ d the hidden size, H heads, `qk_nope_head_dim` n, `qk_rope_head_dim` p,
     o = (softmax . c) W_uv
   The cache holds c after its norm and k_r after RoPE: r + p values a token
   a layer and nothing by head. Both forms are one function
-  (tests/test_deepseek_serving.py holds them to each other).
+  (tests/test_deepseek_serving.py holds them to each other). The absorbed
+  layer is `models/mla.py`'s `attention`, which LongCat-Flash shares: it
+  takes the input norm, the layer's weights (`wq`, or `wqa`, `q_norm`, `wqb`
+  for a query latent), two optional factors on the normed latents and the
+  precision a family states; this family gives it `wq` alone, no factor, and
+  its rounded form (the norm's output in the compute dtype, one piece).
 
     layer 0 .. first_k_dense_replace - 1:  x += SwiGLU_dense(RMSNorm(x))
     the others, with h = RMSNorm(x):
@@ -71,9 +76,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import lm, moe as _moe
-from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs
-from ray_tpu.ops.mla_attend import attend_rows, mla_attend, read_positions
+from ray_tpu.models import lm, mla, moe as _moe
+from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.mla_attend import read_positions
 
 Params = Any
 
@@ -93,7 +98,7 @@ class DeepseekConfig:
     norm_topk_prob: bool = True
     router_scoring: str = "sigmoid"
     routed_scaling_factor: float = 2.448
-    kv_lora_rank: int = 512
+    kv_lora_rank: int = 512          # q_lora_rank null: `mla.attention`'s `wq`
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -284,41 +289,6 @@ def init_cache(cfg: DeepseekConfig, batch: int,
             "counts": jnp.zeros((2, len(COUNTS)), jnp.uint32)}
 
 
-# as `gpt2._WRITE_WINDOW`: the narrowest stretch of positions a write touches
-_WRITE_WINDOW = 128
-
-
-def cache_write(c, l, val, pos0, ok, slot=None):
-    """Layer l of the carried leaf c [L,B,T,F] takes val [N,C,F]: lane i of
-    row n goes to position pos0[n] + i where ok[n, i], in slot n (N = B), or
-    in `slot` for the one row of that slot's own lanes; nothing else
-    changes. `gpt2._cache_write` without the heads: per slot one window of
-    W >= C positions is read, blended and written back in place."""
-    T, F = c.shape[2:]
-    N, C = val.shape[:2]
-    W = min(T, max(C, _WRITE_WINDOW))
-    start = jnp.clip(pos0 // W * W if C == 1 else pos0, 0, T - W)
-    src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [N, W]
-    hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [N, W, C]
-    moved = jnp.einsum("bwc,bcf->bwf", hit.astype(val.dtype), val,
-                       precision=lax.Precision.HIGHEST)
-    take = hit.any(axis=-1)                                           # [N, W]
-    for b in range(N):
-        at = (l, b if slot is None else slot, start[b], 0)
-        old = lax.dynamic_slice(c, at, (1, 1, W, F))
-        new = jnp.where(take[b][:, None], moved[b], old)
-        c = lax.dynamic_update_slice(c, new, at)
-    return c
-
-
-def rows(c, l, slot=None):
-    """Layer l of the carried leaf c [L,B,T,F] as attention reads it: every
-    slot's rows [B,T,F], or `slot`'s alone [1,T,F], where they lie."""
-    if slot is None:
-        return c[l]
-    return lax.dynamic_slice(c, (l, slot, 0, 0), (1, 1) + c.shape[2:])[0]
-
-
 # ---------------------------------------------------------------------------
 # The layer
 # ---------------------------------------------------------------------------
@@ -331,52 +301,12 @@ def _swiglu(h, p, cfg: DeepseekConfig):
 
 def _attention(x, bp, cfg: DeepseekConfig, lat, kr, l, pos0, pos, ok,
                slot=None, rope: bool = True):
-    """x [N,C,D] float32 += absorbed attention of its C lanes (positions
-    `pos` [N,C], written where `ok`) against layer l of the carried
-    caches: row n is slot n (N = B), or the one row is `slot`'s own lanes
-    against that slot's rows alone. Without `rope` the p lanes go
-    un-rotated, a shared key that knows no position (Kimi Linear's
-    `mla_use_nope`; `models/kimi.py` is held to this form)."""
-    B, C, _ = x.shape
-    H, r = cfg.n_head, cfg.kv_lora_rank
-    n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
-    p = bp["attn"]
-    with jax.named_scope("attn"):
-        h = rms_norm(x, bp["attn_norm"], cfg.norm_eps).astype(cfg.dtype)
-        with jax.named_scope("mla_project"):
-            q = jnp.einsum("bcd,dhk->bchk", h, lm.weight(p["wq"], cfg.dtype))
-            ckr = h @ lm.weight(p["wkva"], cfg.dtype)             # [N,C,r+p]
-            c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
-            q_rope, k_r = q[..., n:], ckr[..., r:]                # [N,C,H,p]
-            if rope:
-                cos, sin = rope_freqs(pos, cfg.qk_rope_head_dim,
-                                      cfg.rope_theta)
-                cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-                q_rope = apply_rope(q_rope, cos, sin)
-                k_r = apply_rope(k_r[:, :, None], cos, sin)[:, :, 0]
-            wkvb = lm.weight(p["wkvb"], cfg.dtype)
-            q_abs = jnp.einsum("bchn,rhn->bchr", q[..., :n], wkvb[..., :n])
-        with jax.named_scope("kv_update"):
-            lat = cache_write(lat, l, c, pos0, ok, slot)
-            kr = cache_write(kr, l, k_r, pos0, ok, slot)
-        with jax.named_scope("mla_attend"):
-            scale = 1.0 / math.sqrt(cfg.qk_head_dim)
-            if slot is None and C == 1:
-                # every slot's one lane, the decode program's work: a
-                # slot's rows read once and to its own position
-                mixed = mla_attend(q_abs[:, 0], q_rope[:, 0], lat, kr, l,
-                                   pos[:, 0], ok[:, 0], scale)[:, :, None]
-            else:
-                mixed = attend_rows(q_abs, q_rope, rows(lat, l, slot),
-                                    rows(kr, l, slot), pos, scale)
-            # [N,H,C,r] float32 -> [N,C,H,r]
-            mixed = jnp.moveaxis(mixed, 1, 2).astype(cfg.dtype)
-        with jax.named_scope("mla_project"):
-            o = jnp.einsum("bchr,rhv->bchv", mixed, wkvb[..., n:])
-            x = x + jnp.dot(o.reshape(B, C, H * v),
-                            lm.weight(p["wo"], cfg.dtype),
-                            preferred_element_type=x.dtype)
-    return x, lat, kr
+    """x [N,C,D] float32 += the layer's absorbed attention: `mla.attention`
+    in its rounded form, with no query latent (`q_lora_rank` null) and no
+    factor on the latents, by the weights `bp` holds (`attn_norm`,
+    `attn`)."""
+    return mla.attention(x, bp["attn_norm"], bp["attn"], cfg, lat, kr, l,
+                         pos0, pos, ok, slot, rope)
 
 
 # an expert layer's routed experts in `moe`: what the layers' loop leaves out

@@ -73,10 +73,9 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models import lm, moe as _moe
-from ray_tpu.models.deepseek import cache_write, rows
 from ray_tpu.models.gpt2 import _layer_norm
-from ray_tpu.models.kimi import _write_first
 from ray_tpu.models.llama import apply_rope, rms_norm
+from ray_tpu.models.mla import cache_write, rows, write_first
 from ray_tpu.ops import dsa
 from ray_tpu.ops.dsa_attend import (dsa_attend, read_positions as dsa_read,
                                     rows_chosen)
@@ -377,9 +376,9 @@ def _attend_first(x, p, cfg: KeyeConfig, cache, l, pos, angles, on):
         q, k, v, qi, ki, w = _project(x, p, cfg, angles)
         with jax.named_scope("kv_update"):
             # one scatter a leaf for all slots (`models/kimi.py`)
-            ck = _write_first(cache["k"], l, k, pos, on[:, None])
-            cv = _write_first(cache["v"], l, v, pos, on[:, None])
-            cik = _write_first(cache["ik"], l, ki, pos, on[:, None])
+            ck = write_first(cache["k"], l, k, pos, on[:, None])
+            cv = write_first(cache["v"], l, v, pos, on[:, None])
+            cik = write_first(cache["ik"], l, ki, pos, on[:, None])
         with jax.named_scope("dsa_index"):
             scores = dsa.index_scores(qi, w, rows(cik, l), pos[:, None])
         with jax.named_scope("dsa_select"):

@@ -117,7 +117,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import lm, moe as _moe
-from ray_tpu.models.deepseek import cache_write, rows
+from ray_tpu.models.mla import cache_write, rows, write_first
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.gqa_attend import gqa_attend, read_positions as gqa_read
 from ray_tpu.ops.kda_update import kda_update
@@ -684,24 +684,11 @@ def _kda_further(x, p, cfg: KimiConfig, cache, i, slot, ok):
     return x, {**cache, "kda": kda, "conv": conv}
 
 
-def _write_first(c, i, val, pos, ok, slot=None):
-    """Layer i of the carried leaf c [L,B,T,F] takes val [B,1,F]: slot b's
-    row goes to position pos[b] where ok[b, 0], one scatter for all slots
-    (`deepseek.cache_write` at one lane is a read, a blend and a write a
-    slot: 900 small operations a layer at 128 slots, two fifths of a decode
-    step's and of what a trace of it holds)."""
-    del slot
-    B, T = val.shape[0], c.shape[2]
-    at = jnp.where(ok[:, 0], pos, T)            # past the end: dropped
-    return c.at[i, jnp.arange(B), at].set(val[:, 0], mode="drop",
-                                          unique_indices=True)
-
-
 def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
     """MLA layer `i` of the stack: x [N,C,D] float32 += absorbed attention
     of its C lanes (positions `pos` [N,C], written where `ok`) against the
-    carried rows, `deepseek._attention` without the rotation and with the
-    projections' activations as two pieces: row n is slot n (N = B), or the
+    carried rows, `mla.attention`'s whole form without the rotation, by
+    this family's own layout of the weights: row n is slot n (N = B), or the
     one row is `slot`'s own lanes against that slot's rows alone."""
     B, C, _ = x.shape
     H, r = cfg.n_head, cfg.kv_lora_rank
@@ -722,7 +709,7 @@ def _mla(x, p, cfg: KimiConfig, cache, i, pos0, pos, ok, slot=None):
                     cfg.dtype)                                    # [N,C,H,r]
             q_r = q[..., n:].astype(cfg.dtype)
         with jax.named_scope("kv_update"):
-            write = _write_first if slot is None and C == 1 else cache_write
+            write = write_first if slot is None and C == 1 else cache_write
             lat = write(lat, i, c.astype(cfg.dtype), pos0, ok, slot)
             kr = write(kr, i, ckr[..., r:].astype(cfg.dtype), pos0, ok, slot)
         with jax.named_scope("mla_attend"):

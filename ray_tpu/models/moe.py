@@ -315,8 +315,14 @@ def _one_kernel(xs, w) -> bool:
             and w.dtype != xs.dtype and xs.shape[0] < w.shape[0] * TILE_M)
 
 
+# what a zero-compute expert (LongCat-Flash's `zero_expert_type`) returns for
+# its input: nothing else is known here, and another type is refused by name
+ZERO_EXPERT_TYPES = ("identity",)
+
+
 def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
-             first_expert=None):
+             first_expert=None, zero_experts: int = 0,
+             zero_type: str = "identity"):
     """The experts' part of the layer for the tokens x [B,T,D] (`gates`,
     `experts` [B,T,K], the ids counted over all E): sort the (token, slot)
     pairs by expert, one grouped matmul over the ragged groups for each
@@ -333,11 +339,26 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
     and so is the result, a quarter of the hidden size's bytes a pair).
     Which branch runs which widths: `_one_kernel` (the chip's few float32
     rows a group: Kimi 1,024, Keye and Kanana 768, Solar 1,280, Nemotron-H
-    2,688 in its two-matrix form) or the grouped matmuls (the `cpu` backend,
-    training, many rows a group)."""
+    2,688 in its two-matrix form, LongCat-Flash 6,144 x 2,048) or the
+    grouped matmuls (the `cpu` backend, training, many rows a group).
+
+    `zero_experts` Z > 0: the last Z of the E ids are zero-compute experts of
+    `zero_type` (`identity`: such an expert returns its input, so all of a
+    token's pairs that chose one are the sum of their gates times x). They
+    have no matrices: the stacks hold ids before E - Z, their pairs take the
+    road an absent expert's take (sorted, given no row of any expert, zeroed
+    on the way out) and their term is added under the scope `moe_zero`. With
+    0 nothing of this is traced."""
     B, T, D = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     N = B * T
+    if zero_experts:
+        if zero_type not in ZERO_EXPERT_TYPES:
+            raise ValueError(
+                f"zero-compute experts of type {zero_type!r}: this layer "
+                f"knows {ZERO_EXPERT_TYPES}")
+        if first_expert is None:        # the stacks end before the zero ids
+            first_expert = jnp.int32(0)
 
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(N * K)                  # pair (n, k) at n*K+k
@@ -369,7 +390,14 @@ def _experts(x, gates, experts, wg, wu, wd, cfg: MoEConfig,
                          precision=(lax.Precision.HIGHEST
                                     if back.dtype == jnp.float32 else None),
                          preferred_element_type=jnp.float32)
-        return out.reshape(B, T, D)
+    if zero_experts:
+        with jax.named_scope("moe_zero"):
+            zero_gate = jnp.sum(
+                jnp.where(experts.reshape(N, K) >= E - zero_experts,
+                          gates.reshape(N, K).astype(jnp.float32), 0.0),
+                axis=-1, keepdims=True)
+            out = out + zero_gate * x.reshape(N, D).astype(jnp.float32)
+    return out.reshape(B, T, D)
 
 
 def _experts_on_mesh(x, gates, experts, wg, wu, wd, cfg: MoEConfig, mesh):

@@ -48,8 +48,17 @@ column tile hangs over the matrices' end and is masked): 768 (Keye, Kanana),
 one and a half tiles of 512, where the other choice is two steps too; not
 1,024 (Kimi, OLMoE), which 512 divides; not 1,280 (Solar), for which
 `_column_tile` takes 640, two steps that compute no column twice where three
-of 512 would compute 1,536 for 1,280; and not 2,688 (Nemotron-H), which goes
-whole.
+of 512 would compute 1,536 for 1,280; not 2,688 (Nemotron-H), which goes
+whole; and not 2,048 (LongCat-Flash), four tiles of 512. That one is the
+widest row the kernel meets, d = 6,144: a weight tile [6144, 512] is 6.3 MB,
+the three matrices' double buffers 37.7 MB of `WEIGHT_TILES_BYTES` 40, and
+the float32 row and output blocks of 256 rows 6.3 MB each, two buffers of
+each, inside `VMEM_LIMIT_BYTES` (a column tile of 1,024 is refused: VMEM).
+At 1,536 pairs of which 15 fall on 6 of a layer's 16 held experts a call
+takes 0.662 ms where the three grouped matmuls take 1.235 and the touched
+matrices' bytes 0.554; column tiles of 256 and 128 take 0.660 and 0.642,
+blocks of 128 and 64 rows 0.658 and 0.653: the call is the touched
+matrices' DMA whatever the tile, and the tiles stay (PERF.md, PR 55).
 
 The form of two matrices (`wg` None): an expert is `wu` [G, D, F] and `wd`
 [G, F, D] and row i of the result `relu(xs[i] @ wu[e])^2 @ wd[e]`
@@ -197,7 +206,9 @@ def _column_tile(D: int, F: int, itemsize: int, matrices: int = 3) -> int:
     weights' buffers; 1,280 whole would be 63 MB), and all 2,688 at d =
     1,024 in two (Nemotron-H's experts in their latent: one step an expert,
     22 MB; `benchmarks/expert_mlp_tiles.py --model solar | nemotron` time
-    each against the other tiles and the overhang)."""
+    each against the other tiles and the overhang). 2,048 at d = 6,144 in
+    three (LongCat-Flash) is the first case's: `TILE_F` divides it, four
+    steps an expert, 37.7 MB of weights' buffers (`--model longcat`)."""
     if F <= 2 * TILE_F or F % TILE_F == 0:
         return min(TILE_F, F)
     fits = [n for n in range(TILE_F // 2, F + 1, LANES) if F % n == 0

@@ -24,7 +24,7 @@ for p in (CHIP_DIR, os.path.join(CHIP_DIR, "rehearse")):
 
 from families import kanana  # noqa: E402
 
-from ray_tpu.models import deepseek, moe, serving_family  # noqa: E402
+from ray_tpu.models import deepseek, mla, moe, serving_family  # noqa: E402
 from ray_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 
 ma = importlib.import_module("ray_tpu.ops.mla_attend")
@@ -348,8 +348,11 @@ def test_both_programs_count_the_positions_read_beside_the_attended(
     _, plain = through_the_programs(engine(bf16), PROMPT, 3)
     if form == "kernel":
         monkeypatch.setattr(ma, "BLOCK", block)
-        for name in ("mla_attend", "read_positions"):
-            monkeypatch.setattr(deepseek, name, functools.partial(
+        # the layer calls the kernel where it lives, `models/mla.py`; the
+        # family counts what it read
+        for module, name in ((mla, "mla_attend"),
+                             (deepseek, "read_positions")):
+            monkeypatch.setattr(module, name, functools.partial(
                 getattr(ma, name), interpret=True))
     eng = engine(bf16)
     _, logits = through_the_programs(eng, PROMPT, 3)

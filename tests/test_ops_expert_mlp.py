@@ -19,7 +19,7 @@ D = 128
 SMALL = (32, 16, 128)       # rows a block, rows a product, columns of F
 
 
-def _operands(R, F, G, dtype, seed=0):
+def _operands(R, F, G, dtype, seed=0, D=D):
     ks = jax.random.split(jax.random.key(seed), 4)
     bf = jnp.bfloat16
     return (jax.random.normal(ks[0], (R, D), jnp.float32).astype(dtype),
@@ -28,12 +28,12 @@ def _operands(R, F, G, dtype, seed=0):
             (jax.random.normal(ks[3], (G, F, D)) / F ** 0.5).astype(bf))
 
 
-def _both(sizes, G, F=256, first=None, dtype=jnp.float32, tiles=SMALL):
+def _both(sizes, G, F=256, first=None, dtype=jnp.float32, tiles=SMALL, D=D):
     """(the kernel's rows, the three products') of the stack's groups, and
     the kernel's whole result."""
     sizes = np.asarray(sizes, np.int32)
     R = int(sizes.sum())
-    args = (*_operands(R, F, G, dtype), jnp.asarray(sizes),
+    args = (*_operands(R, F, G, dtype, D=D), jnp.asarray(sizes),
             None if first is None else jnp.int32(first))
     got = jax.jit(lambda *a: op.expert_mlp(*a, tiles=tiles, interpret=True))(
         *args)
@@ -80,6 +80,31 @@ def test_the_kernel_is_the_three_products_and_the_swiglu(sizes, G, kwargs,
     got, want, _ = _both(sizes, G, **kwargs)
     np.testing.assert_allclose(got, want, **tolerance)
     assert np.abs(want).max() > 0.3
+
+
+@pytest.mark.parametrize("sizes", [
+    [3, 0, 250, 10, 120, 60], [0, 0, 0, 2, 300, 141], [200, 56, 1, 255, 0, 0]],
+    ids=["an-empty-expert-and-rows-that-cross-a-tiles-end",
+         "one-held-pair-in-443", "every-pair-held-and-a-tile-filled-exactly"])
+def test_the_kernel_at_the_widest_row_is_the_three_products(sizes):
+    """LongCat-Flash's experts, d = 6,144 and F = 2,048 in three matrices,
+    at the op's own tiles there (256 rows a block, 64 a product, four column
+    tiles of 512): a layer's 4 held experts of a stack, then the two ids past
+    its end that `models/longcat.py` sends the absent experts' pairs and the
+    zero-compute experts' to, which are given no row of any matrix."""
+    D, F = 6144, 2048
+    assert op._tiles(sum(sizes), D, F, 2) == (256, 64, 512)
+    assert op._column_tile(D, F, 2) == 512 and F % 512 == 0
+    got, want, whole = _both(sizes, 4, F=F, first=0, tiles=None, D=D)
+    assert got.shape[0] == sum(sizes[:4])
+    # float32 sums over 6,144 and 2,048 lanes in another order
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-4)
+    assert np.abs(want).max() > 1.0 or not sum(sizes[:4])
+    # a tile that holds rows of the stack's experts comes back zero outside
+    # them; the caller masks the rest (`moe._experts`)
+    held = sum(sizes[:4])
+    tile_end = -(-held // 256) * 256
+    assert not whole[held:min(tile_end, len(whole))].any()
 
 
 def test_rows_of_no_matrix_come_back_zero_beside_a_held_group_or_unread():

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import deepseek, kimi
+from ray_tpu.models import deepseek, kimi, mla
 
 op = importlib.import_module("ray_tpu.ops.mla_attend")
 
@@ -152,7 +152,9 @@ def test_the_families_call_the_op_for_every_slots_one_lane_and_only_there(
         calls.append(args[2].shape)             # the latent leaf, whole
         return op.mla_attend(*args, **kwargs)
 
-    monkeypatch.setattr(family, "mla_attend", seen)
+    # deepseek's layer is `models/mla.py`'s, which holds the name it calls
+    monkeypatch.setattr(mla if family is deepseek else family, "mla_attend",
+                        seen)
     _one_layers_call(family, C, slot)
     assert len(calls) == (1 if through else 0)
     assert all(len(shape) == 4 for shape in calls)

@@ -696,6 +696,59 @@ def test_nemotron_serving_programs_compile_at_the_configurations_sizes(
         "expert_matrix_copies": []}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_longcat_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-longcat-assistant`'s two programs, as its
+    configuration file has them (LongCat-Flash-Chat's widths, double layers
+    0-3 with 16 of 512 routed experts a layer beside 256 zero-compute
+    outputs and an eighth of the vocabulary, 128 slots of 3,072 positions of
+    latent rows in 8 sublayers, chunks of 128), from
+    rehearse/compile_longcat_for_v5e.py: the bytes the file gives, with ==,
+    and room for the pool beside the larger, between 75% and 95% of the
+    chip; the Pallas kernels (two `mla_attend` at 64 heads and one
+    `expert_mlp` at d 6,144 x F 2,048 in the layers' one loop body: 3 in the
+    decode program, and the further lanes' one more `expert_mlp` in the
+    chunk program); no instruction copies a cache leaf or one sublayer's
+    rows for all slots, an expert matrix or a dense FFN's out of its
+    stack."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_longcat_for_v5e import (CONFIG, compile_step,
+                                         kv_bytes_per_token, made_of,
+                                         pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    chunk = str(config["deployment"]["prefill_chunk_size"])
+    if program == "decode":
+        assert sized["total"] == memory["decode_step_bytes"]
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 28
+    else:
+        assert sized["total"] == memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk]
+        assert sized["temp"] < 2 ** 30
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 128 * 128 * 4)     # the chunk's tokens
+    assert kv_bytes_per_token(config) == memory["kv_bytes_per_token"] == 9216
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert 0.75 * HBM_BYTES <= memory["prefill_chunk_bytes_by_chunk_size"][
+        chunk] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in calls) == (1 if program == "decode" else 2)
+    assert sum("/mla_attend/" in c for c in calls) == 2
+    assert made_of(hlo, config) == {
+        "kernels": 3 if program == "decode" else 4, "leaf_copies": {},
+        "sublayer_rows_copies": {}, "expert_matrix_copies": [],
+        "dense_matrix_copies": []}
+
+
 # The cell `serve-keye-longdoc`'s two programs, from
 # rehearse/compile_keye_for_v5e.py. Since PR 54 every slot's first lane
 # attends through `ops/dsa_attend.py`: the decode program's temporaries were
@@ -913,6 +966,53 @@ def test_expert_mlp_kernel_at_solars_widths(chips):
         arr((rows, D), jnp.float32), arr((G, D, F)), arr((G, D, F)),
         arr((G, F, D)), arr((G + 1,), jnp.int32),
         arr((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_expert_mlp_kernel_at_longcats_widths(chips):
+    """d = 6,144 and F = 2,048, the widest row the kernel meets, with the
+    stack of 4 x 16 held experts and a decode step's 1,536 pairs: the column
+    tile is 512 (four steps an expert), its three matrices' double buffers
+    37.7 MB of `WEIGHT_TILES_BYTES` 40, and Mosaic takes them beside the
+    float32 rows' and output's blocks of 256 rows, 6.3 MB each, under
+    `VMEM_LIMIT_BYTES`."""
+    op = importlib.import_module("ray_tpu.ops.expert_mlp")
+    one = SingleDeviceSharding(chips[0])
+    D, F, G, rows = 6144, 2048, 64, 1536
+    assert op._tiles(rows, D, F, 2) == (256, 64, 512)
+    assert 2 * 3 * D * 512 * 2 <= op.WEIGHT_TILES_BYTES
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(op.expert_mlp).lower(
+        arr((rows, D), jnp.float32), arr((G, D, F)), arr((G, D, F)),
+        arr((G, F, D)), arr((G + 2,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_mla_attend_kernel_at_64_heads(chips):
+    """`ops/mla_attend.py` at LongCat's head count and cache shape, 8
+    sublayers x 128 slots x 3,072 positions: `[64, 1024]` float32 scores and
+    a `[64, 512]` accumulator a grid step fit beside the blocks' buffers
+    under the kernel's 32 MB, and the program holds nothing beside its
+    arguments."""
+    op = importlib.import_module("ray_tpu.ops.mla_attend")
+    assert op._block(3072) == 1024
+    one = SingleDeviceSharding(chips[0])
+    L, B, T = 8, 128, 3072
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: op.mla_attend(
+        *a, 192 ** -0.5, kernel=True)).lower(
+        arr((B, 64, 512)), arr((B, 64, 64)), arr((L, B, T, 512)),
+        arr((L, B, T, 64)), arr((), jnp.int32), arr((B,), jnp.int32),
+        arr((B,), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
