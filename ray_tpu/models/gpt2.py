@@ -376,10 +376,12 @@ CACHE_TOKEN_AXIS = {"k": 3, "v": 3}
 
 def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
     """KV cache of all layers: {"k","v"}: [n_layer, B, H, T, Dh] (compute
-    dtype). `decode_step` and `prefill_chunk` carry it whole through their
-    loop over the layers and write their new rows into it; the update is in
-    place only where the caller donates the cache to the jitted step
-    (`donate_argnums`), otherwise the program copies it once on entry."""
+    dtype). `prefill_chunk` carries it whole through its loop over the
+    layers and writes a layer's new rows into it there; `decode_step` only
+    reads it in its loop and writes all layers' rows once, after it. Either
+    update is in place only where the caller donates the cache to the
+    jitted step (`donate_argnums`), otherwise the program copies it once on
+    entry."""
     T = max_len or cfg.max_seq_len
     shape = (cfg.n_layer, batch, cfg.n_head, T, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
@@ -388,19 +390,29 @@ def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
 # the narrowest stretch of positions a cache write touches. On the TPU the
 # cache [.., T, Dh] with Dh = 64 lies with T along the 128 lanes of a tile; a
 # narrower update prefers another layout, and the compiler then re-lays the
-# whole cache around the loop to suit it (tests/test_tpu_compile.py)
+# whole cache to suit it (tests/test_tpu_compile.py). The decode step's one
+# write is this window too, all the layers deep. A window whose start along T
+# is computed moves a 4 KB tile at a time, ~65 ns each however deep it is
+# (benchmarks/cache_write_windows.py has the table)
 _WRITE_WINDOW = 128
 
 
 def _cache_write(c, l, val, pos0, ok):
-    """Layer l of the carried cache c [L,B,H,T,Dh] takes val [B,H,C,Dh]:
-    lane i of slot b goes to position pos0[b] + i where ok[b, i]; nothing
-    else changes. Per slot one window of W >= C positions is read, blended
-    and written back in place: dynamic_update_slice clamps its start near
-    the end of the sequence, so an unmasked block write would smear garbage
-    lanes over valid earlier positions."""
-    _, B, H, T, Dh = c.shape
-    C = val.shape[2]
+    """The cache c [L,B,H,T,Dh] takes val: lane i of slot b goes to position
+    pos0[b] + i where ok[b, i]; nothing else changes. With a layer's index l
+    val is that layer's [B,H,C,Dh] (`prefill_chunk`, inside its loop); with
+    l None it is every layer's, [L,B,H,C,Dh] (`decode_step`, after its
+    loop), and a slot's window is all L layers deep. Per slot one window of
+    W >= C positions is read, blended and written back in place:
+    dynamic_update_slice clamps its start near the end of the sequence, so
+    an unmasked block write would smear garbage lanes over valid earlier
+    positions."""
+    L, B, H, T, Dh = c.shape
+    if l is None:
+        l = 0                               # from the first layer, L deep
+    else:
+        val, L = val[None], 1
+    C = val.shape[3]
     W = min(T, max(C, _WRITE_WINDOW))
     # a lone row's window starts on a multiple of W, the edge of a tile
     # there: such a window is written in 7.7 us against 12.5 (PERF.md, PR 24)
@@ -408,23 +420,29 @@ def _cache_write(c, l, val, pos0, ok):
     # window lane w (at position start + w) takes val lane w - (pos0 - start)
     src = jnp.arange(W)[None, :] - (pos0 - start)[:, None]            # [B, W]
     hit = (src[:, :, None] == jnp.arange(C)) & ok[:, None, :]      # [B, W, C]
-    # the lanes are moved by a 0/1 matrix: exact (one product of 1 a lane),
-    # and a product's result takes the layout its consumer has, where a
-    # gather or a reshape would hand val's own layout on to the whole cache
-    moved = jnp.einsum("bwc,bhcd->bhwd", hit.astype(val.dtype), val,
-                       precision=lax.Precision.HIGHEST)
+    # a chunk's lanes are moved by a 0/1 matrix: exact (one product of 1 a
+    # lane), and a product's result takes the layout its consumer has, where
+    # a gather or a reshape would hand val's own layout on to the whole
+    # cache. A lone row is broadcast along its window where it is blended:
+    # moved for all slots first, it is a window a slot written out and read
+    # back, as many bytes again as the write moves
+    moved = val if C == 1 else jnp.einsum(
+        "bwc,lbhcd->lbhwd", hit.astype(val.dtype), val,
+        precision=lax.Precision.HIGHEST)
     take = hit.any(axis=-1)                                           # [B, W]
     for b in range(B):
         at = (l, b, 0, start[b], 0)
-        old = lax.dynamic_slice(c, at, (1, 1, H, W, Dh))
-        new = jnp.where(take[b][:, None], moved[b], old)
+        old = lax.dynamic_slice(c, at, (L, 1, H, W, Dh))
+        new = jnp.where(take[b][:, None], moved[:, b:b + 1], old)
         c = lax.dynamic_update_slice(c, new, at)
     return c
 
 
 def _cached_layers(layer, x, params: Params, cache):
     """x through `layer(x, ck, cv, bp, l) -> (x, ck, cv)` for each block
-    bp = params["blocks"][l], ck/cv the whole caches. Returns (x, cache)."""
+    bp = params["blocks"][l], ck/cv the whole caches. Returns (x, cache).
+    `prefill_chunk`'s loop; `decode_step` has its own, which carries x
+    alone."""
     def body(carry, scanned):
         l, bp = scanned
         return layer(*carry, bp, l), None
@@ -454,49 +472,69 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     (logits [B, vocab] f32, new_cache). Inactive slots' caches are untouched
     and their logits are garbage — the engine masks them.
 
-    The cache is the carry of the loop over the layers: a layer writes the
-    [H, Dh] row at pos[b] of each active slot into it (`_cache_write`) and
-    reads its own [B,H,T,Dh] slice once, for the scores and the weighted
-    values. The caller must donate `cache` for that to happen in place.
+    The loop over the layers carries the hidden state alone and only reads
+    the cache: a layer attends to its own [B,H,T,Dh] slice as it was before
+    this step, for the positions before pos[b], and to its new row directly
+    (the row's score takes column pos[b] of the scores; what the cache holds
+    at pos[b] and beyond never reaches the result). The new rows leave the
+    loop stacked, [L,B,H,Dh] a leaf, and go into the cache once, after it:
+    one window all the layers deep a slot a leaf (`_cache_write`), 2 B
+    updates a step where a write in every layer made 2 B n_layer. The caller
+    must donate `cache` for that to happen in place.
     """
     B = tokens.shape[0]
     H, Dh = cfg.n_head, cfg.head_dim
-    T = cache["k"].shape[3]
+    L, _, _, T, _ = cache["k"].shape
     wte = params["wte"]
     with jax.named_scope("embed"):
         x = wte[tokens] + params["wpe"][
             jnp.clip(pos, 0, cfg.max_seq_len - 1)]
         x = x.astype(cfg.dtype)                               # [B, D]
-    ok = active[:, None]
+    t_idx = jnp.arange(T)[None, None, :]
+    own = t_idx == pos[:, None, None]                         # [B, 1, T]
+    before = t_idx < pos[:, None, None]
 
-    def layer(x, ck, cv, bp, l):                          # ck/cv [L,B,H,T,Dh]
+    def layer(x, scanned):
+        l, bp = scanned
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1"])
             qkv = h @ lm.weight(bp["attn"]["wqkv"], cfg.dtype) + \
                 lm.weight(bp["attn"]["bqkv"], cfg.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, H, Dh)
-            k = k.reshape(B, H, 1, Dh)
-            v = v.reshape(B, H, 1, Dh)
-            with jax.named_scope("kv_update"):
-                ck = _cache_write(ck, l, k, pos, ok)
-                cv = _cache_write(cv, l, v, pos, ok)
-            scores = jnp.einsum("bhd,bhtd->bht", q, ck[l],
+            q, k, v = (a.reshape(B, H, Dh) for a in jnp.split(qkv, 3, -1))
+            scores = jnp.einsum("bhd,bhtd->bht", q, cache["k"][l],
                                 preferred_element_type=jnp.float32)
+            # the new row's own score where the row will lie: the same
+            # [B,H,T] columns as if it had been written first
+            scores = jnp.where(own, jnp.einsum(
+                "bhd,bhd->bh", q, k,
+                preferred_element_type=jnp.float32)[:, :, None], scores)
             scores = scores / math.sqrt(Dh)
-            t_idx = jnp.arange(T)[None, None, :]
-            scores = jnp.where(t_idx <= pos[:, None, None], scores, -1e30)
+            scores = jnp.where(before | own, scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bht,bhtd->bhd", probs, cv[l])
-            attn = attn.reshape(B, H * Dh)
+            # the cache's values before pos, the new row's at pos: summed
+            # in float32 and rounded once, as one product over T would be
+            attn = jnp.einsum("bht,bhtd->bhd", jnp.where(before, probs, 0),
+                              cache["v"][l],
+                              preferred_element_type=jnp.float32)
+            p_own = jnp.sum(jnp.where(own, probs, 0), axis=-1,
+                            dtype=jnp.float32)                    # [B, H]
+            attn = attn + p_own[:, :, None] * v.astype(jnp.float32)
+            attn = attn.astype(cfg.dtype).reshape(B, H * Dh)
             attn = attn @ lm.weight(bp["attn"]["wo"], cfg.dtype) + \
                 lm.weight(bp["attn"]["bo"], cfg.dtype)
             x = x + attn
         with jax.named_scope("mlp"):
             x = x + _mlp(_layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
-        return x, ck, cv
+        return x, {"k": k, "v": v}
 
-    x, cache = _cached_layers(layer, x, params, cache)
+    # the loop closes over the caches (read only) and stacks each layer's
+    # new k and v row as its output, [L,B,H,Dh] a leaf
+    with jax.named_scope("layers"):
+        x, rows = lax.scan(layer, x, (jnp.arange(L), params["blocks"]))
+    with jax.named_scope("kv_update"):
+        cache = {name: _cache_write(cache[name], None, row[:, :, :, None],
+                                    pos, active[:, None])
+                 for name, row in rows.items()}
     with jax.named_scope("unembed_loss"):
         x = _layer_norm(x, params["ln_f"])
         logits = (x @ _unembedding(params, cfg)).astype(jnp.float32)

@@ -128,6 +128,125 @@ def test_decode_step_writes_one_row_per_active_slot(params, steps, case):
            _written(pos, ones, active))
 
 
+def _write_then_attend(params, cache, tokens, pos, active, cfg):
+    """`decode_step` as it was until PR 56, written plainly: every layer
+    stores its new row at pos[b] of each active slot first, then attends
+    over its whole slice of the cache to that position. Precision as the
+    program's: rows and probabilities in cfg.dtype, scores in float32."""
+    n = tokens.shape[0]
+    H, Dh = cfg.n_head, cfg.head_dim
+    ck, cv = cache["k"], cache["v"]
+    t_len = ck.shape[3]
+    slot = jnp.arange(n)
+    x = (params["wte"][tokens] + params["wpe"][
+        jnp.clip(pos, 0, cfg.max_seq_len - 1)]).astype(cfg.dtype)
+    for l in range(cfg.n_layer):
+        bp = jax.tree.map(lambda w: w[l], params["blocks"])
+        h = gpt2._layer_norm(x, bp["ln1"])
+        qkv = h @ bp["attn"]["wqkv"].astype(cfg.dtype) + \
+            bp["attn"]["bqkv"].astype(cfg.dtype)
+        q, k, v = (a.reshape(n, H, Dh) for a in jnp.split(qkv, 3, -1))
+        keep = ~active[:, None, None]
+        ck = ck.at[l, slot, :, pos].set(jnp.where(keep, ck[l, slot, :, pos], k))
+        cv = cv.at[l, slot, :, pos].set(jnp.where(keep, cv[l, slot, :, pos], v))
+        scores = jnp.einsum("bhd,bhtd->bht", q, ck[l],
+                            preferred_element_type=jnp.float32)
+        seen = jnp.arange(t_len)[None, None, :] <= pos[:, None, None]
+        scores = jnp.where(seen, scores / math.sqrt(Dh), -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        attn = jnp.einsum("bht,bhtd->bhd", probs, cv[l]).reshape(n, H * Dh)
+        x = x + attn @ bp["attn"]["wo"].astype(cfg.dtype) + \
+            bp["attn"]["bo"].astype(cfg.dtype)
+        x = x + gpt2._mlp(gpt2._layer_norm(x, bp["ln2"]), bp["mlp"], cfg)
+    x = gpt2._layer_norm(x, params["ln_f"])
+    logits = (x @ params["wte"].T.astype(cfg.dtype)).astype(jnp.float32)
+    return logits, {"k": ck, "v": cv}
+
+
+# float32: the order of a sum (3e-7 read); bfloat16: the last bit of a
+# value near 1, 0.0039, on its way through two layers (0.0036 read: both
+# forms hold rows and probabilities in bf16 and round the weighted values
+# once)
+_OWN_ROW_TOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("at", [0, 1, 127, 128, T - 1],
+                         ids=lambda p: f"pos{p}")
+def test_decode_step_attends_to_its_own_row_and_writes_it_after(dtype, at):
+    """The layer attends to the cache as it was before the step plus its
+    own new row, and the rows of all layers are written after the loop:
+    the logits and the cache of write-then-attend. Slot 1 is inactive at
+    the same position and slot 2 stands elsewhere. What the cache holds at
+    a slot's position and beyond is stale: finite junk there gives, bit for
+    bit, the logits and the written rows that zeros give."""
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny", dtype=_DTYPES[dtype],
+                                 max_seq_len=T, attn_impl="dense")
+    params = gpt2.init_params(jax.random.key(9), cfg)
+    rng = np.random.default_rng(at)
+    shape = (cfg.n_layer, B, cfg.n_head, T, cfg.head_dim)
+    junk = {n: jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+            for n in "kv"}
+    pos = np.array([at, at, (at + 64) % T, at])
+    active = np.array([True, False, True, True])
+    stale = (np.arange(T)[None, :] >= pos[:, None]) & active[:, None]
+    clean = {n: jnp.where(stale[None, :, None, :, None], 0, a)
+             for n, a in junk.items()}
+    args = (jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+    step = jax.jit(lambda c: gpt2.decode_step(params, c, *args, cfg))
+    logits, new = step(junk)
+    logits_clean, new_clean = step(clean)
+    ref_logits, ref = jax.jit(
+        lambda c: _write_then_attend(params, c, *args, cfg))(junk)
+    tol = _OWN_ROW_TOL[dtype]
+
+    def f32(a):
+        return np.asarray(a.astype(jnp.float32))
+
+    assert np.abs(f32(logits)).max() > 0.1
+    np.testing.assert_allclose(f32(logits)[active], f32(ref_logits)[active],
+                               rtol=tol, atol=tol)
+    assert _same_bits(logits[active], logits_clean[active])
+    written = _written(pos, np.ones(B, np.int64), active)
+    keep = np.broadcast_to(~written[None, :, None, :, None], shape)
+    for n in "kv":
+        got, before, want = (np.asarray(a[n]) for a in (new, junk, ref))
+        # outside the rows of active slots, the inactive slot's whole
+        # cache among them: the bits that came in, and so the reference's
+        assert _same_bits(got[keep], before[keep])
+        assert _same_bits(want[keep], before[keep])
+        np.testing.assert_allclose(f32(new[n])[~keep], f32(ref[n])[~keep],
+                                   rtol=tol, atol=tol)
+        assert not _same_bits(got[~keep], before[~keep])
+        assert _same_bits(got[~keep], np.asarray(new_clean[n])[~keep])
+
+
+def test_engine_decodes_the_tokens_of_write_then_attend(monkeypatch):
+    """32 greedy steps of the engine, two streams in their slots: the
+    tokens the engine gives with the decode program PR 56 replaced."""
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.utils.platform import ensure_virtual_cpu
+
+    ensure_virtual_cpu(1)
+    kw = dict(preset="gpt2-tiny", max_batch=2, max_seq_len=T, seed=5,
+              enable_prefix_caching=False)
+    prompts = ["the quick brown fox ", "a decode step writes its rows once"]
+
+    def tokens_of(engine):
+        try:
+            return [engine.generate(p, max_tokens=32)["token_ids"]
+                    for p in prompts]
+        finally:
+            engine.shutdown()
+
+    got = tokens_of(LLMEngine(**kw))
+    monkeypatch.setattr(gpt2, "decode_step", _write_then_attend)
+    want = tokens_of(LLMEngine(**kw))
+    assert all(len(ids) == 32 for ids in want)
+    assert got == want
+
+
 CHUNK_CASES = {
     # pos0, length, active
     "lengths-0-1-C": ([3, 10, 0, 20], [0, 1, C, 4], [1, 1, 1, 1]),
