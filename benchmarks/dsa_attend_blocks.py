@@ -37,7 +37,7 @@ bytes in under half the time, at 82% of the HBM's peak, where
 divide 13,312 and its last block hangs over. The kernel's values lie
 within 4.2e-4 of the plain form's, whose r.m.s. is 0.038: the unnormalised
 probabilities rounded to bf16 where the plain form rounds the normalised
-ones. `ops/dsa_attend.BLOCK` is 1,024: the best where every slot is live,
+ones. `ops/slot_rows.BLOCK` is 1,024: the best where every slot is live,
 12% behind 2,048 where four are, and `mla_attend`'s and `gqa_attend`'s.
 
 The choice alone, `[32, 13312]` float32 scores: by index (`select_rows`)
@@ -144,8 +144,11 @@ def main() -> int:
     from harness import spec
     from ray_tpu.ops import dsa
 
+    from ray_tpu.ops import slot_rows
+
     op = importlib.import_module("ray_tpu.ops.dsa_attend")
-    out = {"device": jax.devices()[0].device_kind, "default_block": op.BLOCK,
+    out = {"device": jax.devices()[0].device_kind,
+           "default_block": slot_rows.BLOCK,
            "shape": {"layers": L, "slots": B, "kv_heads": G, "queries": R,
                      "lanes": D, "T": T, "topk": TOPK}}
     peak = spec.peaks()[out["device"]]["hbm_bytes_per_s"]
@@ -183,8 +186,11 @@ def main() -> int:
                 fn, chose = op.dsa_attend, by_index
             else:
                 chose = as_mask
-                fn = lambda *a, block=block: op._attend_kernel(  # noqa: E731
-                    *a, block, False)
+                def fn(q, ck, cv, layer, pos, live, keep, scale,
+                       block=block):
+                    return slot_rows.attend(
+                        op.rows_kernel(q, ck, cv, keep, scale), layer, pos,
+                        live, block=block)
 
             # the calls are one program's loop, as the layers' loop is, the
             # leaves and the set its arguments (`mla_attend_blocks.py` has

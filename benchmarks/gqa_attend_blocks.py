@@ -35,7 +35,7 @@ and, where few slots are live, the grid steps that do nothing (0.11 us
 each: 1,800 of them at 512, 900 at 1,024). 2,048 does not divide 25,600
 and its last block hangs over. The kernel's values lie within 2.6e-7 of
 the plain form's, whose r.m.s. is 0.017: the two pieces carry the float32
-q and the probabilities through both. `ops/gqa_attend.BLOCK` is 1,024:
+q and the probabilities through both. `ops/slot_rows.BLOCK` is 1,024:
 within 1.3% of the best where every slot is live, 13% better than 512
 where four are, and `mla_attend`'s.
 
@@ -76,8 +76,11 @@ def main() -> int:
 
     from harness import spec
 
+    from ray_tpu.ops import slot_rows
+
     op = importlib.import_module("ray_tpu.ops.gqa_attend")
-    out = {"device": jax.devices()[0].device_kind, "default_block": op.BLOCK,
+    out = {"device": jax.devices()[0].device_kind,
+           "default_block": slot_rows.BLOCK,
            "shape": {"layers": L, "slots": B, "kv_heads": G, "queries": R,
                      "lanes": D, "T": T}}
     peak = spec.peaks()[out["device"]]["hbm_bytes_per_s"]
@@ -98,8 +101,10 @@ def main() -> int:
             if block is None:
                 fn = functools.partial(op.gqa_attend, kernel=False)
             else:
-                fn = lambda *a, block=block: op._attend_kernel(  # noqa: E731
-                    *a, block, False)
+                def fn(q, ck, cv, layer, pos, live, scale, block=block):
+                    return slot_rows.attend(
+                        op.rows_kernel(q, ck, cv, scale), layer, pos, live,
+                        block=block)
 
             # the calls are one program's loop, as the layers' loop is, the
             # leaves its arguments (`mla_attend_blocks.py` has why); a call

@@ -35,7 +35,7 @@ the roofline, positions read over positions attended):
 The least are 1.225, 0.131 and 0.037 ms. A grid step that does nothing
 costs 0.144 us (the last column: 5,120 steps against 1,280), one that works
 ~0.35. The kernel's result lies within 0.0024 of the plain form's where the
-plain form's values have an r.m.s. of 0.09-0.11. `ops/mla_attend.BLOCK` is
+plain form's values have an r.m.s. of 0.09-0.11. `ops/slot_rows.BLOCK` is
 1,024.
 
 LongCat's cell (PR 55, `--shapes longcat`: 8 sublayers x 128 slots x 3,072
@@ -101,8 +101,11 @@ def main() -> int:
 
     from harness import spec
 
+    from ray_tpu.ops import slot_rows
+
     op = importlib.import_module("ray_tpu.ops.mla_attend")
-    out = {"device": jax.devices()[0].device_kind, "default_block": op.BLOCK}
+    out = {"device": jax.devices()[0].device_kind,
+           "default_block": slot_rows.BLOCK}
     peaks = spec.peaks()[out["device"]]
     bf = jnp.bfloat16
     for name in args.shapes.split(","):
@@ -129,8 +132,11 @@ def main() -> int:
             if block is None:
                 fn = functools.partial(op.mla_attend, kernel=False)
             else:
-                fn = lambda *a, block=block: op._attend_kernel(  # noqa: E731
-                    *a, block, False)
+                def fn(q_abs, q_r, lat, kr, layer, pos, live, scale,
+                       block=block):
+                    return slot_rows.attend(
+                        op.rows_kernel(q_abs, q_r, lat, kr, scale), layer,
+                        pos, live, block=block)
 
             # the calls are one program's loop, as the layers' loop is (a
             # call dispatched alone costs the host 0.6 ms, more than

@@ -1,6 +1,6 @@
 """One token's grouped-head attention over the rows a learned indexer chose
-(decode), a Pallas kernel on the TPU: `ops/mla_attend.py`'s and
-`ops/gqa_attend.py`'s sibling for `ops/dsa.py`'s third step.
+(decode), a Pallas kernel on the TPU, on `ops/slot_rows.py`'s grid:
+`ops/dsa.py`'s third step.
 
 The cache of a sparse-attention layer holds a token's keys and values of all
 G key-value heads side by side, two leaves `[layers, slots, T, G x d]`
@@ -30,8 +30,8 @@ slot ends. The precision is the plain form's: q, the rows and the
 probabilities one piece in the rows' dtype, float32 accumulation.
 
 The grid (slot, block), the clamped block index and the slot that is not
-live are `mla_attend`'s (`_plan`). A block that holds no row of the set
-leaves a running maximum of `_MASKED` and weights of 1 behind; the first
+live are `slot_rows.attend`'s. A block that holds no row of the set
+leaves a running maximum of `MASKED` and weights of 1 behind; the first
 block with a chosen row shrinks them to nothing (exp(-1e30) is 0), and a
 live slot's set is never empty.
 
@@ -50,117 +50,43 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops import dsa, mla_attend as _mla
+from ray_tpu.ops import dsa, slot_rows
+from ray_tpu.ops.slot_rows import MASKED, Leaf
 
-# Positions a grid step takes of a slot's rows, at most (both leaves: 2 MB
-# of bf16 at 1,024 positions of 4 x 128). `mla_attend.BLOCK` has the trade.
-# On the v5e at 32 slots x 13,312 positions, live at 8.2k-12.9k with 2,048
-# rows chosen, a call takes 1.11 / 1.05 / 1.11 ms at 512 / 1,024 / 2,048
-# positions (the gather's form 2.29; the dense bytes at the HBM's peak
-# 0.85, the chosen rows' 0.16), and with 4 of the 32 slots live 0.24 / 0.19
-# / 0.17 (plain 2.29): `benchmarks/dsa_attend_blocks.py`, PERF.md PR 54
-BLOCK = 1024
-# two buffers of a block of both leaves (4 MB at 1,024 positions) and of
-# the mask, a head's scores and probabilities in float32. (Not 64 MB:
-# `gqa_attend.VMEM_LIMIT_BYTES`)
-VMEM_LIMIT_BYTES = 32 * 1024 * 1024
-_MASKED = _mla._MASKED
+# `slot_rows.BLOCK` for these leaves (both: 2 MB of bf16 at 1,024 positions
+# of 4 x 128), on the v5e: at 32 slots x 13,312 positions, live at
+# 8.2k-12.9k with 2,048 rows chosen, a call takes 1.11 / 1.05 / 1.11 ms at
+# 512 / 1,024 / 2,048 positions (the gather's form 2.29; the dense bytes at
+# the HBM's peak 0.85, the chosen rows' 0.16), and with 4 of the 32 slots
+# live 0.24 / 0.19 / 0.17 (plain 2.29): `benchmarks/dsa_attend_blocks.py`,
+# PERF.md PR 54
 
 
-def _kernel(layer_ref, src_ref, first_ref, last_ref, pos_ref, q_ref,
-            keep_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            block: int, T: int, scale: float):
-    """One block of one slot's rows of one layer, the G heads in turn."""
-    del layer_ref, src_ref, first_ref, last_ref
-    slot, j = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[slot]                               # -1: the slot is dead
-    G, _, d = acc_ref.shape
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, _MASKED)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block <= pos)
-    def _():
-        t = j * block + lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        # past the leaf's end the mask is whatever VMEM held: pos < T
-        seen = (keep_ref[0] != 0) & (t <= pos)                 # [1, block]
-        ends = (((1,), (1,)), ((), ()))           # both operands' last axis
-        if T % block:
-            # the last block hangs over the leaf's end: what lies there is
-            # whatever VMEM held, and 0 x NaN is no 0
-            held = j * block + lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0) < T
-        for g in range(G):
-            lanes = pl.ds(g * d, d)
-            k, v = k_ref[0, 0, :, lanes], v_ref[0, 0, :, lanes]  # [block,d]
-            s = lax.dot_general(q_ref[0, g], k, ends,
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(seen, s, _MASKED)                    # [R, block]
-            if T % block:
-                v = jnp.where(held, v, jnp.zeros_like(v))
-            m_old = m_ref[g]
-            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-            shrink = jnp.exp(m_old - m_new)
-            p = jnp.exp(s - m_new)
-            l_ref[g] = shrink * l_ref[g] + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[g] = shrink * acc_ref[g] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_ref[g] = m_new
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        total = l_ref[...]
-        o_ref[0] = acc_ref[...] / jnp.where(total == 0.0, 1.0, total)
+def _block_body(blk, q_ref, keep_ref, k_ref, v_ref, *, scale: float):
+    """The G heads in turn, head g the block's lanes g d .. (g + 1) d."""
+    _, G, _, d = q_ref.shape
+    t = blk.at((1, blk.block), 1)
+    # past the leaf's end the mask is whatever VMEM held: pos < T
+    seen = (keep_ref[0] != 0) & (t <= blk.pos)                 # [1, block]
+    ends = (((1,), (1,)), ((), ()))               # both operands' last axis
+    held = blk.held((blk.block, 1), 0)
+    for g in range(G):
+        lanes = pl.ds(g * d, d)
+        k, v = k_ref[0, 0, :, lanes], v_ref[0, 0, :, lanes]    # [block, d]
+        s = lax.dot_general(q_ref[0, g], k, ends,
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, MASKED)                         # [R, block]
+        yield g, s, slot_rows.zero_past_end(v, held)
 
 
-def _block(T: int) -> int:
-    return _mla._block(T, BLOCK)
-
-
-def _attend_kernel(q, ck, cv, layer, pos, live, keep, scale, block,
-                   interpret: bool):
-    B, G, R, d = q.shape
-    T = ck.shape[2]
-    block = block or _block(T)
-
-    def block_of(slot, j, first, last):
-        return jnp.clip(j, first[slot], last[slot])
-
-    def rows(slot, j, layer, src, first, last, pos):
-        return layer[0], src[slot], block_of(slot, j, first, last), 0
-
-    def mask(slot, j, layer, src, first, last, pos):
-        return src[slot], 0, block_of(slot, j, first, last)
-
-    def own(slot, j, *_):
-        return slot, 0, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5, grid=(B, -(-T // block)),
-        in_specs=[pl.BlockSpec((1, G, R, d), own),
-                  pl.BlockSpec((1, 1, block), mask),
-                  pl.BlockSpec((1, 1, block, G * d), rows),
-                  pl.BlockSpec((1, 1, block, G * d), rows)],
-        out_specs=pl.BlockSpec((1, G, R, d), own),
-        scratch_shapes=[pltpu.VMEM((G, R, 1), jnp.float32),
-                        pltpu.VMEM((G, R, 1), jnp.float32),
-                        pltpu.VMEM((G, R, d), jnp.float32)])
-    return pl.pallas_call(
-        functools.partial(_kernel, block=block, T=T, scale=float(scale)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, R, d), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="dsa_attend", interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      *_mla._plan(pos, live, T, block), q.astype(ck.dtype),
-      keep.astype(jnp.int32)[:, None], ck, cv)
+def rows_kernel(q, ck, cv, keep, scale) -> slot_rows.Kernel:
+    """This kernel on `slot_rows.attend`'s grid: the set as a mask
+    `[B, 1, T]` of int32, a block of it beside the leaves'."""
+    return slot_rows.Kernel(
+        "dsa_attend", functools.partial(_block_body, scale=float(scale)),
+        (q.astype(ck.dtype), Leaf(keep.astype(jnp.int32)[:, None], 2, False),
+         Leaf(ck, 2), Leaf(cv, 2)), q.shape[1:])
 
 
 def rows_chosen(scores, k: int, *, kernel: bool | None = None,
@@ -170,7 +96,7 @@ def rows_chosen(scores, k: int, *, kernel: bool | None = None,
     `interpret`, or `kernel=True`) `dsa.select_mask`'s `keep` [B, T],
     elsewhere `dsa.select_rows`' `(idx, chosen)` [B, K]. The same rows
     either way."""
-    if _mla._use_kernel(kernel, interpret):
+    if slot_rows.use_kernel(kernel, interpret):
         return dsa.select_mask(scores, k)
     return dsa.select_rows(scores, k)
 
@@ -186,8 +112,8 @@ def dsa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
     slot's rows once and to its position; indices through
     `dsa.attend_selected` over a gather of the rows they name."""
     if not isinstance(rows, tuple):
-        return _attend_kernel(q, ck, cv, layer, pos, live, rows, scale, None,
-                              interpret)
+        return slot_rows.attend(rows_kernel(q, ck, cv, rows, scale), layer,
+                                pos, live, interpret=interpret)
     idx, chosen = rows
     B, G, _, d = q.shape
     k_rows, v_rows = (dsa.gather_rows(c, layer, idx).reshape(B, -1, G, d)
@@ -199,9 +125,9 @@ def read_positions(pos, live, T: int, k: int, *, kernel: bool | None = None,
                    interpret: bool = False):
     """The positions whose rows one call of `dsa_attend` reads, summed over
     the live slots (uint32): a slot's position rounded up to a block where
-    the kernel runs (`mla_attend.read_positions` at this block), the chosen
-    rows, min(pos + 1, k), plain."""
-    if _mla._use_kernel(kernel, interpret):
-        return _mla.read_positions(pos, live, T, kernel=True, most=BLOCK)
+    the kernel runs (`slot_rows.read_positions`), the chosen rows,
+    min(pos + 1, k), plain."""
+    if slot_rows.use_kernel(kernel, interpret):
+        return slot_rows.read_positions(pos, live, T, kernel=True)
     return jnp.sum(jnp.where(live.astype(bool), jnp.minimum(pos + 1, k),
                              0)).astype(jnp.uint32)

@@ -50,9 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import slot_state
 from ray_tpu.ops.pieces import pieces
 
 # a grid step takes one slot's state of the layer whole, the leaf's H heads
@@ -68,10 +67,6 @@ PIECES = 3                   # bf16 pieces that add up to a float32
 _HIGHEST = lax.Precision.HIGHEST
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _update_plain(state, layer, a, k, q, v, b, active):
     """The same arithmetic in plain XLA (the CPU backend's path, and what
     the kernel is tested against)."""
@@ -85,33 +80,23 @@ def _update_plain(state, layer, a, k, q, v, b, active):
     return lax.dynamic_update_index_in_dim(state, s_new, layer, 0), o
 
 
-def _kernel(layer_ref, active_ref, s_ref, cols_ref, pick_ref, rows_ref,
-            so_ref, o_ref, *, heads: int, p: int):
+def _update_tile(s_ref, cols_ref, pick_ref, rows_ref, so_ref, o_ref):
     """One slot's state of one layer: H tiles of [N, P]."""
-    del layer_ref
-    slot = pl.program_id(0)
-
-    @pl.when(active_ref[slot] == 0)
-    def _():
-        so_ref[...] = s_ref[...]
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(active_ref[slot] != 0)
-    def _():
-        for h in range(heads):
-            lanes = slice(h * p, (h + 1) * p)
-            group = slice(h // GROUP * LANES, (h // GROUP + 1) * LANES)
-            # [N, 3 P]: the decay, k and q of head h, each over P lanes
-            spread = jnp.dot(cols_ref[0, :, group], pick_ref[h % GROUP],
-                             preferred_element_type=jnp.float32)
-            a, k, q = (spread[:, i * p:(i + 1) * p] for i in range(3))
-            decayed = a * s_ref[0, 0, h]
-            for_k = jnp.sum(decayed * k, axis=0, keepdims=True)    # [1, P]
-            for_q = jnp.sum(decayed * q, axis=0, keepdims=True)
-            bv, b, kq = (rows_ref[0, r:r + 1, lanes] for r in range(3))
-            u = bv - b * for_k
-            o_ref[0, :, lanes] = for_q + kq * u
-            so_ref[0, 0, h] = decayed + k * u
+    heads, p = s_ref.shape[2], s_ref.shape[4]
+    for h in range(heads):
+        lanes = slice(h * p, (h + 1) * p)
+        group = slice(h // GROUP * LANES, (h // GROUP + 1) * LANES)
+        # [N, 3 P]: the decay, k and q of head h, each over P lanes
+        spread = jnp.dot(cols_ref[0, :, group], pick_ref[h % GROUP],
+                         preferred_element_type=jnp.float32)
+        a, k, q = (spread[:, i * p:(i + 1) * p] for i in range(3))
+        decayed = a * s_ref[0, 0, h]
+        for_k = jnp.sum(decayed * k, axis=0, keepdims=True)        # [1, P]
+        for_q = jnp.sum(decayed * q, axis=0, keepdims=True)
+        bv, b, kq = (rows_ref[0, r:r + 1, lanes] for r in range(3))
+        u = bv - b * for_k
+        o_ref[0, :, lanes] = for_q + kq * u
+        so_ref[0, 0, h] = decayed + k * u
 
 
 @functools.lru_cache(maxsize=None)
@@ -143,37 +128,10 @@ def _update_kernel(state, layer, a, k, q, v, b, active, interpret: bool):
         B, 3, H * P)
     rows = jnp.pad(rows, ((0, 0), (0, STRIP - 3), (0, 0)))
 
-    def leaf(slot, layer, on):
-        return layer[0], slot, 0, 0, 0
-
-    def own(slot, layer, on):
-        return slot, 0, 0
-
-    def same(slot, layer, on):
-        return 0, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B,),
-        in_specs=[pl.BlockSpec((1, 1, H, N, P), leaf),
-                  pl.BlockSpec((1, N, groups * LANES), own),
-                  pl.BlockSpec((GROUP, LANES, 3 * P), same),
-                  pl.BlockSpec((1, STRIP, H * P), own)],
-        out_specs=[pl.BlockSpec((1, 1, H, N, P), leaf),
-                   pl.BlockSpec((1, 1, H * P), own)])
-    state, o = pl.pallas_call(
-        functools.partial(_kernel, heads=H, p=P),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((B, 1, H * P), jnp.float32)],
-        # operands count the two prefetched scalars: the state is written
-        # where it is read
-        input_output_aliases={2: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="kda_update", interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
-      state, cols, jnp.asarray(_pick(P), jnp.bfloat16), rows)
+    state, o = slot_state.update(
+        "kda_update", _update_tile, state, layer, active,
+        (cols, slot_state.Same(jnp.asarray(_pick(P), jnp.bfloat16)), rows),
+        (1, H * P), vmem_limit_bytes=VMEM_LIMIT_BYTES, interpret=interpret)
     return state, o.reshape(B, H, P)
 
 
@@ -188,8 +146,6 @@ def kda_update(state: jax.Array, layer, a, k, q, v, b, active, *,
     bit. On the TPU (or with `interpret`, or `kernel=True`) the state goes
     through the Pallas kernel, which writes the leaf in place; elsewhere
     through plain XLA."""
-    if kernel is None:
-        kernel = interpret or _on_tpu()
-    if kernel:
+    if slot_state.use_kernel(kernel, interpret):
         return _update_kernel(state, layer, a, k, q, v, b, active, interpret)
     return _update_plain(state, layer, a, k, q, v, b, active)

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import slot_state
 
 # a grid step takes one slot's one key-value head whole, 4.26 MB in one
 # stretch of HBM: its four buffers (two in, two out) are 17 MiB of VMEM.
@@ -95,10 +96,6 @@ def phi(a: jax.Array) -> jax.Array:
     return jnp.tile(a, d // 2 + 1) * turned * scale
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _normaliser(norm, layer, phi_k, phi_q, g, active):
     """z <- g z + phi(k) for layer `layer`'s normalisers and the read-outs
     phi(q^r) . z: 1/128 of the state, plain XLA on every backend."""
@@ -120,42 +117,32 @@ def _update_plain(state, layer, phi_k, phi_q, v, g, active):
     return lax.dynamic_update_index_in_dim(state, s_new, layer, 0), num
 
 
-def _kernel(layer_ref, active_ref, s_ref, cols_ref, rows_ref, so_ref, num_ref,
-            *, dv: int, lanes: int, ratio: int, precision):
+def _update_tile(s_ref, cols_ref, rows_ref, so_ref, num_ref, *, ratio: int,
+                 precision):
     """One slot's one key-value head: its whole S^T, one stretch of HBM."""
-    del layer_ref
-    b = pl.program_id(0)
-    groups = lanes // 128
+    dv, lanes = s_ref.shape[-2:]
+    rows = rows_ref[0, 0]               # [8, lanes]: R of phi(q), then phi(k)
+    pk = rows[ratio:ratio + 1, :]
 
-    @pl.when(active_ref[b] == 0)
-    def _():
-        so_ref[...] = s_ref[...]
-        num_ref[...] = jnp.zeros_like(num_ref)
+    # the update on the VPU, a tile of the state at a time: the gate and the
+    # value over a strip's lanes once, then one multiply-add a tile, in and
+    # out of VMEM once
+    def strip(i, carry):
+        at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
+        v_tile = jnp.broadcast_to(cols_ref[0, 0, at, 0:1], (STRIP, 128))
+        g_tile = jnp.broadcast_to(cols_ref[0, 0, at, 1:2], (STRIP, 128))
+        for j in range(lanes // 128):
+            tile = slice(j * 128, (j + 1) * 128)
+            so_ref[0, 0, 0, at, tile] = (
+                g_tile * s_ref[0, 0, 0, at, tile] + v_tile * pk[:, tile])
+        return carry
 
-    @pl.when(active_ref[b] != 0)
-    def _():
-        rows = rows_ref[0, 0]           # [8, lanes]: R of phi(q), then phi(k)
-        pk = rows[ratio:ratio + 1, :]
-
-        # the update on the VPU, a tile of the state at a time: the gate
-        # and the value over a strip's lanes once, then one multiply-add a
-        # tile, in and out of VMEM once
-        def strip(i, carry):
-            at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
-            v_tile = jnp.broadcast_to(cols_ref[0, 0, at, 0:1], (STRIP, 128))
-            g_tile = jnp.broadcast_to(cols_ref[0, 0, at, 1:2], (STRIP, 128))
-            for j in range(groups):
-                tile = slice(j * 128, (j + 1) * 128)
-                so_ref[0, 0, 0, at, tile] = (
-                    g_tile * s_ref[0, 0, 0, at, tile] + v_tile * pk[:, tile])
-            return carry
-
-        lax.fori_loop(0, dv // STRIP, strip, 0)
-        # the read-out on the MXU: the rows against the updated state,
-        # contracted over the lanes of both (phi(k)'s row rides along)
-        num_ref[0, 0] = lax.dot_general(
-            rows, so_ref[0, 0, 0], (((1,), (1,)), ((), ())),
-            precision=precision, preferred_element_type=jnp.float32)
+    lax.fori_loop(0, dv // STRIP, strip, 0)
+    # the read-out on the MXU: the rows against the updated state,
+    # contracted over the lanes of both (phi(k)'s row rides along)
+    num_ref[0, 0] = lax.dot_general(
+        rows, so_ref[0, 0, 0], (((1,), (1,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 # the read-out's products: float32 operands split in bfloat16 pieces by the
@@ -170,35 +157,12 @@ def _update_kernel(state, layer, rows, R: int, v, g, active, interpret: bool):
     assert rows.shape == (B, H, QUERY_ROWS, W) and R < QUERY_ROWS, rows.shape
     # per slot and head a column of v and one of g, over the d_v sublanes
     cols = jnp.stack([v, jnp.broadcast_to(g[:, :, None], v.shape)], axis=-1)
-
-    def leaf(b, h, layer, on):
-        return layer[0], b, h, 0, 0
-
-    def head(b, h, layer, on):
-        return b, h, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, H),
-        in_specs=[pl.BlockSpec((1, 1, 1, dv, W), leaf),
-                  pl.BlockSpec((1, 1, dv, 2), head),
-                  pl.BlockSpec((1, 1, QUERY_ROWS, W), head)],
-        out_specs=[pl.BlockSpec((1, 1, 1, dv, W), leaf),
-                   pl.BlockSpec((1, 1, QUERY_ROWS, dv), head)])
-    state, num = pl.pallas_call(
-        functools.partial(_kernel, dv=dv, lanes=W, ratio=R,
+    state, num = slot_state.update(
+        "retention_update",
+        functools.partial(_update_tile, ratio=R,
                           precision=READ_OUT_PRECISION),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((B, H, QUERY_ROWS, dv), jnp.float32)],
-        # operands count the two prefetched scalars: the state is written
-        # where it is read
-        input_output_aliases={2: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="retention_update", interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
-      state, cols, rows)
+        state, layer, active, (cols, rows), (QUERY_ROWS, dv), grid_axes=2,
+        vmem_limit_bytes=VMEM_LIMIT_BYTES, interpret=interpret)
     return state, num[:, :, :R]
 
 
@@ -216,8 +180,6 @@ def retention_update(state: jax.Array, norm: jax.Array, layer, q, k, v, g,
     `interpret`, or `kernel=True`) the state goes through the Pallas kernel,
     which writes the leaf in place; elsewhere through plain XLA. The
     normalisers, 1/128 of the state, are plain XLA everywhere."""
-    if kernel is None:
-        kernel = interpret or _on_tpu()
     B, H, R, d = q.shape
     # one tile's rows a slot and head: the R expanded queries, the expanded
     # key, zeros: expanded together, and what the kernel takes as they are
@@ -226,7 +188,7 @@ def retention_update(state: jax.Array, norm: jax.Array, layer, q, k, v, g,
                                         jnp.float32)], axis=2))
     phi_q, phi_k = rows[:, :, :R], rows[:, :, R]
     norm, den = _normaliser(norm, layer, phi_k, phi_q, g, active)
-    if kernel:
+    if slot_state.use_kernel(kernel, interpret):
         state, num = _update_kernel(state, layer, rows, R, v, g, active,
                                     interpret)
     else:

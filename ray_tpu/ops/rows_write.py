@@ -31,11 +31,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.slot_state import use_kernel
+
 TILE = 128                   # positions a grid step reads and writes
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _write_plain(c, layer, val, pos, on, positions_last: bool):
@@ -113,8 +111,6 @@ def rows_write(c: jax.Array, layer, val, pos, on, *,
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which writes the leaf in place; elsewhere through plain XLA."""
     last = positions_last(c.shape, val.shape[-1])
-    if kernel is None:
-        kernel = interpret or _on_tpu()
-    if kernel:
+    if use_kernel(kernel, interpret):
         return _write_kernel(c, layer, val, pos, on, interpret, last)
     return _write_plain(c, layer, val, pos, on, last)

@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import slot_state
 
 # a grid step takes one slot's state of the layer whole, in one stretch of
 # HBM: 2 MB at granite's published sizes, its four buffers 8 MiB of VMEM;
@@ -48,10 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 STRIP = 8                    # sublanes of a float32 tile
 LANE_TILE = 1024             # lanes a pass of the strips' loop covers
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _over_lanes(cols, lanes: int):
@@ -72,41 +69,30 @@ def _update_plain(state, layer, decay, dtx, b, c, active):
     return lax.dynamic_update_index_in_dim(state, s_new, layer, 0), y
 
 
-def _kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref, cols_ref,
-            so_ref, y_ref, *, n_state: int, lanes: int, tile: int,
-            groups: int):
+def _update_tile(s_ref, decay_ref, dtx_ref, cols_ref, so_ref, y_ref, *,
+                 tile: int, groups: int):
     """One slot's state of one layer: [N, lanes], one stretch of HBM."""
-    del layer_ref
-    slot = pl.program_id(0)
+    n_state, lanes = s_ref.shape[-2:]
+    for j in range(lanes // tile):
+        at_lanes = slice(j * tile, (j + 1) * tile)
+        g = 2 * (j * tile * groups // lanes)        # the pass's group's b
+        decay = decay_ref[0, :, at_lanes]                       # [1, tile]
+        dtx = dtx_ref[0, :, at_lanes]
 
-    @pl.when(active_ref[slot] == 0)
-    def _():
-        so_ref[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+        # a strip of eight of the state's rows at a time: the update and the
+        # read-out's partial sums, in and out of VMEM once
+        def strip(i, acc, at_lanes=at_lanes, decay=decay, dtx=dtx, g=g):
+            at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
+            b_col = jnp.broadcast_to(cols_ref[0, at, g:g + 1], (STRIP, tile))
+            c_col = jnp.broadcast_to(cols_ref[0, at, g + 1:g + 2],
+                                     (STRIP, tile))
+            new = decay * s_ref[0, 0, at, at_lanes] + b_col * dtx
+            so_ref[0, 0, at, at_lanes] = new
+            return acc + new * c_col
 
-    @pl.when(active_ref[slot] != 0)
-    def _():
-        for j in range(lanes // tile):
-            at_lanes = slice(j * tile, (j + 1) * tile)
-            g = 2 * (j * tile * groups // lanes)    # the pass's group's b
-            decay = decay_ref[0, :, at_lanes]                   # [1, tile]
-            dtx = dtx_ref[0, :, at_lanes]
-
-            # a strip of eight of the state's rows at a time: the update and
-            # the read-out's partial sums, in and out of VMEM once
-            def strip(i, acc, at_lanes=at_lanes, decay=decay, dtx=dtx, g=g):
-                at = pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
-                b_col = jnp.broadcast_to(cols_ref[0, at, g:g + 1],
-                                         (STRIP, tile))
-                c_col = jnp.broadcast_to(cols_ref[0, at, g + 1:g + 2],
-                                         (STRIP, tile))
-                new = decay * s_ref[0, 0, at, at_lanes] + b_col * dtx
-                so_ref[0, 0, at, at_lanes] = new
-                return acc + new * c_col
-
-            partial = lax.fori_loop(0, n_state // STRIP, strip,
-                                    jnp.zeros((STRIP, tile), jnp.float32))
-            y_ref[0, :, at_lanes] = partial.sum(axis=0, keepdims=True)
+        partial = lax.fori_loop(0, n_state // STRIP, strip,
+                                jnp.zeros((STRIP, tile), jnp.float32))
+        y_ref[0, :, at_lanes] = partial.sum(axis=0, keepdims=True)
 
 
 def _update_kernel(state, layer, decay, dtx, b, c, active, interpret: bool):
@@ -119,34 +105,10 @@ def _update_kernel(state, layer, decay, dtx, b, c, active, interpret: bool):
     # group g's b and c are columns 2 g and 2 g + 1
     cols = jnp.swapaxes(jnp.stack([b, c], axis=-1), 1, 2).reshape(
         B, N, 2 * G)
-
-    def leaf(slot, layer, on):
-        return layer[0], slot, 0, 0
-
-    def own(slot, layer, on):
-        return slot, 0, 0
-
-    row = pl.BlockSpec((1, 1, F), own)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B,),
-        in_specs=[pl.BlockSpec((1, 1, N, F), leaf), row, row,
-                  pl.BlockSpec((1, N, 2 * G), own)],
-        out_specs=[pl.BlockSpec((1, 1, N, F), leaf), row])
-    state, y = pl.pallas_call(
-        functools.partial(_kernel, n_state=N, lanes=F, tile=tile,
-                          groups=G),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((B, 1, F), jnp.float32)],
-        # operands count the two prefetched scalars: the state is written
-        # where it is read
-        input_output_aliases={2: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="ssm_update", interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
-      state, decay[:, None], dtx[:, None], cols)
+    state, y = slot_state.update(
+        "ssm_update", functools.partial(_update_tile, tile=tile, groups=G),
+        state, layer, active, (decay[:, None], dtx[:, None], cols), (1, F),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES, interpret=interpret)
     return state, y[:, 0]
 
 
@@ -164,9 +126,7 @@ def ssm_update(state: jax.Array, layer, decay, dtx, b, c, active, *,
     the leaf in place; elsewhere through plain XLA."""
     if b.ndim == 2:
         b, c = b[:, None], c[:, None]
-    if kernel is None:
-        kernel = interpret or _on_tpu()
-    if kernel:
+    if slot_state.use_kernel(kernel, interpret):
         return _update_kernel(state, layer, decay, dtx, b, c, active,
                               interpret)
     return _update_plain(state, layer, decay, dtx, b, c, active)

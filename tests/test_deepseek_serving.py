@@ -25,6 +25,7 @@ for p in (CHIP_DIR, os.path.join(CHIP_DIR, "rehearse")):
 from families import kanana  # noqa: E402
 
 from ray_tpu.models import deepseek, mla, moe, serving_family  # noqa: E402
+from ray_tpu.ops import slot_rows  # noqa: E402
 from ray_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 
 ma = importlib.import_module("ray_tpu.ops.mla_attend")
@@ -347,7 +348,7 @@ def test_both_programs_count_the_positions_read_beside_the_attended(
     bf16 = {"dtype": jnp.bfloat16, "param_dtype": jnp.bfloat16}
     _, plain = through_the_programs(engine(bf16), PROMPT, 3)
     if form == "kernel":
-        monkeypatch.setattr(ma, "BLOCK", block)
+        monkeypatch.setattr(slot_rows, "BLOCK", block)
         # the layer calls the kernel where it lives, `models/mla.py`; the
         # family counts what it read
         for module, name in ((mla, "mla_attend"),

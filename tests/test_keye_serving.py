@@ -24,7 +24,7 @@ if CHIP_DIR not in sys.path:
 from families import keye as family  # noqa: E402
 
 from ray_tpu.models import deepseek, keye, llama, serving_family  # noqa: E402
-from ray_tpu.ops import dsa  # noqa: E402
+from ray_tpu.ops import dsa, slot_rows  # noqa: E402
 from ray_tpu.serve.kv_cache import PagedKVCache  # noqa: E402
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
 
@@ -445,7 +445,7 @@ def test_the_programs_through_the_kernel_give_the_plain_paths_logits(
     op = importlib.import_module("ray_tpu.ops.dsa_attend")
     plain = engine()
     forced, want = through_the_programs(plain, PROMPT, N_DECODE)
-    monkeypatch.setattr(op, "BLOCK", 32)
+    monkeypatch.setattr(slot_rows, "BLOCK", 32)
     for name, fn in (("rows_chosen", op.rows_chosen),
                      ("dsa_attend", op.dsa_attend),
                      ("dsa_read", op.read_positions)):

@@ -27,6 +27,7 @@ from families import kimi as family  # noqa: E402
 
 from ray_tpu.models import deepseek, kimi, serving_family  # noqa: E402
 from ray_tpu.ops import kda_update as ku  # noqa: E402
+from ray_tpu.ops import slot_rows  # noqa: E402
 
 ma = importlib.import_module("ray_tpu.ops.mla_attend")
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
@@ -454,7 +455,7 @@ def test_both_programs_count_the_positions_read_beside_the_attended(
     T, block = 96, 16
     _, plain = through_the_programs(engine(BF16), PROMPT, 3)
     if form == "kernel":
-        monkeypatch.setattr(ma, "BLOCK", block)
+        monkeypatch.setattr(slot_rows, "BLOCK", block)
         for name in ("mla_attend", "read_positions"):
             monkeypatch.setattr(kimi, name, functools.partial(
                 getattr(ma, name), interpret=True))

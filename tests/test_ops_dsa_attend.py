@@ -13,10 +13,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import keye
-from ray_tpu.ops import dsa
+from ray_tpu.ops import dsa, slot_rows, slot_state
 
 op = importlib.import_module("ray_tpu.ops.dsa_attend")
-mla = importlib.import_module("ray_tpu.ops.mla_attend")
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 # (G, R, d, topk, T, block): `keye-tiny`'s heads at 96 positions in blocks of
@@ -47,7 +46,7 @@ def _both(monkeypatch, shape, pos, live=None, dtype=BF16, L=1, layer=0,
     """(the kernel's values, the plain path's) [B, G, R, d] as numpy, from
     one set of scores chosen in each path's own form."""
     G, R, d, topk, _, block = shape
-    monkeypatch.setattr(op, "BLOCK", block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.ones(len(pos), bool) if live is None else jnp.asarray(live)
     q, ck, cv, drawn = _operands(shape, len(pos), dtype, L, T=T)
@@ -104,8 +103,8 @@ def test_the_kernel_is_the_plain_path_at_the_published_widths(
 def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
                                                    pos, dtype):
     shape = (*TINY[:4], T, block)
-    monkeypatch.setattr(op, "BLOCK", block)
-    assert op._block(T) == min(T, block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
+    assert slot_rows.block_of(T) == min(T, block)
     got, want = _both(monkeypatch, shape, pos, dtype=dtype, T=T)
     np.testing.assert_allclose(got, want, **TOLERANCE[dtype])
 
@@ -124,7 +123,7 @@ def test_a_dead_slot_reads_nothing_and_the_others_are_exact(
     assert np.isfinite(got).all()
     # a dead slot's grid steps stay on the block the live slot before it
     # ended on: the pipeline moves nothing, of the leaves or of the mask
-    src, first, last, _ = (np.asarray(a) for a in mla._plan(
+    src, first, last, _ = (np.asarray(a) for a in slot_rows.plan(
         jnp.asarray(pos), jnp.asarray(live), TINY[4], TINY[5]))
     assert (first[~on] == last[~on]).all() and not first[on].any()
     assert (src[on] == np.flatnonzero(on)).all()
@@ -194,10 +193,10 @@ def test_equal_scores_at_the_sets_boundary_name_the_same_rows(
                          ids=["plain-form", "kernels-path"])
 def test_the_set_comes_in_the_form_the_platform_reads(monkeypatch,
                                                       on_the_chip):
-    """No option and no name decides: `mla_attend._use_kernel`'s platform
+    """No option and no name decides: `slot_state.use_kernel`'s platform
     (or `interpret`, or `kernel`) does, and `dsa_attend` follows the form it
     is handed."""
-    monkeypatch.setattr(mla, "_on_tpu", lambda: on_the_chip)
+    monkeypatch.setattr(slot_state, "on_tpu", lambda: on_the_chip)
     scores = _seen(_tied(96, 0), [95, 50, 20])
     rows = op.rows_chosen(scores, 16)
     assert isinstance(rows, tuple) != on_the_chip
@@ -211,11 +210,10 @@ def test_the_set_comes_in_the_form_the_platform_reads(monkeypatch,
 @pytest.mark.parametrize("on_the_chip", [False, True],
                          ids=["plain-form", "kernels-path"])
 def test_read_positions_follow_the_path(monkeypatch, on_the_chip):
-    """Through the kernel a live slot's position rounded up to a block, at
-    this kernel's block and not a sibling's; plain the chosen rows."""
-    monkeypatch.setattr(mla, "_on_tpu", lambda: on_the_chip)
-    monkeypatch.setattr(op, "BLOCK", 32)
-    monkeypatch.setattr(mla, "BLOCK", 96)
+    """Through the kernel a live slot's position rounded up to a block
+    (`slot_rows.BLOCK`, the one constant); plain the chosen rows."""
+    monkeypatch.setattr(slot_state, "on_tpu", lambda: on_the_chip)
+    monkeypatch.setattr(slot_rows, "BLOCK", 32)
     T = 3 * 32 + 8
     pos = jnp.asarray([0, 31, 32, T - 1, 77])
     live = jnp.asarray([True, True, True, True, False])
@@ -237,7 +235,7 @@ def _tiny_layer():
 def _first_lanes(monkeypatch, cfg, p, how, B=4, T=40):
     """`keye._attend_first` over B slots, one of them not on, with the op's
     two functions steered `how`: (x, the cache)."""
-    monkeypatch.setattr(op, "BLOCK", 16)           # 40: a ragged last block
+    monkeypatch.setattr(slot_rows, "BLOCK", 16)           # 40: a ragged last block
     monkeypatch.setattr(keye, "rows_chosen", functools.partial(
         op.rows_chosen, **how))
     monkeypatch.setattr(keye, "dsa_attend", functools.partial(

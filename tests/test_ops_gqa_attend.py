@@ -10,9 +10,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import kimi, lm
+from ray_tpu.ops import slot_rows, slot_state
 
 op = importlib.import_module("ray_tpu.ops.gqa_attend")
-mla = importlib.import_module("ray_tpu.ops.mla_attend")
 
 G, R, D = 8, 8, 128
 SCALE = 1.0 / np.sqrt(D)
@@ -31,7 +31,7 @@ def _operands(B, T, q_dtype, L=1, seed=0):
 
 def _both(monkeypatch, T, pos, live, q_dtype=F32, L=1, layer=0, block=BLOCK):
     """(the kernel's values, the plain form's) [B, G, R, d] as numpy."""
-    monkeypatch.setattr(op, "BLOCK", block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.asarray(live, bool)
     args = (*_operands(len(pos), T, q_dtype, L), jnp.int32(layer), pos, live)
@@ -88,7 +88,7 @@ def test_a_dead_slot_reads_nothing_and_the_others_are_exact(
     assert np.isfinite(got).all() and not got[~on].any()
     # a dead slot's grid steps stay on the block the live slot before it
     # ended on (none before it: slot 0's block 0): the pipeline moves nothing
-    src, first, last, _ = (np.asarray(a) for a in mla._plan(
+    src, first, last, _ = (np.asarray(a) for a in slot_rows.plan(
         jnp.asarray(pos), jnp.asarray(live), 4 * BLOCK, BLOCK))
     assert (first[~on] == last[~on]).all() and not first[on].any()
     assert (src[on] == np.flatnonzero(on)).all()
@@ -112,8 +112,8 @@ def test_the_layer_worked_on_is_the_one_named(monkeypatch, q_dtype):
 def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
                                                    pos, q_dtype):
     # 424 has no divisor that is whole lane tiles: its last block hangs over
-    monkeypatch.setattr(op, "BLOCK", block)
-    assert op._block(T) == min(T, block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
+    assert slot_rows.block_of(T) == min(T, block)
     got, want = _both(monkeypatch, T, pos, [True] * 3, q_dtype, block=block)
     np.testing.assert_allclose(got, want, **TOLERANCE[q_dtype])
 
@@ -123,9 +123,8 @@ def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
     (96, 1024, 96), (1000, 256, 256)])
 def test_the_block_is_this_kernels_own_and_divides_the_length(
         monkeypatch, T, most, block):
-    monkeypatch.setattr(op, "BLOCK", most)
-    monkeypatch.setattr(mla, "BLOCK", 384)           # not the sibling's
-    assert op._block(T) == block
+    monkeypatch.setattr(slot_rows, "BLOCK", most)
+    assert slot_rows.block_of(T) == block
 
 
 def test_leaves_with_the_positions_on_the_lanes_are_refused():
@@ -138,8 +137,7 @@ def test_leaves_with_the_positions_on_the_lanes_are_refused():
 
 def test_read_positions_are_a_slots_position_rounded_up_to_a_block(
         monkeypatch):
-    monkeypatch.setattr(op, "BLOCK", BLOCK)
-    monkeypatch.setattr(mla, "BLOCK", 3 * BLOCK)     # not the sibling's
+    monkeypatch.setattr(slot_rows, "BLOCK", BLOCK)
     T = 3 * BLOCK + 40
     pos = jnp.asarray([0, BLOCK - 1, BLOCK, T - 1, 77])
     live = jnp.asarray([True, True, True, True, False])
@@ -192,7 +190,7 @@ def test_the_layer_through_the_kernel_is_the_layer_through_the_plain_form(
     with no backend asked."""
     cfg, bp = _solar_layer()
     B, T = 4, 40
-    monkeypatch.setattr(op, "BLOCK", 16)             # 40: a ragged last block
+    monkeypatch.setattr(slot_rows, "BLOCK", 16)             # 40: a ragged last block
     ks = jax.random.split(jax.random.key(3), 3)
     cache = kimi.init_cache(cfg, B, T)
     cache = {**cache, **{name: jax.random.normal(
@@ -227,8 +225,8 @@ def test_the_programs_count_of_positions_read_follows_the_path(
     the plain form, its position rounded up to a block where the kernel
     runs; a prefilling slot's further lanes `lm.gqa_attend_blocks`' turns
     either way."""
-    monkeypatch.setattr(mla, "_on_tpu", lambda: on_the_chip)
-    monkeypatch.setattr(op, "BLOCK", 32)
+    monkeypatch.setattr(slot_state, "on_tpu", lambda: on_the_chip)
+    monkeypatch.setattr(slot_rows, "BLOCK", 32)
     monkeypatch.setattr(lm, "GQA_BLOCK", 48)
     T = 160
     cache = {"k": jnp.zeros((1, 4, 2, T, 16), BF16)}

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import deepseek, kimi, mla
+from ray_tpu.ops import slot_rows
 
 op = importlib.import_module("ray_tpu.ops.mla_attend")
 
@@ -30,7 +31,7 @@ def _operands(B, T, L=1, seed=0):
 
 def _both(monkeypatch, T, pos, live, L=1, layer=0, block=BLOCK):
     """(the kernel's mixed, the plain form's) [B, H, r] as numpy."""
-    monkeypatch.setattr(op, "BLOCK", block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.asarray(live, bool)
     args = (*_operands(len(pos), T, L), jnp.int32(layer), pos, live)
@@ -66,7 +67,7 @@ def test_a_dead_slot_reads_nothing_and_the_others_are_exact(monkeypatch):
     assert np.isfinite(got).all()
     # slot, first and last block: a dead slot's steps stay on the block the
     # live slot before it ended on (slot 0 has none before it: block 0)
-    src, first, last, at = (np.asarray(a).tolist() for a in op._plan(
+    src, first, last, at = (np.asarray(a).tolist() for a in slot_rows.plan(
         jnp.asarray(pos), jnp.asarray(live), 4 * BLOCK, BLOCK))
     assert (src, first, last) == ([0, 1, 1, 1, 4, 4], [0, 0, 3, 3, 0, 0],
                                   [0, 3, 3, 3, 0, 0])
@@ -88,8 +89,8 @@ def test_the_layer_worked_on_is_the_one_named(monkeypatch):
 def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
                                                    pos):
     # 424 has no divisor that is whole lane tiles: its last block hangs over
-    monkeypatch.setattr(op, "BLOCK", block)
-    assert op._block(T) == min(T, block)
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
+    assert slot_rows.block_of(T) == min(T, block)
     got, want = _both(monkeypatch, T, pos, [True] * 3, block=block)
     np.testing.assert_allclose(got, want, **TOLERANCE)
 
@@ -99,13 +100,13 @@ def test_a_length_that_is_no_multiple_of_the_block(monkeypatch, T, block,
     (4096, 4096, 4096), (96, 1024, 96), (1000, 256, 256)])
 def test_the_block_divides_the_length_where_whole_lane_tiles_can(
         monkeypatch, T, most, block):
-    monkeypatch.setattr(op, "BLOCK", most)
-    assert op._block(T) == block
+    monkeypatch.setattr(slot_rows, "BLOCK", most)
+    assert slot_rows.block_of(T) == block
 
 
 def test_read_positions_are_a_slots_position_rounded_up_to_a_block(
         monkeypatch):
-    monkeypatch.setattr(op, "BLOCK", BLOCK)
+    monkeypatch.setattr(slot_rows, "BLOCK", BLOCK)
     T = 3 * BLOCK + 40
     pos = jnp.asarray([0, BLOCK - 1, BLOCK, T - 1, 77])
     live = jnp.asarray([True, True, True, True, False])

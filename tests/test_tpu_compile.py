@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import gpt2, lm
+from ray_tpu.ops import slot_rows
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.train.spmd import (compile_gpt2_train, compile_pipeline_train,
                                 default_optimizer)
@@ -1023,7 +1024,7 @@ def test_mla_attend_kernel_at_64_heads(chips):
     under the kernel's 32 MB, and the program holds nothing beside its
     arguments."""
     op = importlib.import_module("ray_tpu.ops.mla_attend")
-    assert op._block(3072) == 1024
+    assert slot_rows.block_of(3072) == 1024
     one = SingleDeviceSharding(chips[0])
     L, B, T = 8, 128, 3072
 
@@ -1051,9 +1052,9 @@ def test_mla_attend_kernel_reads_the_leaves_where_they_lie(
     positions on the lanes, is handed over as it lies, not copied into 128
     padded lanes a position (671 MB a call at Kimi's shape)."""
     op = importlib.import_module("ray_tpu.ops.mla_attend")
-    assert op.BLOCK == 1024
-    monkeypatch.setattr(op, "BLOCK", block)
-    assert op._block(T) == block
+    assert slot_rows.BLOCK == 1024
+    monkeypatch.setattr(slot_rows, "BLOCK", block)
+    assert slot_rows.block_of(T) == block
     one = SingleDeviceSharding(chips[0])
 
     def arr(shape, dtype=jnp.bfloat16):
@@ -1082,18 +1083,19 @@ def test_dsa_attend_kernel_reads_the_leaves_where_they_lie(chips, T, block):
     program holds nothing beside its arguments but the mask as int32: no
     layer of a leaf is sliced out (436 MB each), no row is gathered."""
     op = importlib.import_module("ray_tpu.ops.dsa_attend")
-    assert op.BLOCK == 1024 and op._block(13312) == 1024
-    assert op.VMEM_LIMIT_BYTES == 32 * 2 ** 20
+    assert slot_rows.BLOCK == 1024 and slot_rows.block_of(13312) == 1024
+    assert slot_rows.VMEM_LIMIT_BYTES == 32 * 2 ** 20
     one = SingleDeviceSharding(chips[0])
 
     def arr(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = jax.jit(lambda *a: op._attend_kernel(
-        *a, 128 ** -0.5, block, False)).lower(
+    compiled = jax.jit(lambda q, ck, cv, keep, *slots: slot_rows.attend(
+        op.rows_kernel(q, ck, cv, keep, 128 ** -0.5), *slots,
+        block=block)).lower(
         arr((32, 4, 8, 128)), arr((6, 32, T, 512)), arr((6, 32, T, 512)),
-        arr((), jnp.int32), arr((32,), jnp.int32), arr((32,), jnp.bool_),
-        arr((32, T), jnp.bool_)).compile()
+        arr((32, T), jnp.bool_), arr((), jnp.int32), arr((32,), jnp.int32),
+        arr((32,), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes <= 32 * T * 4 \
         + 2 ** 16
@@ -1113,14 +1115,14 @@ def test_gqa_attend_kernel_reads_the_leaves_where_they_lie(
     inside `VMEM_LIMIT_BYTES`; and the program holds nothing beside its
     arguments: no layer of a leaf is sliced out (2.1 GB each)."""
     op = importlib.import_module("ray_tpu.ops.gqa_attend")
-    assert op.BLOCK == 1024 and op._block(T) == 1024
+    assert slot_rows.BLOCK == 1024 and slot_rows.block_of(T) == 1024
     one = SingleDeviceSharding(chips[0])
 
     def arr(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = jax.jit(lambda *a: op._attend_kernel(
-        *a, 128 ** -0.5, block, False)).lower(
+    compiled = jax.jit(lambda q, ck, cv, *slots: slot_rows.attend(
+        op.rows_kernel(q, ck, cv, 128 ** -0.5), *slots, block=block)).lower(
         arr((40, 8, 8, 128), q_dtype), arr((1, 40, 8, T, 128)),
         arr((1, 40, 8, T, 128)), arr((), jnp.int32), arr((40,), jnp.int32),
         arr((40,), jnp.bool_)).compile()
