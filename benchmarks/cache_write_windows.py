@@ -14,40 +14,53 @@ program run `--runs` times and the best taken; ms a step's write:
       chained to the one before it (the decode step's form until PR 56,
       the chunk program's still);
   (b) `after`: a window `[48,1,25,128,64]` a slot a leaf, chained: 16 a step
-      (`decode_step` since PR 56);
+      (`decode_step` from PR 56 to PR 58, and still where no kernel runs);
   (c) `one-op`: the eight windows of a leaf gathered, blended and put back
       by one gather and one scatter a leaf;
   (d) `stream`: for scale, an elementwise pass that reads and writes 0.315
       GB in place: the 0.63 GB the windows move, as fast as a fusion
       streams them (0.77 ms at the HBM's published 819 GB/s);
-  (e) `kernel`: not a plain XLA form, for the follow-up (ROADMAP S4a):
-      `ops/rows_write.py`, granite's Pallas kernel, a call a leaf a layer
-      after the loop on `jnp.swapaxes(leaf, 3, 4)`, which is the leaf's own
-      bytes (`[.., 64, T]` by default is how the chip holds `[.., T, 64]`:
-      the compiled decode step shows two bitcasts and no copy);
+  (e) `kernel-*`: `ops/rows_write.py`'s Pallas kernel on
+      `jnp.swapaxes(leaf, 3, 4)`, which is the leaf's own bytes (`[.., 64,
+      T]` by default is how the chip holds `[.., T, 64]`: the compiled
+      decode step shows two bitcasts and no copy), after the loop:
+      `kernel-loop` a `fori_loop` of 48 turns a leaf, each the one-layer
+      call granite makes (two custom calls in the program); `kernel-calls`
+      the same 96 calls unrolled; `kernel-grid-column` one call a leaf on a
+      (layer, slot) grid with a grid step's rows as the one-layer form
+      takes them, `[H, Dh, 1]`; `kernel-grid-N` the same grid with the rows
+      as `[L, B, Dh, H]`, a head a lane, N layers a grid step
+      (`rows_write._write_every`: `decode_step`'s since PR 58, N = 2);
 
-each with the windows on a tile's edge (a start on a multiple of 128: what
-a lone row's window always has) and off it (37 positions on: a chunk's
-window), and with 8, 4 and 1 slots active (an inactive slot's window is
-read, blended with nothing and written back, as `_cache_write` does).
+the plain forms with the windows on a tile's edge (a start on a multiple of
+128: what a lone row's window always has) and off it (37 positions on: a
+chunk's window), and with 8, 4 and 1 slots active (an inactive slot's window
+is read, blended with nothing and written back, as `_cache_write` does; the
+kernel reads and writes a slot's tile whether it is active or not).
 `--repo` then times the whole `gpt2.decode_step` of that checkout beside
 this tree's, 8 slots at XL widths with seeded weights, calls dispatched
-back to back on a donated cache. `--layers 2 --slots 2 --calls 3 --step 0`
-rehearses on the CPU.
+back to back on a donated cache. `--layers 4 --slots 2 --calls 2 --step 0`
+rehearses on the CPU (the kernels interpreted).
 
-Measured on a v5e (PR 56, my chip runs, calls 1 and 2; ms a step's write,
-best of 3; 8, 4 and 1 slots active read the same to 0.003 ms in every row):
+Measured on a v5e (PR 58, my chip run, call 1; ms a step's write, best of
+3; the plain rows read what PR 56's run read to 0.01 ms, and 8, 4 and 1
+slots active the same to 0.006 ms in every row):
 
-    form       operations a step       on a tile's edge   off it
-    in-loop    768 x 0.41 MB           12.34              12.31
-    after      16 x 19.7 MB             6.32               9.53
-    one-op     2 gathers, 2 scatters   16.29              16.26
-    stream     2 fusions, 0.63 GB       0.97 (650 GB/s)
-    kernel     96 calls of 8 steps      1.49               1.49
+    form                operations a step        on a tile's edge   off it
+    in-loop             768 x 0.41 MB            12.34              12.31
+    after               16 x 19.7 MB              6.32               9.53
+    one-op              2 gathers, 2 scatters    16.29              16.26
+    stream              2 fusions, 0.63 GB        0.97 (650 GB/s)
+    kernel-loop         96 calls of 8 steps       1.49
+    kernel-calls        the same, unrolled        1.89
+    kernel-grid-column  2 calls of 384 steps      1.89
+    kernel-grid-1       2 calls of 384 steps      1.03
+    kernel-grid-2       2 calls of 192 steps      0.97
+    kernel-grid-4       2 calls of 96 steps       0.97
 
-    the whole decode step, pos 100.. / 640..:  15.70 / 15.63 ms this tree,
-    17.32 / 17.25 the parent's (its 768 windows cost its step 7.1 ms of
-    those, the table's loop with nothing else in it 12.3)
+    the whole decode step, pos 100.. / 640..:  10.32 / 10.32 ms this tree
+    (at one layer a grid step), 15.71 / 15.68 the parent's (PR 56's 16
+    windows; 17.32 / 17.25 PR 56's parent, 768 windows)
 
 A window all the layers deep moves no faster a tile than a layer's: a
 traced step's `dynamic-update-slice` of 19.7 MB takes 303 us, 63 ns a 4 KB
@@ -61,6 +74,16 @@ constant starts re-lays and copies the leaf (compiled for a described v5e:
 38 copies, 31 GB accessed). Off a tile's edge the deep window pays half as
 much again (two tiles a row of the window), as PR 24's did. Why the
 scatter's form is the slowest was not looked into.
+
+The kernel's forms differ by what the rows cost, not the tiles. A grid
+step's rows as `[H, Dh, 1]` are a tile of their own a head in HBM (the
+compiled programs: `bf16[48,8,25,64,1]{..T(8,128)(2,1)}`, 157 MB a leaf,
+written by a `copy` and read back by the kernel; a layer's `[8,25,64,1]`,
+3.3 MB, in the loop): as many bytes as the tiles they go into. With a head
+a lane the rows are 16 KB a grid step, the program holds no temporary, and
+the write runs at the pace of the in-place fusion over the same bytes; two
+layers a grid step halve the grid steps' own cost (~0.15 us each) and four
+add nothing.
 
 Writes `chiprun_out/cache_write_windows.json`. One process, which holds
 the chip. Imported by no cell.
@@ -79,6 +102,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 W = 128                                       # gpt2._WRITE_WINDOW
+FORMS = ("in-loop", "after", "one-op", "stream", "kernel-loop",
+         "kernel-calls", "kernel-grid-column", "kernel-grid-1",
+         "kernel-grid-2", "kernel-grid-4")
 
 
 def build(form: str, shape, calls: int):
@@ -150,21 +176,65 @@ def build(form: str, shape, calls: int):
 
         return leaf(k, rows[0]), leaf(v, rows[1])
 
-    def kernel(k, v, rows, start, lane, ok):
-        from ray_tpu.ops.rows_write import rows_write
+    def on_view(write):
+        # [L,B,H,Dh,T]: the bytes as the chip holds them, no copy
+        def both(k, v, rows, start, lane, ok):
+            return tuple(jnp.swapaxes(write(jnp.swapaxes(c, 3, 4), r,
+                                            start + lane, ok), 3, 4)
+                         for c, r in ((k, rows[0]), (v, rows[1])))
+        return both
 
-        def leaf(c, r):
-            # [L,B,H,Dh,T]: the bytes as the chip holds them, no copy
-            view = lax.fori_loop(
-                0, L, lambda l, view: rows_write(
-                    view, l, lax.dynamic_index_in_dim(r, l, 0, False),
-                    start + lane, ok), jnp.swapaxes(c, 3, 4))
-            return jnp.swapaxes(view, 3, 4)
+    def looped(view, r, pos, ok):
+        return lax.fori_loop(0, L, lambda l, view: rw.rows_write(
+            view, l, lax.dynamic_index_in_dim(r, l, 0, False), pos, ok), view)
 
-        return leaf(k, rows[0]), leaf(v, rows[1])
+    def unrolled(view, r, pos, ok):
+        for l in range(L):
+            view = rw.rows_write(view, jnp.int32(l), r[l], pos, ok)
+        return view
 
-    write = {"in-loop": in_loop, "after": after, "one-op": one_op,
-             "stream": stream, "kernel": kernel}[form]
+    def grid_column(view, r, pos, ok):
+        # the (layer, slot) grid with a grid step's rows as `rows_write`'s
+        # one-layer form takes them, [H, Dh, 1]
+        def body(tile_ref, lane_ref, c_ref, val_ref, out_ref):
+            old = c_ref[0, 0]
+            at = lax.broadcasted_iota(jnp.int32, old.shape, 2)
+            out_ref[0, 0] = jnp.where(
+                at == lane_ref[pl.program_id(1)],
+                jnp.broadcast_to(val_ref[0, 0], old.shape), old)
+
+        def tile(layer, slot, tiles, lanes):
+            return layer, slot, 0, 0, tiles[slot]
+
+        return pl.pallas_call(
+            body, out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(L, B),
+                in_specs=[pl.BlockSpec((1, 1, H, Dh, w), tile),
+                          pl.BlockSpec((1, 1, H, Dh, 1),
+                                       lambda l, b, *_: (l, b, 0, 0, 0))],
+                out_specs=pl.BlockSpec((1, 1, H, Dh, w), tile)),
+            input_output_aliases={2: 0}, name="rows_write_column",
+            interpret=interpret,
+        )(pos // w, jnp.where(ok, pos % w, -1), view, r[..., None])
+
+    def grid_of(depth):
+        return lambda view, r, pos, ok: rw._write_every(
+            view, r, pos, ok, interpret, depth)
+
+    kernels = {"kernel-loop": looped, "kernel-calls": unrolled,
+               "kernel-grid-column": grid_column,
+               **{f"kernel-grid-{n}": grid_of(n) for n in (1, 2, 4)}}
+    if form.startswith("kernel"):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        rw = importlib.import_module("ray_tpu.ops.rows_write")
+        interpret = jax.default_backend() != "tpu"     # a CPU rehearsal
+        write = on_view(kernels[form])
+    else:
+        write = {"in-loop": in_loop, "after": after, "one-op": one_op,
+                 "stream": stream}[form]
 
     def program(k, v, rows, start, lane, ok):
         def call(i, kv):
@@ -193,8 +263,9 @@ def time_forms(args) -> list:
         fn = build(form, shape, args.calls)
         # `stream` has no window, the kernel no mask that a slot's being
         # active changes
-        edges = (("on", 0), ("off", 37))[:1 if form == "stream" else 2]
-        actives = [B] if form in ("stream", "kernel") else sorted(
+        plain = form != "stream" and not form.startswith("kernel")
+        edges = (("on", 0), ("off", 37))[:2 if plain else 1]
+        actives = [B] if not plain else sorted(
             {B, max(1, B // 2), 1}, reverse=True)
         for (edge, off), n_active in itertools.product(edges, actives):
             start = np.clip(aligned + off, 0, T - w).astype(np.int32)
@@ -266,7 +337,7 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--calls", type=int, default=100)
     ap.add_argument("--runs", type=int, default=3)
-    ap.add_argument("--forms", default="in-loop,after,one-op,stream,kernel")
+    ap.add_argument("--forms", default=",".join(FORMS))
     ap.add_argument("--step", type=int, default=1,
                     help="0: the windows alone, no decode_step")
     ap.add_argument("--preset", default="gpt2-1.5b")

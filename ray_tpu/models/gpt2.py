@@ -377,11 +377,12 @@ CACHE_TOKEN_AXIS = {"k": 3, "v": 3}
 def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
     """KV cache of all layers: {"k","v"}: [n_layer, B, H, T, Dh] (compute
     dtype). `prefill_chunk` carries it whole through its loop over the
-    layers and writes a layer's new rows into it there; `decode_step` only
-    reads it in its loop and writes all layers' rows once, after it. Either
-    update is in place only where the caller donates the cache to the
-    jitted step (`donate_argnums`), otherwise the program copies it once on
-    entry."""
+    layers and writes a layer's new rows into it there (`_cache_write`);
+    `decode_step` only reads it in its loop and writes all layers' rows
+    once, after it (`_decode_write`: a Pallas call a leaf on the TPU).
+    Either update is in place only where the caller donates the cache to
+    the jitted step (`donate_argnums`), otherwise the program copies it
+    once on entry."""
     T = max_len or cfg.max_seq_len
     shape = (cfg.n_layer, batch, cfg.n_head, T, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
@@ -390,10 +391,12 @@ def init_cache(cfg: GPT2Config, batch: int, max_len: Optional[int] = None):
 # the narrowest stretch of positions a cache write touches. On the TPU the
 # cache [.., T, Dh] with Dh = 64 lies with T along the 128 lanes of a tile; a
 # narrower update prefers another layout, and the compiler then re-lays the
-# whole cache to suit it (tests/test_tpu_compile.py). The decode step's one
-# write is this window too, all the layers deep. A window whose start along T
-# is computed moves a 4 KB tile at a time, ~65 ns each however deep it is
-# (benchmarks/cache_write_windows.py has the table)
+# whole cache to suit it (tests/test_tpu_compile.py). A window whose start
+# along T is computed moves a 4 KB tile at a time, ~65 ns each however deep it
+# is (benchmarks/cache_write_windows.py has the table): the chunk program
+# writes such windows, and so does the decode step wherever no kernel runs.
+# That layout is also, byte for byte, `[.., 64, T]` in its default layout,
+# which is what lets `_decode_write` hand the leaf to `ops/rows_write.py`
 _WRITE_WINDOW = 128
 
 
@@ -401,9 +404,10 @@ def _cache_write(c, l, val, pos0, ok):
     """The cache c [L,B,H,T,Dh] takes val: lane i of slot b goes to position
     pos0[b] + i where ok[b, i]; nothing else changes. With a layer's index l
     val is that layer's [B,H,C,Dh] (`prefill_chunk`, inside its loop); with
-    l None it is every layer's, [L,B,H,C,Dh] (`decode_step`, after its
-    loop), and a slot's window is all L layers deep. Per slot one window of
-    W >= C positions is read, blended and written back in place:
+    l None it is every layer's, [L,B,H,C,Dh] (`_decode_write`, after
+    `decode_step`'s loop, where no kernel runs), and a slot's window is all
+    L layers deep. Per slot one window of W >= C positions is read, blended
+    and written back in place:
     dynamic_update_slice clamps its start near the end of the sequence, so
     an unmasked block write would smear garbage lanes over valid earlier
     positions."""
@@ -436,6 +440,42 @@ def _cache_write(c, l, val, pos0, ok):
         new = jnp.where(take[b][:, None], moved[:, b:b + 1], old)
         c = lax.dynamic_update_slice(c, new, at)
     return c
+
+
+def _decode_write(c, rows, pos, on, interpret: bool = False):
+    """The cache c [L,B,H,T,Dh] takes every layer's new row, rows
+    [L,B,H,Dh], at position pos[b] of every slot that is `on` [B]; nothing
+    else changes: `decode_step`'s write, after its loop.
+
+    Where a Pallas kernel runs (`ops.slot_state.use_kernel`: the TPU), the
+    head is narrower than a tile's 128 lanes and T is whole tiles, through
+    `ops/rows_write.py`: `[.., Dh, T]` in its default layout is byte for
+    byte how the chip holds `[.., T, Dh]` (`_WRITE_WINDOW`), so the axes
+    swapped are the leaf's own bytes, and one call a leaf reads a tile
+    `[H, Dh, 128]` a layer and slot, blends the row's lane and writes it
+    where it read it. Everywhere else `_cache_write`'s windows. Both leave
+    the same bits. Under a mesh that shards the heads over `tp` every shard
+    writes its own heads: the TPU's compiler partitions no Pallas call."""
+    from ray_tpu.ops.rows_write import TILE, rows_write  # `lm.dot` has why
+    from ray_tpu.ops.slot_state import use_kernel
+    from ray_tpu.parallel.mesh import current_mesh
+
+    T, Dh = c.shape[3:]
+    if not (use_kernel(None, interpret) and Dh < TILE and T % TILE == 0):
+        return _cache_write(c, None, rows[:, :, :, None], pos, on[:, None])
+
+    def write(c, rows, pos, on):
+        view = rows_write(jnp.swapaxes(c, 3, 4), None, rows, pos, on,
+                          interpret=interpret)
+        return jnp.swapaxes(view, 3, 4)
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        heads, whole = jax.P(None, None, "tp"), jax.P()
+        write = jax.shard_map(write, mesh=mesh, out_specs=heads,
+                              in_specs=(heads, heads, whole, whole),
+                              check_vma=False)
+    return write(c, rows, pos, on)
 
 
 def _cached_layers(layer, x, params: Params, cache):
@@ -477,10 +517,11 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     this step, for the positions before pos[b], and to its new row directly
     (the row's score takes column pos[b] of the scores; what the cache holds
     at pos[b] and beyond never reaches the result). The new rows leave the
-    loop stacked, [L,B,H,Dh] a leaf, and go into the cache once, after it:
-    one window all the layers deep a slot a leaf (`_cache_write`), 2 B
-    updates a step where a write in every layer made 2 B n_layer. The caller
-    must donate `cache` for that to happen in place.
+    loop stacked, [L,B,H,Dh] a leaf, and go into the cache once, after it
+    (`_decode_write`): on the TPU one Pallas call a leaf that reads and
+    writes a slot's tile of 128 positions a layer in place, elsewhere one
+    window all the layers deep a slot a leaf. The caller must donate `cache`
+    for that to happen in place.
     """
     B = tokens.shape[0]
     H, Dh = cfg.n_head, cfg.head_dim
@@ -532,8 +573,7 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     with jax.named_scope("layers"):
         x, rows = lax.scan(layer, x, (jnp.arange(L), params["blocks"]))
     with jax.named_scope("kv_update"):
-        cache = {name: _cache_write(cache[name], None, row[:, :, :, None],
-                                    pos, active[:, None])
+        cache = {name: _decode_write(cache[name], row, pos, active)
                  for name, row in rows.items()}
     with jax.named_scope("unembed_loss"):
         x = _layer_norm(x, params["ln_f"])

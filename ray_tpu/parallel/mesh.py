@@ -400,3 +400,18 @@ def topology_from_devices(devices: Optional[Sequence[Any]] = None):
     rows = device_rows_by_process(
         list(devices) if devices is not None else jax.devices())
     return Topology(inter=len(rows), intra=min(len(r) for r in rows))
+
+
+def traced_on(mesh: Mesh, fn):
+    """`fn`, traced with `mesh` in sight (`current_mesh`) and `constrain`
+    off: for a program whose placement is GSPMD's, from its arguments'
+    shardings, and whose Pallas calls have to take a shard each through
+    `shard_map`, which needs the mesh (the serving engine's tensor-parallel
+    decode step). At the end of the file: `constrain`'s line is in the
+    training programs' compile-cache keys."""
+    def traced(*args):
+        with use_mesh(mesh), suppress_constraints():
+            return fn(*args)
+
+    traced.__name__ = fn.__name__          # the jitted program's name
+    return traced

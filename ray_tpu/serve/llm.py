@@ -465,7 +465,7 @@ class LLMEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from ray_tpu.parallel.mesh import (MeshConfig, build_mesh,
-                                               use_mesh)
+                                               traced_on, use_mesh)
 
             mesh = build_mesh(
                 MeshConfig(tp=tensor_parallel_size),
@@ -482,8 +482,8 @@ class LLMEngine:
             self.cache = jax.tree.map(
                 lambda a: jax.device_put(a, cache_sh), self.cache)
             rep = NamedSharding(mesh, P())
-            self._step = jax.jit(
-                _step, donate_argnums=(1,),
+            self._step = jax.jit(  # its Pallas call takes a shard each
+                traced_on(mesh, _step), donate_argnums=(1,),
                 in_shardings=(param_sh, {"k": cache_sh, "v": cache_sh},
                               rep, rep, rep),
                 out_shardings=(rep, {"k": cache_sh, "v": cache_sh}))

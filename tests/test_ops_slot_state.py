@@ -109,3 +109,69 @@ def test_no_kernel_of_ops_asks_the_platform_itself(module):
     source = open(mod.__file__).read()
     assert "default_backend" not in source
     assert "pallas_call" not in source or module == "rows_write"
+
+
+# ------------------------------- `rows_write`'s every-layer form (PR 58)
+
+def _leaf_and_rows(L=4, B=4, G=5, d=64, T=256, dtype=jnp.bfloat16):
+    ks = jax.random.split(jax.random.key(58), 2)
+    return (jax.random.normal(ks[0], (L, B, G, d, T), dtype),
+            jax.random.normal(ks[1], (L, B, G, d), dtype))
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4, 3],
+                         ids=["a-layer", "two", "four", "three-of-four"])
+@pytest.mark.parametrize("on", [[1, 1, 1, 1], [1, 0, 1, 0], [0, 0, 0, 1],
+                                [0, 0, 0, 0]],
+                         ids=["all", "some", "one", "none"])
+def test_every_layers_row_in_one_call_is_a_call_a_layer(depth, on):
+    """One call whose grid is (layers, slots) leaves the bits that a call a
+    layer leaves and that the plain form leaves, however many layers a
+    grid step takes (one that does not divide the layers falls back to
+    their common divisor); an inactive slot's tile comes back bit for
+    bit."""
+    c, val = _leaf_and_rows()
+    pos, on = jnp.array([0, 127, 128, 255]), jnp.array(on, bool)
+    got = jax.jit(lambda c: rows_write._write_every(
+        c, val, pos, on, True, depth))(c)
+    want = c
+    for l in range(c.shape[0]):
+        want = rows_write.rows_write(want, jnp.int32(l), val[l], pos, on,
+                                     interpret=True)
+    plain = rows_write.rows_write(c, None, val, pos, on, kernel=False)
+    for other in (want, plain):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                      np.asarray(other).view(np.uint16))
+    off = ~np.asarray(on)
+    np.testing.assert_array_equal(np.asarray(got)[:, off].view(np.uint16),
+                                  np.asarray(c)[:, off].view(np.uint16))
+
+
+def test_the_every_layer_form_is_the_entry_with_no_layer(monkeypatch):
+    c, val = _leaf_and_rows(L=2, B=2, G=1)
+    seen = []
+    monkeypatch.setattr(rows_write, "_write_every",
+                        lambda *a: seen.append(a[-1]) or a[0])
+    rows_write.rows_write(c, None, val, jnp.zeros(2, jnp.int32),
+                          jnp.ones(2, bool), interpret=True)
+    assert seen == [True]
+    # a head of 128 lanes, positions on the rows, has no such form
+    with pytest.raises(AssertionError):
+        rows_write.rows_write(jnp.swapaxes(c, 3, 4), None, val,
+                              jnp.zeros(2, jnp.int32), jnp.ones(2, bool))
+
+
+def test_the_every_layer_form_writes_the_leaf_where_it_reads_it():
+    c, val = _leaf_and_rows()
+    text = jax.jit(lambda c, val: rows_write._write_every(
+        c, val, jnp.zeros(4, jnp.int32), jnp.ones(4, bool), False, 2),
+        donate_argnums=(0,)).trace(c, val).jaxpr
+    call, = (e for e in text.eqns if e.primitive.name == "pallas_call")
+    assert call.params["input_output_aliases"] == ((2, 0),)
+    mapping = call.params["grid_mapping"]
+    assert mapping.num_index_operands == 2 and mapping.grid == (2, 4)
+    # the rows go in a head a lane: [L, B, d, G], 16 KB a grid step in HBM
+    # where [G, d, 1] would be a tile of its own a head
+    assert [tuple(d.block_size for d in b.block_shape)
+            for b in mapping.block_mappings] == [
+        (2, 1, 5, 64, 128), (2, 1, 64, 5), (2, 1, 5, 64, 128)]

@@ -435,3 +435,127 @@ def test_resident_params_is_idempotent(trees):
     moved = {**resident, "wte": resident["wte"] * 2}
     assert _same_bits(gpt2.resident_params(moved, cfg)["unembed"],
                       moved["wte"].T.astype(cfg.dtype))
+
+
+# ------------------------------------ the kernel's write after the loop (PR 58)
+
+# 125M's heads (12 of 64 lanes) in bfloat16, two layers deep; a window of
+# two tiles, so that a row lands in either and on both of their edges
+KT = 256
+KERNEL_CASES = {
+    "all-active": (KT, [0, 127, 128, KT - 1], [1, 1, 1, 1]),
+    "some-inactive": (KT, [0, 127, 128, KT - 1], [1, 0, 1, 0]),
+    "one-active": (KT, [0, 127, 128, KT - 1], [0, 0, 0, 1]),
+    "one-active-first-tile": (KT, [KT - 1, 127, 5, 0], [0, 1, 0, 0]),
+    "none-active": (KT, [3, 127, 128, KT - 1], [0, 0, 0, 0]),
+    "same-position": (KT, [128, 128, 128, 128], [1, 1, 0, 1]),
+    # a serving window that is no multiple of a tile's 128 positions takes
+    # `_cache_write`'s windows, wherever it runs
+    "windows-at-192": (192, [0, 127, 128, 191], [1, 1, 1, 1]),
+}
+
+
+def _kernel_cfg(T):
+    return gpt2.GPT2Config.preset("gpt2-125m", n_layer=2, vocab_size=512,
+                                  max_seq_len=T)
+
+
+@pytest.fixture(scope="module")
+def kernel_params():
+    return gpt2.init_params(jax.random.key(58), _kernel_cfg(KT))
+
+
+def _decode(params, cfg, cache, pos, active, donate=True):
+    """(logits, cache) of one jitted `decode_step`, traced now."""
+    return jax.jit(lambda c, t, pos, a: gpt2.decode_step(
+        params, c, t, pos, a, cfg), donate_argnums=(0,) if donate else ())(
+            cache, jnp.arange(B, dtype=jnp.int32) + 7,
+            jnp.asarray(pos, jnp.int32), jnp.asarray(active, bool))
+
+
+def _bf16_cache(cfg, T, seed):
+    shape = (cfg.n_layer, B, cfg.n_head, T, cfg.head_dim)
+    return {n: jax.random.normal(jax.random.key(seed + i), shape, cfg.dtype)
+            for i, n in enumerate("kv")}
+
+
+@pytest.fixture
+def through_the_kernel(monkeypatch):
+    """`decode_step` on the branch it takes on the chip, its Pallas call
+    interpreted; the fixture's value lists the leaves the kernel was given."""
+    from functools import partial
+    import importlib
+
+    rw = importlib.import_module("ray_tpu.ops.rows_write")
+    calls, kernel = [], rw._write_every
+    monkeypatch.setattr(rw, "_write_every",
+                        lambda c, *a: calls.append(c.shape) or kernel(c, *a))
+    monkeypatch.setattr(gpt2, "_decode_write",
+                        partial(gpt2._decode_write, interpret=True))
+    return calls
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernels_write_leaves_the_windows_bits(kernel_params, case,
+                                                   monkeypatch, request):
+    T, pos, active = KERNEL_CASES[case]
+    cfg = _kernel_cfg(T)
+    want_logits, want = _decode(kernel_params, cfg, _bf16_cache(cfg, T, 3),
+                                pos, active)
+    calls = request.getfixturevalue("through_the_kernel")
+    logits, got = _decode(kernel_params, cfg, _bf16_cache(cfg, T, 3), pos,
+                          active)
+    # which path a call takes is read off its shapes: a leaf a call
+    assert len(calls) == (2 if T % 128 == 0 else 0)
+    assert _same_bits(logits, want_logits)
+    for name in "kv":
+        assert _same_bits(got[name], want[name]), name
+    # and those bits are the input's everywhere but at an active slot's row
+    before = _bf16_cache(cfg, T, 3)
+    row = (np.arange(T) == np.asarray(pos)[:, None]) \
+        & np.asarray(active, bool)[:, None]                          # [B, T]
+    for name in "kv":
+        same = _bits(got[name]) == _bits(before[name])
+        kept = np.broadcast_to(~row[None, :, None, :, None], same.shape)
+        assert same[kept].all()
+        assert not row.any() or not same[~kept].all()
+
+
+def test_an_undonated_cache_is_left_as_it_was_by_the_kernel(
+        kernel_params, through_the_kernel):
+    cfg = _kernel_cfg(KT)
+    cache = _bf16_cache(cfg, KT, 5)
+    before = jax.tree.map(np.array, cache)
+    _, new_cache = _decode(kernel_params, cfg, cache, [0, 127, 128, KT - 1],
+                           [1, 1, 1, 1], donate=False)
+    assert len(through_the_kernel) == 2
+    for name in "kv":
+        assert np.array_equal(np.asarray(cache[name]), before[name])
+        assert not np.array_equal(np.asarray(new_cache[name]), before[name])
+
+
+def test_a_cache_sharded_over_heads_is_written_a_shard_each(
+        through_the_kernel):
+    """The tensor-parallel engine's decode step (`serve/llm.py`): GSPMD
+    places the program, and the kernel, which no compiler partitions, takes
+    each shard's own heads through `shard_map`."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh, traced_on
+
+    cfg = _kernel_cfg(KT)
+    mesh = build_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
+    heads = NamedSharding(mesh, P(None, None, "tp"))
+    cache = _bf16_cache(cfg, KT, 7)["k"]
+    rows = jax.random.normal(jax.random.key(8), (cfg.n_layer, B, cfg.n_head,
+                                                 cfg.head_dim), cfg.dtype)
+    pos, on = jnp.array([0, 127, 128, KT - 1]), jnp.array([1, 1, 0, 1], bool)
+    want = gpt2._cache_write(cache, None, rows[:, :, :, None], pos,
+                             on[:, None])
+    got = jax.jit(traced_on(mesh, lambda c, r: gpt2._decode_write(
+        c, r, pos, on)), in_shardings=(heads, heads), out_shardings=heads)(
+            cache, rows)
+    assert through_the_kernel == [
+        (cfg.n_layer, B, cfg.n_head // 2, cfg.head_dim, KT)]
+    assert got.sharding.is_equivalent_to(heads, got.ndim)
+    assert _same_bits(got, want)

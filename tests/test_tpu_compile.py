@@ -173,9 +173,55 @@ def _serving_step(chips, program, C=16, preset="gpt2-125m", **widths):
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
-def test_serving_step_compiles_at_125m_widths(chips, program):
+def test_serving_step_compiles_at_125m_widths(chips, as_on_tpu, program):
     compiled = _serving_step(chips, program)[0].compile()
     assert _per_device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_tensor_parallel_decode_step_writes_a_shard_each(chips, as_on_tpu,
+                                                         tp):
+    """The decode step as `serve/llm.LLMEngine` jits it with
+    `tensor_parallel_size` 2 or 4, at 125M widths over chips of the 2x2:
+    the weights by their specs, the cache's heads over `tp`, the placement
+    GSPMD's. The TPU's compiler partitions no Pallas call, so
+    `gpt2._decode_write` runs the kernel inside a `shard_map`, each chip on
+    its own 6 or 3 heads: no leaf is gathered, copied or re-laid on the
+    way."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import (MeshConfig, build_mesh, traced_on,
+                                       use_mesh)
+
+    B, T = 8, 1024
+    cfg = gpt2.GPT2Config.preset("gpt2-125m", max_seq_len=T)
+    mesh = build_mesh(MeshConfig(tp=tp), devices=chips[:tp])
+    with use_mesh(mesh):
+        specs = gpt2.resident_specs(cfg)
+    shapes = jax.eval_shape(lambda: gpt2.resident_params(
+        gpt2.init_params(jax.random.key(0), cfg), cfg))
+    params = jax.tree.map(lambda a, spec: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), shapes, specs)
+    heads, rep = NamedSharding(mesh, P(None, None, "tp")), \
+        NamedSharding(mesh, P())
+    cache = _on(heads, jax.eval_shape(lambda: gpt2.init_cache(cfg, B, T)))
+    compiled = jax.jit(
+        traced_on(mesh, lambda p, c, t, pos, a: gpt2.decode_step(
+            p, c, t, pos, a, cfg)), donate_argnums=(1,),
+        out_shardings=(rep, {"k": heads, "v": heads})).lower(
+            params, cache, *_on(rep, (
+                jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B,), jnp.bool_)))).compile()
+    hlo = compiled.as_text()
+    L, H, Dh = cfg.n_layer, cfg.n_head // tp, cfg.head_dim
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', hlo)) == 2
+    assert not _written_arrays(hlo, f"{L},{B},{cfg.n_head},({T},{Dh}|{Dh},{T})")
+    written = _written_arrays(hlo, f"{L},{B},{H},({T},{Dh}|{Dh},{T})")
+    assert {op for op, _ in written} <= {
+        "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+        "custom-call"}, written
+    assert _per_device_bytes(compiled) < 709_166_080 * (1 / tp + 0.2)
 
 
 def _written_arrays(hlo: str, dims: str, dtype: str = "bf16",
@@ -214,37 +260,55 @@ def _written_arrays(hlo: str, dims: str, dtype: str = "bf16",
 # in every step; on the resident tree (the matrices bf16 once, the float32
 # table only gathered from) they hold 0.71 GB and access 0.45, 0.51 and
 # 0.68 GB: 0.64, 0.72 and 0.97 of what they hold, where PR 24's were 0.94,
-# 1.01 and 1.79. Since PR 56 the decode program writes its rows after the loop, a
-# window all the layers deep a slot a leaf. cost_analysis() counts a loop's
-# body once: with the windows out of the loop its count rises though the step
-# moves no more (0.45 -> 0.59 GB at these shapes, 0.83 of what the program
-# holds; 0.99 -> 2.24 GB at XL, where a step moves the same 0.63 GB of
-# windows on both sides), so that program's factor is 0.9 where it was 0.8
+# 1.01 and 1.79. PR 56's decode program wrote its rows after the loop, a
+# window all the layers deep a slot a leaf (cost_analysis() counts a loop's
+# body once, so its count rose to 0.59 GB at these shapes though the step
+# moved no more). Since PR 58 the rows go in through `ops/rows_write.py`, one
+# Pallas call a leaf on the leaf's own bytes viewed [.., Dh, T]: no window,
+# no `dynamic-update-slice`, and nothing of a call's traffic in
+# cost_analysis(): 0.44 GB, 0.61 of what the program holds
 @pytest.mark.parametrize("program,C,most", [
-    ("decode_step", 0, 0.9), ("prefill_chunk", 16, 0.9),
+    ("decode_step", 0, 0.7), ("prefill_chunk", 16, 0.9),
     ("prefill_chunk", 128, 1.2)],
     ids=["decode_step", "prefill_chunk-16", "prefill_chunk-128"])
-def test_serving_step_updates_the_cache_in_place(chips, program, C, most):
+def test_serving_step_updates_the_cache_in_place(chips, as_on_tpu, program,
+                                                 C, most):
     lowered, cfg, (B, T) = _serving_step(chips, program, C)
     compiled = lowered.compile()
     hlo = compiled.as_text()
-    layer = f"{B},{cfg.n_head},{T},{cfg.head_dim}"
-    whole = _written_arrays(hlo, f"{cfg.n_layer},{layer}")
-    # where the windows are written: the chunk program's in the loop over
-    # the layers, which carries the leaves; the decode program's after it,
-    # in the entry computation, and its loop only reads what it closes over
-    updates = [op for op, _ in _written_arrays(
-        hlo, f"{cfg.n_layer},{layer}", entry=program == "decode_step")]
-    assert updates.count("dynamic-update-slice") == 2 * B, updates
-    in_place = {"parameter", "get-tuple-element", "tuple", "while",
-                "dynamic-update-slice", "bitcast"}
-    assert {op for op, _ in whole} <= in_place, whole
+    L, H, Dh = cfg.n_layer, cfg.n_head, cfg.head_dim
+    layer = f"{B},{H},{T},{Dh}"
+    whole = _written_arrays(hlo, f"{L},{layer}")
+    handed_on = {"parameter", "get-tuple-element", "tuple", "while",
+                 "bitcast"}
+    if program == "decode_step":
+        # the loop only reads the leaves it closes over; after it each is
+        # viewed [.., Dh, T] (a bitcast: the chip holds [.., T, 64] with T
+        # on the lanes), written by one kernel in place, and viewed back
+        assert {op for op, _ in whole} <= handed_on, whole
+        view = _written_arrays(hlo, f"{L},{B},{H},{Dh},{T}")
+        assert sorted(op for op, _ in view) == [
+            "bitcast", "bitcast", "custom-call", "custom-call"], view
+        assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                              hlo)) == 2
+        # T stays the minor-most axis, Dh the next: one layout
+        assert {re.search(r"\](\{[\d,]*)", t).group(1) for _, t in view} \
+            == {"{4,3,2,1,0"}, view
+        assert not _written_arrays(hlo, f"{B},{H},{Dh},{T}")
+    else:
+        # the chunk program's windows are written in the loop over the
+        # layers, which carries the leaves
+        updates = [op for op, _ in _written_arrays(hlo, f"{L},{layer}",
+                                                   entry=False)]
+        assert updates.count("dynamic-update-slice") == 2 * B, updates
+        assert {op for op, _ in whole} <= handed_on | {
+            "dynamic-update-slice"}, whole
+        # a window per slot and cache is written into the carry
+        assert sum(op == "dynamic-update-slice" for op, _ in whole) == 2 * B
     # one layout from the argument to the result: nothing re-lays the cache
-    assert len({re.search(r"\[%s\](\{[^}]*\})" % f"{cfg.n_layer},{layer}",
+    assert len({re.search(r"\[%s\](\{[^}]*\})" % f"{L},{layer}",
                           t).group(1) for _, t in whole}) == 1, whole
-    # a window per slot and cache is written into the carry, and no copy
-    # of one layer's cache is made on the way to the scores
-    assert sum(op == "dynamic-update-slice" for op, _ in whole) == 2 * B
+    # and no copy of one layer's cache is made on the way to the scores
     assert not _written_arrays(hlo, layer)
     assert not _written_arrays(hlo, "1," + layer)
     accessed = compiled.cost_analysis()["bytes accessed"]
@@ -265,7 +329,8 @@ _HANDED_ON = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
     dict(preset="gpt2-125m"),
     dict(preset="gpt2-1.5b", vocab_size=50304)],     # the benchmark's
     ids=["125m", "xl"])
-def test_serving_step_converts_no_weights(chips, program, C, widths):
+def test_serving_step_converts_no_weights(chips, as_on_tpu, program, C,
+                                          widths):
     """On the tree the engine holds, a step makes no bf16 copy of any
     [n_layer, ...] stack of weights (until PR 26 eight `convert`s a step,
     hoisted out of the loop: 13.6 ms of a 31.6 ms decode step at XL) and
@@ -297,17 +362,18 @@ def test_serving_step_converts_no_weights(chips, program, C, widths):
 # 1,024 positions, chunks of 128, the padded vocabulary), per device: the
 # parent of PR 29 compiled to these bytes, and to the same instructions but
 # for the table of file names and line numbers (PR 29 changed the engine
-# and the pool around them, not them). The decode program is PR 56's, which
-# writes its rows after the loop: 6,314,675,200 B until then, 0.55 MB more
-# now (the stacked rows [48,8,25,64] of both leaves beside a pair of blended
-# windows); a leaf copied would show as 1.26 GB more. The chunk program is
-# still PR 29's parent's
-GPT2_XL_SERVING_BYTES = {"decode_step": 6_315_223_552,
+# and the pool around them, not them). The decode program is PR 58's: its
+# rows go into the cache after the loop through two calls of
+# `ops/rows_write.py` on the leaves' own bytes, where PR 56's held a pair of
+# blended windows [48,1,25,128,64] (6,315,223,552 B; 6,314,675,200 before
+# PR 56, which wrote in the loop): 0.81 MB less. A leaf copied would show as
+# 1.26 GB more. The chunk program is still PR 29's parent's
+GPT2_XL_SERVING_BYTES = {"decode_step": 6_314_417_152,
                          "prefill_chunk": 6_314_743_808}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
-def test_gpt2_xl_serving_programs_are_the_parents(chips, program):
+def test_gpt2_xl_serving_programs_are_the_parents(chips, as_on_tpu, program):
     lowered, _, _ = _serving_step(chips, program, 128, preset="gpt2-1.5b",
                                   vocab_size=50304)
     assert _per_device_bytes(lowered.compile()) == \
