@@ -1,9 +1,12 @@
 #!/usr/bin/env python3
 """Once, on the chip: `ops/gqa_attend.py` alone at Solar's cell's shape, the
 kernel at every block length against the plain form (`lm.gqa_attend`), the
-calls one program's loop as the layers' loop is.
+calls one program's loop as the layers' loop is; and (`--shapes`) at
+K-EXAONE's cell's two shapes, the global layers' rows and the sliding
+layers' rings.
 
-    chiprun -- python benchmarks/gqa_attend_blocks.py [--calls 100]
+    chiprun -- python benchmarks/gqa_attend_blocks.py [--calls 100] \
+        [--shapes solar,solar-check,kexaone,kexaone-ring]
 
 The cell: 1 softmax layer x 40 slots x 8 key-value heads x 25,600 positions
 of 128 lanes, a float32 q of 8 queries a head (two bf16 pieces), the slots
@@ -39,6 +42,14 @@ q and the probabilities through both. `ops/slot_rows.BLOCK` is 1,024:
 within 1.3% of the best where every slot is live, 13% better than 512
 where four are, and `mla_attend`'s.
 
+K-EXAONE's cell (PR 59): `kexaone`, 2 global layers x 64 slots x 8 heads x
+10,240 positions, the slots live at 6.2k-10.0k (a call a layer: the loop
+turns over both); `kexaone-ring`, 6 sliding layers x 64 slots x 8 heads x a
+ring of 128 rows (`ring=True`: one block a slot, 64 grid steps a call, each
+moving 512 KB; the plain form is `lm.gqa_attend_band` over the whole layer;
+the block lengths do not apply and one kernel row is timed). Its table is
+PERF.md section 6, PR 59.
+
 Writes `chiprun_out/gqa_attend_blocks.json`. One process, which holds the
 chip.
 """
@@ -57,10 +68,13 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks", "chip")]
 
-L, B, G, R, D, T = 1, 40, 8, 8, 128, 25600
+G, R, D = 8, 8, 128
 SCALE = 1.0 / math.sqrt(D)
-LIVE = {"solar": 40, "solar-check": 4}    # name: live slots
-POSITIONS = (16400, 25200)
+# name: (layers, slots, T, live slots, where the live slots stand, a ring)
+SHAPES = {"solar": (1, 40, 25600, 40, (16400, 25200), False),
+          "solar-check": (1, 40, 25600, 4, (16400, 25200), False),
+          "kexaone": (2, 64, 10240, 64, (6200, 10000), False),
+          "kexaone-ring": (6, 64, 128, 64, (6200, 10000), True)}
 BLOCKS = (512, 1024, 2048, 2560)
 
 
@@ -68,6 +82,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--calls", type=int, default=100)
     ap.add_argument("--blocks", default=",".join(str(b) for b in BLOCKS))
+    ap.add_argument("--shapes", default="solar,solar-check")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -81,25 +96,32 @@ def main() -> int:
     op = importlib.import_module("ray_tpu.ops.gqa_attend")
     out = {"device": jax.devices()[0].device_kind,
            "default_block": slot_rows.BLOCK,
-           "shape": {"layers": L, "slots": B, "kv_heads": G, "queries": R,
-                     "lanes": D, "T": T}}
+           "shape": {"kv_heads": G, "queries": R, "lanes": D}}
     peak = spec.peaks()[out["device"]]["hbm_bytes_per_s"]
     ks = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(ks[0], (B, G, R, D), jnp.float32)
-    ck = jax.random.normal(ks[1], (L, B, G, T, D), jnp.bfloat16)
-    cv = jax.random.normal(ks[2], (L, B, G, T, D), jnp.bfloat16)
-    pos = jnp.asarray(np.random.default_rng(0).integers(
-        *POSITIONS, size=B), jnp.int32)
-    for name, n_live in LIVE.items():
+    made = None
+    for name in args.shapes.split(","):
+        L, B, T, n_live, positions, ring = SHAPES[name]
+        if made != (L, B, T):
+            q = jax.random.normal(ks[0], (B, G, R, D), jnp.float32)
+            ck = jax.random.normal(ks[1], (L, B, G, T, D), jnp.bfloat16)
+            cv = jax.random.normal(ks[2], (L, B, G, T, D), jnp.bfloat16)
+            pos = jnp.asarray(np.random.default_rng(0).integers(
+                *positions, size=B), jnp.int32)
+            made = (L, B, T)
         live = jnp.asarray(np.arange(B) % (B // n_live) == 0)
-        attended = int(jnp.sum(jnp.where(live, pos + 1, 0)))
+        # a ring's rows: the last T positions
+        attended = int(jnp.sum(jnp.where(live, jnp.minimum(pos + 1, T)
+                                         if ring else pos + 1, 0)))
         least = attended * 2 * G * D * 2 / peak
         rows, want = {}, None
-        forms = [("plain", None)] + [(b, int(b))
-                                     for b in args.blocks.split(",")]
+        forms = [("plain", None)] + [(b, int(b)) for b in (
+            [T] if ring else args.blocks.split(","))]
         for label, block in forms:
             if block is None:
-                fn = functools.partial(op.gqa_attend, kernel=False)
+                fn = functools.partial(op.gqa_attend, kernel=False, ring=ring)
+            elif ring:
+                fn = functools.partial(op.gqa_attend, ring=True)
             else:
                 def fn(q, ck, cv, layer, pos, live, scale, block=block):
                     return slot_rows.attend(
@@ -110,7 +132,7 @@ def main() -> int:
             # leaves its arguments (`mla_attend_blocks.py` has why); a call
             # takes the one before it into its q, or the compiler would
             # lift the one layer's call out of the loop
-            def calls(ck, cv, n, fn=fn):
+            def calls(ck, cv, n, fn=fn, q=q, pos=pos, live=live, L=L, B=B):
                 return lax.fori_loop(0, n, lambda i, y: fn(
                     q + 1e-6 * y, ck, cv, i % L, pos, live, SCALE),
                     jnp.zeros((B, G, R, D), jnp.float32))
@@ -138,7 +160,8 @@ def main() -> int:
                 "max_abs_from_plain": float(np.abs(got - want).max()),
                 "plain_rms": float(np.sqrt(np.mean(want * want)))}
             print(name, label, json.dumps(rows[label]), flush=True)
-        out[name] = {"live": n_live, "attended_positions": attended,
+        out[name] = {"layers": L, "slots": B, "T": T, "live": n_live,
+                     "attended_positions": attended,
                      "least_ms": least * 1e3, "forms": rows}
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "gqa_attend_blocks.json"),

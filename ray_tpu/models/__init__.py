@@ -20,12 +20,17 @@ alone, the routed experts two-matrix relu^2 MLPs inside a narrow latent;
 sublayers and two dense FFNs whose expert block is read at one sublayer and
 added at the next one's end, zero-compute experts beside the routed ones;
 `mla` is the latent-attention layer with positions that deepseek and longcat
-share, and the latent rows' write and read)."""
+share, and the latent rows' write and read), exaone (K-EXAONE's layers for
+serving: three sliding-window softmax layers, rotated, to each global one,
+un-rotated, in one stack; the window layers' rows a ring a slot, which the
+pool keeps as a snapshot beside the global layers' rows by the block;
+sigmoid-routed experts beside a shared one)."""
 
 from ray_tpu.models import gpt2
 
 __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
-           "solar", "nemotron", "mamba2", "longcat", "mla", "serving_family"]
+           "solar", "nemotron", "mamba2", "longcat", "mla", "exaone",
+           "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
 # module and its config class. A module serves when it has that class
@@ -41,7 +46,7 @@ __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "key
 # request is placed in it, and a step leaves an inactive slot's state as it
 # was). A family may name both kinds (granite, kimi: the pool then keeps, under
 # one hash, a prefix's rows by the block and the state at its end, and a hit
-# needs both). A leaf neither names is the programs' own (`counts`).
+# needs both; exaone: its state is a sliding-window layer's last rows, a ring). A leaf neither names is the programs' own (`counts`).
 #
 # What a family borrows and what it holds. `models/lm.py` ("The serving
 # families") has what every family needs and none owns: seeded weights made
@@ -65,7 +70,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "keye": ("keye", "KeyeConfig"),
             "solar": ("solar", "KimiConfig"),
             "nemotron": ("nemotron", "NemotronConfig"),
-            "longcat": ("longcat", "LongcatConfig")}
+            "longcat": ("longcat", "LongcatConfig"),
+            "kexaone": ("exaone", "ExaoneConfig")}
 
 
 def serving_family(preset: str):
@@ -83,7 +89,8 @@ def serving_family(preset: str):
 
 def __getattr__(name):
     if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
-                "keye", "solar", "nemotron", "mamba2", "longcat", "mla"):
+                "keye", "solar", "nemotron", "mamba2", "longcat", "mla",
+                "exaone"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

@@ -526,9 +526,9 @@ def put_lanes(rest, xb, b):
 
 # -- grouped-head attention over rows by head --------------------------------
 #
-# What granite's and Solar's softmax layers share (`models/granite.py`,
-# `models/kimi.py`): keys and values cached by the G key-value heads. Where
-# a head has 64 lanes the leaves are [layers, slots, G, d, T], the positions
+# What four families' softmax layers share (`models/granite.py`, `kimi.py`
+# for Solar, `nemotron.py`, `exaone.py`): keys and values by the G key-value
+# heads. A head of 64 lanes: leaves [layers, slots, G, d, T], the positions
 # on the lanes, which is how the TPU's compiler lays a `[.., T, 64]` array
 # out anyway (granite; `ops/rows_write.py` writes a decode step's one
 # position a slot); where it has 128 they are [layers, slots, G, T, d], a
@@ -676,4 +676,79 @@ def gqa_write_slot(c, l, slot, val, pos, ok):
     written = hit.any(axis=-1)
     new = jnp.where(written if last else written[:, None], moved,
                     old[0, 0])
+    return lax.dynamic_update_slice(c, new[None, None], at)
+
+
+# -- a sliding window's rows, a ring a slot -----------------------------------
+#
+# A softmax layer that attends the last W positions alone (K-EXAONE's
+# sliding layers, `models/exaone.py`) keeps a slot's keys and values as a
+# ring, a leaf [layers, slots, G, W, d]: position p at row p mod W. A ring
+# whose newest position is `newest` holds position newest - ((newest - r)
+# mod W) at row r, and the row is live iff that is not negative: what a slot
+# held before (zeros, another request's rows) lies at the rows the sequence
+# has not reached. A decode step's one token a slot goes through
+# `ops/gqa_attend.py` (`ring=True`); these are the plain forms, the chunk
+# program's further lanes and the CPU's decode step.
+
+def ring_positions(newest, W: int):
+    """The position each of a ring's W rows holds when the newest position
+    written is `newest` [...] -> [..., W]; negative: the row is not the
+    sequence's."""
+    newest = jnp.asarray(newest)[..., None]
+    return newest - (newest - jnp.arange(W)) % W
+
+
+def gqa_attend_band(q, k, v, t, at, window: int, scale: float, dtype):
+    """q [..., Q, d] at positions `at` [..., Q] over rows k, v [..., S, d]
+    that hold the positions t [..., S] -> [..., Q, d] float32: a query sees
+    the rows with at - window < t <= at and t >= 0, wherever they lie. The
+    precision is `gqa_attend`'s, piece for piece."""
+    Q, whole = q.shape[-2], q.dtype != dtype
+    scores = _rows_added(jnp.einsum(
+        "...qd,...td->...qt", _row_pieces(q, dtype), k,
+        preferred_element_type=jnp.float32), Q)
+    t, at = t[..., None, :], at[..., None]
+    seen = (t >= 0) & (t <= at) & (t > at - window)
+    probs = jax.nn.softmax(jnp.where(seen, scores * scale, _MASKED), axis=-1)
+    probs = _row_pieces(probs, dtype) if whole else probs.astype(dtype)
+    return _rows_added(jnp.einsum("...qt,...td->...qd", probs, v,
+                                  preferred_element_type=jnp.float32), Q)
+
+
+def gqa_attend_ring(q, ck, cv, l, slot, k, v, at, pos, scale: float, dtype):
+    """One slot's queries q [G, Q, d] at positions `at` [G, Q], a chunk's
+    further lanes whose first stands at `pos`, against layer l of the rings
+    ck, cv [L,B,G,W,d] as the lane before them left them (newest position
+    pos - 1) and the chunk's own keys and values k, v [M,G,d] at pos ..: a
+    band of W over both. The ring is read here and written after
+    (`ring_write_slot`): a chunk's lanes overwrite rows that lanes before
+    them still read."""
+    G, W, d = ck.shape[2:]
+    M = k.shape[0]
+    old_k, old_v = (lax.dynamic_slice(c, (l, slot, 0, 0, 0),
+                                      (1, 1, G, W, d))[0, 0]
+                    for c in (ck, cv))
+    t = jnp.concatenate([ring_positions(pos - 1, W), pos + jnp.arange(M)])
+    rows_k = jnp.concatenate([old_k, jnp.swapaxes(k, 0, 1)], axis=1)
+    rows_v = jnp.concatenate([old_v, jnp.swapaxes(v, 0, 1)], axis=1)
+    return gqa_attend_band(q, rows_k, rows_v, t, at, W, scale, dtype)
+
+
+def ring_write_slot(c, l, slot, val, pos, n):
+    """Layer l of the rings c [L,B,G,W,d] takes the first n of val [M,G,d],
+    a chunk's lanes at positions pos .., at rows (pos + m) mod W of slot
+    `slot`: row r takes the last of them that falls on it (the lane at the
+    position the ring holds there once position pos + n - 1 is its newest),
+    and keeps what it has where none does. The lanes are moved by a 0/1
+    matrix (`gqa_write_slot`: exact)."""
+    G, W, d = c.shape[2:]
+    M = val.shape[0]
+    lane = ring_positions(pos + n - 1, W) - pos                       # [W]
+    hit = (lane[:, None] == jnp.arange(M)) & (lane >= 0)[:, None]
+    moved = jnp.einsum("wm,mgd->gwd", hit.astype(val.dtype), val,
+                       precision=lax.Precision.HIGHEST)
+    at = (l, slot, 0, 0, 0)
+    old = lax.dynamic_slice(c, at, (1, 1, G, W, d))
+    new = jnp.where(hit.any(axis=-1)[:, None], moved, old[0, 0])
     return lax.dynamic_update_slice(c, new[None, None], at)

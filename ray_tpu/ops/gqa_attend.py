@@ -29,6 +29,17 @@ rows as `lm._row_pieces` stacks them), a q of the rows' dtype as one.
 (`[.., d, T]`, a head of 64: granite) are not this kernel's: `lm.gqa_attend`
 stays their path, as it is every leaf's off the chip and what the kernel is
 tested against.
+
+A ring leaf (`ring=True`: `[layers, slots, G, W, d]`, the last W positions
+of a sliding-window layer, position p at row p mod W: `models/lm.py`, "a
+sliding window's rows") goes through the same body as one block of W
+positions, under the name `swa_attend`, with the mask by age where a leaf of
+rows has `t <= pos`: row r holds position pos - ((pos - r) mod W) and is
+live iff that is not negative, which is r <= pos while the ring fills and
+every row once pos >= W - 1. That is the rows' own mask with the position
+held at W - 1, and `slot_rows.plan` holds it there: the ring takes no line
+of its own in the kernel. W and d may be equal (128 and 128), so a ring
+says that it is one; it cannot be read off the shape.
 """
 
 from __future__ import annotations
@@ -85,35 +96,45 @@ def _weigh(p, v, *, two: bool):
         preferred_element_type=jnp.float32), two)
 
 
-def rows_kernel(q, ck, cv, scale) -> slot_rows.Kernel:
+def rows_kernel(q, ck, cv, scale, name="gqa_attend") -> slot_rows.Kernel:
     """This kernel on `slot_rows.attend`'s grid: a q that is not of the
     rows' dtype, and its probabilities, as two pieces."""
     two = q.dtype != ck.dtype
     return slot_rows.Kernel(
-        "gqa_attend",
+        name,
         functools.partial(_block_body, two=two, scale=float(scale)),
         (q, Leaf(ck, 3), Leaf(cv, 3)), q.shape[1:],
         functools.partial(_weigh, two=two))
 
 
 def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
-               scale: float, *, kernel: bool | None = None,
-               interpret: bool = False):
+               scale: float, *, ring: bool = False,
+               kernel: bool | None = None, interpret: bool = False):
     """Every slot's one token against its own rows of layer `layer`.
 
     q [B, G, R, d] (float32, or the rows' dtype), the leaves ck, cv
     [L, B, G, T, d] whole, pos [B] (slot b attends positions 0 .. pos[b]),
     live [B] -> [B, G, R, d] float32, garbage for a slot that is not live.
+    With `ring` the leaves are rings [L, B, G, W, d] that hold position
+    pos[b] already, and slot b attends the rows that are the sequence's,
+    positions max(0, pos[b] - W + 1) .. pos[b].
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which reads a live slot's rows once and to its position;
-    elsewhere `lm.gqa_attend` over the whole layer."""
-    assert not positions_last(ck.shape, q.shape[-1]), (ck.shape, q.shape)
+    elsewhere `lm.gqa_attend` over the whole layer (a ring:
+    `lm.gqa_attend_band` over the positions its rows hold)."""
+    assert ring or not positions_last(ck.shape, q.shape[-1]), (ck.shape,
+                                                               q.shape)
     if slot_rows.use_kernel(kernel, interpret):
-        return slot_rows.attend(rows_kernel(q, ck, cv, scale), layer, pos,
-                                live, interpret=interpret)
+        name = "swa_attend" if ring else "gqa_attend"
+        return slot_rows.attend(rows_kernel(q, ck, cv, scale, name), layer,
+                                pos, live, interpret=interpret)
     from ray_tpu.models import lm       # not at the top: `models` imports us
 
     k, v = (lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
             for c in (ck, cv))
-    return lm.gqa_attend(q, k, v, jnp.broadcast_to(
-        pos[:, None, None], q.shape[:3]), scale, ck.dtype)
+    at = jnp.broadcast_to(pos[:, None, None], q.shape[:3])
+    if ring:
+        W = ck.shape[3]
+        return lm.gqa_attend_band(q, k, v, lm.ring_positions(pos, W)[:, None],
+                                  at, W, scale, ck.dtype)
+    return lm.gqa_attend(q, k, v, at, scale, ck.dtype)

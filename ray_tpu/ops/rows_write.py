@@ -19,6 +19,11 @@ has why): a slot's tile of 128 positions is then [G, 128, d] and one of its
 rows is written. Which way round a leaf lies is read off its shape against
 `val`'s d (`positions_last`). (One scatter for all slots instead made the
 compiler re-lay both leaves round every step, T before G: 2.1 GB each.)
+
+A ring leaf (`ring=True`: `[layers, slots, G, W, d]`, the last W positions
+of a sliding-window layer: `models/lm.py`, "a sliding window's rows") is a
+leaf of rows whose every slot is W positions long and takes position p at
+row p mod W. W may equal d, so a ring says that it is one.
 """
 
 from __future__ import annotations
@@ -155,15 +160,18 @@ def positions_last(rows_shape, d: int) -> bool:
     return rows_shape[-2] == d
 
 
-def rows_write(c: jax.Array, layer, val, pos, on, *,
+def rows_write(c: jax.Array, layer, val, pos, on, *, ring: bool = False,
                kernel: bool | None = None, interpret: bool = False):
     """Layer `layer` of the leaf c [L, B, G, d, T] (or [L, B, G, T, d])
     takes val [B, G, d] at position pos[b] of every slot that is `on` [B];
     nothing else changes. With `layer` None every layer of c [L, B, G, d, T]
-    takes its own row, val [L, B, G, d], in one call.
+    takes its own row, val [L, B, G, d], in one call. With `ring` c is rings
+    [L, B, G, W, d] and the row is pos[b] mod W.
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which writes the leaf in place; elsewhere through plain XLA."""
-    last = positions_last(c.shape, val.shape[-1])
+    if ring:
+        pos = pos % c.shape[3]
+    last = not ring and positions_last(c.shape, val.shape[-1])
     if layer is None:
         assert last, c.shape
         if use_kernel(kernel, interpret):

@@ -14,6 +14,12 @@ needed block, which the pipeline finds already in VMEM; a slot that is not
 live is given the index the slot before it ended on, and reads nothing at
 all (`plan`).
 
+A ring leaf (the last W positions of a sliding-window layer, position p at
+row p mod W: `ops/gqa_attend.py`) is a leaf whose T is W, one block: `plan`
+holds a position past the leaf at its last row, W - 1, and the body's mask
+`t <= pos` is then the ring's mask by age, every row live once the ring is
+full and rows 0 .. pos before.
+
 What is a kernel's own is its `Kernel`: the operands, where the positions
 lie in each leaf, and the body of one block: how a block's scores are made
 and which values they weigh.

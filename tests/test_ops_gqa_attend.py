@@ -240,3 +240,136 @@ def test_the_programs_count_of_positions_read_follows_the_path(
     further = jnp.arange(19)[None, :] < (length - 1)[:, None]
     assert int(kimi._read_positions(cache, pos0, length, on, further)) == (
         first + 2 * 48)
+
+
+# ------------------------------------------------------------- a ring leaf
+
+W = 128                     # the window, and a head's lanes: a square leaf
+
+
+def _ring(B, pos, L=2, seed=0, stale=9.0):
+    """Rings [L, B, G, W, d] as a sequence that has reached position pos[b]
+    leaves them: position p at row p mod W; the rows the sequence has not
+    reached hold another request's values (large ones: a live stale row
+    would move the result by far more than any tolerance)."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    most = max(pos) + 1
+    keys = jax.random.normal(ks[0], (L, B, G, most, D), F32)
+    vals = jax.random.normal(ks[1], (L, B, G, most, D), F32)
+    wk = stale * jax.random.normal(ks[2], (L, B, G, W, D), F32)
+    wv = stale + jax.random.normal(ks[3], (L, B, G, W, D), F32)
+    for b, p in enumerate(pos):
+        for t in range(max(0, p - W + 1), p + 1):
+            wk = wk.at[:, b, :, t % W].set(keys[:, b, :, t])
+            wv = wv.at[:, b, :, t % W].set(vals[:, b, :, t])
+    return (keys.astype(BF16), vals.astype(BF16), wk.astype(BF16),
+            wv.astype(BF16))
+
+
+def _banded(q, keys, vals, layer, pos):
+    """The plain banded form over the whole sequence: slot b's query at
+    pos[b] against positions pos[b] - W + 1 .. pos[b], in float64."""
+    out = np.zeros(q.shape, np.float64)
+    q, keys, vals = (np.asarray(a, np.float64) for a in (q, keys, vals))
+    for b, p in enumerate(pos):
+        seen = np.arange(max(0, p - W + 1), p + 1)
+        s = np.einsum("grd,gtd->grt", q[b], keys[layer, b][:, seen]) * SCALE
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[b] = np.einsum("grt,gtd->grd", w / w.sum(-1, keepdims=True),
+                           vals[layer, b][:, seen])
+    return out
+
+
+@PIECES
+@pytest.mark.parametrize("pos", [
+    [0, 1, 126], [127, 128, 129], [5 * W + 77, 3 * W - 1, 3 * W]],
+    ids=["filling", "the-first-wrap", "past-a-wrap"])
+def test_a_ring_through_the_kernel_is_the_banded_form_with_stale_rows_dead(
+        pos, q_dtype):
+    """`ring=True`: the leaf is the last 128 positions, position p at row p
+    mod 128, and 128 x 128 (nothing can be read off its shape). At
+    positions 0, 1, 126, 127, 128, 129 and past several wraps, with a stale
+    ring from an earlier request in the slot: the kernel (interpreted) and
+    the plain form both give the banded form over the whole sequence."""
+    keys, vals, wk, wv = _ring(3, pos)
+    q = (4 * jax.random.normal(jax.random.key(7), (3, G, R, D), F32)).astype(
+        q_dtype)
+    args = (q, wk, wv, jnp.int32(1), jnp.asarray(pos, jnp.int32),
+            jnp.ones(3, bool))
+    got = jax.jit(lambda *a: op.gqa_attend(*a, SCALE, ring=True,
+                                           interpret=True))(*args)
+    plain = jax.jit(lambda *a: op.gqa_attend(*a, SCALE, ring=True,
+                                             kernel=False))(*args)
+    want = _banded(q, keys, vals, 1, pos)
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_allclose(got, plain, **TOLERANCE[q_dtype])
+    # against float64 the two pieces' own remainder shows (16 bits of q and
+    # of a probability): 6e-5; a stale row let in would show as 1 and more
+    exact = dict(rtol=0, atol=2e-4 if q_dtype == F32 else 2e-2)
+    np.testing.assert_allclose(got, want, **exact)
+    np.testing.assert_allclose(plain, want, **exact)
+    # the other layer's ring is another sequence's
+    other = _banded(q, keys, vals, 0, pos)
+    assert np.abs(other - want).max() > 0.1
+
+
+def test_a_ring_is_one_block_whose_mask_is_the_rows_mask_held_at_its_end():
+    """What `ring=True` costs the kernel: nothing. A ring is a leaf whose T
+    is the window, one block; `plan` holds a position past it at row W - 1,
+    where the body's `t <= pos` is the mask by age; the call is named
+    `swa_attend`, the rows' `gqa_attend`; and a ring's shape is refused
+    without the word."""
+    assert slot_rows.block_of(W) == W
+    pos = jnp.asarray([0, 5, W - 1, W, 9 * W + 3])
+    _, first, last, held = (np.asarray(a).tolist() for a in slot_rows.plan(
+        pos, jnp.ones(5, bool), W, W))
+    assert first == last == [0] * 5
+    assert held == [0, 5, W - 1, W - 1, W - 1]
+    for p in np.asarray(pos).tolist():
+        live_by_age = np.asarray(lm.ring_positions(p, W)) >= 0
+        np.testing.assert_array_equal(live_by_age,
+                                      np.arange(W) <= min(p, W - 1))
+    q, ck, cv = _operands(2, W, F32)
+    assert op.rows_kernel(q, ck, cv, SCALE).name == "gqa_attend"
+    assert op.rows_kernel(q, ck, cv, SCALE, "swa_attend").name == "swa_attend"
+    with pytest.raises(AssertionError):
+        op.gqa_attend(q, ck, cv, 0, jnp.zeros(2, jnp.int32),
+                      jnp.ones(2, bool), SCALE, kernel=False)
+
+
+def test_a_dead_slot_of_a_ring_reads_nothing():
+    pos = [300, 40, 127]
+    keys, vals, wk, wv = _ring(3, pos, L=1)
+    wk = wk.at[:, 1].set(jnp.nan)
+    q = jax.random.normal(jax.random.key(2), (3, G, R, D), F32)
+    live = jnp.asarray([True, False, True])
+    got = np.asarray(jax.jit(lambda *a: op.gqa_attend(
+        *a, SCALE, ring=True, interpret=True))(
+            q, wk, wv, jnp.int32(0), jnp.asarray(pos, jnp.int32), live))
+    want = _banded(q, keys, vals, 0, pos)
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], rtol=0, atol=2e-4)
+    assert not got[1].any()
+
+
+@pytest.mark.parametrize("how", [dict(interpret=True), dict(kernel=False)],
+                         ids=["kernel", "plain"])
+def test_a_rings_row_is_written_at_the_position_modulo_the_window(how):
+    """`ops/rows_write.py` with `ring=True`: position p goes to row p mod
+    128 of a 128 x 128 leaf (whose way round cannot be read off its shape),
+    a slot that is not on keeps its ring bit for bit, and no other layer or
+    row changes."""
+    from ray_tpu.ops.rows_write import rows_write
+
+    ring = jax.random.normal(jax.random.key(0), (2, 3, G, W, D), F32).astype(
+        BF16)
+    val = jax.random.normal(jax.random.key(1), (3, G, D), F32).astype(BF16)
+    pos = jnp.asarray([5, 3 * W + 17, 2 * W - 1], jnp.int32)
+    on = jnp.asarray([True, True, False])
+    got = np.asarray(jax.jit(lambda c, v: rows_write(
+        c, jnp.int32(1), v, pos, on, ring=True, **how))(ring, val), np.float32)
+    want = np.asarray(ring, np.float32).copy()
+    want[1, 0, :, 5] = np.asarray(val[0], np.float32)
+    want[1, 1, :, 17] = np.asarray(val[1], np.float32)
+    np.testing.assert_array_equal(got, want)
+    with pytest.raises(AssertionError):
+        rows_write(ring, jnp.int32(1), val, pos, on, **how)

@@ -176,3 +176,25 @@ def test_read_positions_follow_the_one_platform(monkeypatch, on_the_chip):
     assert int(slot_rows.read_positions(pos, live, T, kernel=False)) == 4 * T
     assert int(slot_rows.read_positions(pos, live, T, interpret=True)) \
         == rounded
+
+
+def test_a_ring_leaf_is_one_block_masked_at_its_last_row(monkeypatch):
+    """A leaf whose T is a sliding window (`ops/gqa_attend.py`, `ring`):
+    one block whatever `BLOCK` is, every slot's grid one step, and a
+    position past the leaf held at its last row, where `t <= pos` lets
+    every row through: the fold is then one softmax over the whole ring."""
+    monkeypatch.setattr(slot_rows, "BLOCK", 1024)
+    W = 128
+    assert slot_rows.block_of(W) == W
+    pos = [0, 77, W - 1, W, 40 * W + 5]
+    src, first, last, at = (np.asarray(a).tolist() for a in slot_rows.plan(
+        jnp.asarray(pos), jnp.ones(5, bool), W, W))
+    assert (src, first, last) == (list(range(5)), [0] * 5, [0] * 5)
+    assert at == [0, 77, W - 1, W - 1, W - 1]
+    q, leaf = _one_leaf(5, W)
+    got = _attend(q, leaf, 1, pos, [True] * 5)
+    want = _softmax_over_the_row(q, leaf[1], jnp.minimum(
+        jnp.asarray(pos), W - 1))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-2)
+    assert int(slot_rows.read_positions(
+        jnp.asarray(pos), jnp.ones(5, bool), W, interpret=True)) == 5 * W

@@ -838,6 +838,65 @@ def test_longcat_serving_programs_compile_at_the_configurations_sizes(
         "dense_matrix_copies": []}
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_exaone_serving_programs_compile_at_the_configurations_sizes(
+        chips, as_on_tpu, program):
+    """The cell `serve-kexaone-hotdocs`'s two programs, as its configuration
+    file has them (K-EXAONE-236B-A23B's widths, layers 0-7 L L L G L L L G
+    with 8 of 128 experts a sparse layer and an eighth of the vocabulary, 64
+    slots of 10,240 positions of keys and values by 8 heads in the two
+    global layers and six rings of 128 rows, chunks of 128), from
+    rehearse/compile_exaone_for_v5e.py: the bytes the file gives, with ==,
+    and room for the pool of both kinds beside the larger, between 75% and
+    95% of the chip; the Pallas kernels, a body a kind of layer (two
+    `rows_write` and one attention, `swa_attend` over a ring or `gqa_attend`
+    over rows, in each of the three bodies, one `expert_mlp` in the two
+    sparse ones: 11 in the decode program, and the further lanes' two more
+    `expert_mlp` in the chunk program); no instruction copies a cache leaf,
+    rows or rings, or one layer's for all slots, an expert matrix or the
+    dense MLP's out of its stack, or writes a chunk's scores over all of a
+    slot's positions."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_exaone_for_v5e import (CONFIG, cache_bytes, compile_step,
+                                        made_of, pool_bytes, program_bytes)
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    memory = config["memory"]
+    compiled = compile_step(config, chips, program)
+    sized = program_bytes(compiled)
+    chunk = str(config["deployment"]["prefill_chunk_size"])
+    if program == "decode":
+        assert sized["total"] == memory["decode_step_bytes"]
+        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 26
+    else:
+        assert sized["total"] == memory[
+            "prefill_chunk_bytes_by_chunk_size"][chunk]
+        assert sized["temp"] < 2 ** 30
+    assert sized["arguments"] == memory["arguments_bytes"] + (
+        0 if program == "decode" else 64 * 128 * 4)      # the chunk's tokens
+    assert cache_bytes(config) == {
+        "state_bytes_per_slot": memory["state_bytes_per_slot"],
+        "kv_bytes_per_token": memory["kv_bytes_per_token"]} == {
+        "state_bytes_per_slot": 3_145_728, "kv_bytes_per_token": 8192}
+    assert pool_bytes(config) == memory["prefix_pool_bytes"]
+    assert 0.75 * HBM_BYTES <= memory["prefill_chunk_bytes_by_chunk_size"][
+        chunk] + pool_bytes(config) <= 0.95 * HBM_BYTES
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert sum(c.endswith("/moe_experts/expert_mlp/pallas_call")
+               for c in calls) == (2 if program == "decode" else 4)
+    assert sum("/kv_update/" in c and "rows_write" in c for c in calls) == 6
+    assert sum("/swa_attend/" in c for c in calls) == 2
+    assert sum("/gqa_attend/" in c for c in calls) == 1
+    assert made_of(hlo, config) == {
+        "kernels": 11 if program == "decode" else 13,
+        "whole_slot_scores": [], "leaf_copies": {}, "layer_copies": {},
+        "expert_matrix_copies": [], "dense_matrix_copies": []}
+
+
 # The cell `serve-keye-longdoc`'s two programs, from
 # rehearse/compile_keye_for_v5e.py. Since PR 54 every slot's first lane
 # attends through `ops/dsa_attend.py`: the decode program's temporaries were
@@ -1192,6 +1251,38 @@ def test_gqa_attend_kernel_reads_the_leaves_where_they_lie(
         arr((40, 8, 8, 128), q_dtype), arr((1, 40, 8, T, 128)),
         arr((1, 40, 8, T, 128)), arr((), jnp.int32), arr((40,), jnp.int32),
         arr((40,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32-q-two-pieces", "bf16-q-one-piece"])
+def test_gqa_attend_kernel_takes_a_ring_leaf_at_the_exaone_cells_shape(
+        chips, as_on_tpu, q_dtype):
+    """`ops/gqa_attend.py` and `ops/rows_write.py` with `ring=True` at the
+    cell `serve-kexaone-hotdocs`'s shape, 64 slots x 8 key-value heads x a
+    ring of 128 rows of 128 lanes in 6 sliding layers: Mosaic accepts the
+    one block a slot (a square leaf: nothing is read off its shape), under
+    the name `swa_attend`; and neither program holds anything beside its
+    arguments: no layer of a leaf is sliced out."""
+    op = importlib.import_module("ray_tpu.ops.gqa_attend")
+    write = importlib.import_module("ray_tpu.ops.rows_write")
+    one = SingleDeviceSharding(chips[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ring = arr((6, 64, 8, 128, 128))
+    slots = (arr((), jnp.int32), arr((64,), jnp.int32), arr((64,), jnp.bool_))
+    compiled = jax.jit(lambda q, ck, cv, *slots: op.gqa_attend(
+        q, ck, cv, *slots, 128 ** -0.5, ring=True)).lower(
+        arr((64, 8, 8, 128), q_dtype), ring, ring, *slots).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "swa_attend" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    compiled = jax.jit(lambda c, layer, val, pos, on: write.rows_write(
+        c, layer, val, pos, on, ring=True), donate_argnums=(0,)).lower(
+        ring, slots[0], arr((64, 8, 128)), *slots[1:]).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
