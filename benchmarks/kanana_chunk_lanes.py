@@ -9,7 +9,8 @@ to run without a TPU, prints one JSON line a measurement and writes
 chiprun_out/KANANA_CHUNK_LANES.{json,md} (a run's output, never committed).
 
     chiprun -- python benchmarks/kanana_chunk_lanes.py
-        [--parent .scratch/parent] [--slots 0,1,2,4,8,32] [--forms reused,sliced]
+        [--parent .scratch/parent] [--slots 0,1,2,4,8,32] [--tokens 128,32]
+        [--forms reused,sliced]
 
 Forms:
   decode   `deepseek.decode_step`: what every slot's first lane costs
@@ -47,6 +48,29 @@ the engine's default budget (`max_num_batched_tokens` B + C) hands out at
 most two; a budget that lets 19 or more slots prefill in one step (about
 19 C = 2,432 tokens here) buys steps that cost more than all lanes at once
 did, up to 524 ms where all 32 do.
+
+Since PR 60 `reused` is the packed form: a layer's MLP (experts, shared
+experts, the dense layer's SwiGLU) takes the first lanes and the valid
+further lanes of the slots that prefill as the rows of one call of
+max(2 B, B + C) = 160 rows (`lm.all_lanes`), and only attention goes a slot
+at a time; a slot whose lanes no longer fit the 128 rows behind the first
+lanes is a round of its own. Measured (TPU v5 lite, one chip, PR 60, the
+parent PR 59's tree in the same call; `--tokens 128,32`: what each
+prefilling slot holds):
+
+    slots that prefill            0      1      2      4
+    decode                    13.94
+    reused, 128 tokens each   16.05  22.17  38.50  71.09
+    reused, 32 tokens each    16.05  21.15  24.94  31.81
+    parent, 128 or 32 each    15.67  31.60  47.52  79.32   (PR 48's `reused`)
+
+One slot rides in the first lanes' call for 6.1 ms at 128 tokens and 5.1 at
+32 (its attention over 128 padded lanes either way) where it cost 15.9; two
+slots of
+128 tokens are 254 further lanes for 128 rows, so the second is a round (a
+pass of the experts, 16.3 ms); four slots of 32 tokens, 124 lanes, are one
+round at 3.4-5 ms a slot. No slot prefilling, the call's 160 rows cost 0.4
+ms over the parent's 32.
 """
 
 from __future__ import annotations
@@ -77,30 +101,28 @@ def further_lanes_sliced(stacks: dict):
     """`deepseek._further_lanes` but for where a slot's weights come from:
     layer l of `stacks` (the engine's `dense` and `blocks`, the latter
     without the routed experts' matrices, which are `stack`'s in both
-    forms), sliced inside the loop's body."""
+    forms), sliced inside the loop's body. Since PR 60 the loop is the
+    layer's attention alone."""
     n_dense = jax.tree.leaves(stacks["dense"])[0].shape[0]
     moe = stacks["blocks"]["moe"]
     blocks = {**stacks["blocks"],
               "moe": {k: moe[k] for k in moe if k not in deepseek.ROUTED}}
 
-    def further_lanes(rest, bp, stack, cfg, lat, kr, given, l, pos, ok,
-                      prefilling):
+    def further_lanes(rest, bp, cfg, lat, kr, l, pos, ok, prefilling):
         M = rest.shape[1]
         layers, at = ((blocks, l - n_dense) if "moe" in bp
                       else (stacks["dense"], l))
 
         def slot(b, carry):
-            rest, lat, kr, given = carry
+            rest, lat, kr = carry
             own = lm.layer_weights(layers, at)
             xb, okb, first = lm.slot_lanes(b, rest, ok, pos)
             xb, lat, kr = deepseek._attention(
                 xb, own, cfg, lat, kr, l, first,
                 first[:, None] + jnp.arange(M), okb, slot=b)
-            xb, given = deepseek._mlp(xb, own, stack, l - n_dense, cfg,
-                                      given, okb)
-            return lm.put_lanes(rest, xb, b), lat, kr, given
+            return lm.put_lanes(rest, xb, b), lat, kr
 
-        return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
+        return lm.each_slot(prefilling, slot, (rest, lat, kr))
 
     return further_lanes
 
@@ -164,6 +186,8 @@ def main() -> None:
     ap.add_argument("--parent", default=os.path.join(REPO, ".scratch",
                                                      "parent"))
     ap.add_argument("--slots", default="0,1,2,4,8,32")
+    ap.add_argument("--tokens", default="128",
+                    help="tokens a slot that prefills has, comma-separated")
     ap.add_argument("--forms", default="",
                     help="only these forms, comma-separated")
     args = ap.parse_args()
@@ -186,9 +210,10 @@ def main() -> None:
     os.makedirs(out_dir, exist_ok=True)
     rows = []
 
-    def record(form, prefilling, fn, step_args):
+    def record(form, prefilling, fn, step_args, chunk_tokens=1):
         nonlocal cache
-        row = {"form": form, "prefilling_slots": prefilling}
+        row = {"form": form, "prefilling_slots": prefilling,
+               "chunk_tokens": chunk_tokens}
         try:
             row["ms"], cache = timed_ms(
                 lambda c, *a: fn(params, c, *a), cache, step_args)
@@ -213,11 +238,15 @@ def main() -> None:
     for form, fn in chunks.items():
         if wanted and form not in wanted:
             continue
-        for n in (int(s) for s in args.slots.split(",")):
-            length = jnp.where(jnp.arange(B) < n, C, 1).astype(jnp.int32)
-            record(form, n, fn, (tokens, pos0, length, on))
-    lines = ["| form | slots that prefill | ms a step |", "| --- | --- | --- |"]
+        for many in (int(s) for s in args.tokens.split(",")):
+            for n in (int(s) for s in args.slots.split(",")):
+                length = jnp.where(jnp.arange(B) < n, many, 1).astype(
+                    jnp.int32)
+                record(form, n, fn, (tokens, pos0, length, on), many)
+    lines = ["| form | slots that prefill | tokens each | ms a step |",
+             "| --- | --- | --- | --- |"]
     lines += [f"| {r['form']} | {r['prefilling_slots']} | "
+              f"{r['chunk_tokens']} | "
               + (f"{r['ms']:.2f} |" if "ms" in r
                  else f"refused: {r['refused'][:80]} |") for r in rows]
     with open(os.path.join(out_dir, "KANANA_CHUNK_LANES.md"), "w") as f:

@@ -366,3 +366,74 @@ class VirtualNodes:
             pass
         self.loop.call_soon_threadsafe(self.loop.stop)
         self._thread.join(timeout=10)
+
+
+# `chunk_step_against_decode`'s cases, for a chunk of 8 in 5 slots, where a
+# token-wise half takes 13 rows a call, the 5 first lanes and 8 further ones:
+# (lengths, active, rounds)
+LANES_OF_A_STEP = {
+    "none-prefills": ([1, 1, 4, 1, 1], [1, 1, 0, 1, 1], 1),
+    "one-prefills": ([6, 1, 4, 1, 0], [1, 1, 0, 1, 1], 1),
+    "two-prefill": ([6, 1, 4, 1, 3], [1, 1, 0, 1, 1], 1),
+    "three-prefill": ([5, 1, 4, 3, 2], [1, 1, 0, 1, 1], 1),
+    "all-prefill-whole-chunks": ([8, 8, 8, 8, 8], [1, 1, 1, 1, 1], 5),
+    "two-together-two-alone": ([4, 0, 5, 8, 2], [1, 1, 1, 1, 1], 3),
+}
+
+
+def chunk_step_against_decode(model, cfg, case: str, logit_tol: float,
+                              leaf_tol: float, C: int = 8, T: int = 48,
+                              seed: int = 0):
+    """A serving family's one chunk step against the same tokens a token at
+    a time (the family tests' shared check; it lives here because `tests/`
+    is no package): slot b takes `lengths[b]` tokens of a chunk of C where
+    `active[b]`, behind a history of its own length that `decode_step` fed.
+    The chunk program's logits at each such slot's last lane and every leaf
+    of its cache are the decode program's within the tolerances, a slot
+    that takes none keeps every leaf bit for bit, and the step's lanes go
+    through a token-wise half in the case's rounds (`lm.lane_rounds`)."""
+    import functools
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import lm
+
+    lengths, active, rounds = LANES_OF_A_STEP[case]
+    B = len(lengths)
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths, np.int32)
+    active = np.asarray(active, bool)
+    takes = np.where(active, lengths, 0)
+    history = (3 + 2 * np.arange(B)).astype(np.int32)
+    params = model.init_params(jax.random.key(seed), cfg)
+    step = jax.jit(functools.partial(model.decode_step, cfg=cfg))
+    chunk = jax.jit(functools.partial(model.prefill_chunk, cfg=cfg))
+    cache = model.init_cache(cfg, B, T)
+    for t in range(int(history.max())):
+        _, cache = step(params, cache,
+                        rng.integers(1, cfg.vocab_size, B).astype(np.int32),
+                        np.full((B,), t, np.int32), t < history)
+    before = jax.tree.map(np.asarray, cache)
+    tokens = rng.integers(1, cfg.vocab_size, (B, C)).astype(np.int32)
+    got, after = chunk(params, cache, tokens, history, lengths, active)
+    got, after = np.asarray(got), jax.tree.map(np.asarray, after)
+    want = np.zeros_like(got)
+    for t in range(int(takes.max(initial=0))):
+        logits, cache = step(params, cache, tokens[:, t], history + t,
+                             t < takes)
+        want = np.where((t == takes - 1)[:, None], np.asarray(logits), want)
+    moved = takes > 0
+    assert np.abs(got - want)[moved].max(initial=0) <= logit_tol
+    for name, leaf in jax.tree.map(np.asarray, cache).items():
+        if name == "counts":
+            continue
+        a, b = (np.moveaxis(x.astype(np.float32), 1, 0)
+                for x in (after[name], leaf))
+        assert np.abs(a - b).max() <= leaf_tol * max(1, np.abs(b).max()), (
+            name, np.abs(a - b).max(), np.abs(b).max())
+        np.testing.assert_array_equal(after[name][:, ~moved],
+                                      before[name][:, ~moved], err_msg=name)
+    further = np.arange(1, C + 1)[None, :] < takes[:, None]
+    plan = lm.lane_rounds(further, lm.slots_first(further.any(axis=1)))
+    assert int(plan["count"]) == rounds

@@ -323,13 +323,17 @@ def _expert_stack(blocks: Params, cfg: DeepseekConfig) -> tuple:
         (-1,) + blocks["moe"][w].shape[2:]) for w in ROUTED)
 
 
-def _expert_mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok):
+def _expert_mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok,
+                packed: bool = False):
     """x [N,C,D] += routed experts + shared experts of expert layer i
     (layer n_dense_layer + i), whose routed experts are entries i E ..
     (i + 1) E of `stack` (`_expert_stack`): the stack goes to the kernels
     whole with the ids offset by the layer, and the other layers' groups
     are empty. `given` [E] += the (lane, expert) rows each expert was given
-    for the lanes that are `ok`, by the layer's own ids."""
+    for the lanes that are `ok`, by the layer's own ids. `packed` (the rows
+    are `lm.pack_lanes`'): a row that is not `ok` is no lane's and goes past
+    the stack's end, where `moe._experts` (`first_expert` 0 of a stack
+    shorter than the ids) gives it no row of any matrix and zeroes it."""
     B, C, D = x.shape
     K, E = cfg.experts_per_token, cfg.n_experts
     with jax.named_scope("mlp"):
@@ -342,9 +346,13 @@ def _expert_mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok):
         with jax.named_scope("moe_router"):
             given = given.at[experts.reshape(-1)].add(
                 jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
+        entry, held = i * E + experts, stack[0].shape[0]
+        if packed:
+            entry = jnp.where(ok.reshape(-1, 1), entry, held)
         routed = _moe._experts(
-            h, gates.reshape(B, C, K), (i * E + experts).reshape(B, C, K),
-            *stack, dataclasses.replace(cfg, n_experts=stack[0].shape[0]))
+            h, gates.reshape(B, C, K), entry.reshape(B, C, K), *stack,
+            dataclasses.replace(cfg, n_experts=held + packed),
+            first_expert=jnp.int32(0) if packed else None)
         with jax.named_scope("moe_shared"):
             shared = _swiglu(h, bp["shared"], cfg)
         x = x + routed.astype(x.dtype) + shared.astype(x.dtype)
@@ -365,34 +373,32 @@ def _dense_mlp(x, bp, cfg: DeepseekConfig):
         return x + _swiglu(h, bp["mlp"], cfg).astype(x.dtype)
 
 
-def _mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok):
+def _mlp(x, bp, stack, i, cfg: DeepseekConfig, given, ok,
+         packed: bool = False):
     """The layer's second half, by what its weights are: (x, given)."""
     if "moe" in bp:
-        return _expert_mlp(x, bp, stack, i, cfg, given, ok)
+        return _expert_mlp(x, bp, stack, i, cfg, given, ok, packed)
     return _dense_mlp(x, bp, cfg), given
 
 
-def _further_lanes(rest, bp, stack, cfg: DeepseekConfig, lat, kr, given, l,
-                   pos, ok, prefilling):
-    """One layer over the lanes after the first, rest [B,M,D] with ok
-    [B,M], the first of them at position pos [B], for the slots
+def _further_lanes(rest, bp, cfg: DeepseekConfig, lat, kr, l, pos, ok,
+                   prefilling):
+    """One layer's attention over the lanes after the first, rest [B,M,D]
+    with ok [B,M], the first of them at position pos [B], for the slots
     `prefilling` a slot at a time (`lm.each_slot`): a slot's scores
-    [1,H,M,T] against its own rows, its experts over its own M lanes. The
-    weights are the ones the first lanes read: `bp` as the layers' scan
-    holds it (`lm.each_slot` has the rule) and the experts' `stack`, which
-    every slot's kernels read where it lies."""
+    [1,H,M,T] against its own rows. The weights are the ones the first
+    lanes read: `bp` as the layers' scan holds it (`lm.each_slot` has the
+    rule)."""
     M = rest.shape[1]
 
     def slot(b, carry):
-        rest, lat, kr, given = carry
+        rest, lat, kr = carry
         xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         xb, lat, kr = _attention(xb, bp, cfg, lat, kr, l, at,
                                  at[:, None] + jnp.arange(M), okb, slot=b)
-        xb, given = _mlp(xb, bp, stack, l - cfg.n_dense_layer, cfg, given,
-                         okb)
-        return lm.put_lanes(rest, xb, b), lat, kr, given
+        return lm.put_lanes(rest, xb, b), lat, kr
 
-    return lm.each_slot(prefilling, slot, (rest, lat, kr, given))
+    return lm.each_slot(prefilling, slot, (rest, lat, kr))
 
 
 def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
@@ -406,11 +412,13 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
 
     A layer computes a lane only where the plan put a token (`models/lm.py`,
     "The lanes of a chunk"): every slot's first lane all slots at once, the
-    lanes after it through `_further_lanes`, C of them a slot, the last one
-    padding for the grouped matmul's tiles (`lm.split_lanes`). A step costs
-    the decode program's time plus a term a slot that prefills, where all
-    B x C lanes through every layer cost the worst case whoever prefilled
-    (305 ms at 32 x 128 for one slot's question).
+    lanes after it through attention a slot at a time (`_further_lanes`, C
+    of them a slot, the last one padding: `lm.split_lanes`) and through the
+    layer's second half, which knows nothing of slots, as rows of the first
+    lanes' call (`lm.all_lanes`). A step costs the decode program's time
+    plus a slot's attention a slot that prefills, where all B x C lanes
+    through every layer cost the worst case whoever prefilled (305 ms at
+    32 x 128 for one slot's question).
 
     The dense layers stand before the loop, the expert layers are one scan
     over their stacked weights but the routed experts' three matrices,
@@ -424,6 +432,7 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
     counts = jnp.zeros((4,), jnp.uint32)
     n_dense = cfg.n_dense_layer
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     blocks = params["blocks"]
     stack = _expert_stack(blocks, cfg)
     rest_of = {**blocks, "moe": {k: w for k, w in blocks["moe"].items()
@@ -433,12 +442,20 @@ def _layers(x, params: Params, cache, cfg: DeepseekConfig, pos0, pos, ok,
         given = jnp.zeros((cfg.n_experts,), jnp.int32)
         first, lat, kr = _attention(first, bp, cfg, lat, kr, l, pos0,
                                     pos[:, :1], on[:, None])
-        first, given = _mlp(first, bp, stack, l - n_dense, cfg, given,
-                            on[:, None])
-        if rest is not None:
-            rest, lat, kr, given = _further_lanes(
-                rest, bp, stack, cfg, lat, kr, given, l, pos0 + 1, further,
-                prefilling)
+        if rest is None:
+            first, given = _mlp(first, bp, stack, l - n_dense, cfg, given,
+                                on[:, None])
+        else:
+            # the loop writes the leaves where the first lanes read them: its
+            # lanes wait for theirs (`lm.each_slot`; the experts' counts no
+            # longer tie the two, and a leaf through the barrier is re-laid)
+            first, rest = lax.optimization_barrier((first, rest))
+            rest, lat, kr = _further_lanes(rest, bp, cfg, lat, kr, l,
+                                           pos0 + 1, further, prefilling)
+            first, rest, given = lm.all_lanes(
+                lambda x, ok, g, given: _mlp(x, bp, stack, l - n_dense, cfg,
+                                             given, ok, packed=True),
+                first, on, rest, further, rounds, given)
         if "moe" in bp:
             counts = counts + _expert_counts(given)
         return first, rest, lat, kr, counts

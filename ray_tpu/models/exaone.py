@@ -61,7 +61,8 @@ through the layers, all slots at once (the rows and the rings through
 that plus a slot's further lanes for the slots that prefill
 (`lm.each_slot`: a global layer a block of positions at a time, a sliding
 layer the ring as it stood and the chunk's own keys in a band, the ring
-written after it is read): two forms and no third.
+written after it is read; the MLPs take those lanes as rows of the first
+lanes' call, `lm.all_lanes`): two forms and no third.
 
 The weights exist only in the dtype the replica holds them; float32 are the
 norms' scales, the router and its bias, and so are the residual stream,
@@ -453,14 +454,15 @@ def _dense_mlp(x, p, cfg: ExaoneConfig):
 
 
 def _expert_mlp(x, p, experts_of_all_layers, j, cfg: ExaoneConfig, given,
-                ok):
+                ok, packed: bool = False):
     """x [N,C,D] += the held experts' part of the routed sum + the shared
     expert, for sparse layer j; `given` [E] += the (lane, expert) pairs of
     the lanes that are `ok`, over all E (`kimi._expert_mlp`: a pair whose
     expert is held goes to entry j E' + e - first_expert of the stack of
     every layer's held experts, a pair whose expert is not past the stack's
     end, where `moe._experts` gives it no row of any matrix and zeroes
-    it)."""
+    it). `packed` (the rows are `lm.pack_lanes`'): a row that is not `ok` is
+    no lane's and goes there too."""
     B, C, D = x.shape
     K, held = cfg.experts_per_token, cfg.experts_held
     stack = experts_of_all_layers["wg"].shape[0]
@@ -474,6 +476,8 @@ def _expert_mlp(x, p, experts_of_all_layers, j, cfg: ExaoneConfig, given,
             local = experts - cfg.first_expert
             entry = jnp.where((local >= 0) & (local < held),
                               j * held + local, stack)
+            if packed:
+                entry = jnp.where(ok.reshape(-1, 1), entry, stack)
         routed = _moe._experts(
             h, gates.reshape(B, C, K), entry.reshape(B, C, K),
             *(experts_of_all_layers[w] for w in ("wg", "wu", "wd")),
@@ -499,49 +503,47 @@ def _expert_counts(given, cfg: ExaoneConfig):
 
 
 def _layer(kind: tuple, l, i, params: Params, cfg: ExaoneConfig, pos0, on,
-           further, prefilling, first, rest, cache, counts):
+           further, prefilling, rounds, first, rest, cache, counts):
     """Layer l, of `kind` (sliding or global, dense or sparse), entry i of
-    its kind's leaves: every slot's first lane all slots at once, then the
-    further lanes of the slots that have any, a slot at a time
-    (`lm.each_slot`, which has why the weights are sliced inside the body
-    here)."""
+    its kind's leaves. Attention takes every slot's first lane all slots at
+    once, then the further lanes of the slots that have any, a slot at a
+    time (`lm.each_slot`, which has why the weights are sliced inside the
+    body here); the MLP, which knows nothing of slots, every valid lane of
+    the step in one call (`lm.all_lanes`)."""
     attention, dense = kind
-    n_dense = cfg.n_dense_layer
     mlp_stack = params["dense" if dense else "moe"]
-    mlp_i = l if dense else l - n_dense
-    experts = None if dense else params["experts"]
+    mlp_i = l if dense else l - cfg.n_dense_layer
     given = jnp.zeros((cfg.n_experts,), jnp.int32)
 
-    def both(x, attn_p, mlp_p, cache, given, pos0, pos, ok, slot=None):
-        x, cache = _attention(x, attn_p, cfg, cache, i, pos0, pos, ok,
-                              attention, slot)
+    def mlp(x, ok, g, given):
+        p = lm.layer_weights(mlp_stack, mlp_i, turn=g)
         if dense:
-            return _dense_mlp(x, mlp_p, cfg), cache, given
-        x, given = _expert_mlp(x, mlp_p, experts, mlp_i, cfg, given, ok)
-        return x, cache, given
+            return _dense_mlp(x, p, cfg), given
+        return _expert_mlp(x, p, params["experts"], mlp_i, cfg, given, ok,
+                           packed=g is not None)
 
-    first, cache, given = both(
-        first, lm.layer_weights(params["attn"], l),
-        lm.layer_weights(mlp_stack, mlp_i), cache, given, pos0,
-        pos0[:, None], on[:, None])
-    if rest is not None:
+    first, cache = _attention(
+        first, lm.layer_weights(params["attn"], l), cfg, cache, i, pos0,
+        pos0[:, None], on[:, None], attention)
+    if rest is None:
+        first, given = mlp(first, on[:, None], None, given)
+    else:
         M = rest.shape[1]
-        if dense:
-            # the loop writes the leaves where the first lanes read them
-            # (`lm.each_slot`: no expert counts tie the two here)
-            first, cache = lax.optimization_barrier((first, cache))
+        # the loop writes the leaves where the first lanes read them
+        # (`lm.each_slot`: nothing else ties the two here)
+        first, cache = lax.optimization_barrier((first, cache))
 
         def slot(b, carry):
-            rest, cache, given = carry
+            rest, cache = carry
             xb, okb, at = lm.slot_lanes(b, rest, further, pos0 + 1)
-            xb, cache, given = both(
-                xb, lm.layer_weights(params["attn"], l, turn=b),
-                lm.layer_weights(mlp_stack, mlp_i, turn=b), cache, given, at,
-                at[:, None] + jnp.arange(M), okb, slot=b)
-            return lm.put_lanes(rest, xb, b), cache, given
+            xb, cache = _attention(
+                xb, lm.layer_weights(params["attn"], l, turn=b), cfg, cache,
+                i, at, at[:, None] + jnp.arange(M), okb, attention, b)
+            return lm.put_lanes(rest, xb, b), cache
 
-        rest, cache, given = lm.each_slot(prefilling, slot,
-                                          (rest, cache, given))
+        rest, cache = lm.each_slot(prefilling, slot, (rest, cache))
+        first, rest, given = lm.all_lanes(mlp, first, on, rest, further,
+                                          rounds, given)
     if not dense:
         counts = counts + _expert_counts(given, cfg)
     return first, rest, cache, counts
@@ -571,8 +573,9 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
              cfg: ExaoneConfig, program: int):
     """Both step programs (`kimi._forward`'s shape): a layer computes a lane
     only where the plan put a token, every slot's first lane all slots at
-    once and the lanes after it a slot at a time, C of them a slot with the
-    last one padding for the grouped matmul's tiles.
+    once and the lanes after it through attention a slot at a time, C of
+    them a slot with the last one padding, and through the MLP as rows of
+    the first lanes' call.
 
     The layers are walked as runs of one kind (sliding or global, dense or
     sparse): one loop over the runs, whose body holds one loop a kind, and a
@@ -590,6 +593,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     counts = jnp.zeros((len(COUNTS),), jnp.uint32)
     leaves = {k: v for k, v in cache.items() if k != "counts"}
     kinds = [(attention, l < cfg.n_dense_layer)
@@ -610,7 +614,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
 
     def layer(kind, l, carry):
         return _layer(kind, l, entry[l], params, cfg, pos0, on, further,
-                      prefilling, *carry)
+                      prefilling, rounds, *carry)
 
     def run(r, carry):
         start = first_layer[r]

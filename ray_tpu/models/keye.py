@@ -46,7 +46,8 @@ slot's rows of k and v once, to its position, with the set as a mask, and
 elsewhere a gather of the chosen rows and attention over them: the whole
 decode program) and a chunk's further lanes a slot at a time and only for
 the slots that prefill (`_attend_further`: the same set as a mask over the
-slot's rows, plain; `models/lm.py`, "The lanes of a chunk").
+slot's rows, plain; `models/lm.py`, "The lanes of a chunk"; the experts
+take those lanes as rows of the first lanes' call, `lm.all_lanes`).
 The experts of all layers are one stack `[layers x 128, d, 768]` that no
 loop slices (`models/kimi.py`'s form): a layer hands `moe._experts` the
 whole stack with its ids offset by the layer.
@@ -440,12 +441,15 @@ def _attend_further(x, p, cfg: KeyeConfig, cache, l, slot, at, angles, ok):
     return x, {**cache, "k": ck, "v": cv, "ik": cik}
 
 
-def _expert_mlp(x, p, experts_of_all_layers, l, cfg: KeyeConfig, given, ok):
+def _expert_mlp(x, p, experts_of_all_layers, l, cfg: KeyeConfig, given, ok,
+                packed: bool = False):
     """x [N,C,D] += the routed sum of layer l's experts; `given` [E] += the
     (lane, expert) pairs of the lanes that are `ok`. The stack of every
     layer's experts is handed over whole with the ids offset by the layer
     (`models/kimi.py`'s form): the groups of the other layers are empty,
-    and nothing is sliced out of it."""
+    and nothing is sliced out of it. `packed` (the rows are
+    `lm.pack_lanes`'): a row that is not `ok` is no lane's and goes past
+    the stack's end, to no expert."""
     B, C, D = x.shape
     K, E = cfg.experts_per_token, cfg.n_experts
     stack = experts_of_all_layers["wg"].shape[0]
@@ -456,8 +460,11 @@ def _expert_mlp(x, p, experts_of_all_layers, l, cfg: KeyeConfig, given, ok):
         with jax.named_scope("moe_router"):
             given = given.at[experts.reshape(-1)].add(
                 jnp.repeat(ok.reshape(-1), K).astype(jnp.int32))
+        entry = l * E + experts
+        if packed:
+            entry = jnp.where(ok.reshape(-1, 1), entry, stack)
         routed = _moe._experts(
-            h, gates.reshape(B, C, K), (l * E + experts).reshape(B, C, K),
+            h, gates.reshape(B, C, K), entry.reshape(B, C, K),
             *(experts_of_all_layers[w] for w in ("wg", "wu", "wd")),
             types.SimpleNamespace(n_experts=stack + 1, experts_per_token=K,
                                   dtype=jnp.float32),
@@ -465,25 +472,24 @@ def _expert_mlp(x, p, experts_of_all_layers, l, cfg: KeyeConfig, given, ok):
         return x + routed, given
 
 
-def _further_lanes(rest, params: Params, l, cfg: KeyeConfig, cache, given,
-                   pos, positions, ok, prefilling):
-    """Layer l over the lanes after the first, rest [B,M,D] with ok [B,M],
-    the first of them at row pos [B] and at `positions` [3,B,M], for the
-    slots `prefilling` a slot at a time (`lm.each_slot`, which has why the
-    weights are sliced inside the body here)."""
+def _further_lanes(rest, params: Params, l, cfg: KeyeConfig, cache, pos,
+                   positions, ok, prefilling):
+    """Layer l's attention over the lanes after the first, rest [B,M,D] with
+    ok [B,M], the first of them at row pos [B] and at `positions` [3,B,M],
+    for the slots `prefilling` a slot at a time (`lm.each_slot`, which has
+    why the weights are sliced inside the body here)."""
     M = rest.shape[1]
 
     def slot(b, carry):
-        rest, cache, given = carry
+        rest, cache = carry
         p = lm.layer_weights(params["layers"], l, turn=b)
         xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         angles = rope_angles(lax.dynamic_slice(
             positions, (0, b, 0), (3, 1, M)), cfg)
         xb, cache = _attend_further(xb, p, cfg, cache, l, b, at, angles, okb)
-        xb, given = _expert_mlp(xb, p, params["experts"], l, cfg, given, okb)
-        return lm.put_lanes(rest, xb, b), cache, given
+        return lm.put_lanes(rest, xb, b), cache
 
-    return lm.each_slot(prefilling, slot, (rest, cache, given))
+    return lm.each_slot(prefilling, slot, (rest, cache))
 
 
 def _read_positions(T: int, pos0, on, further, cfg: KeyeConfig):
@@ -525,6 +531,7 @@ def _forward(params: Params, cache, tokens, pos0, positions, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     angles = rope_angles(positions[:, :, :1], cfg)
     if rest is not None:
         # the padding lane stands one past the chunk's last
@@ -538,12 +545,24 @@ def _forward(params: Params, cache, tokens, pos0, positions, length, active,
         p = lm.layer_weights(params["layers"], l)
         first, leaves = _attend_first(first, p, cfg, leaves, l, pos0, angles,
                                       on)
-        first, given = _expert_mlp(first, p, params["experts"], l, cfg,
-                                   given, on[:, None])
-        if rest is not None:
-            rest, leaves, given = _further_lanes(
-                rest, params, l, cfg, leaves, given, pos0 + 1, after,
-                further, prefilling)
+        if rest is None:
+            first, given = _expert_mlp(first, p, params["experts"], l, cfg,
+                                       given, on[:, None])
+        else:
+            # the loop writes the leaves where the first lanes read them: its
+            # lanes wait for theirs (`lm.each_slot`; the experts' counts no
+            # longer tie the two, and a leaf through the barrier is re-laid)
+            first, rest = lax.optimization_barrier((first, rest))
+            rest, leaves = _further_lanes(rest, params, l, cfg, leaves,
+                                          pos0 + 1, after, further,
+                                          prefilling)
+            # the experts know nothing of slots: every valid lane of the
+            # step is a row of one call
+            first, rest, given = lm.all_lanes(
+                lambda x, ok, g, given: _expert_mlp(
+                    x, lm.layer_weights(params["layers"], l, turn=g),
+                    params["experts"], l, cfg, given, ok, packed=True),
+                first, on, rest, further, rounds, given)
         return first, rest, leaves, counts + _expert_counts(given)
 
     with jax.named_scope("layers"):

@@ -779,10 +779,13 @@ def _dense_mlp(x, p, cfg: KimiConfig):
         return x + _swiglu(rms_norm(x, p["norm"], cfg.norm_eps), p, cfg)
 
 
-def _expert_mlp(x, p, experts_of_all_layers, j, cfg: KimiConfig, given, ok):
+def _expert_mlp(x, p, experts_of_all_layers, j, cfg: KimiConfig, given, ok,
+                packed: bool = False):
     """x [N,C,D] += the held experts' part of the routed sum + the shared
     expert, for expert layer j; `given` [E] += the (lane, expert) pairs of
-    the lanes that are `ok`, over all E.
+    the lanes that are `ok`, over all E. `packed` (the rows are
+    `lm.pack_lanes`'): a row that is not `ok` is no lane's and goes to no
+    expert.
 
     The router scores all E experts and chooses K of them. A pair whose
     expert is held, e in first_expert..+E', goes to entry j E' + e -
@@ -804,6 +807,8 @@ def _expert_mlp(x, p, experts_of_all_layers, j, cfg: KimiConfig, given, ok):
             local = experts - cfg.first_expert
             entry = jnp.where((local >= 0) & (local < held),
                               j * held + local, stack)
+            if packed:
+                entry = jnp.where(ok.reshape(-1, 1), entry, stack)
         routed = _moe._experts(
             h, gates.reshape(B, C, K), entry.reshape(B, C, K),
             *(experts_of_all_layers[w] for w in ("wg", "wu", "wd")),
@@ -828,17 +833,16 @@ def _expert_counts(given, cfg: KimiConfig):
                           jnp.sum(given)]).astype(jnp.uint32)
 
 
-def _further_lanes(rest, mixer: str, mixer_stack, i, mlp_stack, mlp_i,
-                   experts, j, cfg: KimiConfig, cache, given, pos, ok,
-                   prefilling):
-    """One layer over the lanes after the first, rest [B,M,D] with ok
-    [B,M], the first of them at position pos [B], for the slots
+def _further_lanes(rest, mixer: str, mixer_stack, i, cfg: KimiConfig, cache,
+                   pos, ok, prefilling):
+    """One layer's mixer over the lanes after the first, rest [B,M,D] with
+    ok [B,M], the first of them at position pos [B], for the slots
     `prefilling` a slot at a time (`lm.each_slot`, which has why the weights
     are sliced inside the body here)."""
     M = rest.shape[1]
 
     def slot(b, carry):
-        rest, cache, given = carry
+        rest, cache = carry
         p = lm.layer_weights(mixer_stack, i)
         xb, okb, at = lm.slot_lanes(b, rest, ok, pos)
         if mixer == "mla":
@@ -848,25 +852,21 @@ def _further_lanes(rest, mixer: str, mixer_stack, i, mlp_stack, mlp_i,
             xb, cache = _gqa(xb, p, cfg, cache, i, at, okb, slot=b)
         else:
             xb, cache = _kda_further(xb, p, cfg, cache, i, b, okb)
-        mp = lm.layer_weights(mlp_stack, mlp_i)
-        if experts is None:
-            xb = _dense_mlp(xb, mp, cfg)
-        else:
-            xb, given = _expert_mlp(xb, mp, experts, j, cfg, given, okb)
-        return lm.put_lanes(rest, xb, b), cache, given
+        return lm.put_lanes(rest, xb, b), cache
 
-    return lm.each_slot(prefilling, slot, (rest, cache, given))
+    return lm.each_slot(prefilling, slot, (rest, cache))
 
 
 def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
-           on, further, prefilling, first, rest, cache, counts):
+           on, further, prefilling, rounds, first, rest, cache, counts):
     """One layer: the mixer `mixer` with entry i of its stack, then the
     dense MLP with entry mlp_i of its stack (j None) or expert layer j's
-    block. Every slot's first lane all slots at once, then the further
-    lanes of the slots that have any."""
+    block. The mixer takes every slot's first lane all slots at once, then
+    the further lanes of the slots that have any, a slot at a time; the
+    MLP, which knows nothing of slots, every valid lane of the step in one
+    call (`lm.all_lanes`)."""
     dense = j is None
     mlp_stack = params["dense" if dense else "moe"]
-    experts = None if dense else params["experts"]
     given = jnp.zeros((cfg.n_experts,), jnp.int32)
     p = lm.layer_weights(params[mixer], i)
     if mixer == "mla":
@@ -876,16 +876,25 @@ def _layer(mixer: str, i, mlp_i, j, params: Params, cfg: KimiConfig, pos0,
         first, cache = _gqa(first, p, cfg, cache, i, pos0, on[:, None])
     else:
         first, cache = _kda_first(first, p, cfg, cache, i, on)
-    mp = lm.layer_weights(mlp_stack, mlp_i)
-    if dense:
-        first = _dense_mlp(first, mp, cfg)
+
+    def mlp(x, ok, g, given):
+        mp = lm.layer_weights(mlp_stack, mlp_i, turn=g)
+        if dense:
+            return _dense_mlp(x, mp, cfg), given
+        return _expert_mlp(x, mp, params["experts"], j, cfg, given, ok,
+                           packed=g is not None)
+
+    if rest is None:
+        first, given = mlp(first, on[:, None], None, given)
     else:
-        first, given = _expert_mlp(first, mp, experts, j, cfg, given,
-                                   on[:, None])
-    if rest is not None:
-        rest, cache, given = _further_lanes(
-            rest, mixer, params[mixer], i, mlp_stack, mlp_i, experts, j, cfg,
-            cache, given, pos0 + 1, further, prefilling)
+        # the loop writes the leaves where the first lanes read them: its
+        # lanes wait for theirs (`lm.each_slot`; the experts' counts no
+        # longer tie the two, and a leaf through the barrier is re-laid)
+        first, rest = lax.optimization_barrier((first, rest))
+        rest, cache = _further_lanes(rest, mixer, params[mixer], i, cfg,
+                                     cache, pos0 + 1, further, prefilling)
+        first, rest, given = lm.all_lanes(mlp, first, on, rest, further,
+                                          rounds, given)
     if not dense:
         counts = counts + _expert_counts(given, cfg)
     return first, rest, cache, counts
@@ -923,9 +932,9 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     """Both step programs. A layer computes a lane only where the plan put
     a token (`models/lm.py`, "The lanes of a chunk"): every slot's first
     lane all slots at once, which is the whole decode program; the lanes
-    after it a slot at a time, only the slots that have them, C of them a
-    slot with the last one padding for the grouped matmul's tiles
-    (`lm.split_lanes`).
+    after it through a layer's mixer a slot at a time, only the slots that
+    have them, C of them a slot with the last one padding
+    (`lm.split_lanes`), and through its MLP as rows of the first lanes' call.
 
     The dense layers stand before the loops. The loops carry the cache, one
     buffer a leaf from layer to layer, written in place where the caller
@@ -937,6 +946,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     counts = jnp.zeros((len(COUNTS),), jnp.uint32)
     leaves = {k: v for k, v in cache.items() if k != "counts"}
     index = _stack_index(cfg)
@@ -969,7 +979,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
         mixer, dense = kind
         return _layer(mixer, mixer_at[l], l if dense else l - n_dense,
                       None if dense else l - n_dense, params, cfg, pos0, on,
-                      further, prefilling, *carry)
+                      further, prefilling, rounds, *carry)
 
     def run(r, carry):
         start = first_layer[r]

@@ -431,6 +431,14 @@ def short_conv(x, taps, window, ok, bias=None):
 # go a slot at a time and only the slots that have any (`each_slot`), so a
 # chunk step costs the decode program's time plus a term a slot that
 # prefills, not B x C lanes whoever prefills.
+#
+# That holds for the half of a layer that mixes a sequence (attention, a
+# state's pass, the cache writes). The half that knows nothing of slots (a
+# router, routed and shared experts, a dense MLP) takes the first lanes and
+# the valid further lanes of every slot as the rows of one call
+# (`lane_rounds`, `pack_lanes`, `unpack_lanes`): its time at these few rows
+# is the weights it reads, and a call a slot read them once more a slot that
+# prefilled (PERF.md, PR 60).
 
 def slots_first(has):
     """has [B] bool -> (the indices of the slots that have it first, in
@@ -486,8 +494,13 @@ def each_slot(slots, slot: Callable, carry):
     PERF.md, PR 40; and a leaf that passes through a conditional untouched
     may be copied on its way.)
 
-    The body stays the family's, because how a layer's weights reach it is
-    measured, and a new family reads this first. No loop slices a routed
+    `slots` may be a stretch of them, (indices, start, stop): the slots of
+    one round of `lane_rounds`.
+
+    The body stays the family's, and it is the half of a layer that mixes a
+    sequence (the token-wise half takes every slot's lanes in one call:
+    `pack_lanes`), because how a layer's weights reach it is measured, and a
+    new family reads this first. No loop slices a routed
     expert's matrix, a copy for its kernel: a body reads the stack of every
     layer's experts whole, as the first lanes do (PERF.md, PR 48). Where the
     layers' loop is a scan over the other weights, close over the layer as
@@ -502,10 +515,15 @@ def each_slot(slots, slot: Callable, carry):
     where nothing a body takes comes from the first lanes' pass (granite:
     no expert counts go in), the compiler cannot tell which is first and
     copies the leaf for the loop, 1.6 GB a leaf a layer. Tie them before
-    the loop: `first, cache = lax.optimization_barrier((first, cache))`."""
-    indices, count = slots
+    the loop: `first, cache = lax.optimization_barrier((first, cache))`, or
+    `(first, rest)` where a leaf's rows are narrower than 128 lanes: such a
+    leaf comes out of a barrier in the default layout, re-laid on its way in
+    and out of the layers' loop (Kimi's `k_rope`, 0.5 GB: PERF.md, PR 60),
+    and the loop waits for its lanes as well as for its leaves."""
+    indices, *stretch = slots
+    start, stop = stretch if len(stretch) == 2 else (0, *stretch)
     return lax.fori_loop(
-        0, count, lambda n, carry: slot(
+        start, stop, lambda n, carry: slot(
             lax.dynamic_index_in_dim(indices, n, 0, keepdims=False), carry),
         carry)
 
@@ -522,6 +540,132 @@ def slot_lanes(b, rest, ok, pos):
 def put_lanes(rest, xb, b):
     """`rest` with slot b's lanes xb [1,M,D] back in their place."""
     return lax.dynamic_update_slice(rest, xb, (b, 0, 0))
+
+
+# -- every lane of a step as the rows of one call ------------------------------
+
+def lanes_a_dispatch(B: int, C: int) -> int:
+    """N, the rows a token-wise block takes in one call: the engine's
+    default `max_num_batched_tokens` (`serve/llm.py`), so that every slot's
+    first lane and B or C further lanes, whichever is more, ride together."""
+    return max(2 * B, B + C)
+
+
+def lane_rounds(further, prefilling):
+    """How a step's lanes go through a layer's token-wise half: what
+    `pack_lanes` reads, from `split_lanes`' further [B,M] and prefilling.
+    Round 0 is every slot's first lane and, behind them in slot order, the
+    valid further lanes of the leading prefilling slots whose lanes all fit
+    the N - B rows left; each prefilling slot after those is a round of its
+    own (what it cost before the lanes were packed). A step the engine's
+    default budget plans with every slot busy is one round, and so is every
+    step whose valid further lanes are N - B at most. A slot's valid further
+    lanes are its leading ones (a chunk is left-aligned), and M is C: the
+    families that pack pad (`split_lanes`). None in the decode program,
+    which has no further lane."""
+    if further is None:
+        return None
+    B, M = further.shape
+    indices, count = prefilling
+    with jax.named_scope("embed"):
+        lanes = further.sum(axis=1).astype(jnp.int32)                  # [B]
+        before = jnp.cumsum(lanes) - lanes
+        fits = (lanes > 0) & (before + lanes <= lanes_a_dispatch(B, M) - B)
+        return {"indices": indices, "lanes": lanes, "before": before,
+                "together": fits.sum().astype(jnp.int32),
+                "rows": jnp.max(jnp.where(fits, before + lanes, 0)),
+                "count": 1 + count - fits.sum().astype(jnp.int32)}
+
+
+def round_slots(rounds, g):
+    """Round g's prefilling slots, for `each_slot`."""
+    alone = rounds["together"] + g - 1
+    return (rounds["indices"], jnp.where(g == 0, 0, alone),
+            jnp.where(g == 0, rounds["together"], alone + 1))
+
+
+def _round_rows(rounds, g):
+    """(the row of the packed further lanes that round g starts at, how many
+    they are)."""
+    B = rounds["lanes"].shape[0]
+    s = rounds["indices"][jnp.clip(rounds["together"] + g - 1, 0, B - 1)]
+    return (jnp.where(g == 0, 0, rounds["before"][s]),
+            jnp.where(g == 0, rounds["rows"], rounds["lanes"][s]))
+
+
+def pack_lanes(first, on, rest, rounds, g):
+    """Round g's lanes as the rows of one call: first [B,1,D] with on [B]
+    and rest [B,M,D] -> (rows [1,N,D], ok [1,N]), N `lanes_a_dispatch`'s
+    of the program's shapes. The B first lanes, then
+    the round's slots' valid further lanes in slot order; a row past them
+    holds some lane's values or zeros and is not `ok`, nor are the first
+    lanes in a round after 0. A slot's lanes come as one window of M rows,
+    the next slot's laid over its invalid tail: no row is gathered."""
+    B, M, D = rest.shape
+    N = lanes_a_dispatch(B, M)
+    start, many = _round_rows(rounds, g)
+
+    def slot(b, rows):
+        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))[0]
+        return lax.dynamic_update_slice(
+            rows, xb, (B + rounds["before"][b] - start, 0))
+
+    rows = jnp.concatenate(
+        [first[:, 0], jnp.zeros((N - B + M, D), first.dtype)])
+    rows = each_slot(round_slots(rounds, g), slot, rows)[:N]
+    ok = jnp.concatenate([on & (g == 0), jnp.arange(N - B) < many])
+    return rows[None], ok[None]
+
+
+def unpack_lanes(first, rest, further, rows, rounds, g):
+    """`pack_lanes`' inverse: the block's rows [1,N,D] back in `first` (in
+    round 0) and in the valid further lanes of the round's slots; no other
+    lane of `rest` changes."""
+    B, M, D = rest.shape
+    start, _ = _round_rows(rounds, g)
+    rows = jnp.concatenate([rows[0], jnp.zeros((M, D), rows.dtype)])
+
+    def slot(b, rest):
+        new = lax.dynamic_slice(
+            rows, (B + rounds["before"][b] - start, 0), (M, D))[None]
+        xb = lax.dynamic_slice(rest, (b, 0, 0), (1, M, D))
+        okb = lax.dynamic_slice(further, (b, 0), (1, M))
+        return put_lanes(rest, jnp.where(okb[:, :, None], new, xb), b)
+
+    return (jnp.where(g == 0, rows[:B, None], first),
+            each_slot(round_slots(rounds, g), slot, rest))
+
+
+def row_buckets(B: int, M: int) -> tuple:
+    """The rows a call may be cut to, ascending: the first lanes and one to
+    four quarters of the N - B rows behind them. For a family whose
+    token-wise half costs by the row and not by the weights it reads
+    (LongCat's two-piece products of 6,144 x 24,576 are the MXU's time at
+    128 rows already): a branch a bucket, a round takes the smallest that
+    holds its rows (`round_bucket`)."""
+    cap = lanes_a_dispatch(B, M) - B
+    return tuple(sorted({B + -(-cap * k // 4) for k in (1, 2, 3, 4)}))
+
+
+def round_bucket(rounds, g, buckets: tuple):
+    """The index of the smallest of `buckets` that holds round g's rows."""
+    rows = rounds["lanes"].shape[0] + _round_rows(rounds, g)[1]
+    return jnp.sum(rows > jnp.asarray(buckets[:-1])).astype(jnp.int32)
+
+
+def all_lanes(block, first, on, rest, further, rounds, carry):
+    """A layer's token-wise half over every valid lane of a chunk step:
+    `block(x [1,N,D], ok [1,N], g, carry) -> (x, carry)`, called once a
+    round (`lane_rounds`: once a step the default budget plans), its rows
+    put back. g is the round, for the body's own slice of its weights
+    (`layer_weights`' `turn`). -> (first, rest, carry)."""
+    def one(g, state):
+        first, rest, carry = state
+        x, ok = pack_lanes(first, on, rest, rounds, g)
+        x, carry = block(x, ok, g, carry)
+        return (*unpack_lanes(first, rest, further, x, rounds, g), carry)
+
+    return lax.fori_loop(0, rounds["count"], one, (first, rest, carry))
 
 
 # -- grouped-head attention over rows by head --------------------------------

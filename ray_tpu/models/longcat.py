@@ -48,9 +48,11 @@ n_layer, slots, T, 512 | 64], sublayer i of layer l at entry 2 l + i
 seven and `zero_rows`).
 
 Both programs are one function: `decode_step` is every slot's first lane
-through the layers, all slots at once, and `prefill_chunk` that plus a
-slot's further lanes for the slots that prefill (`lm.each_slot`): two forms
-and no third; r is carried across the second sublayer in both.
+through the layers, all slots at once, and `prefill_chunk` that plus the
+further lanes of the slots that prefill, through attention a slot at a time
+(`lm.each_slot`) and through the router, the experts and the dense FFNs as
+rows of the first lanes' call (`lm.pack_lanes`): two forms and no third; r
+is carried across the second sublayer in both.
 
 The weights exist only in the dtype the replica holds them; float32 are the
 norms' scales, the router and its bias, and so are the residual stream, r,
@@ -352,7 +354,7 @@ def _dense_ffn(h, p, cfg: LongcatConfig):
 
 
 def _expert_block(h, p, experts_of_all_layers, l, cfg: LongcatConfig, given,
-                  ok):
+                  ok, packed: bool = False):
     """The normed h [N,C,D] float32 -> r, the held experts' part of the
     routed sum + the zero-compute experts' term, for layer l; `given` [768]
     += the (lane, output) pairs of the lanes that are `ok`, over all the
@@ -365,7 +367,8 @@ def _expert_block(h, p, experts_of_all_layers, l, cfg: LongcatConfig, given,
     is zero-compute (e >= 512) to the one after it, `moe._experts`' one
     zero-compute id: neither is given a row of any matrix, both are zeroed on
     the way out, and the zero pairs' gates times h are added there
-    (`moe_zero`)."""
+    (`moe_zero`). `packed` (the rows are `lm.pack_lanes`'): a row that is
+    not `ok` is no lane's and goes to no expert."""
     B, C, D = h.shape
     K, held = cfg.experts_per_token, cfg.experts_held
     stack = experts_of_all_layers["wg"].shape[0]
@@ -378,6 +381,8 @@ def _expert_block(h, p, experts_of_all_layers, l, cfg: LongcatConfig, given,
         entry = jnp.where(
             experts >= cfg.n_experts, stack + 1,
             jnp.where((local >= 0) & (local < held), l * held + local, stack))
+        if packed:
+            entry = jnp.where(ok.reshape(-1, 1), entry, stack)
     r = _moe._experts(
         h, gates.reshape(B, C, K), entry.reshape(B, C, K),
         *(experts_of_all_layers[w] for w in ("wg", "wu", "wd")),
@@ -402,61 +407,114 @@ def _expert_counts(given, cfg: LongcatConfig):
                           jnp.sum(given[cfg.n_experts:])]).astype(jnp.uint32)
 
 
-def _double_layer(x, l, params: Params, cfg: LongcatConfig, lat, kr, given,
-                  pos0, pos, ok, slot=None):
-    """Layer l over x [N,C,D] float32: every slot's first lane (N = B, C =
-    1) or `slot`'s own further lanes (N = 1). r is asked for where h0 exists
-    and added where the layer ends; between the two stand the first dense
-    FFN, the second attention sublayer and the second dense FFN, none of
-    which reads it. The weights are sliced where they lie, a slot's turn its
-    own (`lm.layer_weights`). -> (x, lat, kr, given)."""
+def _layer(l, params: Params, cfg: LongcatConfig, pos0, on, further,
+           prefilling, rounds, first, rest, lat, kr, counts):
+    """One double layer. Its two attention sublayers mix a sequence: every
+    slot's first lane all slots at once, then the further lanes of the slots
+    that have any, a slot at a time (`lm.each_slot`, which has why the
+    weights are sliced inside the body here). What stands behind each is
+    token-wise and takes every valid lane of the step in one call
+    (`lm.pack_lanes`): behind the first the router, the routed experts (r,
+    asked for where h0 exists) and the first dense FFN, behind the second
+    the second dense FFN, where r is added, still in the rows it was made
+    in. The decode program is the first lanes' four steps alone.
+
+    A step whose lanes are more than a call's rows goes in rounds
+    (`lm.lane_rounds`), and a round is the layer from the first dense FFN
+    on for its own slots, since r lives between the two calls: the first
+    lanes' second sublayer belongs to round 0 and writes and counts in no
+    other. At these widths a row costs MXU time whether it is a lane's or
+    not (the dense FFNs' two-piece products, the pairs' rows by expert), so
+    each call is cut to the quarter of the rows behind the first lanes that
+    the round needs (`lm.row_buckets`): a branch a bucket, one taken."""
     scales = mla.latent_scales(cfg)
+    given = jnp.zeros((cfg.router_outputs,), jnp.int32)
 
-    def part(name, i):
-        return lm.layer_weights(params[name], i, turn=slot)
+    def part(name, i, turn=None):
+        return lm.layer_weights(params[name], i, turn=turn)
 
-    def attend(x, lat, kr, i):
-        p = part("attn", i)
+    def attend(x, lat, kr, i, pos0, pos, ok, slot=None, turn=None):
+        p = part("attn", i, slot if turn is None else turn)
         return mla.attention(x, p["norm"], p, cfg, lat, kr, i, pos0, pos, ok,
                              slot, scales=scales, whole=True)
 
-    a, lat, kr = attend(x, lat, kr, 2 * l)
-    dense0, dense1 = part("dense", 2 * l), part("dense", 2 * l + 1)
-    with jax.named_scope("mlp"):
-        h0 = rms_norm(a, dense0["norm"], cfg.norm_eps)
-        r, given = _expert_block(h0, part("moe", l), params["experts"], l,
-                                 cfg, given, ok)
-        b = a + _dense_ffn(h0, dense0, cfg)
-    c, lat, kr = attend(b, lat, kr, 2 * l + 1)
-    with jax.named_scope("mlp"):
-        h1 = rms_norm(c, dense1["norm"], cfg.norm_eps)
-        x = c + _dense_ffn(h1, dense1, cfg) + r
-    return x, lat, kr, given
+    def after_first(a, ok, given, turn=None, packed=False):
+        """a -> (b = a + the first dense FFN, r, given)."""
+        dense0 = part("dense", 2 * l, turn)
+        with jax.named_scope("mlp"):
+            h0 = rms_norm(a, dense0["norm"], cfg.norm_eps)
+            r, given = _expert_block(h0, part("moe", l, turn),
+                                     params["experts"], l, cfg, given, ok,
+                                     packed)
+            return a + _dense_ffn(h0, dense0, cfg), r, given
 
+    def after_second(c, r, turn=None):
+        dense1 = part("dense", 2 * l + 1, turn)
+        with jax.named_scope("mlp"):
+            h1 = rms_norm(c, dense1["norm"], cfg.norm_eps)
+            return c + _dense_ffn(h1, dense1, cfg) + r
 
-def _layer(l, params: Params, cfg: LongcatConfig, pos0, on, further,
-           prefilling, first, rest, lat, kr, counts):
-    """One double layer: every slot's first lane all slots at once, then the
-    further lanes of the slots that have any, a slot at a time
-    (`lm.each_slot`, which has why the weights are sliced inside the body
-    here); `given` goes from the one into the other."""
-    given = jnp.zeros((cfg.router_outputs,), jnp.int32)
-    first, lat, kr, given = _double_layer(
-        first, l, params, cfg, lat, kr, given, pos0, pos0[:, None],
-        on[:, None])
-    if rest is not None:
-        M = rest.shape[1]
+    first, lat, kr = attend(first, lat, kr, 2 * l, pos0, pos0[:, None],
+                            on[:, None])
+    if rest is None:
+        first, r, given = after_first(first, on[:, None], given)
+        first, lat, kr = attend(first, lat, kr, 2 * l + 1, pos0,
+                                pos0[:, None], on[:, None])
+        first = after_second(first, r)
+        return first, rest, lat, kr, counts + _expert_counts(given, cfg)
 
+    M = rest.shape[1]
+
+    def by_slot(i, g, rest, lat, kr):
         def slot(b, carry):
-            rest, lat, kr, given = carry
+            rest, lat, kr = carry
             xb, okb, at = lm.slot_lanes(b, rest, further, pos0 + 1)
-            xb, lat, kr, given = _double_layer(
-                xb, l, params, cfg, lat, kr, given, at,
-                at[:, None] + jnp.arange(M), okb, slot=b)
-            return lm.put_lanes(rest, xb, b), lat, kr, given
+            xb, lat, kr = attend(xb, lat, kr, i, at,
+                                 at[:, None] + jnp.arange(M), okb, slot=b)
+            return lm.put_lanes(rest, xb, b), lat, kr
 
-        rest, lat, kr, given = lm.each_slot(prefilling, slot,
-                                            (rest, lat, kr, given))
+        return lm.each_slot(lm.round_slots(rounds, g), slot, (rest, lat, kr))
+
+    buckets = lm.row_buckets(*rest.shape[:2])
+
+    def cut(stage, g):
+        """`stage` on the leading rows of its operands, a branch a bucket,
+        what it returns of rows padded back to the call's N."""
+        def to(rows):
+            def branch(*operands):
+                out = stage(*(x[:, :rows] if x.ndim > 1 else x
+                              for x in operands))
+                return tuple(jnp.pad(y, ((0, 0), (0, buckets[-1] - rows))
+                                     + ((0, 0),) * (y.ndim - 2))
+                             if y.ndim > 1 else y for y in out)
+            return branch
+
+        return lambda *operands: lax.switch(
+            lm.round_bucket(rounds, g, buckets),
+            [to(rows) for rows in buckets], *operands)
+
+    def one(g, carry):
+        first, rest, lat, kr, given = carry
+        rest, lat, kr = by_slot(2 * l, g, rest, lat, kr)
+        a, ok = lm.pack_lanes(first, on, rest, rounds, g)
+        b, r, given = cut(
+            lambda a, ok, given: after_first(a, ok, given, turn=g,
+                                             packed=True), g)(a, ok, given)
+        first, rest = lm.unpack_lanes(first, rest, further, b, rounds, g)
+        c, lat, kr = attend(first, lat, kr, 2 * l + 1, pos0, pos0[:, None],
+                            (on & (g == 0))[:, None], turn=g)
+        # the loop writes the leaves where the first lanes read them: its
+        # lanes wait for theirs (`lm.each_slot`: nothing else ties the two)
+        first, rest = lax.optimization_barrier(
+            (jnp.where(g == 0, c, first), rest))
+        rest, lat, kr = by_slot(2 * l + 1, g, rest, lat, kr)
+        c, _ = lm.pack_lanes(first, on, rest, rounds, g)
+        x, = cut(lambda c, r: (after_second(c, r, turn=g),), g)(c, r)
+        first, rest = lm.unpack_lanes(first, rest, further, x, rounds, g)
+        return first, rest, lat, kr, given
+
+    first, rest, lat, kr, given = lax.fori_loop(
+        0, rounds["count"], one, (first, rest, lat, kr, given))
     return first, rest, lat, kr, counts + _expert_counts(given, cfg)
 
 
@@ -482,10 +540,12 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     counts = jnp.zeros((len(COUNTS),), jnp.uint32)
 
     def layer(l, carry):
-        return _layer(l, params, cfg, pos0, on, further, prefilling, *carry)
+        return _layer(l, params, cfg, pos0, on, further, prefilling, rounds,
+                      *carry)
 
     with jax.named_scope("layers"):
         first, rest, lat, kr, counts = lax.fori_loop(

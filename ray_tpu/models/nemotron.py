@@ -52,7 +52,8 @@ slot's first lane all slots at once, which is `decode_step` whole (the
 recurrence through `ops/ssm_update.py`, attention through the kernel to each
 slot's position), and a chunk's further lanes a slot at a time and only for
 the slots that prefill (`lm.each_slot`: the SSD form, attention a block of
-positions at a time, the experts over the slot's own lanes).
+positions at a time). An expert layer mixes no sequence: a chunk step's
+valid lanes, first and further, are the rows of one call (`lm.all_lanes`).
 
 The weights exist only in the dtype the replica holds them; float32 are the
 norms' scales, the convolution, `dt_bias`, `A_log`, `D`, W_in's dt columns,
@@ -406,10 +407,12 @@ def _relu2(a):
 
 
 def _expert_block(x, p, experts_of_all_layers, j, cfg: NemotronConfig, given,
-                  ok):
+                  ok, packed: bool = False):
     """x [N,C,D] += the held experts' part of the routed sum, through the
     latent, + the shared expert, for expert layer j; `given` [E] += the
-    (lane, expert) pairs of the lanes that are `ok`, over all E.
+    (lane, expert) pairs of the lanes that are `ok`, over all E. `packed`
+    (the rows are `lm.pack_lanes`'): a row that is not `ok` is no lane's
+    and goes to no expert.
 
     The router scores all E experts over the normed input and chooses K. A
     pair whose expert is held goes to entry j E' + e - first_expert of the
@@ -430,6 +433,8 @@ def _expert_block(x, p, experts_of_all_layers, j, cfg: NemotronConfig, given,
             local = experts - cfg.first_expert
             entry = jnp.where((local >= 0) & (local < held),
                               j * held + local, stack)
+            if packed:
+                entry = jnp.where(ok.reshape(-1, 1), entry, stack)
         with jax.named_scope("moe_latent"):
             c = lm.dot(h, p["w_down"], cfg.dtype)
         routed = _moe._experts(
@@ -460,44 +465,50 @@ def _expert_counts(given, cfg: NemotronConfig):
 
 
 def _layer(kind: str, i, params: Params, cfg: NemotronConfig, pos0, on,
-           further, prefilling, first, rest, cache, counts):
-    """One layer of `kind`, entry i of its stack: every slot's first lane
-    all slots at once, then the further lanes of the slots that have any, a
-    slot at a time (`lm.each_slot`, which has why the weights are sliced
-    inside the body here)."""
+           further, prefilling, rounds, first, rest, cache, counts):
+    """One layer of `kind`, entry i of its stack. A layer that mixes a
+    sequence: every slot's first lane all slots at once, then the further
+    lanes of the slots that have any, a slot at a time (`lm.each_slot`,
+    which has why the weights are sliced inside the body here). An expert
+    layer mixes none: every valid lane of the step is a row of one call
+    (`lm.all_lanes`), the held experts read once."""
     stack = params[kind]
-    experts = params["experts"] if kind == "moe" else None
-    given = jnp.zeros((cfg.n_experts,), jnp.int32)
+    if kind == "moe":
+        given = jnp.zeros((cfg.n_experts,), jnp.int32)
+        if rest is None:
+            first, given = _expert_block(
+                first, lm.layer_weights(stack, i), params["experts"], i, cfg,
+                given, on[:, None])
+        else:
+            def block(x, ok, g, given):
+                return _expert_block(
+                    x, lm.layer_weights(stack, i, turn=g), params["experts"],
+                    i, cfg, given, ok, packed=True)
+
+            first, rest, given = lm.all_lanes(block, first, on, rest,
+                                              further, rounds, given)
+        return first, rest, cache, counts + _expert_counts(given, cfg)
     p = lm.layer_weights(stack, i)
     if kind == "mamba":
         first, cache = _mamba(first, p, cfg, cache, i, on)
-    elif kind == "attention":
-        first, cache = _attention(first, p, cfg, cache, i, pos0, on[:, None])
     else:
-        first, given = _expert_block(first, p, experts, i, cfg, given,
-                                     on[:, None])
+        first, cache = _attention(first, p, cfg, cache, i, pos0, on[:, None])
     if rest is not None:
-        if kind != "moe":
-            # the loop writes the leaves where the first lanes read them
-            # (`lm.each_slot`: nothing else ties the two here)
-            first, cache = lax.optimization_barrier((first, cache))
+        # the loop writes the leaves where the first lanes read them
+        # (`lm.each_slot`: nothing else ties the two here)
+        first, cache = lax.optimization_barrier((first, cache))
 
         def slot(b, carry):
-            rest, cache, given = carry
+            rest, cache = carry
             p = lm.layer_weights(stack, i, turn=b)
             xb, okb, at = lm.slot_lanes(b, rest, further, pos0 + 1)
             if kind == "mamba":
                 xb, cache = _mamba(xb, p, cfg, cache, i, okb, slot=b)
-            elif kind == "attention":
-                xb, cache = _attention(xb, p, cfg, cache, i, at, okb, slot=b)
             else:
-                xb, given = _expert_block(xb, p, experts, i, cfg, given, okb)
-            return lm.put_lanes(rest, xb, b), cache, given
+                xb, cache = _attention(xb, p, cfg, cache, i, at, okb, slot=b)
+            return lm.put_lanes(rest, xb, b), cache
 
-        rest, cache, given = lm.each_slot(prefilling, slot,
-                                          (rest, cache, given))
-    if kind == "moe":
-        counts = counts + _expert_counts(given, cfg)
+        rest, cache = lm.each_slot(prefilling, slot, (rest, cache))
     return first, rest, cache, counts
 
 
@@ -525,8 +536,9 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
              cfg: NemotronConfig, program: int):
     """Both step programs (`kimi._forward`'s shape): a layer computes a lane
     only where the plan put a token, every slot's first lane all slots at
-    once and the lanes after it a slot at a time, C of them a slot with the
-    last one padding for the grouped matmul's tiles.
+    once and the lanes after it a slot at a time where the layer mixes a
+    sequence, C of them a slot with the last one padding; an expert layer
+    takes every valid lane of the step in one call.
 
     The pattern is walked as runs of one kind: one loop over the runs, whose
     body holds one loop a kind, and a kind's loop turns as many times as the
@@ -544,6 +556,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(jnp.float32)              # [B, C, D]
     first, on, rest, further, prefilling = lm.split_lanes(x, ok, pad=True)
+    rounds = lm.lane_rounds(further, prefilling)
     counts = jnp.zeros((len(COUNTS),), jnp.uint32)
     leaves = {k: v for k, v in cache.items() if k != "counts"}
     kinds = cfg.layer_types
@@ -561,7 +574,7 @@ def _forward(params: Params, cache, tokens, pos0, length, active,
 
     def layer(kind, l, carry):
         return _layer(kind, entry[l], params, cfg, pos0, on, further,
-                      prefilling, *carry)
+                      prefilling, rounds, *carry)
 
     def run(r, carry):
         start = first_layer[r]

@@ -238,13 +238,15 @@ class LLMEngine:
     in a step beside the decode lanes. What a larger one costs is the
     family's chunk program's to say: one that runs all B x C lanes through
     its layers costs the same whatever the plan handed out; one that
-    computes a slot's further lanes only where it has any, a slot at a
-    time, grows with the slots that prefill (`chunk_prefilling_slots` in
-    `engine_stats()`), and past some number of them costs more than all
-    lanes at once would: the latent-attention family at Kanana's widths,
-    32 slots and chunks of 128, takes 15.7 ms + 15.9 ms a slot that
-    prefills where all lanes took 310, so 19 slots at once (`benchmarks/
-    kanana_chunk_lanes.py` has the table; PERF.md §7).
+    computes a slot's further lanes only where it has any grows with the
+    slots that prefill (`chunk_prefilling_slots` in `engine_stats()`): a
+    slot's attention a slot, and a pass of the layer's MLP or experts for
+    every slot whose lanes no longer fit the first lanes' call of
+    `max(2 B, B + C)` rows (`lm.lane_rounds`; `chunk_steps_one_dispatch`
+    counts the steps that fitted, every step of the default budget with
+    all slots busy). `benchmarks/kanana_chunk_lanes.py` has the table for
+    the latent-attention family at Kanana's widths, 32 slots and chunks of
+    128, beside the all-lanes form's 310 ms (PERF.md §7).
 
     The next token of every slot is chosen on the device
     (`serve/sampling.select_tokens`) and stays there as the next step's
@@ -418,9 +420,14 @@ class LLMEngine:
         # chunk must fit the serving window (prefill_chunk requires C <= T)
         self.prefill_chunk_size = max(1, min(prefill_chunk_size,
                                              self.max_seq_len - 1))
-        self.max_num_batched_tokens = (
-            max_num_batched_tokens if max_num_batched_tokens
-            else max(2 * max_batch, max_batch + self.prefill_chunk_size))
+        from ray_tpu.models import lm
+
+        # the default budget is the rows of the call a chunk program's
+        # token-wise halves take a step's lanes in
+        self._lanes_a_dispatch = lm.lanes_a_dispatch(
+            max_batch, self.prefill_chunk_size)
+        self.max_num_batched_tokens = (max_num_batched_tokens
+                                       or self._lanes_a_dispatch)
 
         from ray_tpu.serve.sampling import select_tokens
 
@@ -557,6 +564,11 @@ class LLMEngine:
         # one that computes every lane chunk_steps x B x C)
         self.chunk_tokens = 0
         self.chunk_prefilling_slots = 0
+        # the chunk steps whose further lanes rode the first lanes' call
+        # through a layer's token-wise half, one round (`lm.lane_rounds`),
+        # and the further lanes those steps carried
+        self.chunk_steps_one_dispatch = 0
+        self.chunk_lanes_packed = 0
         self.tokens_prefilled = 0      # prompt tokens processed
         # positions the steps' lanes attended over: each lane's last
         # position in its step, summed (what a cache of rows is read for)
@@ -1063,6 +1075,10 @@ class LLMEngine:
             active = lengths > 0
             self.chunk_tokens += int(lengths.sum())
             self.chunk_prefilling_slots += int((lengths > 1).sum())
+            further = int(np.maximum(lengths - 1, 0).sum())
+            if B + further <= self._lanes_a_dispatch:
+                self.chunk_steps_one_dispatch += 1
+                self.chunk_lanes_packed += further
         else:
             lengths = active = decoding
         lanes, prompts, last_prompts, snapshots = [], [], [], []
@@ -1274,6 +1290,8 @@ class LLMEngine:
                 "chunk_steps": self.chunk_steps,
                 "chunk_tokens": self.chunk_tokens,
                 "chunk_prefilling_slots": self.chunk_prefilling_slots,
+                "chunk_steps_one_dispatch": self.chunk_steps_one_dispatch,
+                "chunk_lanes_packed": self.chunk_lanes_packed,
                 "tokens_prefilled": self.tokens_prefilled,
                 "positions_attended": self.positions_attended,
                 "prefix_imports": self.prefix_imports,

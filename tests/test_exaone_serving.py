@@ -25,6 +25,8 @@ if CHIP_DIR not in sys.path:
 
 from families import exaone as family  # noqa: E402
 
+from ray_tpu.cluster_utils import (LANES_OF_A_STEP,  # noqa: E402
+                                   chunk_step_against_decode)
 from ray_tpu.models import (exaone, kimi, lm, moe, nemotron,  # noqa: E402
                             serving_family)
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
@@ -418,6 +420,15 @@ def test_a_prefix_whose_snapshot_was_evicted_is_not_a_hit():
     assert eng.kv.match_prefix(prompts[0][:-1])[0] == 0
     assert eng.kv.rows_without_snapshot_tokens == 32
     assert eng.kv.match_prefix(prompts[2][:-1])[0] == 32
+
+
+@pytest.mark.parametrize("case", LANES_OF_A_STEP)
+def test_a_chunk_step_is_its_tokens_a_token_at_a_time(case):
+    """The chunk program, whose MLPs take every valid lane of the step in
+    one call (`lm.all_lanes`), against `decode_step`: whoever prefills, and
+    when the lanes are more than a call's rows."""
+    chunk_step_against_decode(exaone, tiny(**F32), case,
+                              FLOAT32_LOGIT_TOLERANCE, 1e-6)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

@@ -23,6 +23,8 @@ if CHIP_DIR not in sys.path:
 
 from families import keye as family  # noqa: E402
 
+from ray_tpu.cluster_utils import (LANES_OF_A_STEP,  # noqa: E402
+                                   chunk_step_against_decode)
 from ray_tpu.models import deepseek, keye, llama, serving_family  # noqa: E402
 from ray_tpu.ops import dsa, slot_rows  # noqa: E402
 from ray_tpu.serve.kv_cache import PagedKVCache  # noqa: E402
@@ -369,6 +371,15 @@ def test_the_programs_take_three_position_streams(streams):
 # ------------------------------------------------------------------ the cache
 
 LEAVES = ("k", "v", "ik")
+
+
+@pytest.mark.parametrize("case", LANES_OF_A_STEP)
+def test_a_chunk_step_is_its_tokens_a_token_at_a_time(case):
+    """The chunk program, whose MLPs take every valid lane of the step in
+    one call (`lm.all_lanes`), against `decode_step`: whoever prefills, and
+    when the lanes are more than a call's rows."""
+    chunk_step_against_decode(keye, tiny(**F32), case,
+                              FLOAT32_LOGIT_TOLERANCE, 1e-6)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

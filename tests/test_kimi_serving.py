@@ -25,6 +25,8 @@ if CHIP_DIR not in sys.path:
 
 from families import kimi as family  # noqa: E402
 
+from ray_tpu.cluster_utils import (LANES_OF_A_STEP,  # noqa: E402
+                                   chunk_step_against_decode)
 from ray_tpu.models import deepseek, kimi, serving_family  # noqa: E402
 from ray_tpu.ops import kda_update as ku  # noqa: E402
 from ray_tpu.ops import slot_rows  # noqa: E402
@@ -225,6 +227,15 @@ def test_the_chunked_form_is_the_recurrence_under_strong_decay():
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(o, jnp.stack(outs), atol=2e-5)
     np.testing.assert_allclose(s, state[0, 0], atol=2e-5)
+
+
+@pytest.mark.parametrize("case", LANES_OF_A_STEP)
+def test_a_chunk_step_is_its_tokens_a_token_at_a_time(case):
+    """The chunk program, whose MLPs take every valid lane of the step in
+    one call (`lm.all_lanes`), against `decode_step`: whoever prefills, and
+    when the lanes are more than a call's rows."""
+    chunk_step_against_decode(kimi, tiny(**F32), case,
+                              FLOAT32_LOGIT_TOLERANCE, 1e-6)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

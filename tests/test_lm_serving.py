@@ -106,6 +106,80 @@ def test_the_loop_visits_the_slots_that_have_lanes_once_each_in_order(has):
         assert bits(out) == bits(rest)
 
 
+# lengths of a chunk of 8 in 5 slots (the call's rows are 13: 5 first lanes
+# and 8 further): (lengths, active, the slots of each round)
+STEPS = {
+    "no-further-lane": ([1, 1, 0, 1, 1], [1] * 5, [[]]),
+    "one-slot": ([1, 6, 1, 0, 1], [1] * 5, [[1]]),
+    "three-slots-an-inactive-one-between": (
+        [3, 8, 4, 1, 2], [1, 0, 1, 1, 1], [[0, 2, 4]]),
+    "eight-further-lanes-fill-the-call": (
+        [5, 1, 5, 1, 1], [1] * 5, [[0, 2]]),
+    "nine-are-one-too-many": ([5, 1, 6, 1, 1], [1] * 5, [[0], [2]]),
+    "every-slot-a-whole-chunk": (
+        [8] * 5, [1] * 5, [[0], [1], [2], [3], [4]]),
+    "two-together-then-each-alone": (
+        [4, 0, 5, 8, 2], [1] * 5, [[0, 2], [3], [4]]),
+}
+
+
+@pytest.mark.parametrize("step", STEPS)
+def test_packing_a_steps_lanes_and_putting_them_back_are_inverse(step):
+    """`pack_lanes` a round: the first lanes, then the valid further lanes
+    of the round's slots in slot order, each exactly once over the rounds,
+    `ok` on those rows alone. `unpack_lanes` of the rows times two: every
+    valid lane doubled once, no other lane of `rest` touched, the first
+    lanes taken in round 0 only. `all_lanes` is that loop."""
+    lengths, active, want_rounds = STEPS[step]
+    B, C, D = 5, 8, 3
+    N = lm.lanes_a_dispatch(B, C)
+    assert N == 13
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((B, C, D)), jnp.float32)
+    ok = jnp.asarray((np.arange(C)[None, :] < np.asarray(lengths)[:, None])
+                     & np.asarray(active, bool)[:, None])
+
+    def program(x, ok):
+        first, on, rest, further, prefilling = lm.split_lanes(x, ok, True)
+        rounds = lm.lane_rounds(further, prefilling)
+        packed = [lm.pack_lanes(first, on, rest, rounds, jnp.int32(g))
+                  for g in range(B + 1)]
+
+        def block(rows, row_ok, g, seen):
+            assert rows.shape == (1, N, D) and row_ok.shape == (1, N)
+            return rows * 2, seen + row_ok.sum()
+
+        doubled = lm.all_lanes(block, first, on, rest, further, rounds,
+                               jnp.int32(0))
+        return (first, on, rest, further, rounds["count"], packed, doubled,
+                [lm.round_slots(rounds, jnp.int32(g)) for g in range(B + 1)])
+
+    (first, on, rest, further, count, packed, (first2, rest2, seen),
+     slots) = jax.jit(program)(x, ok)
+    first, rest, further, on = (np.asarray(a) for a in (first, rest, further,
+                                                        on))
+    assert int(count) == len(want_rounds)
+    for g, want in enumerate(want_rounds):
+        indices, lo, hi = slots[g]
+        assert list(np.asarray(indices)[int(lo):int(hi)]) == want
+        rows, row_ok = (np.asarray(a)[0] for a in packed[g])
+        lanes_ = [rest[b, m] for b in want for m in range(C)
+                  if further[b, m]]
+        assert row_ok[B:].sum() == len(lanes_) <= N - B
+        assert row_ok[B:B + len(lanes_)].all()
+        np.testing.assert_array_equal(row_ok[:B], on & (g == 0))
+        np.testing.assert_array_equal(rows[:B], first[:, 0])
+        if lanes_:
+            np.testing.assert_array_equal(rows[B:B + len(lanes_)],
+                                          np.stack(lanes_))
+    assert sorted(b for r in want_rounds for b in r) == \
+        list(np.flatnonzero(further.any(axis=1)))
+    assert int(seen) == on.sum() + further.sum()
+    np.testing.assert_array_equal(first2, first * 2)
+    np.testing.assert_array_equal(
+        rest2, np.where(further[:, :, None], rest * 2, rest))
+
+
 # ----------------------------------------------------- the two-piece product
 
 def _wide(shape, seed):

@@ -24,6 +24,8 @@ if CHIP_DIR not in sys.path:
 
 from families import longcat as family  # noqa: E402
 
+from ray_tpu.cluster_utils import (LANES_OF_A_STEP,  # noqa: E402
+                                   chunk_step_against_decode)
 from ray_tpu.models import (deepseek, kimi, longcat, mla, moe,  # noqa: E402
                             serving_family)
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
@@ -484,6 +486,16 @@ def test_a_pool_hit_gives_back_both_sublayers_rows_of_every_layer():
     _, by_hit = through_the_programs(eng, PROMPT, 6, slot=2, start=n_hit,
                                      forced=chosen)
     np.testing.assert_allclose(by_hit, cold, atol=FLOAT32_LOGIT_TOLERANCE)
+
+
+@pytest.mark.parametrize("case", LANES_OF_A_STEP)
+def test_a_chunk_step_is_its_tokens_a_token_at_a_time(case):
+    """The chunk program, whose routers, experts and dense FFNs take every
+    valid lane of the step in one call (`lm.pack_lanes`), against
+    `decode_step`: whoever prefills, and when the lanes are more than a
+    call's rows (r then lives a round)."""
+    chunk_step_against_decode(longcat, tiny(**F32), case,
+                              FLOAT32_LOGIT_TOLERANCE, 1e-6)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

@@ -24,6 +24,8 @@ if CHIP_DIR not in sys.path:
 
 from families import solar as family  # noqa: E402
 
+from ray_tpu.cluster_utils import (LANES_OF_A_STEP,  # noqa: E402
+                                   chunk_step_against_decode)
 from ray_tpu.models import kimi, lm, moe, serving_family, solar  # noqa: E402
 from ray_tpu.ops import kda_update as ku  # noqa: E402
 from ray_tpu.serve.llm import LLMEngine, OpenAIServer  # noqa: E402
@@ -380,6 +382,15 @@ def test_a_pool_hit_gives_the_logits_of_a_cold_prefill():
     _, by_hit = through_the_programs(eng, PROMPT, 6, slot=2, start=n_hit,
                                      forced=chosen)
     np.testing.assert_allclose(by_hit, cold, atol=FLOAT32_LOGIT_TOLERANCE)
+
+
+@pytest.mark.parametrize("case", LANES_OF_A_STEP)
+def test_a_chunk_step_is_its_tokens_a_token_at_a_time(case):
+    """The chunk program, whose MLPs take every valid lane of the step in
+    one call (`lm.all_lanes`), against `decode_step`: whoever prefills, and
+    when the lanes are more than a call's rows."""
+    chunk_step_against_decode(solar, tiny(**F32), case,
+                              FLOAT32_LOGIT_TOLERANCE, 1e-6)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
