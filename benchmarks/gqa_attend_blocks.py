@@ -6,7 +6,8 @@ K-EXAONE's cell's two shapes, the global layers' rows and the sliding
 layers' rings.
 
     chiprun -- python benchmarks/gqa_attend_blocks.py [--calls 100] \
-        [--shapes solar,solar-check,kexaone,kexaone-ring]
+        [--shapes solar,solar-check,kexaone,kexaone-ring] \
+        [--shapes gpt2,gpt2-chat,granite --blocks 128,256,512,1024]
 
 The cell: 1 softmax layer x 40 slots x 8 key-value heads x 25,600 positions
 of 128 lanes, a float32 q of 8 queries a head (two bf16 pieces), the slots
@@ -50,6 +51,34 @@ moving 512 KB; the plain form is `lm.gqa_attend_band` over the whole layer;
 the block lengths do not apply and one kernel row is timed). Its table is
 PERF.md section 6, PR 59.
 
+Leaves with the positions on the lanes (PR 61: `_lanes_body`, whose block
+is `gqa_attend.BLOCK_LAST`): `gpt2`, GPT-2 XL's serving cells' cache, 48
+layers x 8 slots x 25 heads x 64 x 1,024 positions, one bf16 query a head
+with the step's own row handed over, the slots live at 16-320 (the decode
+cell's positions); `gpt2-chat`, the same with 1 of 8 slots live at 300-1,000
+(the chat cell's decode steps); the plain form is `models/gpt2.py`'s own
+lines (`_decode_attend` where no kernel runs). `granite`, a record for
+ROADMAP S19a: 4 layers x 48 slots x 8 heads x 64 x 8,192 positions, four
+bf16 queries a head, live at 3,100-7,200, against `lm.gqa_attend`. Measured
+on a v5e (PR 61, 480 calls in one program; us a call, the share of the
+attended rows' bytes at the HBM's peak, positions read over attended):
+
+    block   gpt2, 8 live        gpt2-chat, 1 live    granite, 48 live
+    plain   79.2   9.4%  8.57   79.0   8.9%  9.14    1,113  53.8%  1.64
+    128     33.6  22.2%  1.61   22.8  30.6%  1.00    1,374  43.6%  1.01
+    256     39.5  18.9%  2.41   20.4  34.3%  1.14      937  63.9%  1.02
+    512     51.0  14.7%  4.28   17.7  39.6%  1.14      771  77.6%  1.06
+    1,024   76.2   9.8%  8.57   17.2  40.7%  1.14      802  74.6%  1.12
+    2,048                                              845  70.9%  1.20
+
+A grid step that works moves its rows at 85-95% of the HBM's pace; what
+keeps GPT-2's calls at a fifth to two fifths of their roofline is a call's
+own ~7 us, the grid steps that do nothing (0.14 us each) and the half block
+past a position, as much again as the ~170 positions before it. The values
+lie within 1.5e-3 of the plain form's (r.m.s. 0.2: one bf16 piece, the
+probabilities rounded before the division where the plain form rounds
+after). `BLOCK_LAST` is 128, GPT-2's; granite's adoption brings its own.
+
 Writes `chiprun_out/gqa_attend_blocks.json`. One process, which holds the
 chip.
 """
@@ -68,14 +97,40 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks", "chip")]
 
-G, R, D = 8, 8, 128
-SCALE = 1.0 / math.sqrt(D)
-# name: (layers, slots, T, live slots, where the live slots stand, a ring)
-SHAPES = {"solar": (1, 40, 25600, 40, (16400, 25200), False),
-          "solar-check": (1, 40, 25600, 4, (16400, 25200), False),
-          "kexaone": (2, 64, 10240, 64, (6200, 10000), False),
-          "kexaone-ring": (6, 64, 128, 64, (6200, 10000), True)}
+# rows by head: 8 heads of 128 lanes, a float32 q of 8 queries a head
+ROWS = dict(G=8, R=8, D=128, q="float32", last=False, own=False)
+# GPT-2 XL's cache as the chip holds it: 25 heads of 64 on the sublanes, one
+# bf16 query a head, the step's own row beside the leaves
+GPT2 = dict(G=25, R=1, D=64, q="bfloat16", last=True, own=True)
+# name: (layers, slots, T, live slots, where the live slots stand, a ring,
+# the heads: G key-value heads of D lanes, R queries each, q's dtype, the
+# positions on the lanes, the step's own row handed over)
+SHAPES = {"solar": (1, 40, 25600, 40, (16400, 25200), False, ROWS),
+          "solar-check": (1, 40, 25600, 4, (16400, 25200), False, ROWS),
+          "kexaone": (2, 64, 10240, 64, (6200, 10000), False, ROWS),
+          "kexaone-ring": (6, 64, 128, 64, (6200, 10000), True, ROWS),
+          "gpt2": (48, 8, 1024, 8, (16, 320), False, GPT2),
+          "gpt2-chat": (48, 8, 1024, 1, (300, 1000), False, GPT2),
+          "granite": (4, 48, 8192, 48, (3100, 7200), False, dict(
+              G=8, R=4, D=64, q="bfloat16", last=True, own=False))}
 BLOCKS = (512, 1024, 2048, 2560)
+
+
+def gpt2_plain(q, ck, cv, layer, pos, live, scale, own):
+    """`models/gpt2.py`'s plain lines, as the decode step runs them where
+    no kernel does, over the leaves as the model holds them."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt2
+
+    del scale                                   # the model's: 1 / sqrt(Dh)
+    cache = {"k": jnp.swapaxes(ck, 3, 4), "v": jnp.swapaxes(cv, 3, 4)}
+    kernels, gpt2._rows_kernels = gpt2._rows_kernels, lambda *a: False
+    try:
+        return gpt2._decode_attend(q[:, :, 0], *own, cache, layer, pos,
+                                   live)[:, :, None]
+    finally:
+        gpt2._rows_kernels = kernels
 
 
 def main() -> int:
@@ -95,20 +150,25 @@ def main() -> int:
 
     op = importlib.import_module("ray_tpu.ops.gqa_attend")
     out = {"device": jax.devices()[0].device_kind,
-           "default_block": slot_rows.BLOCK,
-           "shape": {"kv_heads": G, "queries": R, "lanes": D}}
+           "default_block": slot_rows.BLOCK, "block_last": op.BLOCK_LAST}
     peak = spec.peaks()[out["device"]]["hbm_bytes_per_s"]
-    ks = jax.random.split(jax.random.key(0), 3)
+    ks = jax.random.split(jax.random.key(0), 5)
     made = None
     for name in args.shapes.split(","):
-        L, B, T, n_live, positions, ring = SHAPES[name]
-        if made != (L, B, T):
-            q = jax.random.normal(ks[0], (B, G, R, D), jnp.float32)
-            ck = jax.random.normal(ks[1], (L, B, G, T, D), jnp.bfloat16)
-            cv = jax.random.normal(ks[2], (L, B, G, T, D), jnp.bfloat16)
-            pos = jnp.asarray(np.random.default_rng(0).integers(
-                *positions, size=B), jnp.int32)
-            made = (L, B, T)
+        L, B, T, n_live, positions, ring, heads = SHAPES[name]
+        G, R, D, last = (heads[n] for n in ("G", "R", "D", "last"))
+        scale = 1.0 / math.sqrt(D)
+        if made != (L, B, T, G, D, last):
+            rows = (L, B, G, D, T) if last else (L, B, G, T, D)
+            q = jax.random.normal(ks[0], (B, G, R, D), jnp.float32).astype(
+                heads["q"])
+            ck = jax.random.normal(ks[1], rows, jnp.bfloat16)
+            cv = jax.random.normal(ks[2], rows, jnp.bfloat16)
+            own = tuple(jax.random.normal(k, (B, G, D), jnp.bfloat16)
+                        for k in ks[3:]) if heads["own"] else ()
+            made = (L, B, T, G, D, last)
+        pos = jnp.asarray(np.random.default_rng(0).integers(
+            *positions, size=B), jnp.int32)
         live = jnp.asarray(np.arange(B) % (B // n_live) == 0)
         # a ring's rows: the last T positions
         attended = int(jnp.sum(jnp.where(live, jnp.minimum(pos + 1, T)
@@ -118,24 +178,28 @@ def main() -> int:
         forms = [("plain", None)] + [(b, int(b)) for b in (
             [T] if ring else args.blocks.split(","))]
         for label, block in forms:
-            if block is None:
+            if block is None and own:
+                fn = functools.partial(gpt2_plain, own=own)
+            elif block is None:
                 fn = functools.partial(op.gqa_attend, kernel=False, ring=ring)
             elif ring:
                 fn = functools.partial(op.gqa_attend, ring=True)
             else:
-                def fn(q, ck, cv, layer, pos, live, scale, block=block):
+                def fn(q, ck, cv, layer, pos, live, scale, block=block,
+                       last=last, own=own):
                     return slot_rows.attend(
-                        op.rows_kernel(q, ck, cv, scale), layer, pos, live,
-                        block=block)
+                        op.rows_kernel(q, ck, cv, scale, last=last, own=own),
+                        layer, pos, live, block=block)
 
             # the calls are one program's loop, as the layers' loop is, the
             # leaves its arguments (`mla_attend_blocks.py` has why); a call
             # takes the one before it into its q, or the compiler would
             # lift the one layer's call out of the loop
-            def calls(ck, cv, n, fn=fn, q=q, pos=pos, live=live, L=L, B=B):
+            def calls(ck, cv, n, fn=fn, q=q, pos=pos, live=live, L=L,
+                      scale=scale):
                 return lax.fori_loop(0, n, lambda i, y: fn(
-                    q + 1e-6 * y, ck, cv, i % L, pos, live, SCALE),
-                    jnp.zeros((B, G, R, D), jnp.float32))
+                    (q + 1e-6 * y).astype(q.dtype), ck, cv, i % L, pos, live,
+                    scale), jnp.zeros(q.shape, jnp.float32))
 
             step = functools.partial(jax.jit(calls), ck, cv)
             try:
@@ -161,7 +225,7 @@ def main() -> int:
                 "plain_rms": float(np.sqrt(np.mean(want * want)))}
             print(name, label, json.dumps(rows[label]), flush=True)
         out[name] = {"layers": L, "slots": B, "T": T, "live": n_live,
-                     "attended_positions": attended,
+                     "heads": heads, "attended_positions": attended,
                      "least_ms": least * 1e3, "forms": rows}
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "gqa_attend_blocks.json"),

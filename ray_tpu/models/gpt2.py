@@ -442,40 +442,121 @@ def _cache_write(c, l, val, pos0, ok):
     return c
 
 
+def _rows_kernels(T: int, Dh: int, interpret: bool = False) -> bool:
+    """Whether the decode step's Pallas kernels take this cache's leaves:
+    where a kernel runs (`ops.slot_state.use_kernel`: the TPU), the head is
+    narrower than a tile's 128 lanes and T is whole tiles. `[.., Dh, T]` in
+    its default layout is then byte for byte how the chip holds
+    `[.., T, Dh]` (`_WRITE_WINDOW`): the axes swapped are the leaf's own
+    bytes, `ops/rows_write.py`'s and `ops/gqa_attend.py`'s leaf with the
+    positions on the lanes."""
+    from ray_tpu.ops.rows_write import TILE             # `lm.dot` has why
+    from ray_tpu.ops.slot_state import use_kernel
+
+    return use_kernel(None, interpret) and Dh < TILE and T % TILE == 0
+
+
+def _a_shard_each(fn, *specs):
+    """`fn` as every shard of a mesh that shards the heads over `tp` runs it
+    on its own heads (`specs`: the arguments', then the result's): the TPU's
+    compiler partitions no Pallas call."""
+    from ray_tpu.parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.shape.get("tp", 1) == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs[:-1],
+                         out_specs=specs[-1], check_vma=False)
+
+
+def rows_read_block(cache, interpret: bool = False) -> int:
+    """The positions of a slot's rows that a decode step's attention reads
+    at a time: the kernel's block where `_decode_attend` goes through it
+    (a live slot's rows to its position rounded up to one), all T where
+    the plain lines run. What `serve/llm.py` counts `positions_read` by."""
+    T, Dh = cache["k"].shape[3:]
+    if not _rows_kernels(T, Dh, interpret):
+        return T
+    from ray_tpu.ops.gqa_attend import block_last
+
+    return block_last(T)
+
+
 def _decode_write(c, rows, pos, on, interpret: bool = False):
     """The cache c [L,B,H,T,Dh] takes every layer's new row, rows
     [L,B,H,Dh], at position pos[b] of every slot that is `on` [B]; nothing
     else changes: `decode_step`'s write, after its loop.
 
-    Where a Pallas kernel runs (`ops.slot_state.use_kernel`: the TPU), the
-    head is narrower than a tile's 128 lanes and T is whole tiles, through
-    `ops/rows_write.py`: `[.., Dh, T]` in its default layout is byte for
-    byte how the chip holds `[.., T, Dh]` (`_WRITE_WINDOW`), so the axes
-    swapped are the leaf's own bytes, and one call a leaf reads a tile
-    `[H, Dh, 128]` a layer and slot, blends the row's lane and writes it
-    where it read it. Everywhere else `_cache_write`'s windows. Both leave
-    the same bits. Under a mesh that shards the heads over `tp` every shard
-    writes its own heads: the TPU's compiler partitions no Pallas call."""
-    from ray_tpu.ops.rows_write import TILE, rows_write  # `lm.dot` has why
-    from ray_tpu.ops.slot_state import use_kernel
-    from ray_tpu.parallel.mesh import current_mesh
-
+    Where the kernels take the leaf (`_rows_kernels`), through
+    `ops/rows_write.py`: one call a leaf reads a tile `[H, Dh, 128]` a
+    layer and slot, blends the row's lane and writes it where it read it.
+    Everywhere else `_cache_write`'s windows. Both leave the same bits.
+    Under a mesh that shards the heads over `tp` every shard writes its own
+    heads."""
     T, Dh = c.shape[3:]
-    if not (use_kernel(None, interpret) and Dh < TILE and T % TILE == 0):
+    if not _rows_kernels(T, Dh, interpret):
         return _cache_write(c, None, rows[:, :, :, None], pos, on[:, None])
+    from ray_tpu.ops.rows_write import rows_write
 
     def write(c, rows, pos, on):
         view = rows_write(jnp.swapaxes(c, 3, 4), None, rows, pos, on,
                           interpret=interpret)
         return jnp.swapaxes(view, 3, 4)
 
-    mesh = current_mesh()
-    if mesh is not None and mesh.shape.get("tp", 1) > 1:
-        heads, whole = jax.P(None, None, "tp"), jax.P()
-        write = jax.shard_map(write, mesh=mesh, out_specs=heads,
-                              in_specs=(heads, heads, whole, whole),
-                              check_vma=False)
-    return write(c, rows, pos, on)
+    heads, whole = jax.P(None, None, "tp"), jax.P()
+    return _a_shard_each(write, heads, heads, whole, whole, heads)(
+        c, rows, pos, on)
+
+
+def _decode_attend(q, k, v, cache, l, pos, on, interpret: bool = False):
+    """A decode step's one token a slot, q [B,H,Dh] with its own new row k,
+    v [B,H,Dh], against layer l of the cache as it was before the step
+    -> [B,H,Dh] float32: the rows before pos[b] and the own row at pos[b];
+    what the cache holds at pos[b] and beyond never reaches the result.
+
+    Where the kernels take the leaves (`_rows_kernels`), through
+    `ops/gqa_attend.py` on the leaves' own bytes viewed [.., Dh, T]: a slot
+    that is `on` reads its rows a block of positions at a time and only as
+    far as its position, one that is not reads nothing (and its values are
+    garbage, as its logits are). Everywhere else the plain lines, which
+    read all T positions of every slot and are what the kernel is tested
+    against. Under a mesh that shards the heads over `tp` every shard
+    attends with its own heads."""
+    T, Dh = cache["k"].shape[3:]
+    if _rows_kernels(T, Dh, interpret):
+        from ray_tpu.ops.gqa_attend import gqa_attend
+
+        def attend(q, k, v, ck, cv, l, pos, on):
+            return gqa_attend(
+                q[:, :, None], jnp.swapaxes(ck, 3, 4), jnp.swapaxes(cv, 3, 4),
+                l, pos, on, 1.0 / math.sqrt(Dh), own=(k, v),
+                interpret=interpret)[:, :, 0]
+
+        row, heads, whole = jax.P(None, "tp"), jax.P(None, None, "tp"), \
+            jax.P()
+        return _a_shard_each(attend, row, row, row, heads, heads, whole,
+                             whole, whole, row)(
+            q, k, v, cache["k"], cache["v"], l, pos, on)
+    t_idx = jnp.arange(T)[None, None, :]
+    own = t_idx == pos[:, None, None]                         # [B, 1, T]
+    before = t_idx < pos[:, None, None]
+    scores = jnp.einsum("bhd,bhtd->bht", q, cache["k"][l],
+                        preferred_element_type=jnp.float32)
+    # the new row's own score where the row will lie: the same
+    # [B,H,T] columns as if it had been written first
+    scores = jnp.where(own, jnp.einsum(
+        "bhd,bhd->bh", q, k,
+        preferred_element_type=jnp.float32)[:, :, None], scores)
+    scores = scores / math.sqrt(Dh)
+    scores = jnp.where(before | own, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    # the cache's values before pos, the new row's at pos: summed
+    # in float32 and rounded once, as one product over T would be
+    attn = jnp.einsum("bht,bhtd->bhd", jnp.where(before, probs, 0),
+                      cache["v"][l], preferred_element_type=jnp.float32)
+    p_own = jnp.sum(jnp.where(own, probs, 0), axis=-1,
+                    dtype=jnp.float32)                            # [B, H]
+    return attn + p_own[:, :, None] * v.astype(jnp.float32)
 
 
 def _cached_layers(layer, x, params: Params, cache):
@@ -515,9 +596,10 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     The loop over the layers carries the hidden state alone and only reads
     the cache: a layer attends to its own [B,H,T,Dh] slice as it was before
     this step, for the positions before pos[b], and to its new row directly
-    (the row's score takes column pos[b] of the scores; what the cache holds
-    at pos[b] and beyond never reaches the result). The new rows leave the
-    loop stacked, [L,B,H,Dh] a leaf, and go into the cache once, after it
+    (`_decode_attend`: on the TPU a Pallas call a layer that reads an active
+    slot's rows only as far as its position, elsewhere plain XLA over all T
+    positions of every slot). The new rows leave the loop stacked,
+    [L,B,H,Dh] a leaf, and go into the cache once, after it
     (`_decode_write`): on the TPU one Pallas call a leaf that reads and
     writes a slot's tile of 128 positions a layer in place, elsewhere one
     window all the layers deep a slot a leaf. The caller must donate `cache`
@@ -525,15 +607,12 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
     """
     B = tokens.shape[0]
     H, Dh = cfg.n_head, cfg.head_dim
-    L, _, _, T, _ = cache["k"].shape
+    L = cache["k"].shape[0]
     wte = params["wte"]
     with jax.named_scope("embed"):
         x = wte[tokens] + params["wpe"][
             jnp.clip(pos, 0, cfg.max_seq_len - 1)]
         x = x.astype(cfg.dtype)                               # [B, D]
-    t_idx = jnp.arange(T)[None, None, :]
-    own = t_idx == pos[:, None, None]                         # [B, 1, T]
-    before = t_idx < pos[:, None, None]
 
     def layer(x, scanned):
         l, bp = scanned
@@ -542,24 +621,7 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos: jax.Array,
             qkv = h @ lm.weight(bp["attn"]["wqkv"], cfg.dtype) + \
                 lm.weight(bp["attn"]["bqkv"], cfg.dtype)
             q, k, v = (a.reshape(B, H, Dh) for a in jnp.split(qkv, 3, -1))
-            scores = jnp.einsum("bhd,bhtd->bht", q, cache["k"][l],
-                                preferred_element_type=jnp.float32)
-            # the new row's own score where the row will lie: the same
-            # [B,H,T] columns as if it had been written first
-            scores = jnp.where(own, jnp.einsum(
-                "bhd,bhd->bh", q, k,
-                preferred_element_type=jnp.float32)[:, :, None], scores)
-            scores = scores / math.sqrt(Dh)
-            scores = jnp.where(before | own, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            # the cache's values before pos, the new row's at pos: summed
-            # in float32 and rounded once, as one product over T would be
-            attn = jnp.einsum("bht,bhtd->bhd", jnp.where(before, probs, 0),
-                              cache["v"][l],
-                              preferred_element_type=jnp.float32)
-            p_own = jnp.sum(jnp.where(own, probs, 0), axis=-1,
-                            dtype=jnp.float32)                    # [B, H]
-            attn = attn + p_own[:, :, None] * v.astype(jnp.float32)
+            attn = _decode_attend(q, k, v, cache, l, pos, active)
             attn = attn.astype(cfg.dtype).reshape(B, H * Dh)
             attn = attn @ lm.weight(bp["attn"]["wo"], cfg.dtype) + \
                 lm.weight(bp["attn"]["bo"], cfg.dtype)

@@ -25,10 +25,20 @@ rows as `lm._row_pieces` stacks them), a q of the rows' dtype as one.
 
 `gqa_attend` takes the two leaves whole and the layer to work on; the grid
 (slot, block), the clamped block index and the slot that is not live are
-`slot_rows.attend`'s. Leaves that hold the positions on the lanes
-(`[.., d, T]`, a head of 64: granite) are not this kernel's: `lm.gqa_attend`
-stays their path, as it is every leaf's off the chip and what the kernel is
-tested against.
+`slot_rows.attend`'s. `lm.gqa_attend` is every leaf's path off the chip and
+what the kernel is tested against.
+
+Leaves that hold the positions on the lanes (`[layers, slots, G, d, T]`, a
+head of 64 on the sublanes: GPT-2's cache as the chip lays it out, granite's)
+go through a body of their own, `_lanes_body`, on the same grid: a block's
+scores are `q [G, R, d] x k [G, d, block]` as they lie, and the weighted
+values contract the last axes of `p [G, R, block]` and `v [G, d, block]`.
+Which body a call takes is read off the leaf's shape
+(`rows_write.positions_last`). Their block is this file's, `BLOCK_LAST`.
+A step that has not written its new row yet (GPT-2's decode step writes
+every layer's after its loop) hands the row over as `own = (k, v)`, each
+`[B, G, d]`: the slot attends the leaf's rows before `pos` and its own row
+at `pos`, as if the row had been written first.
 
 A ring leaf (`ring=True`: `[layers, slots, G, W, d]`, the last W positions
 of a sliding-window layer, position p at row p mod W: `models/lm.py`, "a
@@ -96,25 +106,101 @@ def _weigh(p, v, *, two: bool):
         preferred_element_type=jnp.float32), two)
 
 
-def rows_kernel(q, ck, cv, scale, name="gqa_attend") -> slot_rows.Kernel:
+# `slot_rows.BLOCK` for leaves with the positions on the lanes, on the v5e
+# (`benchmarks/gqa_attend_blocks.py --shapes gpt2,gpt2-chat,granite`, PR 61;
+# us a call = a layer, the plain form first, then 128 / 256 / 512 / 1,024
+# positions a grid step):
+#   GPT-2 XL, 8 slots x 25 heads x 64 x 1,024, one bf16 q a head, own row:
+#     8 live at 16-320 (the decode cell)  79.2 | 33.6  39.5  51.0  76.2
+#     1 live at 300-1,000 (the chat cell)  79.0 | 22.8  20.4  17.7  17.2
+#   granite, 48 slots x 8 heads x 64 x 8,192, four bf16 q a head, live at
+#   3,100-7,200:                          1,113 | 1,374   937   771   802
+# A grid step that works moves its rows at the HBM's pace (1.2-1.3 us at 128
+# positions of 6.4 KB, 8.8 at 1,024: 0.82 and 6.5 MB), one that does not
+# costs 0.14 us and a call ~7 us of its own. GPT-2's lanes stand at a few
+# hundred positions of 1,024 and what a block costs them is the half block
+# read past a position: 128. granite's stand at thousands of 8,192 and pay
+# for 3,072 grid steps a call at 128: its adoption (ROADMAP S19a) brings its
+# own length, 512 by this table
+BLOCK_LAST = 128
+
+
+def block_last(T: int) -> int:
+    """`slot_rows.block_of` under `BLOCK_LAST`: the positions lie along the
+    lanes, so a block is whole lane tiles or the whole leaf."""
+    most = min(T, BLOCK_LAST)
+    whole = [n for n in range(slot_rows.LANES, most + 1, slot_rows.LANES)
+             if T % n == 0]
+    return most if most == T or not whole else whole[-1]
+
+
+def _lanes_body(blk, q_ref, k_ref, v_ref, *own, two: bool, scale: float):
+    """All G heads at once, the positions on the lanes. With the slot's own
+    row (`own`: refs of its k and v, [1, G, 1, d]) the block that holds `pos`
+    takes the row's score in column `pos`, and `_weigh_lanes` its values."""
+    q, k, v = q_ref[0], k_ref[0, 0], v_ref[0, 0]      # [G,R,d], [G,d,block]
+    q = _pieces(q, k.dtype, two)
+    s = _halves_added(jnp.einsum(
+        "gqd,gdt->gqt", q, k, preferred_element_type=jnp.float32), two)
+    at = blk.at(s.shape, 2)
+    mine = None
+    if own:
+        k_own, v_own = (ref[0] for ref in own)                  # [G, 1, d]
+        mine = at == blk.pos, v_own
+        s = jnp.where(mine[0], _halves_added(jnp.sum(
+            q.astype(jnp.float32) * k_own.astype(jnp.float32),
+            axis=-1, keepdims=True), two), s)
+    s = jnp.where(at <= blk.pos, s * scale, MASKED)          # [G, R, block]
+    yield ..., s, (slot_rows.zero_past_end(v, blk.held(v.shape, 2)), mine)
+
+
+def _weigh_lanes(p, values, *, two: bool):
+    """p [G, R, block] against v [G, d, block] -> [G, R, d]; the slot's own
+    row takes its probability apart from the block's, whose lane `pos`
+    holds whatever the cache held."""
+    v, mine = values
+
+    def product(p):
+        return _halves_added(jnp.einsum(
+            "gqt,gdt->gqd", _pieces(p, v.dtype, two), v,
+            preferred_element_type=jnp.float32), two)
+
+    if mine is None:
+        return product(p)
+    hit, v_own = mine
+    p_own = jnp.sum(jnp.where(hit, p, 0.0), axis=-1, keepdims=True)
+    p_own = _halves_added(_pieces(p_own, v.dtype, two).astype(jnp.float32),
+                          two)
+    return product(jnp.where(hit, 0.0, p)) + p_own * v_own.astype(jnp.float32)
+
+
+def rows_kernel(q, ck, cv, scale, name="gqa_attend", *, last: bool = False,
+                own=()) -> slot_rows.Kernel:
     """This kernel on `slot_rows.attend`'s grid: a q that is not of the
-    rows' dtype, and its probabilities, as two pieces."""
+    rows' dtype, and its probabilities, as two pieces; `last`: the leaves
+    hold the positions on the lanes, and may lack the slot's `own` row."""
     two = q.dtype != ck.dtype
+    assert last or not own, "rows by head are written before they are read"
+    body, weigh, at = ((_lanes_body, _weigh_lanes, 4) if last
+                       else (_block_body, _weigh, 3))
     return slot_rows.Kernel(
-        name,
-        functools.partial(_block_body, two=two, scale=float(scale)),
-        (q, Leaf(ck, 3), Leaf(cv, 3)), q.shape[1:],
-        functools.partial(_weigh, two=two))
+        name, functools.partial(body, two=two, scale=float(scale)),
+        (q, Leaf(ck, at), Leaf(cv, at), *(row[:, :, None] for row in own)),
+        q.shape[1:],
+        functools.partial(weigh, two=two))
 
 
 def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
-               scale: float, *, ring: bool = False,
+               scale: float, *, ring: bool = False, own=(),
                kernel: bool | None = None, interpret: bool = False):
     """Every slot's one token against its own rows of layer `layer`.
 
     q [B, G, R, d] (float32, or the rows' dtype), the leaves ck, cv
-    [L, B, G, T, d] whole, pos [B] (slot b attends positions 0 .. pos[b]),
-    live [B] -> [B, G, R, d] float32, garbage for a slot that is not live.
+    [L, B, G, T, d] (or [L, B, G, d, T]) whole, pos [B] (slot b attends
+    positions 0 .. pos[b]), live [B] -> [B, G, R, d] float32, garbage for a
+    slot that is not live. With `own` = (k, v), each [B, G, d], the leaves
+    [L, B, G, d, T] do not hold position pos[b] yet: slot b attends their
+    rows before it and its own row at it.
     With `ring` the leaves are rings [L, B, G, W, d] that hold position
     pos[b] already, and slot b attends the rows that are the sequence's,
     positions max(0, pos[b] - W + 1) .. pos[b].
@@ -122,12 +208,13 @@ def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
     kernel, which reads a live slot's rows once and to its position;
     elsewhere `lm.gqa_attend` over the whole layer (a ring:
     `lm.gqa_attend_band` over the positions its rows hold)."""
-    assert ring or not positions_last(ck.shape, q.shape[-1]), (ck.shape,
-                                                               q.shape)
+    last = not ring and positions_last(ck.shape, q.shape[-1])
     if slot_rows.use_kernel(kernel, interpret):
         name = "swa_attend" if ring else "gqa_attend"
-        return slot_rows.attend(rows_kernel(q, ck, cv, scale, name), layer,
-                                pos, live, interpret=interpret)
+        return slot_rows.attend(
+            rows_kernel(q, ck, cv, scale, name, last=last, own=own), layer,
+            pos, live, block=block_last(ck.shape[4]) if last else None,
+            interpret=interpret)
     from ray_tpu.models import lm       # not at the top: `models` imports us
 
     k, v = (lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
@@ -137,4 +224,9 @@ def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
         W = ck.shape[3]
         return lm.gqa_attend_band(q, k, v, lm.ring_positions(pos, W)[:, None],
                                   at, W, scale, ck.dtype)
+    if own:
+        # the rows as they will lie once the step has written them
+        hit = jnp.arange(k.shape[3]) == pos[:, None, None, None]
+        k, v = (jnp.where(hit, row[..., None], c)
+                for row, c in zip(own, (k, v)))
     return lm.gqa_attend(q, k, v, at, scale, ck.dtype)

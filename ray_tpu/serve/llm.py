@@ -573,6 +573,14 @@ class LLMEngine:
         # positions the steps' lanes attended over: each lane's last
         # position in its step, summed (what a cache of rows is read for)
         self.positions_attended = 0
+        # positions whose rows the steps read for them, where the family
+        # names the block its decode step's attention reads a slot's rows by
+        # (`rows_read_block`: GPT-2; 0 otherwise, and nothing is counted):
+        # a decode lane's positions rounded up to a block, as
+        # `ops/slot_rows.read_positions` has it; all T a lane of a chunk step
+        read_block = getattr(model, "rows_read_block", None)
+        self._rows_read_block = read_block(self.cache) if read_block else 0
+        self.positions_read = 0
         self.prefix_imports = 0        # deferred blobs installed
         self.prefix_blocks_imported = 0
         self.prefix_wait_timeouts = 0  # deadline hit: local prefill
@@ -1081,6 +1089,11 @@ class LLMEngine:
                 self.chunk_lanes_packed += further
         else:
             lengths = active = decoding
+        if self._rows_read_block:
+            n, T = self._rows_read_block, self.max_seq_len
+            self.positions_read += int(np.where(
+                active, T if chunked else np.minimum(
+                    (np.minimum(pos, T - 1) // n + 1) * n, T), 0).sum())
         lanes, prompts, last_prompts, snapshots = [], [], [], []
         produce = np.zeros((B,), bool)
         for i in live:
@@ -1294,6 +1307,8 @@ class LLMEngine:
                 "chunk_lanes_packed": self.chunk_lanes_packed,
                 "tokens_prefilled": self.tokens_prefilled,
                 "positions_attended": self.positions_attended,
+                **({"positions_read": self.positions_read}
+                   if self._rows_read_block else {}),
                 "prefix_imports": self.prefix_imports,
                 "prefix_blocks_imported": self.prefix_blocks_imported,
                 "prefix_wait_timeouts": self.prefix_wait_timeouts,

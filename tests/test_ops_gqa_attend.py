@@ -1,6 +1,8 @@
 """`ops/gqa_attend.py`: the decode kernel, interpreted, against the plain
 `lm.gqa_attend` over the same rows at Solar's head sizes; where
-`kimi._gqa` calls it; and what the decode program counts as read."""
+`kimi._gqa` calls it; what the decode program counts as read; and the body
+for leaves with the positions on the lanes, against `models/gpt2.py`'s plain
+lines at GPT-2 XL's geometry and `lm.gqa_attend` at granite's."""
 
 import importlib
 
@@ -9,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import kimi, lm
+from ray_tpu.models import gpt2, kimi, lm
 from ray_tpu.ops import slot_rows, slot_state
 
 op = importlib.import_module("ray_tpu.ops.gqa_attend")
@@ -127,12 +129,21 @@ def test_the_block_is_this_kernels_own_and_divides_the_length(
     assert slot_rows.block_of(T) == block
 
 
-def test_leaves_with_the_positions_on_the_lanes_are_refused():
-    q, ck, _ = _operands(2, 64, F32)
-    last = jnp.swapaxes(ck, 3, 4)                    # [L, B, G, d, T]
+def test_leaves_with_the_positions_on_the_lanes_take_the_body_of_their_own():
+    """Which way round a leaf lies is read off its shape: the same rows,
+    their last two axes swapped, give the same values through the other
+    body, and only that one takes a slot's own row."""
+    q, ck, cv = _operands(2, 2 * BLOCK, F32)    # two pieces: a block's order
+    pos, live = jnp.asarray([5, 2 * BLOCK - 1]), jnp.ones(2, bool)
+    rows = op.gqa_attend(q, ck, cv, 0, pos, live, SCALE, interpret=True)
+    last = op.gqa_attend(q, jnp.swapaxes(ck, 3, 4), jnp.swapaxes(cv, 3, 4),
+                         0, pos, live, SCALE, interpret=True)
+    np.testing.assert_allclose(last, rows, **TOLERANCE[F32])
+    assert op.rows_kernel(q, ck, cv, SCALE).body.func is op._block_body
+    assert op.rows_kernel(q, ck, cv, SCALE, last=True).body.func \
+        is op._lanes_body
     with pytest.raises(AssertionError):
-        op.gqa_attend(q, last, last, 0, jnp.zeros(2, jnp.int32),
-                      jnp.ones(2, bool), SCALE, kernel=False)
+        op.rows_kernel(q, ck, cv, SCALE, own=(q[:, :, 0], q[:, :, 0]))
 
 
 def test_read_positions_are_a_slots_position_rounded_up_to_a_block(
@@ -373,3 +384,114 @@ def test_a_rings_row_is_written_at_the_position_modulo_the_window(how):
     np.testing.assert_array_equal(got, want)
     with pytest.raises(AssertionError):
         rows_write(ring, jnp.int32(1), val, pos, on, **how)
+
+
+# ------------------------------------------- the positions on the lanes
+
+LAST = 256                  # `BLOCK_LAST` in these tests, whatever the chip's
+XL, GRANITE = dict(G=25, R=1, T=1024), dict(G=8, R=4, T=8192)
+EDGES = [0, LAST - 1, LAST, 1023]
+LANES_CASES = {
+    # GPT-2 XL's geometry, through `gpt2._decode_attend`: a bf16 q, the
+    # step's own row handed over
+    "gpt2-on-and-beside-block-edges": dict(XL, pos=EDGES, live=[1, 1, 1, 1]),
+    "gpt2-dead-slots-between-live-ones": dict(
+        XL, pos=[300, 0, 2 * LAST - 1, 2 * LAST, 77, 1023],
+        live=[1, 0, 1, 0, 0, 1]),
+    "gpt2-a-dead-slot-first-and-last": dict(XL, pos=EDGES,
+                                            live=[0, 1, 1, 0]),
+    "gpt2-the-own-row-alone-at-position-0": dict(XL, pos=[0, 0],
+                                                 live=[1, 1]),
+    "gpt2-no-slot-live": dict(XL, pos=EDGES, live=[0, 0, 0, 0]),
+    # granite's, for ROADMAP S19a: rows written before they are read
+    "granite-rows-written-first": dict(
+        GRANITE, pos=[0, LAST - 1, LAST, 8191, 5000], live=[1, 1, 1, 1, 0]),
+    "granite-the-own-row-handed-over": dict(
+        GRANITE, pos=[0, LAST, 8191], live=[1, 1, 1], own=True),
+    # a q of another dtype: two pieces, of it and of its probabilities
+    "float32-q-two-pieces": dict(GRANITE, T=1024, pos=EDGES,
+                                 live=[1, 1, 0, 1], q=F32),
+    "float32-q-two-pieces-the-own-row": dict(
+        GRANITE, T=1024, pos=EDGES, live=[1, 1, 0, 1], q=F32, own=True),
+}
+
+
+@pytest.mark.parametrize("case", LANES_CASES)
+def test_leaves_by_the_lane_through_the_kernel_are_the_plain_form(
+        monkeypatch, case):
+    """`_lanes_body` on `slot_rows.attend`'s grid, interpreted: every live
+    slot's values are the plain form's (GPT-2: `decode_step`'s own lines,
+    which read the cache as it was and the own row beside it; the others:
+    `lm.gqa_attend` over the layer with the own row written first), a slot
+    that is not live gets zeros, and its NaN rows are never read."""
+    G, R, T, pos, live = (LANES_CASES[case][n] for n in (
+        "G", "R", "T", "pos", "live"))
+    q_dtype = LANES_CASES[case].get("q", BF16)
+    is_gpt2 = case.startswith("gpt2")
+    monkeypatch.setattr(op, "BLOCK_LAST", LAST)
+    assert op.block_last(T) == LAST
+    B, L, d, layer = len(pos), 2, 64, 1
+    ks = jax.random.split(jax.random.key(61), 5)
+    q = (4 * jax.random.normal(ks[0], (B, G, R, d), F32)).astype(q_dtype)
+    ck, cv = (jax.random.normal(k, (L, B, G, d, T), F32).astype(BF16)
+              for k in ks[1:3])
+    own = tuple(jax.random.normal(k, (B, G, d), F32).astype(BF16)
+                for k in ks[3:]) if is_gpt2 or LANES_CASES[case].get(
+                    "own") else ()
+    pos, live = jnp.asarray(pos, jnp.int32), jnp.asarray(live, bool)
+    on = np.asarray(live)
+    # what a dead slot holds must not matter: the grid reads none of it
+    dead = jnp.where(live, 0.0, jnp.nan)[None, :, None, None, None]
+    args = (q, (ck + dead).astype(BF16), (cv + dead).astype(BF16), *own)
+    scale = 1.0 / np.sqrt(d)
+    if is_gpt2:
+        def through(interpret):
+            return jax.jit(lambda q, ck, cv, k, v: gpt2._decode_attend(
+                q[:, :, 0], k, v, {"k": jnp.swapaxes(ck, 3, 4),
+                                   "v": jnp.swapaxes(cv, 3, 4)},
+                jnp.int32(layer), pos, live,
+                interpret=interpret)[:, :, None])(*args)
+    else:
+        def through(interpret):
+            how = dict(interpret=True) if interpret else dict(kernel=False)
+            return jax.jit(lambda q, ck, cv, *own: op.gqa_attend(
+                q, ck, cv, jnp.int32(layer), pos, live, scale, own=own,
+                **how))(*args)
+    got, want = np.asarray(through(True)), np.asarray(through(False))
+    assert got.shape == (B, G, R, d) and got.dtype == np.float32
+    np.testing.assert_allclose(got[on], want[on], **TOLERANCE[q_dtype])
+    assert np.isfinite(got).all() and not got[~on].any()
+    if on.any():
+        assert np.abs(want[on]).max() > 0.5
+    if is_gpt2:
+        # the op's own plain form, the row written first, is those lines
+        rows = np.asarray(op.gqa_attend(*args[:3], jnp.int32(layer), pos,
+                                        live, scale, own=own, kernel=False))
+        np.testing.assert_allclose(rows[on], want[on], **TOLERANCE[q_dtype])
+    if case == "gpt2-the-own-row-alone-at-position-0":
+        np.testing.assert_allclose(got[:, :, 0], np.asarray(own[1], F32),
+                                   rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("T,most,block", [
+    (1024, 256, 256), (1024, 512, 512), (8192, 256, 256), (1024, 2048, 1024),
+    (96, 256, 96), (384, 256, 128), (1000, 256, 256)])
+def test_the_lanes_block_is_whole_lane_tiles_that_divide_the_length(
+        monkeypatch, T, most, block):
+    monkeypatch.setattr(op, "BLOCK_LAST", most)
+    assert op.block_last(T) == block
+
+
+def test_a_leaf_by_the_lane_whose_last_block_hangs_over(monkeypatch):
+    """1,000 positions in blocks of 256: the last block's lanes past the
+    leaf hold whatever VMEM held, and reach no result."""
+    monkeypatch.setattr(op, "BLOCK_LAST", LAST)
+    B, G, R, d, T = 3, 2, 4, 64, 1000
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = (4 * jax.random.normal(ks[0], (B, G, R, d), F32)).astype(BF16)
+    ck, cv = (jax.random.normal(k, (1, B, G, d, T), F32).astype(BF16)
+              for k in ks[1:])
+    pos, live = jnp.asarray([999, 3 * LAST, 100]), jnp.ones(3, bool)
+    got = op.gqa_attend(q, ck, cv, 0, pos, live, 0.125, interpret=True)
+    want = op.gqa_attend(q, ck, cv, 0, pos, live, 0.125, kernel=False)
+    np.testing.assert_allclose(got, want, **TOLERANCE[BF16])

@@ -559,3 +559,112 @@ def test_a_cache_sharded_over_heads_is_written_a_shard_each(
         (cfg.n_layer, B, cfg.n_head // 2, cfg.head_dim, KT)]
     assert got.sharding.is_equivalent_to(heads, got.ndim)
     assert _same_bits(got, want)
+
+
+# ---------------------------------------------- the whole engine (PR 61)
+
+def _served(kernels: bool, monkeypatch):
+    """A tiny float32 GPT-2 engine of 3 slots x 256 positions serves ten
+    greedy requests, four at a time, so slots are admitted, finish and are
+    taken again; with `kernels` the decode step's two Pallas calls run
+    interpreted, the attention in blocks of 128. The layers' matrices are
+    eight times the seeded ones: a reply is then no repetition of its
+    prompt's last token, and a wrong row attended to is another token.
+    Returns ({request: tokens}, engine_stats(), the (pos, active) [B] of
+    every decode step, the lanes the chunk steps ran)."""
+    import importlib
+    import threading
+    from functools import partial
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    if kernels:
+        monkeypatch.setattr(importlib.import_module(
+            "ray_tpu.ops.gqa_attend"), "BLOCK_LAST", 128)
+        for name in ("_decode_attend", "_decode_write", "rows_read_block"):
+            monkeypatch.setattr(gpt2, name, partial(getattr(gpt2, name),
+                                                    interpret=True))
+    cfg = gpt2.GPT2Config.preset("gpt2-tiny", max_seq_len=256,
+                                 dtype=jnp.float32)
+    params = gpt2.init_params(jax.random.key(61), cfg)
+    params["blocks"] = jax.tree.map(lambda a: 8 * a if a.ndim == 3 else a,
+                                    params["blocks"])
+    eng = LLMEngine(preset="gpt2-tiny", max_batch=3, max_seq_len=256,
+                    prefill_chunk_size=16, kv_block_size=8,
+                    params_override=params, cfg_override=cfg)
+    out, decode_steps, chunk_lanes = {}, [], []
+    step, chunk = eng._step, eng._chunk_step
+
+    def seen_step(params, cache, ids, pos, active):
+        decode_steps.append((np.asarray(pos), np.asarray(active)))
+        return step(params, cache, ids, pos, active)
+
+    def seen_chunk(params, cache, tokens, pos, lengths, active):
+        chunk_lanes.append(int(np.asarray(active).sum()))
+        return chunk(params, cache, tokens, pos, lengths, active)
+
+    eng._step, eng._chunk_step = seen_step, seen_chunk
+    try:
+        def ask(i):
+            # prompts that end short of, on and past the first block's edge
+            prompt = [(7 * i + 3 * j * j) % 500 + 1
+                      for j in range(100 + 7 * i)]
+            out[i] = eng.generate(prompt_ids=prompt, max_tokens=8 + 5 * i,
+                                  temperature=0.0)["token_ids"]
+        for first in (0, 4, 8):
+            threads = [threading.Thread(target=ask, args=(i,))
+                       for i in range(first, min(first + 4, 10))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+                assert not t.is_alive()
+        return out, eng.engine_stats(), decode_steps, sum(chunk_lanes)
+    finally:
+        eng.shutdown()
+
+
+def test_an_engines_tokens_through_the_kernels_are_the_plain_paths(
+        monkeypatch):
+    """The attention kernel in the engine's decode program, over a run that
+    admits, finishes and re-admits slots: the greedy tokens are the plain
+    path's, and `positions_read` counts what the path reads: the kernel a
+    decode lane's positions rounded up to a block of 128 (less than a whole
+    block more than it attends), the plain lines all 256; a chunk step all
+    256 a lane either way."""
+    with monkeypatch.context() as m:
+        got, stats, decode_steps, chunk_lanes = _served(True, m)
+    want, plain, _, _ = _served(False, monkeypatch)
+    assert got == want
+    assert len({t for reply in want.values() for t in reply}) > 100
+    # both engines planned the same steps
+    same = ("positions_attended", "chunk_tokens", "total_generated",
+            "engine_steps", "chunk_steps")
+    assert [stats[n] for n in same] == [plain[n] for n in same]
+    assert len(decode_steps) == stats["engine_steps"] - stats["chunk_steps"]
+    # a decode lane at position p attends p + 1 positions and reads them
+    # rounded up to a block, less than a block more
+    lanes = sum(int(on.sum()) for _, on in decode_steps)
+    attended = sum(int((pos + 1)[on].sum()) for pos, on in decode_steps)
+    read = sum(int(((pos // 128 + 1) * 128)[on].sum())
+               for pos, on in decode_steps)
+    assert lanes > 100 and attended <= read < attended + 128 * lanes
+    assert {int(p) // 128 for pos, on in decode_steps for p in pos[on]} \
+        == {0, 1}
+    assert stats["positions_read"] == read + 256 * chunk_lanes
+    # the plain path reads all T a lane of a step, chunk or decode
+    assert plain["positions_read"] == 256 * (lanes + chunk_lanes)
+    for s in (stats, plain):
+        assert s["positions_read"] >= s["positions_attended"] > attended
+
+
+def test_only_a_family_that_names_its_block_counts_positions_read():
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(preset="granite-tiny", max_batch=2, max_seq_len=64)
+    try:
+        assert eng._rows_read_block == 0
+        assert "positions_read" not in eng.engine_stats()
+        assert "positions_attended" in eng.engine_stats()
+    finally:
+        eng.shutdown()

@@ -185,9 +185,9 @@ def test_tensor_parallel_decode_step_writes_a_shard_each(chips, as_on_tpu,
     `tensor_parallel_size` 2 or 4, at 125M widths over chips of the 2x2:
     the weights by their specs, the cache's heads over `tp`, the placement
     GSPMD's. The TPU's compiler partitions no Pallas call, so
-    `gpt2._decode_write` runs the kernel inside a `shard_map`, each chip on
-    its own 6 or 3 heads: no leaf is gathered, copied or re-laid on the
-    way."""
+    `gpt2._decode_write` and, since PR 61, `gpt2._decode_attend` (the loop's
+    one call) run their kernels inside a `shard_map`, each chip on its own
+    6 or 3 heads: no leaf is gathered, copied or re-laid on the way."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel.mesh import (MeshConfig, build_mesh, traced_on,
@@ -215,7 +215,7 @@ def test_tensor_parallel_decode_step_writes_a_shard_each(chips, as_on_tpu,
                 jax.ShapeDtypeStruct((B,), jnp.bool_)))).compile()
     hlo = compiled.as_text()
     L, H, Dh = cfg.n_layer, cfg.n_head // tp, cfg.head_dim
-    assert len(re.findall(r'custom_call_target="tpu_custom_call"', hlo)) == 2
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', hlo)) == 3
     assert not _written_arrays(hlo, f"{L},{B},{cfg.n_head},({T},{Dh}|{Dh},{T})")
     written = _written_arrays(hlo, f"{L},{B},{H},({T},{Dh}|{Dh},{T})")
     assert {op for op, _ in written} <= {
@@ -282,18 +282,22 @@ def test_serving_step_updates_the_cache_in_place(chips, as_on_tpu, program,
     handed_on = {"parameter", "get-tuple-element", "tuple", "while",
                  "bitcast"}
     if program == "decode_step":
-        # the loop only reads the leaves it closes over; after it each is
-        # viewed [.., Dh, T] (a bitcast: the chip holds [.., T, 64] with T
-        # on the lanes), written by one kernel in place, and viewed back
+        # the loop only reads the leaves it closes over, each viewed
+        # [.., Dh, T] (a bitcast: the chip holds [.., T, 64] with T on the
+        # lanes) for its one kernel, the attention's (PR 61); after it each
+        # is viewed so again, written by one kernel in place, and viewed back
         assert {op for op, _ in whole} <= handed_on, whole
         view = _written_arrays(hlo, f"{L},{B},{H},{Dh},{T}")
         assert sorted(op for op, _ in view) == [
-            "bitcast", "bitcast", "custom-call", "custom-call"], view
+            "bitcast", "bitcast", "bitcast", "bitcast", "custom-call",
+            "custom-call"], view
         assert len(re.findall(r'custom_call_target="tpu_custom_call"',
-                              hlo)) == 2
+                              hlo)) == 3
         # T stays the minor-most axis, Dh the next: one layout
         assert {re.search(r"\](\{[\d,]*)", t).group(1) for _, t in view} \
             == {"{4,3,2,1,0"}, view
+        # and no slot's scores over all T positions are written
+        assert not _written_arrays(hlo, f"{B},{H},{T}", "f32")
         assert not _written_arrays(hlo, f"{B},{H},{Dh},{T}")
     else:
         # the chunk program's windows are written in the loop over the
@@ -366,9 +370,13 @@ def test_serving_step_converts_no_weights(chips, as_on_tpu, program, C,
 # rows go into the cache after the loop through two calls of
 # `ops/rows_write.py` on the leaves' own bytes, where PR 56's held a pair of
 # blended windows [48,1,25,128,64] (6,315,223,552 B; 6,314,675,200 before
-# PR 56, which wrote in the loop): 0.81 MB less. A leaf copied would show as
-# 1.26 GB more. The chunk program is still PR 29's parent's
-GPT2_XL_SERVING_BYTES = {"decode_step": 6_314_417_152,
+# PR 56, which wrote in the loop): 0.81 MB less (6,314,417,152 B). Since PR
+# 61 the loop's attention is a call of `ops/gqa_attend.py` on the same views
+# of the leaves: 32,256 B more, the kernel's operands a slot (q, the own
+# rows) for PR 58's float32 scores [8,25,1024] and probabilities. A leaf
+# copied would show as 1.26 GB more. The chunk program is still PR 29's
+# parent's
+GPT2_XL_SERVING_BYTES = {"decode_step": 6_314_449_408,
                          "prefill_chunk": 6_314_743_808}
 
 
