@@ -79,6 +79,15 @@ lie within 1.5e-3 of the plain form's (r.m.s. 0.2: one bf16 piece, the
 probabilities rounded before the division where the plain form rounds
 after). `BLOCK_LAST` is 128, GPT-2's; granite's adoption brings its own.
 
+MiMo's cell (PR 62): `mimo`, 2 global layers x 64 slots x 4 heads x 24,576
+positions, keys [.., 192, T] beside values [.., T, 128], 16 float32 queries
+a head (32 rows as two pieces), the slots live at 1.0k-24.4k as the mixed
+queue leaves them; `mimo-ring`, 5 sliding layers x 64 slots x 8 heads x a
+ring of 128 positions, keys [.., 192, 128], with a sink a head as the fold's
+start. The least is a position's 4 (or 8) x (192 + 128) lanes of bf16. Its
+table is PERF.md section 6, PR 62 (no metric file reads the ring's share:
+BENCHMARK.json is full).
+
 Writes `chiprun_out/gqa_attend_blocks.json`. One process, which holds the
 chip.
 """
@@ -112,7 +121,13 @@ SHAPES = {"solar": (1, 40, 25600, 40, (16400, 25200), False, ROWS),
           "gpt2": (48, 8, 1024, 8, (16, 320), False, GPT2),
           "gpt2-chat": (48, 8, 1024, 1, (300, 1000), False, GPT2),
           "granite": (4, 48, 8192, 48, (3100, 7200), False, dict(
-              G=8, R=4, D=64, q="bfloat16", last=True, own=False))}
+              G=8, R=4, D=64, q="bfloat16", last=True, own=False)),
+          # keys of 192 lanes on the sublanes beside values of 128 by the row
+          "mimo": (2, 64, 24576, 64, (1040, 24400), False, dict(
+              G=4, R=16, D=192, Dv=128, q="float32", last=True, own=False)),
+          "mimo-ring": (5, 64, 128, 64, (1040, 24400), True, dict(
+              G=8, R=8, D=192, Dv=128, q="float32", last=True, own=False,
+              sink=True))}
 BLOCKS = (512, 1024, 2048, 2560)
 
 
@@ -157,13 +172,17 @@ def main() -> int:
     for name in args.shapes.split(","):
         L, B, T, n_live, positions, ring, heads = SHAPES[name]
         G, R, D, last = (heads[n] for n in ("G", "R", "D", "last"))
+        Dv = heads.get("Dv", D)             # values of another width: rows
         scale = 1.0 / math.sqrt(D)
+        sink = (2.0 + jax.random.normal(ks[3], (G, R), jnp.float32)
+                if heads.get("sink") else None)
         if made != (L, B, T, G, D, last):
             rows = (L, B, G, D, T) if last else (L, B, G, T, D)
             q = jax.random.normal(ks[0], (B, G, R, D), jnp.float32).astype(
                 heads["q"])
             ck = jax.random.normal(ks[1], rows, jnp.bfloat16)
-            cv = jax.random.normal(ks[2], rows, jnp.bfloat16)
+            cv = jax.random.normal(
+                ks[2], rows if Dv == D else (L, B, G, T, Dv), jnp.bfloat16)
             own = tuple(jax.random.normal(k, (B, G, D), jnp.bfloat16)
                         for k in ks[3:]) if heads["own"] else ()
             made = (L, B, T, G, D, last)
@@ -173,7 +192,7 @@ def main() -> int:
         # a ring's rows: the last T positions
         attended = int(jnp.sum(jnp.where(live, jnp.minimum(pos + 1, T)
                                          if ring else pos + 1, 0)))
-        least = attended * 2 * G * D * 2 / peak
+        least = attended * G * (D + Dv) * 2 / peak
         rows, want = {}, None
         forms = [("plain", None)] + [(b, int(b)) for b in (
             [T] if ring else args.blocks.split(","))]
@@ -181,14 +200,17 @@ def main() -> int:
             if block is None and own:
                 fn = functools.partial(gpt2_plain, own=own)
             elif block is None:
-                fn = functools.partial(op.gqa_attend, kernel=False, ring=ring)
+                fn = functools.partial(op.gqa_attend, kernel=False, ring=ring,
+                                       sink=sink)
             elif ring:
-                fn = functools.partial(op.gqa_attend, ring=True)
+                fn = functools.partial(op.gqa_attend, ring=True, sink=sink)
             else:
                 def fn(q, ck, cv, layer, pos, live, scale, block=block,
-                       last=last, own=own):
+                       last=last, own=own, sink=sink, Dv=Dv, D=D):
                     return slot_rows.attend(
-                        op.rows_kernel(q, ck, cv, scale, last=last, own=own),
+                        op.rows_kernel(q, ck, cv, scale, last=last, own=own,
+                                       values_last=last and Dv == D,
+                                       sink=sink),
                         layer, pos, live, block=block)
 
             # the calls are one program's loop, as the layers' loop is, the
@@ -196,10 +218,11 @@ def main() -> int:
             # takes the one before it into its q, or the compiler would
             # lift the one layer's call out of the loop
             def calls(ck, cv, n, fn=fn, q=q, pos=pos, live=live, L=L,
-                      scale=scale):
+                      scale=scale, Dv=Dv):
                 return lax.fori_loop(0, n, lambda i, y: fn(
-                    (q + 1e-6 * y).astype(q.dtype), ck, cv, i % L, pos, live,
-                    scale), jnp.zeros(q.shape, jnp.float32))
+                    (q + 1e-6 * y[..., :1]).astype(q.dtype), ck, cv, i % L,
+                    pos, live, scale),
+                    jnp.zeros(q.shape[:-1] + (Dv,), jnp.float32))
 
             step = functools.partial(jax.jit(calls), ck, cv)
             try:

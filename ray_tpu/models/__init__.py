@@ -24,12 +24,17 @@ share, and the latent rows' write and read), exaone (K-EXAONE's layers for
 serving: three sliding-window softmax layers, rotated, to each global one,
 un-rotated, in one stack; the window layers' rows a ring a slot, which the
 pool keeps as a snapshot beside the global layers' rows by the block;
-sigmoid-routed experts beside a shared one)."""
+sigmoid-routed experts beside a shared one), mimo (MiMo-V2's layers for
+serving: five sliding-window layers to each global one, the two kinds with
+4 and 8 key-value heads and a fused projection each, a key of 192 lanes held
+with the positions on the lanes beside a value of 128 a row, a learned sink a
+head in the sliding layers' softmax, sigmoid-routed experts and no shared
+one)."""
 
 from ray_tpu.models import gpt2
 
 __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "keye",
-           "solar", "nemotron", "mamba2", "longcat", "mla", "exaone",
+           "solar", "nemotron", "mamba2", "longcat", "mla", "exaone", "mimo",
            "serving_family"]
 
 # The families `serve/llm.LLMEngine` takes: a preset's first word -> the
@@ -46,7 +51,7 @@ __all__ = ["gpt2", "llama", "moe", "deepseek", "brumby", "granite", "kimi", "key
 # request is placed in it, and a step leaves an inactive slot's state as it
 # was). A family may name both kinds (granite, kimi: the pool then keeps, under
 # one hash, a prefix's rows by the block and the state at its end, and a hit
-# needs both; exaone: its state is a sliding-window layer's last rows, a ring). A leaf neither names is the programs' own (`counts`).
+# needs both; exaone, mimo: the state is a sliding-window layer's last rows, a ring). A leaf neither names is the programs' own (`counts`).
 #
 # What a family borrows and what it holds. `models/lm.py` ("The serving
 # families") has what every family needs and none owns: seeded weights made
@@ -71,7 +76,8 @@ _SERVING = {"gpt2": ("gpt2", "GPT2Config"),
             "solar": ("solar", "KimiConfig"),
             "nemotron": ("nemotron", "NemotronConfig"),
             "longcat": ("longcat", "LongcatConfig"),
-            "kexaone": ("exaone", "ExaoneConfig")}
+            "kexaone": ("exaone", "ExaoneConfig"),
+            "mimo": ("mimo", "MimoConfig")}
 
 
 def serving_family(preset: str):
@@ -90,7 +96,7 @@ def serving_family(preset: str):
 def __getattr__(name):
     if name in ("llama", "moe", "deepseek", "brumby", "granite", "kimi",
                 "keye", "solar", "nemotron", "mamba2", "longcat", "mla",
-                "exaone"):
+                "exaone", "mimo"):
         import importlib
 
         return importlib.import_module(f"ray_tpu.models.{name}")

@@ -347,6 +347,38 @@ def layer_weights(stack: Params, i, turn=None) -> Params:
         lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
 
 
+def layers_in_runs(kinds: list, layer: Callable, carry):
+    """The layers of a model whose layers are of several kinds, walked as
+    runs of one kind: `layer(kind, l, carry) -> carry` for l = 0 .. in
+    order, `kinds[l]` layer l's (anything hashable that sorts). One loop over
+    the runs, whose body holds one loop a kind, and a kind's loop turns as
+    many times as the run is long if the run is of that kind and not at all
+    if it is not. No branch takes a layer's kind (a leaf that passes through
+    a conditional untouched is copied on its way), the program holds a body a
+    kind whatever the depth, and nothing of a layer stands outside the runs'
+    loop. (`models/mimo.py`; `kimi.py`, `nemotron.py` and `exaone.py` each
+    hold the lines this was written from: ROADMAP D28.)"""
+    runs = []                             # [kind, first layer, layers]
+    for l, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, l, 1])
+    bodies = sorted(set(kinds))
+    first_layer = jnp.asarray([first_l for _, first_l, _ in runs])
+    turns = {kind: jnp.asarray([n if k == kind else 0 for k, _, n in runs])
+             for kind in bodies}
+
+    def run(r, carry):
+        start = first_layer[r]
+        for kind in bodies:
+            carry = lax.fori_loop(start, start + turns[kind][r],
+                                  partial(layer, kind), carry)
+        return carry
+
+    return lax.fori_loop(0, len(runs), run, carry)
+
+
 # -- a layer's arithmetic -----------------------------------------------------
 
 def weight(p, dtype):
@@ -716,9 +748,23 @@ def _rows_added(y, Q: int):
     return y if y.shape[-2] == Q else y[..., :Q, :] + y[..., Q:, :]
 
 
-def gqa_attend(q, k, v, at, scale: float, dtype):
+def _softmax(scores, sink=None):
+    """Over the last axis; with `sink` [...], a score a query that takes its
+    share of the probability and has no entry of its own (MiMo's sliding
+    layers: it weighs no value)."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    sink = sink[..., None]
+    m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+    p = jnp.exp(scores - m)
+    return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def gqa_attend(q, k, v, at, scale: float, dtype, sink=None):
     """q [..., Q, d] at positions `at` [..., Q] over the cached rows k, v
-    [..., d, T] (or [..., T, d]) of its key-value head -> [..., Q, d]
+    [..., d, T] (or [..., T, d]; or keys [..., d, T] beside values
+    [..., T, n] of another width, which the result then has) of its
+    key-value head -> [..., Q, d]
     float32: scores times `scale`, causal softmax, weighted values. Every
     one of the T positions is read, whatever `at` is. q in the rows' dtype
     and the probabilities rounded to it go as one piece (granite); a float32
@@ -729,15 +775,16 @@ def gqa_attend(q, k, v, at, scale: float, dtype):
 
     last = positions_last(k.shape, q.shape[-1])
     rows = "dt" if last else "td"
+    values = "nt" if last and v.shape == k.shape else "tn"
     T = k.shape[-1 if last else -2]
     Q, whole = q.shape[-2], q.dtype != dtype
     scores = _rows_added(jnp.einsum(
         f"...qd,...{rows}->...qt", _row_pieces(q, dtype), k,
         preferred_element_type=jnp.float32), Q)
     seen = jnp.arange(T) <= at[..., None]
-    probs = jax.nn.softmax(jnp.where(seen, scores * scale, _MASKED), axis=-1)
+    probs = _softmax(jnp.where(seen, scores * scale, _MASKED), sink)
     probs = _row_pieces(probs, dtype) if whole else probs.astype(dtype)
-    return _rows_added(jnp.einsum(f"...qt,...{rows}->...qd", probs, v,
+    return _rows_added(jnp.einsum(f"...qt,...{values}->...qn", probs, v,
                                   preferred_element_type=jnp.float32), Q)
 
 
@@ -757,9 +804,14 @@ def gqa_attend_blocks(q, ck, cv, l, slot, at, last, scale: float, dtype):
     form's scores for a chunk's Q = R M queries are [G, Q, T] floats, 0.84
     GB at 8 x 128 queries and 25,600 positions; a block's are 34 MB (twice
     that for a float32 q's two pieces). The precision is the plain form's:
-    q and the probabilities as one piece or as two, by q's dtype."""
+    q and the probabilities as one piece or as two, by q's dtype. Keys that
+    hold the positions on the lanes, ck [L,B,G,d,T], stand beside values
+    cv [L,B,G,T,n] of their own width, which is the result's."""
+    from ray_tpu.ops.rows_write import positions_last  # `dot` has why here
+
     G, Q, d = q.shape
-    T = ck.shape[3]
+    keys_last = positions_last(ck.shape, d)
+    T, n = cv.shape[3:]
     turns, block = gqa_blocks(last, T)
     whole = q.dtype != dtype
     q = _row_pieces(q, dtype)
@@ -769,13 +821,18 @@ def gqa_attend_blocks(q, ck, cv, l, slot, at, last, scale: float, dtype):
         # the last block of a T that no block divides starts early: the
         # positions before j block were the turn before's
         start = jnp.minimum(j * block, T - block)
-        k, v = (lax.dynamic_slice(leaf, (l, slot, 0, start, 0),
+        if keys_last:
+            k = lax.dynamic_slice(ck, (l, slot, 0, 0, start),
+                                  (1, 1, G, d, block))[0, 0]
+        else:
+            k = lax.dynamic_slice(ck, (l, slot, 0, start, 0),
                                   (1, 1, G, block, d))[0, 0]
-                for leaf in (ck, cv))
+        v = lax.dynamic_slice(cv, (l, slot, 0, start, 0),
+                              (1, 1, G, block, n))[0, 0]
         t = start + jnp.arange(block)
         scores = _rows_added(jnp.einsum(
-            "gqd,gtd->gqt", q, k, preferred_element_type=jnp.float32),
-            Q) * scale
+            "gqd,gdt->gqt" if keys_last else "gqd,gtd->gqt", q, k,
+            preferred_element_type=jnp.float32), Q) * scale
         seen = (t >= j * block) & (t <= at[..., None])
         scores = jnp.where(seen, scores, _MASKED)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
@@ -791,7 +848,7 @@ def gqa_attend_blocks(q, ck, cv, l, slot, at, last, scale: float, dtype):
     _, s, acc = lax.fori_loop(
         0, turns, turn, (jnp.full((G, Q), _MASKED, jnp.float32),
                          jnp.zeros((G, Q), jnp.float32),
-                         jnp.zeros((G, Q, d), jnp.float32)))
+                         jnp.zeros((G, Q, n), jnp.float32)))
     return acc / jnp.maximum(s, 1e-30)[..., None]
 
 
@@ -843,10 +900,12 @@ def ring_positions(newest, W: int):
     return newest - (newest - jnp.arange(W)) % W
 
 
-def gqa_attend_band(q, k, v, t, at, window: int, scale: float, dtype):
-    """q [..., Q, d] at positions `at` [..., Q] over rows k, v [..., S, d]
-    that hold the positions t [..., S] -> [..., Q, d] float32: a query sees
-    the rows with at - window < t <= at and t >= 0, wherever they lie. The
+def gqa_attend_band(q, k, v, t, at, window: int, scale: float, dtype,
+                    sink=None):
+    """q [..., Q, d] at positions `at` [..., Q] over rows k [..., S, d] and
+    v [..., S, n] that hold the positions t [..., S] -> [..., Q, n] float32:
+    a query sees the rows with at - window < t <= at and t >= 0, wherever
+    they lie, and its `sink` [..., Q] where it has one (`_softmax`). The
     precision is `gqa_attend`'s, piece for piece."""
     Q, whole = q.shape[-2], q.dtype != dtype
     scores = _rows_added(jnp.einsum(
@@ -854,29 +913,36 @@ def gqa_attend_band(q, k, v, t, at, window: int, scale: float, dtype):
         preferred_element_type=jnp.float32), Q)
     t, at = t[..., None, :], at[..., None]
     seen = (t >= 0) & (t <= at) & (t > at - window)
-    probs = jax.nn.softmax(jnp.where(seen, scores * scale, _MASKED), axis=-1)
+    probs = _softmax(jnp.where(seen, scores * scale, _MASKED), sink)
     probs = _row_pieces(probs, dtype) if whole else probs.astype(dtype)
     return _rows_added(jnp.einsum("...qt,...td->...qd", probs, v,
                                   preferred_element_type=jnp.float32), Q)
 
 
-def gqa_attend_ring(q, ck, cv, l, slot, k, v, at, pos, scale: float, dtype):
+def gqa_attend_ring(q, ck, cv, l, slot, k, v, at, pos, scale: float, dtype,
+                    sink=None):
     """One slot's queries q [G, Q, d] at positions `at` [G, Q], a chunk's
     further lanes whose first stands at `pos`, against layer l of the rings
     ck, cv [L,B,G,W,d] as the lane before them left them (newest position
     pos - 1) and the chunk's own keys and values k, v [M,G,d] at pos ..: a
     band of W over both. The ring is read here and written after
     (`ring_write_slot`): a chunk's lanes overwrite rows that lanes before
-    them still read."""
-    G, W, d = ck.shape[2:]
+    them still read. A ring of keys that holds the positions on the lanes,
+    ck [L,B,G,d,W] (`ops/rows_write.ring_positions_last`), is turned as it
+    is read, one slot's; `sink` [G, Q] is `gqa_attend_band`'s."""
+    from ray_tpu.ops.rows_write import ring_positions_last  # as above
+
+    G, W = cv.shape[2:4]
     M = k.shape[0]
     old_k, old_v = (lax.dynamic_slice(c, (l, slot, 0, 0, 0),
-                                      (1, 1, G, W, d))[0, 0]
+                                      (1, 1, G) + c.shape[3:])[0, 0]
                     for c in (ck, cv))
+    if ring_positions_last(ck.shape, k.shape[-1]):
+        old_k = jnp.swapaxes(old_k, 1, 2)
     t = jnp.concatenate([ring_positions(pos - 1, W), pos + jnp.arange(M)])
     rows_k = jnp.concatenate([old_k, jnp.swapaxes(k, 0, 1)], axis=1)
     rows_v = jnp.concatenate([old_v, jnp.swapaxes(v, 0, 1)], axis=1)
-    return gqa_attend_band(q, rows_k, rows_v, t, at, W, scale, dtype)
+    return gqa_attend_band(q, rows_k, rows_v, t, at, W, scale, dtype, sink)
 
 
 def ring_write_slot(c, l, slot, val, pos, n):
@@ -885,14 +951,20 @@ def ring_write_slot(c, l, slot, val, pos, n):
     `slot`: row r takes the last of them that falls on it (the lane at the
     position the ring holds there once position pos + n - 1 is its newest),
     and keeps what it has where none does. The lanes are moved by a 0/1
-    matrix (`gqa_write_slot`: exact)."""
-    G, W, d = c.shape[2:]
+    matrix (`gqa_write_slot`: exact). A ring [L,B,G,d,W] takes them
+    along its lanes."""
+    from ray_tpu.ops.rows_write import ring_positions_last  # as above
+
+    last = ring_positions_last(c.shape, val.shape[-1])
+    G, W = c.shape[2], c.shape[4 if last else 3]
     M = val.shape[0]
     lane = ring_positions(pos + n - 1, W) - pos                       # [W]
     hit = (lane[:, None] == jnp.arange(M)) & (lane >= 0)[:, None]
-    moved = jnp.einsum("wm,mgd->gwd", hit.astype(val.dtype), val,
+    moved = jnp.einsum("wm,mgd->gdw" if last else "wm,mgd->gwd",
+                       hit.astype(val.dtype), val,
                        precision=lax.Precision.HIGHEST)
     at = (l, slot, 0, 0, 0)
-    old = lax.dynamic_slice(c, at, (1, 1, G, W, d))
-    new = jnp.where(hit.any(axis=-1)[:, None], moved, old[0, 0])
+    old = lax.dynamic_slice(c, at, (1, 1, G) + c.shape[3:])
+    written = hit.any(axis=-1)
+    new = jnp.where(written if last else written[:, None], moved, old[0, 0])
     return lax.dynamic_update_slice(c, new[None, None], at)

@@ -50,6 +50,24 @@ every row once pos >= W - 1. That is the rows' own mask with the position
 held at W - 1, and `slot_rows.plan` holds it there: the ring takes no line
 of its own in the kernel. W and d may be equal (128 and 128), so a ring
 says that it is one; it cannot be read off the shape.
+
+Keys and values need not be alike (MiMo: a key has 192 lanes and a value
+128, `[.., T, 192]` in bf16 is tiled to 256 lanes, and a global layer holds
+4 key-value heads where a sliding layer's rings hold 8). The keys then hold
+the positions on the lanes, `[layers, slots, G, 192, T]`, 192 on the
+sublanes, and the values a position a row, `[layers, slots, G, T, 128]`:
+both products as they lie, `_lanes_body`'s scores and `_weigh`'s weighted
+values, and the result `[G, R, 128]`, the values' width. Which way round
+each leaf lies is `rows_write.leaves_lie`'s to say; such leaves take
+`slot_rows.BLOCK` like rows (their lanes stand at thousands of positions).
+
+A `sink` [G, R] (float32: a learned score a query head, MiMo's sliding
+layers) takes part in the softmax's denominator and weighs no value:
+
+    p_t = exp(s_t - m) / (exp(b - m) + sum_t' exp(s_t' - m)),  m = max(b, s)
+
+which is the fold started at a running maximum of b, a sum of 1 and an empty
+accumulator (`slot_rows.Kernel.start`): no line of the body knows of it.
 """
 
 from __future__ import annotations
@@ -61,7 +79,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import slot_rows
-from ray_tpu.ops.rows_write import positions_last
+from ray_tpu.ops.rows_write import leaves_lie
 from ray_tpu.ops.slot_rows import MASKED, Leaf, read_positions  # noqa: F401
 
 # `slot_rows.BLOCK` for these leaves (G heads of both: 4 MB of bf16 at
@@ -134,10 +152,13 @@ def block_last(T: int) -> int:
     return most if most == T or not whole else whole[-1]
 
 
-def _lanes_body(blk, q_ref, k_ref, v_ref, *own, two: bool, scale: float):
-    """All G heads at once, the positions on the lanes. With the slot's own
-    row (`own`: refs of its k and v, [1, G, 1, d]) the block that holds `pos`
-    takes the row's score in column `pos`, and `_weigh_lanes` its values."""
+def _lanes_body(blk, q_ref, k_ref, v_ref, *own, two: bool, scale: float,
+                values_last: bool = True):
+    """All G heads at once, the keys' positions on the lanes; the values'
+    too, or (not `values_last`) the values a position a row, [G, block, n].
+    With the slot's own row (`own`: refs of its k and v, [1, G, 1, d]) the
+    block that holds `pos` takes the row's score in column `pos`, and
+    `_weigh_lanes` its values."""
     q, k, v = q_ref[0], k_ref[0, 0], v_ref[0, 0]      # [G,R,d], [G,d,block]
     q = _pieces(q, k.dtype, two)
     s = _halves_added(jnp.einsum(
@@ -151,14 +172,18 @@ def _lanes_body(blk, q_ref, k_ref, v_ref, *own, two: bool, scale: float):
             q.astype(jnp.float32) * k_own.astype(jnp.float32),
             axis=-1, keepdims=True), two), s)
     s = jnp.where(at <= blk.pos, s * scale, MASKED)          # [G, R, block]
-    yield ..., s, (slot_rows.zero_past_end(v, blk.held(v.shape, 2)), mine)
+    yield ..., s, (slot_rows.zero_past_end(v, blk.held(
+        v.shape, 2 if values_last else 1)), mine)
 
 
-def _weigh_lanes(p, values, *, two: bool):
-    """p [G, R, block] against v [G, d, block] -> [G, R, d]; the slot's own
-    row takes its probability apart from the block's, whose lane `pos`
-    holds whatever the cache held."""
+def _weigh_lanes(p, values, *, two: bool, values_last: bool = True):
+    """p [G, R, block] against v [G, d, block] (or, not `values_last`,
+    [G, block, d]) -> [G, R, d]; the slot's own row takes its probability
+    apart from the block's, whose lane `pos` holds whatever the cache
+    held."""
     v, mine = values
+    if not values_last:
+        return _weigh(p, v, two=two)
 
     def product(p):
         return _halves_added(jnp.einsum(
@@ -175,23 +200,33 @@ def _weigh_lanes(p, values, *, two: bool):
 
 
 def rows_kernel(q, ck, cv, scale, name="gqa_attend", *, last: bool = False,
-                own=()) -> slot_rows.Kernel:
+                own=(), values_last: bool | None = None,
+                sink=None) -> slot_rows.Kernel:
     """This kernel on `slot_rows.attend`'s grid: a q that is not of the
     rows' dtype, and its probabilities, as two pieces; `last`: the leaves
-    hold the positions on the lanes, and may lack the slot's `own` row."""
+    hold the positions on the lanes, and may lack the slot's `own` row;
+    `values_last` where the values lie otherwise than the keys; `sink`
+    [G, R]: the fold's start."""
     two = q.dtype != ck.dtype
     assert last or not own, "rows by head are written before they are read"
-    body, weigh, at = ((_lanes_body, _weigh_lanes, 4) if last
-                       else (_block_body, _weigh, 3))
+    if values_last is None:
+        values_last = last
+    assert last or not values_last, "values on the lanes lie beside keys so"
+    # `_lanes_body` alone knows of values that lie otherwise than its keys
+    way = {"values_last": False} if last and not values_last else {}
+    body, weigh = ((_lanes_body, _weigh_lanes) if last
+                   else (_block_body, _weigh))
     return slot_rows.Kernel(
-        name, functools.partial(body, two=two, scale=float(scale)),
-        (q, Leaf(ck, at), Leaf(cv, at), *(row[:, :, None] for row in own)),
-        q.shape[1:],
-        functools.partial(weigh, two=two))
+        name, functools.partial(body, two=two, scale=float(scale), **way),
+        (q, Leaf(ck, 4 if last else 3), Leaf(cv, 4 if values_last else 3),
+         *(row[:, :, None] for row in own)),
+        # the result is as wide as a value
+        q.shape[1:-1] + (cv.shape[3 if values_last else 4],),
+        functools.partial(weigh, two=two, **way), sink)
 
 
 def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
-               scale: float, *, ring: bool = False, own=(),
+               scale: float, *, ring: bool = False, own=(), sink=None,
                kernel: bool | None = None, interpret: bool = False):
     """Every slot's one token against its own rows of layer `layer`.
 
@@ -204,29 +239,37 @@ def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
     With `ring` the leaves are rings [L, B, G, W, d] that hold position
     pos[b] already, and slot b attends the rows that are the sequence's,
     positions max(0, pos[b] - W + 1) .. pos[b].
+    Keys [L, B, G, d, T] beside values [L, B, G, T, n] (rings: [.., d, W]
+    and [.., W, n]) give [B, G, R, n]. With `sink` [G, R] every query's
+    softmax has that score beside its rows', which weighs no value.
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which reads a live slot's rows once and to its position;
     elsewhere `lm.gqa_attend` over the whole layer (a ring:
     `lm.gqa_attend_band` over the positions its rows hold)."""
-    last = not ring and positions_last(ck.shape, q.shape[-1])
+    last, values_last = leaves_lie(ck.shape, cv.shape, q.shape[-1], ring)
     if slot_rows.use_kernel(kernel, interpret):
         name = "swa_attend" if ring else "gqa_attend"
         return slot_rows.attend(
-            rows_kernel(q, ck, cv, scale, name, last=last, own=own), layer,
-            pos, live, block=block_last(ck.shape[4]) if last else None,
+            rows_kernel(q, ck, cv, scale, name, last=last, own=own,
+                        values_last=values_last, sink=sink), layer,
+            pos, live, block=block_last(ck.shape[4]) if values_last else None,
             interpret=interpret)
     from ray_tpu.models import lm       # not at the top: `models` imports us
 
     k, v = (lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
             for c in (ck, cv))
     at = jnp.broadcast_to(pos[:, None, None], q.shape[:3])
+    if sink is not None:
+        sink = jnp.broadcast_to(sink, q.shape[:3])
     if ring:
-        W = ck.shape[3]
+        W = cv.shape[3]
+        if last:
+            k = jnp.swapaxes(k, -1, -2)
         return lm.gqa_attend_band(q, k, v, lm.ring_positions(pos, W)[:, None],
-                                  at, W, scale, ck.dtype)
+                                  at, W, scale, ck.dtype, sink=sink)
     if own:
         # the rows as they will lie once the step has written them
         hit = jnp.arange(k.shape[3]) == pos[:, None, None, None]
         k, v = (jnp.where(hit, row[..., None], c)
                 for row, c in zip(own, (k, v)))
-    return lm.gqa_attend(q, k, v, at, scale, ck.dtype)
+    return lm.gqa_attend(q, k, v, at, scale, ck.dtype, sink=sink)

@@ -23,7 +23,10 @@ compiler re-lay both leaves round every step, T before G: 2.1 GB each.)
 A ring leaf (`ring=True`: `[layers, slots, G, W, d]`, the last W positions
 of a sliding-window layer: `models/lm.py`, "a sliding window's rows") is a
 leaf of rows whose every slot is W positions long and takes position p at
-row p mod W. W may equal d, so a ring says that it is one.
+row p mod W. W may equal d, so a ring says that it is one. A ring whose d is
+not W may hold the positions on the lanes like any leaf, `[.., d, W]`
+(MiMo's keys of 192 lanes: `[.., W, 192]` in bf16 is tiled to 256 lanes, a
+third more bytes), and that is read off its shape (`ring_positions_last`).
 """
 
 from __future__ import annotations
@@ -160,18 +163,38 @@ def positions_last(rows_shape, d: int) -> bool:
     return rows_shape[-2] == d
 
 
+def ring_positions_last(ring_shape, d: int) -> bool:
+    """`positions_last` for a ring: a square ring [.., W, d = W] is rows."""
+    return (ring_shape[-1] != ring_shape[-2]
+            and positions_last(ring_shape, d))
+
+
+def leaves_lie(k_shape, v_shape, d: int, ring: bool = False) -> tuple:
+    """(whether the keys' leaf holds the positions on the lanes, whether the
+    values' does) for a q of d lanes. The values lie as the keys do where
+    the two leaves are alike; a values' leaf of another shape ([.., T, n]
+    beside keys [.., d, T]: MiMo's keys of 192 lanes and values of 128) and
+    a ring's values are rows."""
+    keys = ring_positions_last(k_shape, d) if ring else positions_last(
+        k_shape, d)
+    return keys, keys and not ring and tuple(v_shape) == tuple(k_shape)
+
+
 def rows_write(c: jax.Array, layer, val, pos, on, *, ring: bool = False,
                kernel: bool | None = None, interpret: bool = False):
     """Layer `layer` of the leaf c [L, B, G, d, T] (or [L, B, G, T, d])
     takes val [B, G, d] at position pos[b] of every slot that is `on` [B];
     nothing else changes. With `layer` None every layer of c [L, B, G, d, T]
     takes its own row, val [L, B, G, d], in one call. With `ring` c is rings
-    [L, B, G, W, d] and the row is pos[b] mod W.
+    [L, B, G, W, d] (or, d not W, [L, B, G, d, W]) and the row is pos[b] mod
+    W.
     On the TPU (or with `interpret`, or `kernel=True`) through the Pallas
     kernel, which writes the leaf in place; elsewhere through plain XLA."""
     if ring:
-        pos = pos % c.shape[3]
-    last = not ring and positions_last(c.shape, val.shape[-1])
+        last = ring_positions_last(c.shape, val.shape[-1])
+        pos = pos % c.shape[4 if last else 3]
+    else:
+        last = positions_last(c.shape, val.shape[-1])
     if layer is None:
         assert last, c.shape
         if use_kernel(kernel, interpret):

@@ -144,26 +144,38 @@ class Kernel(NamedTuple):
     heads it holds, `(at, scores, values)`: the index of the head's part of
     the accumulators (`...`: all), the block's scores `[.., Q, block]`
     masked past the slot's position, and the values
-    `weigh(probabilities, values)` takes."""
+    `weigh(probabilities, values)` takes. `start`, where the softmax has a
+    sink (a learned score a head that takes probability and weighs no
+    value: MiMo's sliding layers): float32 `[.., Q]`, every slot's running
+    maximum before its first block, beside a sum of 1 and an empty
+    accumulator; without one the fold starts empty."""
     name: str
     body: Callable
     inputs: tuple
     acc: tuple
     weigh: Callable = weigh
+    start: jax.Array | None = None
 
 
 def _kernel(layer_ref, src_ref, first_ref, last_ref, pos_ref, *refs,
-            body: Callable, weigh: Callable, block: int, T: int):
+            body: Callable, weigh: Callable, block: int, T: int,
+            sink: bool):
     """One block of one slot's rows of one layer."""
     del layer_ref, src_ref, first_ref, last_ref
     *refs, o_ref, m_ref, l_ref, acc_ref = refs
+    if sink:
+        *refs, start_ref = refs
     slot, j = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[slot]                               # -1: the slot is dead
 
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, MASKED)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink:
+            m_ref[...] = start_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(j * block <= pos)
@@ -217,19 +229,26 @@ def attend(kernel: Kernel, layer, pos, live, *, block: int | None = None,
              if isinstance(x, Leaf))
     n = block or block_of(T)
     out = jax.ShapeDtypeStruct((pos.shape[0],) + kernel.acc, jnp.float32)
-    sums = pltpu.VMEM(kernel.acc[:-1] + (1,), jnp.float32)
+    sums_shape = kernel.acc[:-1] + (1,)
+    sums = pltpu.VMEM(sums_shape, jnp.float32)
+    start = () if kernel.start is None else (
+        kernel.start.astype(jnp.float32).reshape(sums_shape),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5, grid=(out.shape[0], -(-T // n)),
-        in_specs=[_spec(x, n) for x in kernel.inputs],
+        # the start is the same for every slot and block: fetched once
+        in_specs=[_spec(x, n) for x in kernel.inputs] + [
+            pl.BlockSpec(sums_shape, lambda *_: (0,) * len(sums_shape))
+        ] * len(start),
         out_specs=_spec(out, n),
         scratch_shapes=[sums, sums, pltpu.VMEM(kernel.acc, jnp.float32)])
     return pl.pallas_call(
         functools.partial(_kernel, body=kernel.body, weigh=kernel.weigh,
-                          block=n, T=T),
+                          block=n, T=T, sink=bool(start)),
         grid_spec=grid_spec, out_shape=out,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name=kernel.name, interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), *plan(pos, live, T, n),
-      *(x.array if isinstance(x, Leaf) else x for x in kernel.inputs))
+      *(x.array if isinstance(x, Leaf) else x for x in kernel.inputs),
+      *start)

@@ -1307,6 +1307,12 @@ class LLMEngine:
                 "chunk_lanes_packed": self.chunk_lanes_packed,
                 "tokens_prefilled": self.tokens_prefilled,
                 "positions_attended": self.positions_attended,
+                # live positions over the rows the slots hold, a step: what
+                # fixed slots of `max_seq_len` rows leave empty (cumulative;
+                # a window's is the two counts' differences)
+                **({"rows_live_pct": 100.0 * self.positions_attended / (
+                    max(self.engine_steps, 1) * self.max_batch
+                    * self.max_seq_len)} if self.kv_bytes_per_token else {}),
                 **({"positions_read": self.positions_read}
                    if self._rows_read_block else {}),
                 "prefix_imports": self.prefix_imports,

@@ -495,3 +495,141 @@ def test_a_leaf_by_the_lane_whose_last_block_hangs_over(monkeypatch):
     got = op.gqa_attend(q, ck, cv, 0, pos, live, 0.125, interpret=True)
     want = op.gqa_attend(q, ck, cv, 0, pos, live, 0.125, kernel=False)
     np.testing.assert_allclose(got, want, **TOLERANCE[BF16])
+
+
+# ---------------------------------------------------------------------------
+# Keys of 192 lanes beside values of 128, 16 queries a head, a sink
+# (MiMo-V2: the keys hold the positions on the lanes, the values a position a
+# row; `rows_write.leaves_lie`)
+# ---------------------------------------------------------------------------
+
+DK, DV = 192, 128
+
+
+def _mimo_operands(B, T, heads, per, seed=0, L=2, ring=False):
+    """q [B, G, R, 192] float32, keys [L, B, G, 192, T], values
+    [L, B, G, T, 128] in bf16, and a sink a query head [G, R]."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (3 * jax.random.normal(ks[0], (B, heads, per, DK), F32),
+            jax.random.normal(ks[1], (L, B, heads, DK, T), F32).astype(BF16),
+            jax.random.normal(ks[2], (L, B, heads, T, DV), F32).astype(BF16),
+            2.0 + jax.random.normal(ks[3], (heads, per), F32))
+
+
+def _loop(q, ck, cv, layer, pos, scale, sink=None, window=None):
+    """A slot, a head and a query at a time, in float64: the rows before and
+    at `pos` (a ring: the last `window`, wherever they lie)."""
+    q, ck, cv = (np.asarray(a, np.float64) for a in (q, ck, cv))
+    B, G, R, _ = q.shape
+    out = np.zeros((B, G, R, cv.shape[-1]))
+    for b in range(B):
+        if window is None:
+            seen = np.arange(pos[b] + 1)
+        else:       # position p lies at p mod W
+            seen = np.arange(max(0, pos[b] - window + 1), pos[b] + 1) % window
+        for g in range(G):
+            k, v = ck[layer, b, g][:, seen], cv[layer, b, g][seen]
+            for r in range(R):
+                s = q[b, g, r] @ k * scale
+                top = s.max() if sink is None else max(s.max(), sink[g, r])
+                w = np.exp(s - top)
+                total = w.sum() + (0 if sink is None
+                                   else np.exp(sink[g, r] - top))
+                out[b, g, r] = (w / total) @ v
+    return out
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+@pytest.mark.parametrize("pos,live", [
+    ([0, 1, 2], [True] * 3), ([BLOCK - 1, BLOCK, 3 * BLOCK - 1], [True] * 3),
+    ([2 * BLOCK + 5, 17, 3 * BLOCK - 1], [True, False, True]),
+    ([40, 300, 7], [False, False, True])],
+    ids=["first", "ragged-at-the-edges", "a-dead-slot", "dead-slots-first"])
+def test_keys_on_the_lanes_beside_values_by_the_row_at_16_queries_a_head(
+        monkeypatch, pos, live, sink):
+    """A global layer's shape (4 key-value heads, 16 queries each, 192 and
+    128 lanes), interpreted, against `lm.gqa_attend` and a loop: ragged
+    positions, dead slots, and a sink (which a global layer does not have:
+    the fold's start is the kernel's, whoever asks)."""
+    monkeypatch.setattr(slot_rows, "BLOCK", BLOCK)
+    q, ck, cv, b = _mimo_operands(3, 3 * BLOCK, 4, 16)
+    b = b if sink else None
+    scale = DK ** -0.5
+    args = (q, ck, cv, jnp.int32(1), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(live))
+    got = np.asarray(jax.jit(lambda *a: op.gqa_attend(
+        *a, scale, sink=b, interpret=True))(*args))
+    plain = np.asarray(jax.jit(lambda *a: op.gqa_attend(
+        *a, scale, sink=b, kernel=False))(*args))
+    assert got.shape == plain.shape == (3, 4, 16, DV)
+    on = np.asarray(live)
+    np.testing.assert_allclose(got[on], plain[on], **TOLERANCE[F32])
+    want = _loop(q, ck, cv, 1, pos, scale, None if b is None
+                 else np.asarray(b))
+    np.testing.assert_allclose(got[on], want[on], rtol=0, atol=2e-4)
+    assert np.isfinite(got).all() and not got[~on].any()
+    kernel = op.rows_kernel(q, ck, cv, scale, last=True, values_last=False)
+    assert kernel.body.func is op._lanes_body and kernel.acc == (4, 16, DV)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+def test_a_ring_of_keys_on_the_lanes_with_a_sink_a_head(sink):
+    """A sliding layer's rings (8 key-value heads, 8 queries each; keys
+    [.., 192, 128], values [.., 128, 128]) under `swa_attend`: a ring that
+    fills (rows 0 .. pos), one just full, one that has wrapped, and a dead
+    slot; the sink takes its share and weighs no value."""
+    W = 128
+    q, wk, wv, b = _mimo_operands(4, W, 8, 8, seed=1, L=3)
+    b = b if sink else None
+    pos, live = [5, W - 1, 3 * W + 17, 40], [True, True, True, False]
+    scale = DK ** -0.5
+    args = (q, wk, wv, jnp.int32(2), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(live))
+    got = np.asarray(jax.jit(lambda *a: op.gqa_attend(
+        *a, scale, ring=True, sink=b, interpret=True))(*args))
+    plain = np.asarray(jax.jit(lambda *a: op.gqa_attend(
+        *a, scale, ring=True, sink=b, kernel=False))(*args))
+    on = np.asarray(live)
+    np.testing.assert_allclose(got[on], plain[on], **TOLERANCE[F32])
+    want = _loop(q, wk, wv, 2, pos, scale,
+                 None if b is None else np.asarray(b), window=W)
+    np.testing.assert_allclose(got[on], want[on], rtol=0, atol=2e-4)
+    assert not got[~on].any()
+    if sink:
+        # the sink takes probability: every weighted sum is the smaller
+        without = np.asarray(op.gqa_attend(*args, scale, ring=True,
+                                           interpret=True))
+        assert np.abs(without[on] - got[on]).max() > 1e-2
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["rows", "ring"])
+def test_rows_write_takes_a_key_of_192_lanes_along_the_lanes(ring):
+    """`ops/rows_write.py`, interpreted, against its plain form: a key of
+    192 lanes into a leaf [.., 192, T] (a ring [.., 192, 128]: at pos mod
+    128) and a value of 128 into [.., T, 128]; a slot that is not on keeps
+    its tile to the bit."""
+    from ray_tpu.ops.rows_write import leaves_lie, rows_write
+
+    T = 128 if ring else 384
+    _, ck, cv, _ = _mimo_operands(3, T, 4, 1, seed=2)
+    assert leaves_lie(ck.shape, cv.shape, DK, ring) == (True, False)
+    pos = jnp.asarray([5, 300, 127], jnp.int32)
+    on = jnp.asarray([True, True, False])
+    for leaf, d, axis in ((ck, DK, 4), (cv, DV, 3)):
+        val = jax.random.normal(jax.random.key(d), (3, 4, d), F32).astype(
+            BF16)
+        got = rows_write(leaf, jnp.int32(1), val, pos, on, ring=ring,
+                         interpret=True)
+        want = rows_write(leaf, jnp.int32(1), val, pos, on, ring=ring,
+                          kernel=False)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        at = np.asarray(pos) % T
+        moved = np.moveaxis(np.asarray(got, np.float32), axis, 3)
+        for slot in (0, 1):
+            np.testing.assert_array_equal(
+                moved[1, slot, :, at[slot]], np.asarray(val[slot],
+                                                        np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(got[:, 2], np.float32),
+            np.asarray(leaf[:, 2], np.float32))
