@@ -57,9 +57,11 @@ layers x 8 slots x 25 heads x 64 x 1,024 positions, one bf16 query a head
 with the step's own row handed over, the slots live at 16-320 (the decode
 cell's positions); `gpt2-chat`, the same with 1 of 8 slots live at 300-1,000
 (the chat cell's decode steps); the plain form is `models/gpt2.py`'s own
-lines (`_decode_attend` where no kernel runs). `granite`, a record for
-ROADMAP S19a: 4 layers x 48 slots x 8 heads x 64 x 8,192 positions, four
-bf16 queries a head, live at 3,100-7,200, against `lm.gqa_attend`. Measured
+lines (`_decode_attend` where no kernel runs). `granite`, its cell's
+first lanes since PR 63 (`granite._attention_first`): 4 layers x 48 slots x
+8 heads x 64 x 8,192 positions, four bf16 queries a head, live at
+3,100-7,200, against `lm.gqa_attend`. Such shapes get a last row, `rule`:
+the op's own entry as the models call it, at `block_last(T)`. Measured
 on a v5e (PR 61, 480 calls in one program; us a call, the share of the
 attended rows' bytes at the HBM's peak, positions read over attended):
 
@@ -70,6 +72,7 @@ attended rows' bytes at the HBM's peak, positions read over attended):
     512     51.0  14.7%  4.28   17.7  39.6%  1.14      771  77.6%  1.06
     1,024   76.2   9.8%  8.57   17.2  40.7%  1.14      802  74.6%  1.12
     2,048                                              845  70.9%  1.20
+    rule (PR 63: 512)                                  772  77.5%  1.06
 
 A grid step that works moves its rows at 85-95% of the HBM's pace; what
 keeps GPT-2's calls at a fifth to two fifths of their roofline is a call's
@@ -77,7 +80,12 @@ own ~7 us, the grid steps that do nothing (0.14 us each) and the half block
 past a position, as much again as the ~170 positions before it. The values
 lie within 1.5e-3 of the plain form's (r.m.s. 0.2: one bf16 piece, the
 probabilities rounded before the division where the plain form rounds
-after). `BLOCK_LAST` is 128, GPT-2's; granite's adoption brings its own.
+after). `BLOCK_LAST` is 128, GPT-2's, and `block_last` follows the leaf's
+length above it, a 16th: granite's 512 (PR 63's run of the granite column
+read 1,114 | 936 / 772 / 802 at 256 / 512 / 1,024 and 772 under the rule:
+the least is 0.598 ms, 239,340 attended positions of 2,048 B, so the kernel
+alone stands at 77.5% of its rows' bytes; in the cell's decode step the call
+takes 730 us at ~39 live lanes, 66.6%).
 
 MiMo's cell (PR 62): `mimo`, 2 global layers x 64 slots x 4 heads x 24,576
 positions, keys [.., 192, T] beside values [.., T, 128], 16 float32 queries
@@ -196,6 +204,9 @@ def main() -> int:
         rows, want = {}, None
         forms = [("plain", None)] + [(b, int(b)) for b in (
             [T] if ring else args.blocks.split(","))]
+        if last and Dv == D and not ring:
+            # the op's own entry, as the models call it: `block_last`'s block
+            forms.append(("rule", op.block_last(T)))
         for label, block in forms:
             if block is None and own:
                 fn = functools.partial(gpt2_plain, own=own)
@@ -204,6 +215,8 @@ def main() -> int:
                                        sink=sink)
             elif ring:
                 fn = functools.partial(op.gqa_attend, ring=True, sink=sink)
+            elif label == "rule":
+                fn = functools.partial(op.gqa_attend, own=own)
             else:
                 def fn(q, ck, cv, layer, pos, live, scale, block=block,
                        last=last, own=own, sink=sink, Dv=Dv, D=D):
@@ -240,6 +253,7 @@ def main() -> int:
             read = (B * T if block is None else int(jnp.sum(jnp.where(
                 live, jnp.minimum((pos // block + 1) * block, T), 0))))
             rows[label] = {
+                "block": block,
                 "ms_a_call": seconds * 1e3,
                 "roofline_pct": 100 * least / seconds,
                 "read_over_attended": read / attended,

@@ -23,7 +23,9 @@ ragged last blocks at the kernels' own block, both q dtypes of
 `gqa_attend`; an active and an inactive slot and the layer not named of
 the three state kernels, one and eight groups of `ssm_update`, 2 and 64
 heads of `kda_update`. A PR that changes one kernel shows with it which it
-left alone (PR 57: 28 arrays, 0 differ)."""
+left alone (PR 57: 28 arrays, 0 differ; PR 63: 30 with `gqa_attend`'s
+leaves by the lane at 1,024 and 8,192 positions, of which the longer
+differs, as it should)."""
 import hashlib
 import importlib
 import os
@@ -101,6 +103,18 @@ def kernels(root: str, out_path: str) -> None:
         out[f"dsa_attend/T{T}/read_positions"] = np.asarray(
             op("dsa_attend").read_positions(pos, live, T, 256,
                                             interpret=True))
+
+    # leaves with the positions on the lanes (GPT-2's length and granite's:
+    # the block follows the length since PR 63, so granite's sums go a block
+    # of 512 at a time and differ from a parent's of 128 in the last bits)
+    for T in (1024, 8192):
+        pos = jnp.asarray([300, T - 1, 0, 9, 640], jnp.int32)
+        live = jnp.asarray([False, True, True, False, True])
+        got = op("gqa_attend").gqa_attend(
+            normal(11, (5, 2, 4, 64), bf), normal(12, (2, 5, 2, 64, T), bf),
+            normal(13, (2, 5, 2, 64, T), bf), jnp.int32(1), pos, live,
+            0.125, interpret=True)
+        out[f"gqa_attend/lanes/T{T}"] = np.asarray(got)[np.asarray(live)]
 
     def state_kernel(key, fn, state, *args):
         """The leaves whole (an inactive slot's and the other layer's among

@@ -477,9 +477,10 @@ def rows_read_block(cache, interpret: bool = False) -> int:
     T, Dh = cache["k"].shape[3:]
     if not _rows_kernels(T, Dh, interpret):
         return T
-    from ray_tpu.ops.gqa_attend import block_last
+    from ray_tpu.ops.gqa_attend import read_block
 
-    return block_last(T)
+    view = cache["k"].shape[:3] + (Dh, T)       # as `_decode_attend` views it
+    return read_block(view, view, Dh, interpret=interpret)
 
 
 def _decode_write(c, rows, pos, on, interpret: bool = False):

@@ -45,9 +45,13 @@ through the SSD form, with a_i = sum_{s<m<=i} dt_m A:
 a slot at a time and only for the slots that prefill (`models/lm.py`, "The
 lanes of a chunk", has the loop and the contract), as attention's scores
 for a whole chunk are ([8, 4 M, T] floats a slot; for every lane of every
-slot they would be [slots, 32, C, T]). A lane past a slot's length has
-dt = 0: it decays nothing and adds nothing; a slot with no valid lane keeps
-its rows, its state and its window bit for bit, in both programs.
+slot they would be [slots, 32, C, T]). Attention has the same two forms: a
+first lane reads its slot's rows once and to its own position through
+`ops/gqa_attend.py` (a kernel on the chip: PERF.md PR 63), a chunk's
+further lanes all T of their slot in plain XLA. A lane past a slot's
+length has dt = 0: it decays nothing and adds nothing; a slot with no valid
+lane keeps its rows, its state and its window bit for bit, in both
+programs.
 
 The weights exist only in the dtype the replica holds them, a layer at a
 time, as `models/brumby.py` makes its own: one stack a kind of layer. The
@@ -73,6 +77,7 @@ from jax import lax
 
 from ray_tpu.models import lm, mamba2
 from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.gqa_attend import gqa_attend, read_block
 from ray_tpu.ops.rows_write import rows_write
 
 Params = Any
@@ -318,25 +323,33 @@ def _attn_out(x, y, bp, cfg: GraniteConfig):
     return x + cfg.residual_multiplier * o
 
 
-def _attend(q, k, v, at, cfg: GraniteConfig):
-    """`lm.gqa_attend` (every grouped-head family's) at the model's
-    multiplier."""
-    return lm.gqa_attend(q, k, v, at, cfg.attention_multiplier, cfg.dtype)
+def rows_read_block(cache) -> int:
+    """The positions of a slot's rows that a first lane's attention reads at
+    a time, as `ops/gqa_attend.read_block` has them for these leaves. What
+    `serve/llm.py` counts `positions_read` by; it counts a slot of a chunk
+    step at all T, which a prefilling slot's further lanes read (its first
+    lane its block besides) and a decode lane riding along does not: too
+    many where decode lanes fill the chunk steps (PERF.md PR 63 has both
+    ratios)."""
+    return read_block(cache["k"].shape, cache["v"].shape,
+                      cache["k"].shape[3])
 
 
 def _attention_first(x, bp, cfg: GraniteConfig, cache, l, pos, on):
     """One grouped-head attention mixer over every slot's first lane, x
-    [B,1,D], at position pos [B]: -> (x, cache)."""
-    B = x.shape[0]
-    G, R, d = cfg.n_kv_head, cfg.queries_per_kv, cfg.head_dim
+    [B,1,D], at position pos [B]: -> (x, cache). A slot that is `on` reads
+    its rows once and to its own position (`ops/gqa_attend.py`: on the chip
+    a kernel, elsewhere `lm.gqa_attend` over all T of every slot); one that
+    is not reads nothing where the kernel runs, and its lane, zeros there
+    and a value nobody reads in the plain form, meets no other slot's."""
     with jax.named_scope("attn"):
         q, k, v = _qkv(x, bp, cfg)
         with jax.named_scope("kv_update"):
             ck = rows_write(cache["k"], l, k[:, 0], pos, on)
             cv = rows_write(cache["v"], l, v[:, 0], pos, on)
         with jax.named_scope("gqa_attend"):
-            y = _attend(q[:, 0], ck[l], cv[l],
-                        jnp.broadcast_to(pos[:, None, None], (B, G, R)), cfg)
+            y = gqa_attend(q[:, 0], ck, cv, l, pos, on,
+                           cfg.attention_multiplier)
         x = _attn_out(x, y, bp, cfg)
     return x, {**cache, "k": ck, "v": cv}
 
@@ -360,7 +373,8 @@ def _attention_further(x, bp, cfg: GraniteConfig, cache, l, slot, pos, ok):
             qs = jnp.transpose(q[0], (1, 2, 0, 3)).reshape(G, R * M, d)
             at = jnp.broadcast_to(pos + jnp.tile(jnp.arange(M), R),
                                   (G, R * M))
-            y = _attend(qs, *rows, at, cfg)
+            y = lm.gqa_attend(qs, *rows, at, cfg.attention_multiplier,
+                              cfg.dtype)
             y = jnp.transpose(y.reshape(G, R, M, d), (2, 0, 1, 3))[None]
         x = _attn_out(x, y, bp, cfg)
     return x, {**cache, "k": ck, "v": cv}

@@ -34,7 +34,8 @@ go through a body of their own, `_lanes_body`, on the same grid: a block's
 scores are `q [G, R, d] x k [G, d, block]` as they lie, and the weighted
 values contract the last axes of `p [G, R, block]` and `v [G, d, block]`.
 Which body a call takes is read off the leaf's shape
-(`rows_write.positions_last`). Their block is this file's, `BLOCK_LAST`.
+(`rows_write.positions_last`). Their block is this file's, `block_last`,
+which follows the leaf's length.
 A step that has not written its new row yet (GPT-2's decode step writes
 every layer's after its loop) hands the row over as `own = (k, v)`, each
 `[B, G, d]`: the slot attends the leaf's rows before `pos` and its own row
@@ -125,31 +126,64 @@ def _weigh(p, v, *, two: bool):
 
 
 # `slot_rows.BLOCK` for leaves with the positions on the lanes, on the v5e
-# (`benchmarks/gqa_attend_blocks.py --shapes gpt2,gpt2-chat,granite`, PR 61;
-# us a call = a layer, the plain form first, then 128 / 256 / 512 / 1,024
-# positions a grid step):
+# (`benchmarks/gqa_attend_blocks.py --shapes gpt2,gpt2-chat,granite`, PRs 61
+# and 63; us a call = a layer, the plain form first, then 128 / 256 / 512 /
+# 1,024 positions a grid step; in brackets the attended rows' bytes at the
+# HBM's peak over the time):
 #   GPT-2 XL, 8 slots x 25 heads x 64 x 1,024, one bf16 q a head, own row:
-#     8 live at 16-320 (the decode cell)  79.2 | 33.6  39.5  51.0  76.2
-#     1 live at 300-1,000 (the chat cell)  79.0 | 22.8  20.4  17.7  17.2
+#     8 live at 16-320 (the decode cell)  79.2 | 33.6 (22%)  39.5  51.0  76.2
+#     1 live at 300-1,000 (the chat cell)  79.0 | 22.8  20.4  17.7  17.2 (41%)
 #   granite, 48 slots x 8 heads x 64 x 8,192, four bf16 q a head, live at
-#   3,100-7,200:                          1,113 | 1,374   937   771   802
+#   3,100-7,200 (0.60 ms of rows): 1,114 (54%) | 1,374  936  772 (77.5%)  802
+#   and as `block_last` runs it, 512:                        772 (77.5%)
 # A grid step that works moves its rows at the HBM's pace (1.2-1.3 us at 128
 # positions of 6.4 KB, 8.8 at 1,024: 0.82 and 6.5 MB), one that does not
 # costs 0.14 us and a call ~7 us of its own. GPT-2's lanes stand at a few
 # hundred positions of 1,024 and what a block costs them is the half block
 # read past a position: 128. granite's stand at thousands of 8,192 and pay
-# for 3,072 grid steps a call at 128: its adoption (ROADMAP S19a) brings its
-# own length, 512 by this table
+# for 3,072 grid steps a call at 128. So the block follows the leaf's
+# length: `BLOCK_LAST` positions at least, and above that `STEPS_LAST` grid
+# steps a slot at most, which is 128 at 1,024 and 512 at 8,192, both rows'
+# best (a slot then reads a 32nd of the leaf past its position on average:
+# granite's 5.7% of what it attends, a quarter of the 22.5% its kernel
+# stands under its rows' bytes; the rest is the grid's and the call's),
+# and never beyond `slot_rows.BLOCK`, the longest block any table measured
+# (granite's whole 131,072 positions: a 16th would not fit VMEM). Timed at
+# those two lengths alone: at any other the rule is a line through them that
+# the TPU's compiler takes (`tests/test_tpu_compile.py`: 4,096, 16,384 and
+# 131,072) and no run has timed. In granite's decode step the call takes
+# 730 us at ~39 live lanes, 66.6% of the attended rows' bytes (PERF.md PR 63)
 BLOCK_LAST = 128
+STEPS_LAST = 16
 
 
 def block_last(T: int) -> int:
-    """`slot_rows.block_of` under `BLOCK_LAST`: the positions lie along the
-    lanes, so a block is whole lane tiles or the whole leaf."""
-    most = min(T, BLOCK_LAST)
+    """`slot_rows.block_of` for leaves with the positions on the lanes,
+    under `BLOCK_LAST` and `STEPS_LAST`: a block is whole lane tiles that
+    divide T, or the whole leaf."""
+    most = min(T, max(BLOCK_LAST, min(T // STEPS_LAST, slot_rows.BLOCK)))
     whole = [n for n in range(slot_rows.LANES, most + 1, slot_rows.LANES)
              if T % n == 0]
     return most if most == T or not whole else whole[-1]
+
+
+def _block(T: int, values_last: bool) -> int:
+    """The positions a grid step takes of leaves T long: `block_last` where
+    the values lie with the positions on the lanes, `slot_rows.block_of`
+    for rows and rings."""
+    return block_last(T) if values_last else slot_rows.block_of(T)
+
+
+def read_block(ck_shape, cv_shape, d: int, *, kernel: bool | None = None,
+               interpret: bool = False) -> int:
+    """The positions of a slot's rows that `gqa_attend` reads at a time over
+    leaves of these shapes (no rings) for a q of d lanes: its kernel's block
+    where one runs (a live slot's rows to its position rounded up to one),
+    all T in the plain form. What an engine counts `positions_read` by."""
+    values_last = leaves_lie(ck_shape, cv_shape, d)[1]
+    T = cv_shape[4 if values_last else 3]
+    return _block(T, values_last) if slot_rows.use_kernel(
+        kernel, interpret) else T
 
 
 def _lanes_body(blk, q_ref, k_ref, v_ref, *own, two: bool, scale: float,
@@ -252,8 +286,8 @@ def gqa_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, layer, pos, live,
         return slot_rows.attend(
             rows_kernel(q, ck, cv, scale, name, last=last, own=own,
                         values_last=values_last, sink=sink), layer,
-            pos, live, block=block_last(ck.shape[4]) if values_last else None,
-            interpret=interpret)
+            pos, live, block=_block(cv.shape[4 if values_last else 3],
+                                    values_last), interpret=interpret)
     from ray_tpu.models import lm       # not at the top: `models` imports us
 
     k, v = (lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)

@@ -575,7 +575,7 @@ class LLMEngine:
         self.positions_attended = 0
         # positions whose rows the steps read for them, where the family
         # names the block its decode step's attention reads a slot's rows by
-        # (`rows_read_block`: GPT-2; 0 otherwise, and nothing is counted):
+        # (`rows_read_block` of its module; 0 without, and nothing counted):
         # a decode lane's positions rounded up to a block, as
         # `ops/slot_rows.read_positions` has it; all T a lane of a chunk step
         read_block = getattr(model, "rows_read_block", None)

@@ -6,6 +6,8 @@ cache's contract in the engine (state zeroed at placement, rows, state and
 window untouched where inactive, both pooled between two chunk steps, found
 again); the pool of both kinds; and what the family refuses by name."""
 
+import functools
+import importlib
 import os
 import sys
 
@@ -59,7 +61,8 @@ def test_the_tiny_preset_is_the_model_the_reference_is_given():
 
 def engine(compute=F32, chunk=16, **kwargs):
     kwargs.setdefault("kv_blocks", 24)
-    eng = LLMEngine(preset="granite-tiny", max_batch=3, max_seq_len=96,
+    kwargs.setdefault("max_seq_len", 96)
+    eng = LLMEngine(preset="granite-tiny", max_batch=3,
                     seed=SEED, model_overrides=dict(compute),
                     kv_block_size=8, prefill_chunk_size=chunk, **kwargs)
     eng.shutdown()              # the loop: the programs are driven by hand
@@ -221,6 +224,119 @@ def test_an_inactive_lanes_cache_is_bit_identical_after_a_step(program):
         np.testing.assert_array_equal(after[:, 0], before[name][:, 0])
         np.testing.assert_array_equal(after[:, 2], before[name][:, 2])
         assert (after[:, 1] != before[name][:, 1]).any()
+
+
+# ------------------------------ a first lane's attention through the kernel
+
+def _attend_through(monkeypatch, interpret: bool):
+    """`granite.gqa_attend` as the chip runs it (the kernel, interpreted) or
+    as the CPU does (`lm.gqa_attend`): what `slot_state.on_tpu` decides."""
+    op = importlib.import_module("ray_tpu.ops.gqa_attend")
+    how = dict(interpret=True) if interpret else dict(kernel=False)
+    monkeypatch.setattr(granite, "gqa_attend",
+                        functools.partial(op.gqa_attend, **how))
+
+
+@pytest.fixture(scope="module")
+def three_sequences():
+    """An engine of three slots x 256 positions (two blocks of 128 a slot)
+    whose slots hold sequences that have reached positions 152 (the second
+    block), 126 (two short of a block's edge) and 39: (the engine, its
+    cache as numpy, the positions)."""
+    eng = engine(max_seq_len=256)
+    rng = np.random.default_rng(63)
+    pos = []
+    for slot, n in enumerate((150, 124, 37)):
+        through_the_programs(eng, rng.integers(1, 512, n).tolist(), 3,
+                             slot=slot)
+        pos.append(n + 2)
+    return eng, jax.tree.map(np.asarray, eng.cache), np.array(pos, np.int32)
+
+
+# (which slots are live, which slot's rows are NaN where it is dead)
+LIVE = {"all-live": ([1, 1, 1], None),
+        "a-dead-slot-between": ([1, 0, 1], 1),
+        "a-dead-slot-first": ([0, 1, 1], 0),
+        "a-dead-slot-last": ([1, 1, 0], 2)}
+
+
+@pytest.mark.parametrize("live", LIVE)
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_first_lanes_through_the_attention_kernel_are_the_plain_paths(
+        monkeypatch, three_sequences, program, live):
+    """`decode_step` and `prefill_chunk` with every slot's first lane
+    through `ops/gqa_attend.py`'s kernel (interpreted, two blocks of 128):
+    the live slots' logits are the plain path's to the float32 tolerance
+    (the same sums, a block at a time), a slot that is not live keeps its
+    rows, state and window bit for bit, and whatever its rows hold (NaN
+    here) and whatever the kernel returns for it reaches no live lane: the
+    live lanes' logits are the same bits as with the slot's rows sound. In
+    the chunk program slot 0 is a decode lane riding along, slot 1 prefills
+    5 lanes across the block's edge (its further lanes in the plain form)
+    and slot 2 has a chunk of one lane."""
+    eng, start, pos = three_sequences
+    on, dead = np.array(LIVE[live][0], bool), LIVE[live][1]
+    B, C = eng.max_batch, 8
+    tokens = np.random.default_rng(7).integers(1, 512, (B, C)).astype(
+        np.int32)
+
+    def run(interpret, garbage=False):
+        _attend_through(monkeypatch, interpret)
+        cache = {name: jnp.asarray(a) for name, a in start.items()}
+        if garbage:
+            for name in granite.CACHE_TOKEN_AXIS:
+                cache[name] = cache[name].at[:, dead].set(jnp.nan)
+        if program == "decode":
+            return jax.jit(lambda c: granite.decode_step(
+                eng.params, c, tokens[:, 0], pos, on, eng.cfg))(cache)
+        return jax.jit(lambda c: granite.prefill_chunk(
+            eng.params, c, tokens, pos, np.array([1, 5, 1], np.int32), on,
+            eng.cfg))(cache)
+
+    (got, got_cache), (want, want_cache) = run(True), run(False)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got[on]).all() and np.abs(want[on]).max() > 1e-4
+    assert np.abs(got - want)[on].max() <= FLOAT32_LOGIT_TOLERANCE
+    for name, before in start.items():
+        after = np.asarray(got_cache[name])
+        np.testing.assert_array_equal(after[:, ~on], before[:, ~on])
+        assert (after[:, on] != before[:, on]).any()
+        np.testing.assert_allclose(after, np.asarray(want_cache[name]),
+                                   rtol=0, atol=1e-6)
+    if dead is not None:
+        beside, _ = run(True, garbage=True)
+        np.testing.assert_array_equal(np.asarray(beside)[on], got[on])
+
+
+@pytest.mark.parametrize("T,on_the_chip,block", [
+    (8192, True, 512), (8192, False, 8192), (1024, True, 128),
+    (1024, False, 1024), (96, True, 96)])
+def test_the_block_the_engine_counts_rows_read_by_follows_the_path(
+        monkeypatch, T, on_the_chip, block):
+    """`granite.rows_read_block` is `ops/gqa_attend.read_block` of the
+    cache's leaves (which `tests/test_ops_gqa_attend.py` holds to the grid
+    the op traces): `block_last` of the leaf's length where the kernel runs,
+    all T where the plain form does."""
+    from ray_tpu.ops import slot_state
+
+    monkeypatch.setattr(slot_state, "on_tpu", lambda: on_the_chip)
+    cache = jax.eval_shape(lambda: granite.init_cache(tiny(), 2, T))
+    assert granite.rows_read_block(cache) == block
+
+
+def test_the_engine_counts_the_positions_read_beside_the_attended():
+    """Off the chip every lane of every step reads all T: `positions_read`
+    is in `engine_stats()` for this family, T a lane."""
+    eng = LLMEngine(preset="granite-tiny", max_batch=2, max_seq_len=64,
+                    seed=SEED, prefill_chunk_size=16, kv_block_size=8)
+    try:
+        assert eng._rows_read_block == 64
+        eng.generate(prompt_ids=PROMPT[:20], max_tokens=4, temperature=0.0)
+        stats = eng.engine_stats()
+        assert stats["positions_read"] == 64 * stats["engine_steps"]
+        assert 0 < stats["positions_attended"] < stats["positions_read"]
+    finally:
+        eng.shutdown()
 
 
 def test_a_layer_made_alone_is_the_layer_in_the_tree():

@@ -403,11 +403,13 @@ LANES_CASES = {
     "gpt2-the-own-row-alone-at-position-0": dict(XL, pos=[0, 0],
                                                  live=[1, 1]),
     "gpt2-no-slot-live": dict(XL, pos=EDGES, live=[0, 0, 0, 0]),
-    # granite's, for ROADMAP S19a: rows written before they are read
+    # granite's (`granite._attention_first`, PR 63): rows written before
+    # they are read; at 8,192 the block is a 16th of the leaf
     "granite-rows-written-first": dict(
-        GRANITE, pos=[0, LAST - 1, LAST, 8191, 5000], live=[1, 1, 1, 1, 0]),
+        GRANITE, block=512, pos=[0, LAST - 1, LAST, 511, 512, 8191, 5000],
+        live=[1, 1, 1, 1, 1, 1, 0]),
     "granite-the-own-row-handed-over": dict(
-        GRANITE, pos=[0, LAST, 8191], live=[1, 1, 1], own=True),
+        GRANITE, block=512, pos=[0, 512, 8191], live=[1, 1, 1], own=True),
     # a q of another dtype: two pieces, of it and of its probabilities
     "float32-q-two-pieces": dict(GRANITE, T=1024, pos=EDGES,
                                  live=[1, 1, 0, 1], q=F32),
@@ -429,7 +431,7 @@ def test_leaves_by_the_lane_through_the_kernel_are_the_plain_form(
     q_dtype = LANES_CASES[case].get("q", BF16)
     is_gpt2 = case.startswith("gpt2")
     monkeypatch.setattr(op, "BLOCK_LAST", LAST)
-    assert op.block_last(T) == LAST
+    assert op.block_last(T) == LANES_CASES[case].get("block", LAST)
     B, L, d, layer = len(pos), 2, 64, 1
     ks = jax.random.split(jax.random.key(61), 5)
     q = (4 * jax.random.normal(ks[0], (B, G, R, d), F32)).astype(q_dtype)
@@ -473,13 +475,64 @@ def test_leaves_by_the_lane_through_the_kernel_are_the_plain_form(
                                    rtol=0, atol=1e-6)
 
 
-@pytest.mark.parametrize("T,most,block", [
-    (1024, 256, 256), (1024, 512, 512), (8192, 256, 256), (1024, 2048, 1024),
-    (96, 256, 96), (384, 256, 128), (1000, 256, 256)])
+@pytest.mark.parametrize("T,least,block", [
+    (1024, 256, 256), (1024, 512, 512), (8192, 256, 512), (1024, 2048, 1024),
+    (96, 256, 96), (384, 256, 128), (1000, 256, 256),
+    # the shipped `BLOCK_LAST`: GPT-2's cells' leaf and granite's cell's
+    (1024, None, 128), (8192, None, 512), (2048, None, 128),
+    (4096, None, 256), (64, None, 64), (16384, None, 1024),
+    (131072, None, 1024),   # `slot_rows.BLOCK`: what VMEM was sized for
+    # 16ths that are no whole tiles or divide nothing: the longest that do
+    (3072, None, 128), (6144, None, 384), (10240, None, 640),
+    # a length no block divides: the bound itself, the last block ragged
+    (8200, None, 512), (1000, None, 128)])
 def test_the_lanes_block_is_whole_lane_tiles_that_divide_the_length(
-        monkeypatch, T, most, block):
-    monkeypatch.setattr(op, "BLOCK_LAST", most)
+        monkeypatch, T, least, block):
+    """`BLOCK_LAST` positions at least and a 16th of the leaf above that,
+    cut to whole lane tiles that divide T where there are such."""
+    if least is not None:
+        monkeypatch.setattr(op, "BLOCK_LAST", least)
+    assert (op.BLOCK_LAST, op.STEPS_LAST) == (least or 128, 16)
     assert op.block_last(T) == block
+    assert block == T or block % 128 == 0
+
+
+def _grids(jaxpr):
+    """The grid of every Pallas call in a jaxpr, calls within calls too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _grids(sub)
+
+
+@pytest.mark.parametrize("how", [dict(interpret=True), dict(kernel=False)],
+                         ids=["kernel", "plain"])
+@pytest.mark.parametrize("keys,values,T,block", [
+    ((8, 64, 8192), (8, 64, 8192), 8192, 512),      # granite's leaves
+    ((25, 64, 1024), (25, 64, 1024), 1024, 128),    # GPT-2's, as viewed
+    ((8, 2304, 64), (8, 2304, 64), 2304, 768),      # rows: `slot_rows.BLOCK`
+    ((4, 192, 2304), (4, 2304, 128), 2304, 768),    # MiMo's: keys last only
+], ids=["lanes-8192", "lanes-1024", "rows", "keys-on-the-lanes"])
+def test_read_block_is_the_block_the_call_takes(keys, values, T, block, how):
+    """`read_block`, what the engines count `positions_read` by, against the
+    program `gqa_attend` traces for the same leaves: the grid's steps a slot
+    are T over it where the kernel runs; no Pallas call and all T where the
+    plain form does."""
+    B, d = 2, 64 if keys[1] == 64 or keys[2] == 64 else 192
+    ck, cv = (jax.ShapeDtypeStruct((1, B) + shape, BF16)
+              for shape in (keys, values))
+    q = jax.ShapeDtypeStruct((B, keys[0], 2, d), BF16)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+    live = jax.ShapeDtypeStruct((B,), bool)
+    grids = list(_grids(jax.make_jaxpr(lambda q, ck, cv, pos, live: (
+        op.gqa_attend(q, ck, cv, 0, pos, live, 0.125, **how)))(
+            q, ck, cv, pos, live).jaxpr))
+    got = op.read_block(ck.shape, cv.shape, d, **how)
+    if "kernel" in how:
+        assert not grids and got == T
+    else:
+        assert got == block and grids == [(B, T // block)]
 
 
 def test_a_leaf_by_the_lane_whose_last_block_hangs_over(monkeypatch):

@@ -634,7 +634,7 @@ def test_an_engines_tokens_through_the_kernels_are_the_plain_paths(
     256 a lane either way."""
     with monkeypatch.context() as m:
         got, stats, decode_steps, chunk_lanes = _served(True, m)
-    want, plain, _, _ = _served(False, monkeypatch)
+    want, plain, plain_steps, plain_chunk_lanes = _served(False, monkeypatch)
     assert got == want
     assert len({t for reply in want.values() for t in reply}) > 100
     # both engines planned the same steps
@@ -653,18 +653,23 @@ def test_an_engines_tokens_through_the_kernels_are_the_plain_paths(
         == {0, 1}
     assert stats["positions_read"] == read + 256 * chunk_lanes
     # the plain path reads all T a lane of a step, chunk or decode
-    assert plain["positions_read"] == 256 * (lanes + chunk_lanes)
+    assert plain["positions_read"] == 256 * (sum(
+        int(on.sum()) for _, on in plain_steps) + plain_chunk_lanes)
     for s in (stats, plain):
         assert s["positions_read"] >= s["positions_attended"] > attended
 
 
-def test_only_a_family_that_names_its_block_counts_positions_read():
+@pytest.mark.parametrize("preset,block", [
+    ("deepseek-tiny", 0), ("granite-tiny", 64), ("gpt2-tiny", 64)])
+def test_only_a_family_that_names_its_block_counts_positions_read(
+        preset, block):
+    """GPT-2 and granite (since PR 63) name theirs, all T off the chip."""
     from ray_tpu.serve.llm import LLMEngine
 
-    eng = LLMEngine(preset="granite-tiny", max_batch=2, max_seq_len=64)
+    eng = LLMEngine(preset=preset, max_batch=2, max_seq_len=64)
     try:
-        assert eng._rows_read_block == 0
-        assert "positions_read" not in eng.engine_stats()
+        assert eng._rows_read_block == block
+        assert ("positions_read" in eng.engine_stats()) == bool(block)
         assert "positions_attended" in eng.engine_stats()
     finally:
         eng.shutdown()

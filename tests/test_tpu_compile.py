@@ -487,7 +487,14 @@ def test_kanana_serving_programs_compile_at_the_configurations_sizes(
 # older form's bytes (13,163,250,176 and 13,507,884,032) until a `benchmark`
 # issue, so the pins are this file's own, and no larger
 BRUMBY_CHUNK_BYTES = 13_159_184_896
-GRANITE_CHUNK_BYTES = 13_457_487_872
+# granite's since PR 63: every slot's first lane attends through
+# `ops/gqa_attend.py` (the decode program's temporaries were 39,895,040 B of
+# its 13,389,182,976, where one attention layer's float32 scores
+# [48, 8, 4, 8192] shared the head's pieces' space; the chunk program needed
+# 13,457,487,872, 258,048 fewer, all of it temporaries)
+GRANITE_DECODE_BYTES = 13_351_506_944
+GRANITE_DECODE_TEMP_BYTES = 2_219_008
+GRANITE_CHUNK_BYTES = 13_457_745_920
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -543,15 +550,18 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
     """The cell `serve-granite-docgen`'s two programs, as its configuration
     file has them (granite-4.0-h-micro whole: 36 Mamba-2 and 4 attention
     layers, 48 slots of 77 MB of float32 state and 8,192 positions of rows,
-    chunks of 64): the bytes the file gives, room for the pool of both kinds
-    beside the larger; four Pallas kernels in both programs (the state's
-    update in each of the two Mamba runs' loop bodies, a row's write for keys
-    and for values: a chunk's first lane is the decode program's); no
-    instruction copies a cache leaf (the kernel
-    aliases the SSM state, the layers' loops carry the four leaves) or
-    materialises one layer's state for all slots; the decode program's
-    temporaries are one attention layer's scores, the chunk program's under
-    a quarter of a gigabyte: neither computes the padding of 48 x 64 lanes."""
+    chunks of 64): under the bytes the file gives, room for the pool of both
+    kinds beside the larger; five Pallas kernels in both programs (the
+    state's update in each of the two Mamba runs' loop bodies, a row's write
+    for keys and for values and the one `gqa_attend` in the attention layer:
+    a chunk's first lane is the decode program's); **no float32 scores of
+    every slot's first lane over all 8,192 positions, `[48, 8, 4, 8192]`,
+    nor their probabilities in bf16: they stay in VMEM**; no instruction
+    copies a cache leaf (the kernels alias the SSM state and take `k` and
+    `v` whole with the layer's index, the layers' loops carry the four
+    leaves) or materialises one layer's state for all slots; the decode
+    program's temporaries are 2 MB, the chunk program's under a quarter of a
+    gigabyte: neither computes the padding of 48 x 64 lanes."""
     import json
 
     chip_dir, _ = _chip_bench()
@@ -563,13 +573,13 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
     memory = config["memory"]
     compiled = compile_step(config, chips, program)
     sized = program_bytes(compiled)
-    want = (memory["decode_step_bytes"] if program == "decode" else memory[
-        "prefill_chunk_bytes_by_chunk_size"][
-            str(config["deployment"]["prefill_chunk_size"])])
-    if program == "prefill":
-        assert GRANITE_CHUNK_BYTES <= want
-        want = GRANITE_CHUNK_BYTES
-    assert sized["total"] == want
+    if program == "decode":
+        assert sized["total"] == GRANITE_DECODE_BYTES \
+            < memory["decode_step_bytes"]
+    else:
+        assert sized["total"] == GRANITE_CHUNK_BYTES < memory[
+            "prefill_chunk_bytes_by_chunk_size"][
+                str(config["deployment"]["prefill_chunk_size"])]
     assert sized["arguments"] == memory["arguments_bytes"] + (
         0 if program == "decode" else 48 * 64 * 4 * 2)   # the chunk's tokens
     assert sized["arguments"] >= 0.75 * HBM_BYTES
@@ -580,11 +590,45 @@ def test_granite_serving_programs_compile_at_the_configurations_sizes(
     assert pool_bytes(config) == memory["prefix_pool_bytes"]
     assert sized["total"] + pool_bytes(config) <= 0.95 * HBM_BYTES
     if program == "decode":
-        assert sized["temp"] == memory["decode_step_temp_bytes"] < 2 ** 26
+        assert sized["temp"] == GRANITE_DECODE_TEMP_BYTES \
+            < memory["decode_step_temp_bytes"] // 16
     else:
         assert sized["temp"] < 2 ** 28
-    assert made_of(compiled.as_text(), config) == {
-        "kernels": 4, "leaf_copies": {}, "ssm_layer_copies": []}
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert sum("/kv_update/" in c and "rows_write" in c for c in calls) == 2
+    assert sum("/gqa_attend/" in c for c in calls) == 1
+    assert sum("/ssm_update/" in c for c in calls) == 2
+    for dtype in ("f32", "bf16"):
+        assert _written_arrays(hlo, "48,8,4,8192", dtype) == []
+    assert made_of(hlo, config) == {
+        "kernels": 5, "leaf_copies": {}, "ssm_layer_copies": []}
+
+
+@pytest.mark.parametrize("T,slots,block", [
+    (4096, 8, 256), (16384, 8, 1024), (131072, 2, 1024)],
+    ids=["a-16th", "the-longest-block", "the-models-own-length"])
+def test_granites_decode_program_compiles_at_other_lengths_of_leaf(
+        chips, as_on_tpu, T, slots, block):
+    """`gqa_attend.block_last`'s rule away from the two lengths that were
+    timed: the TPU's compiler takes the decode program at a 16th of a
+    shorter leaf, at `slot_rows.BLOCK` where a 16th passes it, and at
+    granite-4.0-h-micro's own 131,072 positions (blocks of 1,024: VMEM holds
+    them, a 16th it could not), the attention one kernel and no float32
+    scores over all T written. How fast, no run has said (PERF.md §7)."""
+    import json
+
+    chip_dir, _ = _chip_bench()
+    from compile_granite_for_v5e import CONFIG, compile_step
+    from ray_tpu.ops.gqa_attend import block_last
+
+    with open(os.path.join(chip_dir, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    config["deployment"].update(max_seq_len=T, max_batch=slots)
+    assert block_last(T) == block
+    hlo = compile_step(config, chips, "decode").as_text()
+    assert sum("/gqa_attend/" in c for c in _mosaic_calls(hlo)) == 1
+    assert _written_arrays(hlo, f"{slots},8,4,{T}", "f32") == []
 
 
 # Kimi's programs since PR 42: every slot's first lane attends through the
